@@ -19,19 +19,32 @@ scheduling → execution → evaluation/visualization), rebuilt TPU-first:
 See SURVEY.md for the layer map and parity notes.
 """
 
-from .utils.config import env_str as _env_str
+import os as _os
 
-# DLS_PLATFORM=cpu|tpu pins the JAX platform before the first backend touch
-# (e.g. to keep CLI/dev runs on the host when no accelerator is reachable);
-# DLS_FORCE_CPU=1 is shorthand for DLS_PLATFORM=cpu.  Must run before
-# anything resolves a backend; importing this package first is enough.
-_plat = _env_str("DLS_PLATFORM") or (
-    "cpu" if _env_str("DLS_FORCE_CPU") else None
-)
-if _plat:
+
+def _place_compile_cache() -> None:
+    """The ONE place the persistent compilation cache is configured.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, jax reads it itself and
+    nothing here touches the setting.  Otherwise the cache lives at the
+    fixed ``<checkout>/.jax_cache`` (git-ignored): the directory is part
+    of nothing's identity but must not move between runs, so never a temp
+    name, a pid or a time.  Runs before any backend is touched —
+    importing this package first is enough."""
+    if _os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        return
     import jax as _jax
 
-    _jax.config.update("jax_platforms", _plat)
+    _jax.config.update(
+        "jax_compilation_cache_dir",
+        _os.path.join(
+            _os.path.dirname(_os.path.dirname(_os.path.abspath(__file__))),
+            ".jax_cache",
+        ),
+    )
+
+
+_place_compile_cache()
 
 from .core.graph import (
     DEFAULT_PARAM_GB,

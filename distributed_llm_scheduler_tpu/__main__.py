@@ -120,6 +120,16 @@ def _load_pretrained_weights(path: str, config, model_name: str):
     return params
 
 
+def device_info() -> dict:
+    """The device the run used, as JAX reports it — stamped into the
+    ``serve``/``execute`` JSON so no number travels without it."""
+    import jax
+
+    devs = jax.devices()
+    return {"platform": devs[0].platform, "kind": devs[0].device_kind,
+            "count": len(devs)}
+
+
 def _export_trace(schedule, path: str, graph=None) -> int:
     """Shared --trace export: 0 on success, 2 (with stderr) on failure.
     ``graph`` adds cross-device transfer-edge flow arrows."""
@@ -561,6 +571,16 @@ def cmd_execute(args) -> int:
         stream_params=args.stream_params,
     )
     summary = rep.summary()
+    summary["device"] = device_info()
+    in_shape = getattr(dag.input_spec, "shape", None)
+    if in_shape:
+        # the forward DAGs leave mha on auto; name what that resolved to
+        # at the DAG's sequence length on this backend
+        from .ops.attention import pallas_supported, resolve_attention_impl
+
+        summary["attention_impl"] = resolve_attention_impl(
+            None, lambda _i: pallas_supported((in_shape[-1], 1))
+        )
     if inject:
         recovery = _injected_recovery(
             inject, dag, schedule, cluster, cfg, rep, params, ids,
@@ -1220,14 +1240,14 @@ def cmd_generate(args) -> int:
 
 
 def cmd_rankcheck(args) -> int:
-    """Sim-vs-real rank agreement (VERDICT r2 #2): schedule with several
+    """Sim-vs-real rank agreement: schedule with several
     policies, predict makespans with the full-fidelity simulator, execute
     each placement on the live devices, report rank agreement as JSON."""
     from .eval.rankcheck import run_rank_check
 
     kwargs = {}
     if args.stress:
-        # the separating configuration (VERDICT r3 next #3): transfer-bound
+        # the separating configuration: transfer-bound
         # by construction, so the sim claims a winner and the check bites
         import jax
 
@@ -1609,8 +1629,19 @@ def cmd_serve(args) -> int:
         time_model=ServiceTimeModel(),
     )
     report = fe.run()
+    # what ran, on what: the resolved attention impl (the request may be
+    # auto), the device, and the digest over serving log + every token —
+    # two runs that must agree (same arrivals, another impl) compare it
+    report["attention_impl"] = eng.resolved_attention_impl
+    report["device"] = device_info()
+    report["digest"] = fe.digest()
 
     out = {k: v for k, v in report.items() if k != "requests"}
+    # per-request tokens ride the --out file only (the stdout summary
+    # stays a summary)
+    report["tokens"] = {
+        rid: fe.results[rid].tolist() for rid in sorted(fe.results)
+    }
     if report["breached"] and args.flight_dir:
         from .obs.export import validate_trace
 
@@ -2327,8 +2358,6 @@ def cmd_bench(args) -> int:
 
 
 def main(argv=None) -> int:
-    # DLS_PLATFORM / DLS_FORCE_CPU are applied by the package __init__,
-    # which python -m imports before this function runs.
     ap = argparse.ArgumentParser(
         prog="distributed_llm_scheduler_tpu",
         description="TPU-native memory-constrained DAG scheduling for LLMs",

@@ -3,7 +3,7 @@
 Multi-pass analyzer emitting structured :class:`Diagnostic` records with
 stable codes (``DAG001`` cycle, ``MEM003`` hbm-overcommit, ``SHD002``
 spec-rank-mismatch, ...) instead of ad-hoc exceptions — see
-docs/ANALYSIS.md for the full taxonomy.  Entry points:
+docs/ANALYSIS.md for the full catalogue.  Entry points:
 
 * :func:`analyze` — run every applicable pass, return one report (the
   ``lint`` CLI subcommand is a thin wrapper over this);

@@ -54,10 +54,9 @@ def analyze_decode(
     * ``DEC005`` (warning, needs ``param_specs``): the paged pool
       geometry (page_size / head_dim / kv-head layout read off the
       ``cache_*`` pool specs) makes the fused Pallas kernel ineligible,
-      so every ``impl="auto"``/``"pallas"`` dispatch silently falls back
-      to the XLA gather path.  The message names each violated tiling
-      constraint.  A warning, never a gate: the gather path is correct,
-      just slower.
+      so every ``impl="auto"`` dispatch takes the XLA gather path (an
+      explicit ``"pallas"`` raises).  The message names each violated
+      tiling rule.  A warning, never a gate: the gather path is correct.
     * ``DEC006`` (warning, needs ``chunk_tokens``): the configured
       chunked-prefill chunk size is degenerate — either it violates the
       ragged multi-token-q kernel's tiling constraints
@@ -174,8 +173,8 @@ def analyze_decode(
                     "DEC005",
                     Severity.WARNING,
                     "paged pool geometry is ineligible for the fused "
-                    "Pallas attention kernel (impl='auto'/'pallas' "
-                    "silently falls back to the XLA gather path): "
+                    "Pallas attention kernel (impl='auto' takes the XLA "
+                    "gather path; an explicit 'pallas' raises): "
                     + "; ".join(violated),
                     data={
                         "page_size": int(page_size),

@@ -26,7 +26,7 @@ class Severity(enum.IntEnum):
         return self.name.lower()
 
 
-#: The documented taxonomy: every code a pass may emit, with a short
+#: The documented catalogue: every code a pass may emit, with a short
 #: description.  docs/ANALYSIS.md mirrors this table; tests assert that
 #: emitted codes stay within it.
 CODES: Dict[str, str] = {
@@ -69,7 +69,7 @@ CODES: Dict[str, str] = {
     "DEC003": "inconsistent paged KV wiring (pools vs page_table)",
     "DEC004": "per-step KV-cache residency (informational)",
     "DEC005": "paged geometry ineligible for the fused Pallas kernel "
-              "(silent gather fallback)",
+              "(auto takes the gather path)",
     "DEC006": "degenerate chunked-prefill chunk size (ragged kernel "
               "ineligible or chunk exceeds the per-segment budget)",
     # -- quantization dtype flow (quant_pass) ---------------------------

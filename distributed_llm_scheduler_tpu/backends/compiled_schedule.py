@@ -14,7 +14,7 @@ Lowering model (MPMD inside SPMD):
 
 * The participating devices form a 1-D mesh (axis ``"dev"``, mesh order
   = cluster order).  The program is SPMD over that mesh via
-  ``parallel/compat.shard_map``.
+  ``jax.shard_map``.
 * Per-device heterogeneous compute is a ``lax.switch`` on
   ``lax.axis_index``: phase ``p``'s branch for device ``d`` runs exactly
   device ``d``'s phase-``p`` tasks (each task's computation pinned as
@@ -490,7 +490,7 @@ class CompiledSchedule:
             outs.extend(jnp.expand_dims(l, 0) for l in fin_leaves)
             return tuple(outs)
 
-        from ..parallel.compat import shard_map
+        from jax import shard_map
 
         in_specs = (
             tuple(P("dev") for _ in dtype_keys),

@@ -59,22 +59,21 @@ from typing import Any, Dict, List, Optional, Sequence, Tuple
 import jax
 from jax.sharding import SingleDeviceSharding
 
-try:
-    # fast transfer path: with the target sharding and source avals known
-    # at plan time, calling the runtime's batched_device_put directly skips
-    # ~30 us/array of argument normalization inside public
-    # ``jax.device_put`` (sharding inference, pytree flatten, aval
-    # abstraction).  Semantics match the public path for the cross-device
-    # moves the plan issues (the public path's same-device aliasing
-    # shortcut never applies to them).
-    from jax._src.lib import xla_client as _xc
-
-    def _fast_put(aval, sharding, xs, devices):
-        return _xc.batched_device_put(aval, sharding, xs, devices, True)
-except Exception:  # pragma: no cover - private API moved; use public path
-    _fast_put = None
+# fast transfer path: with the target sharding and source avals known at
+# plan time, calling the runtime's batched_device_put directly skips
+# ~30 us/array of argument normalization inside public ``jax.device_put``
+# (sharding inference, pytree flatten, aval abstraction).  Semantics match
+# the public path for the cross-device moves the plan issues (the public
+# path's same-device aliasing shortcut never applies to them).  Private
+# API of the pinned jax (pyproject.toml): an upgrade that moves it fails
+# here, at import.
+from jax._src.lib import xla_client as _xc
 
 from .rebatch import extract_steps
+
+
+def _fast_put(aval, sharding, xs, devices):
+    return _xc.batched_device_put(aval, sharding, xs, devices, True)
 
 
 def _array_bytes(x: Any) -> int:
@@ -722,7 +721,7 @@ class DispatchPlan:
                 if metrics is not None or mem is not None:
                     per_edge = [_array_bytes(x) for x in srcs]
                 t0 = time.perf_counter()
-                if step.xfer_avals and _fast_put is not None:
+                if step.xfer_avals:
                     shard, devs = step.xfer_shard, step.xfer_devs
                     moved = [
                         _fast_put(av, shard, [x], devs)

@@ -24,6 +24,11 @@ from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Set
 
+#: budget a host-platform (CPU) device gets when it stands in for a TPU
+#: core in tests and rehearsals: CPU devices have no HBM and report no
+#: ``memory_stats()``.  Accelerators never take this value.
+HOST_STANDIN_GB = 16.0
+
 
 @dataclass
 class DeviceState:
@@ -196,25 +201,25 @@ class Cluster:
                          hbm_cap_gb: Optional[float] = None) -> "Cluster":
         """Build from live JAX devices (one DeviceState per core).
 
-        HBM budget per core comes from ``memory_stats()`` when the platform
-        reports it (TPU does), else ``hbm_cap_gb``, else a conservative
-        default.  Cores are identical, so ``compute_speed`` is 1.0; use
-        ``hbm_cap_gb`` to emulate constrained memory regimes on real
-        hardware (the TPU analog of the reference's regime sweep).
+        HBM budget per core: ``hbm_cap_gb`` when given (emulates a
+        constrained memory regime on real hardware — the TPU analog of
+        the reference's regime sweep), else the device's own
+        ``memory_stats()`` byte limit
+        (:func:`..utils.costmodel.device_hbm_bytes`: a TPU that reports
+        none is an error, never an assumed capacity; host-platform
+        devices stand in at ``HOST_STANDIN_GB``).  Cores are identical,
+        so ``compute_speed`` is 1.0.
         """
         import jax
+
+        from ..utils.costmodel import device_hbm_bytes
 
         devices = list(devices if devices is not None else jax.devices())
         out = []
         for i, dev in enumerate(devices):
             cap = hbm_cap_gb
             if cap is None:
-                try:
-                    stats = dev.memory_stats() or {}
-                    limit = stats.get("bytes_limit")
-                    cap = limit / 1024**3 if limit else 16.0
-                except Exception:
-                    cap = 16.0
+                cap = device_hbm_bytes(dev) / 1024**3
             out.append(DeviceState(
                 f"core_{i}", cap, 1.0, jax_device=dev,
                 slice_id=getattr(dev, "slice_index", None) or 0,

@@ -6,7 +6,7 @@ schedule-consistency and memory-feasibility passes and re-shapes their
 structured diagnostics into the original :class:`ValidationReport`
 (message texts unchanged — callers and tests match on substrings).  New
 code should call :func:`analysis.analyze` directly for coded diagnostics;
-see docs/ANALYSIS.md for the taxonomy.
+see docs/ANALYSIS.md for the catalogue.
 """
 
 from __future__ import annotations

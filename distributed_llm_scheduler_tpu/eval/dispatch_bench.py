@@ -209,8 +209,8 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=None, help="write JSON report here")
     args = ap.parse_args(argv)
 
-    # route around any registered accelerator plugin — the microbench is
-    # a host-overhead measurement and must run on the faked CPU mesh
+    # a host-overhead measurement on the virtual CPU mesh by design:
+    # pinned so it never lands on an attached accelerator
     jax.config.update("jax_platforms", "cpu")
     if len(jax.devices()) < 8:
         print(

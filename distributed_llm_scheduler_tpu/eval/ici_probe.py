@@ -3,8 +3,8 @@
 The flagship bench's ICI sweep (``benchlib.ici_sensitivity``) replays
 FIXED placements in a host-link-bound regime, where a +/-4x ICI error
 moves nothing — correct, but it leaves the estimated tiers untested in
-any regime where interconnect could actually decide placement (VERDICT
-r3 weak #7 / next #8).  This probe constructs that regime: BASELINE
+any regime where interconnect could actually decide placement.  This
+probe constructs that regime: BASELINE
 config #3 — the Llama-3 8B layer DAG (15 GB bf16, cannot fit one 14 GB
 core, so placement is genuinely multi-device) on a modeled 2 x v5e-8
 multislice with the tiered ICI/DCN link — and, per interconnect scale,
@@ -31,8 +31,7 @@ import sys
 import time
 from typing import Any, Dict, Sequence
 
-# all nine registered policies (VERDICT r4 next #3: the r4 probe covered
-# only 5, leaving dfs/mru/pack/refine unexamined at the 5k-task scale)
+# all nine registered policies
 POLICIES = (
     "roundrobin", "dfs", "greedy", "critical", "mru",
     "heft", "pipeline", "pack", "refine",

@@ -1,4 +1,4 @@
-"""Sim-vs-real policy RANK agreement (VERDICT r2 weak #3 / next #2).
+"""Sim-vs-real policy RANK agreement.
 
 The reference's replay rewarded schedulers for a fiction (reference
 ``simulation.py:216-278``: no dependency waits, no transfer costs) — the

@@ -259,8 +259,9 @@ def main(argv=None) -> int:
     ap.add_argument("--out", default=None, help="write JSON report here")
     args = ap.parse_args(argv)
 
-    # route around any registered accelerator plugin — the mesh is only
-    # a device-count fixture here; every number is simulated
+    # the mesh is only a device-count fixture here and every number is
+    # simulated: pinned to the virtual CPU mesh so it never lands on an
+    # attached accelerator
     jax.config.update("jax_platforms", "cpu")
     if len(jax.devices()) < 8:
         print(

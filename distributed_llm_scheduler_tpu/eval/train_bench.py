@@ -7,7 +7,7 @@ the forward chain — each layer's params are needed a second time far from
 the first, and forward activations stay live until their distant backward
 consumer: the activation-memory eviction-stress workload.
 
-This bench is that workload's measured deliverable (VERDICT r3 next #5):
+This bench is that workload's measured deliverable:
 
 1. execute the FULL train-step DAG on a live device (single chip / CPU
    mesh), loss + updated params checked against the fused
@@ -62,7 +62,7 @@ def measure_train_dag(
     from .. import Cluster, DeviceState, get_scheduler, validate_schedule
     from ..backends.device import DeviceBackend
     from ..backends.sim import SimulatedBackend
-    from ..eval.benchlib import choose_cost_model, choose_link, pick_best
+    from ..eval.benchlib import choose_link, pick_best
     from ..frontend.train_dag import build_gpt2_train_dag
     from ..models.gpt2 import GPT2Config
     from ..sched.policies import ALL_SCHEDULERS
@@ -100,11 +100,13 @@ def measure_train_dag(
         f"loss {loss_got:.4f} vs oracle {loss_want:.4f}; "
         f"params+grads match: {oracle_ok}")
 
-    # 2. measured cost model (cached-TPU / derived / live-CPU chain)
-    name_tag = f"gpt2_train_{config.n_layer}l_d{config.n_embd}_b{batch}_t{seq_len}"
-    cm, cost_suffix = choose_cost_model(
-        graph, params, inputs, dev, cache_dir=cache_dir,
-        base_graph_name=name_tag, log=log,
+    # 2. cost model measured on this device (or this machine's cache of
+    # one for the same device_kind)
+    from ..utils.costmodel import calibrate_cached, recalibrate_requested
+
+    cm = calibrate_cached(
+        graph, params, inputs, cache_dir, device=dev,
+        refresh=recalibrate_requested(),
     )
     cm.apply(graph)
 
@@ -118,7 +120,7 @@ def measure_train_dag(
     cluster = Cluster(
         [DeviceState(f"core_{i}", min(budget, hbm_gb)) for i in range(8)]
     )
-    link, link_prov = choose_link(cost_suffix, cache_dir=cache_dir)
+    link, link_prov = choose_link(cache_dir=cache_dir)
     sim = SimulatedBackend(fidelity="full", link=link, dispatch_s=cm.dispatch_s)
     makespans = {}
     schedules = {}
@@ -146,7 +148,8 @@ def measure_train_dag(
     return {
         "model": graph.name,
         "platform": platform,
-        "cost_provenance": (cost_suffix.lstrip("_") or "live-tpu"),
+        "device_kind": dev.device_kind,
+        "cost_provenance": "cache" if cm.cache_hit else "live",
         "link_provenance": link_prov,
         "n_tasks": len(graph),
         "total_param_gb": round(graph.total_param_gb(), 4),

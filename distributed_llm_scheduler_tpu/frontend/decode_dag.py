@@ -2,7 +2,7 @@
 
 The task-graph path (the repo's thesis) and the whole-program decode loop
 (:mod:`..models.decode`) are deliberately twinned everywhere else; this
-builder closes the last gap (VERDICT r2 missing #4): the scheduling layer
+builder closes the last gap: the scheduling layer
 never saw an inference workload.  One cached forward step — prefill
 (``pos = 0``, ``step_len`` = prompt length) or a decode step
 (``step_len = 1``) — becomes a per-layer task DAG where the **KV cache
@@ -21,7 +21,7 @@ slabs are placeable parameters**:
   RoPE/wpe rows are dynamic-sliced at it, cache updates land at it.  ONE
   graph therefore serves every position of a given ``(step_len,
   max_len)`` class — an N-token generation compiles exactly two programs
-  (prefill + decode step), not N (VERDICT r3 next #7).  Compute per step
+  (prefill + decode step), not N.  Compute per step
   is O(max_len) regardless of position (the cache is scanned fully,
   masked), which is also what the FLOPs fields record.
 

@@ -13,7 +13,7 @@ locality, MRU's sweet spot").  The reference itself has no MoE.
 The backbone assembly lives in :mod:`.backbone`, shared with the Llama
 frontend; only the router/experts/combine section is defined here.
 
-Two dispatch modes (VERDICT r3 next #4):
+Two dispatch modes:
 
 * ``routed=False`` (default): experts compute densely (see
   :mod:`..models.mixtral` for why XLA historically wants that);

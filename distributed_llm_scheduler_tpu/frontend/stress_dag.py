@@ -1,6 +1,6 @@
 """Transfer-stress DAG: a workload whose makespan is decided by placement.
 
-Purpose (VERDICT r3 next #3): the flagship rank check runs in the CPU
+Purpose: the flagship rank check runs in the CPU
 mesh's compute-tied regime, where every reasonable placement predicts (and
 measures) a near-tie — an agreement check there "passes" only by tie
 semantics and guards nothing.  This builder constructs the opposite

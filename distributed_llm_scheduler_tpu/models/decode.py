@@ -190,21 +190,12 @@ def _decode_attention_natural(
     """Single-token cached attention in MXU-natural orientation.
 
     The prefill-orientation einsum (``bhqd,bhkd->bhqk``) at T_new = 1
-    forces XLA to transpose the K cache every step — measured 120 GB/s
-    effective on the v5e, ~1/5 of what the chip streams at these shapes.
-    Computing scores as ``K @ q`` instead ((B, Hkv, M, G) with M on
-    sublanes, exactly the cache's storage layout) runs the identical
-    math at 576 GB/s (0.81 -> 0.29 ms/step on the 12-layer flagship, a
-    same-session v5e probe; the committed ``DECODE_r04.json``
-    attribution predates the fix and shows the transposing form at
-    1.91 ms — 10.9% of its byte bound.  The r6 recapture ran on a host
-    core, where the shipped orientation measures 4.7 of the 251 ms CPU
-    step — ``DECODE_r06.json`` ``attribution.attn_ms`` — attention is a
-    ~2% slice there, so the GB/s ratio above stays v5e-attributed).  A
-    Pallas per-layer kernel was tried first
-    and LOST: ~66 us fixed cost per pallas_call x 12 sequential layers
-    swamps any in-kernel win — the right decode kernel here is the one
-    XLA already has, fed shapes in its preferred orientation.
+    makes XLA transpose the K cache every step.  Computing scores as
+    ``K @ q`` instead ((B, Hkv, M, G) with M on sublanes, exactly the
+    cache's storage layout) runs the identical math with both operands
+    read in storage order.  The rewrite's on-chip gain has no surviving
+    record: not measured (ROADMAP S4 re-measures decode attention on the
+    v5e).
 
     GQA comes free: the query group joins the G axis (``bhgd`` below),
     so K/V stream ONCE per KV head — the prefill path's ``jnp.repeat``

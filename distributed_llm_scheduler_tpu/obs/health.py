@@ -23,7 +23,7 @@ CLI's exit-1 message, ``summary()`` for humans.  The flight recorder
 grows a matching ``health=`` trigger so the first mid-soak breach
 dumps the ring while the anomaly's events are still in it.
 
-Detector taxonomy (all enabled by default):
+Detector catalogue (all enabled by default):
 
 ========  ==========================  ======================================
 code      detector                    breach means

@@ -28,7 +28,7 @@ Design constraints, in order:
 
 Track names are free-form strings; by convention ``"host"``
 (:data:`HOST_TRACK`) carries the execute phases and every device node_id
-(``node_0`` …) carries its launches.  The span taxonomy is documented in
+(``node_0`` …) carries its launches.  The span catalogue is documented in
 ``docs/OBSERVABILITY.md``.
 """
 

@@ -1,27 +1,21 @@
-"""Pallas TPU kernels for the hot ops, with XLA fallbacks.
+"""Pallas TPU kernels for the hot ops, with XLA reference paths.
 
-``mha``/``gqa_mha`` (fused flash attention) dispatch per platform:
-hand-written Pallas kernels on TPU, interpreter mode for CPU debugging,
-plain-XLA reference paths everywhere else.  The model families' attention
-routes through these unconditionally (``models/gpt2.py``,
-``models/llama.py`` — Mixtral shares Llama's); differentiation works via a
-custom_vjp (rematerializing backward).  ``layer_norm``/``rms_norm`` are
-standalone fused-norm kernels with the same dispatch scheme — the models
-keep their plain-jnp norms so XLA can fuse them into neighbors inside the
-whole-model forward; the kernels are for task-granular/standalone use.
-Tests pin ``impl="pallas_interpret"`` vs ``impl="xla"`` to check kernel
-numerics on CPU.  Env overrides: ``DLS_TPU_ATTENTION_IMPL`` /
-``DLS_TPU_NORM_IMPL``.
+``mha``/``gqa_mha`` (fused flash attention) dispatch per platform when the
+caller leaves ``impl`` on auto: the hand-written Pallas kernel on TPU, the
+plain-XLA path elsewhere or when the shape does not qualify.  An explicit
+``impl`` is a request and raises when it cannot be honoured.  The model
+families' attention routes through these unconditionally
+(``models/gpt2.py``, ``models/llama.py`` — Mixtral shares Llama's);
+differentiation works via a custom_vjp (rematerializing backward).  Tests
+pin ``impl="pallas_interpret"`` vs ``impl="xla"`` to check kernel numerics
+on CPU.
 """
 
 from .attention import gqa_mha, mha, pallas_supported, reference_mha
-from .norms import layer_norm, rms_norm
 
 __all__ = [
     "mha",
     "gqa_mha",
     "reference_mha",
     "pallas_supported",
-    "layer_norm",
-    "rms_norm",
 ]

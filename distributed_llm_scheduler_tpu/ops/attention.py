@@ -24,9 +24,10 @@ materialized, and the same online-softmax carry runs across a slot's pages
 :func:`paged_decode_attention`'s ``impl`` switch with the same dispatch
 rules as :func:`mha` (:func:`resolve_attention_impl`).
 
-``mha`` is the public entry: it dispatches to the kernel on TPU (or
-interpreter mode for CPU tests) and to a plain-XLA reference elsewhere, so
-models can call it unconditionally.
+``mha`` is the public entry: ``impl=None``/``"auto"`` picks the kernel on
+TPU when the shape qualifies and the plain-XLA path otherwise, so models
+can call it unconditionally; an EXPLICIT ``impl`` is a request, and one
+the shape cannot honour raises (:func:`resolve_attention_impl`).
 
 The reference never executes attention (its "attention" is a DAG node with
 a cost constant, reference ``test_gpt2.py:75-90``); this file exists
@@ -42,16 +43,7 @@ from typing import Any, Optional
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-
-from ..utils.config import env_str
-
-try:  # pallas TPU backend is absent on some CPU-only builds
-    from jax.experimental.pallas import tpu as pltpu
-
-    _HAS_PLTPU = True
-except ImportError:  # pragma: no cover
-    pltpu = None
-    _HAS_PLTPU = False
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = float(jnp.finfo(jnp.float32).min)
 
@@ -185,14 +177,10 @@ def reference_mha(q, k, v, causal: bool = True, sm_scale: Optional[float] = None
 
 
 def _auto_impl() -> str:
-    forced = env_str("DLS_TPU_ATTENTION_IMPL")
-    if forced:
-        return forced
-    try:
-        platform = jax.devices()[0].platform
-    except RuntimeError:  # pragma: no cover - backend init failure
-        platform = "cpu"
-    return "pallas" if (platform == "tpu" and _HAS_PLTPU) else "xla"
+    """What ``auto`` prefers on this process's default backend: the
+    compiled kernel on a TPU, the XLA path elsewhere.  A backend that
+    fails to initialize raises here — it is never read as "cpu"."""
+    return "pallas" if jax.devices()[0].platform == "tpu" else "xla"
 
 
 def pallas_supported(q_shape, block_min: int = 8) -> bool:
@@ -206,22 +194,27 @@ def resolve_attention_impl(impl: Optional[str], supported) -> str:
     (:func:`paged_decode_attention`) entry points, so the two paths cannot
     drift on platform/eligibility behavior.
 
-    ``None`` / ``"auto"`` resolve via :func:`_auto_impl` (the
-    ``DLS_TPU_ATTENTION_IMPL`` env override, else pallas-on-TPU / xla
-    elsewhere).  A pallas impl the shape does not qualify for silently
-    downgrades to ``"xla"`` — ``supported`` is a callable taking the
-    resolved impl name (``"pallas"`` / ``"pallas_interpret"``), so callers
-    can keep compiled-mode tiling constraints out of the interpret path.
-    Anything outside the three known impls raises ``ValueError``.
+    ``None`` / ``"auto"`` let the code choose: :func:`_auto_impl`'s
+    preference (pallas on TPU, xla elsewhere), downgraded to ``"xla"``
+    when the shape does not qualify for the kernel.  An EXPLICIT impl is
+    a request: ``"pallas"`` / ``"pallas_interpret"`` on a shape the kernel
+    cannot take raises ``ValueError`` instead of running something else
+    (engines and the CLI report the resolved name, so what ran is never
+    a guess).  ``supported`` is a callable taking the impl name, so
+    callers can keep compiled-mode tiling constraints out of the
+    interpret path.  Anything outside the known impls raises too.
     """
     if impl is None or impl == "auto":
         impl = _auto_impl()
-        if impl == "auto":  # env var literally forced "auto": no loop
-            impl = "xla"
+        return impl if impl == "xla" or supported(impl) else "xla"
     if impl not in ("xla", "pallas", "pallas_interpret"):
         raise ValueError(f"unknown attention impl {impl!r}")
     if impl != "xla" and not supported(impl):
-        return "xla"
+        raise ValueError(
+            f"attention impl {impl!r} was requested explicitly but this "
+            "call's shape/dtype does not qualify for the kernel; pass "
+            "impl='auto' to let the dispatch choose"
+        )
     return impl
 
 
@@ -233,17 +226,25 @@ def paged_kernel_constraints(
     dtype: Any = jnp.float32,
     q_tokens: Optional[int] = None,
 ) -> list:
-    """Violated tiling/layout constraints for the COMPILED ragged paged
-    kernel — empty list means the geometry is kernel-eligible.
+    """Tile-alignment rules the dispatch applies before choosing the
+    COMPILED ragged paged kernel — empty list means the geometry is
+    kernel-eligible.
 
-    One source of truth for three consumers: the ``impl="auto"``/
-    ``"pallas"`` dispatch (silent gather fallback when non-empty), the
-    DEC005 analysis warning (which quotes these strings verbatim), and the
-    docs.  The constraints are the VMEM block shapes the kernel asks for:
-    each grid step loads one ``(page_size, n_kv_heads, head_dim)`` page,
-    so ``page_size`` must fill the dtype's sublane tile and ``head_dim``
-    must pack the 8-row sublane dimension of the score/accumulator tiles
-    (interpret mode has no tiling and skips this check entirely).
+    One source of truth for three consumers: the dispatch (``"auto"``
+    takes the gather path when non-empty, an explicit ``"pallas"``
+    raises), the DEC005 analysis warning (which quotes these strings
+    verbatim), and the docs.  Each grid step loads one ``(page_size,
+    n_kv_heads, head_dim)`` page; the rules keep ``page_size`` a multiple
+    of the dtype's sublane tile and ``head_dim`` a multiple of 8, i.e.
+    natively aligned tiles (interpret mode has no tiling and skips this
+    check entirely).
+
+    They are a conservative PREFERENCE, not a lowering requirement: on
+    the v5e (libtpu 0.0.34) the kernels compile and match the gather path
+    at geometries these rules reject — f32 page 4, bf16 page 8, head_dim
+    12, a 7-row query chunk (``chip_smoke.py`` kernels probe, PR 21;
+    PERF.md).  Whether misaligned tiles are slower is not measured;
+    ROADMAP S4/D3 settle the rule with the kernels' roofline shares.
     """
     sublane = {2: 16, 1: 32}.get(jnp.dtype(dtype).itemsize, 8)
     out = []
@@ -277,7 +278,8 @@ def paged_kernel_constraints(
 
 
 def paged_pallas_supported(
-    q_shape, pool_shape, interpret: bool = False
+    q_shape, pool_shape, interpret: bool = False,
+    dtype: Any = jnp.float32,
 ) -> bool:
     """Eligibility of the ragged paged kernel for this call.
 
@@ -285,20 +287,35 @@ def paged_pallas_supported(
     of KV heads, matching head_dim, at least one query token (Tn == 1 is
     the decode step; Tn > 1 is a ragged prefill chunk with per-slot
     ``q_lens``).  Compiled mode additionally requires the
-    :func:`paged_kernel_constraints` tiling rules; interpret mode (CPU
-    parity tests) has no tiling constraints.
+    :func:`paged_kernel_constraints` tiling rules at the POOL's ``dtype``
+    (the sublane tile depends on it); interpret mode (CPU parity tests)
+    has no tiling constraints.
     """
     S, Hq, Tn, hd = q_shape
     n_pages, page_size, Hkv, pool_hd = pool_shape
     if Tn < 1 or Hkv < 1 or Hq % Hkv or hd != pool_hd:
         return False
-    if not _HAS_PLTPU:  # PrefetchScalarGridSpec lives in pltpu
-        return False
     if interpret:
         return True
     return not paged_kernel_constraints(
-        page_size, hd, Hkv, n_q_heads=Hq,
+        page_size, hd, Hkv, n_q_heads=Hq, dtype=dtype,
         q_tokens=Tn if Tn > 1 else None,
+    )
+
+
+def resolve_paged_impl(
+    impl: Optional[str], q_shape, pool_shape, dtype: Any
+) -> str:
+    """The impl :func:`paged_decode_attention` runs for this geometry —
+    the same rule the op applies at trace time, callable from the host so
+    engines and reports can NAME what ran (``"auto"`` is a request, never
+    an answer)."""
+    return resolve_attention_impl(
+        impl,
+        lambda i: paged_pallas_supported(
+            q_shape, pool_shape, interpret=(i == "pallas_interpret"),
+            dtype=dtype,
+        ),
     )
 
 
@@ -314,7 +331,8 @@ def mha(
 
     impl: "pallas" (TPU kernel), "pallas_interpret" (CPU-debuggable kernel),
     "xla" (reference einsum path), or None/"auto" = auto (pallas on TPU
-    when the shape qualifies, xla otherwise).
+    when the shape qualifies, xla otherwise).  An explicit kernel impl on
+    a shape :func:`pallas_supported` rejects raises.
     """
     impl = resolve_attention_impl(
         impl, lambda _i: pallas_supported(q.shape)
@@ -687,7 +705,8 @@ def paged_decode_attention(
     the same kernel through the Pallas interpreter (CPU parity tests),
     and ``None``/``"auto"`` picks the kernel on TPU when the geometry
     passes :func:`paged_kernel_constraints`, the gather path otherwise
-    (the silent-fallback seam DEC005 warns about).  Kernel outputs are
+    (the choice DEC005 warns about; :func:`resolve_paged_impl` names it).
+    An explicit kernel impl on an ineligible geometry raises.  Kernel outputs are
     allclose — not bitwise — to the gather path (page-blocked online
     softmax associates its reductions differently), which keeps greedy
     argmax tokens identical at engine scale (pinned by the parity gate).
@@ -712,12 +731,7 @@ def paged_decode_attention(
                 "into the pools first (write-then-attend at chunk "
                 "granularity)"
             )
-        impl = resolve_attention_impl(
-            impl,
-            lambda i: paged_pallas_supported(
-                q.shape, k_pool.shape, interpret=(i == "pallas_interpret")
-            ),
-        )
+        impl = resolve_paged_impl(impl, q.shape, k_pool.shape, k_pool.dtype)
         scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(hd)
         if impl in ("pallas", "pallas_interpret"):
             return _paged_flash_ragged(
@@ -728,12 +742,7 @@ def paged_decode_attention(
         return _gather_chunk_attention(
             q, k_pool, v_pool, page_table, lengths, q_lens, scale
         )
-    impl = resolve_attention_impl(
-        impl,
-        lambda i: paged_pallas_supported(
-            q.shape, k_pool.shape, interpret=(i == "pallas_interpret")
-        ),
-    )
+    impl = resolve_paged_impl(impl, q.shape, k_pool.shape, k_pool.dtype)
     scale = sm_scale if sm_scale is not None else 1.0 / math.sqrt(hd)
     if impl in ("pallas", "pallas_interpret"):
         return _paged_flash(

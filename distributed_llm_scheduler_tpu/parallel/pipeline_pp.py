@@ -40,11 +40,10 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..models import gpt2, llama, mixtral
-from .compat import shard_map
 
 
 def _family_bits(config: Any):

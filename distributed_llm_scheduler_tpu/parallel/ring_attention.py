@@ -22,9 +22,10 @@ import math
 from functools import partial
 import jax
 import jax.numpy as jnp
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .compat import axis_size, shard_map
 
 
 def _block_scores(q, k, q_blk, kv_blk, blk_len):

@@ -26,10 +26,11 @@ from __future__ import annotations
 from functools import partial
 
 import jax
+from jax import shard_map
+from jax.lax import axis_size
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..ops.attention import mha as _fused_mha
-from .compat import axis_size, shard_map
 
 
 def _seq_to_heads(x: jax.Array, axis_name: str) -> jax.Array:

@@ -116,7 +116,7 @@ class BaseScheduler:
     # at all, which concentrates work catastrophically at scale — greedy
     # placed a 5,169-task Llama graph 11x worse than round-robin because
     # the node holding a layer's weights wins every microbatch of that
-    # layer forever (ICI_r04.json; VERDICT r4 next #3).  2.0 keeps all
+    # layer forever (ICI_r04.json).  2.0 keeps all
     # four banded policies within 1.7x of round-robin on that probe while
     # preserving 1.6-3x the cache hits; float('inf') recovers the
     # reference's unbanded behavior.  A node already holding EVERY param

@@ -372,7 +372,7 @@ class PipelineStageScheduler(BaseScheduler):
         # charges — and the best kept (ties prefer contiguous v=1, which
         # also minimizes cross-slice crossings).  Deep interleave cut the
         # 5k-task Llama probe's pipeline makespan from 2.7x to 1.8x of
-        # round-robin (ICI_r05; VERDICT r4 next #3).  An explicit
+        # round-robin (ICI_r05.json).  An explicit
         # ``n_stages`` skips the sweep (one stage per device, as before).
         vmax = (
             1 if self.n_stages

@@ -96,7 +96,7 @@ class GreedyScheduler(BaseScheduler):
     (``BaseScheduler.load_band``) bounds concentration.  Pure param-overlap
     scoring sends every microbatch of a layer to the node that cached the
     layer's weights first, forever — 11x worse than round-robin on the
-    5k-task Llama probe (ICI_r04.json; VERDICT r4 next #3).
+    5k-task Llama probe (ICI_r04.json).
     """
 
     name = "greedy"
@@ -235,7 +235,7 @@ class MRUScheduler(BaseScheduler):
             # candidates = nodes that fit (possibly after eviction); the
             # load band applies on top — the overlap bonus otherwise
             # concentrates shared-param work just like greedy (8x
-            # round-robin on the 5k-task Llama probe, VERDICT r4 next #3)
+            # round-robin on the 5k-task Llama probe, ICI_r04.json)
             candidates = [
                 (node, plan) for node in run.cluster
                 if (plan := eviction_plan(run, task, node, ready_ids))

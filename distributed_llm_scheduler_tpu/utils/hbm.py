@@ -2,7 +2,7 @@
 
 Scheduler ``can_fit`` decisions were bookkeeping-only in round 1: a task's
 ``memory_required`` came from analytic activation-size estimates, while XLA
-allocates temps invisibly (SURVEY.md §7 hard-part #3, VERDICT r1 #4) — so
+allocates temps invisibly (SURVEY.md §7 hard-part #3) — so
 "fits in 14 GB" was never verified against what the compiler actually
 reserves.  :func:`preflight_task_memory` AOT-compiles each unique
 (fn, input-shapes) combination, reads ``compiled.memory_analysis()`` —

@@ -1,6 +1,6 @@
 """Static-analysis subsystem (analysis/): one failing fixture per pass,
 gate behavior on the backends, and a lint smoke test over every frontend
-DAG builder x the default scheduler (docs/ANALYSIS.md taxonomy)."""
+DAG builder x the default scheduler (docs/ANALYSIS.md catalogue)."""
 
 from __future__ import annotations
 
@@ -847,7 +847,7 @@ def test_collective_walk_sees_through_custom_derivatives():
     from distributed_llm_scheduler_tpu.analysis import (
         analyze_collectives_jaxpr,
     )
-    from distributed_llm_scheduler_tpu.parallel.compat import shard_map
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.array(jax.devices()[:2]), ("x",))

@@ -1,78 +1,29 @@
-"""Bench failure-path tests (VERDICT r1 next #7).
+"""Bench decision-logic tests.
 
-Round 1's number was decided by untested fallback logic (probe timeout ->
-CPU regime).  These tests pin every decision-shaped piece of the bench:
-probe retry/backoff, the 4-step cost-model provenance chain, TPU-time
-derivation, metric naming, link-regime choice, and the JSON payload
-(oracle_ok/fallback flags included per ADVICE r1).
+Everything decision-shaped in the bench lives in ``eval/benchlib`` as pure
+functions; these tests pin it: peaks keyed by ``device_kind`` (unknown
+accelerator kind raises, the host platform has none), the link provenance
+string, best-policy picking, the JSON payload (device fields and oracle_ok
+included) and the parity oracle.
 """
 
 import json
-import os
+import types
 
 import pytest
 
 from distributed_llm_scheduler_tpu.eval.benchlib import (
+    DEVICE_PEAKS,
     BenchResult,
-    choose_cost_model,
     choose_link,
     compute_mfu,
-    derive_tpu_costmodel,
+    device_peaks,
     pick_best,
-    probe_backend,
     task_class,
 )
-from distributed_llm_scheduler_tpu.utils.costmodel import CostModel
 
-
-# -- probe -------------------------------------------------------------------
-
-
-def test_probe_succeeds_first_try():
-    calls = []
-
-    def fake_run(cmd, timeout):
-        calls.append(timeout)
-
-    assert probe_backend(run=fake_run, sleep=lambda s: None, log=lambda m: None)
-    assert len(calls) == 1
-
-
-def test_probe_retries_with_backoff_then_fails():
-    calls, sleeps = [], []
-
-    def fake_run(cmd, timeout):
-        calls.append(timeout)
-        raise TimeoutError("tunnel hung")
-
-    ok = probe_backend(
-        timeout_s=7,
-        attempts=3,
-        backoff_s=11,
-        run=fake_run,
-        sleep=sleeps.append,
-        log=lambda m: None,
-    )
-    assert not ok
-    assert calls == [7, 7, 7]
-    assert sleeps == [11, 11]  # no sleep after the last attempt
-
-
-def test_probe_recovers_on_second_attempt():
-    state = {"n": 0}
-
-    def flaky_run(cmd, timeout):
-        state["n"] += 1
-        if state["n"] == 1:
-            raise TimeoutError
-
-    assert probe_backend(
-        run=flaky_run, sleep=lambda s: None, log=lambda m: None
-    )
-    assert state["n"] == 2
-
-
-# -- task classes + derivation ----------------------------------------------
+V5E = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
+HOST = types.SimpleNamespace(platform="cpu", device_kind="cpu")
 
 
 def test_task_class_strips_mb_layer_shard():
@@ -83,156 +34,41 @@ def test_task_class_strips_mb_layer_shard():
     assert task_class("output_concat") == "output_concat"
 
 
-def test_derive_tpu_costmodel_uses_class_ratios():
-    base_cpu = CostModel("base", "cpu", {
-        "mb0_layer_0_attention": 1.0,
-        "mb1_layer_0_attention": 1.0,
-        "mb0_embedding": 0.5,
-    })
-    base_tpu = CostModel("base", "tpu", {
-        "mb0_layer_0_attention": 0.01,   # attention ratio 1/100
-        "mb1_layer_0_attention": 0.01,
-        "mb0_embedding": 0.025,          # embedding ratio 1/20
-    })
-    target_cpu = CostModel("target", "cpu", {
-        "mb0_layer_5_attention": 2.0,    # class match -> /100
-        "mb0_embedding_shard_3": 0.2,    # shard -> embedding class -> /20
-        "mb0_novel_op": 1.0,             # no class -> global median
-    })
-    derived = derive_tpu_costmodel(target_cpu, base_cpu, base_tpu)
-    assert derived.platform == "tpu_derived"
-    assert derived.task_seconds["mb0_layer_5_attention"] == pytest.approx(0.02)
-    assert derived.task_seconds["mb0_embedding_shard_3"] == pytest.approx(0.01)
-    # global median of [0.01, 0.01, 0.05] = 0.01
-    assert derived.task_seconds["mb0_novel_op"] == pytest.approx(0.01)
+# -- peaks -------------------------------------------------------------------
 
 
-def test_derive_rejects_disjoint_bases():
-    with pytest.raises(ValueError):
-        derive_tpu_costmodel(
-            CostModel("t", "cpu", {"a": 1.0}),
-            CostModel("b", "cpu", {"x": 1.0}),
-            CostModel("b", "tpu", {"y": 1.0}),
-        )
+def test_device_peaks_keyed_by_device_kind():
+    assert device_peaks(V5E) is DEVICE_PEAKS["TPU v5 lite"]
+    # the host platform has no peak by design
+    assert device_peaks(HOST) is None
+    # an accelerator kind that is not in the table is an error — it never
+    # borrows another chip's numbers through its platform name
+    other = types.SimpleNamespace(platform="tpu", device_kind="TPU v4")
+    with pytest.raises(KeyError, match="no published peaks"):
+        device_peaks(other)
 
 
-# -- cost-model provenance chain --------------------------------------------
+def test_compute_mfu_against_the_kinds_bf16_peak():
+    assert compute_mfu(197e12, 1.0, V5E) == pytest.approx(1.0)
+    assert compute_mfu(1e12, 1.0, HOST) is None
+    assert compute_mfu(0.0, 1.0, V5E) is None
 
 
-class _FakeDevice:
-    def __init__(self, platform):
-        self.platform = platform
+# -- link --------------------------------------------------------------------
 
 
-def _graph(name, tids):
-    from distributed_llm_scheduler_tpu import Task, TaskGraph
+def test_choose_link_measures_this_machine_and_names_it(tmp_path):
+    import jax
 
-    return TaskGraph([Task(t, 0.1, 1.0, []) for t in tids], name=name).freeze()
+    link, prov = choose_link(cache_dir=str(tmp_path))
+    kind = jax.devices()[0].device_kind
+    assert prov.startswith(kind + ":")
+    assert "param_load=measured" in prov
+    assert link.param_load_gbps > 0
+    # the cache it wrote is keyed by device_kind, not by platform
+    from distributed_llm_scheduler_tpu.utils.costmodel import kind_slug
 
-
-def test_choose_cost_model_prefers_cached_tpu(tmp_path, monkeypatch):
-    g = _graph("flagship", ["a", "b"])
-    cached = CostModel(
-        "flagship", "tpu", {"a": 0.001, "b": 0.002}, method="amortized"
-    )
-    cached.save(str(tmp_path / "flagship_tpu.json"))
-    cm, suffix = choose_cost_model(
-        g, {}, None, _FakeDevice("cpu"), cache_dir=str(tmp_path),
-        log=lambda m: None,
-    )
-    assert suffix == "_tpu_cached"
-    assert cm.task_seconds == cached.task_seconds
-
-
-def test_choose_cost_model_stale_cache_falls_through(tmp_path, monkeypatch):
-    """A cached TPU calibration whose task set mismatches must NOT be used
-    (the round-1 failure mode was silently wrong regimes)."""
-    g = _graph("flagship", ["a", "b"])
-    CostModel("flagship", "tpu", {"a": 0.001}).save(
-        str(tmp_path / "flagship_tpu.json")
-    )
-
-    def fake_calibrate_cached(graph, params, inp, cache_dir, device,
-                              refresh=False):
-        return CostModel(graph.name, device.platform, {"a": 1.0, "b": 1.0})
-
-    monkeypatch.setattr(
-        "distributed_llm_scheduler_tpu.utils.costmodel.calibrate_cached",
-        fake_calibrate_cached,
-    )
-    cm, suffix = choose_cost_model(
-        g, {}, None, _FakeDevice("cpu"), cache_dir=str(tmp_path),
-        log=lambda m: None,
-    )
-    assert suffix == "_cpu"
-    assert cm.platform == "cpu"
-
-
-def test_choose_cost_model_derives_from_base_pair(tmp_path, monkeypatch):
-    g = _graph("flagship", ["mb0_layer_0_attention"])
-    CostModel("base", "cpu", {"mb0_layer_0_attention": 1.0}).save(
-        str(tmp_path / "base_cpu.json")
-    )
-    CostModel("base", "tpu", {"mb0_layer_0_attention": 0.01}).save(
-        str(tmp_path / "base_tpu.json")
-    )
-
-    def fake_calibrate_cached(graph, params, inp, cache_dir, device,
-                              refresh=False):
-        return CostModel(
-            graph.name, device.platform, {"mb0_layer_0_attention": 2.0}
-        )
-
-    monkeypatch.setattr(
-        "distributed_llm_scheduler_tpu.utils.costmodel.calibrate_cached",
-        fake_calibrate_cached,
-    )
-    cm, suffix = choose_cost_model(
-        g, {}, None, _FakeDevice("cpu"), cache_dir=str(tmp_path),
-        base_graph_name="base", log=lambda m: None,
-    )
-    assert suffix == "_tpu_derived"
-    assert cm.task_seconds["mb0_layer_0_attention"] == pytest.approx(0.02)
-
-
-def test_choose_cost_model_cpu_last_resort(tmp_path, monkeypatch):
-    g = _graph("flagship", ["a"])
-
-    def fake_calibrate_cached(graph, params, inp, cache_dir, device,
-                              refresh=False):
-        return CostModel(graph.name, device.platform, {"a": 1.0})
-
-    monkeypatch.setattr(
-        "distributed_llm_scheduler_tpu.utils.costmodel.calibrate_cached",
-        fake_calibrate_cached,
-    )
-    cm, suffix = choose_cost_model(
-        g, {}, None, _FakeDevice("cpu"), cache_dir=str(tmp_path),
-        log=lambda m: None,
-    )
-    assert suffix == "_cpu"
-
-
-# -- link regime -------------------------------------------------------------
-
-
-def test_choose_link_tpu_regime_uses_cached_tpu_calibration(tmp_path):
-    from distributed_llm_scheduler_tpu.utils.linkmodel import LinkCalibration
-
-    cal = LinkCalibration(platform="tpu")
-    cal.param_load_gbps = 17.0
-    cal.provenance["param_load"] = "measured"
-    cal.save(str(tmp_path / "link_tpu.json"))
-    for suffix in ("", "_tpu_cached", "_tpu_derived"):
-        link, prov = choose_link(suffix, cache_dir=str(tmp_path))
-        assert link.param_load_gbps == 17.0
-        assert prov.startswith("tpu:")
-
-
-def test_choose_link_tpu_regime_estimates_when_unmeasured(tmp_path):
-    link, prov = choose_link("", cache_dir=str(tmp_path))
-    assert prov == "tpu:estimated(v5e)"
-    assert link.interconnect_gbps == 100.0
+    assert (tmp_path / f"link_{kind_slug(jax.devices()[0])}.json").exists()
 
 
 # -- result shaping ----------------------------------------------------------
@@ -253,76 +89,28 @@ def test_pick_best_all_incomplete_returns_baseline():
     assert pick_best(ms) == ("roundrobin", 10.0, 10.0)
 
 
-def test_compute_mfu_only_for_known_peaks():
-    assert compute_mfu(197e12, 1.0, "tpu", "bfloat16") == pytest.approx(1.0)
-    assert compute_mfu(1e12, 1.0, "cpu", "float32") is None
-    assert compute_mfu(0.0, 1.0, "tpu", "bfloat16") is None
-
-
-def test_bench_result_payload_flags_degraded_runs():
+def test_bench_result_payload_names_device_and_oracle():
     r = BenchResult(
         n_policies=7,
-        platform_suffix="_tpu_derived",
         best_policy="pipeline",
         best_makespan_s=0.010,
         baseline_makespan_s=0.025,
+        platform="tpu",
+        device_kind="TPU v5 lite",
+        n_devices=1,
         oracle_ok=False,
-        fallback=True,
-        link_provenance="tpu:estimated(v5e)",
+        link_provenance="TPU v5 lite:interconnect=estimated",
     )
     payload = r.to_json()
-    assert payload["metric"] == (
-        "gpt2s_fwd_dag_makespan_best_of_7_policies_tpu_derived"
-    )
+    assert payload["metric"] == "gpt2s_fwd_dag_makespan_best_of_7_policies"
     assert payload["vs_baseline"] == pytest.approx(2.5)
     assert payload["oracle_ok"] is False
-    assert payload["fallback"] is True
+    assert (payload["platform"], payload["device_kind"],
+            payload["n_devices"]) == ("tpu", "TPU v5 lite", 1)
+    # no provenance suffix and no fallback flag: there is one source
+    assert "fallback" not in payload
     assert payload["best_policy"] == "pipeline"
     json.dumps(payload)  # must be serializable as-is
-
-
-def test_bench_result_tpu_measured_metric_has_no_suffix():
-    r = BenchResult(
-        n_policies=7,
-        platform_suffix="",
-        best_policy="pipeline",
-        best_makespan_s=0.010,
-        baseline_makespan_s=0.015,
-    )
-    assert r.metric == "gpt2s_fwd_dag_makespan_best_of_7_policies"
-    assert r.to_json()["fallback"] is False
-
-
-def test_choose_cost_model_rejects_pre_method_cache(tmp_path, monkeypatch):
-    """Caches written before the method field must not be reused: their
-    per-task semantics (and missing dispatch_s) would silently mix with
-    current calibrations."""
-    import json
-
-    g = _graph("flagship", ["a", "b"])
-    path = tmp_path / "flagship_tpu.json"
-    legacy = {
-        "graph_name": "flagship", "platform": "tpu",
-        "task_seconds": {"a": 0.001, "b": 0.002},
-    }  # no "method" key
-    path.write_text(json.dumps(legacy))
-
-    def fake_calibrate_cached(graph, params, inp, cache_dir, device,
-                              refresh=False):
-        return CostModel(
-            graph.name, device.platform, {"a": 1.0, "b": 1.0},
-            method="profile",
-        )
-
-    monkeypatch.setattr(
-        "distributed_llm_scheduler_tpu.utils.costmodel.calibrate_cached",
-        fake_calibrate_cached,
-    )
-    cm, suffix = choose_cost_model(
-        g, {}, None, _FakeDevice("cpu"), cache_dir=str(tmp_path),
-        log=lambda m: None,
-    )
-    assert suffix == "_cpu"  # fell through to live calibration
 
 
 # -- ICI sensitivity ---------------------------------------------------------
@@ -428,24 +216,3 @@ def test_oracle_close_bf16_rejects_systematic_error():
     assert not oracle_close(a, a.reshape(-1, 1), "bfloat16")  # shape
 
 
-def test_measured_snapshot_roundtrip(tmp_path, monkeypatch):
-    """Fresh-TPU bench lines persist and come back stamped with age; a
-    corrupt snapshot degrades to None instead of raising."""
-    from distributed_llm_scheduler_tpu.eval.benchlib import (
-        load_measured_snapshot,
-        save_measured_snapshot,
-    )
-
-    monkeypatch.chdir(tmp_path)
-    assert load_measured_snapshot("gpt2s") is None
-    line = {"metric": "m", "value": 12.3, "mfu_segmented": 0.49}
-    save_measured_snapshot(line, "gpt2s")
-    snap = load_measured_snapshot("gpt2s")
-    assert snap["result"] == line
-    assert snap["age_days"] >= 0
-    assert "T" in snap["measured_at"]
-    # model tags are independent namespaces
-    assert load_measured_snapshot("gpt2m") is None
-    # corruption degrades gracefully
-    (tmp_path / ".costmodel" / "measured_gpt2s.json").write_text("{nope")
-    assert load_measured_snapshot("gpt2s") is None

@@ -1,9 +1,11 @@
 """Wiring tests for eval/capture_artifacts: the one-shot artifact pass
 must place correctly-named files at the repo root, stamp platform/round,
-and degrade a failing leg to an error stub without losing the pass."""
+and FAIL when a leg fails — no error stub is written that could later be
+read as a record."""
 
 import json
-import os
+
+import pytest
 
 from distributed_llm_scheduler_tpu.eval import capture_artifacts as ca
 
@@ -23,82 +25,16 @@ def test_capture_writes_stamped_artifacts(tmp_path, monkeypatch):
     assert data["capture_wall_s"] >= 0
 
 
-def test_capture_failing_leg_degrades_to_stub(tmp_path, monkeypatch):
+def test_capture_failing_leg_fails_the_pass(tmp_path, monkeypatch):
     def boom():
-        raise RuntimeError("tunnel died")
+        raise RuntimeError("leg died")
 
     monkeypatch.setattr(ca, "REPO_ROOT", str(tmp_path))
     monkeypatch.setitem(ca.LEGS, "decode", ("DECODE", boom))
-    monkeypatch.setitem(
-        ca.LEGS, "stream", ("STREAM", lambda: {"slowdown": 1.0})
-    )
-    rc = ca.main(["4", "decode", "stream"])
-    assert rc == 1  # failure surfaced in the exit code...
-    stub = json.loads((tmp_path / "DECODE_r04.json").read_text())
-    assert "tunnel died" in stub["error"]
-    # ...but the healthy leg still captured
-    ok = json.loads((tmp_path / "STREAM_r04.json").read_text())
-    assert ok["slowdown"] == 1.0
-
-
-def test_capture_hanging_leg_times_out_to_stub(tmp_path, monkeypatch):
-    """A leg that HANGS (a tunnel wedge: blocking RPC that never returns)
-    must degrade to an error stub like an exception does, and the later
-    legs must still capture — SIGALRM per leg, DLS_CAPTURE_LEG_TIMEOUT."""
-    import time as _time
-
-    def wedge():
-        _time.sleep(30)
-        return {"never": 1}
-
-    monkeypatch.setattr(ca, "REPO_ROOT", str(tmp_path))
-    monkeypatch.setenv("DLS_CAPTURE_LEG_TIMEOUT", "1")
-    monkeypatch.setitem(ca.LEGS, "decode", ("DECODE", wedge))
-    monkeypatch.setitem(
-        ca.LEGS, "stream", ("STREAM", lambda: {"slowdown": 1.0})
-    )
-    t0 = _time.time()
-    rc = ca.main(["4", "decode", "stream"])
-    assert _time.time() - t0 < 10  # the wedge was cut short
-    assert rc == 1
-    stub = json.loads((tmp_path / "DECODE_r04.json").read_text())
-    assert "exceeded" in stub["error"]
-    ok = json.loads((tmp_path / "STREAM_r04.json").read_text())
-    assert ok["slowdown"] == 1.0
-
-
-def test_nested_leg_timeout_rearms_outer_timer(tmp_path, monkeypatch):
-    """A sub-leg's alarm cleanup must re-arm the enclosing leg's timer
-    (signal.alarm is process-global): after an inner _guarded call, an
-    outer hang must still time out."""
-    import time as _time
-
-    monkeypatch.setenv("DLS_CAPTURE_LEG_TIMEOUT", "2")
-
-    def outer():
-        inner = ca._guarded("inner", lambda: {"ok": 1})
-        assert "error" not in inner
-        _time.sleep(30)  # outer wedge AFTER the inner leg finished
-        return {"never": 1}
-
-    t0 = _time.time()
-    out = ca._guarded("outer", outer)
-    assert _time.time() - t0 < 10
-    assert "exceeded" in out["error"]
-
-
-def test_capture_nested_suberror_surfaces_in_exit_code(tmp_path, monkeypatch):
-    """A sub-leg failure buried inside a composite artifact (e.g. the
-    decode artifact's attribution section) must still fail the pass."""
-    monkeypatch.setattr(ca, "REPO_ROOT", str(tmp_path))
-    monkeypatch.setitem(
-        ca.LEGS, "decode",
-        ("DECODE", lambda: {"decode_tok_s": 1.0,
-                            "attribution": {"error": "tunnel died"}}),
-    )
-    assert ca.main(["4", "decode"]) == 1
-    data = json.loads((tmp_path / "DECODE_r04.json").read_text())
-    assert data["decode_tok_s"] == 1.0  # healthy parts still recorded
+    with pytest.raises(RuntimeError, match="leg died"):
+        ca.main(["4", "decode"])
+    # nothing on disk stands in for the measurement that did not happen
+    assert list(tmp_path.iterdir()) == []
 
 
 def test_capture_rejects_bad_args(tmp_path, monkeypatch):

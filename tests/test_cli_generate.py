@@ -13,7 +13,7 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 def _run(*argv, timeout=300):
     env = dict(
         os.environ,
-        DLS_PLATFORM="cpu",
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
     )
     return subprocess.run(
@@ -50,7 +50,7 @@ def test_execute_rejects_weights_for_synthetic_model():
     """The execute-side fail-fast gate for families without an HF map."""
     env = dict(
         os.environ,
-        DLS_PLATFORM="cpu",
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
     )
     r = subprocess.run(
@@ -107,7 +107,7 @@ def test_execute_inject_failure_recovers():
     """CLI fault injection: kill a node mid-run, recover on survivors."""
     env = dict(
         os.environ,
-        DLS_PLATFORM="cpu",
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
     )
     r = subprocess.run(
@@ -128,7 +128,7 @@ def test_execute_inject_failure_recovers():
 def test_execute_inject_failure_rejects_unknown_node():
     env = dict(
         os.environ,
-        DLS_PLATFORM="cpu",
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
     )
     r = subprocess.run(
@@ -148,7 +148,7 @@ def test_execute_inject_failure_full_completion_edge():
     output when the final task survived."""
     env = dict(
         os.environ,
-        DLS_PLATFORM="cpu",
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
     )
     r = subprocess.run(

@@ -27,7 +27,7 @@ def _donor_file(tmp_path, n_embd=128):
 def _run_execute(weights_path):
     env = dict(
         os.environ,
-        DLS_PLATFORM="cpu",
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
     )
     return subprocess.run(

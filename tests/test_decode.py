@@ -256,27 +256,37 @@ def test_decode_bench_quantized_leg():
 
 def test_decode_roofline_math():
     """Roofline bound: pure arithmetic on param + KV-cache bytes over the
-    assumed HBM bandwidth; None on platforms without a published peak."""
+    device kind's published HBM bandwidth; None on the host platform, an
+    error for an accelerator kind with no published peak."""
+    import types
+
+    import jax
     import jax.numpy as jnp
 
+    from distributed_llm_scheduler_tpu.eval.benchlib import DEVICE_PEAKS
     from distributed_llm_scheduler_tpu.eval.decode_bench import (
-        PEAK_HBM_GBPS,
         decode_roofline,
     )
     from distributed_llm_scheduler_tpu.models.gpt2 import GPT2Config
 
+    v5e = types.SimpleNamespace(platform="tpu", device_kind="TPU v5 lite")
     cfg = GPT2Config.tiny(dtype=jnp.bfloat16)
-    roof = decode_roofline(cfg, batch=4, cache_len=32, platform="tpu")
+    roof = decode_roofline(cfg, batch=4, cache_len=32, device=v5e)
     assert roof is not None
     # bytes decompose exactly: params + cache read + cache write
     kv_read = 2 * cfg.n_layer * 4 * cfg.n_head * 32 * cfg.head_dim * 2
     assert roof["kv_cache_bytes"] == float(kv_read)
     assert roof["bytes_per_step"] > roof["param_bytes"] + kv_read - 1
-    expect_s = roof["bytes_per_step"] / (PEAK_HBM_GBPS["tpu"] * 1e9)
+    expect_s = roof["bytes_per_step"] / DEVICE_PEAKS["TPU v5 lite"][
+        "hbm_bytes_s"]
     assert roof["step_bound_ms"] == pytest.approx(expect_s * 1e3)
     assert roof["bound_tok_s"] == pytest.approx(4 / expect_s)
-    # no published bandwidth -> no bound, not a fabricated one
-    assert decode_roofline(cfg, 4, 32, "cpu") is None
+    # the host platform has no bound, not a fabricated one ...
+    assert decode_roofline(cfg, 4, 32, jax.devices("cpu")[0]) is None
+    # ... and an unknown accelerator kind is an error, never a default
+    other = types.SimpleNamespace(platform="tpu", device_kind="TPU v99")
+    with pytest.raises(KeyError, match="no published peaks"):
+        decode_roofline(cfg, 4, 32, other)
 
 
 def test_decode_bench_sharded_helper_runs():
@@ -297,7 +307,7 @@ def test_decode_bench_sharded_helper_runs():
 
 
 def test_decode_attribution_functional():
-    """Per-component decode attribution (VERDICT r3 next #6): every
+    """Per-component decode attribution: every
     component reports a positive time, derived fields are consistent, and
     byte counts are exact.  CPU = structural check; TPU gives the real
     numbers."""

@@ -1,12 +1,12 @@
 """Decode step as a task DAG (frontend/decode_dag.py): the scheduling
-layer sees an inference workload (VERDICT r2 missing #4).
+layer sees an inference workload.
 
 Pins: prefill-step DAG logits == models/decode cached forward; decode-step
 DAG at pos>0 stays exact over a multi-step loop with functional cache
 updates; cache slabs are real placeable params the scheduler accounts;
 multi-device placed execution matches; and position is RUNTIME data —
 one decode graph serves every step, so an N-token generation compiles
-O(1) programs (VERDICT r3 next #7).
+O(1) programs.
 """
 
 import jax
@@ -106,8 +106,8 @@ def test_long_generation_compiles_constant_graphs():
     """32+ new tokens: position is runtime data, so after the first decode
     step NO new jitted callables appear — the whole generation runs on
     two compiled programs' worth of task fns (prefill + decode classes).
-    VERDICT r3 next #7 asked for <= 4 graphs over >= 32 tokens; the
-    traced-position design gives exactly 2."""
+    The bar was <= 4 graphs over >= 32 tokens; the traced-position
+    design gives exactly 2."""
     ids = _prompt()
     model_params = gpt2.init_params(CFG, jax.random.PRNGKey(0))
     n_new = 32

@@ -143,7 +143,7 @@ def test_ep_remat_train_step_on_mesh(tiny):
     assert abs(float(loss_p) - float(loss_r)) < 1e-5
 
 
-# -- routed dispatch under EP (VERDICT r3 next #4) ---------------------------
+# -- routed dispatch under EP ---------------------------
 
 def _full_capacity(cfg):
     """Capacity factor at which nothing can drop (C == N)."""

@@ -257,25 +257,19 @@ def test_vocab_shards_validation():
         build_gpt2_dag(GPT2Config.tiny(), batch=2, seq_len=16, vocab_shards=0)
 
 
-def test_costmodel_groups_structurally_identical_tasks(tiny_dag, monkeypatch):
-    """Fence-amortized calibration measures one representative per
-    (fn, shapes) group: every layer's attention gets the SAME measured
-    time, and distinct op classes get positive, distinct entries.
-    (Forced onto the amortized path — on the healthy-fence CPU platform
-    calibrate would pick the serial profile method instead.)"""
+def test_costmodel_calibrate_times_every_task(tiny_dag):
+    """``calibrate`` has one method — serial per-task wall times ending
+    in ``block_until_ready``: every task gets a positive entry and the
+    model records how it was measured."""
     from distributed_llm_scheduler_tpu.utils import costmodel
 
-    monkeypatch.setattr(costmodel, "blocking_reliable", lambda d: False)
     cm = costmodel.calibrate(
         tiny_dag.graph, tiny_dag.init_params(), tiny_dag.make_inputs(),
-        repeats=1, reps_per_group=4,
+        repeats=1,
     )
     assert set(cm.task_seconds) == set(tiny_dag.graph.task_ids())
     assert all(t > 0 for t in cm.task_seconds.values())
-    attn = {
-        tid: s for tid, s in cm.task_seconds.items() if "attention" in tid
-    }
-    assert len(attn) >= 2 and len(set(attn.values())) == 1
+    assert cm.method == "profile" and cm.dispatch_s == 0.0
 
 
 def test_readback_fence_forces_completion():

@@ -1,4 +1,4 @@
-"""Pre-flight XLA memory analysis tests (VERDICT r1 #4)."""
+"""Pre-flight XLA memory analysis tests."""
 
 import jax.numpy as jnp
 import pytest

@@ -1,5 +1,5 @@
-"""Interconnect-sensitivity probe in the multi-device-bound regime
-(VERDICT r3 next #8): the sweep must RE-SCHEDULE per scale, band ties
+"""Interconnect-sensitivity probe in the multi-device-bound regime: the
+sweep must RE-SCHEDULE per scale, band ties
 out of winner flips, and report both best- and any-policy movement."""
 
 from distributed_llm_scheduler_tpu.eval.ici_probe import (

@@ -355,21 +355,22 @@ def test_paged_attention_bitwise_dense_parity(lengths):
 
 
 def test_paged_attention_impl_dispatch():
-    """The seam is real now: an explicit ``pallas`` on an ineligible
-    geometry silently downgrades to the gather path (bitwise-equal
-    output — DEC005 is the observability for it), and an unknown impl
-    is a hard error."""
+    """An explicit ``pallas`` on a geometry the dispatch rules reject
+    raises — it is never quietly served by the gather path; ``auto``
+    may choose the gather path (DEC005 is the observability for it), and
+    an unknown impl is a hard error."""
     from distributed_llm_scheduler_tpu.ops.attention import (
         paged_decode_attention,
     )
 
-    # page_size 4 / head_dim 4 violate the lowering tile constraints,
-    # so impl="pallas" must fall back to the gather path
+    # page_size 4 / head_dim 4 violate the dispatch's tile rules
     z = jnp.ones((1, 2, 1, 4), jnp.float32)
     pool = jnp.zeros((2, 4, 2, 4), jnp.float32)
     pt = jnp.zeros((1, 2), jnp.int32)
     L = jnp.zeros((1,), jnp.int32)
-    got = paged_decode_attention(z, pool, pool, pt, L, impl="pallas")
+    with pytest.raises(ValueError, match="requested explicitly"):
+        paged_decode_attention(z, pool, pool, pt, L, impl="pallas")
+    got = paged_decode_attention(z, pool, pool, pt, L, impl="auto")
     ref = paged_decode_attention(z, pool, pool, pt, L, impl="xla")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(ref))
     with pytest.raises(ValueError, match="unknown attention impl"):

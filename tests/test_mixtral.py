@@ -159,7 +159,7 @@ def test_vocab_sharded_mixtral_matches_fused(tiny):
     )
 
 
-# -- routed task-graph dispatch (VERDICT r3 next #4) --------------------------
+# -- routed task-graph dispatch --------------------------
 
 def _routed_dag(tiny, capacity_factor, microbatches=1):
     return build_moe_dag(

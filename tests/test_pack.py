@@ -91,7 +91,7 @@ def test_pack_minimizes_bottleneck_not_total():
 
 
 def test_pack_spills_oversized_group_per_task():
-    """Graceful degradation (VERDICT r4 next #2): a group whose param
+    """Graceful degradation: a group whose param
     union exceeds every device budget no longer zeroes out — its tasks
     spill to singleton placement (min new-param-bytes device that fits),
     so pack degrades toward greedy instead of failing the whole group."""

@@ -8,7 +8,8 @@ trash-page masking (pools poisoned at TRASH_PAGE); a full
 PagedDecodeEngine run retires BITWISE-identical token ids under
 ``impl="xla"`` and ``impl="pallas_interpret"`` with zero leaked pages;
 the shared ``resolve_attention_impl`` helper's dispatch rules (unknown
-impl raises, ineligible explicit pallas downgrades to the gather path);
+impl raises, and so does an explicit pallas request the shape cannot
+honour);
 and the DEC005 eligibility diagnostic fires exactly on geometries
 ``paged_kernel_constraints`` rejects.
 """
@@ -225,14 +226,17 @@ def test_resolve_attention_impl_rules():
     assert resolve_attention_impl(
         "pallas_interpret", lambda i: True
     ) == "pallas_interpret"
-    # ineligible explicit kernel request downgrades to the gather path
-    assert resolve_attention_impl("pallas", lambda i: False) == "xla"
+    # an explicit kernel request the shape cannot honour raises — it is
+    # never quietly served by the gather path
+    with pytest.raises(ValueError, match="requested explicitly"):
+        resolve_attention_impl("pallas", lambda i: False)
     with pytest.raises(ValueError, match="unknown attention impl"):
         resolve_attention_impl("cuda", lambda i: True)
     # auto on a non-TPU host resolves to the gather path
     if jax.default_backend() != "tpu":
         assert resolve_attention_impl(None, lambda i: True) == "xla"
         assert resolve_attention_impl("auto", lambda i: True) == "xla"
+        assert resolve_attention_impl("auto", lambda i: False) == "xla"
 
 
 def test_paged_kernel_constraints():
@@ -255,10 +259,6 @@ def test_paged_kernel_constraints():
 def test_paged_pallas_supported_shapes():
     q = (4, 4, 1, 8)
     pool_ok = (64, 16, 2, 8)
-    try:
-        from jax.experimental.pallas import tpu as _  # noqa: F401
-    except ImportError:
-        pytest.skip("pltpu unavailable on this jax build")
     assert paged_pallas_supported(q, pool_ok, interpret=True)
     # interpret mode only needs structural validity, not lowering tiles
     assert paged_pallas_supported(q, (64, 6, 2, 8), interpret=True)

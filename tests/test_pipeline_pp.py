@@ -140,7 +140,7 @@ def test_train_cli_pp():
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     env = dict(
         os.environ,
-        DLS_PLATFORM="cpu",
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
     )
     r = subprocess.run(

@@ -1,6 +1,6 @@
 """Pretrained-weight ingestion: HF/torch GPT-2 state dict -> flat params.
 
-The strong form of the round-1 VERDICT ask ("load real weights through
+The strong form of the round-1 ask ("load real weights through
 build_gpt2_dag + fused-forward logit check"): a *torch* GPT2LMHeadModel is
 the weight donor AND the independent numerical oracle — its logits must
 match our fused forward and our scheduled DAG execution on the same

@@ -1,4 +1,4 @@
-"""Sim-vs-real policy rank agreement (VERDICT r2 #2).
+"""Sim-vs-real policy rank agreement.
 
 The strong honesty check the modeled headline needs: the simulator's
 predicted policy ORDERING must match the measured ordering when the same

@@ -17,7 +17,7 @@ def test_measure_streaming_tiny():
     assert res["budget_respected"], res
     assert res["capped_makespan_ms"] > 0
     assert res["total_param_gb"] > res["budget_gb"]
-    # bound reporting (VERDICT r3 weak #3): the artifact must show its
+    # bound reporting: the artifact must show its
     # distance to its own floor
     assert res["param_load_calls"] <= res["param_loads"]
     assert res["param_load_gb"] > 0

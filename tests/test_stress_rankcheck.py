@@ -1,4 +1,4 @@
-"""Transfer-stress DAG + the separating rank check (VERDICT r3 next #3).
+"""Transfer-stress DAG + the separating rank check.
 
 The flagship rank check runs in the CPU mesh's compute-tied regime where
 every placement near-ties; the transfer-stress DAG constructs the regime

@@ -82,7 +82,7 @@ def test_activation_memory_pressure_favors_mru(tiny_train):
 def test_train_dag_executes_on_placed_devices(tiny_train):
     """The whole fwd+bwd+opt step runs through DeviceBackend on a
     multi-device mesh with loss and updated params matching local
-    execution (VERDICT r3 next #5: config #5 on placed devices)."""
+    execution."""
     from distributed_llm_scheduler_tpu.backends.device import DeviceBackend
 
     params = tiny_train.init_params()

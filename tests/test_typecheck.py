@@ -2,7 +2,7 @@
 (analysis/stream_pass): one golden repro per code (TYP001-TYP004,
 STR001-STR003), the verdict fold, the compiled backend's diagnostic-driven
 stream refusal, the `lint --json` schema, and the `precomputed=` gate
-reuse (docs/ANALYSIS.md taxonomy)."""
+reuse (docs/ANALYSIS.md catalogue)."""
 
 from __future__ import annotations
 
@@ -351,7 +351,7 @@ def test_report_to_json_schema():
 def test_cli_lint_json():
     env = dict(
         os.environ,
-        DLS_PLATFORM="cpu",
+        JAX_PLATFORMS="cpu",
         XLA_FLAGS="--xla_force_host_platform_device_count=8",
     )
     r = subprocess.run(

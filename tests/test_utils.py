@@ -128,7 +128,7 @@ def test_profiling_helpers():
 def _run_cli(*args):
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["DLS_FORCE_CPU"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     return subprocess.run(
         [sys.executable, "-m", "distributed_llm_scheduler_tpu", *args],
         capture_output=True, text=True, cwd=os.path.dirname(os.path.dirname(__file__)),
@@ -222,7 +222,7 @@ def test_cli_visualize_menu(tmp_path):
     summary, reject an unknown choice, and exit cleanly on q."""
     env = dict(os.environ)
     env["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-    env["DLS_FORCE_CPU"] = "1"
+    env["JAX_PLATFORMS"] = "cpu"
     r = subprocess.run(
         [sys.executable, "-m", "distributed_llm_scheduler_tpu",
          "visualize", "--model", "llm", "--num-layers", "2",
