@@ -612,15 +612,13 @@ class CompiledSchedule:
                 )
 
         n_fences = 0
+        fence_s = 0.0
         if fence:
-            t_f0 = time.perf_counter() if tracer is not None else 0.0
-            n_fences = self.backend._fence_run(tips_by_node)
+            n_fences, fence_s = self.backend._timed_fence(
+                tips_by_node, tracer
+            )
             if tracer is not None:
                 t_f1 = time.perf_counter()
-                tracer.complete(
-                    "fence", t_f0, t_f1, track="host", cat="collect",
-                    devices=len(tips_by_node),
-                )
                 # one fused program span per device: the compiled path
                 # has no per-task boundaries, so the device rows carry a
                 # single cat="program" span each (obs/attribution.py
@@ -661,6 +659,7 @@ class CompiledSchedule:
             "loop_s": t_launch - t0,
             "stage_s": t_stage - t0,
             "launch_s": t_launch - t_stage,
+            "fence_s": fence_s,
         }
         return (
             final, {}, self.transfer_edges, self.transfer_bytes,

@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from ..core.graph import TaskGraph
 from ..core.schedule import Schedule
 from ..frontend.decode_dag import cache_dims
+from ..obs.trace import annotate
 
 
 def compose_step_fn(
@@ -1343,15 +1344,19 @@ class PagedDecodeEngine:
                         )
             chunk = self._np.zeros((1, ct), self._np.int32)
             chunk[0, :C] = st["ids"][0, base:base + C]
+            # a DISPATCH span: it ends when the chunk program is enqueued
+            # (no sync is added to close it "when ready"); the chunk's
+            # device time is the device trace's (prefill_dev_us_tok)
             ev = None
             if self.tracer is not None:
                 ev = self.tracer.begin(
                     "prefill_chunk", track="decode", cat="decode",
                     rid=str(st["rid"]), base=base, tokens=C,
                 )
-            first = self._chunk_prefill(
-                jnp.asarray(chunk), self.page_table[s], base, C
-            )
+            with annotate("prefill_chunk"):
+                first = self._chunk_prefill(
+                    jnp.asarray(chunk), self.page_table[s], base, C
+                )
             if ev is not None:
                 self.tracer.end(ev)
                 if self.reqtrace is not None:
@@ -1377,13 +1382,17 @@ class PagedDecodeEngine:
     def _fold_chunked(self, s: int, st: Dict[str, Any], first) -> None:
         """The LAST chunk folded: its final-row logits are the first
         token, the slot flips from prefilling to decoding, and TTFT
-        anchors here — mirroring the whole-prompt admission fold."""
+        anchors here — mirroring the whole-prompt admission fold: the
+        clock is read after the readback, when the token is on the host
+        (the chunk programs still in flight, 75 ms each on the v5e, end
+        before it)."""
         rid = st["rid"]
+        tok = int(first[0])  # the readback: waits for the last chunk
         t_done = self._clock()
         self.lengths[s] = st["P"]
-        self.cur_tok[s, 0] = int(first[0])
+        self.cur_tok[s, 0] = tok
         self.remaining[s] = st["max_new"] - 1
-        self._tokens[rid] = [int(first[0])]
+        self._tokens[rid] = [tok]
         self._first_tok_t[rid] = t_done
         del self._chunk_state[s]
         for rl in self._reqlogs:
@@ -1781,7 +1790,18 @@ class PagedDecodeEngine:
         full = (max(ct, self.slots * self.seg_steps)
                 if ct is not None else 0)
         spent = self._advance_chunks() if self._chunk_state else 0
-        self._admit()
+        with annotate("admit"):
+            t_a0 = self._clock() if self.tracer is not None else 0.0
+            admitted = self._admit()
+            if self.tracer is not None:
+                # the engine's half of admission (the front-end's is its
+                # own ``admit`` span): no chunk program is dispatched
+                # inside; whole-prompt waves nest in it
+                self.tracer.complete(
+                    "admit", t_a0, self._clock(), track="decode",
+                    cat="decode", admitted=admitted,
+                    queue_depth=len(self._queue),
+                )
         if (ct is not None and spent < full and any(
                 st["next"] == 0 for st in self._chunk_state.values())):
             self._advance_chunks(full - spent)
@@ -1798,15 +1818,24 @@ class PagedDecodeEngine:
             if not owed.any():
                 return 0
         self._ensure_exclusive()
-        t_sg0 = self._clock()
-        toks, self.pools = self._seg(
-            self.weights, self.pools, self.page_table, self.lengths,
-            self.cur_tok, self.remaining,
-        )
-        toks = self._np.asarray(toks)  # the one readback per segment
-        # the fold timestamp: every token this segment delivered became
-        # host-visible at this readback (lifecycle-log delivery events)
-        t_sg1 = self._clock()
+        with annotate("segment"):
+            t_sg0 = self._clock()
+            toks, self.pools = self._seg(
+                self.weights, self.pools, self.page_table, self.lengths,
+                self.cur_tok, self.remaining,
+            )
+            toks = self._np.asarray(toks)  # the one readback per segment
+            # the fold timestamp: every token this segment delivered
+            # became host-visible at this readback (lifecycle-log
+            # delivery events)
+            t_sg1 = self._clock()
+        with annotate("fold"):
+            return self._fold_segment(toks, owed, t_sg0, t_sg1)
+
+    def _fold_segment(self, toks, owed, t_sg0: float, t_sg1: float) -> int:
+        """What follows a segment's readback at ``t_sg1``: the tokens go
+        to their requests, finished slots retire, the gauges are sampled.
+        Returns the tokens delivered."""
         if self.tracer is not None:
             self.tracer.complete(
                 "segment", t_sg0, t_sg1, track="decode",
@@ -1835,7 +1864,7 @@ class PagedDecodeEngine:
         ran = self._np.minimum(owed, self.seg_steps)
         self.lengths = self.lengths + ran
         self.remaining = self._np.maximum(owed - self.seg_steps, 0)
-        delivered = 0
+        delivered = retired = 0
         for s in range(self.slots):
             rid = self._slot_req[s]
             if rid is None:
@@ -1851,12 +1880,18 @@ class PagedDecodeEngine:
             # decoding nothing yet) — it retires only after its fold
             if 0 < owed[s] <= self.seg_steps:
                 self._retire(s)
+                retired += 1
         self.segments_run += 1
         self.metrics.counter("decode.segments_run").inc()
         self.metrics.counter("decode.tokens_delivered").inc(delivered)
         self._emit_pool_occupancy()
         self._emit_queue_depth()
         self._emit_jit_cache_size()
+        if self.tracer is not None:
+            self.tracer.complete(
+                "fold", t_sg1, self._clock(), track="decode", cat="decode",
+                delivered=delivered, retired=retired,
+            )
         return delivered
 
     def run(self) -> Dict[Any, Any]:

@@ -35,6 +35,7 @@ in-process "multi-node" strategy (SURVEY.md §4).
 from __future__ import annotations
 # dls-lint: allow-file(DET001) real-device execution timing: wall time IS the measured quantity
 
+import functools
 import time
 import warnings
 from dataclasses import dataclass, field
@@ -45,6 +46,25 @@ import jax
 from ..core.cluster import Cluster
 from ..core.graph import TaskGraph
 from ..core.schedule import Schedule, TaskTiming
+from ..obs import process_metrics
+from ..obs.trace import (
+    CAT_CALL,
+    CAT_COLLECT,
+    CAT_PLAN,
+    CAT_SCHEDULE,
+    CAT_STAGE,
+    PhaseClock,
+    annotate,
+)
+
+
+# what one ``execute`` call spends outside its dispatch loop, in call
+# order; every call reports each (0.0 where the phase did not run), plus
+# ``other_s`` for what none of them covers
+_CALL_PHASES = (
+    "order_s", "place_s", "plan_s", "warmup_s", "rtt_s", "fence_s",
+    "report_s",
+)
 
 
 @dataclass
@@ -73,10 +93,18 @@ class DeviceReport:
     # planned path exists to shrink; on platforms where a launch can
     # block on device compute it is an upper bound.
     dispatch_overhead_s: float = 0.0
-    # per-rep breakdown of the loop wall: planned dispatch reports
-    # {loop_s, stage_s (input placement + batched transfers), launch_s};
-    # the legacy paths report {loop_s}
+    # the call's wall time tiled into phases, always on.  Per rep, the
+    # loop wall: planned and compiled dispatch report {loop_s, stage_s
+    # (input placement + batched transfers), launch_s}, with loop_s their
+    # sum; the legacy paths report {loop_s}.  Per call: order_s
+    # (dispatch_order), place_s (place_params), plan_s (plan, program or
+    # segment build), warmup_s, rtt_s (the fence round-trip probe),
+    # fence_s (the host waiting in the end-of-run fence: how far the
+    # device is behind the host), report_s (memory stats, registry, this
+    # report) and other_s = wall_s less all of these: see leaf_phases()
     dispatch_phases: Dict[str, float] = field(default_factory=dict)
+    # host wall of the whole execute() call, entry to return
+    wall_s: float = 0.0
     # True when the run used the pre-planned fast path (dispatch_plan)
     planned: bool = False
     # True when the run used the whole-program compiled path
@@ -100,18 +128,49 @@ class DeviceReport:
     param_load_bytes: int = 0
     param_evictions: int = 0
     peak_param_bytes: Dict[str, int] = field(default_factory=dict)
-    # traced runs only: the run doctor's measured critical-path summary
-    # (obs/attribution.py) over this execute's span window — makespan
-    # split into compute/transfer/dispatch/idle plus stragglers/bubbles
-    attribution: Optional[Dict[str, Any]] = None
     # memprof runs only: the memory doctor's per-device timeline summary
     # (obs/memprof.py) — peaks, watermark attribution buckets, and
     # platform reconciliation where memory_stats() reported
     memory: Optional[Dict[str, Any]] = None
+    # traced runs only: (tracer, this execute's span window), what
+    # ``attribution`` is computed from when it is first read
+    attribution_source: Optional[Tuple[Any, Tuple[float, float]]] = field(
+        default=None, repr=False, compare=False
+    )
+
+    @functools.cached_property
+    def attribution(self) -> Optional[Dict[str, Any]]:
+        """Traced runs only: the run doctor's measured critical-path
+        summary (obs/attribution.py) over this execute's span window —
+        makespan split into compute/transfer/dispatch/idle plus
+        stragglers/bubbles.  Computed on first read, from the tracer as
+        it is then: read it before the tracer's events are cleared.
+        Diagnosis only — never fails."""
+        if self.attribution_source is None:
+            return None
+        from ..obs.attribution import attribute_run
+
+        tracer, window = self.attribution_source
+        try:
+            att = attribute_run(tracer, window=window)
+            return att.summary() if att.critical_path else None
+        except Exception:
+            return None
 
     @property
     def total_param_gb_placed(self) -> float:
         return sum(self.param_bytes_placed.values()) / 1024**3
+
+    def leaf_phases(self) -> Dict[str, float]:
+        """The phases of ``dispatch_phases`` that tile ``wall_s`` (with
+        ``reps=1`` they sum to it by construction): every phase but
+        ``loop_s`` where the path splits the loop into ``stage_s`` and
+        ``launch_s``."""
+        split = "launch_s" in self.dispatch_phases
+        return {
+            k: v for k, v in self.dispatch_phases.items()
+            if not (split and k == "loop_s")
+        }
 
     def summary(self) -> Dict[str, Any]:
         return {
@@ -123,6 +182,7 @@ class DeviceReport:
             "param_gb_placed": self.total_param_gb_placed,
             "compile_s": self.compile_s,
             "n_dispatches": self.n_dispatches,
+            "wall_ms": self.wall_s * 1e3,
             "dispatch_overhead_ms": self.dispatch_overhead_s * 1e3,
             "dispatch_phases_ms": {
                 k: v * 1e3 for k, v in self.dispatch_phases.items()
@@ -257,6 +317,25 @@ class DeviceBackend:
             combined = combined + t.astype(combined.dtype)
         readback_fence(combined)
         return 1
+
+    def _timed_fence(
+        self, last_on_device: Dict[str, Any], tracer: Any,
+    ) -> Tuple[int, float]:
+        """:meth:`_fence_run` with the host's wait in it read, always:
+        ``(n_fences, fence_s)``.  The wait is the time the device is
+        behind the host when the dispatch loop ends.  Entered as the
+        profiler annotation ``dls/fence``; with a tracer, the ``fence``
+        leaf of the host track."""
+        with annotate("fence"):
+            t0 = time.perf_counter()
+            n_fences = self._fence_run(last_on_device)
+            t1 = time.perf_counter()
+        if tracer is not None:
+            tracer.complete(
+                "fence", t0, t1, track="host", cat=CAT_COLLECT,
+                devices=len(last_on_device),
+            )
+        return n_fences, t1 - t0
 
     # -- placement ---------------------------------------------------------
     def place_params(
@@ -1095,16 +1174,9 @@ class DeviceBackend:
                 last_on_device[node] = outputs[exports[-1]]
         # guard on executed segments, not `outputs` — ext_outputs seeds can
         # make `outputs` non-empty when nothing actually ran
+        fence_s = 0.0
         if last_on_device and fence:
-            if tracer is not None:
-                t_f0 = time.perf_counter()
-            n_fences = self._fence_run(last_on_device)
-            if tracer is not None:
-                tracer.complete(
-                    "fence", t_f0, time.perf_counter(),
-                    track="host", cat="collect",
-                    devices=len(last_on_device),
-                )
+            n_fences, fence_s = self._timed_fence(last_on_device, tracer)
         # same semantics as the per-task path: None when the graph's last
         # task didn't execute (callers detect incomplete runs by this)
         final = outputs.get(graph.topo_order[-1]) if graph.topo_order else None
@@ -1114,7 +1186,8 @@ class DeviceBackend:
         }
         return (
             final, {}, transfer_edges, transfer_bytes, n_fences,
-            len(segments), executed, {"loop_s": loop_s},
+            len(segments), executed,
+            {"loop_s": loop_s, "fence_s": fence_s},
         )
 
     # -- execution ---------------------------------------------------------
@@ -1265,20 +1338,13 @@ class DeviceBackend:
         # that device's whole queue drained (_fence_run combines them into
         # ONE readback).
         n_fences = 0
+        fence_s = 0.0
         if len(outputs) > n_ext and fence:
             last_on_device: Dict[str, Any] = {}
             for tid in order:
                 if tid in outputs:
                     last_on_device[placement[tid]] = outputs[tid]
-            if tracer is not None:
-                t_f0 = time.perf_counter()
-            n_fences = self._fence_run(last_on_device)
-            if tracer is not None:
-                tracer.complete(
-                    "fence", t_f0, time.perf_counter(),
-                    track="host", cat="collect",
-                    devices=len(last_on_device),
-                )
+            n_fences, fence_s = self._timed_fence(last_on_device, tracer)
         final = outputs.get(graph.topo_order[-1]) if graph.topo_order else None
         executed = {
             k: v for k, v in outputs.items()
@@ -1286,7 +1352,8 @@ class DeviceBackend:
         }
         return (
             final, timings, transfer_edges, transfer_bytes, n_fences,
-            len(outputs) - n_ext, executed, {"loop_s": loop_s},
+            len(outputs) - n_ext, executed,
+            {"loop_s": loop_s, "fence_s": fence_s},
         )
 
     def paged_decode_engine(
@@ -1492,6 +1559,9 @@ class DeviceBackend:
         runs unrecorded, same as the tracer — only the timed reps land
         on the timeline.  Explicit only (no ambient fallback).
         """
+        # the call's own wall starts here: checks, the pre-execution gate
+        # and whatever else no phase below covers end up in ``other_s``
+        t_call0 = time.perf_counter()
         if segments and profile:
             raise ValueError(
                 "profile=True needs per-task dispatch; run without segments"
@@ -1598,10 +1668,16 @@ class DeviceBackend:
         mreg = metrics if metrics is not None else ambient_metrics()
         jit_hits0 = self.jit_cache_hits
         jit_miss0 = self.jit_cache_misses
+        # always on: the call tiles its own wall time into leaf phases
+        # (about twenty clock reads a call, none per launch); with a
+        # tracer each leaf is also a span of the host track, and the
+        # leaves of one call do not overlap
+        clock = PhaseClock(tracer, t0=t_call0)
+        clock.seconds.update(dict.fromkeys(_CALL_PHASES, 0.0))
         ev_exec = None
         if tracer is not None:
             ev_exec = tracer.begin(
-                "execute", cat="schedule", policy=schedule.policy,
+                "execute", cat=CAT_CALL, policy=schedule.policy,
                 segments=segments, reps=reps,
             )
         # one linearization for the stream plan, the segment build, and
@@ -1609,13 +1685,9 @@ class DeviceBackend:
         # schedule) and costs ~ms on 500-task DAGs
         order_once: List[str] = []
         if not compiled:
-            t_ph = time.perf_counter() if tracer is not None else 0.0
-            order_once = self.dispatch_order(graph, schedule)
-            if tracer is not None:
-                tracer.complete(
-                    "dispatch_order", t_ph, time.perf_counter(),
-                    track="host", cat="schedule", tasks=len(order_once),
-                )
+            with clock.phase("order_s", "dispatch_order", CAT_SCHEDULE) as a:
+                order_once = self.dispatch_order(graph, schedule)
+                a["tasks"] = len(order_once)
         segments_pre = None
         if stream_params:
             placed, bytes_per_node = {}, {d.node_id: 0 for d in self.cluster}
@@ -1626,16 +1698,18 @@ class DeviceBackend:
             # batched load per fused program, next segment prefetched
             # while the current one runs)
             if segments:
-                segments_pre = self.build_segments(
-                    graph, schedule, order_once,
-                    max_union_gb=self._stream_segment_caps(),
-                    # size by the ACTUAL host arrays: declared/default
-                    # sizes can under-count and defeat the budget split
-                    param_gb={
-                        g: _array_bytes(params[g]) / (1024**3)
-                        for g in graph.unique_params()
-                    },
-                )
+                with clock.phase("plan_s", "segment_build", CAT_PLAN) as a:
+                    segments_pre = self.build_segments(
+                        graph, schedule, order_once,
+                        max_union_gb=self._stream_segment_caps(),
+                        # size by the ACTUAL host arrays: declared/default
+                        # sizes can under-count and defeat the budget split
+                        param_gb={
+                            g: _array_bytes(params[g]) / (1024**3)
+                            for g in graph.unique_params()
+                        },
+                    )
+                    a["segments"] = len(segments_pre)
                 stream_plan = self.segment_stream_plan(graph, segments_pre)
             else:
                 stream_plan = {}
@@ -1651,22 +1725,21 @@ class DeviceBackend:
             # CompiledSchedule.build — per-global placement never happens
             placed, bytes_per_node = {}, {}
         else:
-            t_ph = time.perf_counter() if tracer is not None else 0.0
-            placed, bytes_per_node = self.place_params(
-                graph, schedule, params, mem=memprof
-            )
-            if tracer is not None:
-                tracer.complete(
-                    "place_params", t_ph, time.perf_counter(),
-                    track="host", cat="stage",
-                    bytes=sum(bytes_per_node.values()),
+            with clock.phase("place_s", "place_params", CAT_STAGE) as a:
+                placed, bytes_per_node = self.place_params(
+                    graph, schedule, params, mem=memprof
                 )
+                a["bytes"] = sum(bytes_per_node.values())
         if segments and segments_pre is None:
             # plain segmented runs were rebuilding segments inside every
             # timed rep (the same host-work-in-makespan bias the order
             # hoist removes); the length-match guard in _run_segmented
             # still handles drop-filter divergence
-            segments_pre = self.build_segments(graph, schedule, order_once)
+            with clock.phase("plan_s", "segment_build", CAT_PLAN) as a:
+                segments_pre = self.build_segments(
+                    graph, schedule, order_once
+                )
+                a["segments"] = len(segments_pre)
 
         # planned fast path: precompute the immutable dispatch plan at
         # warmup time (resolved executables, prebuilt param bindings,
@@ -1677,90 +1750,77 @@ class DeviceBackend:
         if compiled:
             from .compiled_schedule import CompiledSchedule
 
-            t_ph = time.perf_counter() if tracer is not None else 0.0
-            prog = CompiledSchedule.build(
-                self, graph, schedule, params, graph_input,
-                donate=donate, pre_analysis=self.pre_analysis,
-                pre_report=pre_report,
-            )
-            bytes_per_node = prog.param_bytes_per_node
-            if tracer is not None:
-                tracer.complete(
-                    "program_build", t_ph, time.perf_counter(),
-                    track="host", cat="plan",
-                    phases=len(prog.ir.phases),
-                    exchanges=prog.ir.n_exchanges,
+            with clock.phase("plan_s", "program_build", CAT_PLAN) as a:
+                prog = CompiledSchedule.build(
+                    self, graph, schedule, params, graph_input,
+                    donate=donate, pre_analysis=self.pre_analysis,
+                    pre_report=pre_report,
                 )
+                a["phases"] = len(prog.ir.phases)
+                a["exchanges"] = prog.ir.n_exchanges
+            bytes_per_node = prog.param_bytes_per_node
         elif planned:
             from .dispatch_plan import DispatchPlan
 
-            t_ph = time.perf_counter() if tracer is not None else 0.0
-            plan = DispatchPlan.build(
-                self, graph, schedule, order_once, placed,
-                ext_keys=tuple(ext_outputs or ()),
-                donate=donate, coalesce=coalesce,
-                keep_outputs=keep_outputs,
-            )
-            if tracer is not None:
-                tracer.complete(
-                    "plan_build", t_ph, time.perf_counter(),
-                    track="host", cat="plan", steps=len(plan.steps),
+            with clock.phase("plan_s", "plan_build", CAT_PLAN) as a:
+                plan = DispatchPlan.build(
+                    self, graph, schedule, order_once, placed,
+                    ext_keys=tuple(ext_outputs or ()),
+                    donate=donate, coalesce=coalesce,
+                    keep_outputs=keep_outputs,
                 )
+                a["steps"] = len(plan.steps)
 
         compile_s = 0.0
         if warmup:
-            t_ph = time.perf_counter() if tracer is not None else 0.0
-            if prog is not None:
-                # first run traces + XLA-compiles the whole-program
-                # executable; same donation-warning note as the plan path
-                t0 = time.perf_counter()
-                with warnings.catch_warnings():
-                    warnings.filterwarnings(
-                        "ignore",
-                        message="Some donated buffers were not usable",
-                    )
-                    prog.run(graph_input, fence=True)
-                compile_s = time.perf_counter() - t0
-            elif plan is not None:
-                # one full planned execution: jits every resolved
-                # executable (donating variants and coalesced groups
-                # included) and fills the static transfer-byte table.
-                # XLA warns once per lowering when a donated buffer's
-                # shape matches no output; the donation is still honored
-                # (the buffer is freed), so the warning is noise here.
-                t0 = time.perf_counter()
-                with warnings.catch_warnings():
-                    warnings.filterwarnings(
-                        "ignore",
-                        message="Some donated buffers were not usable",
-                    )
-                    plan.run(graph_input, ext_outputs, fence=True)
-                compile_s = time.perf_counter() - t0
-            else:
-                # a throwaway streamer for the warmup pass: jit caches warm
-                # up, and the timed run's streamer starts cold (capacity
-                # misses are the thing being measured)
-                compile_s = self.warmup(
-                    graph, schedule, placed, graph_input, segments=segments,
-                    ext_outputs=ext_outputs,
-                    streamer=(
-                        self._ParamStreamer(
-                            self.cluster, params, plan=stream_plan,
-                            lookahead=stream_lookahead,
+            # warmup runs untraced (its transfers/launches are compile
+            # artifacts, not steady-state behavior); one host span
+            # covers the whole compile window
+            with clock.phase("warmup_s", "warmup", CAT_PLAN) as a:
+                if prog is not None:
+                    # first run traces + XLA-compiles the whole-program
+                    # executable; same donation-warning note as the plan path
+                    t0 = time.perf_counter()
+                    with warnings.catch_warnings():
+                        warnings.filterwarnings(
+                            "ignore",
+                            message="Some donated buffers were not usable",
                         )
-                        if stream_params else None
-                    ),
-                    rebatch=rebatch,
-                    segments_pre=segments_pre,
-                )
-            if tracer is not None:
-                # warmup runs untraced (its transfers/launches are compile
-                # artifacts, not steady-state behavior); one host span
-                # covers the whole compile window
-                tracer.complete(
-                    "warmup", t_ph, time.perf_counter(),
-                    track="host", cat="plan", compile_s=compile_s,
-                )
+                        prog.run(graph_input, fence=True)
+                    compile_s = time.perf_counter() - t0
+                elif plan is not None:
+                    # one full planned execution: jits every resolved
+                    # executable (donating variants and coalesced groups
+                    # included) and fills the static transfer-byte table.
+                    # XLA warns once per lowering when a donated buffer's
+                    # shape matches no output; the donation is still honored
+                    # (the buffer is freed), so the warning is noise here.
+                    t0 = time.perf_counter()
+                    with warnings.catch_warnings():
+                        warnings.filterwarnings(
+                            "ignore",
+                            message="Some donated buffers were not usable",
+                        )
+                        plan.run(graph_input, ext_outputs, fence=True)
+                    compile_s = time.perf_counter() - t0
+                else:
+                    # a throwaway streamer for the warmup pass: jit caches warm
+                    # up, and the timed run's streamer starts cold (capacity
+                    # misses are the thing being measured)
+                    compile_s = self.warmup(
+                        graph, schedule, placed, graph_input, segments=segments,
+                        ext_outputs=ext_outputs,
+                        streamer=(
+                            self._ParamStreamer(
+                                self.cluster, params, plan=stream_plan,
+                                lookahead=stream_lookahead,
+                            )
+                            if stream_params else None
+                        ),
+                        rebatch=rebatch,
+                        segments_pre=segments_pre,
+                    )
+                a["compile_s"] = compile_s
 
         # fence round-trip, measured per execute (outside the timed
         # region).  Callers timing several executes back-to-back (bench
@@ -1771,7 +1831,9 @@ class DeviceBackend:
         else:
             from ..utils.costmodel import _fence_rtt
 
-            rtt = _fence_rtt(self._fence_device())
+            with clock.phase("rtt_s", "fence_rtt", CAT_COLLECT) as a:
+                rtt = _fence_rtt(self._fence_device())
+                a["rtt_s"] = rtt
 
         streamer = (
             self._ParamStreamer(
@@ -1781,7 +1843,6 @@ class DeviceBackend:
             if stream_params else None
         )
         t0 = time.perf_counter()
-        loop_s_total = 0.0
         phases_total: Dict[str, float] = {}
         for r in range(reps):
             fence = r == reps - 1  # intermediate reps queue without fencing
@@ -1821,103 +1882,109 @@ class DeviceBackend:
                     ext_outputs, streamer, fence=fence, order=order_once,
                     tracer=tracer, metrics=mreg, mem=memprof,
                 )
-            loop_s_total += phases.get("loop_s", 0.0)
             for k, v in phases.items():
                 phases_total[k] = phases_total.get(k, 0.0) + v
             if tracer is not None:
+                # encloses the rep's leaves (stage_input, dispatch_loop,
+                # fence), as ``execute`` encloses all: their own category
                 tracer.complete(
                     f"rep{r}", t_ph, time.perf_counter(),
-                    track="host", cat="launch",
+                    track="host", cat=CAT_CALL,
                     dispatches=n_disp, fenced=fence,
                 )
         wall = time.perf_counter() - t0
         makespan = max((wall - n_fences * rtt) / reps, 1e-9)
+        # the fence is per call (only the last rep fences); the loop and
+        # its staging and launch halves stay per rep
+        clock.seconds["fence_s"] = phases_total.pop("fence_s", 0.0)
+        loop_s_total = phases_total.get("loop_s", 0.0)
         dispatch_overhead_s = loop_s_total / reps
         dispatch_phases = {k: v / reps for k, v in phases_total.items()}
 
-        # per-device peaks where the platform reports them (TPU does; the
-        # host platform returns None)
-        peaks: Dict[str, int] = {}
-        for d in self.cluster:
-            stats = d.jax_device.memory_stats() or {}
-            if "peak_bytes_in_use" in stats:
-                peaks[d.node_id] = int(stats["peak_bytes_in_use"])
-        if memprof is not None:
-            # platform truth where PJRT reports it; the profiler's
-            # model-derived timeline stands alone elsewhere
-            memprof.reconcile(peaks)
+        with clock.phase("report_s", "report", CAT_COLLECT):
+            # per-device peaks where the platform reports them (TPU does; the
+            # host platform returns None)
+            peaks: Dict[str, int] = {}
+            for d in self.cluster:
+                stats = d.jax_device.memory_stats() or {}
+                if "peak_bytes_in_use" in stats:
+                    peaks[d.node_id] = int(stats["peak_bytes_in_use"])
+            if memprof is not None:
+                # platform truth where PJRT reports it; the profiler's
+                # model-derived timeline stands alone elsewhere
+                memprof.reconcile(peaks)
 
-        if timings:
-            schedule.timings = timings
-        if mreg is not None:
-            # per-rep counts are identical across reps, so the run totals
-            # are a clean multiply; histograms get one sample per execute
-            mreg.counter("dispatch.launches").inc(n_disp * reps)
-            mreg.counter("dispatch.transfer_edges").inc(tedges * reps)
-            mreg.counter("dispatch.transfer_bytes", unit="bytes").inc(
-                tbytes * reps
-            )
-            mreg.histogram("dispatch.overhead_s", unit="s").observe(
-                dispatch_overhead_s
-            )
-            mreg.histogram("execute.makespan_s", unit="s").observe(makespan)
-            mreg.histogram("execute.compile_s", unit="s").observe(compile_s)
-            mreg.counter("compile.jit_cache_hits").inc(
-                self.jit_cache_hits - jit_hits0
-            )
-            mreg.counter("compile.jit_cache_misses").inc(
-                self.jit_cache_misses - jit_miss0
-            )
             if timings:
-                # profile mode: busy fraction per device over the measured
-                # span — the Gantt chart's utilization column as a gauge
-                span_end = max(t.finish for t in timings.values())
-                busy: Dict[str, float] = {}
-                for t in timings.values():
-                    busy[t.node_id] = busy.get(t.node_id, 0.0) + t.duration
-                for n, b in busy.items():
-                    mreg.gauge(f"device.utilization.{n}", unit="frac").set(
-                        b / span_end if span_end > 0 else 0.0
-                    )
-        attribution = None
+                schedule.timings = timings
+            if mreg is not None:
+                # per-rep counts are identical across reps, so the run totals
+                # are a clean multiply; histograms get one sample per execute
+                mreg.counter("dispatch.launches").inc(n_disp * reps)
+                mreg.counter("dispatch.transfer_edges").inc(tedges * reps)
+                mreg.counter("dispatch.transfer_bytes", unit="bytes").inc(
+                    tbytes * reps
+                )
+                mreg.histogram("dispatch.overhead_s", unit="s").observe(
+                    dispatch_overhead_s
+                )
+                mreg.histogram("execute.makespan_s", unit="s").observe(makespan)
+                mreg.histogram("execute.compile_s", unit="s").observe(compile_s)
+                mreg.counter("compile.jit_cache_hits").inc(
+                    self.jit_cache_hits - jit_hits0
+                )
+                mreg.counter("compile.jit_cache_misses").inc(
+                    self.jit_cache_misses - jit_miss0
+                )
+                if timings:
+                    # profile mode: busy fraction per device over the measured
+                    # span — the Gantt chart's utilization column as a gauge
+                    span_end = max(t.finish for t in timings.values())
+                    busy: Dict[str, float] = {}
+                    for t in timings.values():
+                        busy[t.node_id] = busy.get(t.node_id, 0.0) + t.duration
+                    for n, b in busy.items():
+                        mreg.gauge(f"device.utilization.{n}", unit="frac").set(
+                            b / span_end if span_end > 0 else 0.0
+                        )
+            report = DeviceReport(
+                policy=schedule.policy,
+                makespan_s=makespan,
+                output=output,
+                n_devices=len(self.cluster),
+                transfer_edges=tedges,
+                transfer_bytes=tbytes,
+                param_bytes_placed=bytes_per_node,
+                compile_s=compile_s,
+                timings=timings,
+                peak_hbm_bytes=peaks,
+                n_dispatches=n_disp,
+                dispatch_overhead_s=dispatch_overhead_s,
+                dispatch_phases=dispatch_phases,
+                planned=plan is not None,
+                compiled=prog is not None,
+                task_outputs=touts if keep_outputs else {},
+                streamed=streamer is not None,
+                param_loads=streamer.loads if streamer else 0,
+                param_load_calls=streamer.load_calls if streamer else 0,
+                param_load_bytes=streamer.load_bytes if streamer else 0,
+                param_evictions=streamer.evictions if streamer else 0,
+                peak_param_bytes=dict(streamer.peak) if streamer else {},
+                memory=memprof.summary() if memprof is not None else None,
+            )
         if ev_exec is not None:
             tracer.end(ev_exec, makespan_s=makespan)
-            # run doctor: attribute this execute's span window (window
-            # filtering keeps ambient tracers that accumulated earlier
-            # runs correct).  Diagnosis only — never fail the run on it.
-            try:
-                from ..obs.attribution import attribute_run
-
-                att = attribute_run(
-                    tracer, window=(ev_exec["t0"], ev_exec["t1"]),
-                )
-                if att.critical_path:
-                    attribution = att.summary()
-            except Exception:
-                attribution = None
-        return DeviceReport(
-            policy=schedule.policy,
-            makespan_s=makespan,
-            output=output,
-            n_devices=len(self.cluster),
-            transfer_edges=tedges,
-            transfer_bytes=tbytes,
-            param_bytes_placed=bytes_per_node,
-            compile_s=compile_s,
-            timings=timings,
-            peak_hbm_bytes=peaks,
-            n_dispatches=n_disp,
-            dispatch_overhead_s=dispatch_overhead_s,
-            dispatch_phases=dispatch_phases,
-            planned=plan is not None,
-            compiled=prog is not None,
-            task_outputs=touts if keep_outputs else {},
-            streamed=streamer is not None,
-            param_loads=streamer.loads if streamer else 0,
-            param_load_calls=streamer.load_calls if streamer else 0,
-            param_load_bytes=streamer.load_bytes if streamer else 0,
-            param_evictions=streamer.evictions if streamer else 0,
-            peak_param_bytes=dict(streamer.peak) if streamer else {},
-            attribution=attribution,
-            memory=memprof.summary() if memprof is not None else None,
-        )
+            # run doctor: this execute's span window (window filtering
+            # keeps tracers that accumulated other runs correct).  Read
+            # when ``report.attribution`` first is, not here: walking
+            # the critical path of one 1,561-launch step cost several
+            # untraced steps
+            report.attribution_source = (
+                tracer, (ev_exec["t0"], ev_exec["t1"]),
+            )
+        report.wall_s = clock.finish(timed_elsewhere=loop_s_total)
+        dispatch_phases.update(clock.seconds)
+        pm = process_metrics()
+        for k, v in dispatch_phases.items():
+            pm.histogram(f"execute.phase.{k}", unit="s").observe(v)
+        pm.histogram("execute.wall_s", unit="s").observe(report.wall_s)
+        return report

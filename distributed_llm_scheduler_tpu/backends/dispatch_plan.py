@@ -69,6 +69,7 @@ from jax.sharding import SingleDeviceSharding
 # here, at import.
 from jax._src.lib import xla_client as _xc
 
+from ..obs.trace import CAT_LAUNCH, CAT_STAGE, annotate
 from .rebatch import extract_steps
 
 
@@ -667,26 +668,32 @@ class DispatchPlan:
         """Execute the plan once.  Same return contract as the legacy
         runners plus a phase dict: ``(final, timings, transfer_edges,
         transfer_bytes, n_fences, n_dispatches, executed, phases)`` with
-        ``phases = {loop_s, stage_s, launch_s}`` — host wall inside the
-        dispatch loop (fence excluded), split into staging (input placement
-        + batched transfers) and launch (executable calls).
+        ``phases = {loop_s, stage_s, launch_s, fence_s}`` — host wall inside
+        the dispatch loop (fence excluded), split into staging (input
+        placement + batched transfers) and launch (executable calls), and
+        the host's wait in the end-of-run fence: the time the device is
+        behind the host.  These clock reads are always on (five a run,
+        two per step that moves data between chips); so are the profiler
+        annotations ``dls/stage_input``, ``dls/dispatch_loop`` and
+        ``dls/fence``.
 
         ``tracer`` (obs.trace.Tracer, optional): records one launch span
-        per step on the step's device track, staging spans, and transfer
-        flow arrows from producer launches.  ``metrics`` (obs.metrics.
-        MetricsRegistry, optional): per-(src->dst) transfer byte counters.
-        Both default to None and every instrumentation point is behind a
-        None check — the disabled hot loop is the PR 2 fast path
-        unchanged (the <2% regression budget is measured by
-        ``eval/dispatch_bench.py``).
+        per step on the step's device track, staging spans, transfer
+        flow arrows from producer launches, and on the host track the
+        leaves ``stage_input``, ``dispatch_loop`` and ``fence``, which do
+        not overlap.  ``metrics`` (obs.metrics.MetricsRegistry, optional):
+        per-(src->dst) transfer byte counters.  Both default to None and
+        every per-launch instrumentation point is behind a None check.
 
         ``mem`` (obs.memprof.MemoryProfiler, optional): records input
         staging, transfer copies, task-output births, and donation-driven
         frees (the lifetimes :meth:`donation_table` documents) onto the
         per-device timelines."""
         vals: List[Any] = [None] * self.n_slots
+        # where each producer's launch ended, for the flow arrows: only a
+        # plan that moves data between chips draws any
         done: Optional[Dict[str, Tuple[str, float]]] = (
-            {} if tracer is not None else None
+            {} if tracer is not None and self.transfer_edges else None
         )
         t_loop0 = time.perf_counter()
         stage_s = 0.0
@@ -694,127 +701,135 @@ class DispatchPlan:
             for k, s in self.ext_slots:
                 vals[s] = ext_outputs[k]
         if self.input_slots:
-            t0 = time.perf_counter()
-            for _n, dev, s in self.input_slots:
-                vals[s] = jax.device_put(graph_input, dev)
-                if mem is not None:
-                    mem.alloc(
-                        _n, "input", _array_bytes(vals[s]), "activations"
-                    )
-            stage_s += time.perf_counter() - t0
+            with annotate("stage_input"):
+                t0 = time.perf_counter()
+                for _n, dev, s in self.input_slots:
+                    vals[s] = jax.device_put(graph_input, dev)
+                    if mem is not None:
+                        mem.alloc(
+                            _n, "input", _array_bytes(vals[s]),
+                            "activations",
+                        )
+                t1 = time.perf_counter()
+            stage_s += t1 - t0
             if tracer is not None:
                 tracer.complete(
-                    "stage_input", t0, time.perf_counter(),
-                    track="host", cat="stage", devices=len(self.input_slots),
+                    "stage_input", t0, t1, track="host", cat=CAT_STAGE,
+                    devices=len(self.input_slots),
                 )
 
         tbytes = 0
-        for step in self.steps:
-            per_edge = None
-            if step.xfer_slots:
-                args = list(step.get_args(vals))
-                srcs = step.get_srcs(vals)
-                if step.xfer_bytes is None:
-                    step.xfer_bytes = sum(
-                        _array_bytes(srcs[ui]) for _p, ui in step.xfer_map
-                    )
-                if metrics is not None or mem is not None:
-                    per_edge = [_array_bytes(x) for x in srcs]
-                t0 = time.perf_counter()
-                if step.xfer_avals:
-                    shard, devs = step.xfer_shard, step.xfer_devs
-                    moved = [
-                        _fast_put(av, shard, [x], devs)
-                        for av, x in zip(step.xfer_avals, srcs)
-                    ]
+        t_d0 = time.perf_counter()
+        with annotate("dispatch_loop"):
+            for step in self.steps:
+                per_edge = None
+                if step.xfer_slots:
+                    args = list(step.get_args(vals))
+                    srcs = step.get_srcs(vals)
+                    if step.xfer_bytes is None:
+                        step.xfer_bytes = sum(
+                            _array_bytes(srcs[ui]) for _p, ui in step.xfer_map
+                        )
+                    if metrics is not None or mem is not None:
+                        per_edge = [_array_bytes(x) for x in srcs]
+                    t0 = time.perf_counter()
+                    if step.xfer_avals:
+                        shard, devs = step.xfer_shard, step.xfer_devs
+                        moved = [
+                            _fast_put(av, shard, [x], devs)
+                            for av, x in zip(step.xfer_avals, srcs)
+                        ]
+                    else:
+                        # first (warmup) pass: public path, then cache avals.
+                        # Pytree task outputs (dict-of-grads, cache slabs)
+                        # have no single aval — those steps stay on the
+                        # public path permanently (False sentinel).
+                        moved = jax.device_put(srcs, step.dev)
+                        if step.xfer_avals is None:
+                            step.xfer_avals = (
+                                tuple(m.aval for m in moved)
+                                if all(hasattr(m, "aval") for m in moved)
+                                else False
+                            )
+                    t1 = time.perf_counter()
+                    stage_s += t1 - t0
+                    if tracer is not None:
+                        tracer.complete(
+                            "stage", t0, t1, track=step.node_id, cat="stage",
+                            transfers=len(step.xfer_slots),
+                        )
+                    if metrics is not None:
+                        for ui, src_node in enumerate(step.xfer_src_nodes):
+                            metrics.counter(
+                                f"transfer.bytes.{src_node}->{step.node_id}",
+                                unit="bytes",
+                            ).inc(per_edge[ui])
+                    if mem is not None:
+                        for ui, src in enumerate(step.xfer_src_tids):
+                            mem.alloc(
+                                step.node_id, f"xfer:{src}", per_edge[ui],
+                                "transfers",
+                            )
+                    for pos, ui in step.xfer_map:
+                        args[pos] = moved[ui]
                 else:
-                    # first (warmup) pass: public path, then cache avals.
-                    # Pytree task outputs (dict-of-grads, cache slabs)
-                    # have no single aval — those steps stay on the
-                    # public path permanently (False sentinel).
-                    moved = jax.device_put(srcs, step.dev)
-                    if step.xfer_avals is None:
-                        step.xfer_avals = (
-                            tuple(m.aval for m in moved)
-                            if all(hasattr(m, "aval") for m in moved)
-                            else False
-                        )
-                t1 = time.perf_counter()
-                stage_s += t1 - t0
+                    args = step.get_args(vals)
+                tbytes += step.xfer_bytes
                 if tracer is not None:
-                    tracer.complete(
-                        "stage", t0, t1, track=step.node_id, cat="stage",
-                        transfers=len(step.xfer_slots),
-                    )
-                if metrics is not None:
-                    for ui, src_node in enumerate(step.xfer_src_nodes):
-                        metrics.counter(
-                            f"transfer.bytes.{src_node}->{step.node_id}",
-                            unit="bytes",
-                        ).inc(per_edge[ui])
+                    t_l0 = time.perf_counter()
+                if step.group:
+                    outs = step.fn(step.pd, *args)
+                    for s, o in zip(step.out_slots, outs):
+                        vals[s] = o
+                else:
+                    vals[step.out_slots[0]] = step.fn(step.pd, *args)
                 if mem is not None:
-                    for ui, src in enumerate(step.xfer_src_tids):
+                    # births, then the donation-consumed producers' deaths —
+                    # the exact lifetimes donation_table() documents
+                    for t, s in zip(step.out_tids, step.out_slots):
                         mem.alloc(
-                            step.node_id, f"xfer:{src}", per_edge[ui],
-                            "transfers",
+                            step.node_id, f"out:{t}", _array_bytes(vals[s]),
+                            "activations",
                         )
-                for pos, ui in step.xfer_map:
-                    args[pos] = moved[ui]
-            else:
-                args = step.get_args(vals)
-            tbytes += step.xfer_bytes
-            if tracer is not None:
-                t_l0 = time.perf_counter()
-            if step.group:
-                outs = step.fn(step.pd, *args)
-                for s, o in zip(step.out_slots, outs):
-                    vals[s] = o
-            else:
-                vals[step.out_slots[0]] = step.fn(step.pd, *args)
-            if mem is not None:
-                # births, then the donation-consumed producers' deaths —
-                # the exact lifetimes donation_table() documents
-                for t, s in zip(step.out_tids, step.out_slots):
-                    mem.alloc(
-                        step.node_id, f"out:{t}", _array_bytes(vals[s]),
-                        "activations",
+                    for t in step.donate_tids:
+                        mem.free(step.node_id, f"out:{t}")
+                if tracer is not None:
+                    t_l1 = time.perf_counter()
+                    name = (
+                        step.tids[0] if len(step.tids) == 1
+                        else f"{step.tids[0]}+{len(step.tids) - 1}"
                     )
-                for t in step.donate_tids:
-                    mem.free(step.node_id, f"out:{t}")
-            if tracer is not None:
-                t_l1 = time.perf_counter()
-                name = (
-                    step.tids[0] if len(step.tids) == 1
-                    else f"{step.tids[0]}+{len(step.tids) - 1}"
-                )
-                tracer.complete(
-                    name, t_l0, t_l1, track=step.node_id, cat="launch",
-                    tasks=len(step.tids), edges=step.n_edges,
-                )
-                for t in step.tids:
-                    done[t] = (step.node_id, t_l1)
-                for ui, src in enumerate(step.xfer_src_tids):
-                    src_pt = done.get(src)
-                    if src_pt is not None:
-                        tracer.flow(
-                            "transfer", src_pt[0], src_pt[1],
-                            step.node_id, t_l0, src=src, dst=step.tids[0],
-                        )
-        loop_s = time.perf_counter() - t_loop0
+                    tracer.complete(
+                        name, t_l0, t_l1, track=step.node_id, cat="launch",
+                        tasks=len(step.tids), edges=step.n_edges,
+                    )
+                    if done is not None:
+                        for t in step.tids:
+                            done[t] = (step.node_id, t_l1)
+                        for src in step.xfer_src_tids:
+                            src_pt = done.get(src)
+                            if src_pt is not None:
+                                tracer.flow(
+                                    "transfer", src_pt[0], src_pt[1],
+                                    step.node_id, t_l0, src=src,
+                                    dst=step.tids[0],
+                                )
+        t_d1 = time.perf_counter()
+        loop_s = t_d1 - t_loop0
+        if tracer is not None:
+            # the host-track leaf over the per-launch spans of the device
+            # tracks: with stage_input and fence it tiles the rep
+            tracer.complete(
+                "dispatch_loop", t_d0, t_d1, track="host", cat=CAT_LAUNCH,
+                steps=len(self.steps),
+            )
 
         n_fences = 0
+        fence_s = 0.0
         if fence and self.steps:
-            if tracer is not None:
-                t_f0 = time.perf_counter()
-            n_fences = self._backend._fence_run(
-                {n: vals[s] for n, s in self.fence_slots}
+            n_fences, fence_s = self._backend._timed_fence(
+                {n: vals[s] for n, s in self.fence_slots}, tracer
             )
-            if tracer is not None:
-                tracer.complete(
-                    "fence", t_f0, time.perf_counter(),
-                    track="host", cat="collect",
-                    devices=len(self.fence_slots),
-                )
         final = vals[self.final_slot] if self.final_slot is not None else None
         executed = {t: vals[s] for t, s in self.keep_list}
         return (
@@ -824,5 +839,6 @@ class DispatchPlan:
                 "loop_s": loop_s,
                 "stage_s": stage_s,
                 "launch_s": loop_s - stage_s,
+                "fence_s": fence_s,
             },
         )

@@ -37,7 +37,8 @@ subsystems (planned dispatch, segment fusion, paged decode):
 * :mod:`.health` — the soak doctor's trend gate: leak/degradation
   detectors (HLT001–HLT006) over time series, ``exceeds``-style report.
 
-Everything is opt-in.  Two ways to turn it on:
+Spans, counters and per-request records are opt-in.  Two ways to turn
+them on:
 
 * **Explicit**: pass ``trace=Tracer()`` / ``metrics=MetricsRegistry()``
   to ``DeviceBackend.execute`` (or the paged decode engine), then
@@ -49,9 +50,13 @@ Everything is opt-in.  Two ways to turn it on:
   artifacts, and the ``execute`` CLI exports the trace on exit.
 
 With the env var unset and no explicit objects passed, the ambient
-getters return ``None`` and instrumented hot paths skip all recording
-(``if tracer is not None`` guards — the disabled path stays within the
-<2% planned-dispatch overhead budget).
+getters return ``None`` and the per-launch and per-token paths skip all
+recording (``if tracer is not None`` guards).
+
+One thing is always on: every ``DeviceBackend.execute`` call tiles its
+own wall time into leaf phases (``DeviceReport.dispatch_phases``) and
+observes each once into :func:`process_metrics`, the process-wide
+registry an operator or a benchmark reads without holding the report.
 """
 
 from __future__ import annotations
@@ -105,11 +110,12 @@ from .timeseries import (
     theil_sen_slope,
     validate_timeseries,
 )
-from .trace import HOST_TRACK, Tracer
+from .trace import HOST_TRACK, PhaseClock, Tracer, annotate
 
 _ambient_tracer: Optional[Tracer] = None
 _ambient_metrics: Optional[MetricsRegistry] = None
 _ambient_flight: Optional[FlightRecorder] = None
+_process_metrics = MetricsRegistry()
 
 
 def trace_enabled() -> bool:
@@ -139,6 +145,15 @@ def ambient_metrics() -> Optional[MetricsRegistry]:
     return _ambient_metrics
 
 
+def process_metrics() -> MetricsRegistry:
+    """The process-wide registry that is always on, ``DLS_TRACE`` or not:
+    per ``execute`` call one observation into each ``execute.phase.*_s``
+    histogram and into ``execute.wall_s``.  Nothing per launch or per
+    token is recorded here; the per-edge transfer counters stay behind
+    the explicit / ambient registry."""
+    return _process_metrics
+
+
 def flight_enabled() -> bool:
     """True when ``DLS_FLIGHT`` requests the ambient flight recorder."""
     return env_flag("DLS_FLIGHT")
@@ -158,11 +173,14 @@ def ambient_flight() -> Optional[FlightRecorder]:
 
 
 def reset_ambient() -> None:
-    """Drop the ambient tracer/registry/flight (tests; fresh CLI legs)."""
+    """Drop the ambient tracer/registry/flight and empty the process
+    registry (tests; fresh CLI legs)."""
     global _ambient_tracer, _ambient_metrics, _ambient_flight
+    global _process_metrics
     _ambient_tracer = None
     _ambient_metrics = None
     _ambient_flight = None
+    _process_metrics = MetricsRegistry()
 
 
 __all__ = [
@@ -180,6 +198,7 @@ __all__ = [
     "MemDriftReport",
     "MemoryProfiler",
     "MetricsRegistry",
+    "PhaseClock",
     "RequestLog",
     "RequestRecord",
     "RequestTraceRecorder",
@@ -194,6 +213,7 @@ __all__ = [
     "ambient_flight",
     "ambient_metrics",
     "ambient_tracer",
+    "annotate",
     "attribute_requests",
     "attribute_run",
     "attribute_trace",
@@ -208,6 +228,7 @@ __all__ = [
     "flight_enabled",
     "load_timeseries",
     "merge_snapshots",
+    "process_metrics",
     "report_from_fleet_artifact",
     "report_from_soak_artifact",
     "validate_fleet_health",

@@ -10,21 +10,35 @@ record time; :mod:`..obs.export` renders the list as a Chrome/Perfetto
 
 Design constraints, in order:
 
-* **Zero overhead when off.**  Tracing is opt-in; every instrumented hot
-  path guards with ``if tracer is not None`` and does *no* work
-  otherwise (the <2% planned-dispatch regression budget in ISSUE 4).
-  There is deliberately no no-op tracer object: a None check is cheaper
-  than a dispatched no-op method call, and the call sites stay honest
-  about what runs in the disabled path.
+* **Nothing per launch or per token when off.**  Tracing is opt-in;
+  every per-launch and per-token recording point guards with ``if
+  tracer is not None`` and does *no* work otherwise.  There is
+  deliberately no no-op tracer object: a None check is cheaper than a
+  dispatched no-op method call, and the call sites stay honest about
+  what runs in the disabled path.  What stays on without a tracer is
+  per *call* and per *tick*: the phase clock of ``execute`` (about
+  twenty clock reads, :class:`PhaseClock`) and the profiler annotations
+  below.  The measured cost of an attached tracer is in PERF.md.
 * **Injectable clock.**  ``Tracer(clock=...)`` takes any ``() -> float``
   seconds source; tests drive a fake clock and assert exact span
   nesting/ordering.  Default is ``time.perf_counter`` — the same
   timebase the backend's measured timings use, so profile-mode task
   walls and tracer spans land on one consistent timeline.
 * **Host-side only.**  Spans bound *host* observations (dispatch
-  windows, segment round-trips); device-side truth comes from
-  profile-mode ``block_until_ready`` timings, which callers record via
+  windows, segment round-trips): a span says when the host entered and
+  left a piece of its own code, never when the device ran.  Device-side
+  truth comes from the profiler's device trace, or from profile-mode
+  ``block_until_ready`` timings, which callers record via
   :meth:`Tracer.complete` with explicit timestamps.
+* **The phase boundaries also go to the profiler.**  The leaf phases of
+  ``execute`` (``dispatch_order``, ``place_params``, ``plan_build``,
+  ``warmup``, ``fence_rtt``, ``stage_input``, ``dispatch_loop``,
+  ``fence``, ``report``) and of the serving tick (``admit``,
+  ``prefill_chunk``, ``segment``, ``fold``, ``idle_wait``) are entered
+  as ``jax.profiler.TraceAnnotation("dls/<name>")`` (:func:`annotate`),
+  tracer or not: a profile taken by anyone shows the host phases on the
+  device trace's own clock.  Nothing per launch or per token is
+  annotated.
 
 Track names are free-form strings; by convention ``"host"``
 (:data:`HOST_TRACK`) carries the execute phases and every device node_id
@@ -37,12 +51,15 @@ from __future__ import annotations
 from contextlib import contextmanager
 from typing import Any, Callable, Dict, Iterator, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from .clockutil import resolve_clock
 
 HOST_TRACK = "host"
 
 # event categories (Chrome "cat" field): the execute phase machine plus
 # the decode engine's lifecycle — see docs/OBSERVABILITY.md
+CAT_CALL = "call"           # enclosing spans: ``execute`` and ``rep<r>``
 CAT_SCHEDULE = "schedule"   # dispatch-order linearization
 CAT_PLAN = "plan"           # plan build + warmup compilation
 CAT_STAGE = "stage"         # param placement + transfer staging
@@ -51,6 +68,14 @@ CAT_COLLECT = "collect"     # end-of-run fence + readbacks
 CAT_TASK = "task"           # per-task device spans (profile timings)
 CAT_TRANSFER = "transfer"   # cross-device flow edges
 CAT_DECODE = "decode"       # paged decode engine lifecycle
+
+ANNOTATION_PREFIX = "dls/"
+
+
+def annotate(name: str) -> TraceAnnotation:
+    """``with annotate("fold"):`` enters ``dls/fold`` on the profiler's
+    host timeline; without a profiler session it does nothing."""
+    return TraceAnnotation(ANNOTATION_PREFIX + name)
 
 
 class Tracer:
@@ -186,3 +211,52 @@ class Tracer:
 
     def __len__(self) -> int:
         return len(self.events)
+
+
+class PhaseClock:
+    """Tiles one call's wall time into named leaf phases, always on.
+
+    ``with clock.phase("order_s", "dispatch_order", CAT_SCHEDULE) as args``
+    reads the clock on entry and exit, adds the difference to
+    ``seconds["order_s"]``, enters the profiler annotation
+    ``dls/dispatch_order`` and, with a tracer, records the span on the
+    host track with whatever the body put into ``args``.  Phases do not
+    nest, so ``finish`` can give what no phase covered as ``other_s``:
+    the leaves then sum to the wall of the call by construction.
+    """
+
+    def __init__(
+        self, tracer: Any = None, t0: Optional[float] = None,
+        clock: Optional[Callable[[], float]] = None,
+    ):
+        self.clock: Callable[[], float] = resolve_clock(clock)
+        self.tracer = tracer
+        self.seconds: Dict[str, float] = {}
+        self.t0 = self.clock() if t0 is None else t0
+
+    @contextmanager
+    def phase(
+        self, key: str, span: str, cat: str,
+    ) -> Iterator[Dict[str, Any]]:
+        args: Dict[str, Any] = {}
+        with annotate(span):
+            t0 = self.clock()
+            try:
+                yield args
+            finally:
+                t1 = self.clock()
+                self.seconds[key] = self.seconds.get(key, 0.0) + (t1 - t0)
+                if self.tracer is not None:
+                    self.tracer.complete(
+                        span, t0, t1, track=HOST_TRACK, cat=cat, **args
+                    )
+
+    def finish(self, timed_elsewhere: float = 0.0) -> float:
+        """The wall since ``t0``; what neither a phase nor the seconds
+        ``timed_elsewhere`` (by the callee, under its own names) covered
+        goes to ``seconds["other_s"]``."""
+        wall = self.clock() - self.t0
+        self.seconds["other_s"] = (
+            wall - timed_elsewhere - sum(self.seconds.values())
+        )
+        return wall

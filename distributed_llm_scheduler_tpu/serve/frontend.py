@@ -54,6 +54,7 @@ import numpy as np
 
 from ..obs.reqlog import _percentiles
 from ..obs.slo import SLOPolicy, SLOReport, evaluate_slo
+from ..obs.trace import annotate
 from .loadgen import Arrival, prompt_token_ids
 
 
@@ -291,33 +292,16 @@ class ServingFrontend:
                 rt.shed(req.a.rid, now, cause="shed_deadline")
         self._backlog.clear()
 
+    def _tracer(self):
+        """The engine's span tracer, or None — reached as ``reqtrace``
+        is, behind the same guard."""
+        return getattr(self.engine, "tracer", None)
+
     def _tick(self) -> None:
         now = self.clock()
         rel = now - self.t0
-        # 1. inject arrivals whose deadline has passed
-        rt = self._reqtrace()
-        while self._pending and self._pending[0].t <= rel + 1e-9:
-            a = self._pending.pop(0)
-            req = self._make_req(a)
-            self._reqs[a.rid] = req
-            if rt is not None:
-                # waterfall anchor = ARRIVAL time, matching the serving
-                # row's t_submit; the engine's later submit() for the
-                # same rid is an idempotent no-op on this track
-                rt.submit(
-                    a.rid, self.t0 + a.t, prompt_len=a.prompt_len,
-                    max_new_tokens=a.max_new_tokens,
-                    priority=a.priority,
-                )
-            if self.admission == "fifo":
-                self._submit_to_engine(req)   # admit-all: engine FIFO queues
-            else:
-                self._backlog.append(req)
-        # 2. admission control (slo mode submits exactly what fits NOW)
-        if self.admission == "slo":
-            waves = self._admit_backlog(now)
-        else:
-            waves = 1 if (self.engine._queue and self.engine.free_slots) else 0
+        with annotate("admit"):
+            waves = self._inject_and_admit(now, rel)
         # 3. drive the engine one segment; charge virtual service time.
         #    The wave cost lands BEFORE the engine's admission clock
         #    reads so prefill has nonzero virtual duration.
@@ -346,21 +330,77 @@ class ServingFrontend:
         #    just forward, so a breaching window can roll past a
         #    deferred backlog)
         if not engine_busy and not waves:
-            if self._virtual:
-                if self._pending:
-                    gap = (self._pending[0].t - rel)
-                    self.clock.advance(max(gap, self.tm.idle_s))
-                elif self._backlog:
-                    self.clock.advance(self.tm.idle_s)
+            with annotate("idle_wait"):
+                self._idle_wait(rel)
+
+    def _inject_and_admit(self, now: float, rel: float) -> int:
+        """Steps 1 and 2 of a tick: inject the arrivals that are due,
+        then admission control.  Returns the prefill waves submitted.
+        With a tracer, the ``admit`` span of the decode track, from the
+        tick's own clock read ``now``: it closes before the engine is
+        driven, so before any chunk program is dispatched."""
+        # 1. inject arrivals whose deadline has passed
+        rt = self._reqtrace()
+        queued0 = len(self.engine._queue)
+        while self._pending and self._pending[0].t <= rel + 1e-9:
+            a = self._pending.pop(0)
+            req = self._make_req(a)
+            self._reqs[a.rid] = req
+            if rt is not None:
+                # waterfall anchor = ARRIVAL time, matching the serving
+                # row's t_submit; the engine's later submit() for the
+                # same rid is an idempotent no-op on this track
+                rt.submit(
+                    a.rid, self.t0 + a.t, prompt_len=a.prompt_len,
+                    max_new_tokens=a.max_new_tokens,
+                    priority=a.priority,
+                )
+            if self.admission == "fifo":
+                self._submit_to_engine(req)   # admit-all: engine FIFO queues
             else:
-                # real clock: actually sleep until the next arrival's
-                # deadline (floor keeps the loop from busy-spinning on
-                # an imminent arrival; cap keeps mid-run submit()s and
-                # soak deadlines responsive within 50 ms)
-                wait = 0.001
-                if self._pending:
-                    wait = max(self._pending[0].t - rel, 0.0005)
-                self._sleep(min(wait, 0.05))
+                self._backlog.append(req)
+        # 2. admission control (slo mode submits exactly what fits NOW)
+        if self.admission == "slo":
+            waves = self._admit_backlog(now)
+        else:
+            waves = 1 if (self.engine._queue and self.engine.free_slots) else 0
+        tracer = self._tracer()
+        if tracer is not None:
+            tracer.complete(
+                "admit", now, self.clock(), track="decode", cat="decode",
+                admitted=len(self.engine._queue) - queued0,
+                backlog=len(self._backlog),
+                queue_depth=len(self.engine._queue),
+            )
+        return waves
+
+    def _idle_wait(self, rel: float) -> None:
+        """Step 5 of a tick whose engine is empty: sleep (or move the
+        virtual clock) toward the next arrival.  With a tracer, the
+        ``idle_wait`` span of the decode track."""
+        tracer = self._tracer()
+        t_i0 = self.clock() if tracer is not None else 0.0
+        if self._virtual:
+            if self._pending:
+                gap = (self._pending[0].t - rel)
+                self.clock.advance(max(gap, self.tm.idle_s))
+            elif self._backlog:
+                self.clock.advance(self.tm.idle_s)
+        else:
+            # real clock: actually sleep until the next arrival's
+            # deadline (floor keeps the loop from busy-spinning on
+            # an imminent arrival; cap keeps mid-run submit()s and
+            # soak deadlines responsive within 50 ms)
+            wait = 0.001
+            if self._pending:
+                wait = max(self._pending[0].t - rel, 0.0005)
+            self._sleep(min(wait, 0.05))
+        if tracer is not None:
+            tracer.complete(
+                "idle_wait", t_i0, self.clock(), track="decode",
+                cat="decode", pending=len(self._pending),
+                backlog=len(self._backlog),
+            )
 
     def _make_req(self, a: Arrival) -> _Req:
         """Materialize the serving state for a just-injected arrival

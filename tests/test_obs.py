@@ -540,6 +540,93 @@ def test_execute_traced_output_matches_untraced(monkeypatch):
     assert traced.summary()["attribution"] is att
 
 
+def test_attribution_is_read_not_computed_by_execute(monkeypatch):
+    """The critical-path walk costs several steps' time on a graph of
+    1,500 launches, so ``execute`` only notes where its spans are; the
+    walk runs once, when ``.attribution`` is first read."""
+    from distributed_llm_scheduler_tpu.backends.device import DeviceBackend
+    from distributed_llm_scheduler_tpu.frontend.gpt2_dag import build_gpt2_dag
+    from distributed_llm_scheduler_tpu.models.gpt2 import GPT2Config
+    from distributed_llm_scheduler_tpu.obs import attribution as attr_mod
+
+    calls = []
+    real = attr_mod.attribute_run
+
+    def counting(tracer, window=None, **kw):
+        calls.append(window)
+        return real(tracer, window=window, **kw)
+
+    monkeypatch.setattr(attr_mod, "attribute_run", counting)
+    dag = build_gpt2_dag(GPT2Config.tiny(), batch=1, seq_len=8)
+    params, ids = dag.init_params(), dag.make_inputs()
+    cluster = Cluster.from_jax_devices(jax.devices()[:2])
+    schedule = get_scheduler("roundrobin").schedule(dag.graph, cluster)
+    backend = DeviceBackend(cluster)
+    tr = Tracer()
+    first = backend.execute(dag.graph, schedule, params, ids, trace=tr)
+    second = backend.execute(
+        dag.graph, schedule, params, ids, trace=tr, warmup=False
+    )
+    assert calls == []
+    att = second.attribution
+    assert len(calls) == 1
+    assert second.attribution is att and second.summary()["attribution"] is att
+    assert len(calls) == 1
+    # each report attributes its own call's window of the shared tracer
+    ex = [e for e in tr.events if e["name"] == "execute"]
+    assert calls[0] == (ex[1]["t0"], ex[1]["t1"])
+    assert first.attribution["makespan_s"] != att["makespan_s"]
+    assert calls[1] == (ex[0]["t0"], ex[0]["t1"])
+    assert sum(att["fractions"].values()) == pytest.approx(1.0, abs=1e-6)
+
+
+def test_traced_execute_adds_a_constant_number_of_events_and_leaf_spans():
+    """Twenty calls on one tracer: every call adds the same events, and
+    its host-track leaves tile the ``execute`` span without overlap
+    (``execute`` and ``rep0`` enclose, in a category of their own)."""
+    from distributed_llm_scheduler_tpu.backends.device import DeviceBackend
+    from distributed_llm_scheduler_tpu.frontend.gpt2_dag import build_gpt2_dag
+    from distributed_llm_scheduler_tpu.models.gpt2 import GPT2Config
+    from distributed_llm_scheduler_tpu.obs.trace import CAT_CALL
+
+    dag = build_gpt2_dag(GPT2Config.tiny(), batch=1, seq_len=8)
+    params, ids = dag.init_params(), dag.make_inputs()
+    cluster = Cluster.from_jax_devices(jax.devices()[:2])
+    schedule = get_scheduler("roundrobin").schedule(dag.graph, cluster)
+    backend = DeviceBackend(cluster)
+    backend.execute(dag.graph, schedule, params, ids)
+    tr = Tracer()
+    sizes = []
+    for _ in range(20):
+        rep = backend.execute(
+            dag.graph, schedule, params, ids, trace=tr, warmup=False
+        )
+        sizes.append(len(tr.events))
+    added = {b - a for a, b in zip([0] + sizes, sizes)}
+    assert len(added) == 1 and added.pop() > rep.n_dispatches
+
+    last = tr.events[sizes[-2]:]
+    host = [e for e in last if e["type"] == "span"
+            and e["track"] == HOST_TRACK]
+    enclosing = {e["name"] for e in host if e["cat"] == CAT_CALL}
+    assert enclosing == {"execute", "rep0"}
+    leaves = sorted((e for e in host if e["cat"] != CAT_CALL),
+                    key=lambda e: e["t0"])
+    assert [e["name"] for e in leaves] == [
+        "dispatch_order", "place_params", "plan_build", "fence_rtt",
+        "stage_input", "dispatch_loop", "fence", "report",
+    ]
+    assert all(a["t1"] <= b["t0"] for a, b in zip(leaves, leaves[1:]))
+    ex = next(e for e in host if e["name"] == "execute")
+    assert ex["t0"] <= leaves[0]["t0"] and leaves[-1]["t1"] <= ex["t1"]
+    # the spans are the phase clock's own reads
+    by = {e["name"]: e["t1"] - e["t0"] for e in leaves}
+    ph = rep.dispatch_phases
+    assert by["dispatch_order"] == pytest.approx(ph["order_s"], abs=1e-12)
+    assert by["fence"] == pytest.approx(ph["fence_s"], abs=1e-12)
+    assert by["stage_input"] + by["dispatch_loop"] <= ph["loop_s"]
+
+
 # ---------------------------------------------------------------------------
 # Attribution (run doctor)
 

@@ -450,6 +450,127 @@ def test_chunked_prefill_bitwise_across_chunk_sizes(_engine):
         np.testing.assert_array_equal(whole[k], chunk16[k])
 
 
+class _TickingClock:
+    """Scripted wall clock: every read moves it on by ``dt``, so spans
+    get a length and a strict order; ``sleep`` is the front-end's idle
+    sleep and moves it by what was asked."""
+
+    def __init__(self, dt: float = 1e-4):
+        self.t = 0.0
+        self.dt = dt
+
+    def __call__(self) -> float:
+        self.t += self.dt
+        return self.t
+
+    def sleep(self, seconds: float) -> None:
+        self.t += seconds
+
+
+def test_decode_track_spans_tile_the_tick_without_overlap(_engine):
+    """A served run in chunked mode on a scripted wall clock: the
+    ``decode``-track spans ``admit`` / ``prefill_chunk`` / ``segment`` /
+    ``fold`` / ``idle_wait`` never overlap, a ``fold`` starts where its
+    segment's readback ended, and ``idle_wait`` is drawn exactly on the
+    ticks that find the engine empty."""
+    from distributed_llm_scheduler_tpu.obs.trace import Tracer
+
+    eng, _pool = _engine()
+    clk = _TickingClock()
+    tr = Tracer(clock=clk)
+    eng.rebind_obs(clock=clk, tracer=tr)
+    eng.chunk_tokens = 8
+    busy_ticks, idle_ticks = [], []
+    try:
+        fe = ServingFrontend(
+            eng,
+            [Arrival("a", 0.0, 24, 6), Arrival("b", 0.001, 8, 3),
+             Arrival("c", 0.6, 20, 9), Arrival("d", 1.3, 24, 2)],
+            SLOPolicy(ttft_s=60.0),
+            sleep=lambda s: (idle_ticks.append(fe.ticks), clk.sleep(s)),
+        )
+        step = eng.step_segment
+        eng.step_segment = lambda: (busy_ticks.append(fe.ticks), step())[1]
+        rep = fe.run()
+    finally:
+        del eng.step_segment
+        eng.chunk_tokens = None
+        eng.rebind_obs(clock=VirtualClock())
+    assert rep["completed"] == 4 and rep["pages_leaked"] == 0
+
+    names = ("admit", "prefill_chunk", "segment", "fold", "idle_wait")
+    spans = sorted(
+        (e for e in tr.events if e["type"] == "span"
+         and e["track"] == "decode" and e["name"] in names),
+        key=lambda e: (e["t0"], e["t1"]))
+    assert {e["name"] for e in spans} == set(names)
+    for a, b in zip(spans, spans[1:]):
+        assert a["t1"] <= b["t0"], (a, b)
+    by = {n: [e for e in spans if e["name"] == n] for n in names}
+    # one fold per segment, from the segment's own readback stamp
+    assert ([e["t0"] for e in by["fold"]]
+            == [e["t1"] for e in by["segment"]])
+    # every request's first token comes from its prefill, not a segment
+    assert sum(e["args"]["delivered"] for e in by["fold"]) == 20 - 4
+    assert sum(e["args"]["retired"] for e in by["fold"]) == 4
+    # every tick draws the front-end's admit; a tick that drives the
+    # engine draws the engine's too, and never sleeps
+    assert idle_ticks and not set(idle_ticks) & set(busy_ticks)
+    assert len(idle_ticks) + len(busy_ticks) == fe.ticks
+    assert len(by["idle_wait"]) == len(idle_ticks)
+    assert len(by["admit"]) == fe.ticks + len(busy_ticks)
+    assert sum(e["args"]["admitted"] for e in by["admit"]
+               if "backlog" in e["args"]) == 4
+    assert all(e["t1"] - e["t0"] >= 0.0005 for e in by["idle_wait"])
+
+
+def test_first_token_is_stamped_after_the_last_chunks_readback(_engine):
+    """Chunked mode: the last chunk's result is still on the device when
+    ``_fold_chunked`` gets it, and reading it waits for the chunk.  The
+    first delivery may not be stamped before that wait is over — a
+    stand-in result whose ``int()`` moves the scripted clock shows it."""
+    eng, _pool = _engine()
+    clk = _TickingClock()
+    eng.rebind_obs(clock=clk)
+    eng.chunk_tokens = 8
+    read_at = []
+
+    class Scalar:
+        def __init__(self, real):
+            self.real = real
+
+        def __int__(self):
+            clk.sleep(0.075)          # the chunk program finishes
+            read_at.append(clk.t)
+            return int(self.real)
+
+    class Pending:
+        def __init__(self, real):
+            self.real = real
+
+        def __getitem__(self, i):
+            return Scalar(self.real[i])
+
+    chunk = eng._chunk_prefill
+    eng._chunk_prefill = lambda *a: Pending(chunk(*a))
+    try:
+        rng = np.random.RandomState(3)
+        eng.submit("long", jnp.asarray(
+            rng.randint(1, 50, size=(1, 24)), jnp.int32), 5)
+        out = eng.run()
+        rec = eng.reqlog.get("long")
+        ttft = eng.metrics.histogram("decode.ttft_s").max
+    finally:
+        del eng._chunk_prefill
+        eng.chunk_tokens = None
+        eng.rebind_obs(clock=VirtualClock())
+    assert out["long"].size == 5 and len(read_at) == 1
+    assert rec.t_first_token >= read_at[0]
+    assert rec.deliveries[0] == (rec.t_first_token, 1)
+    assert ttft == pytest.approx(rec.t_first_token - rec.t_submit)
+    assert ttft > 0.075
+
+
 def test_frontend_rejects_bad_config(_engine):
     eng, _pool = _engine()
     arrivals = [Arrival("a", 0.0, 8, 4)]
