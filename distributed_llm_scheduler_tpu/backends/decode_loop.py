@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from ..core.graph import TaskGraph
 from ..core.schedule import Schedule
 from ..frontend.decode_dag import cache_dims
+from ..obs import process_metrics
 from ..obs.trace import annotate
 
 
@@ -235,6 +236,18 @@ def compose_paged_step_fn(
     return step
 
 
+def kv_live_block_share(lengths, rows_per_block: int, capacity: int) -> float:
+    """Share of the page table's blocks that the single-token paged
+    kernel walks for these per-slot ``lengths`` (a host numpy array):
+    slot ``s`` costs ``cdiv(min(L_s, capacity - 1) + 1, rows_per_block)``
+    of its ``cdiv(capacity, rows_per_block)`` blocks, a slot at length 0
+    — one the engine is not decoding — one.  ``rows_per_block`` is the page
+    size times :func:`...ops.attention.paged_block_pages`."""
+    live = lengths.clip(max=capacity - 1) // rows_per_block + 1
+    return float(live.sum()) / (
+        lengths.size * -(-capacity // rows_per_block))
+
+
 def build_paged_decode_loop(
     graph: TaskGraph,
     schedule: Schedule,
@@ -359,7 +372,7 @@ class PagedDecodeEngine:
             attention_impl if attention_impl is not None
             else getattr(graph, "attention_impl", None)
         )
-        from ..ops.attention import resolve_paged_impl
+        from ..ops.attention import paged_block_pages, resolve_paged_impl
 
         n_layers, n_kv, hd = _cd(config)
         # what the decode step's paged attention actually runs at this
@@ -374,6 +387,11 @@ class PagedDecodeEngine:
         )
         self.page_size = pool.page_size
         self.capacity = pages_per_seq * pool.page_size
+        # rows in one block of the paged kernel's walk at this geometry:
+        # what ``decode.kv_live_block_share`` counts live blocks in
+        self.kv_block_rows = pool.page_size * paged_block_pages(
+            pool.page_size, pages_per_seq, n_kv, hd, config.dtype
+        )
         self.seg_steps = seg_steps
         # chunked prefill: prompts longer than this admit in fixed-token
         # chunks co-scheduled with decode segments instead of one whole-
@@ -1818,6 +1836,17 @@ class PagedDecodeEngine:
             if not owed.any():
                 return 0
         self._ensure_exclusive()
+        # how much of the page table the segment's attention will walk,
+        # from the host's own lengths (slots not decoding sit at 0): once
+        # per dispatched segment, into the engine's registry and the
+        # process-wide always-on one
+        share = kv_live_block_share(
+            self.lengths, self.kv_block_rows, self.capacity
+        )
+        for reg in (self.metrics, process_metrics()):
+            reg.histogram(
+                "decode.kv_live_block_share", unit="ratio"
+            ).observe(share)
         with annotate("segment"):
             t_sg0 = self._clock()
             toks, self.pools = self._seg(

@@ -16,11 +16,12 @@ recompilation per block).  Scores/accumulators are float32 for stability;
 inputs/outputs stay in the model dtype (bfloat16 on TPU hits the MXU).
 
 The decode-side sibling is the ragged paged kernel (``_paged_kernel``):
-grid (slot, logical page), where each grid step's K/V block is selected by
-the request's page table through a scalar-prefetch index map — one physical
-page DMAs HBM→VMEM per step, the gathered (S, M, Hkv, hd) view is never
-materialized, and the same online-softmax carry runs across a slot's pages
-(ragged tail and trash pages masked to −inf).  Both paged impls sit behind
+its grid is the list of LIVE (slot, page block) pairs, as long as the
+slots' lengths make it, and each K/V page ref of a step is selected by the
+request's page table through a scalar-prefetch index map — only live
+physical pages DMA HBM→VMEM, the gathered (S, M, Hkv, hd) view is never
+materialized, and the same online-softmax carry runs across a slot's
+blocks (the ragged tail masked to −inf).  Both paged impls sit behind
 :func:`paged_decode_attention`'s ``impl`` switch with the same dispatch
 rules as :func:`mha` (:func:`resolve_attention_impl`).
 
@@ -218,6 +219,11 @@ def resolve_attention_impl(impl: Optional[str], supported) -> str:
     return impl
 
 
+def _sublane_rows(dtype: Any) -> int:
+    """Rows of the dtype's sublane tile: 8 at 32 bits, 16 at 16, 32 at 8."""
+    return {2: 16, 1: 32}.get(jnp.dtype(dtype).itemsize, 8)
+
+
 def paged_kernel_constraints(
     page_size: int,
     head_dim: int,
@@ -233,20 +239,25 @@ def paged_kernel_constraints(
     One source of truth for three consumers: the dispatch (``"auto"``
     takes the gather path when non-empty, an explicit ``"pallas"``
     raises), the DEC005 analysis warning (which quotes these strings
-    verbatim), and the docs.  Each grid step loads one ``(page_size,
-    n_kv_heads, head_dim)`` page; the rules keep ``page_size`` a multiple
-    of the dtype's sublane tile and ``head_dim`` a multiple of 8, i.e.
-    natively aligned tiles (interpret mode has no tiling and skips this
-    check entirely).
+    verbatim), and the docs.  The kernels move K/V one ``(page_size,
+    n_kv_heads, head_dim)`` page at a time — the single-token kernel
+    several such page refs a grid step, a block of
+    :func:`paged_block_pages` pages, the block rule and its VMEM
+    reckoning being stated once over :func:`_paged_kernel`; the rules
+    here keep ``page_size`` a multiple of the dtype's sublane tile and
+    ``head_dim`` a multiple of 8, i.e. natively aligned tiles (interpret
+    mode has no tiling and skips this check entirely).  No rule bounds
+    the table: ``pages_per_seq`` short of a block is one block, and one
+    that is not a multiple of the block has a shorter last block.
 
     They are a conservative PREFERENCE, not a lowering requirement: on
     the v5e (libtpu 0.0.34) the kernels compile and match the gather path
     at geometries these rules reject — f32 page 4, bf16 page 8, head_dim
-    12, a 7-row query chunk (``chip_smoke.py`` kernels probe, PR 21;
-    PERF.md).  Whether misaligned tiles are slower is not measured;
-    ROADMAP S4/D3 settle the rule with the kernels' roofline shares.
+    12, a 7-row query chunk (``chip_smoke.py`` kernels probe, PR 21 and,
+    for the block walk, PR 25; PERF.md).  Whether misaligned tiles are
+    slower is not measured.
     """
-    sublane = {2: 16, 1: 32}.get(jnp.dtype(dtype).itemsize, 8)
+    sublane = _sublane_rows(dtype)
     out = []
     if page_size % sublane:
         out.append(
@@ -348,30 +359,84 @@ def mha(
     return reference_mha(q, k, v, causal=causal, sm_scale=scale)
 
 
-def _paged_kernel(
-    pt_ref, len_ref, q_ref, k_ref, v_ref, kn_ref, vn_ref, o_ref,
-    acc_ref, m_ref, l_ref, *, sm_scale, page_size, groups, has_new,
-):
-    """One (slot, logical page) grid step of the ragged paged kernel.
+# -- single-token paged attention: the block walk ---------------------------
+#
+# One grid step covers a BLOCK of ``pages_per_block`` consecutive logical
+# pages of one slot.  The rule (stated once, here; the engine's
+# ``decode.kv_live_block_share`` counter and the tests read it through
+# :func:`paged_block_pages`): a block is as many pages as keep the
+# pipeline's K and V page buffers (2 pools x 2 buffers a page) inside
+# ``_PAGED_BUFFER_BYTES`` of VMEM at the pool's dtype, a page counted as
+# the tile-padded bytes it occupies there (heads padded to the dtype's
+# sublane tile, head_dim to 128 lanes), and never more than the table
+# holds — a table shorter than one block IS one block.  At GPT-2 XL's
+# serving geometry (page 16, 25 heads of 64, bf16) a page is 128 KiB, so a
+# block is 4 pages = 64 rows; the narrowest page the layout allows (one
+# sublane tile of heads, 128 lanes) gives 128 rows.  Compute is one page
+# at a time, so the f32 working set is a page's (K, V, scores), not a
+# block's, and the 16 MiB default VMEM scope holds buffers and all.  The
+# budget is the v5e's measured optimum (PERF.md section 6, PR 25): every
+# slot costs at least one step of one page (~0.9 us), a live page ~0.45
+# us, and a step ~0.03 us for each page ref it carries whether the page
+# is live or not — so budgets of 1 / 2 / 4 / 8 MiB read 126 / 128 / 138 /
+# 152 us a call at the chat cell's load and 1.00-1.02 ms at a full table.
+_PAGED_BUFFER_BYTES = 2 << 20
 
-    The grid walks slot-major / page-minor, so the online-softmax carry
-    (``acc``/``m``/``l`` VMEM scratch, persistent across grid steps) is
-    initialized at a slot's first page and folded into ``o_ref`` at its
-    last.  ``k_ref``/``v_ref`` hold ONE physical page — the BlockSpec
-    index map reads the scalar-prefetched page table, so the DMA engine
-    fetches exactly ``page_table[s, j]`` and the gathered view never
-    exists in HBM.  Masking: global row position ``j*page_size + r`` must
-    be ``<= lengths[s]`` — the same comparison that masks the ragged tail
-    also zeroes every trash-page row (a live sequence's length never
-    reaches into an unallocated page).  ``has_new`` statically compiles
-    in the write-then-attend insert: the page containing position
-    ``lengths[s]`` gets this step's K/V row substituted before the scores
-    (clamped to the last row like the gather path's
-    ``dynamic_update_slice``).
+
+def paged_block_pages(
+    page_size: int, pages_per_seq: int, n_kv_heads: int, head_dim: int,
+    dtype: Any,
+) -> int:
+    """Pages in one block of the single-token paged kernel's walk, from
+    what the call can observe (the rule is in the comment above).
+    ``page_size *`` this is ``rows_per_block``: slot ``s`` costs
+    ``cdiv(min(L_s, capacity - 1) + 1, rows_per_block)`` live blocks."""
+    sublane = _sublane_rows(dtype)
+    page_bytes = (
+        page_size * -(-n_kv_heads // sublane) * sublane
+        * -(-head_dim // 128) * 128 * jnp.dtype(dtype).itemsize
+    )
+    return max(1, min(pages_per_seq, _PAGED_BUFFER_BYTES // (4 * page_bytes)))
+
+
+def _paged_kernel(
+    slot_ref, block_ref, fetch_ref, len_ref, q_ref, kn_ref, vn_ref, *refs,
+    sm_scale, page_size, pages_per_seq, pages_per_block, groups, has_new,
+):
+    """One LIVE (slot, page block) of the ragged paged kernel.
+
+    The grid is the list of live blocks, slot-major, and as long as that
+    list (:func:`_paged_flash` works it out from the lengths): step ``t``
+    is block ``block_ref[t]`` of slot ``slot_ref[t]``.  ``refs`` holds
+    ``pages_per_block`` K page refs, as many V page refs, the output and
+    the online-softmax scratch.  Each page ref is ONE physical page: its
+    BlockSpec index map reads ``fetch_ref``, so the DMA engine fetches
+    exactly the slot's live pages and the gathered view never exists in
+    HBM.  Slot ``s`` attends rows ``0 .. last`` with ``last =
+    min(lengths[s], capacity - 1)``; its live pages are ``0 .. last //
+    page_size`` and its live blocks the ``cdiv`` of that.  A block past
+    it is not in the list, and inside the last live block a page past
+    ``last`` is neither fetched nor computed, so a slot at length 0 —
+    every slot the engine is not decoding — costs one step of one page.
+
+    The online-softmax carry (``acc``/``m``/``l`` VMEM scratch,
+    persistent across grid steps) is initialized at a slot's first block
+    and folded into the output at its last live one.  Only the page
+    holding row ``last`` is ragged: there rows past ``lengths[s]`` get a
+    −inf score and a zeroed V row (whatever they hold, NaN included,
+    reaches nothing), and ``has_new`` statically compiles in the
+    write-then-attend insert — this step's K/V row substituted at
+    ``last`` before the scores (clamped to the capacity's last row like
+    the gather path's ``dynamic_update_slice``).  Pages before it are
+    wholly live and take the plain path.
     """
-    s_idx = pl.program_id(0)
-    j = pl.program_id(1)
-    n_j = pl.num_programs(1)
+    del fetch_ref  # read by the page BlockSpecs' index maps only
+    ppb = pages_per_block
+    k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
+    o_ref, acc_ref, m_ref, l_ref = refs[2 * ppb:]
+    t = pl.program_id(0)
+    s_idx = slot_ref[t]
+    j = block_ref[t]
 
     @pl.when(j == 0)
     def _init():
@@ -380,44 +445,59 @@ def _paged_kernel(
         l_ref[...] = jnp.zeros_like(l_ref)
 
     L = len_ref[s_idx]
+    last = jnp.minimum(L, pages_per_seq * page_size - 1)
+    last_page = last // page_size
     hd = q_ref.shape[-1]
-    Hkv = k_ref.shape[2]
-    q = (q_ref[0].astype(jnp.float32) * sm_scale).reshape(Hkv, groups, hd)
-    k = k_ref[0].astype(jnp.float32)  # (page_size, Hkv, hd)
-    v = v_ref[0].astype(jnp.float32)
-    if has_new:
-        # insert this step's row at position L (clamped to the capacity's
-        # last row — dynamic_update_slice semantics, gather-path parity)
-        capacity = n_j * page_size
-        ins = jnp.minimum(L, capacity - 1) - j * page_size
-        sel = (
-            jax.lax.broadcasted_iota(jnp.int32, (page_size, 1, 1), 0) == ins
-        )
-        k = jnp.where(sel, kn_ref[0].astype(jnp.float32)[None], k)
-        v = jnp.where(sel, vn_ref[0].astype(jnp.float32)[None], v)
-    # scores (Hkv, page_size, G): K @ q, the gather path's orientation
-    s = jax.lax.dot_general(
-        k, q, (((2,), (2,)), ((1,), (0,))),
-        preferred_element_type=jnp.float32,
-    )
-    pos = (
-        jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * page_size
-    )
-    s = jnp.where(pos <= L, s, _NEG_INF)
-    # position 0 is unmasked for every slot, so after page 0 the running
-    # max is a real (finite) score and the exp() arguments stay finite
-    m_prev = m_ref[...]                       # (Hkv, G)
-    m_new = jnp.maximum(m_prev, s.max(axis=1))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new[:, None, :])        # (Hkv, page_size, G)
-    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
-    acc_ref[...] = acc_ref[...] * alpha[:, :, None] + jax.lax.dot_general(
-        p, v, (((1,), (0,)), ((0,), (1,))),
-        preferred_element_type=jnp.float32,
-    )  # (Hkv, G, hd)
-    m_ref[...] = m_new
+    Hkv = k_refs[0].shape[2]
 
-    @pl.when(j == n_j - 1)
+    def attend(page, k_ref, v_ref, ragged):
+        q = (q_ref[0].astype(jnp.float32) * sm_scale).reshape(
+            Hkv, groups, hd)
+        k = k_ref[0].astype(jnp.float32)  # (page_size, Hkv, hd)
+        v = v_ref[0].astype(jnp.float32)
+        if ragged:
+            row = jax.lax.broadcasted_iota(
+                jnp.int32, (page_size, 1, 1), 0) + page * page_size
+            if has_new:
+                sel = row == last
+                k = jnp.where(sel, kn_ref[0].astype(jnp.float32)[None], k)
+                v = jnp.where(sel, vn_ref[0].astype(jnp.float32)[None], v)
+            v = jnp.where(row <= L, v, 0.0)
+        # scores (Hkv, page_size, G): K @ q, the gather path's orientation
+        s = jax.lax.dot_general(
+            k, q, (((2,), (2,)), ((1,), (0,))),
+            preferred_element_type=jnp.float32,
+        )
+        if ragged:
+            pos = (
+                jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+                + page * page_size
+            )
+            s = jnp.where(pos <= L, s, _NEG_INF)
+        # position 0 is unmasked for every slot, so after page 0 the
+        # running max is a real (finite) score and the exp() arguments
+        # stay finite
+        m_prev = m_ref[...]                       # (Hkv, G)
+        m_new = jnp.maximum(m_prev, s.max(axis=1))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new[:, None, :])        # (Hkv, page_size, G)
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
+        acc_ref[...] = acc_ref[...] * alpha[:, :, None] + (
+            jax.lax.dot_general(
+                p, v, (((1,), (0,)), ((0,), (1,))),
+                preferred_element_type=jnp.float32,
+            )
+        )  # (Hkv, G, hd)
+        m_ref[...] = m_new
+
+    for i in range(ppb):
+        page = j * ppb + i
+        pl.when(page < last_page)(
+            functools.partial(attend, page, k_refs[i], v_refs[i], False))
+        pl.when(page == last_page)(
+            functools.partial(attend, page, k_refs[i], v_refs[i], True))
+
+    @pl.when(j == last_page // ppb)
     def _finalize():
         out = acc_ref[...] / l_ref[...][:, :, None]
         o_ref[0] = out.reshape(Hkv * groups, hd).astype(o_ref.dtype)
@@ -430,18 +510,34 @@ def _paged_flash(
     q, k_pool, v_pool, page_table, lengths, k_new, v_new, *,
     sm_scale, has_new, interpret,
 ):
-    """Fused ragged paged attention: page-table-directed block loads.
+    """Fused ragged paged attention whose work follows the live pages.
 
-    Grid (slots, pages_per_seq); the page table and lengths ride as
-    scalar-prefetch operands so the K/V BlockSpec index maps can point
-    each grid step's DMA at the slot's physical page.  Per grid step the
-    only HBM traffic is one (page_size, Hkv, hd) page per pool — the
-    dense gather's (S, M, Hkv, hd) intermediate never exists.
+    The grid is DYNAMIC: one step per live page block, ``sum_s
+    cdiv(min(L_s, capacity - 1) + 1, rows_per_block)`` of them
+    (:func:`paged_block_pages`), listed slot-major in three small tables
+    that ride as scalar-prefetch operands beside the lengths — the slot
+    and the block of step ``t``, and ``fetch[t, i]``, the physical page
+    the block's ``i``-th page ref holds.  Each pool is passed once per
+    page of a block, every pass a one-page BlockSpec whose index map
+    reads ``fetch``: the table's page while the logical page is live,
+    physical page 0 past the slot's last row (any fixed page would do —
+    a block index that repeats from one grid step to the next is not
+    fetched again, and the kernel never reads it).  HBM traffic, compute
+    and grid steps are proportional to the rows the slots hold, not to
+    the table's capacity; the dense gather's (S, M, Hkv, hd)
+    intermediate never exists; the step count is data like the lengths,
+    so no length, admission or retirement recompiles.
+
+    Why the page refs and not a hand-written DMA loop over the live
+    pages: the v5e compiler takes a page out of a pool in whole tiles
+    only (a slice of 25 heads of 64 is refused), and padding the pools to
+    the tile costs a pass over both for every call.
     """
     S, Hq, _, hd = q.shape
     _, page_size, Hkv, _ = k_pool.shape
     G = Hq // Hkv
     ppseq = page_table.shape[1]
+    ppb = paged_block_pages(page_size, ppseq, Hkv, hd, k_pool.dtype)
     q3 = q.reshape(S, Hq, hd)
     if has_new:
         kn = k_new.reshape(S, Hkv, hd)
@@ -449,25 +545,44 @@ def _paged_flash(
     else:  # zero placeholders keep the arity static; kernel never reads
         kn = jnp.zeros((S, Hkv, hd), k_pool.dtype)
         vn = jnp.zeros((S, Hkv, hd), v_pool.dtype)
+
+    # the live blocks, slot-major: step t < n_live is block block_of[t]
+    # of slot slot_of[t]; the tables are as long as the table's capacity
+    # in blocks, the grid only as long as the list
+    lengths = lengths.astype(jnp.int32)
+    last_page = jnp.minimum(lengths, ppseq * page_size - 1) // page_size
+    blocks = last_page // ppb + 1                       # live, per slot
+    ends = jnp.cumsum(blocks)
+    steps = jnp.arange(S * -(-ppseq // ppb), dtype=jnp.int32)
+    slot_of = jnp.minimum(
+        (steps[:, None] >= ends[None, :]).sum(axis=1, dtype=jnp.int32),
+        S - 1)
+    block_of = steps - (ends - blocks)[slot_of]
+    page = block_of[:, None] * ppb + jnp.arange(ppb, dtype=jnp.int32)
+    fetch = jnp.where(
+        page <= last_page[slot_of][:, None],
+        page_table.astype(jnp.int32)[
+            slot_of[:, None], jnp.minimum(page, ppseq - 1)],
+        0,
+    ).reshape(-1)
+
+    def page_spec(i):
+        return pl.BlockSpec(
+            (1, page_size, Hkv, hd),
+            lambda t, slot, blk, fetch, ln: (fetch[t * ppb + i], 0, 0, 0),
+        )
+
+    def slot_spec(heads):
+        return pl.BlockSpec(
+            (1, heads, hd), lambda t, slot, blk, fetch, ln: (slot[t], 0, 0))
+
+    pages = [page_spec(i) for i in range(ppb)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(S, ppseq),
-        in_specs=[
-            pl.BlockSpec((1, Hq, hd), lambda s, j, pt, ln: (s, 0, 0)),
-            pl.BlockSpec(
-                (1, page_size, Hkv, hd),
-                lambda s, j, pt, ln: (pt[s, j], 0, 0, 0),
-            ),
-            pl.BlockSpec(
-                (1, page_size, Hkv, hd),
-                lambda s, j, pt, ln: (pt[s, j], 0, 0, 0),
-            ),
-            pl.BlockSpec((1, Hkv, hd), lambda s, j, pt, ln: (s, 0, 0)),
-            pl.BlockSpec((1, Hkv, hd), lambda s, j, pt, ln: (s, 0, 0)),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, Hq, hd), lambda s, j, pt, ln: (s, 0, 0)
-        ),
+        num_scalar_prefetch=4,
+        grid=(ends[-1],),
+        in_specs=[slot_spec(Hq), slot_spec(Hkv), slot_spec(Hkv)]
+        + pages + pages,
+        out_specs=slot_spec(Hq),
         scratch_shapes=[
             pltpu.VMEM((Hkv, G, hd), jnp.float32),
             pltpu.VMEM((Hkv, G), jnp.float32),
@@ -477,14 +592,15 @@ def _paged_flash(
     out = pl.pallas_call(
         functools.partial(
             _paged_kernel, sm_scale=sm_scale, page_size=page_size,
-            groups=G, has_new=has_new,
+            pages_per_seq=ppseq, pages_per_block=ppb, groups=G,
+            has_new=has_new,
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, Hq, hd), q.dtype),
         interpret=interpret,
     )(
-        page_table.astype(jnp.int32), lengths.astype(jnp.int32),
-        q3, k_pool, v_pool, kn, vn,
+        slot_of, block_of, fetch, lengths,
+        q3, kn, vn, *([k_pool] * ppb), *([v_pool] * ppb),
     )
     return out.reshape(S, Hq, 1, hd)
 
@@ -568,9 +684,11 @@ def _paged_flash_ragged(
 ):
     """Fused ragged multi-token-q paged attention (prefill chunks).
 
-    Same (slots, pages_per_seq) grid and page-table-directed block loads
-    as :func:`_paged_flash`, with a (1, Hq, Tn, hd) query block per slot
-    and per-slot ``q_lens`` as a third scalar-prefetch operand.  No
+    A static (slots, pages_per_seq) grid, one table-directed page load
+    a step whatever the lengths (the walk :func:`_paged_flash` had before
+    it followed the live blocks; no serving path runs this kernel), with
+    a (1, Hq, Tn, hd) query block per slot and per-slot ``q_lens`` as a
+    third scalar-prefetch operand.  No
     in-kernel insert: chunk K/V rows are scattered into the pool before
     the call (write-then-attend at chunk granularity).
     """

@@ -22,6 +22,7 @@ import pytest
 from distributed_llm_scheduler_tpu import Cluster, get_scheduler
 from distributed_llm_scheduler_tpu.models.kv_pages import TRASH_PAGE, PagePool
 from distributed_llm_scheduler_tpu.ops.attention import (
+    paged_block_pages,
     paged_decode_attention,
     paged_kernel_constraints,
     paged_pallas_supported,
@@ -61,6 +62,24 @@ FIXTURES = [
     ("capacity_minus_one", 2, 4, 2, 8, 16, 2, [31, 31], True),
     ("small_pages_interpret", 3, 4, 2, 8, 4, 4, [0, 5, 15], True),
 ]
+
+# The block walk (PR 25).  At page 16, 2 KV heads of 8 (or 16), float32, a
+# page is 64 KiB in VMEM and a block 8 pages = 128 rows
+# (``test_block_rule`` pins that), so a 20-page table is 3 blocks of 8, 8
+# and 4 pages: lengths at rows_per_block - 1 / = / + 1, dead slots
+# (L = 0) between live ones, L = capacity - 1 with the insert, GQA.
+BLOCK_FIXTURES = [
+    ("block_straddle_below_at_above", 3, 4, 2, 8, 16, 20,
+     [127, 128, 129], True),
+    ("block_dead_slots_between_live", 6, 4, 2, 8, 16, 20,
+     [0, 127, 0, 128, 0, 300], True),
+    ("block_table_not_multiple_capacity_minus_one", 4, 4, 2, 8, 16, 20,
+     [319, 0, 256, 255], True),
+    ("block_gqa_4to1", 3, 8, 2, 16, 16, 20, [129, 0, 319], True),
+    ("block_no_insert", 3, 4, 2, 8, 16, 20, [128, 0, 319], False),
+    ("block_past_capacity_clamps", 2, 4, 2, 8, 16, 20, [320, 1], True),
+]
+FIXTURES += BLOCK_FIXTURES
 
 
 @pytest.mark.parametrize(
@@ -107,6 +126,67 @@ def test_kernel_masks_poisoned_trash_page():
             q, k_pool, v_pool, pt, L, hd ** -0.5, impl="pallas_interpret"
         ))
     np.testing.assert_array_equal(np.asarray(outs[0]), np.asarray(outs[1]))
+
+
+def test_block_rule():
+    """``paged_block_pages``: as many pages as keep the K and V double
+    buffers inside the VMEM budget at the tile-padded page, never more
+    than the table holds; a short table is one block."""
+    # the block fixtures above rest on this geometry: 3 blocks of 8, 8, 4
+    assert paged_block_pages(16, 20, 2, 8, jnp.float32) == 8
+    assert paged_block_pages(16, 20, 2, 16, jnp.float32) == 8
+    # GPT-2 XL serving: page 16, 25 heads of 64, bf16 -> a 128 KiB page
+    # (25 -> 32 heads, 64 -> 128 lanes), 4 to a block, 16 blocks a slot
+    assert paged_block_pages(16, 64, 25, 64, jnp.bfloat16) == 4
+    # the tiny serving geometries: the whole table is one block
+    for ppseq in (1, 2, 4, 8):
+        assert paged_block_pages(4, ppseq, 4, 16, jnp.float32) == ppseq
+        assert paged_block_pages(8, ppseq, 12, 64, jnp.bfloat16) == ppseq
+    # a page wider than the budget still makes a block of one
+    assert paged_block_pages(512, 4, 64, 256, jnp.float32) == 1
+
+
+@pytest.mark.parametrize("past", ["last_live_block", "last_live_page"])
+def test_kernel_never_touches_dead_blocks(past):
+    """The dead-block witness: every page of the table is a real page of
+    its own (no trash page), and every page that lies wholly past a
+    slot's last live block — or, stricter, past its last live page — is
+    filled with NaN.  A block that was computed and masked away would
+    give 0 * NaN; the output must stay finite and bitwise equal."""
+    S, Hq, Hkv, hd, ps, ppseq = 4, 4, 2, 8, 16, 20
+    ppb = paged_block_pages(ps, ppseq, Hkv, hd, jnp.float32)
+    lengths = [0, 130, 127, 319]
+    rng = np.random.RandomState(9)
+    n_pages = S * ppseq + 1
+    k_pool = rng.randn(n_pages, ps, Hkv, hd).astype(np.float32)
+    v_pool = rng.randn(n_pages, ps, Hkv, hd).astype(np.float32)
+    pt = 1 + np.arange(S * ppseq, dtype=np.int32).reshape(S, ppseq)
+    k_nan, v_nan = k_pool.copy(), v_pool.copy()
+    k_nan[TRASH_PAGE] = v_nan[TRASH_PAGE] = np.nan
+    n_dead = 0
+    for s, L in enumerate(lengths):
+        last_page = min(L, ppseq * ps - 1) // ps
+        first_dead = (
+            (last_page // ppb + 1) * ppb if past == "last_live_block"
+            else last_page + 1
+        )
+        for j in range(first_dead, ppseq):
+            k_nan[pt[s, j]] = v_nan[pt[s, j]] = np.nan
+            n_dead += 1
+    assert n_dead > S  # the witness has something to witness
+    q = jnp.asarray(rng.randn(S, Hq, 1, hd), jnp.float32)
+    kn = jnp.asarray(rng.randn(S, Hkv, 1, hd), jnp.float32)
+    vn = jnp.asarray(rng.randn(S, Hkv, 1, hd), jnp.float32)
+    outs = [
+        np.asarray(paged_decode_attention(
+            q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(pt),
+            jnp.asarray(lengths, jnp.int32), hd ** -0.5,
+            k_new=kn, v_new=vn, impl="pallas_interpret",
+        ))
+        for kp, vp in ((k_pool, v_pool), (k_nan, v_nan))
+    ]
+    assert np.isfinite(outs[1]).all()
+    np.testing.assert_array_equal(outs[0], outs[1])
 
 
 # -- ragged multi-token-q (chunked prefill) ----------------------------------
