@@ -615,6 +615,59 @@ def phase_kernels(meter: CompileMeter, interpret: bool = False,
             lambda: A.reference_mha(q, k, v, causal=True, sm_scale=scale),
         )
 
+    def latent_cases():
+        """The Xing4.0 block's three kernels (``models/xing4.py``,
+        ``ops/attention._mla_paged_flash``) compiled at a tiny aligned
+        geometry against their gather / XLA paths: whether they start."""
+        from distributed_llm_scheduler_tpu.models import xing4 as X
+
+        rng = np.random.RandomState(8)
+        dt = jnp.bfloat16
+
+        def arr(*shape, scale=1.0, dtype=dt):
+            return jnp.asarray(scale * rng.standard_normal(shape), dtype)
+
+        H, W, rank, lps, lpp = 4, 256, 128, 16, 4
+        lengths = (0, 15, 16, 41)
+        q, pool = arr(S, H, W, scale=0.1), arr(S * lpp + 1, lps, W)
+        new = arr(S, W)
+        table = jnp.asarray(_page_table(np.asarray(lengths) + 1, lps, lpp))
+        lens = jnp.asarray(lengths, jnp.int32)
+        h, I, E, k, N = 256, 512, 8, 2, 12
+        x = arr(N, h)
+        idx = jnp.asarray(rng.randint(0, E, size=(N, k)), jnp.int32)
+        gate = arr(N, k, dtype=jnp.float32)
+        gu, dw = arr(E, 2 * I, h, scale=0.05), arr(E, I, h, scale=0.05)
+        kern = "pallas_interpret" if interpret else "pallas"
+        cfg = X.Xing4Config.tiny(hidden_size=h, dtype=dt)
+        p = {"hca_phi": arr(24, 4 * h, scale=0.02, dtype=jnp.float32),
+             "hca_alpha": jnp.full((3,), 0.5, jnp.float32),
+             "hca_b": arr(24, dtype=jnp.float32)}
+        streams = arr(N, 4, h)
+        return [
+            _kernel_case(
+                "mla_paged_flash_ps16_bf16",
+                lambda: A._mla_paged_flash(
+                    q, pool, table, lens, new, rank=rank, has_new=True,
+                    interpret=interpret),
+                lambda: A.mla_paged_decode_attention(
+                    q, pool, table, lens, rank, new_row=new, impl="xla")),
+            _kernel_case(
+                "moe_experts_bf16",
+                lambda: X._moe_experts(x, idx, gate, gu, dw, impl=kern)[0],
+                # float32 operands: at "highest" the v5e compiler refuses
+                # ragged_dot's own kernel on bf16 ("Bad lhs type", PR 27)
+                lambda: X._moe_experts(
+                    x.astype(jnp.float32), idx, gate, gu.astype(jnp.float32),
+                    dw.astype(jnp.float32), impl="xla")[0]),
+            _kernel_case(
+                "hc_maps_bf16",
+                lambda: jnp.concatenate([m.reshape(N, -1) for m in X.hc_maps(
+                    streams, p, "hca", cfg, kern)], -1),
+                lambda: jnp.concatenate([m.reshape(N, -1) for m in X.hc_maps(
+                    streams, p, "hca", cfg, "xla")], -1)),
+        ]
+
     with timed(meter, ph):
         ph["cases"] = [
             flash_case(f"flash_mha_T{flash_T}", flash_T),
@@ -627,7 +680,7 @@ def phase_kernels(meter: CompileMeter, interpret: bool = False,
             paged_case("paged_flash_ps8_hd12_f32", hd=12, Hq=4, Hkv=2),
             ragged_case("paged_flash_ragged_ps8_q7_f32", q_tokens=7,
                         q_lens=(7, 7, 5, 1)),
-        ]
+        ] + latent_cases()
     for c in ph["cases"] + ph["probe"]:
         log(f"kernel[{c['name']}]: ok={c['ok']} "
             f"max|d|={c.get('max_abs_diff')} {c.get('error', '')}")

@@ -1584,8 +1584,8 @@ def cmd_serve(args) -> int:
         print(f"serve: trace -> {args.save_trace}", file=sys.stderr)
 
     cfg = _config_from(args)
-    if _weights_family(cfg.model) != "gpt2":
-        print("serve: needs a gpt2-family model (paged decode)",
+    if not cfg.model.startswith(("gpt2", "xing4")):
+        print("serve: needs a gpt2- or xing4-family model (paged decode)",
               file=sys.stderr)
         return 2
     slots, ps, n_pages, ppseq = 4, 8, 13, 4

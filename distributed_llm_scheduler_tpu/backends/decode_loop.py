@@ -30,7 +30,7 @@ import jax.numpy as jnp
 
 from ..core.graph import TaskGraph
 from ..core.schedule import Schedule
-from ..frontend.decode_dag import cache_dims
+from ..frontend.decode_dag import cache_dims, cache_spec
 from ..obs import process_metrics
 from ..obs.trace import annotate
 
@@ -194,17 +194,23 @@ def compose_paged_step_fn(
     mask: inactive slots (retired or not yet admitted) write the trash
     page, so one compiled step serves every admission/retirement state.
 
+    The cache is whatever :func:`...frontend.decode_dag.cache_spec`
+    says the family keeps (K and V pools, or one latent pool): layer
+    ``i``'s task emits ``{kind}_new`` for each pool kind.  Layer tasks
+    that emit ``stats`` (an expert layer's routing counts) have them
+    stacked, layer-major.
+
     Returns ``step(weights, pools, page_table, ids, lengths, active)
-    -> (logits, new_pools)``.
+    -> (logits, new_pools, stats or None)``.
     """
-    from ..models.kv_pages import write_token_kv
+    from ..models.kv_pages import write_token_rows
 
     order = _placed_order(graph, schedule)
     sink = [tid for tid in order if not graph.dependents(tid)][0]
-    n_layers, _, _ = cache_dims(config)
+    spec = cache_spec(config)
 
     def step(weights, pools, page_table, ids, lengths, active):
-        inputs = {"ids": ids, "lengths": lengths}
+        inputs = {"ids": ids, "lengths": lengths, "active": active}
         outs: Dict[str, Any] = {}
         for tid in order:
             task = graph[tid]
@@ -224,14 +230,18 @@ def compose_paged_step_fn(
             outs[tid] = task.fn(p, *args)
         logits = outs[sink]
         new_pools = dict(pools)
-        for i in range(n_layers):
+        stats = []
+        for i in range(spec.n_layers):
             o = outs[f"layer_{i}"]
-            for kind in ("k", "v"):
-                new_pools[f"cache_{kind}_{i}"] = write_token_kv(
-                    new_pools[f"cache_{kind}_{i}"], o[f"{kind}_new"],
+            for kind in spec.kinds:
+                new_pools[f"cache_{kind}_{i}"] = write_token_rows(
+                    new_pools[f"cache_{kind}_{i}"],
+                    spec.step_rows(o[f"{kind}_new"]),
                     page_table, lengths, active,
                 )
-        return logits, new_pools
+            if "stats" in o:
+                stats.append(o["stats"])
+        return logits, new_pools, (jnp.stack(stats) if stats else None)
 
     return step
 
@@ -258,7 +268,7 @@ def build_paged_decode_loop(
     page pools donated.
 
     ``seg(weights, pools, page_table, lengths, cur_tok, remaining) ->
-    (tokens, new_pools)`` where ``cur_tok`` is each slot's (S, 1)
+    (tokens, new_pools[, stats])`` where ``cur_tok`` is each slot's (S, 1)
     current token, ``remaining`` the (S,) int32 decode steps each slot
     still owes, and ``tokens`` the (S, steps) greedy continuation (rows
     past a slot's ``remaining`` are garbage — the caller truncates).
@@ -281,7 +291,7 @@ def build_paged_decode_loop(
         def body(carry, _):
             pools, lengths, cur_tok, remaining = carry
             active = remaining > 0
-            logits, pools = step(
+            logits, pools, stats = step(
                 weights, pools, page_table, cur_tok, lengths, active
             )
             nxt = jnp.argmax(
@@ -290,15 +300,20 @@ def build_paged_decode_loop(
             cur_tok = jnp.where(active[:, None], nxt, cur_tok)
             lengths = lengths + active.astype(jnp.int32)
             remaining = jnp.maximum(remaining - 1, 0)
-            return (pools, lengths, cur_tok, remaining), nxt[:, 0]
+            return (pools, lengths, cur_tok, remaining), (nxt[:, 0], stats)
 
-        (pools2, _, _, _), toks = jax.lax.scan(
+        (pools2, _, _, _), (toks, stats) = jax.lax.scan(
             body, (pools, lengths, cur_tok, remaining), None, length=steps
         )
         # slot state is NOT returned: the host reconstructs lengths /
         # cur_tok / remaining from ``toks`` exactly (they're deterministic
-        # functions of the emitted tokens), saving per-segment readbacks
-        return toks.T, pools2
+        # functions of the emitted tokens), saving per-segment readbacks.
+        # A family whose layers count something on the device (expert
+        # routing) gets the (steps, layers, ...) counts as a third output,
+        # read back with the tokens.
+        if stats is None:
+            return toks.T, pools2
+        return toks.T, pools2, stats
 
     return jax.jit(seg, donate_argnums=(1,))
 
@@ -344,8 +359,7 @@ class PagedDecodeEngine:
     ):
         import numpy as np
 
-        from ..frontend.decode_dag import cache_dims as _cd
-        from ..models.kv_pages import TRASH_PAGE, init_paged_kv
+        from ..models.kv_pages import TRASH_PAGE
         from ..obs import (
             MetricsRegistry,
             RequestLog,
@@ -372,26 +386,44 @@ class PagedDecodeEngine:
             attention_impl if attention_impl is not None
             else getattr(graph, "attention_impl", None)
         )
-        from ..ops.attention import paged_block_pages, resolve_paged_impl
+        from ..ops.attention import (
+            latent_block_pages,
+            paged_block_pages,
+            resolve_mla_paged_impl,
+            resolve_paged_impl,
+        )
 
-        n_layers, n_kv, hd = _cd(config)
+        # what a layer caches for a token: every pool this engine
+        # allocates, gathers, scatters, copies and resets goes through it
+        self.cache = cache_spec(config)
+        n_layers = self.cache.n_layers
+        self.page_size = pool.page_size
+        self.capacity = pages_per_seq * pool.page_size
         # what the decode step's paged attention actually runs at this
         # geometry on this backend (the request may be None/"auto"); an
         # explicit kernel request the geometry cannot honour raises here,
-        # before anything compiles
-        self.resolved_attention_impl = resolve_paged_impl(
-            self.attention_impl,
-            (slots, getattr(config, "n_head", n_kv), 1, hd),
-            (pool.n_pages, pool.page_size, n_kv, hd),
-            config.dtype,
-        )
-        self.page_size = pool.page_size
-        self.capacity = pages_per_seq * pool.page_size
-        # rows in one block of the paged kernel's walk at this geometry:
-        # what ``decode.kv_live_block_share`` counts live blocks in
-        self.kv_block_rows = pool.page_size * paged_block_pages(
-            pool.page_size, pages_per_seq, n_kv, hd, config.dtype
-        )
+        # before anything compiles.  ``kv_block_rows``: rows in one block
+        # of the paged kernel's walk at this geometry, what
+        # ``decode.kv_live_block_share`` counts live blocks in
+        if self.cache.kind == "latent":
+            width = self.cache.rows[0][1][0]
+            self.resolved_attention_impl = resolve_mla_paged_impl(
+                self.attention_impl, pool.page_size, width,
+                config.kv_lora_rank, config.dtype,
+            )
+            block_pages = latent_block_pages(
+                pool.page_size, pages_per_seq, width, config.dtype)
+        else:
+            _, n_kv, hd = cache_dims(config)
+            self.resolved_attention_impl = resolve_paged_impl(
+                self.attention_impl,
+                (slots, getattr(config, "n_head", n_kv), 1, hd),
+                (pool.n_pages, pool.page_size, n_kv, hd),
+                config.dtype,
+            )
+            block_pages = paged_block_pages(
+                pool.page_size, pages_per_seq, n_kv, hd, config.dtype)
+        self.kv_block_rows = pool.page_size * block_pages
         self.seg_steps = seg_steps
         # chunked prefill: prompts longer than this admit in fixed-token
         # chunks co-scheduled with decode segments instead of one whole-
@@ -411,6 +443,8 @@ class PagedDecodeEngine:
         # page) until the last chunk folds.
         self._chunk_state: Dict[int, Dict[str, Any]] = {}
         self._chunk_rr = 0
+        # extra args of the next ``segment`` span (:meth:`_observe_moe`)
+        self._seg_span_args: Dict[str, Any] = {}
         # drain seam (fleet failover): while set, submit() hard-rejects
         # new work — already-queued and in-flight requests keep running
         # to completion, which is what lets a sick replica empty itself
@@ -432,8 +466,8 @@ class PagedDecodeEngine:
         # tokens, so keeping them on host avoids a flurry of tiny .at[]
         # dispatches per admission and per-segment readbacks (at serving
         # granularity that overhead was the whole paged-vs-dense margin)
-        self.pools = init_paged_kv(
-            n_layers, pool.n_pages, pool.page_size, n_kv, hd, config.dtype
+        self.pools = self.cache.init_pools(
+            pool.n_pages, pool.page_size, config.dtype
         )
         self.page_table = np.full(
             (slots, pages_per_seq), TRASH_PAGE, np.int32
@@ -504,7 +538,7 @@ class PagedDecodeEngine:
         # attach_ownership_log() or rebind_obs(ownlog=...).
         self.ownlog = None
         self._page_bytes = (
-            n_layers * 2 * pool.page_size * n_kv * hd
+            n_layers * pool.page_size * self.cache.row_elems
             * np.dtype(config.dtype).itemsize
         )
         # the pools are one placed slab: attribute kv pages to the node
@@ -548,7 +582,7 @@ class PagedDecodeEngine:
         ``decode.jit_cache_entries`` series counts compile classes seen
         *this run*, and a reused engine must emit the same series a fresh
         build would."""
-        from ..models.kv_pages import TRASH_PAGE, init_paged_kv
+        from ..models.kv_pages import TRASH_PAGE
 
         self._prefill_cache = {}
 
@@ -567,11 +601,8 @@ class PagedDecodeEngine:
         drop = getattr(self.pool, "drop_cached", None)
         if drop is not None:
             drop()
-        n_layers = self.n_layers
-        n_kv, hd = self.pools["cache_k_0"].shape[2:]
-        self.pools = init_paged_kv(
-            n_layers, self.pool.n_pages, self.pool.page_size, n_kv, hd,
-            self.config.dtype,
+        self.pools = self.cache.init_pools(
+            self.pool.n_pages, self.pool.page_size, self.config.dtype
         )
         self.page_table = np.full(
             (self.slots, self.pages_per_seq), TRASH_PAGE, np.int32
@@ -991,6 +1022,22 @@ class PagedDecodeEngine:
         self.metrics.counter("decode.requests_submitted").inc()
         self._emit_queue_depth()
 
+    def _forward_last(self, w, ids, cache, pos0, row):
+        """The family's cached forward over ``ids`` (b, T) at ``pos0``
+        and the logits of chunk row ``row`` (static or traced), (b, V):
+        all any prefill program needs of them.  A family that offers
+        ``forward_cached_row`` computes no other row's logits."""
+        from ..parallel.decode import _family_of, _module_for
+
+        mod = _module_for(_family_of(self.config))
+        if hasattr(mod, "forward_cached_row"):
+            return mod.forward_cached_row(
+                w, ids, cache, pos0, self.config, row,
+                impl=self.attention_impl)
+        logits, cache = mod.forward_cached(w, ids, cache, pos0, self.config)
+        return jax.lax.dynamic_index_in_dim(
+            logits, row, 1, keepdims=False), cache
+
     # -- prefill + page scatter (ONE call per admission ROUND; one
     # compiled class per (prompt length, batch size)) ----------------------
     def _prefill_scatter(self, prompt_ids: jax.Array, pt_rows):
@@ -1000,41 +1047,20 @@ class PagedDecodeEngine:
         ``prompt_ids`` (b, P); ``pt_rows`` (b, pages_per_seq) physical
         page rows (trash-padded tails).  Returns the (b,) first greedy
         tokens.  Weights are an argument (see the segment fn)."""
-        from ..frontend.decode_dag import cache_dims as _cd
-        from ..models import decode as _decode
-        from ..parallel.decode import _family_of, _module_for
-
         b, P = prompt_ids.shape
         key = (P, b, self.attention_impl)
         fn = self._prefill_store.get(key)
         if fn is None:
-            mod = _module_for(_family_of(self.config))
-            n_layers, n_kv, hd = _cd(self.config)
+            spec, fwd = self.cache, self._forward_last
             cap, cfg = self.capacity, self.config
             ppseq, ps = self.pages_per_seq, self.page_size
 
             def _fn(w, ids, pools, pages):
-                cache = _decode.init_cache(
-                    n_layers, b, n_kv, cap, hd, cfg.dtype
-                )
-                logits, cache = mod.forward_cached(
-                    w, ids, cache, 0, cfg
-                )
-                first = jnp.argmax(
-                    logits[:, -1, :], axis=-1
-                ).astype(jnp.int32)
-                flat_pages = pages.reshape(b * ppseq)
-                new = dict(pools)
-                for i in range(n_layers):
-                    for kind in ("k", "v"):
-                        # (b, cap, Hkv, hd) scatter-ready, page-chunked
-                        rows = cache[kind][i].transpose(0, 2, 1, 3)
-                        paged = rows.reshape(b * ppseq, ps, n_kv, hd)
-                        pool = new[f"cache_{kind}_{i}"]
-                        new[f"cache_{kind}_{i}"] = pool.at[flat_pages].set(
-                            paged.astype(pool.dtype), mode="drop"
-                        )
-                return first, new
+                cache = spec.init_dense(b, cap, cfg.dtype)
+                last, cache = fwd(w, ids, cache, 0, P - 1)
+                first = jnp.argmax(last, axis=-1).astype(jnp.int32)
+                return first, spec.scatter(
+                    pools, cache, pages.reshape(b * ppseq), ps)
 
             fn = jax.jit(_fn, donate_argnums=(2,))
             self._prefill_store[key] = fn
@@ -1076,53 +1102,24 @@ class PagedDecodeEngine:
         of the resident prefix pages; ``wt_rows`` (b, pages_per_seq)
         the write table.  One compile class per ``(P, h, b, impl)``.
         """
-        from ..frontend.decode_dag import cache_dims as _cd
-        from ..models import decode as _decode
-        from ..parallel.decode import _family_of, _module_for
-
         b, P = prompt_ids.shape
         h = int(h)
         key = ("shared", P, h, b, self.attention_impl)
         fn = self._prefill_store.get(key)
         if fn is None:
-            mod = _module_for(_family_of(self.config))
-            n_layers, n_kv, hd = _cd(self.config)
+            spec, fwd = self.cache, self._forward_last
             cap, cfg = self.capacity, self.config
             ppseq, ps = self.pages_per_seq, self.page_size
             pre = h * ps
 
             def _fn(w, ids_tail, pools, spages, wpages):
-                cache = _decode.init_cache(
-                    n_layers, b, n_kv, cap, hd, cfg.dtype
-                )
-                flat_sh = spages.reshape(b * h)
-                for i in range(n_layers):
-                    for kind in ("k", "v"):
-                        poolarr = pools[f"cache_{kind}_{i}"]
-                        rows = jnp.take(poolarr, flat_sh, axis=0)
-                        rows = rows.reshape(b, pre, n_kv, hd)
-                        rows = rows.transpose(0, 2, 1, 3)  # (b,Hkv,pre,hd)
-                        buf = cache[kind]
-                        cache[kind] = buf.at[i, :, :, :pre, :].set(
-                            rows.astype(buf.dtype)
-                        )
-                logits, cache = mod.forward_cached(
-                    w, ids_tail, cache, pre, cfg
-                )
-                first = jnp.argmax(
-                    logits[:, -1, :], axis=-1
-                ).astype(jnp.int32)
-                flat_pages = wpages.reshape(b * ppseq)
-                new = dict(pools)
-                for i in range(n_layers):
-                    for kind in ("k", "v"):
-                        rows = cache[kind][i].transpose(0, 2, 1, 3)
-                        paged = rows.reshape(b * ppseq, ps, n_kv, hd)
-                        pool = new[f"cache_{kind}_{i}"]
-                        new[f"cache_{kind}_{i}"] = pool.at[flat_pages].set(
-                            paged.astype(pool.dtype), mode="drop"
-                        )
-                return first, new
+                cache = spec.gather(
+                    spec.init_dense(b, cap, cfg.dtype), pools,
+                    spages.reshape(b * h), b, pre)
+                last, cache = fwd(w, ids_tail, cache, pre, P - pre - 1)
+                first = jnp.argmax(last, axis=-1).astype(jnp.int32)
+                return first, spec.scatter(
+                    pools, cache, wpages.reshape(b * ppseq), ps)
 
             fn = jax.jit(_fn, donate_argnums=(2,))
             self._prefill_store[key] = fn
@@ -1162,47 +1159,19 @@ class PagedDecodeEngine:
         :meth:`_prefill_scatter_shared`, so the chunk's rows, the final
         logits row, and every downstream decode step match a
         whole-prompt run bit for bit."""
-        from ..frontend.decode_dag import cache_dims as _cd
-        from ..models import decode as _decode
-        from ..parallel.decode import _family_of, _module_for
-
         key = ("chunk", self.chunk_tokens, 1, self.attention_impl)
         fn = self._prefill_store.get(key)
         if fn is None:
-            mod = _module_for(_family_of(self.config))
-            n_layers, n_kv, hd = _cd(self.config)
+            spec, fwd = self.cache, self._forward_last
             cap, cfg = self.capacity, self.config
-            ppseq, ps = self.pages_per_seq, self.page_size
+            ps = self.page_size
 
             def _fn(w, ids, pools, pages, pos0, creal):
-                cache = _decode.init_cache(
-                    n_layers, 1, n_kv, cap, hd, cfg.dtype
-                )
-                for i in range(n_layers):
-                    for kind in ("k", "v"):
-                        poolarr = pools[f"cache_{kind}_{i}"]
-                        rows = jnp.take(poolarr, pages, axis=0)
-                        rows = rows.reshape(1, cap, n_kv, hd)
-                        rows = rows.transpose(0, 2, 1, 3)
-                        buf = cache[kind]
-                        cache[kind] = buf.at[i].set(rows.astype(buf.dtype))
-                logits, cache = mod.forward_cached(
-                    w, ids, cache, pos0, cfg
-                )
-                last = jax.lax.dynamic_index_in_dim(
-                    logits, creal - 1, 1, keepdims=False
-                )
+                cache = spec.gather(
+                    spec.init_dense(1, cap, cfg.dtype), pools, pages, 1, cap)
+                last, cache = fwd(w, ids, cache, pos0, creal - 1)
                 first = jnp.argmax(last, axis=-1).astype(jnp.int32)
-                new = dict(pools)
-                for i in range(n_layers):
-                    for kind in ("k", "v"):
-                        rows = cache[kind][i].transpose(0, 2, 1, 3)
-                        paged = rows.reshape(ppseq, ps, n_kv, hd)
-                        poolarr = new[f"cache_{kind}_{i}"]
-                        new[f"cache_{kind}_{i}"] = poolarr.at[pages].set(
-                            paged.astype(poolarr.dtype), mode="drop"
-                        )
-                return first, new
+                return first, spec.scatter(pools, cache, pages, ps)
 
             fn = jax.jit(_fn, donate_argnums=(2,))
             self._prefill_store[key] = fn
@@ -1849,17 +1818,40 @@ class PagedDecodeEngine:
             ).observe(share)
         with annotate("segment"):
             t_sg0 = self._clock()
-            toks, self.pools = self._seg(
+            toks, self.pools, *stats = self._seg(
                 self.weights, self.pools, self.page_table, self.lengths,
                 self.cur_tok, self.remaining,
             )
             toks = self._np.asarray(toks)  # the one readback per segment
+            if stats:  # counted on the device, same program: no new sync
+                self._observe_moe(self._np.asarray(stats[0]), owed)
             # the fold timestamp: every token this segment delivered
             # became host-visible at this readback (lifecycle-log
             # delivery events)
             t_sg1 = self._clock()
         with annotate("fold"):
             return self._fold_segment(toks, owed, t_sg0, t_sg1)
+
+    def _observe_moe(self, stats, owed) -> None:
+        """An expert family's routing counts of one segment, ``stats``
+        (steps, expert layers, 2) = (share of the experts picked, largest
+        expert's picks over the mean), into the engine's registry and the
+        process-wide one: the median over the layer-steps in which a
+        slot still decoded."""
+        np = self._np
+        ran = stats[:min(int(owed.max()), self.seg_steps)].reshape(-1, 2)
+        # on the segment's span too: the mean distinct experts a
+        # layer-step read, over ALL the segment's steps (a step in which
+        # no slot decodes any more reads none, and its expert kernel is
+        # called all the same): what the kernel's mean bytes follow
+        self._seg_span_args = {"experts_touched": float(
+            stats[..., 0].mean() * self.config.n_routed_experts)}
+        touched, imbalance = (float(v) for v in np.median(ran, axis=0))
+        for reg in (self.metrics, process_metrics()):
+            reg.histogram(
+                "moe.experts_touched_share", unit="ratio").observe(touched)
+            reg.histogram(
+                "moe.pick_imbalance", unit="ratio").observe(imbalance)
 
     def _fold_segment(self, toks, owed, t_sg0: float, t_sg1: float) -> int:
         """What follows a segment's readback at ``t_sg1``: the tokens go
@@ -1869,7 +1861,7 @@ class PagedDecodeEngine:
             self.tracer.complete(
                 "segment", t_sg0, t_sg1, track="decode",
                 cat="decode", steps=self.seg_steps,
-                active=int((owed > 0).sum()),
+                active=int((owed > 0).sum()), **self._seg_span_args,
             )
         if self.reqtrace is not None:
             # per-request decode spans reuse the segment's two hoisted

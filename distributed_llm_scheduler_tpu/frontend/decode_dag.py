@@ -60,6 +60,25 @@ def cache_dims(config: Any) -> tuple:
     return config.n_layers, config.n_kv_heads, config.head_dim
 
 
+def cache_spec(config: Any):
+    """The per-layer cache description of any family's config
+    (:class:`...models.kv_pages.CacheSpec`): kind ``kv`` with rows
+    ``(n_kv_heads, head_dim)`` for the attention families
+    (:func:`cache_dims`), kind ``latent`` with one ``[c | k_r]`` row for
+    MLA.  The paged builder, the engine's prefill / chunk / copy-on-write
+    / reset code and the step composer all read the cache through it."""
+    from ..models.kv_pages import CacheSpec
+    from ..parallel.decode import _family_of
+
+    if _family_of(config) == "xing4":
+        from ..models.xing4 import latent_row_width
+
+        return CacheSpec("latent", config.n_layers,
+                         (("c", (latent_row_width(config),)),))
+    n_layers, n_kv, hd = cache_dims(config)
+    return CacheSpec("kv", n_layers, (("k", (n_kv, hd)), ("v", (n_kv, hd))))
+
+
 class DecodeDAG(ModelDAG):
     """ModelDAG whose graph input is ``{"ids": (B, T) int32, "pos": ()
     int32}`` — position is runtime data, so one graph serves every step
@@ -475,7 +494,7 @@ class PagedDecodeDAG(ModelDAG):
         key = key if key is not None else jax.random.PRNGKey(1)
         shape = self.input_spec["ids"].shape
         S = shape[0]
-        return {
+        out = {
             "ids": jax.random.randint(
                 key, shape, 0, self.config.vocab_size, dtype=jnp.int32
             ),
@@ -484,10 +503,145 @@ class PagedDecodeDAG(ModelDAG):
                 else jnp.asarray(lengths, jnp.int32)
             ),
         }
+        if "active" in self.input_spec:
+            out["active"] = jnp.ones((S,), bool)
+        return out
+
+
+def _finish_paged_dag(tasks, name, config, input_spec, specs,
+                      reference_forward, init_fn, slots, page_size,
+                      pages_per_seq, attention_impl) -> PagedDecodeDAG:
+    """What every family's paged builder ends with."""
+    graph = TaskGraph(tasks, name=name).freeze()
+    # stamped on the graph too: the engine receives the bare TaskGraph
+    # and keys its prefill compile-class cache on the impl
+    graph.attention_impl = attention_impl
+    dag = PagedDecodeDAG(
+        graph=graph,
+        config=config,
+        input_spec=input_spec,
+        param_specs=specs,
+        reference_forward=reference_forward,
+        init_fn=init_fn,
+    )
+    dag.slots = slots
+    dag.page_size = page_size
+    dag.pages_per_seq = pages_per_seq
+    dag.attention_impl = attention_impl
+    return dag
+
+
+def _build_xing4_paged_decode_dag(
+    config, slots, page_size, n_pages, pages_per_seq, effective_flops,
+    attention_impl,
+) -> PagedDecodeDAG:
+    """The paged decode step of the Xing4.0 block
+    (:mod:`..models.xing4`): the residual on every edge between layer
+    tasks is the ``(slots, hc_mult, hidden)`` streams, the cache one
+    latent pool a layer (:func:`cache_spec`), positions the rotary
+    angles of ``lengths``.  Expert layers put their routing counts on the
+    edge as ``stats``; the ``active`` input takes the slots that decode
+    nothing out of the routing."""
+    from ..models import xing4
+    from ..models.kv_pages import TRASH_PAGE
+
+    S, ps, h = slots, page_size, config.hidden_size
+    spec = cache_spec(config)
+    specs = {
+        name: jax.ShapeDtypeStruct(shape, dtype)
+        for name, (shape, dtype) in xing4.param_shapes(config).items()
+    }
+    specs.update(jax.eval_shape(
+        lambda: spec.init_pools(n_pages, ps, config.dtype)))
+    specs["page_table"] = jax.ShapeDtypeStruct((S, pages_per_seq), jnp.int32)
+    input_spec = {
+        "ids": jax.ShapeDtypeStruct((S, 1), jnp.int32),
+        "lengths": jax.ShapeDtypeStruct((S,), jnp.int32),
+        "active": jax.ShapeDtypeStruct((S,), jnp.bool_),
+    }
+    tasks: List[Task] = []
+    out_specs: Dict[str, Any] = {}
+    add = make_task_adder(tasks, out_specs, specs, input_spec, effective_flops)
+
+    def f_embed(p, inputs):
+        return {"x": xing4.embed(p, inputs["ids"][:, 0], config),
+                "lengths": inputs["lengths"], "live": inputs["active"]}
+
+    def layer_fn(i):
+        def f_layer(p, prev):
+            x, row, stats = xing4.decode_layer(
+                p, prev["x"], prev["lengths"], prev["live"], p["cache_c"],
+                p["page_table"], config, i, impl=attention_impl)
+            out = {"x": x, "c_new": row, "lengths": prev["lengths"],
+                   "live": prev["live"]}
+            if stats is not None:
+                out["stats"] = stats
+            return out
+        return f_layer
+
+    def f_head(p, prev):
+        return xing4.head(p, prev["x"], config)[:, None, :]
+
+    add("embed", f_embed, [], {"wte": "wte"}, 2.0 * S * h, "embed")
+    prev = "embed"
+    M = pages_per_seq * ps
+    for i in range(config.n_layers):
+        shapes = xing4.layer_param_shapes(config, i)
+        alias = {k: f"h{i}_{k}" for k in shapes}
+        alias.update(cache_c=f"cache_c_{i}", page_table="page_table")
+        # weights streamed once a step (experts: the picked ones), plus
+        # the absorbed attention over the slot's capacity
+        act = sum(
+            2.0 * S * math.prod(shape) * (
+                config.experts_per_tok / config.n_routed_experts
+                if k.startswith("exp_") else 1.0)
+            for k, (shape, _) in shapes.items() if len(shape) >= 2)
+        flops = act + 2.0 * 2.0 * S * config.n_heads * M * spec.row_elems
+        tid = f"layer_{i}"
+        add(tid, layer_fn(i), [prev], alias, flops, tid)
+        prev = tid
+    add("logits", f_head, [prev],
+        {"norm_f_g": "norm_f_g", "head_w": "head_w"},
+        2.0 * S * h * config.vocab_size, "head")
+
+    name = (
+        f"xing4paged_{config.n_layers}l_d{h}_s{S}_ps{ps}_p{n_pages}"
+        + ("" if config.dtype == jnp.float32
+           else f"_{jnp.dtype(config.dtype).name}")
+        + ("" if attention_impl is None else f"_att{attention_impl}")
+    )
+
+    def init_fn(key):
+        params = xing4.init_params(config, key)
+        params.update(spec.init_pools(n_pages, ps, config.dtype))
+        params["page_table"] = jnp.full(
+            (S, pages_per_seq), TRASH_PAGE, jnp.int32)
+        return params
+
+    def reference_forward(params, inputs):
+        """Independent oracle: per slot, the pages gathered into a dense
+        latent cache and the family's EXPANDED ``forward_cached`` at the
+        slot's position — no absorbed form, no paged op."""
+        weights = {k: v for k, v in params.items()
+                   if not k.startswith("cache_") and k != "page_table"}
+        outs = []
+        for s in range(S):
+            cache = spec.gather(
+                spec.init_dense(1, M, config.dtype), params,
+                params["page_table"][s], 1, M)
+            logits, _ = xing4.forward_cached(
+                weights, inputs["ids"][s:s + 1], cache,
+                inputs["lengths"][s], config, impl="xla")
+            outs.append(logits)
+        return jnp.concatenate(outs, axis=0)
+
+    return _finish_paged_dag(
+        tasks, name, config, input_spec, specs, reference_forward, init_fn,
+        S, ps, pages_per_seq, attention_impl)
 
 
 def build_paged_decode_dag(
-    config: Optional[GPT2Config] = None,
+    config: Any = None,
     slots: int = 4,
     page_size: int = 16,
     n_pages: int = 64,
@@ -495,7 +649,11 @@ def build_paged_decode_dag(
     effective_flops: float = DEFAULT_EFFECTIVE_FLOPS,
     attention_impl: Optional[str] = None,
 ) -> PagedDecodeDAG:
-    """Paged single-token decode step as a task DAG (GPT-2 family).
+    """Paged single-token decode step as a task DAG: the GPT-2 family
+    (below) or, for a :class:`...models.xing4.Xing4Config`, the Xing4.0
+    block over a latent pool (:func:`_build_xing4_paged_decode_dag`) —
+    the one builder that reaches :class:`...backends.decode_loop.
+    PagedDecodeEngine`.
 
     The dense decode DAG's per-layer ``cache_k_{i}``/``cache_v_{i}``
     slabs become shared page POOLS ``(n_pages, page_size, H, hd)`` and
@@ -531,6 +689,12 @@ def build_paged_decode_dag(
     if n_pages < 2:
         raise ValueError(f"n_pages must be >= 2 (page 0 is reserved), "
                          f"got {n_pages}")
+    from ..parallel.decode import _family_of
+
+    if _family_of(config) == "xing4":
+        return _build_xing4_paged_decode_dag(
+            config, slots, page_size, n_pages, pages_per_seq,
+            effective_flops, attention_impl)
     S, D, H = slots, config.n_embd, config.n_head
     hd, ps = config.head_dim, page_size
     M = pages_per_seq * page_size  # per-slot gathered capacity
@@ -676,23 +840,9 @@ def build_paged_decode_dag(
             outs.append(logits)
         return jnp.concatenate(outs, axis=0)
 
-    graph = TaskGraph(tasks, name=name).freeze()
-    # stamped on the graph too: the engine receives the bare TaskGraph
-    # and keys its prefill compile-class cache on the impl
-    graph.attention_impl = attention_impl
-    dag = PagedDecodeDAG(
-        graph=graph,
-        config=config,
-        input_spec=input_spec,
-        param_specs=specs,
-        reference_forward=reference_forward,
-        init_fn=init_fn,
-    )
-    dag.slots = S
-    dag.page_size = ps
-    dag.pages_per_seq = pages_per_seq
-    dag.attention_impl = attention_impl
-    return dag
+    return _finish_paged_dag(
+        tasks, name, config, input_spec, specs, reference_forward, init_fn,
+        S, ps, pages_per_seq, attention_impl)
 
 
 def build_decode_dag_any(config: Any, **kw) -> ModelDAG:

@@ -34,7 +34,9 @@ unused tail read finite (masked-out) garbage instead of faulting.
 
 from __future__ import annotations
 
+import dataclasses
 import hashlib
+import math
 from dataclasses import dataclass, field
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
@@ -490,6 +492,97 @@ class PagePool:
             self.free(to_free)
 
 
+@dataclasses.dataclass(frozen=True)
+class CacheSpec:
+    """What one layer caches for a token, and how the paged engine moves
+    it: the per-layer cache description every family's config maps to
+    (:func:`...frontend.decode_dag.cache_spec`).
+
+    ``kind`` ``"kv"``: two pools a layer, ``cache_k_{i}`` / ``cache_v_{i}``
+    with row ``(n_kv_heads, head_dim)``; the family's dense cache keeps
+    heads ahead of positions, ``(L, b, Hkv, cap, hd)``.  ``kind``
+    ``"latent"``: one pool a layer, ``cache_c_{i}``, row ``(width,)`` —
+    MLA's normalised latent and shared rotated key; dense ``(L, b, cap,
+    width)``.  ``rows`` lists ``(pool kind, row shape)``; pools are
+    ``(n_pages, page_size, *row)`` in both."""
+
+    kind: str
+    n_layers: int
+    rows: Tuple[Tuple[str, Tuple[int, ...]], ...]
+
+    @property
+    def kinds(self) -> Tuple[str, ...]:
+        return tuple(k for k, _ in self.rows)
+
+    @property
+    def row_elems(self) -> int:
+        """Values one token occupies in one layer, all pools."""
+        return sum(math.prod(r) for _, r in self.rows)
+
+    def init_pools(self, n_pages: int, page_size: int,
+                   dtype: Any) -> Dict[str, jax.Array]:
+        """Zeroed pools keyed ``cache_{kind}_{i}``."""
+        return {
+            f"cache_{kind}_{i}": jnp.zeros((n_pages, page_size, *row), dtype)
+            for i in range(self.n_layers) for kind, row in self.rows
+        }
+
+    def init_dense(self, batch: int, cap: int, dtype: Any) -> Dict[str, Any]:
+        """The family's zeroed dense cache ``{kind: (L, b, ...)}``."""
+        return {kind: jnp.zeros(
+            (self.n_layers, *self._dense(kind, (batch, cap, *row))), dtype)
+            for kind, row in self.rows}
+
+    def _dense(self, kind: str, shape):
+        if self.kind == "kv":      # (b, cap, Hkv, hd) -> (b, Hkv, cap, hd)
+            return (shape[0], shape[2], shape[1], shape[3])
+        return tuple(shape)
+
+    def to_rows(self, dense_layer: jax.Array) -> jax.Array:
+        """One layer of the dense cache as ``(b, cap, *row)``."""
+        if self.kind == "kv":
+            return dense_layer.transpose(0, 2, 1, 3)
+        return dense_layer
+
+    def step_rows(self, new: jax.Array) -> jax.Array:
+        """A decode step's new rows ``(S, *row)`` out of a layer task's
+        ``{kind}_new`` output (kv tasks emit ``(S, Hkv, 1, hd)``)."""
+        return new[:, :, 0, :] if self.kind == "kv" else new
+
+    def gather(self, cache: Dict[str, Any], pools: Dict[str, Any],
+               pages: jax.Array, batch: int, n_rows: int) -> Dict[str, Any]:
+        """``cache`` with rows ``[0, n_rows)`` of every layer filled from
+        the pools through ``pages`` (flat physical ids, ``batch`` runs)."""
+        out = dict(cache)
+        for i in range(self.n_layers):
+            for kind, row in self.rows:
+                rows = jnp.take(pools[f"cache_{kind}_{i}"], pages, axis=0)
+                rows = self._from_rows(rows.reshape(batch, n_rows, *row))
+                at = ((i, slice(None), slice(None), slice(0, n_rows))
+                      if self.kind == "kv" else
+                      (i, slice(None), slice(0, n_rows)))
+                out[kind] = out[kind].at[at].set(rows.astype(out[kind].dtype))
+        return out
+
+    def _from_rows(self, rows: jax.Array) -> jax.Array:
+        return rows.transpose(0, 2, 1, 3) if self.kind == "kv" else rows
+
+    def scatter(self, pools: Dict[str, Any], cache: Dict[str, Any],
+                pages: jax.Array, page_size: int) -> Dict[str, Any]:
+        """``pools`` with every page in ``pages`` (flat physical ids,
+        covering each sequence's whole capacity) rewritten from the dense
+        ``cache``; out-of-range ids are dropped."""
+        new = dict(pools)
+        for i in range(self.n_layers):
+            for kind, row in self.rows:
+                rows = self.to_rows(cache[kind][i])
+                paged = rows.reshape(pages.shape[0], page_size, *row)
+                pool = new[f"cache_{kind}_{i}"]
+                new[f"cache_{kind}_{i}"] = pool.at[pages].set(
+                    paged.astype(pool.dtype), mode="drop")
+        return new
+
+
 def init_paged_kv(
     n_layers: int,
     n_pages: int,
@@ -504,12 +597,9 @@ def init_paged_kv(
     caches uniformly.  Layout ``(n_pages, page_size, n_kv_heads,
     head_dim)``: pages lead, so assembling a sequence is one gather on
     axis 0."""
-    shape = (n_pages, page_size, n_kv_heads, head_dim)
-    out: Dict[str, jax.Array] = {}
-    for i in range(n_layers):
-        out[f"cache_k_{i}"] = jnp.zeros(shape, dtype)
-        out[f"cache_v_{i}"] = jnp.zeros(shape, dtype)
-    return out
+    row = (n_kv_heads, head_dim)
+    return CacheSpec("kv", n_layers, (("k", row), ("v", row))).init_pools(
+        n_pages, page_size, dtype)
 
 
 def page_table_array(
@@ -544,12 +634,24 @@ def write_token_kv(
     ``active`` (S,) bool.  Inactive slots write the trash page, so the
     scatter stays static-shape under an admission/retirement mask.
     """
+    return write_token_rows(pool, new[:, :, 0, :], page_table, lengths, active)
+
+
+def write_token_rows(
+    pool: jax.Array,
+    rows: jax.Array,
+    page_table: jax.Array,
+    lengths: jax.Array,
+    active: jax.Array,
+) -> jax.Array:
+    """:func:`write_token_kv` for rows already ``(S, *row)`` — whatever a
+    row is (``(Hkv, hd)`` or a latent ``(width,)``)."""
     n_pages, ps = pool.shape[0], pool.shape[1]
     s_idx = jnp.arange(page_table.shape[0])
     logical = jnp.where(active, lengths // ps, 0)
     pid = jnp.where(active, page_table[s_idx, logical], TRASH_PAGE)
     slot = jnp.where(active, lengths % ps, 0)
-    rows = new[:, :, 0, :].astype(pool.dtype)  # (S, Hkv, hd)
+    rows = rows.astype(pool.dtype)
     # flat row index: one 1-D scatter instead of a 2-D one (inactive
     # slots land in the trash page's row 0)
     flat = pool.reshape(n_pages * ps, *pool.shape[2:])

@@ -399,6 +399,49 @@ def paged_block_pages(
     return max(1, min(pages_per_seq, _PAGED_BUFFER_BYTES // (4 * page_bytes)))
 
 
+def latent_block_pages(
+    page_size: int, pages_per_seq: int, row_width: int, dtype: Any,
+) -> int:
+    """:func:`paged_block_pages` for the latent pool of
+    :func:`_mla_paged_flash`: one pool, so two buffers a page, and a
+    page is ``page_size`` rows of ``row_width`` values padded to whole
+    128-lane tiles — the same VMEM budget, the same rule."""
+    page_bytes = (
+        page_size * -(-row_width // 128) * 128 * jnp.dtype(dtype).itemsize
+    )
+    return max(1, min(pages_per_seq, _PAGED_BUFFER_BYTES // (2 * page_bytes)))
+
+
+def _live_block_tables(page_table, lengths, page_size: int, ppb: int):
+    """The dynamic grid both single-token paged kernels walk: the live
+    (slot, page block) pairs, slot-major.  Returns ``(slot_of, block_of,
+    fetch, lengths, n_live)``: step ``t < n_live`` is block
+    ``block_of[t]`` of slot ``slot_of[t]`` and its ``i``-th page ref
+    holds physical page ``fetch[t * ppb + i]`` (page 0 past the slot's
+    last row: a block index that repeats from one step to the next is
+    not fetched again, and the kernel never reads it).  The tables are
+    as long as the table's capacity in blocks; the grid only as long as
+    the list."""
+    S, ppseq = page_table.shape
+    lengths = lengths.astype(jnp.int32)
+    last_page = jnp.minimum(lengths, ppseq * page_size - 1) // page_size
+    blocks = last_page // ppb + 1                       # live, per slot
+    ends = jnp.cumsum(blocks)
+    steps = jnp.arange(S * -(-ppseq // ppb), dtype=jnp.int32)
+    slot_of = jnp.minimum(
+        (steps[:, None] >= ends[None, :]).sum(axis=1, dtype=jnp.int32),
+        S - 1)
+    block_of = steps - (ends - blocks)[slot_of]
+    page = block_of[:, None] * ppb + jnp.arange(ppb, dtype=jnp.int32)
+    fetch = jnp.where(
+        page <= last_page[slot_of][:, None],
+        page_table.astype(jnp.int32)[
+            slot_of[:, None], jnp.minimum(page, ppseq - 1)],
+        0,
+    ).reshape(-1)
+    return slot_of, block_of, fetch, lengths, ends[-1]
+
+
 def _paged_kernel(
     slot_ref, block_ref, fetch_ref, len_ref, q_ref, kn_ref, vn_ref, *refs,
     sm_scale, page_size, pages_per_seq, pages_per_block, groups, has_new,
@@ -546,25 +589,8 @@ def _paged_flash(
         kn = jnp.zeros((S, Hkv, hd), k_pool.dtype)
         vn = jnp.zeros((S, Hkv, hd), v_pool.dtype)
 
-    # the live blocks, slot-major: step t < n_live is block block_of[t]
-    # of slot slot_of[t]; the tables are as long as the table's capacity
-    # in blocks, the grid only as long as the list
-    lengths = lengths.astype(jnp.int32)
-    last_page = jnp.minimum(lengths, ppseq * page_size - 1) // page_size
-    blocks = last_page // ppb + 1                       # live, per slot
-    ends = jnp.cumsum(blocks)
-    steps = jnp.arange(S * -(-ppseq // ppb), dtype=jnp.int32)
-    slot_of = jnp.minimum(
-        (steps[:, None] >= ends[None, :]).sum(axis=1, dtype=jnp.int32),
-        S - 1)
-    block_of = steps - (ends - blocks)[slot_of]
-    page = block_of[:, None] * ppb + jnp.arange(ppb, dtype=jnp.int32)
-    fetch = jnp.where(
-        page <= last_page[slot_of][:, None],
-        page_table.astype(jnp.int32)[
-            slot_of[:, None], jnp.minimum(page, ppseq - 1)],
-        0,
-    ).reshape(-1)
+    slot_of, block_of, fetch, lengths, n_live = _live_block_tables(
+        page_table, lengths, page_size, ppb)
 
     def page_spec(i):
         return pl.BlockSpec(
@@ -579,7 +605,7 @@ def _paged_flash(
     pages = [page_spec(i) for i in range(ppb)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
-        grid=(ends[-1],),
+        grid=(n_live,),
         in_specs=[slot_spec(Hq), slot_spec(Hkv), slot_spec(Hkv)]
         + pages + pages,
         out_specs=slot_spec(Hq),
@@ -914,6 +940,226 @@ def paged_decode_attention(
     return (o / l.reshape(S, Hkv, G, 1)).astype(out_dtype).reshape(
         S, Hq, 1, hd
     )
+
+
+# -- absorbed MLA over a latent page pool ------------------------------------
+#
+# One pool a layer, row = [c | k_r]: ``rank`` normalised latent values and
+# the rotated shared key.  With W_UK absorbed into the query and W_UV
+# applied after, every head scores against the SAME row (all ``width``
+# values) and takes its values from the row's first ``rank``, so a page
+# is read once and used by all heads.  The grid is the live-block list of
+# :func:`_live_block_tables`, shared with :func:`_paged_flash`.
+
+
+def mla_kernel_constraints(
+    page_size: int, row_width: int, rank: int, dtype: Any = jnp.float32,
+) -> list:
+    """Tiling rules for the COMPILED latent kernel, in the manner of
+    :func:`paged_kernel_constraints` (empty = eligible; ``auto`` takes
+    the gather path otherwise, an explicit ``"pallas"`` raises): the
+    page a whole number of the dtype's sublane tiles, the value part a
+    whole number of 128-lane tiles so that its slice of the row is
+    aligned."""
+    sublane = _sublane_rows(dtype)
+    out = []
+    if page_size % sublane:
+        out.append(
+            f"page_size {page_size} is not a multiple of the {sublane}-row "
+            f"sublane tile for {jnp.dtype(dtype).name} latent pages"
+        )
+    if rank % 128 or not 0 < rank <= row_width:
+        out.append(
+            f"latent rank {rank} is not a positive multiple of the "
+            f"128-lane tile inside the {row_width}-wide row"
+        )
+    return out
+
+
+def resolve_mla_paged_impl(
+    impl: Optional[str], page_size: int, row_width: int, rank: int,
+    dtype: Any,
+) -> str:
+    """What :func:`mla_paged_decode_attention` runs at this geometry
+    (:func:`resolve_attention_impl`'s rule; interpret mode has no
+    tiling)."""
+    return resolve_attention_impl(
+        impl,
+        lambda i: i == "pallas_interpret" or not mla_kernel_constraints(
+            page_size, row_width, rank, dtype),
+    )
+
+
+def _mla_paged_kernel(
+    slot_ref, block_ref, fetch_ref, len_ref, q_ref, new_ref, *refs,
+    page_size, pages_per_seq, pages_per_block, rank, has_new,
+):
+    """One live (slot, page block) of the absorbed-MLA kernel: the walk,
+    the online-softmax carry and the ragged last page are
+    :func:`_paged_kernel`'s; a page is ``(page_size, width)`` rows that
+    all heads share, scores are float32 over the whole row and the
+    float32 accumulator takes the row's first ``rank`` values."""
+    del fetch_ref
+    ppb = pages_per_block
+    c_refs = refs[:ppb]
+    o_ref, acc_ref, m_ref, l_ref = refs[ppb:]
+    t = pl.program_id(0)
+    s_idx = slot_ref[t]
+    j = block_ref[t]
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    L = len_ref[s_idx]
+    last = jnp.minimum(L, pages_per_seq * page_size - 1)
+    last_page = last // page_size
+
+    def attend(page, c_ref, ragged):
+        q = q_ref[0]                      # (H, width), already scaled
+        rows = c_ref[0]                   # (page_size, width)
+        if ragged:
+            row = jax.lax.broadcasted_iota(
+                jnp.int32, (page_size, 1), 0) + page * page_size
+            if has_new:
+                rows = jnp.where(row == last, new_ref[0], rows)
+            rows = jnp.where(row <= L, rows, jnp.zeros_like(rows))
+        s = jax.lax.dot_general(
+            q, rows, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # (H, page_size)
+        if ragged:
+            pos = jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1) + page * page_size
+            s = jnp.where(pos <= L, s, _NEG_INF)
+        m_prev = m_ref[...]                       # (H, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(rows.dtype), rows[:, :rank],
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # (H, rank)
+        m_ref[...] = m_new
+
+    for i in range(ppb):
+        page = j * ppb + i
+        pl.when(page < last_page)(
+            functools.partial(attend, page, c_refs[i], False))
+        pl.when(page == last_page)(
+            functools.partial(attend, page, c_refs[i], True))
+
+    @pl.when(j == last_page // ppb)
+    def _finalize():
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit, static_argnames=("rank", "has_new", "interpret")
+)
+def _mla_paged_flash(
+    q, pool, page_table, lengths, new_row, *, rank, has_new, interpret,
+):
+    """Absorbed MLA over the latent pool through the page table.
+
+    ``q`` (S, H, width) — per head ``[q_nope W_UK^T | q_rope]`` with the
+    softmax scale folded in; ``pool`` (P, page_size, width); ``new_row``
+    (S, width) this step's ``[c | k_r]``, inserted write-then-attend at
+    ``lengths[s]``.  Returns (S, H, rank): ``softmax(q . row) . c`` per
+    head, W_UV still to apply.  Work follows the live pages exactly as
+    :func:`_paged_flash`'s does."""
+    S, H, width = q.shape
+    _, page_size, _ = pool.shape
+    ppseq = page_table.shape[1]
+    ppb = latent_block_pages(page_size, ppseq, width, pool.dtype)
+    q = q.astype(pool.dtype)
+    new = (new_row.astype(pool.dtype) if has_new
+           else jnp.zeros((S, width), pool.dtype)).reshape(S, 1, width)
+    slot_of, block_of, fetch, lengths, n_live = _live_block_tables(
+        page_table, lengths, page_size, ppb)
+
+    def page_spec(i):
+        return pl.BlockSpec(
+            (1, page_size, width),
+            lambda t, slot, blk, fetch, ln: (fetch[t * ppb + i], 0, 0),
+        )
+
+    def slot_spec(rows, cols):
+        return pl.BlockSpec(
+            (1, rows, cols), lambda t, slot, blk, fetch, ln: (slot[t], 0, 0))
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(n_live,),
+        in_specs=[slot_spec(H, width), slot_spec(1, width)]
+        + [page_spec(i) for i in range(ppb)],
+        out_specs=slot_spec(H, rank),
+        scratch_shapes=[
+            pltpu.VMEM((H, rank), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((H, 1), jnp.float32),
+        ],
+    )
+    return pl.pallas_call(
+        functools.partial(
+            _mla_paged_kernel, page_size=page_size, pages_per_seq=ppseq,
+            pages_per_block=ppb, rank=rank, has_new=has_new,
+        ),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((S, H, rank), pool.dtype),
+        interpret=interpret,
+        name="_mla_paged_flash",
+    )(slot_of, block_of, fetch, lengths, q, new, *([pool] * ppb))
+
+
+def _mla_gather_attention(q, pool, page_table, lengths, new_row, rank):
+    """The gather path of :func:`mla_paged_decode_attention`: the slot's
+    rows gathered dense through the table, masked past ``lengths``."""
+    S, H, width = q.shape
+    rows = jnp.take(pool, page_table, axis=0).reshape(S, -1, width)
+    M = rows.shape[1]
+    if new_row is not None:
+        at = jnp.minimum(lengths, M - 1)
+        rows = rows.at[jnp.arange(S), at].set(new_row.astype(rows.dtype))
+    valid = jnp.arange(M)[None, :] <= lengths[:, None]
+    rows = jnp.where(valid[:, :, None], rows, jnp.zeros_like(rows))
+    s = jnp.einsum("shw,smw->shm", q.astype(rows.dtype), rows,
+                   preferred_element_type=jnp.float32)
+    s = jnp.where(valid[:, None, :], s, _NEG_INF)
+    p = jax.nn.softmax(s, axis=-1)
+    o = jnp.einsum("shm,smc->shc", p.astype(rows.dtype), rows[..., :rank],
+                   preferred_element_type=jnp.float32)
+    return o.astype(pool.dtype)
+
+
+def mla_paged_decode_attention(
+    q: jax.Array,
+    pool: jax.Array,
+    page_table: jax.Array,
+    lengths: jax.Array,
+    rank: int,
+    new_row: Optional[jax.Array] = None,
+    impl: Optional[str] = None,
+) -> jax.Array:
+    """Single-token absorbed MLA over a latent page pool: slot ``s``
+    attends rows ``m <= lengths[s]`` of its pages (``new_row`` first
+    written at ``lengths[s]``).  Shapes as :func:`_mla_paged_flash`;
+    ``impl`` as :func:`paged_decode_attention` — the kernel, the kernel
+    interpreted, or the gather path for a geometry
+    :func:`mla_kernel_constraints` refuses."""
+    impl = resolve_mla_paged_impl(
+        impl, pool.shape[1], pool.shape[2], rank, pool.dtype)
+    if impl in ("pallas", "pallas_interpret"):
+        return _mla_paged_flash(
+            q, pool, page_table, lengths, new_row, rank=rank,
+            has_new=new_row is not None,
+            interpret=impl == "pallas_interpret",
+        )
+    return _mla_gather_attention(q, pool, page_table, lengths, new_row, rank)
 
 
 def gqa_mha(

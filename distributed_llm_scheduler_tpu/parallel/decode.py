@@ -34,7 +34,7 @@ from .sharding import shard_params
 
 def _family_of(config: Any) -> str:
     name = type(config).__name__.lower()
-    for fam in ("gpt2", "llama", "mixtral"):
+    for fam in ("gpt2", "llama", "mixtral", "xing4"):
         if fam in name:
             return fam
     raise ValueError(f"unknown model family for config {type(config)!r}")
@@ -45,10 +45,11 @@ _FAMILY_MODULES = {}
 
 def _module_for(family: str):
     if not _FAMILY_MODULES:
-        from ..models import gpt2, llama, mixtral
+        from ..models import gpt2, llama, mixtral, xing4
 
         _FAMILY_MODULES.update(
-            {"gpt2": gpt2, "llama": llama, "mixtral": mixtral}
+            {"gpt2": gpt2, "llama": llama, "mixtral": mixtral,
+             "xing4": xing4}
         )
     return _FAMILY_MODULES[family]
 

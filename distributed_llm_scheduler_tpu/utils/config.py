@@ -127,6 +127,12 @@ class RunConfig:
                 },
                 "n_layers", "max_seq_len", build_moe_dag,
             )
+        if self.model.startswith("xing4"):
+            # served only (the paged decode DAG); no forward-DAG builder
+            from ..models.xing4 import Xing4Config
+
+            return ({"xing4-tiny": Xing4Config.tiny}, "n_layers",
+                    "max_positions", None)
         return None
 
     def model_config(self):
@@ -191,6 +197,11 @@ class RunConfig:
         family = self._model_family()
         if family is not None:
             variants, layers_field, max_seq_field, builder = family
+            if builder is None:
+                raise ValueError(
+                    f"model {self.model!r} has no forward DAG: it is served "
+                    "only (the `serve` command's paged decode DAG)"
+                )
             cfg = self.model_config()
             if self.num_layers:
                 cfg = dataclasses.replace(cfg, **{layers_field: self.num_layers})
