@@ -570,10 +570,14 @@ def phase_kernels(meter: CompileMeter, interpret: bool = False,
     ph: Dict[str, Any] = {"interpret": interpret, "cases": [], "probe": []}
 
     def paged_case(name, dtype=jnp.float32, ps=ps, hd=head_dim, Hq=n_head,
-                   Hkv=n_head, lengths=(0, 7, 8, 23)):
+                   Hkv=n_head, lengths=(0, 7, 8, 23), ppseq=ppseq,
+                   stored=False):
         rng = np.random.RandomState(5)
         x = _paged_inputs(rng, S, Hq, Hkv, hd, ps, ppseq,
                           S * ppseq + 1, lengths, dtype)
+        if stored:  # the engine's form: a row's heads side by side
+            for pool in ("k_pool", "v_pool"):
+                x[pool] = x[pool].reshape(*x[pool].shape[:2], Hkv * hd)
         kn = jnp.asarray(rng.standard_normal((S, Hkv, 1, hd)), dtype)
         vn = jnp.asarray(rng.standard_normal((S, Hkv, 1, hd)), dtype)
         sc = 1.0 / float(np.sqrt(hd))
@@ -678,6 +682,11 @@ def phase_kernels(meter: CompileMeter, interpret: bool = False,
             paged_case("paged_flash_ps4_f32", ps=4, lengths=(0, 3, 4, 11)),
             paged_case("paged_flash_ps8_bf16", dtype=jnp.bfloat16),
             paged_case("paged_flash_ps8_hd12_f32", hd=12, Hq=4, Hkv=2),
+            # GPT-2 XL's stored row (25 heads of 64 = 1,600 values, not a
+            # whole number of 128-lane tiles), three blocks of 9 pages
+            paged_case("paged_flash_ps16_w1600_bf16", dtype=jnp.bfloat16,
+                       ps=16, hd=64, Hq=25, Hkv=25, ppseq=20,
+                       lengths=(0, 143, 144, 300), stored=True),
             ragged_case("paged_flash_ragged_ps8_q7_f32", q_tokens=7,
                         q_lens=(7, 7, 5, 1)),
         ] + latent_cases()

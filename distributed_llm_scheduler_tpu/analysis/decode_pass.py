@@ -151,20 +151,23 @@ def analyze_decode(
             )
 
     # DEC005: fused-kernel eligibility of the pool geometry --------------
+    # a kv pool is stored (n_pages, page_size, n_kv_heads * head_dim)
+    # (``models/kv_pages.CacheSpec``); the paged builder stamps the
+    # head_dim that splits its row on the graph.  A latent pool has no
+    # heads and goes by its own rules (``mla_kernel_constraints``).
     pool_spec = None
-    if paged and param_specs:
+    hd = getattr(graph, "kv_head_dim", None)
+    if paged and param_specs and hd:
         pool_spec = next(
-            (
-                param_specs[p]
-                for p in sorted(param_specs)
-                if _is_cache_param(p) and getattr(param_specs[p], "ndim", 0) == 4
-            ),
+            (param_specs[p] for p in sorted(param_specs)
+             if p.startswith("cache_k_")),
             None,
         )
         if pool_spec is not None:
             from ..ops.attention import paged_kernel_constraints
 
-            _n_pages, page_size, n_kv, hd = pool_spec.shape
+            _n_pages, page_size, width = pool_spec.shape
+            n_kv = width // hd   # DEC006 below reads these too
             violated = paged_kernel_constraints(
                 page_size, hd, n_kv, dtype=pool_spec.dtype
             )
@@ -192,7 +195,6 @@ def analyze_decode(
         if pool_spec is not None:
             from ..ops.attention import paged_kernel_constraints
 
-            _n_pages, page_size, n_kv, hd = pool_spec.shape
             ragged_violated = paged_kernel_constraints(
                 page_size, hd, n_kv, dtype=pool_spec.dtype,
                 q_tokens=int(chunk_tokens),

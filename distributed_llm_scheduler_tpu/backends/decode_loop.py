@@ -235,8 +235,7 @@ def compose_paged_step_fn(
             o = outs[f"layer_{i}"]
             for kind in spec.kinds:
                 new_pools[f"cache_{kind}_{i}"] = write_token_rows(
-                    new_pools[f"cache_{kind}_{i}"],
-                    spec.step_rows(o[f"{kind}_new"]),
+                    new_pools[f"cache_{kind}_{i}"], o[f"{kind}_new"],
                     page_table, lengths, active,
                 )
             if "stats" in o:
@@ -418,7 +417,7 @@ class PagedDecodeEngine:
             self.resolved_attention_impl = resolve_paged_impl(
                 self.attention_impl,
                 (slots, getattr(config, "n_head", n_kv), 1, hd),
-                (pool.n_pages, pool.page_size, n_kv, hd),
+                (pool.n_pages, pool.page_size, n_kv * hd),
                 config.dtype,
             )
             block_pages = paged_block_pages(

@@ -1147,8 +1147,8 @@ def _paged_op_parity(kernel_impl: str, page_size: int = 16) -> Dict[str, Any]:
             _paged_op_parity_fixtures(ps):
         n_pages = S * ppseq + 1
         q = jnp.asarray(rng.randn(S, Hq, 1, hd), jnp.float32)
-        k_pool = jnp.asarray(rng.randn(n_pages, ps, Hkv, hd), jnp.float32)
-        v_pool = jnp.asarray(rng.randn(n_pages, ps, Hkv, hd), jnp.float32)
+        k_pool = jnp.asarray(rng.randn(n_pages, ps, Hkv * hd), jnp.float32)
+        v_pool = jnp.asarray(rng.randn(n_pages, ps, Hkv * hd), jnp.float32)
         # poison the trash page: parity then also proves the masking
         k_pool = k_pool.at[TRASH_PAGE].set(1e9)
         v_pool = v_pool.at[TRASH_PAGE].set(1e9)
@@ -1227,8 +1227,8 @@ def _ragged_op_parity(
             _ragged_op_parity_fixtures(ps):
         n_pages = S * ppseq + 1
         q = jnp.asarray(rng.randn(S, Hq, Tn, hd), jnp.float32)
-        k_pool = jnp.asarray(rng.randn(n_pages, ps, Hkv, hd), jnp.float32)
-        v_pool = jnp.asarray(rng.randn(n_pages, ps, Hkv, hd), jnp.float32)
+        k_pool = jnp.asarray(rng.randn(n_pages, ps, Hkv * hd), jnp.float32)
+        v_pool = jnp.asarray(rng.randn(n_pages, ps, Hkv * hd), jnp.float32)
         k_pool = k_pool.at[TRASH_PAGE].set(1e9)
         v_pool = v_pool.at[TRASH_PAGE].set(1e9)
         pt = np.full((S, ppseq), TRASH_PAGE, np.int32)
@@ -1391,7 +1391,7 @@ def measure_paged_kernel(
         "kernel_impl": kernel_impl,
         "kernel_geometry_eligible": bool(paged_pallas_supported(
             (slots, n_kv_heads, 1, head_dim),
-            (n_pages, page_size, n_kv_heads, head_dim),
+            (n_pages, page_size, n_kv_heads * head_dim),
         )),
         "n_requests": n_requests,
         "useful_tokens": useful_tokens,

@@ -656,7 +656,8 @@ def build_paged_decode_dag(
     PagedDecodeEngine`.
 
     The dense decode DAG's per-layer ``cache_k_{i}``/``cache_v_{i}``
-    slabs become shared page POOLS ``(n_pages, page_size, H, hd)`` and
+    slabs become shared page POOLS ``(n_pages, page_size, H * hd)`` (the
+    stored form of :class:`...models.kv_pages.CacheSpec`) and
     every layer task additionally aliases the ``page_table`` param
     ``(slots, pages_per_seq) int32`` — so placement and the analysis
     passes see the paged cache's real residency: the pool bytes are the
@@ -679,7 +680,7 @@ def build_paged_decode_dag(
     name carries it, so schedules/compile caches keyed on the graph
     never alias two impls.
     """
-    from ..models.kv_pages import TRASH_PAGE, init_paged_kv
+    from ..models.kv_pages import TRASH_PAGE
     from ..ops.attention import paged_decode_attention, resolve_attention_impl
 
     if attention_impl is not None:
@@ -701,15 +702,13 @@ def build_paged_decode_dag(
     eps = config.ln_eps
     scale = 1.0 / math.sqrt(hd)
 
+    spec = cache_spec(config)
     specs = {
         name: jax.ShapeDtypeStruct(shape, dtype)
         for name, (shape, dtype) in gpt2.param_shapes(config).items()
     }
-    for i in range(config.n_layer):
-        for kind in ("k", "v"):
-            specs[f"cache_{kind}_{i}"] = jax.ShapeDtypeStruct(
-                (n_pages, ps, H, hd), config.dtype
-            )
+    specs.update(jax.eval_shape(
+        lambda: spec.init_pools(n_pages, ps, config.dtype)))
     specs["page_table"] = jax.ShapeDtypeStruct((S, pages_per_seq), jnp.int32)
     input_spec = {
         "ids": jax.ShapeDtypeStruct((S, 1), jnp.int32),
@@ -801,9 +800,7 @@ def build_paged_decode_dag(
 
     def init_fn(key):
         params = gpt2.init_params(config, key)
-        params.update(init_paged_kv(
-            config.n_layer, n_pages, ps, H, hd, config.dtype
-        ))
+        params.update(spec.init_pools(n_pages, ps, config.dtype))
         params["page_table"] = jnp.full(
             (S, pages_per_seq), TRASH_PAGE, jnp.int32
         )
@@ -825,11 +822,11 @@ def build_paged_decode_dag(
         for s in range(S):
             cache = {
                 "k": jnp.stack([
-                    gather_kv(params[f"cache_k_{i}"], pt[s:s + 1])
+                    gather_kv(params[f"cache_k_{i}"], pt[s:s + 1], hd)
                     for i in range(config.n_layer)
                 ]),
                 "v": jnp.stack([
-                    gather_kv(params[f"cache_v_{i}"], pt[s:s + 1])
+                    gather_kv(params[f"cache_v_{i}"], pt[s:s + 1], hd)
                     for i in range(config.n_layer)
                 ]),
             }
@@ -840,9 +837,13 @@ def build_paged_decode_dag(
             outs.append(logits)
         return jnp.concatenate(outs, axis=0)
 
-    return _finish_paged_dag(
+    dag = _finish_paged_dag(
         tasks, name, config, input_spec, specs, reference_forward, init_fn,
         S, ps, pages_per_seq, attention_impl)
+    # what splits a stored K/V row into heads: the DEC005 / DEC006
+    # eligibility checks see the graph and its param specs only
+    dag.graph.kv_head_dim = hd
+    return dag
 
 
 def build_decode_dag_any(config: Any, **kw) -> ModelDAG:

@@ -17,10 +17,10 @@ Three pieces, split by where they run:
   off the device's reported memory via
   :func:`..utils.costmodel.device_hbm_bytes`).  Pure Python; never
   traced.
-* :func:`init_paged_kv` — the device-side per-layer page pools
-  (``(n_pages, page_size, n_kv_heads, head_dim)`` — the kernel-natural
-  layout the ragged-paged-attention TPU kernels consume, pages on the
-  leading axis so one gather assembles a sequence).
+* :class:`CacheSpec` / :func:`init_paged_kv` — the device-side per-layer
+  page pools, ``(n_pages, page_size, row_width)``: a token's cached
+  values as one vector on the lanes, pages on the leading axis so one
+  gather assembles a sequence and a kernel reads a page where it lies.
 * scatter helpers (:func:`write_token_kv`, :func:`write_prompt_kv`) —
   static-shape jittable writes: one token's K/V row into its page slot
   (traced page id + slot), or a whole prefilled prompt page-reshaped
@@ -503,8 +503,23 @@ class CacheSpec:
     heads ahead of positions, ``(L, b, Hkv, cap, hd)``.  ``kind``
     ``"latent"``: one pool a layer, ``cache_c_{i}``, row ``(width,)`` —
     MLA's normalised latent and shared rotated key; dense ``(L, b, cap,
-    width)``.  ``rows`` lists ``(pool kind, row shape)``; pools are
-    ``(n_pages, page_size, *row)`` in both."""
+    width)``.  ``rows`` lists ``(pool kind, row shape)``.
+
+    The stored form is one for every kind: a pool is ``(n_pages,
+    page_size, row_width)``, the row's values flattened into ONE vector
+    that lies on the lanes, pages major.  The device holds
+    :func:`...ops.attention.lane_width` lanes for a row (whole 128-lane
+    tiles) and a paged kernel reads a page as a ``(page_size,
+    row_width)`` block of the argument itself.  Why one vector and not
+    ``(heads, head_dim)``: the v5e compiler's default layout puts on the
+    lanes whichever dimension pads least to whole tiles, and behind a
+    64-wide ``head_dim`` that was the page INDEX, so every kernel call
+    paid a transposing copy of the whole pool (PERF.md section 4).  A kv
+    row is exactly ``n_kv_heads * head_dim`` wide — the kernel tells the
+    heads apart by ``head_dim`` — and GPT-2 XL's 1,600 keeps the lanes
+    beside a 16-row page at every pool size that fits; a latent row,
+    whose 128-row page pads nothing, is padded by its model to
+    ``lane_width`` (576 -> 640) to keep them."""
 
     kind: str
     n_layers: int
@@ -521,9 +536,10 @@ class CacheSpec:
 
     def init_pools(self, n_pages: int, page_size: int,
                    dtype: Any) -> Dict[str, jax.Array]:
-        """Zeroed pools keyed ``cache_{kind}_{i}``."""
+        """Zeroed pools keyed ``cache_{kind}_{i}``, in the stored form."""
         return {
-            f"cache_{kind}_{i}": jnp.zeros((n_pages, page_size, *row), dtype)
+            f"cache_{kind}_{i}": jnp.zeros(
+                (n_pages, page_size, math.prod(row)), dtype)
             for i in range(self.n_layers) for kind, row in self.rows
         }
 
@@ -543,11 +559,6 @@ class CacheSpec:
         if self.kind == "kv":
             return dense_layer.transpose(0, 2, 1, 3)
         return dense_layer
-
-    def step_rows(self, new: jax.Array) -> jax.Array:
-        """A decode step's new rows ``(S, *row)`` out of a layer task's
-        ``{kind}_new`` output (kv tasks emit ``(S, Hkv, 1, hd)``)."""
-        return new[:, :, 0, :] if self.kind == "kv" else new
 
     def gather(self, cache: Dict[str, Any], pools: Dict[str, Any],
                pages: jax.Array, batch: int, n_rows: int) -> Dict[str, Any]:
@@ -576,7 +587,7 @@ class CacheSpec:
         for i in range(self.n_layers):
             for kind, row in self.rows:
                 rows = self.to_rows(cache[kind][i])
-                paged = rows.reshape(pages.shape[0], page_size, *row)
+                paged = rows.reshape(pages.shape[0], page_size, -1)
                 pool = new[f"cache_{kind}_{i}"]
                 new[f"cache_{kind}_{i}"] = pool.at[pages].set(
                     paged.astype(pool.dtype), mode="drop")
@@ -594,9 +605,9 @@ def init_paged_kv(
     """Zeroed per-layer page pools keyed ``cache_k_{i}`` / ``cache_v_{i}``
     — the same naming contract the dense decode DAG uses, so
     ``split_cache_params`` and the analysis passes treat paged and dense
-    caches uniformly.  Layout ``(n_pages, page_size, n_kv_heads,
-    head_dim)``: pages lead, so assembling a sequence is one gather on
-    axis 0."""
+    caches uniformly.  Stored form ``(n_pages, page_size, n_kv_heads *
+    head_dim)`` (:class:`CacheSpec`): pages lead, so assembling a
+    sequence is one gather on axis 0."""
     row = (n_kv_heads, head_dim)
     return CacheSpec("kv", n_layers, (("k", row), ("v", row))).init_pools(
         n_pages, page_size, dtype)
@@ -619,24 +630,6 @@ def page_table_array(
     return jnp.asarray(rows, jnp.int32)
 
 
-def write_token_kv(
-    pool: jax.Array,
-    new: jax.Array,
-    page_table: jax.Array,
-    lengths: jax.Array,
-    active: jax.Array,
-) -> jax.Array:
-    """Scatter one step's K (or V) rows into their page slots.
-
-    ``pool`` (P, ps, Hkv, hd); ``new`` (S, Hkv, 1, hd) — this step's row
-    per slot; ``page_table`` (S, pages_per_seq) int32; ``lengths`` (S,)
-    int32 — tokens already cached per slot (the write position);
-    ``active`` (S,) bool.  Inactive slots write the trash page, so the
-    scatter stays static-shape under an admission/retirement mask.
-    """
-    return write_token_rows(pool, new[:, :, 0, :], page_table, lengths, active)
-
-
 def write_token_rows(
     pool: jax.Array,
     rows: jax.Array,
@@ -644,19 +637,32 @@ def write_token_rows(
     lengths: jax.Array,
     active: jax.Array,
 ) -> jax.Array:
-    """:func:`write_token_kv` for rows already ``(S, *row)`` — whatever a
-    row is (``(Hkv, hd)`` or a latent ``(width,)``)."""
-    n_pages, ps = pool.shape[0], pool.shape[1]
+    """Scatter one step's rows into their page slots.
+
+    ``pool`` (P, ps, row_width); ``rows`` (S, ...) — this step's row per
+    slot, its ``row_width`` values in whatever shape a layer task emits
+    them (K or V heads ``(Hkv, 1, hd)``, a latent ``(width,)``);
+    ``page_table`` (S, pages_per_seq) int32; ``lengths`` (S,) int32 —
+    tokens already cached per slot (the write position); ``active`` (S,)
+    bool.  Inactive slots write the trash page, so the scatter stays
+    static-shape under an admission/retirement mask.
+    """
+    n_pages, ps, width = pool.shape
     s_idx = jnp.arange(page_table.shape[0])
     logical = jnp.where(active, lengths // ps, 0)
     pid = jnp.where(active, page_table[s_idx, logical], TRASH_PAGE)
     slot = jnp.where(active, lengths % ps, 0)
-    rows = rows.astype(pool.dtype)
+    rows = rows.reshape(rows.shape[0], width).astype(pool.dtype)
     # flat row index: one 1-D scatter instead of a 2-D one (inactive
     # slots land in the trash page's row 0)
-    flat = pool.reshape(n_pages * ps, *pool.shape[2:])
+    flat = pool.reshape(n_pages * ps, width)
     flat = flat.at[pid * ps + slot].set(rows, mode="drop")
     return flat.reshape(pool.shape)
+
+
+#: the K/V name of :func:`write_token_rows` (one function since the pools
+#: hold every kind's row as one vector)
+write_token_kv = write_token_rows
 
 
 def write_prompt_kv(
@@ -675,16 +681,16 @@ def write_prompt_kv(
         raise ValueError(
             f"rows cover {rows.shape[0]} tokens, pages cover {n_pg * ps}"
         )
-    paged = rows.reshape(n_pg, ps, *rows.shape[1:]).astype(pool.dtype)
+    paged = rows.reshape(n_pg, ps, -1).astype(pool.dtype)
     return pool.at[pages].set(paged, mode="drop")
 
 
 def gather_kv(
-    pool: jax.Array, page_table: jax.Array
+    pool: jax.Array, page_table: jax.Array, head_dim: int
 ) -> jax.Array:
     """Assemble per-sequence contiguous KV views from the pool.
 
-    ``pool`` (P, ps, Hkv, hd), ``page_table`` (S, n_pg) ->
+    ``pool`` (P, ps, Hkv * hd), ``page_table`` (S, n_pg) ->
     ``(S, Hkv, n_pg * ps, hd)`` — the dense-cache orientation
     (:func:`..models.decode.cached_attention`), so downstream attention
     math is shared verbatim with the dense path.  Unallocated table
@@ -695,31 +701,26 @@ def gather_kv(
     right for oracles and tests; the hot attention path uses
     :func:`gather_kv_flat` instead.
     """
-    S, n_pg = page_table.shape
-    ps, hkv, hd = pool.shape[1], pool.shape[2], pool.shape[3]
-    pages = jnp.take(pool, page_table.reshape(-1), axis=0)
-    view = pages.reshape(S, n_pg, ps, hkv, hd)
-    return view.transpose(0, 3, 1, 2, 4).reshape(S, hkv, n_pg * ps, hd)
+    return gather_kv_flat(pool, page_table, head_dim).transpose(0, 2, 1, 3)
 
 
 def gather_kv_flat(
-    pool: jax.Array, page_table: jax.Array
+    pool: jax.Array, page_table: jax.Array, head_dim: int
 ) -> jax.Array:
     """Token-major per-sequence view: ``(S, n_pg * ps, Hkv, hd)``.
 
     Same gather as :func:`gather_kv` but WITHOUT the transpose to the
     dense orientation — the reshape is free on the gather's contiguous
-    output (pages arrive token-major already), so this is the layout the
-    per-step XLA attention path uses; the caller permutes its
-    ``dot_general`` batch dims instead of the data.  Token order is
-    identical to the dense view's, so score/softmax reductions see the
-    same operands in the same logical order (the bitwise-parity
-    invariant the op tests pin).
+    output (pages arrive token-major already, a row's heads side by
+    side), so this is the layout the per-step XLA attention path uses;
+    the caller permutes its ``dot_general`` batch dims instead of the
+    data.  Token order is identical to the dense view's, so
+    score/softmax reductions see the same operands in the same logical
+    order (the bitwise-parity invariant the op tests pin).
     """
     S, n_pg = page_table.shape
-    ps, hkv, hd = pool.shape[1], pool.shape[2], pool.shape[3]
     pages = jnp.take(pool, page_table.reshape(-1), axis=0)
-    return pages.reshape(S, n_pg * ps, hkv, hd)
+    return pages.reshape(S, n_pg * pool.shape[1], -1, head_dim)
 
 
 def paged_param_bytes(

@@ -48,7 +48,11 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from ..ops.attention import mla_paged_decode_attention, resolve_attention_impl
+from ..ops.attention import (
+    lane_width,
+    mla_paged_decode_attention,
+    resolve_attention_impl,
+)
 
 #: cache rows the expanded prefill attention rebuilds K/V for at a time
 PREFILL_KV_BLOCK = 1024
@@ -157,8 +161,9 @@ class Xing4Config:
 
 def latent_row_width(cfg: Xing4Config) -> int:
     """Values in one cached row: ``[c | k_r]`` padded to whole 128-lane
-    tiles (what the device holds for it in any case)."""
-    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+    tiles (what the device holds for it in any case; why it matters is
+    in :class:`.kv_pages.CacheSpec`)."""
+    return lane_width(cfg.kv_lora_rank + cfg.qk_rope_head_dim)
 
 
 # -- parameters -----------------------------------------------------------------

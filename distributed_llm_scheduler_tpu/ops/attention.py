@@ -240,7 +240,7 @@ def paged_kernel_constraints(
     takes the gather path when non-empty, an explicit ``"pallas"``
     raises), the DEC005 analysis warning (which quotes these strings
     verbatim), and the docs.  The kernels move K/V one ``(page_size,
-    n_kv_heads, head_dim)`` page at a time — the single-token kernel
+    n_kv_heads * head_dim)`` page at a time — the single-token kernel
     several such page refs a grid step, a block of
     :func:`paged_block_pages` pages, the block rule and its VMEM
     reckoning being stated once over :func:`_paged_kernel`; the rules
@@ -294,17 +294,22 @@ def paged_pallas_supported(
 ) -> bool:
     """Eligibility of the ragged paged kernel for this call.
 
-    Structural preconditions (every mode): query heads an exact multiple
-    of KV heads, matching head_dim, at least one query token (Tn == 1 is
-    the decode step; Tn > 1 is a ragged prefill chunk with per-slot
-    ``q_lens``).  Compiled mode additionally requires the
+    ``pool_shape`` is the stored ``(n_pages, page_size, row_width)`` or
+    the head-split ``(n_pages, page_size, n_kv_heads, head_dim)`` form
+    (:func:`_stored_rows`).  Structural preconditions (every mode): the
+    row a whole number of ``head_dim`` heads, query heads an exact
+    multiple of them, at least one query token (Tn == 1 is the decode
+    step; Tn > 1 is a ragged prefill chunk with per-slot ``q_lens``).
+    Compiled mode additionally requires the
     :func:`paged_kernel_constraints` tiling rules at the POOL's ``dtype``
     (the sublane tile depends on it); interpret mode (CPU parity tests)
     has no tiling constraints.
     """
     S, Hq, Tn, hd = q_shape
-    n_pages, page_size, Hkv, pool_hd = pool_shape
-    if Tn < 1 or Hkv < 1 or Hq % Hkv or hd != pool_hd:
+    page_size, width = pool_shape[1], math.prod(pool_shape[2:])
+    Hkv = width // hd
+    if Tn < 1 or Hkv < 1 or width % hd or Hq % Hkv or (
+            len(pool_shape) == 4 and pool_shape[3] != hd):
         return False
     if interpret:
         return True
@@ -364,52 +369,63 @@ def mha(
 # One grid step covers a BLOCK of ``pages_per_block`` consecutive logical
 # pages of one slot.  The rule (stated once, here; the engine's
 # ``decode.kv_live_block_share`` counter and the tests read it through
-# :func:`paged_block_pages`): a block is as many pages as keep the
-# pipeline's K and V page buffers (2 pools x 2 buffers a page) inside
-# ``_PAGED_BUFFER_BYTES`` of VMEM at the pool's dtype, a page counted as
-# the tile-padded bytes it occupies there (heads padded to the dtype's
-# sublane tile, head_dim to 128 lanes), and never more than the table
-# holds — a table shorter than one block IS one block.  At GPT-2 XL's
-# serving geometry (page 16, 25 heads of 64, bf16) a page is 128 KiB, so a
-# block is 4 pages = 64 rows; the narrowest page the layout allows (one
-# sublane tile of heads, 128 lanes) gives 128 rows.  Compute is one page
-# at a time, so the f32 working set is a page's (K, V, scores), not a
-# block's, and the 16 MiB default VMEM scope holds buffers and all.  The
-# budget is the v5e's measured optimum (PERF.md section 6, PR 25): every
-# slot costs at least one step of one page (~0.9 us), a live page ~0.45
-# us, and a step ~0.03 us for each page ref it carries whether the page
-# is live or not — so budgets of 1 / 2 / 4 / 8 MiB read 126 / 128 / 138 /
-# 152 us a call at the chat cell's load and 1.00-1.02 ms at a full table.
+# :func:`paged_block_pages` / :func:`latent_block_pages`): a block is as
+# many pages as keep the pipeline's page buffers (two a page and pool)
+# inside ``_PAGED_BUFFER_BYTES`` of VMEM at the pool's dtype, a page
+# counted as the bytes it occupies there — ``page_size`` rows of
+# :func:`lane_width` values — and never more than the table holds: a
+# table shorter than one block IS one block.  At GPT-2 XL's serving
+# geometry (page 16, a row of 25 x 64 = 1,600 values, bf16) a K or V page
+# is 52 KiB, so a block is 9 pages = 144 rows; a latent page of 128 rows
+# of 640 is 160 KiB and a block 6 pages.  The K/V kernel computes a block
+# at a time — its pages stacked into one (rows_per_block, row_width)
+# operand, so the MXU makes two passes over 144 rows where nine pages took
+# eighteen — and the latent kernel a page at a time; either way the
+# working set is well inside the 16 MiB default VMEM scope.  The budget is
+# the v5e's measured optimum (PERF.md section 6, PR 25 and PR 28): every
+# slot costs at least one step, and a step pays ~0.04 us for each page ref
+# it carries whether the page is live or not, so a smaller block is
+# cheaper where most slots hold nothing and a larger one where most are
+# full.
 _PAGED_BUFFER_BYTES = 2 << 20
+
+
+def lane_width(values: int) -> int:
+    """Lanes the device holds for a row of ``values``: whole 128-lane
+    tiles.  The one reckoning of a stored row's width: a page's bytes
+    (:func:`_block_pages`) and the latent row a model pads itself to
+    (:func:`...models.xing4.latent_row_width`) both come from it."""
+    return -(-values // 128) * 128
+
+
+def _block_pages(
+    page_size: int, pages_per_seq: int, row_width: int, dtype: Any,
+    pools: int,
+) -> int:
+    page_bytes = page_size * lane_width(row_width) * jnp.dtype(dtype).itemsize
+    return max(1, min(
+        pages_per_seq, _PAGED_BUFFER_BYTES // (2 * pools * page_bytes)))
 
 
 def paged_block_pages(
     page_size: int, pages_per_seq: int, n_kv_heads: int, head_dim: int,
     dtype: Any,
 ) -> int:
-    """Pages in one block of the single-token paged kernel's walk, from
-    what the call can observe (the rule is in the comment above).
+    """Pages in one block of the single-token paged kernel's walk over
+    the K and V pools, from what the call can observe (the rule is in
+    the comment above; a row is ``n_kv_heads * head_dim`` values).
     ``page_size *`` this is ``rows_per_block``: slot ``s`` costs
     ``cdiv(min(L_s, capacity - 1) + 1, rows_per_block)`` live blocks."""
-    sublane = _sublane_rows(dtype)
-    page_bytes = (
-        page_size * -(-n_kv_heads // sublane) * sublane
-        * -(-head_dim // 128) * 128 * jnp.dtype(dtype).itemsize
-    )
-    return max(1, min(pages_per_seq, _PAGED_BUFFER_BYTES // (4 * page_bytes)))
+    return _block_pages(
+        page_size, pages_per_seq, n_kv_heads * head_dim, dtype, pools=2)
 
 
 def latent_block_pages(
     page_size: int, pages_per_seq: int, row_width: int, dtype: Any,
 ) -> int:
-    """:func:`paged_block_pages` for the latent pool of
-    :func:`_mla_paged_flash`: one pool, so two buffers a page, and a
-    page is ``page_size`` rows of ``row_width`` values padded to whole
-    128-lane tiles — the same VMEM budget, the same rule."""
-    page_bytes = (
-        page_size * -(-row_width // 128) * 128 * jnp.dtype(dtype).itemsize
-    )
-    return max(1, min(pages_per_seq, _PAGED_BUFFER_BYTES // (2 * page_bytes)))
+    """:func:`paged_block_pages` for the one latent pool of
+    :func:`_mla_paged_flash`: the same VMEM budget, the same rule."""
+    return _block_pages(page_size, pages_per_seq, row_width, dtype, pools=1)
 
 
 def _live_block_tables(page_table, lengths, page_size: int, ppb: int):
@@ -442,9 +458,25 @@ def _live_block_tables(page_table, lengths, page_size: int, ppb: int):
     return slot_of, block_of, fetch, lengths, ends[-1]
 
 
+def _stored_rows(pool):
+    """A K or V pool in the stored form ``(n_pages, page_size,
+    row_width)``, the row its ``n_kv_heads * head_dim`` values as one
+    vector on the lanes (:class:`...models.kv_pages.CacheSpec`).  The
+    head-split ``(n_pages, page_size, n_kv_heads, head_dim)`` form is
+    taken as a view of it."""
+    return pool.reshape(*pool.shape[:2], -1)
+
+
+def _head_rows(pool, head_dim: int):
+    """The head-split view ``(n_pages, page_size, n_kv_heads, head_dim)``
+    of a pool in either form: what the paths off the serving path read
+    (:func:`_paged_flash_ragged`, the gather paths)."""
+    return pool.reshape(*pool.shape[:2], -1, head_dim)
+
+
 def _paged_kernel(
     slot_ref, block_ref, fetch_ref, len_ref, q_ref, kn_ref, vn_ref, *refs,
-    sm_scale, page_size, pages_per_seq, pages_per_block, groups, has_new,
+    page_size, pages_per_seq, pages_per_block, head_dim, has_new,
 ):
     """One LIVE (slot, page block) of the ragged paged kernel.
 
@@ -452,98 +484,131 @@ def _paged_kernel(
     list (:func:`_paged_flash` works it out from the lengths): step ``t``
     is block ``block_ref[t]`` of slot ``slot_ref[t]``.  ``refs`` holds
     ``pages_per_block`` K page refs, as many V page refs, the output and
-    the online-softmax scratch.  Each page ref is ONE physical page: its
-    BlockSpec index map reads ``fetch_ref``, so the DMA engine fetches
-    exactly the slot's live pages and the gathered view never exists in
-    HBM.  Slot ``s`` attends rows ``0 .. last`` with ``last =
-    min(lengths[s], capacity - 1)``; its live pages are ``0 .. last //
-    page_size`` and its live blocks the ``cdiv`` of that.  A block past
-    it is not in the list, and inside the last live block a page past
-    ``last`` is neither fetched nor computed, so a slot at length 0 —
-    every slot the engine is not decoding — costs one step of one page.
+    the scratch.  Each page ref is ONE physical page where it lies in
+    the pool, ``(page_size, row_width)`` with every KV head's values side
+    by side on the lanes: its BlockSpec index map reads ``fetch_ref``, so
+    the DMA engine fetches exactly the slot's live pages and the gathered
+    view never exists in HBM.  Slot ``s`` attends rows ``0 .. last`` with
+    ``last = min(lengths[s], capacity - 1)``; its live pages are ``0 ..
+    last // page_size`` and its live blocks the ``cdiv`` of that.  A
+    block past it is not in the list; inside the last live block a page
+    past ``last`` is not fetched, and what its ref holds is masked.
 
-    The online-softmax carry (``acc``/``m``/``l`` VMEM scratch,
-    persistent across grid steps) is initialized at a slot's first block
-    and folded into the output at its last live one.  Only the page
-    holding row ``last`` is ragged: there rows past ``lengths[s]`` get a
-    −inf score and a zeroed V row (whatever they hold, NaN included,
-    reaches nothing), and ``has_new`` statically compiles in the
-    write-then-attend insert — this step's K/V row substituted at
+    Heads are told apart on the MXU, not by splitting the lanes.
+    ``q_ref`` is ``(groups, row_width)``: group ``g``'s scaled query
+    heads side by side as the pools hold their KV heads.  A slot's first
+    block spreads it into the MASKED query ``qm`` (scratch), one row a
+    query head, holding that head's query in its KV head's lanes and
+    zeros in all others (row ``g * heads_p + h`` is head ``h`` of group
+    ``g``, a group's heads padded to a sublane tile).  So ``qm . K^T``
+    over the whole row is each head's own scores — the other heads'
+    lanes add products with an exact zero — and ``p . V`` gives every
+    row all heads' values, of which the finalize keeps the row's own
+    lanes.  The output is ``(groups, row_width)`` like the query.
+
+    A block is computed at once: its pages stacked into one
+    ``(rows_per_block, row_width)`` K and one V, one score matmul, one
+    online-softmax update (``acc``/``m``/``l`` VMEM scratch, persistent
+    across grid steps, initialized at a slot's first block and folded
+    into the output at its last live one) and one value matmul.  Only the
+    block holding row ``last`` is ragged: there rows past ``last`` get a
+    −inf score and a zeroed V row by selection (whatever they hold, NaN
+    included, reaches nothing), and ``has_new`` statically compiles in
+    the write-then-attend insert — this step's K/V row substituted at
     ``last`` before the scores (clamped to the capacity's last row like
-    the gather path's ``dynamic_update_slice``).  Pages before it are
-    wholly live and take the plain path.
+    the gather path's ``dynamic_update_slice``).  Blocks before it are
+    wholly live and take the plain path.  With the insert, a slot at
+    length 0 — every slot the engine is not decoding — attends its own
+    row alone: the softmax of one score is 1 and the output that row's
+    values, so the step reads no page and multiplies nothing.
     """
     del fetch_ref  # read by the page BlockSpecs' index maps only
     ppb = pages_per_block
     k_refs, v_refs = refs[:ppb], refs[ppb:2 * ppb]
-    o_ref, acc_ref, m_ref, l_ref = refs[2 * ppb:]
+    o_ref, qm_ref, acc_ref, m_ref, l_ref = refs[2 * ppb:]
+    groups = q_ref.shape[1]
+    heads_p = qm_ref.shape[0] // groups
     t = pl.program_id(0)
     s_idx = slot_ref[t]
     j = block_ref[t]
+    L = len_ref[s_idx]
+    last = jnp.minimum(L, pages_per_seq * page_size - 1)
+    last_page = last // page_size
 
-    @pl.when(j == 0)
+    if has_new:
+        @pl.when(L == 0)
+        def _new_row_only():
+            o_ref[0] = jnp.broadcast_to(
+                vn_ref[0], o_ref.shape[1:]).astype(o_ref.dtype)
+
+        when = lambda cond: pl.when(jnp.logical_and(cond, L > 0))
+    else:
+        when = pl.when
+
+    def own_lanes():
+        """(heads_p, row_width): lane belongs to the row's KV head."""
+        shape = (heads_p, qm_ref.shape[1])
+        head = jax.lax.broadcasted_iota(jnp.int32, shape, 0) * head_dim
+        lane = jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        return (lane >= head) & (lane < head + head_dim)
+
+    @when(j == 0)
     def _init():
+        own = own_lanes()
+        for g in range(groups):
+            qm_ref[g * heads_p:(g + 1) * heads_p] = jnp.where(
+                own, q_ref[0, g:g + 1].astype(jnp.float32), 0.0
+            ).astype(qm_ref.dtype)
         acc_ref[...] = jnp.zeros_like(acc_ref)
         m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    L = len_ref[s_idx]
-    last = jnp.minimum(L, pages_per_seq * page_size - 1)
-    last_page = last // page_size
-    hd = q_ref.shape[-1]
-    Hkv = k_refs[0].shape[2]
-
-    def attend(page, k_ref, v_ref, ragged):
-        q = (q_ref[0].astype(jnp.float32) * sm_scale).reshape(
-            Hkv, groups, hd)
-        k = k_ref[0].astype(jnp.float32)  # (page_size, Hkv, hd)
-        v = v_ref[0].astype(jnp.float32)
+    def attend(ragged):
+        # the block's pages stacked: (pages_per_block * page_size, row_width)
+        k = jnp.concatenate([r[0] for r in k_refs], axis=0)
+        v = jnp.concatenate([r[0] for r in v_refs], axis=0)
+        first_row = j * (ppb * page_size)
         if ragged:
             row = jax.lax.broadcasted_iota(
-                jnp.int32, (page_size, 1, 1), 0) + page * page_size
+                jnp.int32, (k.shape[0], 1), 0) + first_row
             if has_new:
-                sel = row == last
-                k = jnp.where(sel, kn_ref[0].astype(jnp.float32)[None], k)
-                v = jnp.where(sel, vn_ref[0].astype(jnp.float32)[None], v)
-            v = jnp.where(row <= L, v, 0.0)
-        # scores (Hkv, page_size, G): K @ q, the gather path's orientation
+                k = jnp.where(row == last, kn_ref[0], k)
+                v = jnp.where(row == last, vn_ref[0], v)
+            v = jnp.where(row <= last, v, jnp.zeros_like(v))
         s = jax.lax.dot_general(
-            k, q, (((2,), (2,)), ((1,), (0,))),
+            qm_ref[...], k, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )
+        )  # (groups * heads_p, rows of a block)
         if ragged:
-            pos = (
-                jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-                + page * page_size
-            )
-            s = jnp.where(pos <= L, s, _NEG_INF)
-        # position 0 is unmasked for every slot, so after page 0 the
+            pos = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + first_row
+            s = jnp.where(pos <= last, s, _NEG_INF)
+        # position 0 is unmasked for every slot, so after block 0 the
         # running max is a real (finite) score and the exp() arguments
         # stay finite
-        m_prev = m_ref[...]                       # (Hkv, G)
-        m_new = jnp.maximum(m_prev, s.max(axis=1))
+        m_prev = m_ref[...]                       # (rows, 1)
+        m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
-        p = jnp.exp(s - m_new[:, None, :])        # (Hkv, page_size, G)
-        l_ref[...] = l_ref[...] * alpha + p.sum(axis=1)
-        acc_ref[...] = acc_ref[...] * alpha[:, :, None] + (
-            jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((0,), (1,))),
-                preferred_element_type=jnp.float32,
-            )
-        )  # (Hkv, G, hd)
+        p = jnp.exp(s - m_new)
+        l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
+        acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )  # (rows, row_width)
         m_ref[...] = m_new
 
-    for i in range(ppb):
-        page = j * ppb + i
-        pl.when(page < last_page)(
-            functools.partial(attend, page, k_refs[i], v_refs[i], False))
-        pl.when(page == last_page)(
-            functools.partial(attend, page, k_refs[i], v_refs[i], True))
+    when(j < last_page // ppb)(functools.partial(attend, False))
+    when(j == last_page // ppb)(functools.partial(attend, True))
 
-    @pl.when(j == last_page // ppb)
+    @when(j == last_page // ppb)
     def _finalize():
-        out = acc_ref[...] / l_ref[...][:, :, None]
-        o_ref[0] = out.reshape(Hkv * groups, hd).astype(o_ref.dtype)
+        own = own_lanes()
+        for g in range(groups):
+            rows = slice(g * heads_p, (g + 1) * heads_p)
+            num = jnp.where(own, acc_ref[rows], 0.0).sum(
+                axis=0, keepdims=True)
+            den = jnp.where(own, l_ref[rows], 0.0).sum(
+                axis=0, keepdims=True)
+            o_ref[0, g:g + 1] = (num / den).astype(o_ref.dtype)
 
 
 @functools.partial(
@@ -553,7 +618,16 @@ def _paged_flash(
     q, k_pool, v_pool, page_table, lengths, k_new, v_new, *,
     sm_scale, has_new, interpret,
 ):
-    """Fused ragged paged attention whose work follows the live pages.
+    """Fused ragged paged attention whose work follows the live pages,
+    reading every page where the pool holds it.
+
+    ``k_pool`` / ``v_pool`` are in the stored form ``(n_pages,
+    page_size, row_width)`` (:func:`_stored_rows`); a page ref is a
+    ``(1, page_size, row_width)`` block of the argument itself, so no
+    copy of a pool is made in front of the call — and none by XLA's
+    prefetching either: the pools are pinned to HBM, or the compiler
+    moves a whole pool into its fast memory ahead of a call that reads a
+    few pages of it.
 
     The grid is DYNAMIC: one step per live page block, ``sum_s
     cdiv(min(L_s, capacity - 1) + 1, rows_per_block)`` of them
@@ -570,65 +644,68 @@ def _paged_flash(
     the table's capacity; the dense gather's (S, M, Hkv, hd)
     intermediate never exists; the step count is data like the lengths,
     so no length, admission or retirement recompiles.
-
-    Why the page refs and not a hand-written DMA loop over the live
-    pages: the v5e compiler takes a page out of a pool in whole tiles
-    only (a slice of 25 heads of 64 is refused), and padding the pools to
-    the tile costs a pass over both for every call.
     """
     S, Hq, _, hd = q.shape
-    _, page_size, Hkv, _ = k_pool.shape
+    k_pool, v_pool = _stored_rows(k_pool), _stored_rows(v_pool)
+    if not interpret:
+        k_pool = pltpu.with_memory_space_constraint(k_pool, pltpu.HBM)
+        v_pool = pltpu.with_memory_space_constraint(v_pool, pltpu.HBM)
+    dtype = k_pool.dtype
+    _, page_size, W = k_pool.shape
+    Hkv = W // hd
     G = Hq // Hkv
     ppseq = page_table.shape[1]
-    ppb = paged_block_pages(page_size, ppseq, Hkv, hd, k_pool.dtype)
-    q3 = q.reshape(S, Hq, hd)
+    ppb = paged_block_pages(page_size, ppseq, Hkv, hd, dtype)
+    # query head h * G + g -> group g, lanes of KV head h: (S, G, W)
+    qg = (q.astype(jnp.float32) * sm_scale).astype(dtype).reshape(
+        S, Hkv, G, hd).transpose(0, 2, 1, 3).reshape(S, G, W)
     if has_new:
-        kn = k_new.reshape(S, Hkv, hd)
-        vn = v_new.reshape(S, Hkv, hd)
+        kn = k_new.reshape(S, 1, W).astype(dtype)
+        vn = v_new.reshape(S, 1, W).astype(dtype)
     else:  # zero placeholders keep the arity static; kernel never reads
-        kn = jnp.zeros((S, Hkv, hd), k_pool.dtype)
-        vn = jnp.zeros((S, Hkv, hd), v_pool.dtype)
+        kn = vn = jnp.zeros((S, 1, W), dtype)
 
     slot_of, block_of, fetch, lengths, n_live = _live_block_tables(
         page_table, lengths, page_size, ppb)
 
     def page_spec(i):
         return pl.BlockSpec(
-            (1, page_size, Hkv, hd),
-            lambda t, slot, blk, fetch, ln: (fetch[t * ppb + i], 0, 0, 0),
+            (1, page_size, W),
+            lambda t, slot, blk, fetch, ln: (fetch[t * ppb + i], 0, 0),
         )
 
-    def slot_spec(heads):
+    def slot_spec(rows):
         return pl.BlockSpec(
-            (1, heads, hd), lambda t, slot, blk, fetch, ln: (slot[t], 0, 0))
+            (1, rows, W), lambda t, slot, blk, fetch, ln: (slot[t], 0, 0))
 
     pages = [page_spec(i) for i in range(ppb)]
+    rows = G * -(-Hkv // 8) * 8   # a group's heads padded to a sublane tile
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(n_live,),
-        in_specs=[slot_spec(Hq), slot_spec(Hkv), slot_spec(Hkv)]
-        + pages + pages,
-        out_specs=slot_spec(Hq),
+        in_specs=[slot_spec(G), slot_spec(1), slot_spec(1)] + pages + pages,
+        out_specs=slot_spec(G),
         scratch_shapes=[
-            pltpu.VMEM((Hkv, G, hd), jnp.float32),
-            pltpu.VMEM((Hkv, G), jnp.float32),
-            pltpu.VMEM((Hkv, G), jnp.float32),
+            pltpu.VMEM((rows, W), dtype),
+            pltpu.VMEM((rows, W), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
+            pltpu.VMEM((rows, 1), jnp.float32),
         ],
     )
     out = pl.pallas_call(
         functools.partial(
-            _paged_kernel, sm_scale=sm_scale, page_size=page_size,
-            pages_per_seq=ppseq, pages_per_block=ppb, groups=G,
-            has_new=has_new,
+            _paged_kernel, page_size=page_size, pages_per_seq=ppseq,
+            pages_per_block=ppb, head_dim=hd, has_new=has_new,
         ),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, Hq, hd), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, G, W), q.dtype),
         interpret=interpret,
     )(
         slot_of, block_of, fetch, lengths,
-        q3, kn, vn, *([k_pool] * ppb), *([v_pool] * ppb),
+        qg, kn, vn, *([k_pool] * ppb), *([v_pool] * ppb),
     )
-    return out.reshape(S, Hq, 1, hd)
+    return out.reshape(S, G, Hkv, hd).transpose(0, 2, 1, 3).reshape(
+        S, Hq, 1, hd)
 
 
 def _paged_ragged_kernel(
@@ -712,13 +789,16 @@ def _paged_flash_ragged(
 
     A static (slots, pages_per_seq) grid, one table-directed page load
     a step whatever the lengths (the walk :func:`_paged_flash` had before
-    it followed the live blocks; no serving path runs this kernel), with
+    it followed the live blocks; no serving path runs this kernel, and it
+    reads the pools through their head-split view, :func:`_head_rows`),
+    with
     a (1, Hq, Tn, hd) query block per slot and per-slot ``q_lens`` as a
     third scalar-prefetch operand.  No
     in-kernel insert: chunk K/V rows are scattered into the pool before
     the call (write-then-attend at chunk granularity).
     """
     S, Hq, Tn, hd = q.shape
+    k_pool, v_pool = _head_rows(k_pool, hd), _head_rows(v_pool, hd)
     _, page_size, Hkv, _ = k_pool.shape
     G = Hq // Hkv
     ppseq = page_table.shape[1]
@@ -776,8 +856,8 @@ def _gather_chunk_attention(
     from ..models.kv_pages import gather_kv_flat  # lazy: models imports ops
 
     S, Hq, Tn, hd = q.shape
-    k_view = gather_kv_flat(k_pool, page_table)  # (S, M, Hkv, hd)
-    v_view = gather_kv_flat(v_pool, page_table)
+    k_view = gather_kv_flat(k_pool, page_table, hd)  # (S, M, Hkv, hd)
+    v_view = gather_kv_flat(v_pool, page_table, hd)
     Hkv = k_view.shape[2]
     G = Hq // Hkv
     qg = (q * scale).reshape(S, Hkv, G * Tn, hd)
@@ -822,8 +902,10 @@ def paged_decode_attention(
     per-sequence length-masked, static shapes throughout.
 
     ``q`` (S, Hq, 1, hd) — one new token per batch slot; ``k_pool`` /
-    ``v_pool`` (P, page_size, Hkv, hd) — the shared page pools
-    (:mod:`..models.kv_pages`); ``page_table`` (S, pages_per_seq) int32
+    ``v_pool`` (P, page_size, Hkv * hd) — the shared page pools in their
+    stored form, a row's heads side by side on the lanes
+    (:class:`..models.kv_pages.CacheSpec`; the head-split (P, page_size,
+    Hkv, hd) form is taken as a view of it); ``page_table`` (S, pages_per_seq) int32
     — slot ``s``'s logical page ``j`` lives in physical page
     ``page_table[s, j]``; ``lengths`` (S,) int32 — tokens already cached
     per slot.  ``k_new``/``v_new`` (S, Hkv, 1, hd), when given, are this
@@ -903,8 +985,8 @@ def paged_decode_attention(
     # and softmax reductions see the SAME operands in the SAME logical
     # order, so outputs stay bit-identical to the dense-orientation math
     # (pinned by the parity tests).
-    k_view = gather_kv_flat(k_pool, page_table)  # (S, M, Hkv, hd)
-    v_view = gather_kv_flat(v_pool, page_table)
+    k_view = gather_kv_flat(k_pool, page_table, hd)  # (S, M, Hkv, hd)
+    v_view = gather_kv_flat(v_pool, page_table, hd)
     M, Hkv = k_view.shape[1], k_view.shape[2]
     G = Hq // Hkv
 

@@ -182,7 +182,7 @@ def test_explicit_pallas_on_ineligible_paged_geometry_raises():
 
     # 3 query heads over 2 KV heads: no GQA grouping, no kernel
     q = jnp.zeros((2, 3, 1, 8))
-    pool = jnp.zeros((5, 8, 2, 8))
+    pool = jnp.zeros((5, 8, 2 * 8))  # stored form: 2 KV heads of 8
     table, lengths = jnp.zeros((2, 2), jnp.int32), jnp.zeros(2, jnp.int32)
     for impl in ("pallas", "pallas_interpret"):
         with pytest.raises(ValueError, match="requested explicitly"):
@@ -235,11 +235,13 @@ def test_paged_eligibility_uses_the_pool_dtype():
         paged_pallas_supported,
     )
 
-    q, pool = (4, 4, 1, 8), (64, 8, 2, 8)
-    for dtype in (jnp.float32, jnp.bfloat16):
-        assert paged_pallas_supported(q, pool, dtype=dtype) == (
-            not paged_kernel_constraints(8, 8, 2, n_q_heads=4, dtype=dtype)
-        )
+    q = (4, 4, 1, 8)
+    # the stored (pages, page_size, Hkv * hd) form and its head-split view
+    for pool in ((64, 8, 2 * 8), (64, 8, 2, 8)):
+        for dtype in (jnp.float32, jnp.bfloat16):
+            assert paged_pallas_supported(q, pool, dtype=dtype) == (
+                not paged_kernel_constraints(
+                    8, 8, 2, n_q_heads=4, dtype=dtype))
 
 
 def _fake_tpu(kind="TPU v5 lite", stats=None):
@@ -510,7 +512,7 @@ def test_aot_paged_kernel_compiles_for_v5e(v5e, ps, dtype):
     from distributed_llm_scheduler_tpu.ops import attention as A
 
     n_pages = _S * _PPSEQ + 1
-    pool = v5e((n_pages, ps, _H, _HD), dtype)
+    pool = v5e((n_pages, ps, _H * _HD), dtype)
     new = v5e((_S, _H, 1, _HD), dtype)
     jax.jit(lambda q, k, v, pt, ln, kn, vn: A._paged_flash(
         q, k, v, pt, ln, kn, vn, sm_scale=0.125, has_new=True,
@@ -526,7 +528,7 @@ def test_aot_ragged_kernel_compiles_for_v5e(v5e, dtype):
     from distributed_llm_scheduler_tpu.ops import attention as A
 
     ps, Tn = 8, 16
-    pool = v5e((_S * _PPSEQ + 1, ps, _H, _HD), dtype)
+    pool = v5e((_S * _PPSEQ + 1, ps, _H * _HD), dtype)
     jax.jit(lambda q, k, v, pt, ln, ql: A._paged_flash_ragged(
         q, k, v, pt, ln, ql, sm_scale=0.125, interpret=False,
     )).lower(
@@ -534,6 +536,70 @@ def test_aot_ragged_kernel_compiles_for_v5e(v5e, dtype):
         v5e((_S, _PPSEQ), jnp.int32), v5e((_S,), jnp.int32),
         v5e((_S,), jnp.int32),
     ).compile()
+
+
+@pytest.mark.parametrize("n_pages", [513, 2049])
+def test_aot_xl_pools_are_read_where_they_lie(v5e, n_pages):
+    """The copy guard (PR 28).  Two layer-steps at GPT-2 XL's serving
+    geometry — 32 slots, page 16, 64 pages a slot, 25 heads of 64, bf16 —
+    as the segment runs them: kernel, then ``write_token_rows``, pools
+    donated and carried through a scan.  The pools' entry layout keeps
+    the stored row minor-most, so no copy or transpose inside the loop
+    has a pool's shape and the program's temporaries stay far under one
+    pool.  Held ``(pages, 16, 25, 64)`` the page INDEX went to the lanes
+    and every call transposed both whole pools: four copies a
+    layer-step, 70 MB of temporaries."""
+    import re
+
+    from distributed_llm_scheduler_tpu.models.kv_pages import (
+        CacheSpec,
+        write_token_rows,
+    )
+    from distributed_llm_scheduler_tpu.ops import attention as A
+
+    S, ps, ppseq, H, hd, layers = 32, 16, 64, 25, 64, 2
+    spec = CacheSpec("kv", layers, (("k", (H, hd)), ("v", (H, hd))))
+    pools = {k: v5e(v.shape, v.dtype) for k, v in jax.eval_shape(
+        lambda: spec.init_pools(n_pages, ps, jnp.bfloat16)).items()}
+    pool_shape = (n_pages, ps, H * hd)
+    assert {v.shape for v in pools.values()} == {pool_shape}
+    row = v5e((S, H, 1, hd), jnp.bfloat16)
+
+    def seg(pools, q, table, lengths, k_new, v_new):
+        live = jnp.ones((S,), bool)
+
+        def step(carry, _):
+            pools, x, lengths = carry
+            pools = dict(pools)
+            for i in range(layers):
+                k, v = pools[f"cache_k_{i}"], pools[f"cache_v_{i}"]
+                x = q + A._paged_flash(
+                    x, k, v, table, lengths, k_new, v_new,
+                    sm_scale=hd ** -0.5, has_new=True, interpret=False)
+                pools[f"cache_k_{i}"] = write_token_rows(
+                    k, k_new, table, lengths, live)
+                pools[f"cache_v_{i}"] = write_token_rows(
+                    v, v_new, table, lengths, live)
+            return (pools, x, lengths + 1), None
+
+        (pools, x, _), _ = jax.lax.scan(
+            step, (pools, q, lengths), None, length=2)
+        return pools, x
+
+    compiled = jax.jit(seg, donate_argnums=0).lower(
+        pools, row, v5e((S, ppseq), jnp.int32), v5e((S,), jnp.int32),
+        row, row).compile()
+    text = compiled.as_text()
+    dims = ",".join(map(str, pool_shape))
+    entry = re.search(r"entry_computation_layout=\{\((.*?)\)->", text).group(1)
+    layouts = re.findall(rf"bf16\[{dims}\]\{{([\d,]+)", entry)
+    assert len(layouts) == 2 * layers and set(layouts) == {"2,1,0"}, layouts
+    moved = [line.strip()[:160] for line in text.splitlines() if re.search(
+        rf"= bf16\[{dims}\]\S* (copy|transpose|copy-start|copy-done)\(",
+        line)]
+    assert not moved, moved
+    one_pool = n_pages * ps * A.lane_width(H * hd) * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < one_pool / 4
 
 
 def test_aot_serving_segment_compiles_for_v5e_without_weights(v5e):

@@ -37,6 +37,12 @@ NAME = "decode.kv_live_block_share"
     ([319, 0, 256, 255], 128, 320, (3 + 1 + 3 + 2) / 12),
     # the tiny serving geometry: the whole table is one block
     ([0, 5, 31, 17], 32, 32, 1.0),
+    # GPT-2 XL's table since the pools hold the row on the lanes (PR 28):
+    # a block is 9 pages = 144 rows, 8 blocks a slot, the last of 16 rows
+    ([130, 900, 350, 512, 260, 700, 445] + [0] * 25, 144, 1024,
+     (1 + 7 + 3 + 4 + 2 + 5 + 4 + 25) / 256),
+    ([0] * 32, 144, 1024, 32 / 256),
+    ([143, 144, 1007, 1008, 1023], 144, 1024, (1 + 2 + 7 + 8 + 8) / 40),
 ])
 def test_share_of_fixed_lengths(lengths, rows, capacity, want):
     got = kv_live_block_share(

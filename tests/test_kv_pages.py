@@ -260,17 +260,22 @@ def _rand(key, shape):
 
 def test_prompt_write_gather_roundtrip():
     ps, hkv, hd = 4, 2, 8
-    pool_arr = jnp.zeros((8, ps, hkv, hd), jnp.float32)
+    # the stored form: a row's heads side by side, (pages, ps, hkv * hd)
+    pool_arr = init_paged_kv(1, 8, ps, hkv, hd, jnp.float32)["cache_k_0"]
+    assert pool_arr.shape == (8, ps, hkv * hd)
     rows = _rand(0, (2 * ps, hkv, hd))
     pt = page_table_array([[3, 5]], pages_per_seq=4)
     pool_arr = write_prompt_kv(pool_arr, rows, jnp.asarray([3, 5]))
-    view = gather_kv(pool_arr, pt)  # (1, hkv, 16, hd) dense orientation
+    # a stored row is the token's (hkv, hd) values flattened
+    np.testing.assert_array_equal(
+        np.asarray(pool_arr[3]), np.asarray(rows[:ps].reshape(ps, hkv * hd)))
+    view = gather_kv(pool_arr, pt, hd)  # (1, hkv, 16, hd) dense orientation
     dense = rows.transpose(1, 0, 2)[None]
     np.testing.assert_array_equal(np.asarray(view[:, :, : 2 * ps]), dense)
     # tail entries gather the (zero) trash page
     assert not np.any(np.asarray(view[:, :, 2 * ps:]))
     # flat view is the token-major layout of the same data
-    flat = gather_kv_flat(pool_arr, pt)
+    flat = gather_kv_flat(pool_arr, pt, hd)
     np.testing.assert_array_equal(
         np.asarray(flat), np.asarray(view.transpose(0, 2, 1, 3))
     )
@@ -278,7 +283,7 @@ def test_prompt_write_gather_roundtrip():
 
 def test_token_write_lands_in_page_slot_and_trash_for_inactive():
     ps, hkv, hd = 4, 2, 8
-    pool_arr = jnp.zeros((8, ps, hkv, hd), jnp.float32)
+    pool_arr = jnp.zeros((8, ps, hkv * hd), jnp.float32)
     pt = page_table_array([[2, 4], [6, 7]], pages_per_seq=2)
     new = _rand(1, (2, hkv, 1, hd))
     # slot 0 at length 5 -> logical page 1 (phys 4), slot offset 1;
@@ -288,10 +293,11 @@ def test_token_write_lands_in_page_slot_and_trash_for_inactive():
         jnp.asarray([5, 2], jnp.int32),
         jnp.asarray([True, False]),
     )
-    np.testing.assert_array_equal(np.asarray(out[4, 1]), np.asarray(new[0, :, 0]))
+    np.testing.assert_array_equal(
+        np.asarray(out[4, 1]), np.asarray(new[0, :, 0].reshape(-1)))
     # only the trash page and the target slot changed
     changed = np.flatnonzero(
-        np.asarray(jnp.any(out != pool_arr, axis=(1, 2, 3)))
+        np.asarray(jnp.any(out != pool_arr, axis=(1, 2)))
     )
     assert set(changed) <= {TRASH_PAGE, 4}
 
@@ -325,7 +331,7 @@ def test_paged_attention_bitwise_dense_parity(lengths):
     # scatter each slot's dense rows into disjoint pages
     pool = PagePool(n_pages=S * ppseq + 1, page_size=ps)
     tables = [pool.alloc(ppseq) for _ in range(S)]
-    k_pool = jnp.zeros((pool.n_pages, ps, Hkv, hd), jnp.float32)
+    k_pool = jnp.zeros((pool.n_pages, ps, Hkv * hd), jnp.float32)
     v_pool = jnp.zeros_like(k_pool)
     for s in range(S):
         pages = jnp.asarray(tables[s])
@@ -365,7 +371,7 @@ def test_paged_attention_impl_dispatch():
 
     # page_size 4 / head_dim 4 violate the dispatch's tile rules
     z = jnp.ones((1, 2, 1, 4), jnp.float32)
-    pool = jnp.zeros((2, 4, 2, 4), jnp.float32)
+    pool = jnp.zeros((2, 4, 2 * 4), jnp.float32)
     pt = jnp.zeros((1, 2), jnp.int32)
     L = jnp.zeros((1,), jnp.int32)
     with pytest.raises(ValueError, match="requested explicitly"):

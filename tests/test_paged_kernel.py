@@ -22,6 +22,8 @@ import pytest
 from distributed_llm_scheduler_tpu import Cluster, get_scheduler
 from distributed_llm_scheduler_tpu.models.kv_pages import TRASH_PAGE, PagePool
 from distributed_llm_scheduler_tpu.ops.attention import (
+    lane_width,
+    latent_block_pages,
     paged_block_pages,
     paged_decode_attention,
     paged_kernel_constraints,
@@ -30,13 +32,21 @@ from distributed_llm_scheduler_tpu.ops.attention import (
 )
 
 
-def _paged_state(S, Hkv, hd, ps, ppseq, lengths, seed=0, poison=True):
-    """Random pools + a page table covering each slot's rows, with the
-    trash page poisoned so parity also proves the masking."""
+def _pools(rng, n_pages, ps, Hkv, hd, dtype=jnp.float32):
+    """Random K and V pools in the stored form ``(n_pages, page_size,
+    Hkv * hd)``: a row's heads side by side on the lanes."""
+    return tuple(
+        jnp.asarray(rng.randn(n_pages, ps, Hkv * hd), dtype)
+        for _ in range(2))
+
+
+def _paged_state(S, Hkv, hd, ps, ppseq, lengths, seed=0, poison=True,
+                 dtype=jnp.float32):
+    """Random stored-form pools + a page table covering each slot's
+    rows, with the trash page poisoned so parity also proves the
+    masking."""
     rng = np.random.RandomState(seed)
-    n_pages = S * ppseq + 1
-    k_pool = jnp.asarray(rng.randn(n_pages, ps, Hkv, hd), jnp.float32)
-    v_pool = jnp.asarray(rng.randn(n_pages, ps, Hkv, hd), jnp.float32)
+    k_pool, v_pool = _pools(rng, S * ppseq + 1, ps, Hkv, hd, dtype)
     if poison:
         k_pool = k_pool.at[TRASH_PAGE].set(1e9)
         v_pool = v_pool.at[TRASH_PAGE].set(1e9)
@@ -50,64 +60,126 @@ def _paged_state(S, Hkv, hd, ps, ppseq, lengths, seed=0, poison=True):
     return k_pool, v_pool, jnp.asarray(pt), jnp.asarray(lengths, jnp.int32)
 
 
-# (name, S, Hq, Hkv, hd, ps, ppseq, lengths, with_insert)
+F32, BF16 = jnp.float32, jnp.bfloat16
+
+# (name, S, Hq, Hkv, hd, ps, ppseq, lengths, with_insert, dtype)
 FIXTURES = [
-    ("ragged_mix", 3, 4, 2, 8, 16, 4, [0, 5, 49], True),
-    ("no_insert", 3, 4, 2, 8, 16, 4, [1, 16, 31], False),
-    ("mha_heads", 2, 2, 2, 8, 16, 2, [15, 19], True),
-    ("gqa_4to1", 2, 8, 2, 16, 16, 2, [3, 30], True),
-    ("single_page_request", 2, 4, 2, 8, 16, 1, [1, 15], True),
-    ("one_token_and_empty", 2, 4, 2, 8, 16, 2, [1, 0], True),
-    ("exactly_full_pages", 2, 4, 2, 8, 16, 2, [16, 31], True),
-    ("capacity_minus_one", 2, 4, 2, 8, 16, 2, [31, 31], True),
-    ("small_pages_interpret", 3, 4, 2, 8, 4, 4, [0, 5, 15], True),
+    ("ragged_mix", 3, 4, 2, 8, 16, 4, [0, 5, 49], True, F32),
+    ("no_insert", 3, 4, 2, 8, 16, 4, [1, 16, 31], False, F32),
+    ("mha_heads", 2, 2, 2, 8, 16, 2, [15, 19], True, F32),
+    ("gqa_4to1", 2, 8, 2, 16, 16, 2, [3, 30], True, F32),
+    ("single_page_request", 2, 4, 2, 8, 16, 1, [1, 15], True, F32),
+    ("one_token_and_empty", 2, 4, 2, 8, 16, 2, [1, 0], True, F32),
+    ("exactly_full_pages", 2, 4, 2, 8, 16, 2, [16, 31], True, F32),
+    ("capacity_minus_one", 2, 4, 2, 8, 16, 2, [31, 31], True, F32),
+    ("small_pages_interpret", 3, 4, 2, 8, 4, 4, [0, 5, 15], True, F32),
 ]
 
-# The block walk (PR 25).  At page 16, 2 KV heads of 8 (or 16), float32, a
-# page is 64 KiB in VMEM and a block 8 pages = 128 rows
-# (``test_block_rule`` pins that), so a 20-page table is 3 blocks of 8, 8
-# and 4 pages: lengths at rows_per_block - 1 / = / + 1, dead slots
-# (L = 0) between live ones, L = capacity - 1 with the insert, GQA.
+# The merged-lane row (PR 28): every KV head's values side by side on the
+# lanes, heads told apart by the masked query.  GPT-2 XL's own row (25
+# heads of 64 = 1,600 values, not a whole number of 128-lane tiles), a
+# single KV head under 1 and 8 query heads, a GQA group over a padded
+# head count (3 KV heads -> a sublane tile of 8), both dtypes.
+LANE_FIXTURES = [
+    ("lanes_xl_row_bf16", 2, 25, 25, 64, 16, 3, [0, 37], True, BF16),
+    ("lanes_xl_row_f32", 2, 25, 25, 64, 16, 2, [17, 31], True, F32),
+    ("lanes_single_kv_head_mha", 2, 1, 1, 64, 16, 2, [5, 20], True, F32),
+    ("lanes_single_kv_head_gqa_8to1_bf16", 2, 8, 1, 128, 16, 2, [16, 0],
+     True, BF16),
+    ("lanes_gqa_2to1_three_kv_heads", 3, 6, 3, 32, 16, 3, [0, 33, 47],
+     True, F32),
+    ("lanes_gqa_4to1_no_insert_bf16", 2, 16, 4, 64, 16, 2, [1, 31], False,
+     BF16),
+]
+FIXTURES += LANE_FIXTURES
+
+# The block walk (PR 25) at the rows_per_block the stored row gives (PR
+# 28).  At page 16, 4 KV heads of 128 (a 512-wide row), float32, a page
+# is 32 KiB in VMEM and a block 16 pages = 256 rows; GPT-2 XL's row in
+# bf16 is a 52 KiB page and a block 9 pages = 144 rows
+# (``test_block_rule`` pins both).  So a 40-page table is 3 blocks of 16,
+# 16 and 8 pages and a 20-page XL table 3 of 9, 9 and 2: lengths at
+# rows_per_block - 1 / = / + 1, dead slots (L = 0) between live ones,
+# L = capacity - 1 with the insert and past it, GQA.
 BLOCK_FIXTURES = [
-    ("block_straddle_below_at_above", 3, 4, 2, 8, 16, 20,
-     [127, 128, 129], True),
-    ("block_dead_slots_between_live", 6, 4, 2, 8, 16, 20,
-     [0, 127, 0, 128, 0, 300], True),
-    ("block_table_not_multiple_capacity_minus_one", 4, 4, 2, 8, 16, 20,
-     [319, 0, 256, 255], True),
-    ("block_gqa_4to1", 3, 8, 2, 16, 16, 20, [129, 0, 319], True),
-    ("block_no_insert", 3, 4, 2, 8, 16, 20, [128, 0, 319], False),
-    ("block_past_capacity_clamps", 2, 4, 2, 8, 16, 20, [320, 1], True),
+    ("block_straddle_below_at_above", 3, 4, 4, 128, 16, 40,
+     [255, 256, 257], True, F32),
+    ("block_dead_slots_between_live", 6, 4, 4, 128, 16, 40,
+     [0, 255, 0, 256, 0, 600], True, F32),
+    ("block_table_not_multiple_capacity_minus_one", 4, 4, 4, 128, 16, 40,
+     [639, 0, 512, 511], True, F32),
+    ("block_gqa_2to1", 3, 8, 4, 128, 16, 40, [257, 0, 639], True, F32),
+    ("block_no_insert", 3, 4, 4, 128, 16, 40, [256, 0, 639], False, F32),
+    ("block_past_capacity_clamps", 2, 4, 4, 128, 16, 40, [640, 1], True,
+     F32),
+    ("block_xl_row_straddle_bf16", 4, 25, 25, 64, 16, 20,
+     [143, 144, 145, 0], True, BF16),
+    ("block_xl_row_capacity_edges_bf16", 3, 25, 25, 64, 16, 20,
+     [319, 0, 320], True, BF16),
 ]
 FIXTURES += BLOCK_FIXTURES
 
 
+def _tol(dtype):
+    """Kernel against gather path: float32 agrees to rounding; in
+    bfloat16 the kernel rounds the probabilities and the output to 8
+    bits."""
+    return dict(atol=1e-5, rtol=1e-5) if dtype == F32 else dict(
+        atol=2e-2, rtol=2e-2)
+
+
 @pytest.mark.parametrize(
-    "name,S,Hq,Hkv,hd,ps,ppseq,lengths,with_insert",
+    "name,S,Hq,Hkv,hd,ps,ppseq,lengths,with_insert,dtype",
     FIXTURES, ids=[f[0] for f in FIXTURES],
 )
 def test_kernel_matches_gather(name, S, Hq, Hkv, hd, ps, ppseq, lengths,
-                               with_insert):
-    k_pool, v_pool, pt, L = _paged_state(S, Hkv, hd, ps, ppseq, lengths)
+                               with_insert, dtype):
+    k_pool, v_pool, pt, L = _paged_state(
+        S, Hkv, hd, ps, ppseq, lengths, dtype=dtype)
     rng = np.random.RandomState(1)
-    q = jnp.asarray(rng.randn(S, Hq, 1, hd), jnp.float32)
+    q = jnp.asarray(rng.randn(S, Hq, 1, hd), dtype)
     kn = vn = None
     if with_insert:
-        kn = jnp.asarray(rng.randn(S, Hkv, 1, hd), jnp.float32)
-        vn = jnp.asarray(rng.randn(S, Hkv, 1, hd), jnp.float32)
+        kn = jnp.asarray(rng.randn(S, Hkv, 1, hd), dtype)
+        vn = jnp.asarray(rng.randn(S, Hkv, 1, hd), dtype)
     scale = hd ** -0.5
+    # the gather path on the same values in float32 (the CPU backend has
+    # no grouped bfloat16 dot)
+    f32 = lambda t: None if t is None else t.astype(jnp.float32)
     ref = paged_decode_attention(
-        q, k_pool, v_pool, pt, L, scale, k_new=kn, v_new=vn, impl="xla"
+        f32(q), f32(k_pool), f32(v_pool), pt, L, scale, k_new=f32(kn),
+        v_new=f32(vn), impl="xla"
     )
     got = paged_decode_attention(
         q, k_pool, v_pool, pt, L, scale, k_new=kn, v_new=vn,
         impl="pallas_interpret",
     )
+    assert got.shape == q.shape and got.dtype == q.dtype
     assert bool(jnp.all(jnp.isfinite(got))), f"{name}: non-finite output"
     np.testing.assert_allclose(
-        np.asarray(got), np.asarray(ref), atol=1e-5, rtol=1e-5,
-        err_msg=f"{name}: kernel diverged from gather path",
+        np.asarray(got, np.float32), np.asarray(ref, np.float32),
+        **_tol(dtype), err_msg=f"{name}: kernel diverged from gather path",
     )
+
+
+@pytest.mark.parametrize("impl", ["xla", "pallas_interpret"])
+def test_head_split_pools_are_a_view_of_the_stored_form(impl):
+    """``(n_pages, page_size, Hkv, hd)`` pools — what ``chip_smoke.py``
+    and the benchmark's AOT test pass — give bitwise what the stored
+    ``(n_pages, page_size, Hkv * hd)`` form gives."""
+    S, Hq, Hkv, hd, ps, ppseq = 3, 6, 3, 16, 16, 3
+    k_pool, v_pool, pt, L = _paged_state(S, Hkv, hd, ps, ppseq, [0, 20, 47])
+    rng = np.random.RandomState(4)
+    q = jnp.asarray(rng.randn(S, Hq, 1, hd), jnp.float32)
+    kn = jnp.asarray(rng.randn(S, Hkv, 1, hd), jnp.float32)
+    vn = jnp.asarray(rng.randn(S, Hkv, 1, hd), jnp.float32)
+    split = lambda pool: pool.reshape(*pool.shape[:2], Hkv, hd)
+    outs = [
+        np.asarray(paged_decode_attention(
+            q, kp, vp, pt, L, hd ** -0.5, k_new=kn, v_new=vn, impl=impl))
+        for kp, vp in ((k_pool, v_pool), (split(k_pool), split(v_pool)))
+    ]
+    np.testing.assert_array_equal(outs[0], outs[1])
 
 
 def test_kernel_masks_poisoned_trash_page():
@@ -130,36 +202,56 @@ def test_kernel_masks_poisoned_trash_page():
 
 def test_block_rule():
     """``paged_block_pages``: as many pages as keep the K and V double
-    buffers inside the VMEM budget at the tile-padded page, never more
-    than the table holds; a short table is one block."""
-    # the block fixtures above rest on this geometry: 3 blocks of 8, 8, 4
-    assert paged_block_pages(16, 20, 2, 8, jnp.float32) == 8
-    assert paged_block_pages(16, 20, 2, 16, jnp.float32) == 8
-    # GPT-2 XL serving: page 16, 25 heads of 64, bf16 -> a 128 KiB page
-    # (25 -> 32 heads, 64 -> 128 lanes), 4 to a block, 16 blocks a slot
-    assert paged_block_pages(16, 64, 25, 64, jnp.bfloat16) == 4
+    buffers inside the VMEM budget, a page reckoned at ``page_size`` rows
+    of whole 128-lane tiles, never more than the table holds; a short
+    table is one block.  ``latent_block_pages`` is the same rule over
+    one pool."""
+    # the block fixtures above rest on these: 3 blocks of 16, 16, 8 ...
+    assert paged_block_pages(16, 40, 4, 128, jnp.float32) == 16
+    # ... and GPT-2 XL serving: page 16, a row of 25 x 64 = 1,600 values
+    # held as 1,664 lanes, bf16 -> a 52 KiB page, 9 to a block (it was a
+    # 128 KiB head-padded page and 4), 8 blocks a 64-page slot
+    assert lane_width(1600) == 1664 and lane_width(640) == 640
+    assert paged_block_pages(16, 64, 25, 64, jnp.bfloat16) == 9
+    assert paged_block_pages(16, 20, 25, 64, jnp.bfloat16) == 9
+    # a row narrower than one tile still occupies one
+    assert paged_block_pages(16, 200, 2, 8, jnp.float32) == 64
     # the tiny serving geometries: the whole table is one block
     for ppseq in (1, 2, 4, 8):
         assert paged_block_pages(4, ppseq, 4, 16, jnp.float32) == ppseq
         assert paged_block_pages(8, ppseq, 12, 64, jnp.bfloat16) == ppseq
     # a page wider than the budget still makes a block of one
     assert paged_block_pages(512, 4, 64, 256, jnp.float32) == 1
+    # one pool, so twice the pages: Xing4.0's 128 x 640 latent page
+    assert latent_block_pages(128, 136, 640, jnp.bfloat16) == 6
+    assert latent_block_pages(16, 64, 1600, jnp.bfloat16) == 19
+
+
+# (name, S, Hq, Hkv, hd, ps, ppseq, lengths, dtype)
+DEAD_BLOCK_GEOMETRIES = [
+    ("wide_row_f32", 4, 4, 4, 128, 16, 40, [0, 260, 255, 639], F32),
+    ("xl_row_bf16", 4, 25, 25, 64, 16, 20, [0, 150, 143, 319], BF16),
+]
 
 
 @pytest.mark.parametrize("past", ["last_live_block", "last_live_page"])
-def test_kernel_never_touches_dead_blocks(past):
+@pytest.mark.parametrize(
+    "name,S,Hq,Hkv,hd,ps,ppseq,lengths,dtype",
+    DEAD_BLOCK_GEOMETRIES, ids=[g[0] for g in DEAD_BLOCK_GEOMETRIES],
+)
+def test_kernel_never_touches_dead_blocks(name, S, Hq, Hkv, hd, ps, ppseq,
+                                          lengths, dtype, past):
     """The dead-block witness: every page of the table is a real page of
     its own (no trash page), and every page that lies wholly past a
     slot's last live block — or, stricter, past its last live page — is
     filled with NaN.  A block that was computed and masked away would
     give 0 * NaN; the output must stay finite and bitwise equal."""
-    S, Hq, Hkv, hd, ps, ppseq = 4, 4, 2, 8, 16, 20
-    ppb = paged_block_pages(ps, ppseq, Hkv, hd, jnp.float32)
-    lengths = [0, 130, 127, 319]
+    ppb = paged_block_pages(ps, ppseq, Hkv, hd, dtype)
+    assert ppb < ppseq  # several blocks a slot, or nothing is dead
     rng = np.random.RandomState(9)
     n_pages = S * ppseq + 1
-    k_pool = rng.randn(n_pages, ps, Hkv, hd).astype(np.float32)
-    v_pool = rng.randn(n_pages, ps, Hkv, hd).astype(np.float32)
+    k_pool = rng.randn(n_pages, ps, Hkv * hd).astype(np.float32)
+    v_pool = rng.randn(n_pages, ps, Hkv * hd).astype(np.float32)
     pt = 1 + np.arange(S * ppseq, dtype=np.int32).reshape(S, ppseq)
     k_nan, v_nan = k_pool.copy(), v_pool.copy()
     k_nan[TRASH_PAGE] = v_nan[TRASH_PAGE] = np.nan
@@ -174,15 +266,15 @@ def test_kernel_never_touches_dead_blocks(past):
             k_nan[pt[s, j]] = v_nan[pt[s, j]] = np.nan
             n_dead += 1
     assert n_dead > S  # the witness has something to witness
-    q = jnp.asarray(rng.randn(S, Hq, 1, hd), jnp.float32)
-    kn = jnp.asarray(rng.randn(S, Hkv, 1, hd), jnp.float32)
-    vn = jnp.asarray(rng.randn(S, Hkv, 1, hd), jnp.float32)
+    q = jnp.asarray(rng.randn(S, Hq, 1, hd), dtype)
+    kn = jnp.asarray(rng.randn(S, Hkv, 1, hd), dtype)
+    vn = jnp.asarray(rng.randn(S, Hkv, 1, hd), dtype)
     outs = [
         np.asarray(paged_decode_attention(
-            q, jnp.asarray(kp), jnp.asarray(vp), jnp.asarray(pt),
-            jnp.asarray(lengths, jnp.int32), hd ** -0.5,
+            q, jnp.asarray(kp, dtype), jnp.asarray(vp, dtype),
+            jnp.asarray(pt), jnp.asarray(lengths, jnp.int32), hd ** -0.5,
             k_new=kn, v_new=vn, impl="pallas_interpret",
-        ))
+        ), np.float32)
         for kp, vp in ((k_pool, v_pool), (k_nan, v_nan))
     ]
     assert np.isfinite(outs[1]).all()
@@ -197,9 +289,7 @@ def _ragged_state(S, Hkv, hd, ps, ppseq, spans, seed=0, poison=True):
     chunk's K/V are already scattered (write-then-attend at chunk
     granularity), so any pool content exercises both paths equally."""
     rng = np.random.RandomState(seed)
-    n_pages = S * ppseq + 1
-    k_pool = jnp.asarray(rng.randn(n_pages, ps, Hkv, hd), jnp.float32)
-    v_pool = jnp.asarray(rng.randn(n_pages, ps, Hkv, hd), jnp.float32)
+    k_pool, v_pool = _pools(rng, S * ppseq + 1, ps, Hkv, hd)
     if poison:
         k_pool = k_pool.at[TRASH_PAGE].set(1e9)
         v_pool = v_pool.at[TRASH_PAGE].set(1e9)
@@ -432,14 +522,6 @@ def test_dag_names_distinguish_impls():
 
 
 # -- DEC005 eligibility diagnostic ------------------------------------------
-
-def _paged_specs(page_size, hd, n_kv=2, dtype=jnp.float32):
-    return {
-        "cache_k_0": jax.ShapeDtypeStruct((8, page_size, n_kv, hd), dtype),
-        "cache_v_0": jax.ShapeDtypeStruct((8, page_size, n_kv, hd), dtype),
-        "page_table": jax.ShapeDtypeStruct((2, 4), jnp.int32),
-    }
-
 
 def test_dec005_fires_on_ineligible_geometry():
     from distributed_llm_scheduler_tpu.analysis import analyze

@@ -376,6 +376,9 @@ def test_gpt2_served_tokens_are_the_parents(chunk, sharing):
     pool = (PagePool(n_pages=25, page_size=8, sharing=True) if sharing
             else None)
     _, _, eng = _engine(cfg, w, "xla", chunk, pool=pool)
+    # ... out of pools in the stored form: a row's heads on the lanes
+    assert {p.shape[2:] for p in eng.pools.values()} == {
+        (cfg.n_head * cfg.head_dim,)}
     rng = np.random.RandomState(0)
     base = rng.randint(1, cfg.vocab_size, size=(1, 16)).astype(np.int32)
     prompts = [rng.randint(1, cfg.vocab_size, size=(1, n)).astype(np.int32)
