@@ -24,6 +24,20 @@ import json
 import os
 import sys
 
+# the family registry: a table of names, no model code behind the import
+from .models import (
+    CACHED_FUNCTIONS,
+    PAGED_FUNCTIONS,
+    cache_spec,
+    families,
+    family_of,
+    family_of_model,
+    model_config,
+    module_of,
+    offers,
+    resolve,
+)
+
 
 def _add_common(p: argparse.ArgumentParser) -> None:
     p.add_argument("--model", default="gpt2",
@@ -79,23 +93,22 @@ def _config_from(args: argparse.Namespace):
     return RunConfig(**kw)
 
 
-# families with HF name maps (frontend/pretrained.py); drives both the
-# fail-fast family check and the mapper dispatch
-_WEIGHT_MAPPERS = {
-    "gpt2": "gpt2_params_from_state_dict",
-    "llama": "llama_params_from_state_dict",
-    "mixtral": "mixtral_params_from_state_dict",
-}
-_WEIGHTS_UNSUPPORTED = (
-    f"--weights supports the {', '.join(sorted(_WEIGHT_MAPPERS))} "
-    "families (HF name maps in frontend/pretrained.py)"
-)
+def _offers(model_name: str, names) -> bool:
+    """Whether the model's family module has these functions
+    (``models.PAGED_FUNCTIONS`` / ``CACHED_FUNCTIONS``)."""
+    return offers(family_of_model(model_name), *names)
 
 
-def _weights_family(model_name: str):
-    return next(
-        (f for f in _WEIGHT_MAPPERS if model_name.startswith(f)), None
-    )
+def _weights_mapper(model_name: str):
+    """``"module:function"`` of the family's HF name map, or None."""
+    family = family_of_model(model_name)
+    return family.weights_mapper if family is not None else None
+
+
+def _weights_unsupported() -> str:
+    mapped = sorted(f.name for f in families().values() if f.weights_mapper)
+    return (f"--weights supports the {', '.join(mapped)} families (HF name "
+            "maps in frontend/pretrained.py)")
 
 
 def _load_pretrained_weights(path: str, config, model_name: str):
@@ -103,13 +116,10 @@ def _load_pretrained_weights(path: str, config, model_name: str):
     error (shared by ``execute --weights`` and ``generate --weights``)."""
     import torch
 
-    from .frontend import pretrained
-
-    family = _weights_family(model_name)
-    if family is None:
-        print(_WEIGHTS_UNSUPPORTED, file=sys.stderr)
+    if _weights_mapper(model_name) is None:
+        print(_weights_unsupported(), file=sys.stderr)
         return None
-    mapper = getattr(pretrained, _WEIGHT_MAPPERS[family])
+    mapper = resolve(_weights_mapper(model_name))
     try:
         sd = torch.load(path, map_location="cpu", weights_only=True)
         params = mapper(sd, config)
@@ -379,13 +389,13 @@ def cmd_lint(args) -> int:
         return rep.exit_code
 
     cfg = _config_from(args)
-    if args.decode and _weights_family(cfg.model) is None:
+    if args.decode and not _offers(cfg.model, CACHED_FUNCTIONS):
         print("--decode needs a real model family (gpt2*/llama*/mixtral*)",
               file=sys.stderr)
         return 2
-    if args.paged and _weights_family(cfg.model) != "gpt2":
-        print("--paged lints the paged decode step (gpt2 family only)",
-              file=sys.stderr)
+    if args.paged and not _offers(cfg.model, PAGED_FUNCTIONS):
+        print("--paged lints the paged decode step: the model's family "
+              "must offer it (gpt2*, xing4*)", file=sys.stderr)
         return 2
     if args.paged:
         from .frontend.decode_dag import build_paged_decode_dag
@@ -395,9 +405,9 @@ def cmd_lint(args) -> int:
             page_size=getattr(args, "page_size", 16),
         )
     elif args.decode:
-        from .frontend.decode_dag import build_decode_dag_any
+        from .frontend.decode_dag import build_decode_dag
 
-        dag = build_decode_dag_any(cfg.model_config(), batch=cfg.batch)
+        dag = build_decode_dag(cfg.model_config(), batch=cfg.batch)
         if cfg.quantize == "int8":
             from .utils.quantize import quantize_dag
 
@@ -445,7 +455,8 @@ def cmd_lint(args) -> int:
             print(f"--fix: re-sorted execution order on {len(resorted)} "
                   f"node(s): {shown}", file=sys.stderr)
 
-    family = _weights_family(cfg.model)
+    family = family_of_model(cfg.model)
+    family = family.name if family is not None else None
     param_specs = getattr(dag, "param_specs", None)
     param_shapes = mesh_axes = None
     if family is not None and param_specs:
@@ -528,9 +539,9 @@ def cmd_execute(args) -> int:
               "detected, not configured; drop --slices (use `schedule "
               "--slices N` for modeled multislice runs)", file=sys.stderr)
         return 2
-    if cfg.weights and _weights_family(cfg.model) is None:
+    if cfg.weights and _weights_mapper(cfg.model) is None:
         # fail fast, before graph build / device binding / scheduling
-        print(_WEIGHTS_UNSUPPORTED, file=sys.stderr)
+        print(_weights_unsupported(), file=sys.stderr)
         return 2
     dag = cfg.build_graph()
     if not hasattr(dag, "graph"):
@@ -801,23 +812,26 @@ def cmd_train(args) -> int:
     import jax
     import jax.numpy as jnp
 
-    from .models.gpt2 import GPT2Config
     from .parallel.mesh import factorize_mesh, make_mesh
-    from .parallel.train import make_train_step
 
-    if args.model.startswith("mixtral"):
-        return _cmd_train_moe(args)
-    cfg_map = {"gpt2": GPT2Config.small, "gpt2-medium": GPT2Config.medium,
-               "gpt2-tiny": GPT2Config.tiny}
-    if args.model not in cfg_map:
+    family = family_of_model(args.model)
+    if family is None or family.trainer is None:
         # silently training a default GPT-2 when asked for llama would be
         # worse than refusing
-        print(f"train supports {sorted(cfg_map)} and mixtral* (dp x ep "
+        print("train supports gpt2* (dp x tp, --pp) and mixtral* (dp x ep "
               "expert parallelism, --routed for sparse dispatch); llama "
               "trains via the task-graph path: --train-step on "
               "schedule/execute", file=sys.stderr)
         return 2
-    mcfg = cfg_map[args.model]()
+    try:
+        mcfg = model_config(args.model)
+    except ValueError as e:
+        print(str(e), file=sys.stderr)
+        return 2
+    make_train_step = resolve(family.trainer)
+    if hasattr(mcfg, "n_experts"):
+        # experts to spread: the family's factory trains dp x ep
+        return _cmd_train_moe(args, mcfg, make_train_step)
     pp_mb = 0
     if args.pp:
         # pipeline-parallel training: stages as mesh shards, one GPipe
@@ -833,7 +847,7 @@ def cmd_train(args) -> int:
             print("--pp already scans layer blocks within each stage; "
                   "drop --scan", file=sys.stderr)
             return 2
-        layers = mcfg.n_layer
+        layers = getattr(mcfg, family.layers_field)
         if (
             args.pp < 1
             or layers % args.pp
@@ -862,7 +876,7 @@ def cmd_train(args) -> int:
         batch = max(batch, pp_mb)  # each microbatch needs >= 1 sequence
     return _run_train_loop(
         args, train_step, init_state, batch,
-        seq=min(args.seq_len, mcfg.n_positions),
+        seq=min(args.seq_len, getattr(mcfg, family.positions_field)),
         vocab_size=mcfg.vocab_size,
     )
 
@@ -896,31 +910,17 @@ def _run_train_loop(args, train_step, init_state, batch, seq, vocab_size):
     return 0
 
 
-def _cmd_train_moe(args) -> int:
+def _cmd_train_moe(args, mcfg, make_moe_train_step) -> int:
     """Mixtral training on a dp x ep mesh (dense or routed dispatch) —
     the CLI face of ``parallel/expert.make_moe_train_step``."""
     import jax
-    import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh
 
-    from .models.mixtral import MixtralConfig
-    from .parallel.expert import make_moe_train_step
-
-    cfg_map = {
-        "mixtral": MixtralConfig.mixtral_8x7b,
-        "mixtral-8x7b": MixtralConfig.mixtral_8x7b,
-        "mixtral-tiny": MixtralConfig.tiny,
-    }
-    if args.model not in cfg_map:
-        print(f"unknown model {args.model!r}; mixtral variants are "
-              f"{sorted(cfg_map)}", file=sys.stderr)
-        return 2
     if args.pp or args.scan:
         print("--pp/--scan are the GPT-2 train path's flags; the MoE "
               "path trains dp x ep", file=sys.stderr)
         return 2
-    mcfg = cfg_map[args.model]()
     n_dev = len(jax.devices())
     # widest ep that divides both the expert count and the device count;
     # remaining devices become dp
@@ -979,25 +979,18 @@ def cmd_generate(args) -> int:
     import jax
     import jax.numpy as jnp
 
-    from .models import gpt2, llama, mixtral
-    from .utils.config import RunConfig
-
-    # same variant table as every other subcommand (utils/config.py)
+    # same variant table as every other subcommand (the family registry)
     try:
-        config = RunConfig(model=args.model).model_config()
+        config = model_config(args.model)
     except ValueError as e:
         print(str(e), file=sys.stderr)
         return 2
-    if config is None:
+    if config is None or not _offers(args.model, ("generate",)):
         print("generate needs a real model family (gpt2* / llama* / "
               "mixtral*); synthetic graphs have no decode path",
               file=sys.stderr)
         return 2
-    # family resolution shared with the weights table (prefix match, not
-    # first letter: a future 'mistral-*' must not silently bind mixtral)
-    mod = {
-        "gpt2": gpt2, "llama": llama, "mixtral": mixtral,
-    }[_weights_family(args.model)]
+    mod = module_of(config)
 
     if args.weights:
         params = _load_pretrained_weights(args.weights, config, args.model)
@@ -1034,8 +1027,7 @@ def cmd_generate(args) -> int:
         from .backends.device import DeviceBackend
         from .frontend.decode_dag import (
             apply_cache_updates,
-            build_decode_dag_any,
-            cache_dims,
+            build_decode_dag,
             decode_inputs,
         )
         from .models.decode import _position_limit
@@ -1055,12 +1047,8 @@ def cmd_generate(args) -> int:
         # weights + zero cache slabs, allocated ONCE (shapes are fixed by
         # max_len); each step's updates fold back in functionally
         params_c = dict(params)
-        n_layers, nkv, hd = cache_dims(config)
-        for i in range(n_layers):
-            for kind in ("k", "v"):
-                params_c[f"cache_{kind}_{i}"] = jnp.zeros(
-                    (1, nkv, max_len, hd), config.dtype
-                )
+        params_c.update(
+            cache_spec(config).init_slabs(1, max_len, config.dtype))
         # position is runtime data: ONE graph + schedule per step_len
         # class (prefill, then single-token) serves every position — an
         # N-token generation compiles 2 programs, not N
@@ -1073,7 +1061,7 @@ def cmd_generate(args) -> int:
             from .utils.quantize import quantize_dag, quantize_like
 
         def _tg_dag(step_len):
-            d = build_decode_dag_any(
+            d = build_decode_dag(
                 config, batch=1, step_len=step_len, max_len=max_len
             )
             return quantize_dag(
@@ -1197,10 +1185,9 @@ def cmd_generate(args) -> int:
                 quantize_params,
             )
 
-            fam = _weights_family(args.model)
             qparams = quantize_params(
                 params, scheme="grouped",
-                rowwise_keys=ROWWISE_EMBED_KEYS.get(fam, ()),
+                rowwise_keys=ROWWISE_EMBED_KEYS.get(family_of(config), ()),
             )
             dt = jnp.dtype(config.dtype)
 
@@ -1328,9 +1315,9 @@ def _observed_run(args, tracer, metrics) -> int:
     )
     if getattr(args, "skip_decode", False):
         return 0
-    if _weights_family(cfg.model) != "gpt2":
-        print("decode leg skipped: paged decode is gpt2-family only "
-              "(the execute leg above still traced)", file=sys.stderr)
+    if not _offers(cfg.model, PAGED_FUNCTIONS):
+        print("decode leg skipped: the model's family offers no paged "
+              "decode (the execute leg above still traced)", file=sys.stderr)
         return 0
     import jax
     import jax.numpy as jnp
@@ -1415,9 +1402,9 @@ def _slo_live_requests(args, flight):
     from .backends.device import DeviceBackend
 
     cfg = _config_from(args)
-    if _weights_family(cfg.model) != "gpt2":
-        print("slo: live run needs a gpt2-family model (paged decode)",
-              file=sys.stderr)
+    if not _offers(cfg.model, PAGED_FUNCTIONS):
+        print("slo: live run needs a model whose family offers the paged "
+              "decode (gpt2*, xing4*)", file=sys.stderr)
         return 2, None
     import jax
     import jax.numpy as jnp
@@ -1584,9 +1571,9 @@ def cmd_serve(args) -> int:
         print(f"serve: trace -> {args.save_trace}", file=sys.stderr)
 
     cfg = _config_from(args)
-    if not cfg.model.startswith(("gpt2", "xing4")):
-        print("serve: needs a gpt2- or xing4-family model (paged decode)",
-              file=sys.stderr)
+    if not _offers(cfg.model, PAGED_FUNCTIONS):
+        print("serve: needs a model whose family offers the paged decode "
+              "(gpt2*, xing4*)", file=sys.stderr)
         return 2
     slots, ps, n_pages, ppseq = 4, 8, 13, 4
     too_big = [a.rid for a in arrivals

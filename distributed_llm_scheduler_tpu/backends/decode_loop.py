@@ -30,7 +30,7 @@ import jax.numpy as jnp
 
 from ..core.graph import TaskGraph
 from ..core.schedule import Schedule
-from ..frontend.decode_dag import cache_dims, cache_spec
+from ..models import cache_spec, module_of
 from ..obs import process_metrics
 from ..obs.trace import annotate
 
@@ -74,7 +74,7 @@ def compose_step_fn(
     if len(sinks) != 1:
         raise ValueError(f"expected one sink (logits) task, got {sinks}")
     sink = sinks[0]
-    n_layers, _, _ = cache_dims(config)
+    spec = cache_spec(config)
 
     def step(weights, caches, ids, pos):
         inputs = {"ids": ids, "pos": pos}
@@ -93,9 +93,9 @@ def compose_step_fn(
             outs[tid] = task.fn(p, *args)
         logits = outs[sink]
         new_caches = dict(caches)
-        for i in range(n_layers):
+        for i in range(spec.n_layers):
             o = outs[f"layer_{i}"]
-            for kind in ("k", "v"):
+            for kind in spec.kinds:
                 buf = new_caches[f"cache_{kind}_{i}"]
                 new_caches[f"cache_{kind}_{i}"] = jax.lax.dynamic_update_slice(
                     buf, o[f"{kind}_new"].astype(buf.dtype),
@@ -194,7 +194,7 @@ def compose_paged_step_fn(
     mask: inactive slots (retired or not yet admitted) write the trash
     page, so one compiled step serves every admission/retirement state.
 
-    The cache is whatever :func:`...frontend.decode_dag.cache_spec`
+    The cache is whatever :func:`...models.cache_spec`
     says the family keeps (K and V pools, or one latent pool): layer
     ``i``'s task emits ``{kind}_new`` for each pool kind.  Layer tasks
     that emit ``stats`` (an expert layer's routing counts) have them
@@ -385,13 +385,6 @@ class PagedDecodeEngine:
             attention_impl if attention_impl is not None
             else getattr(graph, "attention_impl", None)
         )
-        from ..ops.attention import (
-            latent_block_pages,
-            paged_block_pages,
-            resolve_mla_paged_impl,
-            resolve_paged_impl,
-        )
-
         # what a layer caches for a token: every pool this engine
         # allocates, gathers, scatters, copies and resets goes through it
         self.cache = cache_spec(config)
@@ -404,24 +397,11 @@ class PagedDecodeEngine:
         # before anything compiles.  ``kv_block_rows``: rows in one block
         # of the paged kernel's walk at this geometry, what
         # ``decode.kv_live_block_share`` counts live blocks in
-        if self.cache.kind == "latent":
-            width = self.cache.rows[0][1][0]
-            self.resolved_attention_impl = resolve_mla_paged_impl(
-                self.attention_impl, pool.page_size, width,
-                config.kv_lora_rank, config.dtype,
-            )
-            block_pages = latent_block_pages(
-                pool.page_size, pages_per_seq, width, config.dtype)
-        else:
-            _, n_kv, hd = cache_dims(config)
-            self.resolved_attention_impl = resolve_paged_impl(
-                self.attention_impl,
-                (slots, getattr(config, "n_head", n_kv), 1, hd),
-                (pool.n_pages, pool.page_size, n_kv * hd),
-                config.dtype,
-            )
-            block_pages = paged_block_pages(
-                pool.page_size, pages_per_seq, n_kv, hd, config.dtype)
+        self.resolved_attention_impl = self.cache.resolve_impl(
+            self.attention_impl, slots, pool.n_pages, pool.page_size,
+            config.dtype)
+        block_pages = self.cache.block_pages(
+            pool.page_size, pages_per_seq, config.dtype)
         self.kv_block_rows = pool.page_size * block_pages
         self.seg_steps = seg_steps
         # chunked prefill: prompts longer than this admit in fixed-token
@@ -1024,18 +1004,10 @@ class PagedDecodeEngine:
     def _forward_last(self, w, ids, cache, pos0, row):
         """The family's cached forward over ``ids`` (b, T) at ``pos0``
         and the logits of chunk row ``row`` (static or traced), (b, V):
-        all any prefill program needs of them.  A family that offers
-        ``forward_cached_row`` computes no other row's logits."""
-        from ..parallel.decode import _family_of, _module_for
-
-        mod = _module_for(_family_of(self.config))
-        if hasattr(mod, "forward_cached_row"):
-            return mod.forward_cached_row(
-                w, ids, cache, pos0, self.config, row,
-                impl=self.attention_impl)
-        logits, cache = mod.forward_cached(w, ids, cache, pos0, self.config)
-        return jax.lax.dynamic_index_in_dim(
-            logits, row, 1, keepdims=False), cache
+        all any prefill program needs of them: the family's
+        ``forward_cached_row``."""
+        return module_of(self.config).forward_cached_row(
+            w, ids, cache, pos0, self.config, row, impl=self.attention_impl)
 
     # -- prefill + page scatter (ONE call per admission ROUND; one
     # compiled class per (prompt length, batch size)) ----------------------

@@ -51,9 +51,9 @@ def decode_roofline(
     bw = _peak_hbm_gbps(device)
     if bw is None:
         return None
-    from ..parallel.decode import _family_of, _module_for
+    from ..models import module_of
 
-    mod = _module_for(_family_of(config))
+    mod = module_of(config)
     shaped = jax.eval_shape(
         lambda k: mod.init_params(config, k),
         jax.ShapeDtypeStruct((2,), jnp.uint32),
@@ -65,18 +65,8 @@ def decode_roofline(
         for v in jax.tree_util.tree_leaves(shaped)
     )
 
-    def _attr(*names):
-        # gpt2 names n_head/n_layer; llama/mixtral name n_kv_heads/
-        # n_heads/n_layers — take the first present
-        for n in names:
-            v = getattr(config, n, None)
-            if v is not None:
-                return v
-        raise AttributeError(f"config has none of {names}")
-
-    n_kv = _attr("n_kv_heads", "n_kv_head", "n_heads", "n_head")
-    head_dim = config.head_dim
-    n_layer = _attr("n_layers", "n_layer")
+    spec = mod.cache_spec(config)
+    n_layer, (n_kv, head_dim) = spec.n_layers, spec.rows[0][1]
     itemsize = jnp.dtype(config.dtype).itemsize
     kv_read = 2 * n_layer * batch * n_kv * cache_len * head_dim * itemsize
     kv_write = 2 * n_layer * batch * n_kv * head_dim * itemsize
@@ -101,10 +91,10 @@ def _dequant_forward(family: str, dtype_name: str):
     and recompiling the whole generation program on every
     ``measure_decode(quantize=True)`` call and pinning each orphaned
     executable in that cache."""
-    from ..parallel.decode import _module_for
+    from ..models import family_module
     from ..utils.quantize import dequantize
 
-    mod = _module_for(family)
+    mod = family_module(family)
     dt = jnp.dtype(dtype_name)
 
     def fwd_q(p, *args, **kw):
@@ -143,7 +133,7 @@ def measure_decode(
     int8 legitimately perturbs logits, so this is a fraction, not an
     exactness claim) and the bound fields reflect the quantized bytes.
     """
-    from ..parallel.decode import _family_of, _module_for
+    from ..models import family_of, module_of
     from ..utils.costmodel import _fence_rtt, readback_fence, time_amortized
 
     if config is None:
@@ -152,7 +142,7 @@ def measure_decode(
         config = GPT2Config.small(dtype=jnp.bfloat16)
     if new_tokens < 2:
         raise ValueError("new_tokens must be >= 2 to difference out prefill")
-    mod = _module_for(_family_of(config))
+    mod = module_of(config)
     key = key if key is not None else jax.random.PRNGKey(0)
     params = mod.init_params(config, key)
     ids = jax.random.randint(
@@ -174,7 +164,7 @@ def measure_decode(
         gen_params = quantize_params(
             params,
             scheme="grouped",
-            rowwise_keys=ROWWISE_EMBED_KEYS.get(_family_of(config), ()),
+            rowwise_keys=ROWWISE_EMBED_KEYS.get(family_of(config), ()),
         )
         q_param_bytes = sum(
             (v.q.nbytes + v.scale.nbytes) if isinstance(v, QParam)
@@ -182,7 +172,7 @@ def measure_decode(
             for v in gen_params.values()
         )
         fwd_q = _dequant_forward(
-            _family_of(config), jnp.dtype(config.dtype).name
+            family_of(config), jnp.dtype(config.dtype).name
         )
 
         def generate(p, n):
@@ -330,7 +320,8 @@ def measure_decode_sharded(
     """
     import jax as _jax
 
-    from ..parallel.decode import _family_of, _module_for, generate_sharded
+    from ..models import module_of
+    from ..parallel.decode import generate_sharded
     from ..parallel.mesh import make_mesh
     from ..utils.costmodel import _fence_rtt, readback_fence, time_amortized
 
@@ -342,7 +333,7 @@ def measure_decode_sharded(
         raise ValueError(
             f"tp={tp} needs {tp} devices, have {len(_jax.devices())}"
         )
-    mod = _module_for(_family_of(config))
+    mod = module_of(config)
     params = mod.init_params(config, _jax.random.PRNGKey(0))
     ids = _jax.random.randint(
         _jax.random.PRNGKey(1), (batch, prompt_len), 0, config.vocab_size,
@@ -421,11 +412,10 @@ def measure_decode_dag(
     from ..core.cluster import Cluster
     from ..frontend.decode_dag import (
         apply_cache_updates,
-        build_decode_dag_any,
-        cache_dims,
+        build_decode_dag,
         decode_inputs,
     )
-    from ..parallel.decode import _family_of, _module_for
+    from ..models import cache_spec, family_of, module_of
     from ..utils.costmodel import _fence_rtt
 
     if config is None:
@@ -435,7 +425,7 @@ def measure_decode_dag(
     if new_tokens < 3:
         raise ValueError("new_tokens must be >= 3 (compile steps are "
                          "excluded from the end-to-end timing)")
-    mod = _module_for(_family_of(config))
+    mod = module_of(config)
     dev = jax.devices()[0]
     params = mod.init_params(config, jax.random.PRNGKey(0))
     ids = jax.random.randint(
@@ -446,13 +436,9 @@ def measure_decode_dag(
 
     cluster = Cluster.from_jax_devices([dev])
     backend = DeviceBackend(cluster)
-    n_layers, nkv, hd = cache_dims(config)
     params_c = dict(params)
-    for i in range(n_layers):
-        for kind in ("k", "v"):
-            params_c[f"cache_{kind}_{i}"] = jnp.zeros(
-                (batch, nkv, max_len, hd), config.dtype
-            )
+    params_c.update(cache_spec(config).init_slabs(
+        batch, max_len, config.dtype))
 
     graphs: Dict[int, Any] = {}
 
@@ -460,7 +446,7 @@ def measure_decode_dag(
         step_len = tok_ids.shape[1]
         first = step_len not in graphs
         if first:
-            ddag = build_decode_dag_any(
+            ddag = build_decode_dag(
                 config, batch=batch, step_len=step_len, max_len=max_len
             )
             sched = get_scheduler(policy).schedule(ddag.graph, cluster)
@@ -582,15 +568,12 @@ def measure_decode_dag(
                 f"(limit {limit}, prompt {prompt_len})"
             )
         max_len2 = prompt_len + 1 + 2 * K
-        pdag2 = build_decode_dag_any(
+        pdag2 = build_decode_dag(
             config, batch=batch, step_len=prompt_len, max_len=max_len2
         )
         params2 = dict(params)
-        for i in range(n_layers):
-            for kind in ("k", "v"):
-                params2[f"cache_{kind}_{i}"] = jnp.zeros(
-                    (batch, nkv, max_len2, hd), config.dtype
-                )
+        params2.update(cache_spec(config).init_slabs(
+            batch, max_len2, config.dtype))
         psched2 = get_scheduler(policy).schedule(pdag2.graph, cluster)
         rep2 = backend.execute(
             pdag2.graph, psched2, params2,
@@ -604,7 +587,7 @@ def measure_decode_dag(
         tok0 = jnp.argmax(
             rep2.output[:, -1, :], axis=-1
         ).astype(jnp.int32)[:, None]
-        ddag2 = build_decode_dag_any(
+        ddag2 = build_decode_dag(
             config, batch=batch, step_len=1, max_len=max_len2
         )
         dsched2 = get_scheduler(policy).schedule(ddag2.graph, cluster)
@@ -700,7 +683,7 @@ def measure_decode_dag(
               + traceback.format_exc(), file=sys.stderr)
 
     out = {
-        "family": _family_of(config),
+        "family": family_of(config),
         "platform": dev.platform,
         "batch": batch,
         "prompt_len": prompt_len,
@@ -767,26 +750,21 @@ def decode_attribution(
     the table.  Numbers are meaningful on the TPU; on CPU the structure
     still runs (functional check) but bounds are None.
     """
-    from ..parallel.decode import _family_of, _module_for
+    from ..models import family_of, module_of
     from ..utils.costmodel import _fence_rtt, readback_fence, time_amortized
 
     if config is None:
         from ..models.gpt2 import GPT2Config
 
         config = GPT2Config.small(dtype=jnp.bfloat16)
-    family = _family_of(config)
-    mod = _module_for(family)
+    family = family_of(config)
+    mod = module_of(config)
     from ..models import decode as _decode
-
-    from ..frontend.decode_dag import cache_dims
 
     platform = jax.devices()[0].platform
     params = mod.init_params(config, jax.random.PRNGKey(0))
     cache_len = prompt_len + new_tokens
-    n_layer_c, nkv_c, hd_c = cache_dims(config)
-    cache = _decode.init_cache(
-        n_layer_c, batch, nkv_c, cache_len, hd_c, config.dtype
-    )
+    cache = mod.init_cache(config, batch, cache_len)
     pos = jnp.int32(prompt_len)
     tok = jax.random.randint(
         jax.random.PRNGKey(2), (batch, 1), 0, config.vocab_size, jnp.int32
@@ -812,8 +790,8 @@ def decode_attribution(
         lambda p, t, c, s: mod.forward_cached(p, t, c, s, config),
         donate_argnums=(2,),
     )
-    logits0, c_run = jit_don(params, tok, _decode.init_cache(
-        n_layer_c, batch, nkv_c, cache_len, hd_c, config.dtype), pos)
+    logits0, c_run = jit_don(
+        params, tok, mod.init_cache(config, batch, cache_len), pos)
     readback_fence(logits0)
 
     def donated_step():
@@ -825,29 +803,19 @@ def decode_attribution(
 
     t_fwd_donated = max(time_amortized(donated_step, reps, rtt), 1e-9)
 
-    # LM head alone
-    D = getattr(config, "n_embd", None) or config.d_model
+    # the head alone (final norm + projection), at the residual width
+    D = params[mod.EMBED_PARAMS[0]].shape[-1]
     x1 = jax.random.normal(
         jax.random.PRNGKey(3), (batch, 1, D), config.dtype
     )
-    if family == "gpt2":
-        t_head, _ = timeit(
-            lambda p, x: mod.output_projection(x, p["wte"]), params, x1
-        )
-    else:
-        from ..models import llama as _llama
-
-        t_head, _ = timeit(
-            lambda p, x: _llama.lm_head(x, p["lm_head"]), params, x1
-        )
+    t_head, _ = timeit(lambda p, x: mod.head(p, x, config), params, x1)
 
     # all layers' cached attention over full buffers
     import math as _math
 
-    n_layer = getattr(config, "n_layers", None) or config.n_layer
-    nh = getattr(config, "n_heads", None) or config.n_head
-    nkv = getattr(config, "n_kv_heads", None) or nh
-    hd = config.head_dim
+    spec = mod.cache_spec(config)
+    n_layer, (nkv, hd) = spec.n_layers, spec.rows[0][1]
+    nh = spec.q_heads or nkv
     scale = 1.0 / _math.sqrt(hd)
     q1 = jax.random.normal(
         jax.random.PRNGKey(4), (batch, nh, 1, hd), config.dtype
@@ -963,8 +931,8 @@ def measure_paged_decode(
     from ..backends.device import DeviceBackend
     from ..core.cluster import Cluster
     from ..frontend.decode_dag import build_paged_decode_dag
+    from ..models import module_of
     from ..models.kv_pages import PagePool, pages_needed
-    from ..parallel.decode import _family_of, _module_for
     from ..sched.policies import get_scheduler
     from ..utils.costmodel import readback_fence
 
@@ -972,7 +940,7 @@ def measure_paged_decode(
         from ..models.gpt2 import GPT2Config
 
         config = GPT2Config.tiny()  # f32: batch-size-invariant numerics
-    mod = _module_for(_family_of(config))
+    mod = module_of(config)
     capacity = pages_per_seq * page_size
     params = mod.init_params(config, jax.random.PRNGKey(0))
 
@@ -1297,25 +1265,23 @@ def measure_paged_kernel(
     from ..backends.device import DeviceBackend
     from ..core.cluster import Cluster
     from ..frontend.decode_dag import build_paged_decode_dag
+    from ..models import module_of
     from ..models.kv_pages import PagePool
     from ..ops.attention import paged_pallas_supported
-    from ..parallel.decode import _family_of, _module_for
     from ..sched.policies import get_scheduler
 
     if config is None:
         from ..models.gpt2 import GPT2Config
 
         config = GPT2Config.tiny()
-    mod = _module_for(_family_of(config))
+    mod = module_of(config)
     capacity = pages_per_seq * page_size
     params = mod.init_params(config, jax.random.PRNGKey(0))
     weights = {
         k: v for k, v in params.items()
         if not (k.startswith("cache_") or k == "page_table")
     }
-    from ..frontend.decode_dag import cache_dims
-
-    _n_layers, n_kv_heads, head_dim = cache_dims(config)
+    n_kv_heads, head_dim = mod.cache_spec(config).rows[0][1]
 
     on_tpu = jax.default_backend() == "tpu"
     kernel_impl = "pallas" if on_tpu else "pallas_interpret"

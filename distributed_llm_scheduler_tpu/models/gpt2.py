@@ -23,12 +23,34 @@ from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Any, Dict, Tuple
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import mha as _fused_mha
+from ..ops.attention import paged_decode_attention
+
+# Megatron split (parallel/sharding.py reads it): parameter-name pattern
+# -> PartitionSpec, checked in order
+PARAM_RULES = [
+    # embedding table replicated: GPT-2's vocab (50257) is not divisible by
+    # any tp, and NamedSharding requires even splits.  Memory-sharding the
+    # table needs vocab padding to a tp multiple first — future work.
+    (r"wte$", P()),
+    (r"wpe$", P()),                      # positions replicated
+    (r"attn_qkv_w$", P(None, "tp")),
+    (r"attn_qkv_b$", P("tp")),
+    (r"attn_proj_w$", P("tp", None)),
+    (r"attn_proj_b$", P()),
+    (r"mlp_fc_w$", P(None, "tp")),
+    (r"mlp_fc_b$", P("tp")),
+    (r"mlp_proj_w$", P("tp", None)),
+    (r"mlp_proj_b$", P()),
+    (r"ln.*_[gb]$", P()),
+    (r".*", P()),                        # anything else: replicated
+]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -398,6 +420,155 @@ def forward_cached(
         )
         x = x + h
     return _head(x, params, config), cache
+
+
+def forward_cached_row(
+    params, input_ids, cache, pos_start, config: GPT2Config, row,
+    impl: Optional[str] = None,
+):
+    """:func:`forward_cached` and the logits of chunk row ``row`` (static
+    or traced) only, (b, V): all a prefill program needs of them.
+    ``impl`` is the served families' common argument; the dense cached
+    attention here has one implementation."""
+    del impl
+    logits, cache = forward_cached(params, input_ids, cache, pos_start, config)
+    return jax.lax.dynamic_index_in_dim(
+        logits, row, 1, keepdims=False), cache
+
+
+# -- what the decode-step DAG builders call (models/__init__.py) -------------
+# ``p`` is a task's params under LOCAL names: a layer's weights as
+# :func:`layer_param_names` spells them, plus its ``cache_k`` / ``cache_v``
+# (dense slabs or page pools) and, paged, the ``page_table``.
+
+EMBED_PARAMS = ("wte", "wpe")
+HEAD_PARAMS = ("ln_f_g", "ln_f_b", "wte")
+
+_LAYER_NAMES = {
+    "ln1_g": "ln1_g", "ln1_b": "ln1_b",
+    "qkv_w": "attn_qkv_w", "qkv_b": "attn_qkv_b",
+    "attn_proj_w": "attn_proj_w", "attn_proj_b": "attn_proj_b",
+    "ln2_g": "ln2_g", "ln2_b": "ln2_b",
+    "fc_w": "mlp_fc_w", "fc_b": "mlp_fc_b",
+    "mlp_proj_w": "mlp_proj_w", "mlp_proj_b": "mlp_proj_b",
+}
+
+
+def layer_param_names(config: GPT2Config, layer: int) -> Dict[str, str]:
+    return {loc: f"h{layer}_{glob}" for loc, glob in _LAYER_NAMES.items()}
+
+
+def cache_spec(config: GPT2Config):
+    from .kv_pages import CacheSpec
+
+    row = (config.n_head, config.head_dim)
+    return CacheSpec("kv", config.n_layer, (("k", row), ("v", row)))
+
+
+def embed(p, ids, config: GPT2Config):
+    return embedding(ids, p["wte"], p["wpe"])
+
+
+def head(p, x, config: GPT2Config):
+    return _head(x, p, config)
+
+
+def _cached_block(p, x, config: GPT2Config, attend):
+    """One layer over ``x`` (B, T, D): LN, QKV split into heads,
+    ``attend(q, k, v)`` over (B, H, T, hd), projection, MLP.  Returns the
+    residual stream and this step's ``k`` / ``v`` heads."""
+    B, T, D = x.shape
+    H, hd = config.n_head, config.head_dim
+    ln1 = layer_norm(x, p["ln1_g"], p["ln1_b"], config.ln_eps)
+    qkv = ln1 @ p["qkv_w"] + p["qkv_b"]
+    q, k, v = jnp.split(qkv, 3, axis=-1)
+
+    def heads(t):
+        return t.reshape(B, T, H, hd).transpose(0, 2, 1, 3)
+
+    q, k, v = heads(q), heads(k), heads(v)
+    att = attend(q, k, v).transpose(0, 2, 1, 3).reshape(B, T, D)
+    x = x + (att @ p["attn_proj_w"] + p["attn_proj_b"])
+    ln2 = layer_norm(x, p["ln2_g"], p["ln2_b"], config.ln_eps)
+    h = ffn_contract(
+        ffn_activation(ffn_expand(ln2, p["fc_w"], p["fc_b"])),
+        p["mlp_proj_w"], p["mlp_proj_b"],
+    )
+    return x + h, k, v
+
+
+def cached_embed(p, ids, pos, config: GPT2Config):
+    """Token embedding + position rows [pos, pos + T) at a traced ``pos``."""
+    wpe_rows = jax.lax.dynamic_slice(
+        p["wpe"], (pos, jnp.int32(0)), (ids.shape[-1], config.n_embd))
+    return p["wte"][ids] + wpe_rows
+
+
+def cached_layer(p, x, pos, config: GPT2Config, layer: int):
+    """One layer of a cached step over the dense slabs: attention over
+    [0, pos + T) of the cache, this step's keys / values included.
+    Returns ``(x, {"k": k_new, "v": v_new})``."""
+    from . import decode
+
+    def attend(q, k, v):
+        k_cache = jax.lax.dynamic_update_slice(
+            p["cache_k"], k.astype(p["cache_k"].dtype),
+            (jnp.int32(0), jnp.int32(0), pos, jnp.int32(0)))
+        v_cache = jax.lax.dynamic_update_slice(
+            p["cache_v"], v.astype(p["cache_v"].dtype),
+            (jnp.int32(0), jnp.int32(0), pos, jnp.int32(0)))
+        return decode.cached_attention(
+            q, k_cache, v_cache, pos, 1.0 / math.sqrt(config.head_dim))
+
+    x, k, v = _cached_block(p, x, config, attend)
+    return x, {"k": k, "v": v}
+
+
+def cached_flops(config: GPT2Config, batch: int, step_len: int, max_len: int):
+    """``(embed, [a layer's ...], head)`` FLOPs of one cached step:
+    projections on T tokens + attention over the FULL masked cache
+    (compute is O(max_len) at any position: static shapes)."""
+    B, T, M, D = batch, step_len, max_len, config.n_embd
+    layer = (
+        2.0 * B * T * D * 3 * D
+        + 2.0 * 2.0 * B * config.n_head * T * M * config.head_dim
+        + 2.0 * B * T * D * D
+        + 2.0 * B * T * D * 4 * D * 2
+    )
+    return (2.0 * B * T * D, [layer] * config.n_layer,
+            2.0 * B * T * D * config.vocab_size)
+
+
+def decode_embed(p, ids, lengths, config: GPT2Config):
+    """Paged step: slot ``s`` sits at its own ``lengths[s]``."""
+    wpe_rows = jnp.take(p["wpe"], lengths, axis=0)[:, None, :]
+    return p["wte"][ids] + wpe_rows
+
+
+def decode_layer(p, x, lengths, live, config: GPT2Config, layer: int,
+                 impl: Optional[str] = None):
+    """One layer of the paged step, ``x`` (S, 1, D): ragged paged
+    attention over the shared pools (this step's k / v inserted into the
+    gathered view: the pool write itself is the loop composer's fold),
+    then the MLP.  Returns ``(x, {"k": k_new, "v": v_new}, None)``."""
+    del live
+
+    def attend(q, k, v):
+        return paged_decode_attention(
+            q, p["cache_k"], p["cache_v"], p["page_table"], lengths,
+            1.0 / math.sqrt(config.head_dim), k_new=k, v_new=v, impl=impl)
+
+    x, k, v = _cached_block(p, x, config, attend)
+    return x, {"k": k, "v": v}, None
+
+
+decode_head = head
+
+
+def decode_flops(config: GPT2Config, slots: int, capacity: int):
+    """The paged step's: attention gathers the slot's full paged
+    capacity every step."""
+    return cached_flops(config, slots, 1, capacity)
 
 
 def generate(

@@ -496,7 +496,7 @@ class PagePool:
 class CacheSpec:
     """What one layer caches for a token, and how the paged engine moves
     it: the per-layer cache description every family's config maps to
-    (:func:`...frontend.decode_dag.cache_spec`).
+    (its family's ``cache_spec``; :func:`..cache_spec` asks by config).
 
     ``kind`` ``"kv"``: two pools a layer, ``cache_k_{i}`` / ``cache_v_{i}``
     with row ``(n_kv_heads, head_dim)``; the family's dense cache keeps
@@ -524,10 +524,48 @@ class CacheSpec:
     kind: str
     n_layers: int
     rows: Tuple[Tuple[str, Tuple[int, ...]], ...]
+    #: kv: the query heads, where they are not the kv heads (GQA)
+    q_heads: Optional[int] = None
+    #: latent: the row's leading values that are the latent itself (the
+    #: part the absorbed kernel accumulates; the rest is the rotated key)
+    rank: Optional[int] = None
 
     @property
     def kinds(self) -> Tuple[str, ...]:
         return tuple(k for k, _ in self.rows)
+
+    @property
+    def head_dim(self) -> Optional[int]:
+        """What splits a stored kv row into heads; a latent row has none."""
+        return self.rows[0][1][-1] if self.kind == "kv" else None
+
+    def resolve_impl(self, impl: Optional[str], slots: int, n_pages: int,
+                     page_size: int, dtype: Any) -> str:
+        """What this cache's paged decode attention runs at this geometry
+        on this backend (``impl`` may be None / ``"auto"``): the op's own
+        rule, asked from the host.  An explicit kernel request that the
+        geometry cannot honour raises."""
+        from ..ops.attention import resolve_mla_paged_impl, resolve_paged_impl
+
+        row = self.rows[0][1]
+        if self.kind == "latent":
+            return resolve_mla_paged_impl(
+                impl, page_size, row[0], self.rank, dtype)
+        n_kv, hd = row
+        return resolve_paged_impl(
+            impl, (slots, self.q_heads or n_kv, 1, hd),
+            (n_pages, page_size, n_kv * hd), dtype)
+
+    def block_pages(self, page_size: int, pages_per_seq: int,
+                    dtype: Any) -> int:
+        """Pages in one block of the paged decode kernel's walk over this
+        cache's pools (``page_size *`` this is the rows of a block)."""
+        from ..ops.attention import latent_block_pages, paged_block_pages
+
+        row = self.rows[0][1]
+        if self.kind == "latent":
+            return latent_block_pages(page_size, pages_per_seq, row[0], dtype)
+        return paged_block_pages(page_size, pages_per_seq, *row, dtype)
 
     @property
     def row_elems(self) -> int:
@@ -540,6 +578,17 @@ class CacheSpec:
         return {
             f"cache_{kind}_{i}": jnp.zeros(
                 (n_pages, page_size, math.prod(row)), dtype)
+            for i in range(self.n_layers) for kind, row in self.rows
+        }
+
+    def init_slabs(self, batch: int, cap: int,
+                   dtype: Any) -> Dict[str, jax.Array]:
+        """Zeroed dense per-layer slabs keyed ``cache_{kind}_{i}``: what
+        the dense decode-step DAG places (one layer of
+        :meth:`init_dense` each)."""
+        return {
+            f"cache_{kind}_{i}": jnp.zeros(
+                self._dense(kind, (batch, cap, *row)), dtype)
             for i in range(self.n_layers) for kind, row in self.rows
         }
 

@@ -26,8 +26,30 @@ from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ..ops.attention import gqa_mha as _fused_gqa
+
+# Megatron split of the Llama backbone (llama + mixtral; parallel/
+# sharding.py reads it): GQA attention and the SwiGLU / expert FFNs.  KV
+# projections are column-sharded over tp, so tp must divide n_kv_heads for
+# an even head split (LlamaConfig defaults: 8 kv heads).  The expert
+# suffixes (``e{j}_w_gate`` etc.) match the same FFN rules — dense-dispatch
+# experts tensor-parallelize exactly like the dense FFN.  ``lm_head``
+# (d, vocab) column-shards when tp divides the vocab (128256 = 8 x 16032);
+# ``tok_emb`` stays replicated (row-sharded gathers cost an all-gather per
+# lookup for ~1 GB saved — the wrong trade at decode time).
+PARAM_RULES = [
+    (r"tok_emb$", P()),
+    (r"(wq|wk|wv)$", P(None, "tp")),     # column: heads split over tp
+    (r"wo$", P("tp", None)),             # row: output partial-summed
+    (r"(w_gate|w_up)$", P(None, "tp")),
+    (r"w_down$", P("tp", None)),
+    (r"router$", P()),
+    (r"lm_head$", P(None, "tp")),
+    (r".*_g$", P()),                     # RMSNorm gains replicated
+    (r".*", P()),
+]
 
 
 @dataclasses.dataclass(frozen=True)
@@ -391,6 +413,93 @@ def forward_cached(
         x = residual_add(x, h)
     x = rms_norm(x, params["final_norm_g"], config.rms_eps)
     return lm_head(x, params["lm_head"]), cache
+
+
+# -- what the decode-step DAG builder calls (models/__init__.py); Mixtral
+# shares all of it but the FFN ----------------------------------------------
+
+EMBED_PARAMS = ("tok_emb",)
+HEAD_PARAMS = ("final_norm_g", "lm_head")
+
+
+def layer_param_names(config: LlamaConfig, layer: int) -> Dict[str, str]:
+    return {k: f"l{layer}_{k}" for k in _BLOCK_KEYS}
+
+
+def cache_spec(config: Any):
+    from .kv_pages import CacheSpec
+
+    row = (config.n_kv_heads, config.head_dim)
+    return CacheSpec("kv", config.n_layers, (("k", row), ("v", row)),
+                     q_heads=config.n_heads)
+
+
+def embed(p, ids, config: Any):
+    return embedding(ids, p["tok_emb"])
+
+
+def head(p, x, config: Any):
+    return lm_head(rms_norm(x, p["final_norm_g"], config.rms_eps),
+                   p["lm_head"])
+
+
+def cached_embed(p, ids, pos, config: Any):
+    return embed(p, ids, config)
+
+
+def cached_layer(p, x, pos, config: Any, layer: int, ffn=None):
+    """One layer of a cached step over the dense GQA slabs ``cache_k`` /
+    ``cache_v`` (b, n_kv_heads, max_len, hd): RoPE dynamic-sliced at the
+    traced ``pos``, attention over [0, pos + T).  ``ffn(h)`` replaces the
+    SwiGLU FFN (Mixtral's experts).  Returns ``(x, {"k": ..., "v": ...})``."""
+    from . import decode
+
+    B, T, _ = x.shape
+    nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
+    h = rms_norm(x, p["attn_norm_g"], config.rms_eps)
+    q = (h @ p["wq"]).reshape(B, T, nh, hd).transpose(0, 2, 1, 3)
+    k = (h @ p["wk"]).reshape(B, T, nkv, hd).transpose(0, 2, 1, 3)
+    v = (h @ p["wv"]).reshape(B, T, nkv, hd).transpose(0, 2, 1, 3)
+    cos_all, sin_all = rope_tables(
+        p["cache_k"].shape[2], hd, config.rope_theta)
+    cos = jax.lax.dynamic_slice(cos_all, (pos, 0), (T, hd // 2))
+    sin = jax.lax.dynamic_slice(sin_all, (pos, 0), (T, hd // 2))
+    q, k = apply_rope(q, cos, sin), apply_rope(k, cos, sin)
+    k_cache = jax.lax.dynamic_update_slice(
+        p["cache_k"], k.astype(p["cache_k"].dtype),
+        (jnp.int32(0), jnp.int32(0), pos, jnp.int32(0)))
+    v_cache = jax.lax.dynamic_update_slice(
+        p["cache_v"], v.astype(p["cache_v"].dtype),
+        (jnp.int32(0), jnp.int32(0), pos, jnp.int32(0)))
+    att = decode.cached_attention(
+        q, k_cache, v_cache, pos, 1.0 / math.sqrt(hd))
+    x = x + att.transpose(0, 2, 1, 3).reshape(B, T, nh * hd) @ p["wo"]
+    h2 = rms_norm(x, p["ffn_norm_g"], config.rms_eps)
+    if ffn is None:
+        out = ffn_down(
+            ffn_glu(ffn_gate(h2, p["w_gate"]), ffn_up(h2, p["w_up"])),
+            p["w_down"])
+    else:
+        out = ffn(h2)
+    return x + out, {"k": k, "v": v}
+
+
+def cached_flops(config: Any, batch: int, step_len: int, max_len: int,
+                 ffn_flops=None):
+    """``(embed, [a layer's ...], head)`` FLOPs of one cached step;
+    attention scans the full masked cache, O(max_len) at any position."""
+    B, T, M, D = batch, step_len, max_len, config.d_model
+    nh, nkv, hd = config.n_heads, config.n_kv_heads, config.head_dim
+    if ffn_flops is None:  # gate, up, down matmuls
+        ffn_flops = 3 * 2.0 * B * T * D * config.ffn_hidden
+    layer = (
+        2.0 * B * T * D * (nh + 2 * nkv) * hd
+        + 2.0 * 2.0 * B * nh * T * M * hd
+        + 2.0 * B * T * nh * hd * D
+        + ffn_flops
+    )
+    return (2.0 * B * T * D, [layer] * config.n_layers,
+            2.0 * B * T * D * config.vocab_size)
 
 
 def generate(

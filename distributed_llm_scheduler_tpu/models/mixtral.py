@@ -517,6 +517,40 @@ def forward_cached(
     return _llama.lm_head(x, params["lm_head"]), cache
 
 
+# -- what the decode-step DAG builder calls (models/__init__.py): the
+# Llama backbone's, with the experts for an FFN --------------------------------
+
+PARAM_RULES = _llama.PARAM_RULES
+EMBED_PARAMS = _llama.EMBED_PARAMS
+HEAD_PARAMS = _llama.HEAD_PARAMS
+cache_spec = _llama.cache_spec
+embed = _llama.embed
+head = _llama.head
+cached_embed = _llama.cached_embed
+
+
+def layer_param_names(config: MixtralConfig, layer: int) -> Dict[str, str]:
+    return {k: f"l{layer}_{k}" for k in _layer_keys(config)}
+
+
+def cached_layer(p, x, pos, config: MixtralConfig, layer: int):
+    """Router + dense experts per step: routing is per token, exactly
+    as the fused cached forward does."""
+    return _llama.cached_layer(
+        p, x, pos, config, layer, ffn=lambda h: _moe(p, h, config))
+
+
+def cached_flops(config: MixtralConfig, batch: int, step_len: int,
+                 max_len: int):
+    # router + DENSE per-step expert sweep (every expert runs every
+    # token — the disclosed dense-dispatch cost)
+    tokens_d = 2.0 * batch * step_len * config.d_model
+    return _llama.cached_flops(
+        config, batch, step_len, max_len,
+        ffn_flops=(tokens_d * config.n_experts
+                   + config.n_experts * 3 * tokens_d * config.ffn_hidden))
+
+
 def generate(
     params: Dict[str, jax.Array],
     prompt_ids: jax.Array,

@@ -700,26 +700,28 @@ def prefill_layer(p, X, rows, pos0, cfg: Xing4Config, layer: int, impl=None):
     return Xf.reshape(b, T, n, h), new_rows
 
 
-def decode_layer(p, X, lengths, live, pool, page_table, cfg: Xing4Config,
-                 layer: int, impl=None):
+def decode_layer(p, X, lengths, live, cfg: Xing4Config, layer: int,
+                 impl=None):
     """One layer of one decode step: ``X`` (S, n, h), one token a slot at
-    position ``lengths[s]``; absorbed MLA over the latent ``pool``
-    through ``page_table`` (this step's row attended before it is
-    written: the pool write is the loop composer's).  Returns
-    ``(X', row (S, width), moe stats or None)``."""
+    position ``lengths[s]``; absorbed MLA over the latent pool
+    ``p["cache_c"]`` through ``p["page_table"]`` (this step's row
+    attended before it is written: the pool write is the loop
+    composer's).  ``live`` takes the slots that decode nothing out of the
+    routing.  Returns ``(X', {"c": row (S, width)}, moe stats or None)``."""
 
     def attn(xn):
         q_nope, q_rope, row = mla_project(p, xn, lengths, cfg)
         o_lat = mla_paged_decode_attention(
-            mla_absorbed_query(p, q_nope, q_rope, cfg), pool, page_table,
-            lengths, cfg.kv_lora_rank, new_row=row, impl=impl)
+            mla_absorbed_query(p, q_nope, q_rope, cfg), p["cache_c"],
+            p["page_table"], lengths, cfg.kv_lora_rank, new_row=row,
+            impl=impl)
         return mla_absorbed_output(p, o_lat, cfg), row
 
     X, row = hc_sublayer(X, p, "hca", p["attn_norm_g"], attn, cfg, impl)
     X, stats = hc_sublayer(
         X, p, "hcf", p["ffn_norm_g"],
         lambda xn: ffn(p, xn, cfg, layer, live=live, impl=impl), cfg, impl)
-    return X, row, stats
+    return X, {"c": row}, stats
 
 
 def embed(params, ids, cfg: Xing4Config):
@@ -734,6 +736,54 @@ def head(params, X, cfg: Xing4Config):
     x = X.astype(jnp.float32).sum(-2).astype(X.dtype)
     return jnp.dot(rms_norm(x, params["norm_f_g"], cfg.rms_eps),
                    params["head_w"], preferred_element_type=jnp.float32)
+
+
+# -- the rest of what the paged builder and the engine call
+# (models/__init__.py) ---------------------------------------------------------
+
+EMBED_PARAMS = ("wte",)
+HEAD_PARAMS = ("norm_f_g", "head_w")
+#: the step's graph takes ``active`` (the slots that decode) as an input
+#: and carries it on every edge as ``live``
+DECODE_TAKES_LIVE = True
+
+
+def layer_param_names(cfg: Xing4Config, layer: int) -> Dict[str, str]:
+    return {k: f"h{layer}_{k}" for k in layer_param_shapes(cfg, layer)}
+
+
+def cache_spec(cfg: Xing4Config):
+    from .kv_pages import CacheSpec
+
+    return CacheSpec("latent", cfg.n_layers,
+                     (("c", (latent_row_width(cfg),)),),
+                     rank=cfg.kv_lora_rank)
+
+
+def decode_embed(p, ids, lengths, cfg: Xing4Config):
+    """Positions are the layers' rotary angles, not the embedding's."""
+    return embed(p, ids[:, 0], cfg)
+
+
+def decode_head(p, X, cfg: Xing4Config):
+    return head(p, X, cfg)[:, None, :]
+
+
+def decode_flops(cfg: Xing4Config, slots: int, capacity: int):
+    """``(embed, [layer i's ...], head)`` FLOPs of one paged step: a
+    layer's weights streamed once (experts: the picked ones), plus the
+    absorbed attention over the slot's capacity."""
+    S, h = slots, cfg.hidden_size
+    picked = cfg.experts_per_tok / cfg.n_routed_experts
+    attention = (2.0 * 2.0 * S * cfg.n_heads * capacity
+                 * latent_row_width(cfg))
+    layers = [
+        sum(2.0 * S * math.prod(shape)
+            * (picked if k.startswith("exp_") else 1.0)
+            for k, (shape, _) in layer_param_shapes(cfg, i).items()
+            if len(shape) >= 2) + attention
+        for i in range(cfg.n_layers)]
+    return 2.0 * S * h, layers, 2.0 * S * h * cfg.vocab_size
 
 
 def init_cache(cfg: Xing4Config, batch: int, cap: int, dtype=None):

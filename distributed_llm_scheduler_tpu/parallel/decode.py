@@ -29,29 +29,8 @@ from typing import Any, Dict, Optional
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from .sharding import shard_params
-
-
-def _family_of(config: Any) -> str:
-    name = type(config).__name__.lower()
-    for fam in ("gpt2", "llama", "mixtral", "xing4"):
-        if fam in name:
-            return fam
-    raise ValueError(f"unknown model family for config {type(config)!r}")
-
-
-_FAMILY_MODULES = {}
-
-
-def _module_for(family: str):
-    if not _FAMILY_MODULES:
-        from ..models import gpt2, llama, mixtral, xing4
-
-        _FAMILY_MODULES.update(
-            {"gpt2": gpt2, "llama": llama, "mixtral": mixtral,
-             "xing4": xing4}
-        )
-    return _FAMILY_MODULES[family]
+from ..models import cache_spec, family_of, module_of
+from .sharding import param_spec, shard_params
 
 
 def shard_decode_params(
@@ -62,25 +41,26 @@ def shard_decode_params(
     Validates the head-divisibility precondition up front (an uneven
     NamedSharding split fails deep inside device_put otherwise).
     """
-    family = _family_of(config)
+    family = family_of(config)
     tp = mesh.shape.get("tp", 1)
     if tp > 1:
-        if family == "gpt2":
-            # qkv/mlp biases column-shard as P("tp"): widths are 3*d and
-            # 4*d, both tp-divisible iff the head count is (d = heads*hd)
-            kv_heads = config.n_head
-        else:
-            kv_heads = config.n_kv_heads
-            if config.vocab_size % tp != 0:
-                raise ValueError(
-                    f"tp={tp} must divide vocab_size={config.vocab_size} "
-                    "for the column split of lm_head (pick a smaller tp)"
-                )
+        # every dimension the family's rules split over tp must divide
+        # evenly: the qkv / kv column split means the (kv-)head count, an
+        # untied (d, vocab) head the vocabulary
+        kv_heads = cache_spec(config).rows[0][1][0]
         if kv_heads % tp != 0:
             raise ValueError(
                 f"tp={tp} must divide the (kv-)head count {kv_heads} for "
                 "the attention column split (pick a smaller tp)"
             )
+        for name, value in params.items():
+            for axis, dim in zip(param_spec(name, family), value.shape):
+                if axis == "tp" and dim % tp != 0:
+                    raise ValueError(
+                        f"tp={tp} must divide dimension {dim} of {name} "
+                        f"{tuple(value.shape)} for its column / row split "
+                        "(pick a smaller tp)"
+                    )
     return shard_params(mesh, params, family)
 
 
@@ -100,14 +80,12 @@ def generate_sharded(
     (replicated otherwise — a batch of 1 prompt is the common decode
     case and dp>1 would idle anyway).
     """
-    family = _family_of(config)
-    mod = _module_for(family)
     params = shard_decode_params(mesh, params, config)
     dp = mesh.shape.get("dp", 1)
     B = prompt_ids.shape[0]
     spec = P("dp", None) if (dp > 1 and B % dp == 0) else P()
     prompt_ids = jax.device_put(prompt_ids, NamedSharding(mesh, spec))
-    return mod.generate(
+    return module_of(config).generate(
         params, prompt_ids, config, max_new_tokens, key=key, **kw
     )
 

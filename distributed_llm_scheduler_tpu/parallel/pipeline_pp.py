@@ -43,36 +43,7 @@ import jax.numpy as jnp
 from jax import lax, shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..models import gpt2, llama, mixtral
-
-
-def _family_bits(config: Any):
-    """(module, n_layers, d_model, shared_keys, embed_fn, head_fn) per
-    family — the only family-specific pieces; the pipeline scan itself is
-    identical for every Llama-backbone and GPT-2 model."""
-    from .decode import _family_of  # the ONE validated family dispatch
-
-    family = _family_of(config)  # raises ValueError for unknown configs
-    if family == "gpt2":
-        return (
-            gpt2, config.n_layer, config.n_embd,
-            ("wte", "wpe", "ln_f_g", "ln_f_b"),
-            lambda sp, ids: gpt2.embedding(ids, sp["wte"], sp["wpe"]),
-            lambda p, x: gpt2.output_projection(
-                gpt2.layer_norm(x, p["ln_f_g"], p["ln_f_b"], config.ln_eps),
-                p["wte"],
-            ),
-        )
-    mod = llama if family == "llama" else mixtral
-    return (
-        mod, config.n_layers, config.d_model,
-        ("tok_emb", "final_norm_g", "lm_head"),
-        lambda sp, ids: llama.embedding(ids, sp["tok_emb"]),
-        lambda p, x: llama.lm_head(
-            llama.rms_norm(x, p["final_norm_g"], config.rms_eps),
-            p["lm_head"],
-        ),
-    )
+from ..models import cache_spec, mixtral, module_of
 
 
 def _stack_stage_params(
@@ -108,7 +79,9 @@ def pipeline_forward(
     recomputes block activations instead of storing every step's — the
     same HBM-for-FLOPs trade as the dp/tp path's ``remat``.
     """
-    mod, L, D, shared_keys, embed_fn, head_fn = _family_bits(config)
+    # the only family-specific pieces — its module's block, embedding and
+    # head; the pipeline scan itself is identical for every family
+    mod, L = module_of(config), cache_spec(config).n_layers
     S = mesh.shape["pp"]
     B, M = input_ids.shape[0], microbatches
     if L % S != 0:
@@ -119,8 +92,11 @@ def pipeline_forward(
     T = input_ids.shape[1]
 
     stage_params = _stack_stage_params(mod, params, config, S, L)
-    shared = {k: params[k] for k in shared_keys}
+    shared = {k: params[k] for k in (*mod.EMBED_PARAMS, *mod.HEAD_PARAMS)}
     ids_mb = input_ids.reshape(M, mb, T)
+    D = jax.eval_shape(
+        lambda sp, ids: mod.embed(sp, ids, config), shared, ids_mb[0]
+    ).shape[-1]
 
     stage_specs = {k: P("pp") for k in stage_params}
 
@@ -148,7 +124,7 @@ def pipeline_forward(
             # successor hop: device s receives s-1's previous output
             # (device 0 receives zeros — it sources from the embedding)
             recv = lax.ppermute(prev_out, "pp", perm) if S > 1 else prev_out
-            x0 = embed_fn(shared_p, ids_mb[jnp.clip(t, 0, M - 1)])
+            x0 = mod.embed(shared_p, ids_mb[jnp.clip(t, 0, M - 1)], config)
             x = jnp.where(s == 0, x0, recv)
             y = run_stage(x)
             widx = t - (S - 1)
@@ -186,7 +162,7 @@ def pipeline_forward(
         shared,
         ids_mb,
     )
-    return head_fn(params, acts.reshape(B, T, -1))
+    return mod.head(params, acts.reshape(B, T, -1), config)
 
 
 def pp_loss_fn(
@@ -224,7 +200,7 @@ def make_pp_train_step(
     checkpoints stay in the shared flat layout)."""
     from .train import make_step_from_loss
 
-    mod, *_ = _family_bits(config)
+    mod = module_of(config)
 
     def loss(params, input_ids, targets):
         return pp_loss_fn(

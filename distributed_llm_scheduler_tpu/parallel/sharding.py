@@ -3,7 +3,8 @@
 Megatron-style tensor parallelism expressed as GSPMD sharding annotations —
 no hand-written collectives.  The forward is written as a *global* program
 (models/gpt2.py); `NamedSharding` placement of params + inputs makes XLA
-partition the matmuls and insert the per-layer all-reduces:
+partition the matmuls and insert the per-layer all-reduces.  The rules are
+each family's own (``PARAM_RULES`` in its module under models/):
 
 * qkv / mlp-expand weights: column-sharded over ``tp`` (output features);
 * attn-proj / mlp-contract weights: row-sharded over ``tp`` (input
@@ -22,56 +23,16 @@ from typing import Any, Dict
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-# parameter-name pattern -> PartitionSpec, checked in order (GPT-2 family
-# naming from models/gpt2.py; llama/mixtral reuse the same suffix scheme)
-GPT2_PARAM_RULES = [
-    # embedding table replicated: GPT-2's vocab (50257) is not divisible by
-    # any tp, and NamedSharding requires even splits.  Memory-sharding the
-    # table needs vocab padding to a tp multiple first — future work.
-    (r"wte$", P()),
-    (r"wpe$", P()),                      # positions replicated
-    (r"attn_qkv_w$", P(None, "tp")),
-    (r"attn_qkv_b$", P("tp")),
-    (r"attn_proj_w$", P("tp", None)),
-    (r"attn_proj_b$", P()),
-    (r"mlp_fc_w$", P(None, "tp")),
-    (r"mlp_fc_b$", P("tp")),
-    (r"mlp_proj_w$", P("tp", None)),
-    (r"mlp_proj_b$", P()),
-    (r"ln.*_[gb]$", P()),
-    (r".*", P()),                        # anything else: replicated
-]
-
-
-# Llama-backbone families (llama + mixtral): Megatron split of the GQA
-# attention and the SwiGLU / expert FFNs.  KV projections are column-sharded
-# over tp, so tp must divide n_kv_heads for an even head split (LlamaConfig
-# defaults: 8 kv heads).  The expert suffixes (``e{j}_w_gate`` etc.) match
-# the same FFN rules — dense-dispatch experts tensor-parallelize exactly
-# like the dense FFN.  ``lm_head`` (d, vocab) column-shards when tp divides
-# the vocab (128256 = 8 x 16032); ``tok_emb`` stays replicated (row-sharded
-# gathers cost an all-gather per lookup for ~1 GB saved — the wrong trade
-# at decode time).
-LLAMA_PARAM_RULES = [
-    (r"tok_emb$", P()),
-    (r"(wq|wk|wv)$", P(None, "tp")),     # column: heads split over tp
-    (r"wo$", P("tp", None)),             # row: output partial-summed
-    (r"(w_gate|w_up)$", P(None, "tp")),
-    (r"w_down$", P("tp", None)),
-    (r"router$", P()),
-    (r"lm_head$", P(None, "tp")),
-    (r".*_g$", P()),                     # RMSNorm gains replicated
-    (r".*", P()),
-]
-
+from ..models import family_module
 
 def param_spec(name: str, family: str = "gpt2") -> P:
     # stacked-layer params (models/gpt2.stack_layer_params): the leading
     # layer dim is never sharded; the per-layer spec shifts right by one
     if name.startswith("layers_"):
         return P(None, *param_spec(name[len("layers_"):], family))
-    rules = GPT2_PARAM_RULES if family.startswith("gpt2") else LLAMA_PARAM_RULES
-    for pattern, spec in rules:
+    # the family's own Megatron rules (pattern -> spec, checked in order);
+    # a family without any is replicated
+    for pattern, spec in getattr(family_module(family), "PARAM_RULES", ()):
         if re.search(pattern, name):
             return spec
     return P()

@@ -10,6 +10,7 @@ distributed_llm_scheduler_tpu <cmd>`` just works.
 from __future__ import annotations
 
 import dataclasses
+import inspect
 import os
 from typing import Optional, Tuple
 
@@ -86,77 +87,24 @@ class RunConfig:
     # it) — random init when unset
     weights: Optional[str] = None
 
-    def _model_family(self):
-        """(variants, layers_field, max_seq_field, builder) for real model
-        families, or None for synthetic workloads.  One table so every
-        family shares the same variant lookup / num_layers override /
-        seq-len clamp behavior."""
-        if self.model.startswith("gpt2"):
-            from ..frontend.gpt2_dag import build_gpt2_dag
-            from ..models.gpt2 import GPT2Config
-
-            return (
-                {
-                    "gpt2": GPT2Config.small,
-                    "gpt2-medium": GPT2Config.medium,
-                    "gpt2-tiny": GPT2Config.tiny,
-                },
-                "n_layer", "n_positions", build_gpt2_dag,
-            )
-        if self.model.startswith("llama"):
-            from ..frontend.llama_dag import build_llama_dag
-            from ..models.llama import LlamaConfig
-
-            return (
-                {
-                    "llama": LlamaConfig.llama3_8b,
-                    "llama-8b": LlamaConfig.llama3_8b,
-                    "llama-tiny": LlamaConfig.tiny,
-                },
-                "n_layers", "max_seq_len", build_llama_dag,
-            )
-        if self.model.startswith("mixtral"):
-            from ..frontend.moe_dag import build_moe_dag
-            from ..models.mixtral import MixtralConfig
-
-            return (
-                {
-                    "mixtral": MixtralConfig.mixtral_8x7b,
-                    "mixtral-8x7b": MixtralConfig.mixtral_8x7b,
-                    "mixtral-tiny": MixtralConfig.tiny,
-                },
-                "n_layers", "max_seq_len", build_moe_dag,
-            )
-        if self.model.startswith("xing4"):
-            # served only (the paged decode DAG); no forward-DAG builder
-            from ..models.xing4 import Xing4Config
-
-            return ({"xing4-tiny": Xing4Config.tiny}, "n_layers",
-                    "max_positions", None)
-        return None
-
     def model_config(self):
         """Model config instance for a real-family variant name.
 
         The ONE variant-name lookup (CLI generate and build_graph share
-        it): returns None for synthetic workloads, raises ValueError for an
-        unknown variant of a known family."""
-        family = self._model_family()
-        if family is None:
-            return None
-        variants = family[0]
-        maker = variants.get(self.model)
-        if maker is None:
-            raise ValueError(
-                f"unknown model {self.model!r}; variants are "
-                f"{' / '.join(sorted(variants))}"
-            )
-        return maker()
+        it; the table is the family registry's, :mod:`..models`): returns
+        None for synthetic workloads, raises ValueError for an unknown
+        variant of a known family."""
+        from ..models import model_config
+
+        return model_config(self.model)
 
     def build_graph(self):
         from ..frontend import generators
 
-        if self.train_step and not self.model.startswith("gpt2"):
+        from ..models import family_of_model, resolve
+
+        family = family_of_model(self.model)
+        if self.train_step and (family is None or family.train_dag is None):
             raise ValueError(
                 "--train-step currently supports gpt2* models only"
             )
@@ -174,7 +122,10 @@ class RunConfig:
             raise ValueError(
                 f"unknown quantize mode {self.quantize!r}; choose none | int8"
             )
-        if self.routed and not self.model.startswith("mixtral"):
+        builder = (resolve(family.forward_dag)
+                   if family is not None and family.forward_dag else None)
+        if self.routed and (builder is None or "routed" not in
+                            inspect.signature(builder).parameters):
             # same contract as --quantize below: silently ignoring the
             # flag would report dense numbers as routed ones
             raise ValueError(
@@ -186,7 +137,7 @@ class RunConfig:
                 "--train-step does not support --quantize (int8 weights "
                 "are an inference-path representation)"
             )
-        if self.quantize != "none" and self._model_family() is None:
+        if self.quantize != "none" and family is None:
             # silently ignoring the flag would report full-precision
             # numbers as quantized ones
             raise ValueError(
@@ -194,9 +145,7 @@ class RunConfig:
                 "mixtral*); synthetic graphs carry no weights to quantize"
             )
 
-        family = self._model_family()
         if family is not None:
-            variants, layers_field, max_seq_field, builder = family
             if builder is None:
                 raise ValueError(
                     f"model {self.model!r} has no forward DAG: it is served "
@@ -204,12 +153,12 @@ class RunConfig:
                 )
             cfg = self.model_config()
             if self.num_layers:
-                cfg = dataclasses.replace(cfg, **{layers_field: self.num_layers})
-            seq = min(self.seq_len, getattr(cfg, max_seq_field))
+                cfg = dataclasses.replace(
+                    cfg, **{family.layers_field: self.num_layers})
+            seq = min(self.seq_len, getattr(cfg, family.positions_field))
             if self.train_step:
-                from ..frontend.train_dag import build_gpt2_train_dag
-
-                return build_gpt2_train_dag(cfg, batch=self.batch, seq_len=seq)
+                return resolve(family.train_dag)(
+                    cfg, batch=self.batch, seq_len=seq)
             extra = (
                 {"routed": True, "capacity_factor": self.capacity_factor}
                 if self.routed

@@ -49,6 +49,108 @@ def two_nodes() -> Cluster:
     )
 
 
+@pytest.fixture
+def placed_replay():
+    """``replay(graph, params, graph_input, cluster, schedules, sim) ->
+    {name: (makespan_s, work_s)}``: placed runs' makespans as a CPU shared
+    with other test workers can measure them.
+
+    Every schedule really runs on the mesh (once free-running to warm,
+    then ``profile=True``: every task fenced and timed where it was
+    placed), and ``sim`` replays each schedule with its tasks' own
+    measured times for their costs.  A task's time is its minimum over
+    ``repeats`` rounds, and a round visits every schedule in turn, so a
+    burst of load from another worker lands on all of them alike and is
+    dropped by the minimum.  What load remains stretches the tasks about
+    alike: it moves the scale of a makespan, not which of two placements
+    is shorter, nor the makespan's ratio to the work (``work_s``, the sum
+    of the task times).  The raw wall time of a free-running eight-device
+    run on cores that six workers share says neither."""
+    from distributed_llm_scheduler_tpu.backends.device import DeviceBackend
+
+    def replay(graph, params, graph_input, cluster, schedules, sim,
+               repeats=5):
+        backend = DeviceBackend(cluster)
+        for schedule in schedules.values():
+            backend.execute(graph, schedule, params, graph_input)
+        best = {name: {} for name in schedules}
+        for _ in range(repeats):
+            for name, schedule in schedules.items():
+                rep = backend.execute(graph, schedule, params, graph_input,
+                                      profile=True, warmup=False)
+                for tid, t in rep.timings.items():
+                    best[name][tid] = min(
+                        best[name].get(tid, t.duration), t.duration)
+        modeled = {t.task_id: t.compute_time for t in graph}
+        out = {}
+        try:
+            for name, schedule in schedules.items():
+                assert set(best[name]) == set(schedule.placement), name
+                for tid, seconds in best[name].items():
+                    graph[tid].compute_time = max(seconds, 1e-7)
+                out[name] = (sim.execute(graph, cluster, schedule).makespan,
+                             sum(best[name].values()))
+        finally:
+            for t in graph:
+                t.compute_time = modeled[t.task_id]
+        return out
+
+    return replay
+
+
+@pytest.fixture
+def replayed_rank_check(placed_replay):
+    """``check(graph, params, graph_input, policies, cluster) -> dict``:
+    the rank check's question — does the placement the simulator predicts
+    to win really win on the mesh — asked of :func:`placed_replay`
+    makespans, not of free-running wall times.  Set-up as
+    ``eval.rankcheck.run_rank_check`` does it (live link and cost
+    calibration, link-aware policies, host-synchronous transfers on the
+    CPU mesh); returns ``predicted`` and ``measured`` seconds a policy."""
+    import os
+
+    from distributed_llm_scheduler_tpu import get_scheduler
+    from distributed_llm_scheduler_tpu.backends.sim import SimulatedBackend
+    from distributed_llm_scheduler_tpu.utils.costmodel import calibrate
+    from distributed_llm_scheduler_tpu.utils.linkmodel import calibrate_link
+
+    def check(graph, params, graph_input, policies, cluster):
+        link = calibrate_link(
+            [d.jax_device for d in cluster],
+            sizes=(1 << 14, 1 << 18, 1 << 22), repeats=3,
+        ).to_link_model()
+        cm = calibrate(graph, params, graph_input, repeats=3)
+        cm.apply(graph)
+        sim = SimulatedBackend(
+            fidelity="full", link=link, host_slots=os.cpu_count() or 1,
+            dispatch_s=cm.dispatch_s, host_synchronous_transfers=True,
+        )
+        scheds = {}
+        for policy in policies:
+            scheds[policy] = get_scheduler(policy, link=link).schedule(
+                graph, cluster)
+            assert not scheds[policy].failed, policy
+        measured = {
+            policy: makespan for policy, (makespan, _) in placed_replay(
+                graph, params, graph_input, cluster, scheds, sim).items()}
+        # the model the predictions are made from: each task's least time
+        # over a calibration before and one after the placed runs — a
+        # burst of load from another worker that covers one calibration
+        # would otherwise inflate every compute time against the link's
+        # and turn a predicted separation into a tie
+        again = calibrate(graph, params, graph_input, repeats=3)
+        for tid, seconds in again.task_seconds.items():
+            graph[tid].compute_time = max(
+                min(seconds, cm.task_seconds[tid]), 1e-7)
+        predicted = {
+            policy: sim.execute(graph, cluster, sched).makespan
+            for policy, sched in scheds.items()
+        }
+        return {"predicted": predicted, "measured": measured}
+
+    return check
+
+
 @pytest.fixture(scope="session")
 def session_serve_engine():
     """ONE compiled bench-scenario serving engine for the whole session.

@@ -189,7 +189,7 @@ def test_backbone_decode_dag_multistep_token_exact(family):
     traced step position, per-step MoE routing) — with ONE decode graph
     reused across steps."""
     from distributed_llm_scheduler_tpu.frontend.decode_dag import (
-        build_decode_dag_any,
+        build_decode_dag,
     )
 
     if family == "llama":
@@ -214,7 +214,7 @@ def test_backbone_decode_dag_multistep_token_exact(family):
 
     cluster = Cluster.from_jax_devices(jax.devices()[:1])
     backend = DeviceBackend(cluster)
-    dag = build_decode_dag_any(cfg, batch=b, step_len=p_len, max_len=m)
+    dag = build_decode_dag(cfg, batch=b, step_len=p_len, max_len=m)
     params = dag.init_params()
     params.update(model_params)
     sched = get_scheduler("greedy").schedule(dag.graph, cluster)
@@ -224,7 +224,7 @@ def test_backbone_decode_dag_multistep_token_exact(family):
     params = apply_cache_updates(params, rep.task_outputs, cfg, pos=0)
     tok = jnp.argmax(np.asarray(rep.output)[:, -1, :], axis=-1)
     got = [tok]
-    ddag = build_decode_dag_any(cfg, batch=b, step_len=1, max_len=m)
+    ddag = build_decode_dag(cfg, batch=b, step_len=1, max_len=m)
     dsched = get_scheduler("greedy").schedule(ddag.graph, cluster)
     for s in range(1, n_new):
         pos = p_len + s - 1
@@ -362,7 +362,7 @@ def test_decode_loop_token_exact_backbones(family):
         split_cache_params,
     )
     from distributed_llm_scheduler_tpu.frontend.decode_dag import (
-        build_decode_dag_any,
+        build_decode_dag,
     )
 
     if family == "llama":
@@ -387,7 +387,7 @@ def test_decode_loop_token_exact_backbones(family):
 
     cluster = Cluster.from_jax_devices(jax.devices()[:1])
     backend = DeviceBackend(cluster)
-    dag = build_decode_dag_any(cfg, batch=b, step_len=p_len, max_len=m)
+    dag = build_decode_dag(cfg, batch=b, step_len=p_len, max_len=m)
     params = dag.init_params()
     params.update(model_params)
     sched = get_scheduler("greedy").schedule(dag.graph, cluster)
@@ -399,7 +399,7 @@ def test_decode_loop_token_exact_backbones(family):
         jnp.int32
     )[:, None]
 
-    ddag = build_decode_dag_any(cfg, batch=b, step_len=1, max_len=m)
+    ddag = build_decode_dag(cfg, batch=b, step_len=1, max_len=m)
     dsched = get_scheduler("greedy").schedule(ddag.graph, cluster)
     weights, caches = split_cache_params(params)
     loop = build_decode_loop(ddag.graph, dsched, cfg, steps=n_new - 1)

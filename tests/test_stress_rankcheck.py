@@ -93,30 +93,29 @@ def test_slot_charged_transfers():
     assert slotted == pytest.approx(0.02 + 4.0, rel=1e-6)
 
 
-def test_separating_rank_check_on_mesh():
-    """End-to-end: predicted separation, no tie escape, winner agreement.
-    Retries absorb host-load contamination (see memory: CPU-mesh
-    measurements are ruined by concurrent heavy jobs).
+def test_separating_rank_check_on_mesh(replayed_rank_check):
+    """End-to-end: predicted separation, no tie escape, and the
+    separation is real — the placement the simulator predicts to win
+    beats, on the mesh, the one it predicts to lose (the one that moves
+    every edge).  "On the mesh" is the
+    ``placed_replay`` makespan (tests/conftest.py: each placement really
+    runs and is replayed with its own fenced task times).  Until PR 29
+    the predicted winner's free-running wall time had to be within 5% of
+    the best, retried three times against host load; between the two
+    locality-keeping placements that margin is inside what other test
+    workers' load does to either.
 
     Chain count deliberately does NOT divide the device count: when it
     does, round-robin's cyclic assignment accidentally reproduces perfect
     chain locality and the regime collapses back to a tie.
     """
-    from distributed_llm_scheduler_tpu.eval.rankcheck import run_rank_check
-
     dag = build_transfer_stress_dag(chains=6, length=6, edge_mb=8.0)
     cluster = Cluster.from_jax_devices(jax.devices()[:4], hbm_cap_gb=4.0)
-    last = None
-    for _ in range(3):
-        rep = run_rank_check(
-            dag.graph, dag.init_params(), dag.make_inputs(),
-            policies=("roundrobin", "greedy", "pipeline"),
-            cluster=cluster, measure_repeats=3, reps=2,
-            log=lambda m: None,
-        )
-        last = rep
-        if rep["winner_agreement"] and not rep["prediction_is_tie"]:
-            break
-    assert last["prediction_is_tie"] is False, last
-    assert last["prediction_spread"] > 1.3, last
-    assert last["winner_agreement"], last
+    got = replayed_rank_check(
+        dag.graph, dag.init_params(), dag.make_inputs(),
+        ("roundrobin", "greedy", "pipeline"), cluster)
+    predicted, measured = got["predicted"], got["measured"]
+    winner = min(predicted, key=predicted.get)
+    loser = max(predicted, key=predicted.get)
+    assert predicted[loser] > predicted[winner] * 1.3, got
+    assert measured[winner] < measured[loser], got

@@ -402,7 +402,7 @@ def test_gate_precomputed_still_raises_on_errors():
 
 def test_builders_typecheck_clean():
     from distributed_llm_scheduler_tpu.frontend.decode_dag import (
-        build_decode_dag_any,
+        build_decode_dag,
     )
     from distributed_llm_scheduler_tpu.frontend.gpt2_dag import build_gpt2_dag
     from distributed_llm_scheduler_tpu.models.gpt2 import GPT2Config
@@ -410,7 +410,7 @@ def test_builders_typecheck_clean():
     cfg = GPT2Config.tiny()
     for dag in (
         build_gpt2_dag(cfg, batch=1, seq_len=16),
-        build_decode_dag_any(cfg, batch=2),
+        build_decode_dag(cfg, batch=2),
     ):
         cluster = Cluster.from_jax_devices(hbm_cap_gb=4.0)
         schedule = get_scheduler("greedy").schedule(dag.graph, cluster)

@@ -32,9 +32,12 @@ from distributed_llm_scheduler_tpu.backends.decode_loop import (  # noqa: E402
 from distributed_llm_scheduler_tpu.backends.device import DeviceBackend  # noqa: E402
 from distributed_llm_scheduler_tpu.frontend.decode_dag import (  # noqa: E402
     build_paged_decode_dag,
-    cache_spec,
 )
-from distributed_llm_scheduler_tpu.models import gpt2, xing4  # noqa: E402
+from distributed_llm_scheduler_tpu.models import (  # noqa: E402
+    cache_spec,
+    gpt2,
+    xing4,
+)
 from distributed_llm_scheduler_tpu.models.kv_pages import PagePool  # noqa: E402
 from distributed_llm_scheduler_tpu.ops.attention import (  # noqa: E402
     latent_block_pages,
