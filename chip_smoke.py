@@ -417,8 +417,10 @@ def phase_execute(meter: CompileMeter, model: str = "gpt2", batch: int = 8,
                   num_nodes: int = 1,
                   schedulers: tuple = ("heft",),
                   segment_modes: tuple = (False, True)) -> Dict[str, Any]:
-    """The placed-DAG executor through ``execute``: per-task planned path
-    and ``--segments`` (``segment_modes``), per scheduler.
+    """The placed-DAG executor through ``execute``: the planned path (fused
+    same-device launches by default: ``n_dispatches`` beside ``n_tasks``
+    shows how many programs a step took) and ``--segments``
+    (``segment_modes``), per scheduler.
 
     Gates: the CLI exits 0 on ``num_nodes`` devices; with more than one
     node, every device reports a non-zero HBM peak and at least one edge
@@ -472,7 +474,10 @@ def phase_execute(meter: CompileMeter, model: str = "gpt2", batch: int = 8,
                     )
                     got = np.asarray(rep.output, np.float32)
                 ref = np.asarray(want, np.float32)
+                leg["n_tasks"] = len(dag.graph.topo_order)
                 leg["oracle"].update(
+                    n_dispatches=rep.n_dispatches,
+                    bitwise=bool(np.array_equal(got, ref)),
                     max_abs_diff=round(float(np.abs(got - ref).max()), 6),
                     rel_fro=float(np.linalg.norm((got - ref).ravel())
                                   / max(np.linalg.norm(ref.ravel()), 1e-12)),
@@ -496,9 +501,13 @@ def phase_execute(meter: CompileMeter, model: str = "gpt2", batch: int = 8,
                 )
                 ph["legs"][name] = leg
                 log(f"execute[{name}]: rc={leg['rc']} devices="
-                    f"{leg['n_devices']} makespan={leg['makespan_ms']}ms "
+                    f"{leg['n_devices']} launches={leg['n_dispatches']} "
+                    f"(oracle run {rep.n_dispatches}) of "
+                    f"{leg['n_tasks']} tasks makespan="
+                    f"{leg['makespan_ms']}ms "
                     f"edges={leg['transfer_edges']} oracle="
-                    f"{leg['oracle']['close']} (max|d|="
+                    f"{leg['oracle']['close']} (bitwise="
+                    f"{leg['oracle']['bitwise']} max|d|="
                     f"{leg['oracle']['max_abs_diff']}) wall={leg['wall_s']}s "
                     f"compile={leg['compile_s']}s")
             del want, params, backend

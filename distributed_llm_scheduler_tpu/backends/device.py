@@ -264,9 +264,15 @@ class DeviceBackend:
         self._seg_cache: "weakref.WeakKeyDictionary" = (
             weakref.WeakKeyDictionary()
         )
-        # graph -> {(tids, exports, donate_argnums): jitted coalesced
-        # launch group} (dispatch_plan coalescing); weak like _seg_cache
-        self._group_cache: "weakref.WeakKeyDictionary" = (
+        # (launch structure, donate_argnums) -> jitted fused launch
+        # (dispatch_plan.launch_structure: member fn objects, in-run
+        # wiring by position, exported positions).  No task id, graph or
+        # parameter name is in the key or the value: layers, graphs and
+        # execute() calls wired alike share one executable
+        self._group_cache: Dict[Any, Callable[..., Any]] = {}
+        # graph -> True when no task fn carries a jaxpr effect (host
+        # callbacks): what lets execute() fuse launches by default
+        self._effect_free: "weakref.WeakKeyDictionary" = (
             weakref.WeakKeyDictionary()
         )
         # graph -> {program signature: jitted whole-program callable}
@@ -667,42 +673,103 @@ class DeviceBackend:
             else:
                 self.jit_cache_hits += 1
             return fn
-        fn = self._jit_cache.get(task.fn)
-        if fn is None:
+        return self._jit_of(task.fn)
+
+    def _jit_of(self, fn: Callable[..., Any]):
+        """The shared plain jit of one task ``fn`` object (a per-task
+        launch calls it; a fused launch traces through it, so the member
+        is traced once however many structures hold it)."""
+        jfn = self._jit_cache.get(fn)
+        if jfn is None:
             self.jit_cache_misses += 1
-            fn = jax.jit(task.fn)
-            self._jit_cache[task.fn] = fn
+            jfn = jax.jit(fn)
+            self._jit_cache[fn] = jfn
         else:
             self.jit_cache_hits += 1
-        return fn
+        return jfn
 
     def _grouped_jitted(
-        self,
-        graph: TaskGraph,
-        tids: Tuple[str, ...],
-        exports: Tuple[str, ...],
-        donate_argnums: Tuple[int, ...] = (),
+        self, key: Any, donate_argnums: Tuple[int, ...] = (),
     ):
-        """Jitted coalesced launch group (dispatch_plan): ``tids`` run in
-        order inside ONE executable, ``optimization_barrier`` between
-        members keeping per-task numerics bit-identical to separate
-        launches.  Cached per (graph, tids, exports, donate pattern) —
-        same keying rationale as ``_segment_callable``."""
-        per_graph = self._group_cache.setdefault(graph, {})
-        key = (tids, exports, donate_argnums)
-        fn = per_graph.get(key)
+        """Jitted fused launch (dispatch_plan) for one launch *structure*
+        ``key = (fns, binds, export_positions)``: the members run in order
+        inside ONE executable, ``optimization_barrier`` between them
+        keeping per-task numerics bit-identical to separate launches.
+        Cached per (structure, donate pattern), never per task ids: every
+        launch wired alike — the same span one layer down, the next
+        ``execute()``, another graph over the same fns — calls the same
+        jit object, and ``compile.group_structures`` in
+        ``obs.process_metrics()`` counts how many this backend built."""
+        cache_key = (key, donate_argnums)
+        fn = self._group_cache.get(cache_key)
         if fn is None:
             from .dispatch_plan import _build_group_fn
 
+            fns, binds, export_pos = key
             self.jit_cache_misses += 1
             fn = jax.jit(
-                _build_group_fn(graph, tids, exports),
+                _build_group_fn(
+                    tuple(self._jit_of(f) for f in fns),
+                    binds, export_pos,
+                ),
                 donate_argnums=donate_argnums or None,
             )
-            per_graph[key] = fn
+            self._group_cache[cache_key] = fn
+            process_metrics().gauge("compile.group_structures").set(
+                len(self._group_cache)
+            )
         else:
             self.jit_cache_hits += 1
         return fn
+
+    def host_effect_free(
+        self, graph: TaskGraph, params: Dict[str, Any], graph_input: Any,
+        ext_outputs: Optional[Dict[str, Any]] = None,
+    ) -> bool:
+        """True when no task ``fn`` of ``graph`` carries a jaxpr effect.
+
+        An effect (``jax.debug.callback``, ``io_callback``, an ordered
+        print) is the one thing a fused launch cannot keep: inside one XLA
+        program an unordered host callback has no per-launch ordering.
+        Read from ``jax.make_jaxpr(fn).effects``, once per distinct ``fn``
+        object (16 on the GPT-2 DAGs), against the output avals the graph
+        carries (``Task.out_shape``; a task without one is shape-evaluated
+        here).  Remembered per graph, so a second ``execute()`` pays a
+        dictionary read."""
+        known = self._effect_free.get(graph)
+        if known is not None:
+            return known
+        from .dispatch_plan import _sds
+
+        def sds(x: Any) -> Any:
+            return jax.tree_util.tree_map(_sds, x)
+
+        avals: Dict[str, Any] = {
+            k: sds(v) for k, v in (ext_outputs or {}).items()
+        }
+        in_aval = sds(graph_input)
+        seen: Dict[Any, bool] = {}
+        for tid in graph.topo_order:
+            task = graph[tid]
+            if task.fn in seen and task.out_shape is not None:
+                continue
+            pd = {loc: sds(params[g]) for loc, g in task.param_items()}
+            args = [
+                avals[d] if d in avals else graph[d].out_shape
+                for d in task.arg_tasks or task.dependencies
+            ] or [in_aval]
+            if task.fn not in seen:
+                jaxpr, out = jax.make_jaxpr(task.fn, return_shape=True)(
+                    pd, *args
+                )
+                seen[task.fn] = bool(jaxpr.effects)
+            else:
+                out = jax.eval_shape(task.fn, pd, *args)
+            if task.out_shape is None:
+                avals[tid] = out
+        free = not any(seen.values())
+        self._effect_free[graph] = free
+        return free
 
     def warmup(
         self,
@@ -1417,7 +1484,7 @@ class DeviceBackend:
         reps: int = 1,
         rebatch: bool = True,
         planned: Optional[bool] = None,
-        coalesce: bool = False,
+        coalesce: Optional[bool] = None,
         donate: Optional[bool] = None,
         compiled: bool = False,
         fence_rtt: Optional[float] = None,
@@ -1429,10 +1496,11 @@ class DeviceBackend:
         """Place params, compile, run, measure.
 
         ``planned`` selects the pre-planned fast dispatch path
-        (:mod:`.dispatch_plan`): an immutable per-task plan built at
+        (:mod:`.dispatch_plan`): an immutable launch plan built at
         warmup (resolved executables, prebuilt param bindings, integer
         value-table indices, batched per-launch ``device_put`` staging),
-        so the hot loop issues only cached-executable calls.  Default
+        so the hot loop issues only cached-executable calls — one per
+        fused same-device run (``coalesce`` below), not one per task.  Default
         (``None``) auto-enables it whenever compatible — ``profile``
         (needs per-task timing hooks), ``stream_params`` (param residency
         changes mid-run), and ``segments`` (already fused) keep the
@@ -1477,13 +1545,23 @@ class DeviceBackend:
         forced off by ``keep_outputs`` (retained outputs must outlive the
         run — passing ``donate=True`` with ``keep_outputs`` raises).
 
-        ``coalesce`` (planned only, opt-in): fuse runs of consecutive
-        same-device tasks whose non-leading members consume only
-        values produced inside the run into ONE launch, with
+        ``coalesce`` (planned only): launch runs of consecutive
+        same-device tasks as ONE program each
+        (:mod:`.dispatch_plan`, "fused launches"), with
         ``optimization_barrier`` between members so per-task outputs stay
-        bit-identical.  Opt-in because host-side effects inside task fns
-        (``jax.debug.callback(ordered=False)``) lose their per-launch
-        ordering inside a single XLA program.
+        bit-identical: O(runs) launches a step where the per-task plan
+        makes O(tasks), through executables keyed by a launch's
+        *structure*, so every layer wired alike shares one.  The default
+        (``None``) is the code's choice: it fuses the launches whose
+        structure repeats in the plan, unless a task ``fn`` carries host
+        effects (``jax.debug.callback`` and kin, read from each distinct
+        ``fn``'s jaxpr once: inside one XLA program an unordered callback
+        loses its per-launch ordering) — such a graph keeps per-task
+        launches.  ``False`` forces per-task launches (the parity
+        reference); ``True`` fuses every run, repeated or not, and is the
+        caller's word that no ``fn`` needs per-launch ordering.
+        ``keep_outputs`` and ``ext_outputs`` compose: every member is
+        then exported.  ``DeviceReport.n_dispatches`` counts the launches.
 
         ``reps > 1`` dispatches the whole placed run ``reps`` times
         back-to-back and fences ONCE at the end; ``makespan_s`` is then
@@ -1614,6 +1692,8 @@ class DeviceBackend:
             )
         if coalesce and not planned:
             raise ValueError("coalesce=True requires the planned path")
+        if not planned:
+            coalesce = False
         if donate and keep_outputs:
             raise ValueError(
                 "donate=True deletes dying intermediates; keep_outputs "
@@ -1659,6 +1739,10 @@ class DeviceBackend:
         missing = sorted(graph.unique_params() - set(params))
         if missing:
             raise ValueError(f"params missing for placement: {missing[:5]}")
+        if coalesce is None and not self.host_effect_free(
+            graph, params, graph_input, ext_outputs
+        ):
+            coalesce = False
         # obs: explicit trace=/metrics= win; else the DLS_TRACE ambient
         # pair; else None — and every instrumented path below guards on
         # None, so a disabled run records nothing and pays only the checks
@@ -1987,4 +2071,8 @@ class DeviceBackend:
         for k, v in dispatch_phases.items():
             pm.histogram(f"execute.phase.{k}", unit="s").observe(v)
         pm.histogram("execute.wall_s", unit="s").observe(report.wall_s)
+        if plan is not None:
+            pm.histogram("execute.tasks_per_launch").observe(
+                sum(len(st.tids) for st in plan.steps) / max(n_disp, 1)
+            )
         return report

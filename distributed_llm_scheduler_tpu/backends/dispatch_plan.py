@@ -29,20 +29,32 @@ all fixed before the first launch.  This module moves it to plan time:
   feeding one step at two argument positions is not donated at all.
   Cross-core transfers are fresh copies owned by the consuming step, so
   those are always donated (the producer's original stays live).
-* **Coalesced launches** (opt-in ``coalesce=True``): the global dispatch
-  order is first re-linearized to maximize runs of consecutive same-device
-  tasks — legal because async dispatch only needs a task's upstreams
-  *enqueued* first, and both ``Schedule.per_node`` order and topological
-  dispatch order are preserved exactly.  Each run (capped at
-  :data:`_GROUP_CAP` members to bound XLA program size) becomes ONE jitted
-  multi-task call: members read in-group values directly and everything
+* **Fused launches** (what ``execute()`` does on this path unless it must
+  not): the global dispatch order is first re-linearized to maximize runs
+  of consecutive same-device tasks — legal because async dispatch only
+  needs a task's upstreams *enqueued* first, and both
+  ``Schedule.per_node`` order and topological dispatch order are preserved
+  exactly.  Each run is cut into launches (:func:`_cut_runs`: where
+  nothing the launch produced is still awaited by the rest of its run,
+  else at :data:`_GROUP_CAP` members) and every launch is ONE jitted
+  multi-task call: members read in-run values directly and everything
   else (earlier task outputs, ext values, the staged graph input) as
   launch arguments, so per-task placement semantics survive intact.
   ``jax.lax.optimization_barrier`` between member computations keeps each
   task's numerics bit-identical to separate launches (XLA cannot fuse
-  across the barrier).  Opt-in because host-side effects inside task fns
-  (``jax.debug.callback(ordered=False)``) have no ordering guarantee
-  within one XLA program.
+  across the barrier).  The executable is keyed by the launch's
+  *structure* (:func:`launch_structure`: member ``fn`` objects, in-run
+  wiring by member position, exported positions, donation pattern) and
+  takes each member's weights positionally, so layer 7's launch calls
+  the executable layer 0's compiled: O(runs) launches a step, O(distinct
+  structures) programs a process.  By default only structures that occur
+  more than once in the plan are fused (a launch nobody repeats would
+  pay a compile for one call a step; its tasks launch singly, as their
+  shared per-``fn`` programs).  The host-effect rule: a task ``fn`` whose
+  jaxpr carries effects (``jax.debug.callback``, ``io_callback``) loses
+  its per-launch ordering inside one XLA program, so ``execute()`` reads
+  the effects of each distinct ``fn`` once and keeps such a graph on
+  per-task launches.
 
 Fail-and-continue is preserved statically: tasks with failed (unplaced or
 transitively skipped) upstreams are dropped at plan build, mirroring the
@@ -54,7 +66,7 @@ from __future__ import annotations
 # dls-lint: allow-file(DET001) dispatch timing harness: wall time IS the measured quantity
 
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 from jax.sharding import SingleDeviceSharding
@@ -70,7 +82,6 @@ from jax.sharding import SingleDeviceSharding
 from jax._src.lib import xla_client as _xc
 
 from ..obs.trace import CAT_LAUNCH, CAT_STAGE, annotate
-from .rebatch import extract_steps
 
 
 def _fast_put(aval, sharding, xs, devices):
@@ -125,70 +136,175 @@ def donation_supported() -> bool:
 # argument list (the staged per-node input slot backs it at run time)
 GRAPH_INPUT = "__graph_input__"
 
-# max members per coalesced launch — bounds XLA program size / compile time
-_GROUP_CAP = 16
+# max members per fused launch — bounds XLA program size / compile time,
+# and how long a cross-chip consumer waits on members it does not read
+_GROUP_CAP = 32
+
+
+def _arg_ids(task) -> Sequence[str]:
+    return task.arg_tasks or task.dependencies
 
 
 def group_arg_binds(graph, tids: Tuple[str, ...]):
-    """Argument wiring for a (possibly coalesced) launch over ``tids``.
+    """Argument wiring for a (possibly fused) launch over ``tids``.
 
     Returns ``(binds, ext_list)``.  ``ext_list`` is the ordered tuple of
-    external inputs the launch takes after the params dict: task ids
-    produced outside the group, or :data:`GRAPH_INPUT` for a root member's
+    external inputs the launch takes after the params: task ids produced
+    outside the group, or :data:`GRAPH_INPUT` for a root member's
     graph-input read — one entry per (member, arg position) occurrence,
     duplicates kept, mirroring the legacy loop's per-argument semantics.
-    ``binds[i]`` wires member i's arguments: ``('v', tid)`` reads an
-    in-group value, ``('x', k)`` reads ``ext_list[k]``.
+    ``binds[i]`` wires member i's arguments: ``('v', j)`` reads the value
+    member ``j`` of this launch produced, ``('x', k)`` reads
+    ``ext_list[k]`` — positions only, so two launches wired alike compare
+    equal whatever their task ids.
     """
-    inside: set = set()
-    binds: List[Tuple[Tuple[str, Any], ...]] = []
+    inside: Dict[str, int] = {}
+    binds: List[Tuple[Tuple[str, int], ...]] = []
     ext_list: List[str] = []
-    for tid in tids:
-        aids = graph[tid].arg_tasks or graph[tid].dependencies
-        row: List[Tuple[str, Any]] = []
-        if aids:
-            for d in aids:
-                if d in inside:
-                    row.append(("v", d))
-                else:
-                    row.append(("x", len(ext_list)))
-                    ext_list.append(d)
-        else:
-            row.append(("x", len(ext_list)))
-            ext_list.append(GRAPH_INPUT)
+    for i, tid in enumerate(tids):
+        aids = _arg_ids(graph[tid])
+        row: List[Tuple[str, int]] = []
+        for d in aids or (GRAPH_INPUT,):
+            if d in inside:
+                row.append(("v", inside[d]))
+            else:
+                row.append(("x", len(ext_list)))
+                ext_list.append(d)
         binds.append(tuple(row))
-        inside.add(tid)
+        inside[tid] = i
     return tuple(binds), tuple(ext_list)
 
 
-def _build_group_fn(graph, tids: Tuple[str, ...], exports: Tuple[str, ...]):
-    """One callable running ``tids`` in order: (params-by-global-name,
-    *external-args) -> tuple of export outputs.
+def _program_order(graph, span: Sequence[str]) -> Tuple[str, ...]:
+    """The order a fused launch computes ``span`` in: its dataflow-connected
+    components one after another (by first member), each in span order.
 
-    Members read values produced inside the group directly and everything
-    else from the external argument list (wiring from
+    Inside one XLA program the member order binds nothing — the compiler
+    schedules the islands itself — so the launch is free to name its
+    members canonically: two microbatch chains a policy interleaved in
+    lockstep and the same two interleaved one task apart become the same
+    program.  The plan's ``tids`` keep the span's (per-node) order."""
+    pos = {t: i for i, t in enumerate(span)}
+    root = list(range(len(span)))
+
+    def find(i: int) -> int:
+        while root[i] != i:
+            root[i] = root[root[i]]
+            i = root[i]
+        return i
+
+    for i, t in enumerate(span):
+        for d in _arg_ids(graph[t]):
+            j = pos.get(d)
+            if j is not None:
+                a, b = find(i), find(j)
+                root[max(a, b)] = min(a, b)
+    comps: Dict[int, List[str]] = {}
+    for i, t in enumerate(span):
+        comps.setdefault(find(i), []).append(t)
+    return tuple(t for r in sorted(comps) for t in comps[r])
+
+
+class FusedLaunch(NamedTuple):
+    """What one fused launch runs, by name and by structure."""
+
+    members: Tuple[str, ...]    # task ids in program order
+    exports: Tuple[str, ...]    # members whose values leave the launch
+    key: Any                    # the executable's structure (below)
+    ext_list: Tuple[str, ...]   # external inputs, in argument order
+
+
+def launch_structure(
+    graph, members: Tuple[str, ...], exports: Tuple[str, ...]
+) -> FusedLaunch:
+    """A fused launch over ``members`` (program order), with ``key`` what
+    its program depends on and nothing else: ``(fns, binds,
+    export_positions)`` — the member ``fn`` objects, the in-run wiring by
+    position, and which members' values leave the launch.  Task ids,
+    global parameter names and layer numbers are not in it; the donation
+    pattern joins the key where the executable is resolved
+    (``DeviceBackend._grouped_jitted``)."""
+    binds, ext_list = group_arg_binds(graph, members)
+    pos = {t: i for i, t in enumerate(members)}
+    key = (
+        tuple(graph[t].fn for t in members), binds,
+        tuple(pos[t] for t in exports),
+    )
+    return FusedLaunch(members, exports, key, ext_list)
+
+
+def _build_group_fn(fns: Tuple[Any, ...], binds, export_pos: Tuple[int, ...]):
+    """One callable running ``fns`` in order: (per-member param dicts,
+    *external-args) -> tuple of exported outputs.
+
+    Members read values produced inside the launch directly and
+    everything else from the external argument list (wiring from
     :func:`group_arg_binds`).  ``optimization_barrier`` between members
     pins each task's computation as its own fusion island, so per-task
-    outputs are bit-identical to separate launches.
+    outputs are bit-identical to separate launches.  ``fns`` are the
+    backend's per-``fn`` jitted callables: a member is traced once per
+    process and argument shape, however many launches and structures it
+    appears in.  Nothing here names a task or a graph, so a cached
+    executable keeps neither alive.
     """
-    steps = extract_steps(graph, tids)
-    binds, _ext = group_arg_binds(graph, tids)
+    last = len(fns) - 1
 
-    def group_fn(gp, *ext_args):
-        vals: Dict[str, Any] = {}
-        for i, (tid, fn, pitems, _aids) in enumerate(steps):
-            pd = {loc: gp[g] for loc, g in pitems}
+    def group_fn(pds, *ext_args):
+        vals: List[Any] = []
+        for i, fn in enumerate(fns):
             args = [
                 vals[ref] if kind == "v" else ext_args[ref]
                 for kind, ref in binds[i]
             ]
-            out = fn(pd, *args)
-            if i < len(steps) - 1:
+            out = fn(pds[i], *args)
+            if i < last:
                 out = jax.lax.optimization_barrier(out)
-            vals[tid] = out
-        return tuple(vals[t] for t in exports)
+            vals.append(out)
+        return tuple(vals[p] for p in export_pos)
 
     return group_fn
+
+
+def _cut_runs(graph, placement, order: Sequence[str]) -> List[List[str]]:
+    """Cut the (re-linearized) dispatch order into launches: spans of
+    consecutive same-device tasks.
+
+    A span closes where nothing it produced is still read by the rest of
+    its same-device run — once it holds a quarter of :data:`_GROUP_CAP`, so
+    that independent chains a policy runs side by side (pack: waves of a
+    few microbatches through one layer) end together and the next wave
+    starts the same program again — and at :data:`_GROUP_CAP` members
+    where the run never comes clean (one chip: the residual stream is
+    always in flight, and the cap's spans repeat down the layers)."""
+    spans: List[List[str]] = []
+    i, n = 0, len(order)
+    while i < n:
+        node = placement[order[i]]
+        j = i
+        while j < n and placement[order[j]] == node:
+            j += 1
+        run = order[i:j]
+        idx = {t: k for k, t in enumerate(run)}
+        last_read: Dict[int, int] = {}
+        for k, t in enumerate(run):
+            for d in _arg_ids(graph[t]):
+                p = idx.get(d)
+                if p is not None:
+                    last_read[p] = k
+        cur: List[str] = []
+        open_until = -1
+        for k, t in enumerate(run):
+            cur.append(t)
+            open_until = max(open_until, last_read.get(k, -1))
+            if len(cur) >= _GROUP_CAP or (
+                open_until <= k and 4 * len(cur) >= _GROUP_CAP
+            ):
+                spans.append(cur)
+                cur, open_until = [], -1
+        if cur:
+            spans.append(cur)
+        i = j
+    return spans
 
 
 def _sds(x: Any):
@@ -373,9 +489,12 @@ class DispatchPlan:
         placed_params: Dict[Tuple[str, str], Any],
         ext_keys: Tuple[str, ...] = (),
         donate: bool = False,
-        coalesce: bool = False,
+        coalesce: Optional[bool] = False,
         keep_outputs: bool = False,
     ) -> "DispatchPlan":
+        """``coalesce``: ``False`` one launch a task; ``True`` every
+        same-device span one launch; ``None`` only the spans whose
+        structure repeats in this plan (``execute()``'s default)."""
         placement = schedule.placement
         if keep_outputs:
             donate = False  # retained outputs must all outlive the run
@@ -391,37 +510,64 @@ class DispatchPlan:
             live.add(tid)
             alive.append(tid)
 
-        # launch groups: singletons unless coalescing is on.  Coalescing
+        # launches: one task each unless coalescing is on.  Coalescing
         # first re-linearizes the dispatch order (per-node order and topo
-        # dispatch preserved), then cuts it into capped same-device runs.
-        groups: List[List[str]] = []
-        if coalesce and alive:
+        # dispatch preserved), then cuts it into same-device spans.
+        if coalesce is not False and alive:
             alive = _relinearize(graph, schedule, alive, set(ext_keys))
-        if coalesce:
-            for tid in alive:
-                if (
-                    groups
-                    and placement[groups[-1][0]] == placement[tid]
-                    and len(groups[-1]) < _GROUP_CAP
-                ):
-                    groups[-1].append(tid)
-                else:
-                    groups.append([tid])
+            groups = _cut_runs(graph, placement, alive)
         else:
             groups = [[t] for t in alive]
 
-        group_of = {t: gi for gi, g in enumerate(groups) for t in g}
-        consumers: Dict[str, set] = {t: set() for t in alive}
+        consumers: Dict[str, List[str]] = {t: [] for t in alive}
         for tid in alive:
-            for d in graph[tid].arg_tasks or graph[tid].dependencies:
+            for d in _arg_ids(graph[tid]):
                 if d in consumers:
-                    consumers[d].add(group_of[tid])
-        exports_of: List[Tuple[str, ...]] = []
-        for gi, g in enumerate(groups):
-            exports_of.append(tuple(
+                    consumers[d].append(tid)
+
+        def exports_from(g: Sequence[str]) -> Tuple[str, ...]:
+            # a member's value leaves the launch when the run keeps it, a
+            # task outside reads it, or nothing reads it (a sink)
+            inside = set(g)
+            return tuple(
                 t for t in g
-                if keep_outputs or (consumers[t] - {gi}) or not consumers[t]
-            ))
+                if keep_outputs or not consumers[t]
+                or any(c not in inside for c in consumers[t])
+            )
+
+        # what each launch of several tasks runs; None for a single task
+        fused: List[Optional[FusedLaunch]] = []
+        for g in groups:
+            if len(g) == 1:
+                fused.append(None)
+                continue
+            members = _program_order(graph, g)
+            fused.append(
+                launch_structure(graph, members, exports_from(members))
+            )
+        if coalesce is None:
+            # the code's own choice: fuse what repeats.  A structure met
+            # once in the plan would compile a program for one call a
+            # step; its tasks launch singly instead, through the per-fn
+            # programs they share with every other layer
+            seen: Dict[Any, int] = {}
+            for f in fused:
+                if f is not None:
+                    seen[f.key] = seen.get(f.key, 0) + 1
+            regrouped: List[List[str]] = []
+            refused: List[Optional[FusedLaunch]] = []
+            for g, f in zip(groups, fused):
+                if f is not None and seen[f.key] == 1:
+                    regrouped.extend([t] for t in g)
+                    refused.extend([None] * len(g))
+                else:
+                    regrouped.append(g)
+                    refused.append(f)
+            groups, fused = regrouped, refused
+        exports_of: List[Tuple[str, ...]] = [
+            f.exports if f is not None else tuple(g)
+            for g, f in zip(groups, fused)
+        ]
 
         # slot allocation: ext, then per-device graph input, then exports
         slot_of: Dict[str, int] = {}
@@ -450,8 +596,12 @@ class DispatchPlan:
             fence[placement[g[0]]] = slot_of[g[-1]]
         fence_slots = tuple(sorted(fence.items()))
 
-        # per-group external argument lists (slot-backed launch inputs)
-        ext_lists = [group_arg_binds(graph, tuple(g))[1] for g in groups]
+        # per-launch external argument lists (slot-backed launch inputs)
+        ext_lists = [
+            f.ext_list if f is not None
+            else group_arg_binds(graph, tuple(g))[1]
+            for g, f in zip(groups, fused)
+        ]
 
         # last consuming group index per slot (donation lifetime analysis)
         last_use: Dict[int, int] = {}
@@ -553,19 +703,19 @@ class DispatchPlan:
             step.donate_slots = tuple(donate_slots)
             step.donate_tids = tuple(tid_of_slot[s] for s in donate_slots)
             step.donate_argnums = donate_argnums
-            step.group = len(g) > 1
-            if step.group:
-                exports = exports_of[gi]
-                step.out_slots = tuple(slot_of[t] for t in exports)
-                step.out_tids = exports
-                step.fn = backend._grouped_jitted(
-                    graph, tuple(g), exports, donate_argnums
+            launch = fused[gi]
+            step.group = launch is not None
+            if launch is not None:
+                step.out_slots = tuple(slot_of[t] for t in launch.exports)
+                step.out_tids = launch.exports
+                step.fn = backend._grouped_jitted(launch.key, donate_argnums)
+                step.pd = tuple(
+                    {
+                        loc: placed_params[(glob, node)]
+                        for loc, glob in graph[t].param_items()
+                    }
+                    for t in launch.members
                 )
-                step.pd = {
-                    glob: placed_params[(glob, node)]
-                    for t in g
-                    for _, glob in graph[t].param_items()
-                }
             else:
                 step.out_slots = (slot_of[g[0]],)
                 step.out_tids = (g[0],)

@@ -38,11 +38,11 @@ def extract_steps(
 ) -> Tuple[Tuple[str, Any, Tuple[Tuple[str, str], ...], Tuple[str, ...]], ...]:
     """Per-task ``(tid, fn, param_items, arg_ids)`` extracted up front.
 
-    Shared by every multi-task callable builder (segment fusion here and in
-    ``DeviceBackend._segment_callable``, coalesced launch groups in
-    :mod:`.dispatch_plan`): closures built over these tuples never capture
-    ``graph``, so a cache value keyed weakly by the graph cannot keep its
-    own key alive.
+    Shared by the multi-task callable builders (segment fusion here and
+    in ``DeviceBackend._segment_callable``, the whole-program lowering in
+    :mod:`.compiled_schedule`): closures built over these tuples never
+    capture ``graph``, so a cache value keyed weakly by the graph cannot
+    keep its own key alive.
     """
     return tuple(
         (

@@ -9,10 +9,11 @@ inside the dispatch loop, fence excluded) for four configurations on the
 8-virtual-device CPU mesh:
 
 * ``legacy``          — the per-task ``_run`` loop (``planned=False``)
-* ``planned``         — plan-then-dispatch, default flags (donation on
-                        where supported)
-* ``coalesce``        — planned + coalesced multi-task launches, donation
-                        on (the flagship default-shaped fast path)
+* ``planned``         — plan-then-dispatch, one launch a task
+                        (``coalesce=False``; donation on where supported)
+* ``coalesce``        — planned + fused multi-task launches, every run
+                        (``coalesce=True``), donation on; ``execute()``'s
+                        default fuses the runs whose structure repeats
 * ``coalesce_nodonate`` — planned + coalesced with donation off: the pure
                         dispatch-overhead configuration (donation trades
                         a little host time for peak-memory savings, so it
@@ -24,7 +25,7 @@ sample.  Two gates, both asserted in CI:
 
 * ``coalesce_nodonate`` must reduce host dispatch wall by at least
   ``--min-reduction`` (default 0.40) vs ``legacy``;
-* ``planned`` (defaults, donation on) must still beat ``legacy`` by at
+* ``planned`` (per-task launches, donation on) must still beat ``legacy`` by at
   least ``--min-reduction-default`` (default 0.15).
 
 Bit-identity is checked alongside: a ``keep_outputs`` run of the
@@ -121,7 +122,7 @@ def run_dispatch_bench(
 
     legs = {
         "legacy": dict(planned=False),
-        "planned": dict(),
+        "planned": dict(coalesce=False),
         "coalesce": dict(coalesce=True),
         "coalesce_nodonate": dict(coalesce=True, donate=False),
     }

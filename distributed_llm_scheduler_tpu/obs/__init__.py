@@ -148,7 +148,9 @@ def ambient_metrics() -> Optional[MetricsRegistry]:
 def process_metrics() -> MetricsRegistry:
     """The process-wide registry that is always on, ``DLS_TRACE`` or not:
     per ``execute`` call one observation into each ``execute.phase.*_s``
-    histogram and into ``execute.wall_s``.  Nothing per launch or per
+    histogram, into ``execute.wall_s`` and (planned calls) into
+    ``execute.tasks_per_launch``; the gauge ``compile.group_structures``
+    counts the fused-launch executables built.  Nothing per launch or per
     token is recorded here; the per-edge transfer counters stay behind
     the explicit / ambient registry."""
     return _process_metrics

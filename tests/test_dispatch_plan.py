@@ -9,7 +9,11 @@ properties that make that trade safe:
 * donation never deletes a buffer any later launch still reads;
 * coalescing may only re-linearize: per-node schedule order and
   topological enqueue order survive, and task outputs stay bit-identical
-  to the un-coalesced path (optimization_barrier guarantees this).
+  to the un-coalesced path (optimization_barrier guarantees this);
+* fused launches are what ``execute()`` does by default: launches of
+  one structure share ONE executable whatever their layer, a ``fn`` with
+  host effects keeps the graph on per-task launches, and the transfer
+  accounting is the per-task plan's.
 """
 
 import jax
@@ -234,6 +238,260 @@ def test_donation_frees_dying_intermediates(setup):
     )
 
 
+# -- fused launches are the default ------------------------------------
+
+
+def _deep(n_devices, policy, n_layer=4, microbatches=4):
+    """A multi-layer, multi-microbatch tiny GPT-2 DAG placed by ``policy``
+    over the first ``n_devices`` CPU devices, on a backend of its own."""
+    import dataclasses
+
+    dag = build_gpt2_dag(
+        dataclasses.replace(GPT2Config.tiny(), n_layer=n_layer),
+        batch=microbatches, seq_len=16, microbatches=microbatches,
+    )
+    cluster = Cluster.from_jax_devices(
+        jax.devices()[:n_devices], hbm_cap_gb=4.0
+    )
+    schedule = get_scheduler(policy).schedule(dag.graph, cluster)
+    assert not schedule.failed
+    return dag, dag.init_params(), dag.make_inputs(), DeviceBackend(cluster), schedule
+
+
+DEEP_CASES = [(1, "heft"), (4, "pack"), (4, "roundrobin"), (8, "greedy")]
+
+
+@pytest.fixture(scope="module", params=DEEP_CASES,
+                ids=[f"{p}-{n}dev" for n, p in DEEP_CASES])
+def deep(request):
+    return _deep(*request.param)
+
+
+def test_default_execute_fuses_and_is_bit_identical_per_task(deep):
+    """``execute()`` with default arguments launches fewer programs than
+    tasks wherever the placement leaves same-device runs that repeat, and
+    every task's output equals the legacy loop's bit for bit."""
+    dag, params, ids, backend, schedule = deep
+    ref = backend.execute(
+        dag.graph, schedule, params, ids, planned=False, keep_outputs=True
+    )
+    rep = backend.execute(dag.graph, schedule, params, ids, keep_outputs=True)
+    assert rep.planned
+    n_tasks = len(dag.graph.topo_order)
+    assert ref.n_dispatches == n_tasks
+    if schedule.policy != "roundrobin":
+        assert rep.n_dispatches < n_tasks
+    assert set(rep.task_outputs) == set(ref.task_outputs)
+    for tid, out in ref.task_outputs.items():
+        assert np.array_equal(
+            np.asarray(out), np.asarray(rep.task_outputs[tid])
+        ), tid
+    assert np.array_equal(np.asarray(ref.output), np.asarray(rep.output))
+
+
+def test_default_plan_keeps_transfers_and_per_node_order(deep):
+    """Fusing changes how many host calls a step makes and nothing else:
+    transfer edges and bytes are the per-task plan's, every node runs its
+    tasks in the schedule's order, every task is enqueued after its
+    upstreams."""
+    dag, params, ids, backend, schedule = deep
+    plain = backend.execute(dag.graph, schedule, params, ids, coalesce=False)
+    fused = backend.execute(dag.graph, schedule, params, ids)
+    assert fused.transfer_edges == plain.transfer_edges
+    assert fused.transfer_bytes == plain.transfer_bytes
+    assert np.array_equal(np.asarray(plain.output), np.asarray(fused.output))
+
+    order = backend.dispatch_order(dag.graph, schedule)
+    placed, _ = backend.place_params(dag.graph, schedule, params)
+    p_plain = DispatchPlan.build(backend, dag.graph, schedule, order, placed)
+    p_fused = DispatchPlan.build(
+        backend, dag.graph, schedule, order, placed, coalesce=None
+    )
+    assert p_fused.transfer_edges == p_plain.transfer_edges
+    assert _per_node_sequences(p_fused) == _per_node_sequences(p_plain)
+    assert _per_node_sequences(p_fused) == {
+        n: list(ts) for n, ts in schedule.per_node.items() if ts
+    }
+    seen = set()
+    for st in p_fused.steps:
+        for tid in st.tids:
+            assert all(d in seen for d in _deps(dag.graph, tid)), tid
+            seen.add(tid)
+
+
+@pytest.mark.parametrize("n_devices,policy", [(1, "heft"), (4, "pack")])
+def test_launches_of_one_structure_share_one_executable(n_devices, policy):
+    """The fused executable is keyed by what the program depends on —
+    member fns, in-run wiring, exports, donation — not by task ids: a
+    4-layer x 4-microbatch graph builds a handful, and a graph twice as
+    deep builds no more (``jit_cache_misses`` flat in depth)."""
+    from distributed_llm_scheduler_tpu.obs import process_metrics
+
+    built = []
+    for n_layer in (4, 8):
+        dag, params, ids, backend, schedule = _deep(
+            n_devices, policy, n_layer=n_layer
+        )
+        rep = backend.execute(dag.graph, schedule, params, ids)
+        plan_steps = rep.n_dispatches
+        structures = len(backend._group_cache)
+        assert 1 <= structures <= 8
+        assert plan_steps < len(dag.graph.topo_order)
+        gauge = process_metrics().snapshot()["gauges"]
+        assert gauge["compile.group_structures"]["value"] == structures
+        misses = backend.jit_cache_misses
+        backend.execute(dag.graph, schedule, params, ids, warmup=False)
+        assert backend.jit_cache_misses == misses   # nothing built twice
+        built.append((structures, misses))
+    if n_devices == 1:
+        # one chip: the same spans repeat down the layers, so depth adds
+        # launches and not programs
+        assert built[0] == built[1]
+    fused_jits = {
+        id(st.fn) for st in _default_plan(
+            dag, params, backend, schedule).steps if st.group
+    }
+    assert len(fused_jits) <= structures
+
+
+def _default_plan(dag, params, backend, schedule):
+    order = backend.dispatch_order(dag.graph, schedule)
+    placed, _ = backend.place_params(dag.graph, schedule, params)
+    return DispatchPlan.build(
+        backend, dag.graph, schedule, order, placed, coalesce=None,
+        donate=donation_supported(),
+    )
+
+
+def test_tasks_per_launch_lands_in_the_process_registry(deep):
+    from distributed_llm_scheduler_tpu.obs import (
+        process_metrics,
+        reset_ambient,
+    )
+
+    dag, params, ids, backend, schedule = deep
+    backend.execute(dag.graph, schedule, params, ids)
+    reset_ambient()
+    rep = backend.execute(dag.graph, schedule, params, ids, warmup=False)
+    h = process_metrics().snapshot()["histograms"]["execute.tasks_per_launch"]
+    assert h["count"] == 1
+    assert h["max"] == pytest.approx(
+        len(dag.graph.topo_order) / rep.n_dispatches
+    )
+    reset_ambient()
+
+
+def _chain_graph(noisy):
+    """Two independent chains of 24 repeated (scale, shift) stages over
+    one shared pair of fns — long enough that the capped spans repeat;
+    ``noisy`` puts an unordered host callback in one of the fns."""
+    import jax.numpy as jnp
+
+    from distributed_llm_scheduler_tpu import Task, TaskGraph
+
+    heard = []
+
+    def f_scale(p, x):
+        if noisy:
+            jax.debug.callback(lambda v: heard.append(float(v)), x.sum())
+        return x * p["w"]
+
+    def f_shift(p, x):
+        return x + 1.0
+
+    def f_root(p, x):
+        return x.astype(jnp.float32)
+
+    tasks, params = [], {}
+    for c in range(2):
+        prev = f"c{c}_root"
+        tasks.append(Task(prev, 1e-6, 1e-4, fn=f_root))
+        for i in range(24):
+            w = f"w{i % 4}"
+            params[w] = jnp.full((4,), 1.0 + (i % 4) / 8, jnp.float32)
+            a, b = f"c{c}_s{i}_scale", f"c{c}_s{i}_shift"
+            tasks.append(Task(
+                a, 1e-6, 1e-4, dependencies=[prev], params_needed={w},
+                param_bytes={w: 16}, fn=f_scale, arg_tasks=[prev],
+                param_alias={"w": w},
+            ))
+            tasks.append(Task(
+                b, 1e-6, 1e-4, dependencies=[a], fn=f_shift, arg_tasks=[a],
+            ))
+            prev = b
+    return TaskGraph(tasks, name=f"chains_{noisy}").freeze(), params, heard
+
+
+@pytest.mark.parametrize("noisy", [False, True])
+def test_a_fn_with_host_effects_keeps_per_task_launches(noisy):
+    """The one thing a fused launch cannot keep is the per-launch
+    ordering of an unordered host callback: ``execute()`` reads each
+    distinct fn's jaxpr effects once and leaves such a graph on one
+    launch a task; the same graph without the callback fuses."""
+    import jax.numpy as jnp
+
+    graph, params, heard = _chain_graph(noisy)
+    cluster = Cluster.from_jax_devices(jax.devices()[:1], hbm_cap_gb=4.0)
+    backend = DeviceBackend(cluster)
+    schedule = get_scheduler("heft").schedule(graph, cluster)
+    assert not schedule.failed
+    x = jnp.arange(4, dtype=jnp.int32)
+    assert backend.host_effect_free(graph, params, x) is (not noisy)
+    rep = backend.execute(graph, schedule, params, x, keep_outputs=True)
+    ref = backend.execute(
+        graph, schedule, params, x, planned=False, keep_outputs=True
+    )
+    n_tasks = len(graph.topo_order)
+    if noisy:
+        assert rep.n_dispatches == n_tasks
+        jax.effects_barrier()
+        assert heard  # and the callback did run
+    else:
+        assert rep.n_dispatches < n_tasks
+    for tid, out in ref.task_outputs.items():
+        assert np.array_equal(
+            np.asarray(out), np.asarray(rep.task_outputs[tid])
+        ), tid
+
+
+@pytest.mark.parametrize("stage,order", [
+    (0, "lockstep"), (0, "lagged"), (5, "lockstep"), (5, "lagged"),
+])
+def test_launch_structure_names_no_task_and_no_interleaving(stage, order):
+    """The key an executable is cached under holds fn objects and
+    positions only: the same two stages of two chains give one key
+    whichever layer they sit in and however the policy interleaved the
+    two chains; the plan's tids keep the span's own order."""
+    from distributed_llm_scheduler_tpu.backends.dispatch_plan import (
+        _program_order,
+        launch_structure,
+    )
+
+    graph, _params, _heard = _chain_graph(False)
+
+    def span_of(i, how):
+        a = [f"c0_s{i}_scale", f"c0_s{i}_shift",
+             f"c0_s{i + 1}_scale", f"c0_s{i + 1}_shift"]
+        b = [t.replace("c0_", "c1_") for t in a]
+        if how == "lockstep":
+            return [t for pair in zip(a, b) for t in pair]
+        return [a[0], a[1], b[0], a[2], b[1], a[3], b[2], b[3]]
+
+    def key_of(span):
+        members = _program_order(graph, span)
+        exports = (members[3], members[7])   # each chain's last value
+        return launch_structure(graph, members, exports)
+
+    want = key_of(span_of(0, "lockstep"))
+    got = key_of(span_of(stage, order))
+    assert got.key == want.key
+    # one outside read a chain
+    assert len(got.ext_list) == len(want.ext_list) == 2
+    fns, binds, export_pos = got.key
+    assert len(fns) == 8 and export_pos == (3, 7)
+    assert not any(isinstance(x, str) for row in binds for _k, x in row)
+
+
 # -- donation-alias analysis (analysis/donation_pass) -------------------
 
 
@@ -311,7 +569,7 @@ def test_leaf_phases_tile_the_call_and_land_once_in_the_process_registry(
     hists = process_metrics().snapshot()["histograms"]
     assert set(hists) == (
         {f"execute.phase.{k}" for k in LEAVES | {"loop_s"}}
-        | {"execute.wall_s"}
+        | {"execute.wall_s", "execute.tasks_per_launch"}
     )
     assert all(h["count"] == 5 for h in hists.values())
     assert hists["execute.wall_s"]["max"] >= rep.wall_s
