@@ -137,15 +137,23 @@ def analyze_decode(
             for p, nbytes in t.param_bytes.items():
                 if _is_cache_param(p):
                     pool_bytes[p] = nbytes
-        if len(set(pool_bytes.values())) > 1:
-            lo = min(pool_bytes, key=pool_bytes.get)
-            hi = max(pool_bytes, key=pool_bytes.get)
+        # one pool shape per KIND of pool (``cache_{kind}_{layer}``): a
+        # per-layer cache keeps pools of several kinds and widths side
+        # by side, but two layers' pools of one kind are one geometry
+        by_kind: Dict[str, Dict[str, int]] = {}
+        for p, nbytes in pool_bytes.items():
+            by_kind.setdefault(p.rsplit("_", 1)[0], {})[p] = nbytes
+        for same in by_kind.values():
+            if len(set(same.values())) <= 1:
+                continue
+            lo = min(same, key=same.get)
+            hi = max(same, key=same.get)
             rep.add(
                 "DEC003",
                 Severity.ERROR,
                 "KV page pools disagree on geometry: "
-                f"{lo!r} is {pool_bytes[lo]} bytes but {hi!r} is "
-                f"{pool_bytes[hi]} bytes (one pool shape per graph)",
+                f"{lo!r} is {same[lo]} bytes but {hi!r} is "
+                f"{same[hi]} bytes (one pool shape per graph and kind)",
                 param=hi,
                 data={"pool_bytes": dict(sorted(pool_bytes.items()))},
             )

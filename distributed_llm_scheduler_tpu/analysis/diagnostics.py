@@ -116,6 +116,7 @@ CODES: Dict[str, str] = {
     "PGL005": "pool accounting mismatch: free + used do not tile the pool",
     "PGL006": "refcount underflow/overflow on a shared page",
     "PGL007": "write or cow split violates copy-on-write discipline",
+    "PGL008": "the cache keeps pages the ownership stream does not cover",
     # -- request-lifecycle protocol (lifecycle_pass) --------------------
     "LCY001": "illegal lifecycle transition (state/timestamp mismatch)",
     "LCY002": "non-monotone per-request timestamps (time travel)",

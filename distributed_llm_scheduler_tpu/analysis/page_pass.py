@@ -1,4 +1,4 @@
-"""Pass — page-lifetime prover (PGL001-PGL007).
+"""Pass — page-lifetime prover (PGL001-PGL008).
 
 Replays the append-only ownership event stream recorded by the
 :class:`~..models.kv_pages.PageOwnershipLog` seam against a REF-COUNTED
@@ -42,6 +42,8 @@ PGL007  copy-on-write violation: a ``write`` on a page with
         refcount > 1 and no preceding split (aliased readers would
         observe it), or a ``cow`` split whose destination was not
         allocated before the source reference was dropped
+PGL008  the cache keeps pages the stream does not cover (a ring
+        layer's slot-owned pages): an explicit refusal, never a pass
 ======  ==========================================================
 
 A shared page with any live owner is NOT an orphan — PGL001 is judged
@@ -105,6 +107,18 @@ def analyze_pages(
     rep = AnalysisReport()
     events = _events_of(source)
     pool_pages = _n_pages_of(source, n_pages)
+    uncovered = (source.get("uncovered") if isinstance(source, dict)
+                 else getattr(source, "uncovered", None))
+    if uncovered:
+        # the stream is the shared pool's; pages it never allocates
+        # (a window layer's slot-owned rings) are outside the proof, and
+        # a clean replay must not read as a proof of them
+        rep.add(
+            "PGL008",
+            Severity.ERROR,
+            f"page lifetimes are not proven for this cache: {uncovered}",
+            data={"uncovered": uncovered},
+        )
 
     # page -> seq of the alloc event currently covering it
     allocated: Dict[int, int] = {}

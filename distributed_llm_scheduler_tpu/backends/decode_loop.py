@@ -195,10 +195,14 @@ def compose_paged_step_fn(
     page, so one compiled step serves every admission/retirement state.
 
     The cache is whatever :func:`...models.cache_spec`
-    says the family keeps (K and V pools, or one latent pool): layer
-    ``i``'s task emits ``{kind}_new`` for each pool kind.  Layer tasks
+    says the family keeps, layer by layer (K and V pools, one latent
+    pool, or pools that differ between layers): layer ``i``'s task emits
+    ``{kind}_new`` for each of its pool kinds.  A ring layer's row goes
+    through the static ring table at ``lengths mod ring`` instead of the
+    page table (:class:`...models.kv_pages.CacheSpec`).  Layer tasks
     that emit ``stats`` (an expert layer's routing counts) have them
-    stacked, layer-major.
+    stacked, layer-major — per name where a layer's ``stats`` is a dict
+    of named counts, over the layers that emit that name.
 
     Returns ``step(weights, pools, page_table, ids, lengths, active)
     -> (logits, new_pools, stats or None)``.
@@ -208,6 +212,14 @@ def compose_paged_step_fn(
     order = _placed_order(graph, schedule)
     sink = [tid for tid in order if not graph.dependents(tid)][0]
     spec = cache_spec(config)
+
+    def write(pool, row, page_table, lengths, active, window):
+        if window is None:
+            return write_token_rows(pool, row, page_table, lengths, active)
+        slots, ps = page_table.shape[0], pool.shape[1]
+        ring = jnp.asarray(spec.ring_table(slots, ps))
+        return write_token_rows(
+            pool, row, ring, lengths % (ring.shape[1] * ps), active)
 
     def step(weights, pools, page_table, ids, lengths, active):
         inputs = {"ids": ids, "lengths": lengths, "active": active}
@@ -230,16 +242,23 @@ def compose_paged_step_fn(
             outs[tid] = task.fn(p, *args)
         logits = outs[sink]
         new_pools = dict(pools)
-        stats = []
+        stats, named = [], {}
         for i in range(spec.n_layers):
             o = outs[f"layer_{i}"]
-            for kind in spec.kinds:
-                new_pools[f"cache_{kind}_{i}"] = write_token_rows(
+            window = spec.layer(i).window
+            for kind in spec.layer_kinds(i):
+                new_pools[f"cache_{kind}_{i}"] = write(
                     new_pools[f"cache_{kind}_{i}"], o[f"{kind}_new"],
-                    page_table, lengths, active,
+                    page_table, lengths, active, window,
                 )
-            if "stats" in o:
+            if isinstance(o.get("stats"), dict):
+                for k, v in o["stats"].items():
+                    named.setdefault(k, []).append(v)
+            elif "stats" in o:
                 stats.append(o["stats"])
+        if named:
+            return logits, new_pools, {
+                k: jnp.stack(v) for k, v in named.items()}
         return logits, new_pools, (jnp.stack(stats) if stats else None)
 
     return step
@@ -434,6 +453,12 @@ class PagedDecodeEngine:
         # tail, or chunk) so a VirtualClock frontend can charge prefill
         # compute time proportional to tokens.  None costs nothing.
         self.prefill_time_charge: Optional[Callable[[int], None]] = None
+        # probe seam: when set, called once per segment with the named
+        # per-step arrays the layers emitted that the engine does not
+        # read itself (a sparse-selection family's ``dsa_idx``), as
+        # numpy, and the request id, start length and owed steps of
+        # every slot.  None costs nothing: the arrays stay on the device.
+        self.stats_probe: Optional[Callable[..., None]] = None
         self._np = np
         self.n_layers = n_layers
         self._seg = build_paged_decode_loop(
@@ -446,8 +471,17 @@ class PagedDecodeEngine:
         # dispatches per admission and per-segment readbacks (at serving
         # granularity that overhead was the whole paged-vs-dense margin)
         self.pools = self.cache.init_pools(
-            pool.n_pages, pool.page_size, config.dtype
+            pool.n_pages, pool.page_size, config.dtype, slots=slots
         )
+        # ring layers (a window layer's slot-owned pages): the static
+        # table the prefill programs gather and scatter a slot's ring
+        # through; None where the spec has none, and then no program
+        # takes the argument
+        self._rings = (
+            self.cache.ring_table(slots, pool.page_size)
+            if self.cache.has_rings else None
+        )
+        self.sharing    # a cache with ring layers refuses a sharing pool
         self.page_table = np.full(
             (slots, pages_per_seq), TRASH_PAGE, np.int32
         )
@@ -517,7 +551,7 @@ class PagedDecodeEngine:
         # attach_ownership_log() or rebind_obs(ownlog=...).
         self.ownlog = None
         self._page_bytes = (
-            n_layers * pool.page_size * self.cache.row_elems
+            pool.page_size * self.cache.paged_row_elems
             * np.dtype(config.dtype).itemsize
         )
         # the pools are one placed slab: attribute kv pages to the node
@@ -550,6 +584,11 @@ class PagedDecodeEngine:
         pool.ownlog = log
         if log is not None and getattr(log, "n_pages", None) is None:
             log.n_pages = pool.n_pages
+        if log is not None and self._rings is not None:
+            log.uncovered = (
+                f"{self._rings.size} slot-owned ring pages in each window "
+                "layer's pool are written by their slots and never pass "
+                "through the allocator")
 
     def reset(self) -> None:
         """Fresh pool/table/queue state, compiled programs kept.
@@ -581,7 +620,8 @@ class PagedDecodeEngine:
         if drop is not None:
             drop()
         self.pools = self.cache.init_pools(
-            self.pool.n_pages, self.pool.page_size, self.config.dtype
+            self.pool.n_pages, self.pool.page_size, self.config.dtype,
+            slots=self.slots,
         )
         self.page_table = np.full(
             (self.slots, self.pages_per_seq), TRASH_PAGE, np.int32
@@ -684,8 +724,18 @@ class PagedDecodeEngine:
     @property
     def sharing(self) -> bool:
         """Whether the pool interns prefix chunks (read live off the
-        pool, so ``rebind_obs``'s pristine replacement keeps the mode)."""
-        return bool(getattr(self.pool, "sharing", False))
+        pool, so ``rebind_obs``'s pristine replacement keeps the mode).
+        Refused for a cache with ring layers: a shared page carries the
+        paged layers' rows of a prefix and nothing of the window layers'
+        state, so a request that aliased one would decode over rings it
+        never filled."""
+        on = bool(getattr(self.pool, "sharing", False))
+        if on and self._rings is not None:
+            raise ValueError(
+                "prefix sharing is not built for a cache with ring "
+                "(window) layers: a shared page does not carry their "
+                "state; use PagePool(sharing=False)")
+        return on
 
     def _release_pages(self, pages, owner: str, site: str) -> None:
         """The ONE page-release path for retire/preempt/reset: records
@@ -1011,13 +1061,21 @@ class PagedDecodeEngine:
 
     # -- prefill + page scatter (ONE call per admission ROUND; one
     # compiled class per (prompt length, batch size)) ----------------------
-    def _prefill_scatter(self, prompt_ids: jax.Array, pt_rows):
+    def _ring_args(self, slots) -> tuple:
+        """What a prefill program takes beside the page rows where the
+        cache has ring layers: the ``slots``' own ring pages, flat."""
+        if self._rings is None:
+            return ()
+        return (jnp.asarray(self._rings[list(slots)].reshape(-1)),)
+
+    def _prefill_scatter(self, prompt_ids: jax.Array, pt_rows, slots=()):
         """Prefill ``b`` same-length prompts and scatter all their cache
         rows into their pages in ONE jitted, pool-donating call.
 
         ``prompt_ids`` (b, P); ``pt_rows`` (b, pages_per_seq) physical
-        page rows (trash-padded tails).  Returns the (b,) first greedy
-        tokens.  Weights are an argument (see the segment fn)."""
+        page rows (trash-padded tails); ``slots`` the slots they go to
+        (read where the cache has ring layers).  Returns the (b,) first
+        greedy tokens.  Weights are an argument (see the segment fn)."""
         b, P = prompt_ids.shape
         key = (P, b, self.attention_impl)
         fn = self._prefill_store.get(key)
@@ -1026,12 +1084,12 @@ class PagedDecodeEngine:
             cap, cfg = self.capacity, self.config
             ppseq, ps = self.pages_per_seq, self.page_size
 
-            def _fn(w, ids, pools, pages):
-                cache = spec.init_dense(b, cap, cfg.dtype)
+            def _fn(w, ids, pools, pages, *ring):
+                cache = spec.init_dense(b, cap, cfg.dtype, page_size=ps)
                 last, cache = fwd(w, ids, cache, 0, P - 1)
                 first = jnp.argmax(last, axis=-1).astype(jnp.int32)
                 return first, spec.scatter(
-                    pools, cache, pages.reshape(b * ppseq), ps)
+                    pools, cache, pages.reshape(b * ppseq), ps, *ring)
 
             fn = jax.jit(_fn, donate_argnums=(2,))
             self._prefill_store[key] = fn
@@ -1042,7 +1100,8 @@ class PagedDecodeEngine:
         if self.prefill_time_charge is not None:
             self.prefill_time_charge(b * P)
         first, self.pools = fn(
-            self.weights, prompt_ids, self.pools, jnp.asarray(pt_rows)
+            self.weights, prompt_ids, self.pools, jnp.asarray(pt_rows),
+            *self._ring_args(slots)
         )
         return first
 
@@ -1106,7 +1165,8 @@ class PagedDecodeEngine:
         return first
 
     # -- chunked prefill (co-scheduled with decode segments) ---------------
-    def _chunk_prefill(self, ids_chunk, pt_row, base: int, creal: int):
+    def _chunk_prefill(self, ids_chunk, pt_row, base: int, creal: int,
+                       slot: int = 0):
         """Run ONE prefill chunk for one slot: gather the slot's pages
         into a dense per-slot cache, run the transformer over the
         ``chunk_tokens`` chunk at traced ``pos_start = base``, and
@@ -1137,12 +1197,13 @@ class PagedDecodeEngine:
             cap, cfg = self.capacity, self.config
             ps = self.page_size
 
-            def _fn(w, ids, pools, pages, pos0, creal):
+            def _fn(w, ids, pools, pages, pos0, creal, *ring):
                 cache = spec.gather(
-                    spec.init_dense(1, cap, cfg.dtype), pools, pages, 1, cap)
+                    spec.init_dense(1, cap, cfg.dtype, page_size=ps), pools,
+                    pages, 1, cap, *ring)
                 last, cache = fwd(w, ids, cache, pos0, creal - 1)
                 first = jnp.argmax(last, axis=-1).astype(jnp.int32)
-                return first, spec.scatter(pools, cache, pages, ps)
+                return first, spec.scatter(pools, cache, pages, ps, *ring)
 
             fn = jax.jit(_fn, donate_argnums=(2,))
             self._prefill_store[key] = fn
@@ -1153,7 +1214,7 @@ class PagedDecodeEngine:
         first, self.pools = fn(
             self.weights, ids_chunk, self.pools,
             jnp.asarray(pt_row, jnp.int32),
-            jnp.int32(base), jnp.int32(creal),
+            jnp.int32(base), jnp.int32(creal), *self._ring_args((slot,)),
         )
         return first
 
@@ -1313,7 +1374,7 @@ class PagedDecodeEngine:
                 )
             with annotate("prefill_chunk"):
                 first = self._chunk_prefill(
-                    jnp.asarray(chunk), self.page_table[s], base, C
+                    jnp.asarray(chunk), self.page_table[s], base, C, s
                 )
             if ev is not None:
                 self.tracer.end(ev)
@@ -1566,7 +1627,8 @@ class PagedDecodeEngine:
                     all_ids, h0, sh_rows, wt_rows
                 )
             else:
-                first = self._prefill_scatter(all_ids, pt_rows)
+                first = self._prefill_scatter(
+                    all_ids, pt_rows, free_slots[:len(batch)])
             first = self._np.asarray(first)
             # first token exists NOW (the prefill's readback): the
             # admission timestamp is each request's TTFT anchor
@@ -1795,7 +1857,7 @@ class PagedDecodeEngine:
             )
             toks = self._np.asarray(toks)  # the one readback per segment
             if stats:  # counted on the device, same program: no new sync
-                self._observe_moe(self._np.asarray(stats[0]), owed)
+                self._observe_stats(stats[0], owed)
             # the fold timestamp: every token this segment delivered
             # became host-visible at this readback (lifecycle-log
             # delivery events)
@@ -1803,7 +1865,36 @@ class PagedDecodeEngine:
         with annotate("fold"):
             return self._fold_segment(toks, owed, t_sg0, t_sg1)
 
-    def _observe_moe(self, stats, owed) -> None:
+    def _observe_stats(self, stats, owed) -> None:
+        """What the segment's layers counted on the device, onto the
+        next ``segment`` span and into the registries: an expert
+        family's routing counts (an array, or ``stats["moe"]``) and a
+        sparse-selection family's rows (``stats["dsa"]``, (steps, full
+        layers, 2) = (latent rows the decoding slots' attention read,
+        rows those slots hold)).  Any other named array is the
+        ``stats_probe``'s, if one is set."""
+        np = self._np
+        if not isinstance(stats, dict):
+            stats = {"moe": stats}
+        args = {}
+        if "moe" in stats:
+            args.update(self._observe_moe(np.asarray(stats["moe"]), owed))
+        if "dsa" in stats:
+            read, held = np.asarray(stats["dsa"]).reshape(-1, 2).sum(axis=0)
+            args.update(rows_selected=float(read), rows_live=float(held))
+            if held:
+                for reg in (self.metrics, process_metrics()):
+                    reg.histogram(
+                        "dsa.selected_share", unit="ratio"
+                    ).observe(float(read / held))
+        self._seg_span_args = args
+        if self.stats_probe is not None:
+            self.stats_probe(
+                {k: np.asarray(v) for k, v in stats.items()
+                 if k not in ("moe", "dsa")},
+                list(self._slot_req), self.lengths.copy(), owed)
+
+    def _observe_moe(self, stats, owed) -> Dict[str, float]:
         """An expert family's routing counts of one segment, ``stats``
         (steps, expert layers, 2) = (share of the experts picked, largest
         expert's picks over the mean), into the engine's registry and the
@@ -1811,18 +1902,19 @@ class PagedDecodeEngine:
         slot still decoded."""
         np = self._np
         ran = stats[:min(int(owed.max()), self.seg_steps)].reshape(-1, 2)
-        # on the segment's span too: the mean distinct experts a
-        # layer-step read, over ALL the segment's steps (a step in which
-        # no slot decodes any more reads none, and its expert kernel is
-        # called all the same): what the kernel's mean bytes follow
-        self._seg_span_args = {"experts_touched": float(
-            stats[..., 0].mean() * self.config.n_routed_experts)}
         touched, imbalance = (float(v) for v in np.median(ran, axis=0))
         for reg in (self.metrics, process_metrics()):
             reg.histogram(
                 "moe.experts_touched_share", unit="ratio").observe(touched)
             reg.histogram(
                 "moe.pick_imbalance", unit="ratio").observe(imbalance)
+        # for the segment's span: the mean distinct experts a layer-step
+        # read, over ALL the segment's steps (a step in which no slot
+        # decodes any more reads none, and its expert kernel is called
+        # all the same): what the kernel's mean bytes follow
+        return {"experts_touched": float(
+            stats[..., 0].mean() * getattr(
+                self.config, "n_held_experts", self.config.n_routed_experts))}
 
     def _fold_segment(self, toks, owed, t_sg0: float, t_sg1: float) -> int:
         """What follows a segment's readback at ``t_sg1``: the tokens go

@@ -108,7 +108,8 @@ def _chain(fam, config, spec, add, flops, f_embed, layer_fn, f_head,
         fn = fns.get(tuple(alias))
         if fn is None:
             fn = fns[tuple(alias)] = layer_fn(i)
-        alias.update({f"cache_{k}": f"cache_{k}_{i}" for k in spec.kinds})
+        alias.update(
+            {f"cache_{k}": f"cache_{k}_{i}" for k in spec.layer_kinds(i)})
         alias.update(shared_alias)
         tid = f"layer_{i}"
         add(tid, fn, [prev], alias, layer_flops[i], tid)
@@ -276,7 +277,8 @@ def build_paged_decode_dag(
 ) -> PagedDecodeDAG:
     """Paged single-token decode step as a task DAG, for any family that
     offers the paged functions (``models.PAGED_FUNCTIONS``: GPT-2 over
-    K / V pools, the Xing4.0 block over a latent pool) — the one builder
+    K / V pools, the Xing4.0 block over a latent pool, the dots3 block over
+    per-layer pools, some of them rings) — the one builder
     that reaches :class:`...backends.decode_loop.PagedDecodeEngine`.
 
     The dense decode DAG's per-layer slabs become shared page POOLS
@@ -332,7 +334,7 @@ def build_paged_decode_dag(
         for k, (shape, dtype) in fam.param_shapes(config).items()
     }
     specs.update(jax.eval_shape(
-        lambda: spec.init_pools(n_pages, ps, config.dtype)))
+        lambda: spec.init_pools(n_pages, ps, config.dtype, slots=S)))
     specs["page_table"] = jax.ShapeDtypeStruct((S, pages_per_seq), jnp.int32)
     input_spec = {
         "ids": jax.ShapeDtypeStruct((S, 1), jnp.int32),
@@ -376,7 +378,7 @@ def build_paged_decode_dag(
 
     def init_fn(key):
         params = fam.init_params(config, key)
-        params.update(spec.init_pools(n_pages, ps, config.dtype))
+        params.update(spec.init_pools(n_pages, ps, config.dtype, slots=S))
         params["page_table"] = jnp.full(
             (S, pages_per_seq), TRASH_PAGE, jnp.int32)
         return params
@@ -389,10 +391,12 @@ def build_paged_decode_dag(
         weights = {k: v for k, v in params.items()
                    if not k.startswith("cache_") and k != "page_table"}
         outs = []
+        rings = spec.ring_table(S, ps) if spec.has_rings else None
         for s in range(S):
             cache = spec.gather(
-                spec.init_dense(1, M, config.dtype), params,
-                params["page_table"][s], 1, M)
+                spec.init_dense(1, M, config.dtype, page_size=ps), params,
+                params["page_table"][s], 1, M,
+                *(() if rings is None else (jnp.asarray(rings[s]),)))
             logits, _ = fam.forward_cached_row(
                 weights, inputs["ids"][s:s + 1], cache,
                 inputs["lengths"][s], config, 0, impl="xla")

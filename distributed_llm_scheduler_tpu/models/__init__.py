@@ -117,7 +117,7 @@ def module_of(config: Any):
 
 
 def cache_spec(config: Any):
-    """What one layer of this config caches for a token
+    """What each layer of this config caches for a token
     (:class:`.kv_pages.CacheSpec`): its family's ``cache_spec``."""
     return module_of(config).cache_spec(config)
 
@@ -182,6 +182,10 @@ for _f in (
     # served only (the paged decode DAG); no forward-DAG builder
     Family(
         "xing4", f"{__name__}.xing4", "Xing4Config", {"xing4-tiny": "tiny"},
+        "n_layers", "max_positions",
+    ),
+    Family(
+        "dots3", f"{__name__}.dots3", "Dots3Config", {"dots3-tiny": "tiny"},
         "n_layers", "max_positions",
     ),
 ):

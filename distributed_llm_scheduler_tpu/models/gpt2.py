@@ -462,7 +462,7 @@ def cache_spec(config: GPT2Config):
     from .kv_pages import CacheSpec
 
     row = (config.n_head, config.head_dim)
-    return CacheSpec("kv", config.n_layer, (("k", row), ("v", row)))
+    return CacheSpec.uniform("kv", config.n_layer, (("k", row), ("v", row)))
 
 
 def embed(p, ids, config: GPT2Config):

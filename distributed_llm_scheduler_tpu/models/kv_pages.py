@@ -84,6 +84,9 @@ class PageOwnershipLog:
     def __init__(self, n_pages: Optional[int] = None):
         self.n_pages = n_pages
         self.events: List[Dict[str, Any]] = []
+        #: set by an engine whose cache keeps pages this stream never
+        #: sees (ring layers): what they are; the prover refuses (PGL008)
+        self.uncovered: Optional[str] = None
 
     def record(
         self,
@@ -115,11 +118,14 @@ class PageOwnershipLog:
     def snapshot(self) -> Dict[str, Any]:
         """JSON-ready view (schema ``dls.pages/1``) — what a serve/soak
         artifact embeds so ``doctor --serve`` can replay it offline."""
-        return {
+        out = {
             "schema": OWNERSHIP_SCHEMA,
             "n_pages": self.n_pages,
             "events": [dict(e) for e in self.events],
         }
+        if self.uncovered:
+            out["uncovered"] = self.uncovered
+        return out
 
 
 def pages_needed(n_tokens: int, page_size: int) -> int:
@@ -493,17 +499,34 @@ class PagePool:
 
 
 @dataclasses.dataclass(frozen=True)
-class CacheSpec:
-    """What one layer caches for a token, and how the paged engine moves
-    it: the per-layer cache description every family's config maps to
-    (its family's ``cache_spec``; :func:`..cache_spec` asks by config).
+class LayerCache:
+    """One layer's entry of a :class:`CacheSpec`: its pools ``rows``,
+    ``(pool kind, row shape)`` each, the latent ``rank`` where a pool is
+    one (the row's leading values that are the latent itself — the part
+    the absorbed kernel accumulates; the rest is the rotated key), and —
+    for a **ring layer** — the ``window`` of positions a query may see
+    (itself included).  ``window`` ``None``: the layer caches the whole
+    context in pages of the shared pool, through the page table."""
 
-    ``kind`` ``"kv"``: two pools a layer, ``cache_k_{i}`` / ``cache_v_{i}``
-    with row ``(n_kv_heads, head_dim)``; the family's dense cache keeps
-    heads ahead of positions, ``(L, b, Hkv, cap, hd)``.  ``kind``
-    ``"latent"``: one pool a layer, ``cache_c_{i}``, row ``(width,)`` —
-    MLA's normalised latent and shared rotated key; dense ``(L, b, cap,
-    width)``.  ``rows`` lists ``(pool kind, row shape)``.
+    rows: Tuple[Tuple[str, Tuple[int, ...]], ...]
+    rank: Optional[int] = None
+    window: Optional[int] = None
+
+
+@dataclasses.dataclass(frozen=True)
+class CacheSpec:
+    """What each layer caches for a token, and how the paged engine moves
+    it: the cache description every family's config maps to (its
+    family's ``cache_spec``; :func:`..cache_spec` asks by config).
+
+    ``layers`` holds one :class:`LayerCache` a layer; a family whose
+    layers are all alike (GPT-2, Llama, Xing4.0) builds it with
+    :meth:`uniform`.  ``kind`` ``"kv"``: pools ``cache_k_{i}`` /
+    ``cache_v_{i}`` with row ``(n_kv_heads, head_dim)``; the family's
+    dense cache keeps heads ahead of positions, ``(L, b, Hkv, cap, hd)``.
+    ``kind`` ``"latent"``: pools of row ``(width,)`` — MLA's normalised
+    latent and shared rotated key ``cache_c_{i}``, and whatever else a
+    layer keeps a token (an indexer's key); dense ``(L, b, cap, width)``.
 
     The stored form is one for every kind: a pool is ``(n_pages,
     page_size, row_width)``, the row's values flattened into ONE vector
@@ -519,25 +542,114 @@ class CacheSpec:
     heads apart by ``head_dim`` — and GPT-2 XL's 1,600 keeps the lanes
     beside a 16-row page at every pool size that fits; a latent row,
     whose 128-row page pads nothing, is padded by its model to
-    ``lane_width`` (576 -> 640) to keep them."""
+    ``lane_width`` (576 -> 640) to keep them.
+
+    **Ring layers.**  A layer with a ``window`` is a ring layer: its
+    pool is not paged out of the shared :class:`PagePool` but owned by
+    the slots — ``ring_pages`` pages a slot in a pool of ``1 + slots *
+    ring_pages`` pages (page 0 the trash page, slot ``s`` the pages
+    :meth:`ring_table` lists), position ``p`` in ring row ``p mod
+    (ring_pages * page_size)`` — so it costs nothing per context token,
+    the allocator, admission and ``pages_needed`` never hear of it, and
+    the same :func:`write_token_rows` writes it through the static ring
+    table.  The dense cache the prefill programs hand the family holds,
+    per pool kind, the layers that keep that kind stacked in layer
+    order: ``{kind: (layers with it, b, cap, *row)}``, a ring kind
+    ``cap`` = the ring's rows.
+
+    ``walk`` names the pool whose live blocks the decode step's paged
+    kernel walks, ``(pool kind, rank)`` — what :meth:`resolve_impl` and
+    :meth:`block_pages` ask about; ``None``: the first pool of layers
+    that are all alike."""
 
     kind: str
-    n_layers: int
-    rows: Tuple[Tuple[str, Tuple[int, ...]], ...]
+    layers: Tuple[LayerCache, ...]
     #: kv: the query heads, where they are not the kv heads (GQA)
     q_heads: Optional[int] = None
-    #: latent: the row's leading values that are the latent itself (the
-    #: part the absorbed kernel accumulates; the rest is the rotated key)
-    rank: Optional[int] = None
+    #: rows a ring layer keeps for a slot (>= its window)
+    ring_rows: int = 0
+    walk: Optional[Tuple[str, Optional[int]]] = None
+
+    @classmethod
+    def uniform(cls, kind: str, n_layers: int,
+                rows: Tuple[Tuple[str, Tuple[int, ...]], ...],
+                q_heads: Optional[int] = None,
+                rank: Optional[int] = None) -> "CacheSpec":
+        """Every layer alike: ``rows`` / ``rank`` of each."""
+        return cls(kind, (LayerCache(rows, rank),) * n_layers, q_heads)
+
+    @property
+    def n_layers(self) -> int:
+        return len(self.layers)
+
+    def _alike(self) -> LayerCache:
+        """The one entry of layers that are all alike; a spec whose
+        layers differ has no answer for the whole model."""
+        first = self.layers[0]
+        if any(lc != first for lc in self.layers):
+            raise ValueError(
+                "this cache's layers differ: ask layer(i) / layer_kinds(i)")
+        return first
+
+    @property
+    def rows(self) -> Tuple[Tuple[str, Tuple[int, ...]], ...]:
+        """``(pool kind, row shape)`` of each pool of layers all alike."""
+        return self._alike().rows
+
+    @property
+    def rank(self) -> Optional[int]:
+        return self._alike().rank
 
     @property
     def kinds(self) -> Tuple[str, ...]:
+        """The pool kinds of layers all alike."""
         return tuple(k for k, _ in self.rows)
+
+    def layer(self, i: int) -> LayerCache:
+        return self.layers[i]
+
+    def layer_kinds(self, i: int) -> Tuple[str, ...]:
+        return tuple(k for k, _ in self.layers[i].rows)
+
+    @property
+    def has_rings(self) -> bool:
+        return any(lc.window is not None for lc in self.layers)
+
+    def ring_pages(self, page_size: int) -> int:
+        """Pages a slot owns in each ring layer's pool."""
+        return -(-self.ring_rows // page_size)
+
+    def ring_table(self, slots: int, page_size: int):
+        """The static table of the ring pools, ``(slots, ring_pages)``
+        int32 (numpy): slot ``s`` owns pages ``1 + s * ring_pages + j``."""
+        import numpy as np
+
+        rp = self.ring_pages(page_size)
+        return (1 + np.arange(slots * rp, dtype=np.int32)).reshape(slots, rp)
+
+    def _pools(self):
+        """Every pool as ``(layer, kind, row, n, window)``, ``n`` the
+        layer's index among the layers that keep ``kind`` (the layer
+        itself where all are alike)."""
+        seen: Dict[str, int] = {}
+        for i, lc in enumerate(self.layers):
+            for kind, row in lc.rows:
+                n = seen.get(kind, 0)
+                seen[kind] = n + 1
+                yield i, kind, row, n, lc.window
 
     @property
     def head_dim(self) -> Optional[int]:
         """What splits a stored kv row into heads; a latent row has none."""
         return self.rows[0][1][-1] if self.kind == "kv" else None
+
+    def _walked(self) -> Tuple[Tuple[int, ...], Optional[int]]:
+        """``(row, rank)`` of the pool the decode step's kernel walks."""
+        if self.walk is None:
+            return self.rows[0][1], self.rank
+        kind, rank = self.walk
+        return next(row for lc in self.layers for k, row in lc.rows
+                    if k == kind), rank
 
     def resolve_impl(self, impl: Optional[str], slots: int, n_pages: int,
                      page_size: int, dtype: Any) -> str:
@@ -547,10 +659,10 @@ class CacheSpec:
         geometry cannot honour raises."""
         from ..ops.attention import resolve_mla_paged_impl, resolve_paged_impl
 
-        row = self.rows[0][1]
+        row, rank = self._walked()
         if self.kind == "latent":
             return resolve_mla_paged_impl(
-                impl, page_size, row[0], self.rank, dtype)
+                impl, page_size, row[0], rank, dtype)
         n_kv, hd = row
         return resolve_paged_impl(
             impl, (slots, self.q_heads or n_kv, 1, hd),
@@ -562,41 +674,62 @@ class CacheSpec:
         cache's pools (``page_size *`` this is the rows of a block)."""
         from ..ops.attention import latent_block_pages, paged_block_pages
 
-        row = self.rows[0][1]
+        row, _ = self._walked()
         if self.kind == "latent":
             return latent_block_pages(page_size, pages_per_seq, row[0], dtype)
         return paged_block_pages(page_size, pages_per_seq, *row, dtype)
 
     @property
     def row_elems(self) -> int:
-        """Values one token occupies in one layer, all pools."""
+        """Values one token occupies in one layer, all pools (layers all
+        alike)."""
         return sum(math.prod(r) for _, r in self.rows)
 
-    def init_pools(self, n_pages: int, page_size: int,
-                   dtype: Any) -> Dict[str, jax.Array]:
-        """Zeroed pools keyed ``cache_{kind}_{i}``, in the stored form."""
+    @property
+    def paged_row_elems(self) -> int:
+        """Values one token occupies in the shared pages, all layers: a
+        ring layer holds none there."""
+        return sum(math.prod(row) for _, _, row, _, window in self._pools()
+                   if window is None)
+
+    def init_pools(self, n_pages: int, page_size: int, dtype: Any,
+                   slots: Optional[int] = None) -> Dict[str, jax.Array]:
+        """Zeroed pools keyed ``cache_{kind}_{i}``, in the stored form;
+        a ring layer's holds ``slots`` rings and the trash page."""
+        if self.has_rings and slots is None:
+            raise ValueError("a spec with ring layers sizes its ring pools "
+                             "by the engine's slots")
+        ring = 1 + (slots or 0) * self.ring_pages(page_size)
         return {
             f"cache_{kind}_{i}": jnp.zeros(
-                (n_pages, page_size, math.prod(row)), dtype)
-            for i in range(self.n_layers) for kind, row in self.rows
+                (n_pages if window is None else ring, page_size,
+                 math.prod(row)), dtype)
+            for i, kind, row, _, window in self._pools()
         }
 
     def init_slabs(self, batch: int, cap: int,
                    dtype: Any) -> Dict[str, jax.Array]:
         """Zeroed dense per-layer slabs keyed ``cache_{kind}_{i}``: what
         the dense decode-step DAG places (one layer of
-        :meth:`init_dense` each)."""
+        :meth:`init_dense` each; layers all alike)."""
         return {
             f"cache_{kind}_{i}": jnp.zeros(
                 self._dense(kind, (batch, cap, *row)), dtype)
             for i in range(self.n_layers) for kind, row in self.rows
         }
 
-    def init_dense(self, batch: int, cap: int, dtype: Any) -> Dict[str, Any]:
-        """The family's zeroed dense cache ``{kind: (L, b, ...)}``."""
-        return {kind: jnp.zeros(
-            (self.n_layers, *self._dense(kind, (batch, cap, *row))), dtype)
-            for kind, row in self.rows}
+    def init_dense(self, batch: int, cap: int, dtype: Any,
+                   page_size: Optional[int] = None) -> Dict[str, Any]:
+        """The family's zeroed dense cache ``{kind: (L, b, ...)}``; per
+        layer, ``L`` counts the layers that keep the kind and a ring
+        kind's ``cap`` is the ring (whole pages of ``page_size``)."""
+        ring = self.ring_pages(page_size or 1) * (page_size or 1)
+        shapes: Dict[str, Any] = {}
+        for _, kind, row, n, window in self._pools():
+            shapes[kind] = (n + 1, *self._dense(
+                kind, (batch, cap if window is None else ring, *row)))
+        return {kind: jnp.zeros(shape, dtype)
+                for kind, shape in shapes.items()}
 
     def _dense(self, kind: str, shape):
         if self.kind == "kv":      # (b, cap, Hkv, hd) -> (b, Hkv, cap, hd)
@@ -610,36 +743,43 @@ class CacheSpec:
         return dense_layer
 
     def gather(self, cache: Dict[str, Any], pools: Dict[str, Any],
-               pages: jax.Array, batch: int, n_rows: int) -> Dict[str, Any]:
+               pages: jax.Array, batch: int, n_rows: int,
+               ring: Optional[jax.Array] = None) -> Dict[str, Any]:
         """``cache`` with rows ``[0, n_rows)`` of every layer filled from
-        the pools through ``pages`` (flat physical ids, ``batch`` runs)."""
+        the pools through ``pages`` (flat physical ids, ``batch`` runs);
+        a ring layer's whole ring through ``ring`` (the slots' rows of
+        :meth:`ring_table`, flat)."""
         out = dict(cache)
-        for i in range(self.n_layers):
-            for kind, row in self.rows:
-                rows = jnp.take(pools[f"cache_{kind}_{i}"], pages, axis=0)
-                rows = self._from_rows(rows.reshape(batch, n_rows, *row))
-                at = ((i, slice(None), slice(None), slice(0, n_rows))
-                      if self.kind == "kv" else
-                      (i, slice(None), slice(0, n_rows)))
-                out[kind] = out[kind].at[at].set(rows.astype(out[kind].dtype))
+        for i, kind, row, n, window in self._pools():
+            take, rows_n = pages, n_rows
+            if window is not None:
+                take, rows_n = ring, out[kind].shape[2]
+            rows = jnp.take(pools[f"cache_{kind}_{i}"], take, axis=0)
+            rows = self._from_rows(rows.reshape(batch, rows_n, *row))
+            at = ((n, slice(None), slice(None), slice(0, rows_n))
+                  if self.kind == "kv" else
+                  (n, slice(None), slice(0, rows_n)))
+            out[kind] = out[kind].at[at].set(rows.astype(out[kind].dtype))
         return out
 
     def _from_rows(self, rows: jax.Array) -> jax.Array:
         return rows.transpose(0, 2, 1, 3) if self.kind == "kv" else rows
 
     def scatter(self, pools: Dict[str, Any], cache: Dict[str, Any],
-                pages: jax.Array, page_size: int) -> Dict[str, Any]:
+                pages: jax.Array, page_size: int,
+                ring: Optional[jax.Array] = None) -> Dict[str, Any]:
         """``pools`` with every page in ``pages`` (flat physical ids,
         covering each sequence's whole capacity) rewritten from the dense
-        ``cache``; out-of-range ids are dropped."""
+        ``cache``, a ring layer's through ``ring``; out-of-range ids are
+        dropped."""
         new = dict(pools)
-        for i in range(self.n_layers):
-            for kind, row in self.rows:
-                rows = self.to_rows(cache[kind][i])
-                paged = rows.reshape(pages.shape[0], page_size, -1)
-                pool = new[f"cache_{kind}_{i}"]
-                new[f"cache_{kind}_{i}"] = pool.at[pages].set(
-                    paged.astype(pool.dtype), mode="drop")
+        for i, kind, _, n, window in self._pools():
+            into = pages if window is None else ring
+            rows = self.to_rows(cache[kind][n])
+            paged = rows.reshape(into.shape[0], page_size, -1)
+            pool = new[f"cache_{kind}_{i}"]
+            new[f"cache_{kind}_{i}"] = pool.at[into].set(
+                paged.astype(pool.dtype), mode="drop")
         return new
 
 
@@ -658,8 +798,9 @@ def init_paged_kv(
     head_dim)`` (:class:`CacheSpec`): pages lead, so assembling a
     sequence is one gather on axis 0."""
     row = (n_kv_heads, head_dim)
-    return CacheSpec("kv", n_layers, (("k", row), ("v", row))).init_pools(
-        n_pages, page_size, dtype)
+    return CacheSpec.uniform(
+        "kv", n_layers, (("k", row), ("v", row))).init_pools(
+            n_pages, page_size, dtype)
 
 
 def page_table_array(
@@ -801,6 +942,8 @@ __all__ = [
     "TRASH_PAGE",
     "PageOwnershipLog",
     "PagePool",
+    "CacheSpec",
+    "LayerCache",
     "pages_needed",
     "prefix_chunk_keys",
     "pool_bytes_per_layer",

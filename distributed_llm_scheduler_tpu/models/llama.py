@@ -430,8 +430,8 @@ def cache_spec(config: Any):
     from .kv_pages import CacheSpec
 
     row = (config.n_kv_heads, config.head_dim)
-    return CacheSpec("kv", config.n_layers, (("k", row), ("v", row)),
-                     q_heads=config.n_heads)
+    return CacheSpec.uniform("kv", config.n_layers, (("k", row), ("v", row)),
+                             q_heads=config.n_heads)
 
 
 def embed(p, ids, config: Any):

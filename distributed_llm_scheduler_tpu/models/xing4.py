@@ -755,9 +755,9 @@ def layer_param_names(cfg: Xing4Config, layer: int) -> Dict[str, str]:
 def cache_spec(cfg: Xing4Config):
     from .kv_pages import CacheSpec
 
-    return CacheSpec("latent", cfg.n_layers,
-                     (("c", (latent_row_width(cfg),)),),
-                     rank=cfg.kv_lora_rank)
+    return CacheSpec.uniform(
+        "latent", cfg.n_layers, (("c", (latent_row_width(cfg),)),),
+        rank=cfg.kv_lora_rank)
 
 
 def decode_embed(p, ids, lengths, cfg: Xing4Config):

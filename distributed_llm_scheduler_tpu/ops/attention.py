@@ -1141,10 +1141,11 @@ def _mla_paged_kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("rank", "has_new", "interpret")
+    jax.jit, static_argnames=("rank", "has_new", "interpret", "name")
 )
 def _mla_paged_flash(
     q, pool, page_table, lengths, new_row, *, rank, has_new, interpret,
+    name="_mla_paged_flash",
 ):
     """Absorbed MLA over the latent pool through the page table.
 
@@ -1153,7 +1154,8 @@ def _mla_paged_flash(
     (S, width) this step's ``[c | k_r]``, inserted write-then-attend at
     ``lengths[s]``.  Returns (S, H, rank): ``softmax(q . row) . c`` per
     head, W_UV still to apply.  Work follows the live pages exactly as
-    :func:`_paged_flash`'s does."""
+    :func:`_paged_flash`'s does.  ``name`` is the kernel's name in a
+    device trace (the selected-row attention runs it under its own)."""
     S, H, width = q.shape
     _, page_size, _ = pool.shape
     ppseq = page_table.shape[1]
@@ -1194,7 +1196,7 @@ def _mla_paged_flash(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((S, H, rank), pool.dtype),
         interpret=interpret,
-        name="_mla_paged_flash",
+        name=name,
     )(slot_of, block_of, fetch, lengths, q, new, *([pool] * ppb))
 
 
@@ -1242,6 +1244,313 @@ def mla_paged_decode_attention(
             interpret=impl == "pallas_interpret",
         )
     return _mla_gather_attention(q, pool, page_table, lengths, new_row, rank)
+
+
+# -- sparse selection over a latent page pool, and a window over a ring ------
+#
+# DeepSeek-V3.2's "DSA" as the dots3 family runs it in a decode step: a
+# light indexer scores every cached row of a slot, the step keeps the
+# ``top_k`` best rows exactly, and absorbed MLA reads those rows alone
+# through the page table.  Three device ops with stable names:
+# ``_dsa_index`` (a kernel on :func:`_live_block_tables`' grid over the
+# indexer-key pool), the exact selection (``lax.top_k`` and the gather of
+# the picked rows, XLA), and ``_dsa_sparse_attn`` (:func:`_mla_paged_flash`
+# over the gathered rows).  ``_swa_latent_attn`` is absorbed MLA over a
+# slot-owned RING of pages with a lower bound on the rows it may see.
+
+
+def _dsa_index_kernel(
+    slot_ref, block_ref, fetch_ref, len_ref, q_ref, w_ref, *refs,
+    page_size, pages_per_seq, pages_per_block,
+):
+    """One live (slot, page block): ``I[r] = sum_j w[j] relu(q[j] . k[r])``
+    for the block's rows, float32 throughout (a rounding flip at the
+    selection's boundary swaps a whole row of attention)."""
+    del fetch_ref
+    ppb = pages_per_block
+    k_refs, o_ref = refs[:ppb], refs[ppb]
+    t = pl.program_id(0)
+    L = len_ref[slot_ref[t]]
+    last_page = jnp.minimum(L, pages_per_seq * page_size - 1) // page_size
+    j = block_ref[t]
+
+    def score(i):
+        keys = k_refs[i][0].astype(jnp.float32)           # (ps, Di)
+        s = jax.lax.dot_general(
+            q_ref[0], keys, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32,
+            precision=jax.lax.Precision.HIGHEST)          # (Hi, ps)
+        o_ref[0, :, i * page_size:(i + 1) * page_size] = (
+            jnp.maximum(s, 0.0) * w_ref[0]).sum(axis=0, keepdims=True)
+
+    for i in range(ppb):
+        pl.when(j * ppb + i <= last_page)(functools.partial(score, i))
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def _dsa_index(q, w, pool, page_table, lengths, *, interpret):
+    """Index scores of every live row of every slot: ``q`` (S, Hi, Di)
+    float32, ``w`` (S, Hi) float32, ``pool`` (P, page_size, Di) the cached
+    indexer keys.  Returns (S, capacity) float32; rows past a slot's last
+    live page hold whatever was there (the caller masks by position)."""
+    S, Hi, Di = q.shape
+    _, ps, _ = pool.shape
+    ppseq = page_table.shape[1]
+    ppb = latent_block_pages(ps, ppseq, Di, pool.dtype)
+    nblk = -(-ppseq // ppb)
+    slot_of, block_of, fetch, lengths, n_live = _live_block_tables(
+        page_table, lengths, ps, ppb)
+
+    def page_spec(i):
+        return pl.BlockSpec(
+            (1, ps, Di),
+            lambda t, slot, blk, fetch, ln: (fetch[t * ppb + i], 0, 0))
+
+    def slot_spec(rows, cols):
+        return pl.BlockSpec(
+            (1, rows, cols), lambda t, slot, blk, fetch, ln: (slot[t], 0, 0))
+
+    out = pl.pallas_call(
+        functools.partial(
+            _dsa_index_kernel, page_size=ps, pages_per_seq=ppseq,
+            pages_per_block=ppb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=4,
+            grid=(n_live,),
+            in_specs=[slot_spec(Hi, Di), slot_spec(Hi, 1)]
+            + [page_spec(i) for i in range(ppb)],
+            out_specs=pl.BlockSpec(
+                (1, 1, ppb * ps),
+                lambda t, slot, blk, fetch, ln: (slot[t], 0, blk[t])),
+        ),
+        out_shape=jax.ShapeDtypeStruct((S, 1, nblk * ppb * ps), jnp.float32),
+        interpret=interpret,
+        name="_dsa_index",
+    )(slot_of, block_of, fetch, lengths, q.astype(jnp.float32),
+      w.astype(jnp.float32)[:, :, None], *([pool] * ppb))
+    return out[:, 0, :ppseq * ps]
+
+
+def index_scores(q, w, keys):
+    """``I[b, t, m] = sum_j w[b, t, j] relu(q[b, t, j] . keys[b, m])`` in
+    float32: the indexer's score, the plain form (``q`` (B, T, Hi, Di),
+    ``w`` (B, T, Hi), ``keys`` (B, M, Di))."""
+    s = jnp.einsum("bthd,bmd->bthm", q.astype(jnp.float32),
+                   keys.astype(jnp.float32),
+                   precision=jax.lax.Precision.HIGHEST)
+    return (jnp.maximum(s, 0.0)
+            * w.astype(jnp.float32)[..., None]).sum(axis=2)
+
+
+def dsa_index_scores(q, w, pool, page_table, lengths, new_key, impl=None):
+    """The indexer's scores of one decode step, (S, capacity) float32:
+    slot ``s`` scores the cached keys of its pages and, at position
+    ``lengths[s]``, ``new_key`` (S, Di) — this step's, not yet written;
+    every later position reads ``-inf``."""
+    S, _, Di = q.shape
+    ps = pool.shape[1]
+    cap = page_table.shape[1] * ps
+    impl = resolve_attention_impl(
+        impl, lambda i: i == "pallas_interpret" or (
+            ps % _sublane_rows(pool.dtype) == 0 and Di % 128 == 0))
+    if impl == "xla":
+        keys = jnp.take(pool, page_table, axis=0).reshape(S, cap, Di)
+        scores = index_scores(q[:, None], w[:, None], keys)[:, 0]
+    else:
+        scores = _dsa_index(q, w, pool, page_table, lengths,
+                            interpret=impl == "pallas_interpret")
+    mine = index_scores(q[:, None], w[:, None], new_key[:, None])[:, 0]
+    pos = jnp.arange(cap, dtype=jnp.int32)[None, :]
+    at = jnp.minimum(lengths, cap - 1)[:, None]
+    return jnp.where(pos < at, scores,
+                     jnp.where(pos == at, mine, -jnp.inf))
+
+
+def dsa_select(scores, lengths, top_k: int):
+    """The exact selection: the ``min(L + 1, top_k)`` positions of each
+    slot with the largest score (``scores`` holds ``-inf`` past ``L``).
+    Returns ``(idx (S, k) int32, n (S,) int32)``: the first ``n[s]``
+    entries of ``idx[s]`` are the picked positions, best first."""
+    k = min(int(top_k), scores.shape[1])
+    _, idx = jax.lax.top_k(scores, k)
+    return idx.astype(jnp.int32), jnp.minimum(lengths.astype(jnp.int32) + 1, k)
+
+
+def dsa_sparse_attention(q, pool, page_table, idx, n, lengths, new_row, rank,
+                         impl=None):
+    """Absorbed MLA over the selected rows alone: slot ``s`` attends rows
+    ``idx[s, :n[s]]`` of its pages (position ``lengths[s]`` is
+    ``new_row[s]``, this step's).  The picked rows are gathered through
+    the page table — ``k`` rows a slot, whatever the context — and
+    :func:`_mla_paged_flash` runs over them as ``_dsa_sparse_attn``.
+    ``q`` (S, H, width) as :func:`mla_paged_decode_attention`'s; returns
+    (S, H, rank)."""
+    S, k = idx.shape
+    P, ps, width = pool.shape
+    page = jnp.take_along_axis(page_table, idx // ps, axis=1)
+    rows = jnp.take(pool.reshape(P * ps, width), page * ps + idx % ps, axis=0)
+    rows = jnp.where((idx == lengths[:, None])[:, :, None],
+                     new_row.astype(pool.dtype)[:, None, :], rows)
+    impl = resolve_attention_impl(
+        impl, lambda i: k % ps == 0 and (
+            i == "pallas_interpret" or not mla_kernel_constraints(
+                ps, width, rank, pool.dtype)))
+    if impl == "xla":
+        valid = jnp.arange(k)[None, :] < n[:, None]
+        s = jnp.einsum("shw,smw->shm", q.astype(rows.dtype), rows,
+                       preferred_element_type=jnp.float32)
+        p = jax.nn.softmax(jnp.where(valid[:, None, :], s, _NEG_INF), axis=-1)
+        return jnp.einsum(
+            "shm,smc->shc", p.astype(rows.dtype), rows[..., :rank],
+            preferred_element_type=jnp.float32).astype(pool.dtype)
+    table = jnp.arange(S * (k // ps), dtype=jnp.int32).reshape(S, k // ps)
+    return _mla_paged_flash(
+        q, rows.reshape(S * (k // ps), ps, width), table, n - 1, None,
+        rank=rank, has_new=False, interpret=impl == "pallas_interpret",
+        name="_dsa_sparse_attn")
+
+
+def _ring_valid(r, L, ring: int, window: int):
+    """Which ring rows ``r`` a query at position ``L`` may see: the row
+    of position ``p`` is ``p mod ring``, the query's own is ``L mod
+    ring``, and ``d`` rows back lies position ``L - d``."""
+    d = L % ring - r
+    d = jnp.where(d < 0, d + ring, d)
+    return jnp.logical_and(d < window, d <= L), d == 0
+
+
+def _swa_kernel(len_ref, q_ref, new_ref, c_ref, o_ref, acc_ref, m_ref, l_ref,
+                *, page_size, ring_pages, window, rank):
+    """One (slot, ring page): the online-softmax carry of
+    :func:`_mla_paged_kernel` over the slot's own ring, a row masked
+    unless its position lies in the window (rows the ring still holds
+    from before it, and across a wrap, are not seen)."""
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    L = len_ref[pl.program_id(0)]
+    ring = ring_pages * page_size
+    col = jax.lax.broadcasted_iota(
+        jnp.int32, (page_size, 1), 0) + j * page_size
+    ok_col, own_col = _ring_valid(col, L, ring, window)
+    rows = jnp.where(own_col, new_ref[0], c_ref[0])
+    rows = jnp.where(ok_col, rows, jnp.zeros_like(rows))
+    q = q_ref[0]
+    s = jax.lax.dot_general(
+        q, rows, (((1,), (1,)), ((), ())),
+        preferred_element_type=jnp.float32)               # (H, page_size)
+    r = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1) + j * page_size
+    ok, _ = _ring_valid(r, L, ring, window)
+    s = jnp.where(ok, s, _NEG_INF)
+    m_prev = m_ref[...]
+    m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.where(ok, jnp.exp(s - m_new), 0.0)   # a page may hold no row
+    l_ref[...] = l_ref[...] * alpha + p.sum(axis=1, keepdims=True)
+    acc_ref[...] = acc_ref[...] * alpha + jax.lax.dot_general(
+        p.astype(rows.dtype), rows[:, :rank], (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32)
+    m_ref[...] = m_new
+
+    @pl.when(j == ring_pages - 1)
+    def _finalize():
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+@functools.partial(jax.jit, static_argnames=("rank", "window", "interpret"))
+def _swa_latent_attn(q, pool, lengths, new_row, *, rank, window, interpret):
+    S, H, width = q.shape
+    n, ps, _ = pool.shape
+    rp = (n - 1) // S
+    return pl.pallas_call(
+        functools.partial(_swa_kernel, page_size=ps, ring_pages=rp,
+                          window=window, rank=rank),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(S, rp),
+            in_specs=[
+                pl.BlockSpec((1, H, width), lambda s, j, ln: (s, 0, 0)),
+                pl.BlockSpec((1, 1, width), lambda s, j, ln: (s, 0, 0)),
+                pl.BlockSpec((1, ps, width),
+                             lambda s, j, ln: (1 + s * rp + j, 0, 0)),
+            ],
+            out_specs=pl.BlockSpec((1, H, rank), lambda s, j, ln: (s, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((H, rank), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+                pltpu.VMEM((H, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((S, H, rank), pool.dtype),
+        interpret=interpret,
+        name="_swa_latent_attn",
+    )(lengths.astype(jnp.int32), q.astype(pool.dtype),
+      new_row.astype(pool.dtype).reshape(S, 1, width), pool)
+
+
+def latent_window_attention(q, pool, lengths, new_row, rank, window,
+                            impl=None):
+    """Single-token absorbed MLA over a ring pool (:class:`...models.
+    kv_pages.CacheSpec`, ring layers): ``pool`` (1 + S * ring_pages,
+    page_size, width), slot ``s`` owning pages ``1 + s * ring_pages + j``
+    and position ``p`` lying in ring row ``p mod (ring_pages *
+    page_size)``.  The query at position ``lengths[s]`` attends positions
+    ``lengths[s] - window < p <= lengths[s]``, its own row ``new_row[s]``
+    (not yet written).  ``q`` and the result as
+    :func:`mla_paged_decode_attention`'s."""
+    S, H, width = q.shape
+    n, ps, _ = pool.shape
+    ring = (n - 1) // S * ps
+    impl = resolve_mla_paged_impl(impl, ps, width, rank, pool.dtype)
+    if impl != "xla":
+        return _swa_latent_attn(
+            q, pool, lengths, new_row, rank=rank, window=window,
+            interpret=impl == "pallas_interpret")
+    rows = pool[1:].reshape(S, ring, width)
+    ok, own = _ring_valid(jnp.arange(ring, dtype=jnp.int32)[None, :],
+                          lengths.astype(jnp.int32)[:, None], ring, window)
+    rows = jnp.where(own[:, :, None],
+                     new_row.astype(rows.dtype)[:, None, :], rows)
+    rows = jnp.where(ok[:, :, None], rows, jnp.zeros_like(rows))
+    s = jnp.einsum("shw,smw->shm", q.astype(rows.dtype), rows,
+                   preferred_element_type=jnp.float32)
+    p = jax.nn.softmax(jnp.where(ok[:, None, :], s, _NEG_INF), axis=-1)
+    return jnp.einsum("shm,smc->shc", p.astype(rows.dtype), rows[..., :rank],
+                      preferred_element_type=jnp.float32).astype(pool.dtype)
+
+
+def kth_largest_mask(scores, allowed, k: int):
+    """``allowed`` and among the ``k`` largest allowed ``scores`` of each
+    row (last axis): the exact selection as a mask, for many queries at
+    once.  The ``k``-th largest value is found by bisection on the bits
+    of an order-preserving integer key, 32 counting passes and no sort;
+    a row with no more than ``k`` allowed entries keeps them all.  Of
+    entries tied at the ``k``-th value the earliest are kept, as
+    ``lax.top_k`` keeps them (the relu makes exact zeros)."""
+    bits = jax.lax.bitcast_convert_type(
+        scores.astype(jnp.float32), jnp.int32)
+    key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    key = jax.lax.bitcast_convert_type(key, jnp.uint32) ^ jnp.uint32(1 << 31)
+    key = jnp.where(allowed, key, jnp.uint32(0))
+
+    def body(b, t):
+        cand = t | (jnp.uint32(1) << (jnp.uint32(31) - b.astype(jnp.uint32)))
+        enough = (key >= cand[..., None]).sum(-1, dtype=jnp.int32) >= k
+        return jnp.where(enough, cand, t)
+
+    thr = jax.lax.fori_loop(
+        0, 32, body, jnp.zeros(scores.shape[:-1], jnp.uint32))
+    above = jnp.logical_and(allowed, key > thr[..., None])
+    tied = jnp.logical_and(allowed, key == thr[..., None])
+    room = k - above.sum(-1, dtype=jnp.int32)
+    return jnp.logical_or(above, jnp.logical_and(
+        tied, jnp.cumsum(tied, axis=-1, dtype=jnp.int32) <= room[..., None]))
+
 
 
 def gqa_mha(

@@ -558,7 +558,7 @@ def test_aot_xl_pools_are_read_where_they_lie(v5e, n_pages):
     from distributed_llm_scheduler_tpu.ops import attention as A
 
     S, ps, ppseq, H, hd, layers = 32, 16, 64, 25, 64, 2
-    spec = CacheSpec("kv", layers, (("k", (H, hd)), ("v", (H, hd))))
+    spec = CacheSpec.uniform("kv", layers, (("k", (H, hd)), ("v", (H, hd))))
     pools = {k: v5e(v.shape, v.dtype) for k, v in jax.eval_shape(
         lambda: spec.init_pools(n_pages, ps, jnp.bfloat16)).items()}
     pool_shape = (n_pages, ps, H * hd)

@@ -82,7 +82,7 @@ def layer_param_names(cfg, layer):
 
 def cache_spec(cfg):
     row = (cfg.heads, cfg.head_dim)
-    return CacheSpec("kv", cfg.depth, (("k", row), ("v", row)))
+    return CacheSpec.uniform("kv", cfg.depth, (("k", row), ("v", row)))
 
 
 def _qkv(p, x, cfg):
@@ -164,8 +164,8 @@ def _tiny(family: str):
 # -- the contract -----------------------------------------------------------------
 
 
-def test_the_toy_is_registered_beside_the_four():
-    assert FAMILIES == ["gpt2", "llama", "mixtral", "toy", "xing4"]
+def test_the_toy_is_registered_beside_the_five():
+    assert FAMILIES == ["dots3", "gpt2", "llama", "mixtral", "toy", "xing4"]
 
 
 @pytest.mark.parametrize("family", FAMILIES)
@@ -203,7 +203,8 @@ def test_family_contract(family):
     if paged:
         dag = build_paged_decode_dag(
             cfg, slots=2, page_size=8, n_pages=5, pages_per_seq=2)
-        assert cache_of(dag) == shapes_of(spec.init_pools(5, 8, cfg.dtype))
+        assert cache_of(dag) == shapes_of(
+            spec.init_pools(5, 8, cfg.dtype, slots=2))
         assert dag.graph.name.startswith(f"{family}paged_{spec.n_layers}l_")
         assert ("active" in dag.input_spec) == getattr(
             mod, "DECODE_TAKES_LIVE", False)
@@ -237,7 +238,7 @@ def test_family_is_decided_by_type_not_by_class_name():
     ("gpt2", "gpt2"), ("gpt2-medium", "gpt2"), ("gpt2-tiny", "gpt2"),
     ("llama", "llama"), ("llama-8b", "llama"), ("llama-tiny", "llama"),
     ("mixtral-8x7b", "mixtral"), ("mixtral-tiny", "mixtral"),
-    ("xing4-tiny", "xing4"), ("toy-tiny", "toy")])
+    ("xing4-tiny", "xing4"), ("dots3-tiny", "dots3"), ("toy-tiny", "toy")])
 def test_variant_names_make_their_familys_config(model, family):
     assert models.family_of_model(model).name == family
     assert models.family_of(models.model_config(model)) == family
@@ -271,7 +272,7 @@ def test_what_each_family_offers():
               if models.offers(rows[f], *models.PAGED_FUNCTIONS)}
     dense = {f for f in rows
              if models.offers(rows[f], *models.CACHED_FUNCTIONS)}
-    assert served == {"gpt2", "xing4", "toy"}
+    assert served == {"gpt2", "xing4", "dots3", "toy"}
     assert dense == {"gpt2", "llama", "mixtral"}
 
 
