@@ -7,9 +7,10 @@ DAG that Python bookkeeping is most of the 21.9 ms host dispatch overhead
 (BENCH_r05.json) — work whose inputs (graph, schedule, placed params) are
 all fixed before the first launch.  This module moves it to plan time:
 
-* **Immutable plan** (:class:`DispatchPlan`): built once per ``execute``
-  from the frozen graph, the schedule's dispatch linearization, and the
-  placed params.  Each step carries its resolved jitted executable, a
+* **Immutable plan** (:class:`DispatchPlan`): built from the frozen
+  graph, the schedule's dispatch linearization, and the placed params,
+  and kept on the backend with them (:class:`PreparedCall`) for as long
+  as ``execute`` is called with what it was built from.  Each step carries its resolved jitted executable, a
   prebuilt param binding dict, and integer indices into a flat value
   table — the hot loop does list indexing and calls, nothing else.
 * **Batched staging**: all of a step's cross-core inputs go up in ONE
@@ -412,6 +413,140 @@ def _relinearize(graph, schedule, alive: List[str], done: set) -> List[str]:
     return out
 
 
+def _stable_leaves(value: Any) -> Optional[Tuple[Any, ...]]:
+    """The leaves of one parameter if every one is a live ``jax.Array``
+    (immutable: what was put stays what the caller holds), else None (a
+    host ``numpy`` array can be written in place)."""
+    leaves = (
+        (value,) if isinstance(value, jax.Array)
+        else tuple(jax.tree_util.tree_leaves(value))
+    )
+    for leaf in leaves:
+        if (
+            not isinstance(leaf, jax.Array)
+            or isinstance(leaf, jax.core.Tracer)
+            or leaf.is_deleted()
+        ):
+            return None
+    return leaves or None
+
+
+def call_avals(graph_input: Any, ext_outputs: Optional[Dict[str, Any]]):
+    """Hashable shapes and dtypes of what a call feeds the plan from
+    outside (the graph input, the ext values by key): every launch's and
+    transfer's shape follows from them, the graph and the parameters."""
+    leaves, treedef = jax.tree_util.tree_flatten((graph_input, ext_outputs))
+    return treedef, tuple(jax.typeof(leaf) for leaf in leaves)
+
+
+class PreparedCall:
+    """What ``DeviceBackend.execute()`` derives from its arguments alone,
+    kept on the backend between calls (one per live graph, the latest).
+
+    *Structure* — ``order``, ``plan``, ``graph_params``, ``gate_passed`` —
+    is valid for ``key``: the graph (by identity and ``version``), the
+    schedule's ``signature()``, the ext keys, the flags, the cluster's
+    devices and the call's input avals.  *Placement* — ``placed``,
+    ``avals``, ``bytes_per_node`` — is valid name by name while the
+    caller's ``params[name]`` IS the array that was put (``sources`` keeps
+    those arrays alive, so an ``id`` cannot come back as another array);
+    a name with a host array has no source and is put again every call.
+    Holds no reference to the graph: a dead graph releases all of it.
+    """
+
+    __slots__ = (
+        "key", "order", "plan", "graph_params", "gate_passed", "pairs_of",
+        "sources", "avals", "placed", "bytes_per_node",
+    )
+
+    def __init__(
+        self, key: Any, graph, schedule, order: List[str],
+        graph_params: frozenset,
+    ):
+        self.key = key
+        self.order = order
+        self.plan: Optional[DispatchPlan] = None
+        self.graph_params = graph_params    # graph.unique_params()
+        self.gate_passed = False
+        # param -> the (param, node_id) pairs the schedule needs
+        pairs_of: Dict[str, Dict[Tuple[str, str], None]] = {}
+        for tid, node_id in schedule.placement.items():
+            for p in graph[tid].params_needed:
+                pairs_of.setdefault(p, {})[(p, node_id)] = None
+        self.pairs_of = {p: tuple(ps) for p, ps in pairs_of.items()}
+        self.sources: Dict[str, Tuple[Any, ...]] = {}
+        self.avals: Dict[str, Any] = {}     # param -> pytree of avals put
+        self.placed: Dict[Tuple[str, str], Any] = {}
+        self.bytes_per_node: Dict[str, int] = {}
+
+    def stale_names(self, params: Dict[str, Any]) -> List[str]:
+        """The names whose placed replicas are not ``params``' arrays."""
+        stale = []
+        sources = self.sources
+        for name in self.pairs_of:
+            kept = sources.get(name)
+            value = params[name]
+            if kept is None:
+                stale.append(name)
+            elif len(kept) == 1 and value is kept[0]:
+                if value.is_deleted():
+                    stale.append(name)
+            else:
+                leaves = _stable_leaves(value)
+                if (
+                    leaves is None or len(leaves) != len(kept)
+                    or any(a is not b for a, b in zip(leaves, kept))
+                ):
+                    stale.append(name)
+        return stale
+
+    def place(self, params: Dict[str, Any], cluster) -> int:
+        """Bring the replicas up to the caller's ``params``: put again,
+        onto every device that needs it, each parameter whose array is
+        not the one placed (another ``jax.Array`` under the name, a
+        deleted one, a host array, a name never placed), re-bind the kept
+        plan's steps to the new replicas and leave every other replica
+        where it is.  Returns how many names were put; should a put
+        raise, the entry is half placed and the caller drops it."""
+        stale = self.stale_names(params)
+        if not stale:
+            return 0
+        pairs = [pair for name in stale for pair in self.pairs_of[name]]
+        # the replicas these replace go first: a training loop's old
+        # weights must not sit beside the new ones on every chip
+        if self.plan is not None:
+            self.plan.rebind(dict.fromkeys(pairs))
+        for pair in pairs:
+            self.placed.pop(pair, None)
+        reshaped = False
+        for name in stale:
+            self.sources.pop(name, None)
+            avals = jax.tree_util.tree_map(jax.typeof, params[name])
+            reshaped |= self.avals.get(name, avals) != avals
+            self.avals[name] = avals
+        fresh = {
+            (name, node_id): jax.device_put(
+                params[name], cluster[node_id].jax_device
+            )
+            for name, node_id in pairs
+        }
+        # placed values may be pytrees (QParam int8+scale pairs)
+        jax.block_until_ready(list(fresh.values()))
+        self.placed.update(fresh)
+        for name in stale:
+            leaves = _stable_leaves(params[name])
+            if leaves is not None:
+                self.sources[name] = leaves
+        self.bytes_per_node = {d.node_id: 0 for d in cluster}
+        for name, name_pairs in self.pairs_of.items():
+            nbytes = _array_bytes(self.avals[name])
+            for _p, node_id in name_pairs:
+                self.bytes_per_node[node_id] += nbytes
+        if self.plan is not None:
+            self.plan.rebind(fresh, reshaped=reshaped)
+        return len(stale)
+
+
 class PlanStep:
     """One launch: a single task or a coalesced same-device group."""
 
@@ -420,7 +555,8 @@ class PlanStep:
         "node_id",
         "dev",           # jax device the launch runs on
         "fn",            # resolved jitted callable (donating variant baked in)
-        "pd",            # prebuilt param binding dict (immutable across runs)
+        "pd",            # prebuilt param binding dict; its values change only
+                         # through DispatchPlan.rebind (a parameter re-placed)
         "arg_slots",     # value-table indices of the launch args, in order
         "get_args",      # itemgetter over arg_slots (C-speed gather)
         "xfer_slots",    # unique slots needing device_put onto `dev`
@@ -444,7 +580,8 @@ class PlanStep:
 
 
 class DispatchPlan:
-    """Immutable dispatch program for one (graph, schedule, ext) triple.
+    """Immutable dispatch program for one (graph, schedule, ext) triple
+    (only :meth:`rebind` writes to it: other weights, the same program).
 
     Built by :meth:`build`; executed by :meth:`run`.  The value table is a
     flat list: slots 0..len(ext)-1 hold external outputs, then one slot per
@@ -465,6 +602,7 @@ class DispatchPlan:
         transfer_edges: int,
         donate: bool,
         coalesce: bool,
+        param_binds: Dict[Tuple[str, str], List[Tuple[Dict[str, Any], str]]],
     ):
         self._backend = backend
         self.steps = steps
@@ -477,6 +615,9 @@ class DispatchPlan:
         self.transfer_edges = transfer_edges
         self.donate = donate
         self.coalesce = coalesce
+        # (param, node_id) -> every (step binding dict, local name) that
+        # holds its placed array: what rebind() writes through
+        self.param_binds = param_binds
 
     # -- construction ------------------------------------------------------
     @classmethod
@@ -620,10 +761,20 @@ class DispatchPlan:
         }
         protected = {final_slot} | {s for _, s in fence_slots}
 
+        param_binds: Dict[
+            Tuple[str, str], List[Tuple[Dict[str, Any], str]]
+        ] = {}
+
+        def bind(tid: str, node: str) -> Dict[str, Any]:
+            pd: Dict[str, Any] = {}
+            for loc, glob in graph[tid].param_items():
+                pd[loc] = placed_params[(glob, node)]
+                param_binds.setdefault((glob, node), []).append((pd, loc))
+            return pd
+
         steps: List[PlanStep] = []
         transfer_edges = 0
         for gi, g in enumerate(groups):
-            lead = graph[g[0]]
             node = placement[g[0]]
             dev = backend.cluster[node].jax_device
             ext_list = ext_lists[gi]
@@ -709,21 +860,12 @@ class DispatchPlan:
                 step.out_slots = tuple(slot_of[t] for t in launch.exports)
                 step.out_tids = launch.exports
                 step.fn = backend._grouped_jitted(launch.key, donate_argnums)
-                step.pd = tuple(
-                    {
-                        loc: placed_params[(glob, node)]
-                        for loc, glob in graph[t].param_items()
-                    }
-                    for t in launch.members
-                )
+                step.pd = tuple(bind(t, node) for t in launch.members)
             else:
                 step.out_slots = (slot_of[g[0]],)
                 step.out_tids = (g[0],)
                 step.fn = backend._jitted(graph, g[0], donate_argnums)
-                step.pd = {
-                    loc: placed_params[(glob, node)]
-                    for loc, glob in lead.param_items()
-                }
+                step.pd = bind(g[0], node)
             steps.append(step)
 
         keep_list = tuple(
@@ -736,7 +878,7 @@ class DispatchPlan:
                 for n, s in sorted(input_slot.items())
             ),
             fence_slots, final_slot, keep_list, transfer_edges,
-            donate, coalesce,
+            donate, coalesce, param_binds,
         )
         # donation self-check (analysis/donation_pass): re-derives the
         # lifetime safety the builder just computed, from the exported
@@ -804,6 +946,26 @@ class DispatchPlan:
     @property
     def n_launches(self) -> int:
         return len(self.steps)
+
+    # -- parameters re-placed between runs ---------------------------------
+    def rebind(
+        self, replicas: Dict[Tuple[str, str], Any], reshaped: bool = False,
+    ) -> None:
+        """Point every step that reads a ``(param, node_id)`` of
+        ``replicas`` at the array given for it (``None``: at nothing, so
+        the old replica can go before the new one comes): what a kept
+        plan needs when the caller hands in other weights under the same
+        names.  ``reshaped``: one of them has another shape or dtype than
+        the array it replaces, so the transfer sizes and avals learnt on
+        the first run are learnt again on the next."""
+        for pair, new in replicas.items():
+            for pd, loc in self.param_binds.get(pair, ()):
+                pd[loc] = new
+        if reshaped:
+            for st in self.steps:
+                if st.xfer_map:
+                    st.xfer_avals = None
+                    st.xfer_bytes = None
 
     # -- execution ---------------------------------------------------------
     def run(

@@ -219,6 +219,9 @@ class TaskGraph:
         self._dependents: Dict[str, List[str]] = {}
         self._param_gb: Dict[str, float] = {}
         self._topo: Optional[List[str]] = None
+        # counts add_task calls: what a cache keyed by this graph's
+        # identity compares to know the graph it saw is the graph it sees
+        self.version = 0
         for t in tasks:
             self.add_task(t)
 
@@ -228,6 +231,7 @@ class TaskGraph:
             raise GraphValidationError(f"duplicate task id {task.task_id!r}")
         self._tasks[task.task_id] = task
         self._topo = None  # invalidate
+        self.version += 1
 
     def freeze(self) -> "TaskGraph":
         """Validate, compute topo order, and fix the param size table.

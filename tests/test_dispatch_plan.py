@@ -13,7 +13,11 @@ properties that make that trade safe:
 * fused launches are what ``execute()`` does by default: launches of
   one structure share ONE executable whatever their layer, a ``fn`` with
   host effects keeps the graph on per-task launches, and the transfer
-  accounting is the per-task plan's.
+  accounting is the per-task plan's;
+* what ``execute()`` derives from its arguments alone is kept on the
+  backend between calls (``PreparedCall``) and is never stale: a call
+  whose graph, schedule, flags or weights changed runs what a fresh
+  backend would run.
 """
 
 import jax
@@ -561,7 +565,7 @@ def test_leaf_phases_tile_the_call_and_land_once_in_the_process_registry(
         assert sum(leaves.values()) == pytest.approx(rep.wall_s, rel=1e-9)
         ph = rep.dispatch_phases
         assert ph["stage_s"] + ph["launch_s"] == pytest.approx(ph["loop_s"])
-        assert ph["warmup_s"] == 0.0 and ph["plan_s"] > 0
+        assert ph["warmup_s"] == 0.0 and ph["plan_s"] >= 0
         assert rep.attribution is None
         around.append(abs((b - a) - rep.wall_s) / (b - a))
     # the best of five: a preempted host thread is not the program's
@@ -593,3 +597,347 @@ def test_every_execution_path_tiles_its_call(setup, kw, split):
     assert leaves["fence_s"] > 0 and leaves["warmup_s"] > 0
     assert sum(leaves.values()) == pytest.approx(rep.wall_s, rel=1e-9)
     assert rep.summary()["wall_ms"] == pytest.approx(rep.wall_s * 1e3)
+
+
+# -- the prepared call: kept between calls, never stale ------------------
+
+
+def _prepared_counts():
+    from distributed_llm_scheduler_tpu.obs import process_metrics
+
+    counters = process_metrics().snapshot()["counters"]
+    return tuple(
+        int(counters.get(f"execute.prepared.{k}", {"value": 0})["value"])
+        for k in ("hits", "structure_misses", "placement_misses")
+    )
+
+
+def _delta(before):
+    return tuple(a - b for a, b in zip(_prepared_counts(), before))
+
+
+@pytest.fixture()
+def small():
+    """A graph, weights, backend and schedule of the test's own (nothing
+    kept by an earlier test), over four devices."""
+    dag, params, ids, backend, schedule = _deep(
+        4, "pack", n_layer=2, microbatches=2
+    )
+    return dag, params, ids, backend, schedule
+
+
+def _logits(rep):
+    return np.asarray(rep.output)
+
+
+@pytest.mark.parametrize("n_devices,policy", [(1, "heft"), (4, "pack")])
+def test_a_repeated_call_hits_and_is_bit_identical(n_devices, policy):
+    """The second call with the same graph, schedule and weights builds
+    nothing and puts nothing, and its logits are the first call's and a
+    fresh backend's bit for bit."""
+    dag, params, ids, backend, schedule = _deep(
+        n_devices, policy, n_layer=2, microbatches=2
+    )
+    c0 = _prepared_counts()
+    first = backend.execute(dag.graph, schedule, params, ids)
+    assert _delta(c0) == (0, 1, 0)
+    plan = backend._prepared[dag.graph].plan
+    placed = dict(backend._prepared[dag.graph].placed)
+    again = backend.execute(dag.graph, schedule, params, ids, warmup=False)
+    third = backend.execute(dag.graph, schedule, params, ids, reps=2)
+    assert _delta(c0) == (2, 1, 0)
+    entry = backend._prepared[dag.graph]
+    assert entry.plan is plan
+    assert all(entry.placed[k] is v for k, v in placed.items())
+    fresh = DeviceBackend(backend.cluster).execute(
+        dag.graph, schedule, params, ids
+    )
+    for rep in (again, third, fresh):
+        assert np.array_equal(_logits(first), _logits(rep))
+    assert again.n_dispatches == first.n_dispatches
+    assert again.transfer_bytes == first.transfer_bytes
+    assert again.param_bytes_placed == first.param_bytes_placed
+
+
+@pytest.mark.parametrize("changed", ["all", "one"])
+def test_new_weights_under_the_same_names_are_placed_and_run(small, changed):
+    """A training loop: other ``jax.Array``s under the same names give
+    the new weights' logits, count a placement miss and no structure
+    miss; only the names that changed are put again."""
+    dag, params, ids, backend, schedule = small
+    backend.execute(dag.graph, schedule, params, ids)
+    entry = backend._prepared[dag.graph]
+    plan, placed = entry.plan, dict(entry.placed)
+    names = list(params) if changed == "all" else [sorted(params)[0]]
+    new = dict(params)
+    for name in names:
+        new[name] = params[name] * 1.5 + 0.01
+    c0 = _prepared_counts()
+    rep = backend.execute(dag.graph, schedule, new, ids, warmup=False)
+    assert _delta(c0) == (0, 0, 1)
+    assert backend._prepared[dag.graph] is entry and entry.plan is plan
+    for (name, node), was in placed.items():
+        assert (entry.placed[(name, node)] is was) is (name not in names)
+    want = DeviceBackend(backend.cluster).execute(
+        dag.graph, schedule, new, ids
+    )
+    assert np.array_equal(_logits(rep), _logits(want))
+    c0 = _prepared_counts()
+    old = backend.execute(dag.graph, schedule, params, ids, warmup=False)
+    assert not np.array_equal(_logits(rep), _logits(old))
+    assert _delta(c0) == (0, 0, 1)
+    # and now the call's weights are the kept ones again: a hit
+    backend.execute(dag.graph, schedule, params, ids, warmup=False)
+    assert _delta(c0) == (1, 0, 1)
+
+
+@pytest.mark.parametrize("kind", ["numpy", "pytree", "deleted"])
+def test_a_parameter_that_may_have_changed_is_put_again(small, kind):
+    """A host array written in place between calls is honoured (it is
+    put every call); a pytree parameter is followed leaf by leaf; a
+    deleted array is not served from its replica."""
+    dag, params, ids, backend, schedule = small
+    name = "wte" if "wte" in params else sorted(params)[0]
+    mine = dict(params)
+    if kind == "numpy":
+        mine[name] = np.array(params[name])
+    elif kind == "deleted":
+        mine[name] = params[name] + 0.0
+    if kind == "pytree":
+        from distributed_llm_scheduler_tpu.backends.dispatch_plan import (
+            PreparedCall,
+        )
+
+        entry = PreparedCall.__new__(PreparedCall)
+        entry.pairs_of = {"w": (("w", "n0"),)}
+        pair = (params[name], params[name] + 1.0)
+        entry.sources = {"w": pair}
+        assert entry.stale_names({"w": pair}) == []
+        assert entry.stale_names({"w": list(pair)}) == []
+        assert entry.stale_names({"w": (pair[0], pair[1] + 0.0)}) == ["w"]
+        assert entry.stale_names({"w": (pair[0], np.asarray(pair[1]))}) == ["w"]
+        assert entry.stale_names({"w": pair[0]}) == ["w"]
+        return
+    first = backend.execute(dag.graph, schedule, mine, ids)
+    c0 = _prepared_counts()
+    if kind == "numpy":
+        mine[name][...] = mine[name] * 2.0 + 0.5
+        rep = backend.execute(dag.graph, schedule, mine, ids, warmup=False)
+        assert _delta(c0) == (0, 0, 1)
+        want = DeviceBackend(backend.cluster).execute(
+            dag.graph, schedule, mine, ids
+        )
+        assert np.array_equal(_logits(rep), _logits(want))
+        assert not np.array_equal(_logits(rep), _logits(first))
+        # unwritten, it is still put: a host array is never trusted
+        c0 = _prepared_counts()
+        backend.execute(dag.graph, schedule, mine, ids, warmup=False)
+        assert _delta(c0) == (0, 0, 1)
+    else:
+        mine[name].delete()
+        with pytest.raises(RuntimeError, match="deleted"):
+            backend.execute(dag.graph, schedule, mine, ids, warmup=False)
+        # half placed, the entry is not kept: the next call builds anew
+        assert dag.graph not in backend._prepared
+        mine[name] = params[name] + 0.0
+        rep = backend.execute(dag.graph, schedule, mine, ids, warmup=False)
+        assert _delta(c0) == (0, 1, 0)
+        assert np.array_equal(_logits(rep), _logits(first))
+
+
+def _mutate_in_place(schedule, cluster, graph):
+    # swap two independent neighbours of one node's list and of the
+    # global order alike: still a valid schedule, another decision
+    for tasks in schedule.per_node.values():
+        for a, b in zip(tasks, tasks[1:]):
+            if a not in graph[b].dependencies and (
+                set(graph[a].dependencies) == set(graph[b].dependencies)
+            ):
+                i, j = tasks.index(a), tasks.index(b)
+                tasks[i], tasks[j] = b, a
+                o = schedule.assignment_order
+                i, j = o.index(a), o.index(b)
+                o[i], o[j] = o[j], o[i]
+                return schedule
+    raise AssertionError("no swappable pair")
+
+
+STRUCTURE_CHANGES = {
+    "schedule_mutated_in_place": lambda s, c, g: (
+        _mutate_in_place(s, c, g), {}),
+    "another_policy": lambda s, c, g: (
+        get_scheduler("roundrobin").schedule(g, c), {}),
+    "ext_keys": lambda s, c, g: (s, {"ext_outputs": {"outside": np.ones(3)}}),
+    "keep_outputs": lambda s, c, g: (s, {"keep_outputs": True}),
+    "donate": lambda s, c, g: (s, {"donate": not donation_supported()}),
+    "coalesce": lambda s, c, g: (s, {"coalesce": True}),
+    "input_shape": lambda s, c, g: (s, {}),
+}
+
+
+@pytest.mark.parametrize("change", sorted(STRUCTURE_CHANGES))
+def test_a_changed_structure_misses_and_runs_what_a_fresh_backend_runs(
+        small, change):
+    """Whatever the plan is a function of — the schedule's decision (even
+    when the same object was written in place), the flags, the ext keys,
+    the input's shape — a change builds anew, and gives what a backend
+    that never saw the earlier call gives."""
+    dag, params, ids, backend, schedule = small
+    backend.execute(dag.graph, schedule, params, ids)
+    old_plan = backend._prepared[dag.graph].plan
+    schedule2, kw = STRUCTURE_CHANGES[change](
+        schedule, backend.cluster, dag.graph
+    )
+    if change == "input_shape":
+        ids = jax.numpy.concatenate([ids, ids], axis=0)
+    c0 = _prepared_counts()
+    rep = backend.execute(dag.graph, schedule2, params, ids, **kw)
+    assert _delta(c0) == (0, 1, 0)
+    assert backend._prepared[dag.graph].plan is not old_plan
+    want = DeviceBackend(backend.cluster).execute(
+        dag.graph, schedule2, params, ids, **kw
+    )
+    assert np.array_equal(_logits(rep), _logits(want))
+    assert rep.n_dispatches == want.n_dispatches
+    assert rep.transfer_edges == want.transfer_edges
+    assert rep.transfer_bytes == want.transfer_bytes
+    # the same arguments again: kept
+    c0 = _prepared_counts()
+    again = backend.execute(
+        dag.graph, schedule2, params, ids, warmup=False, **kw
+    )
+    assert _delta(c0) == (1, 0, 0)
+    assert np.array_equal(_logits(again), _logits(want))
+    assert again.transfer_bytes == want.transfer_bytes
+
+
+@pytest.mark.parametrize("when", ["from_the_start", "after_a_pass"])
+def test_a_schedule_that_fails_the_gate_raises_on_every_call(small, when):
+    """The gate's verdict is kept for a pass only: a failing schedule
+    raises on the first and on the second call, and a schedule written
+    into failing after it passed raises too."""
+    from distributed_llm_scheduler_tpu.analysis import AnalysisError
+
+    dag, params, ids, backend, schedule = small
+    if when == "after_a_pass":
+        backend.execute(dag.graph, schedule, params, ids)
+        assert backend._prepared[dag.graph].gate_passed
+    node = next(n for n, ts in schedule.per_node.items() if ts)
+    schedule.per_node["ghost"] = [schedule.per_node[node].pop()]
+    c0 = _prepared_counts()
+    for _ in range(2):
+        with pytest.raises(AnalysisError, match="SCH001"):
+            backend.execute(dag.graph, schedule, params, ids, warmup=False)
+    assert _delta(c0) == (0, 0, 0)
+
+
+def test_the_gate_runs_again_where_its_pass_was_not_its_own(small):
+    """A pass on the caller's ``pre_report`` is not kept, and a backend
+    whose gate was off runs it once it is on."""
+    from distributed_llm_scheduler_tpu import analysis
+
+    dag, params, ids, backend, schedule = small
+    report = analysis.analyze(dag.graph, backend.cluster, schedule)
+    backend.execute(dag.graph, schedule, params, ids, pre_report=report)
+    assert not backend._prepared[dag.graph].gate_passed
+    backend.execute(dag.graph, schedule, params, ids, warmup=False)
+    assert backend._prepared[dag.graph].gate_passed
+    backend.pre_analysis = False
+    c0 = _prepared_counts()
+    backend.execute(dag.graph, schedule, params, ids, warmup=False)
+    assert _delta(c0) == (0, 1, 0)
+    assert not backend._prepared[dag.graph].gate_passed
+
+
+@pytest.mark.parametrize("kw", [
+    {"memprof": True}, {"profile": True}, {"segments": True},
+    {"compiled": True}, {"stream_params": True}, {"planned": False},
+], ids=lambda kw: next(iter(kw)))
+def test_the_paths_that_bypass_the_prepared_call_leave_it_alone(small, kw):
+    """``memprof`` (placement is its subject) and the paths that run no
+    plan neither count nor touch the entry."""
+    from distributed_llm_scheduler_tpu.obs.memprof import MemoryProfiler
+
+    dag, params, ids, backend, schedule = small
+    ref = backend.execute(dag.graph, schedule, params, ids)
+    entry = backend._prepared[dag.graph]
+    plan, placed = entry.plan, dict(entry.placed)
+    if "memprof" in kw:
+        kw = {"memprof": MemoryProfiler()}
+    c0 = _prepared_counts()
+    rep = backend.execute(dag.graph, schedule, params, ids, **kw)
+    assert _delta(c0) == (0, 0, 0)
+    assert backend._prepared[dag.graph] is entry and entry.plan is plan
+    assert all(entry.placed[k] is v for k, v in placed.items())
+    np.testing.assert_allclose(
+        _logits(rep), _logits(ref), rtol=2e-5, atol=2e-5
+    )
+    if "memprof" in kw:
+        # placement is recorded: the profiler saw every parameter put
+        assert any(
+            ev["label"].startswith("param:") for ev in kw["memprof"].events
+        )
+
+
+def test_a_dead_graph_releases_its_entry_and_its_replicas():
+    import gc
+    import weakref
+
+    dag, params, ids, backend, schedule = _deep(
+        4, "pack", n_layer=2, microbatches=2
+    )
+    backend.execute(dag.graph, schedule, params, ids)
+    entry = backend._prepared[dag.graph]
+    # a replica on a device the caller's array does not live on
+    home = next(iter(params.values())).devices()
+    replica = next(
+        weakref.ref(v) for v in entry.placed.values()
+        if v.devices() != home
+    )
+    plan = weakref.ref(entry.plan)
+    assert len(backend._prepared) == 1
+    del entry, dag
+    gc.collect()
+    assert len(backend._prepared) == 0
+    assert plan() is None and replica() is None
+
+
+@pytest.mark.parametrize("coalesce", [None, False, True])
+def test_no_kept_replica_is_deleted_by_a_donating_run(small, coalesce):
+    if not donation_supported():
+        pytest.skip("platform ignores donate_argnums")
+    dag, params, ids, backend, schedule = small
+    first = backend.execute(
+        dag.graph, schedule, params, ids, donate=True, coalesce=coalesce
+    )
+    for _ in range(2):
+        rep = backend.execute(
+            dag.graph, schedule, params, ids, donate=True,
+            coalesce=coalesce, warmup=False,
+        )
+        entry = backend._prepared[dag.graph]
+        assert not any(v.is_deleted() for v in entry.placed.values())
+        assert not any(v.is_deleted() for v in params.values())
+        assert np.array_equal(_logits(rep), _logits(first))
+
+
+def test_the_fence_round_trip_is_probed_once_a_backend(small, monkeypatch):
+    from distributed_llm_scheduler_tpu.utils import costmodel
+
+    dag, params, ids, backend, schedule = small
+    calls = []
+    real = costmodel._fence_rtt
+
+    def counted(device, samples=5):
+        calls.append(device)
+        return real(device, samples)
+
+    monkeypatch.setattr(costmodel, "_fence_rtt", counted)
+    reps = [
+        backend.execute(dag.graph, schedule, params, ids, **kw)
+        for kw in ({}, {"warmup": False}, {"segments": True},
+                   {"fence_rtt": 0.25})
+    ]
+    assert len(calls) == 1
+    assert all(r.leaf_phases()["rtt_s"] >= 0 for r in reps)
+    assert reps[3].leaf_phases()["rtt_s"] == 0.0
