@@ -395,7 +395,7 @@ def cmd_lint(args) -> int:
         return 2
     if args.paged and not _offers(cfg.model, PAGED_FUNCTIONS):
         print("--paged lints the paged decode step: the model's family "
-              "must offer it (gpt2*, xing4*, dots3*)", file=sys.stderr)
+              "must offer it (gpt2*, xing4*, dots3*, glm4_lite*)", file=sys.stderr)
         return 2
     if args.paged:
         from .frontend.decode_dag import build_paged_decode_dag
@@ -479,7 +479,7 @@ def cmd_lint(args) -> int:
         graph_input=getattr(dag, "input_spec", None),
         chunk_tokens=getattr(args, "chunk_tokens", None),
         decode_budget=(
-            cfg.batch * args.seg_steps
+            cfg.batch * args.seg_steps * getattr(dag, "rows_per_step", 1)
             if getattr(args, "chunk_tokens", None) is not None
             else None
         ),
@@ -1404,7 +1404,7 @@ def _slo_live_requests(args, flight):
     cfg = _config_from(args)
     if not _offers(cfg.model, PAGED_FUNCTIONS):
         print("slo: live run needs a model whose family offers the paged "
-              "decode (gpt2*, xing4*, dots3*)", file=sys.stderr)
+              "decode (gpt2*, xing4*, dots3*, glm4_lite*)", file=sys.stderr)
         return 2, None
     import jax
     import jax.numpy as jnp
@@ -1573,7 +1573,7 @@ def cmd_serve(args) -> int:
     cfg = _config_from(args)
     if not _offers(cfg.model, PAGED_FUNCTIONS):
         print("serve: needs a model whose family offers the paged decode "
-              "(gpt2*, xing4*, dots3*)", file=sys.stderr)
+              "(gpt2*, xing4*, dots3*, glm4_lite*)", file=sys.stderr)
         return 2
     slots, ps, n_pages, ppseq = 4, 8, 13, 4
     too_big = [a.rid for a in arrivals

@@ -48,7 +48,12 @@ def analyze_decode(
       (scan-loop ineligible).
     * ``DEC003`` (error): inconsistent paged wiring — a task reads pools
       without the page table (or vice versa), or the per-layer pools
-      disagree on geometry.
+      disagree on geometry, or the rows a slot feeds a step
+      (``graph.rows_per_step``, stamped by the paged builder: 1, or a
+      family's ``DECODE_ROWS`` where it is stepped with its draft module)
+      and the ``draft`` task disagree: more than one row without a
+      ``draft`` sink that holds a pool of its own, or a ``draft`` task on
+      a one-row step.
     * ``DEC004`` (info): per-step KV residency payload
       (``data={"kv_bytes": ..., "paged": ...}``).
     * ``DEC005`` (warning, needs ``param_specs``): the paged pool
@@ -62,8 +67,9 @@ def analyze_decode(
       ragged multi-token-q kernel's tiling constraints
       (``paged_kernel_constraints(..., q_tokens=chunk_tokens)``), so
       every chunk wave silently runs the XLA gather path, or it exceeds
-      ``decode_budget`` (the engine's per-segment decode-token capacity
-      ``slots * seg_steps``), so a single chunk monopolizes the
+      ``decode_budget`` (the engine's per-segment decode capacity in
+      model-forward rows, ``slots * seg_steps * rows a slot a step``),
+      so a single chunk monopolizes the
       segment's prefill budget and chunking degenerates to one chunk
       per segment regardless of load.  Like DEC005, a warning and never
       a gate: the engine's output is bitwise-correct either way.
@@ -158,6 +164,30 @@ def analyze_decode(
                 data={"pool_bytes": dict(sorted(pool_bytes.items()))},
             )
 
+        # rows a step and the draft task go together
+        rows = int(getattr(graph, "rows_per_step", 1))
+        drafts = [t for t in tasks if t.group == "draft"]
+        layer_pools = {p for t in tasks if t.group != "draft"
+                       for p in t.params_needed if _is_cache_param(p)}
+        problem = None
+        if rows > 1 and len(drafts) != 1:
+            problem = (f"the step feeds {rows} rows a slot but has "
+                       f"{len(drafts)} draft task(s): the rows past the "
+                       "first are drafts, and one task verifies them")
+        elif rows == 1 and drafts:
+            problem = (f"task {drafts[0].task_id!r} is a draft task on a "
+                       "step of one row a slot: there is no draft to verify")
+        elif drafts and (graph.dependents(drafts[0].task_id) or not any(
+                _is_cache_param(p) and p not in layer_pools
+                for p in drafts[0].params_needed)):
+            problem = (f"draft task {drafts[0].task_id!r} must be the sink "
+                       "and hold a cache pool no layer task reads (the "
+                       "draft module's rows roll back with the model's)")
+        if problem:
+            rep.add("DEC003", Severity.ERROR, problem,
+                    data={"rows_per_step": rows,
+                          "draft_tasks": [t.task_id for t in drafts]})
+
     # DEC005: fused-kernel eligibility of the pool geometry --------------
     # a kv pool is stored (n_pages, page_size, n_kv_heads * head_dim)
     # (``models/kv_pages.CacheSpec``); the paged builder stamps the
@@ -218,7 +248,8 @@ def analyze_decode(
             problems.append(
                 f"chunk_tokens {chunk_tokens} exceeds the per-segment "
                 f"decode-token capacity {decode_budget} (slots * "
-                "seg_steps): one chunk monopolizes each segment's "
+                "seg_steps * rows a step): one chunk monopolizes each "
+                "segment's "
                 "prefill budget, so chunked admission degenerates to "
                 "one chunk per segment regardless of load"
             )

@@ -46,6 +46,13 @@ PGL008  the cache keeps pages the stream does not cover (a ring
         layer's slot-owned pages): an explicit refusal, never a pass
 ======  ==========================================================
 
+A step that verifies drafts (``rows_per_step`` > 1) writes one row past
+a request's last cached token; that row lies inside the ``prompt +
+max_new`` footprint admission allocates (the last token owed is never
+cached), so its page is in the stream like any other and no rule here
+knows of rows: a page a rejected draft touched is proven by the same
+alloc / assign / release / free replay.
+
 A shared page with any live owner is NOT an orphan — PGL001 is judged
 over physical pages after the last reference drops.
 

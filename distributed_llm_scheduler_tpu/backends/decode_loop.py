@@ -30,7 +30,7 @@ import jax.numpy as jnp
 
 from ..core.graph import TaskGraph
 from ..core.schedule import Schedule
-from ..models import cache_spec, module_of
+from ..models import cache_spec, draft_rows, module_of
 from ..obs import process_metrics
 from ..obs.trace import annotate
 
@@ -204,16 +204,31 @@ def compose_paged_step_fn(
     stacked, layer-major — per name where a layer's ``stats`` is a dict
     of named counts, over the layers that emit that name.
 
+    A family stepped with its draft module (``models.draft_rows`` > 1):
+    ``ids`` is ``(S, R)``, every task's ``{kind}_new`` is ``(S, R, ...)``
+    and lands at ``lengths .. lengths + R - 1``, the spec's draft layers'
+    rows come from the ``draft`` task — the sink, whose whole output
+    dict (``logits``, ``draft_logits``) is returned as ``logits``.
+
     Returns ``step(weights, pools, page_table, ids, lengths, active)
     -> (logits, new_pools, stats or None)``.
     """
-    from ..models.kv_pages import write_token_rows
+    from ..models.kv_pages import write_step_rows, write_token_rows
 
     order = _placed_order(graph, schedule)
     sink = [tid for tid in order if not graph.dependents(tid)][0]
     spec = cache_spec(config)
+    n_main = spec.n_layers - spec.draft_layers
+    rows_per_step = draft_rows(config)
+    if rows_per_step > 1 and spec.has_rings:
+        raise ValueError(
+            "a step that verifies drafts is not built for a cache with "
+            "ring (window) layers: a rejected row would overwrite the "
+            "ring row a later query still reads")
 
     def write(pool, row, page_table, lengths, active, window):
+        if rows_per_step > 1:
+            return write_step_rows(pool, row, page_table, lengths, active)
         if window is None:
             return write_token_rows(pool, row, page_table, lengths, active)
         slots, ps = page_table.shape[0], pool.shape[1]
@@ -244,7 +259,7 @@ def compose_paged_step_fn(
         new_pools = dict(pools)
         stats, named = [], {}
         for i in range(spec.n_layers):
-            o = outs[f"layer_{i}"]
+            o = outs[f"layer_{i}" if i < n_main else "draft"]
             window = spec.layer(i).window
             for kind in spec.layer_kinds(i):
                 new_pools[f"cache_{kind}_{i}"] = write(
@@ -302,8 +317,66 @@ def build_paged_decode_loop(
     HBM and in the compile cache per executable), so every serving
     program shares the one device-resident weight dict and is
     independent of weight values.
+
+    A family stepped with its draft module (``models.DRAFT_FUNCTIONS``)
+    gets the same segment with a step that VERIFIES: ``cur_tok`` is
+    ``(S, 2)`` — the current token ``x`` (position ``L``, not cached
+    yet) and the draft ``d`` for ``L + 1`` — and one step
+
+    1. runs the main model over the rows ``[x@L, d@L+1]``, causal, both
+       written: ``y0``, ``y1`` the argmax of each row's logits;
+    2. accepts the draft where ``y0 == d`` — computed here, on the
+       device, from nothing else — and the slot owes more than one token;
+    3. runs the draft module over ``[(h_L, y0)@L, (h_L+1, y1)@L+1]``,
+       both written; the next draft is its row 1's argmax where the
+       draft was accepted, else row 0's;
+    4. emits ``y0``, and ``y1`` where accepted; ``lengths`` advances and
+       ``remaining`` (TOKENS owed) falls by the count.  The row at ``L +
+       1`` of a rejected draft is overwritten by the next step before
+       any query may see it (a query row ``r`` sees positions ``<= L +
+       r``), in the draft module's pool as in the main layers'.
+
+    ``tokens`` is then ONE int32 array ``(S, steps, 4)`` — per step
+    ``[y0, y1, tokens emitted (0, 1 or 2), the draft the NEXT step
+    verifies]`` — which is all the host folds from: the tokens of a
+    slot are each step's first ``count`` entries, its lengths the sum
+    of the counts, the drafts verified the column shifted by one.  The
+    sequence emitted is exactly the one-row greedy sequence.
     """
     step = compose_paged_step_fn(graph, schedule, config)
+    rows_per_step = draft_rows(config)
+    if rows_per_step not in (1, 2):
+        raise ValueError(
+            f"a step verifies one draft a slot (2 rows), the family asks "
+            f"for {rows_per_step}")
+
+    def seg_drafts(weights, pools, page_table, lengths, cur_tok, remaining):
+        def body(carry, _):
+            pools, lengths, cur, remaining = carry
+            active = remaining > 0
+            out, pools, stats = step(
+                weights, pools, page_table, cur, lengths, active)
+            y = jnp.argmax(out["logits"], axis=-1).astype(jnp.int32)
+            nd = jnp.argmax(out["draft_logits"], axis=-1).astype(jnp.int32)
+            accept = jnp.logical_and(y[:, 0] == cur[:, 1], remaining > 1)
+            n = jnp.where(active, 1 + accept.astype(jnp.int32), 0)
+            nxt = jnp.where(accept[:, None], jnp.stack(
+                [y[:, 1], nd[:, 1]], axis=1), jnp.stack(
+                [y[:, 0], nd[:, 0]], axis=1))
+            cur = jnp.where(active[:, None], nxt, cur)
+            rec = jnp.concatenate([y, n[:, None], cur[:, 1:]], axis=1)
+            return (pools, lengths + n, cur, remaining - n), (rec, stats)
+
+        (pools2, _, _, _), (recs, stats) = jax.lax.scan(
+            body, (pools, lengths, cur_tok, remaining), None, length=steps
+        )
+        recs = recs.transpose(1, 0, 2)      # (S, steps, 4)
+        if stats is None:
+            return recs, pools2
+        return recs, pools2, stats
+
+    if rows_per_step > 1:
+        return jax.jit(seg_drafts, donate_argnums=(1,))
 
     def seg(weights, pools, page_table, lengths, cur_tok, remaining):
         def body(carry, _):
@@ -408,6 +481,11 @@ class PagedDecodeEngine:
         # allocates, gathers, scatters, copies and resets goes through it
         self.cache = cache_spec(config)
         n_layers = self.cache.n_layers
+        # rows a slot feeds a decode step: 1, or 2 where the family is
+        # stepped with its own draft module (``models.DRAFT_FUNCTIONS``) —
+        # then ``cur_tok`` holds the current token AND the draft for the
+        # position after it, and a step yields one token or two
+        self.rows_per_step = draft_rows(config)
         self.page_size = pool.page_size
         self.capacity = pages_per_seq * pool.page_size
         # what the decode step's paged attention actually runs at this
@@ -486,7 +564,7 @@ class PagedDecodeEngine:
             (slots, pages_per_seq), TRASH_PAGE, np.int32
         )
         self.lengths = np.zeros((slots,), np.int32)
-        self.cur_tok = np.zeros((slots, 1), np.int32)
+        self.cur_tok = np.zeros((slots, self.rows_per_step), np.int32)
         self.remaining = np.zeros((slots,), np.int32)
         # host state
         self._queue: list = []
@@ -627,7 +705,8 @@ class PagedDecodeEngine:
             (self.slots, self.pages_per_seq), TRASH_PAGE, np.int32
         )
         self.lengths = np.zeros((self.slots,), np.int32)
-        self.cur_tok = np.zeros((self.slots, 1), np.int32)
+        self.cur_tok = np.zeros(
+            (self.slots, self.rows_per_step), np.int32)
         self.remaining = np.zeros((self.slots,), np.int32)
         self._queue = []
         self._slot_req = [None] * self.slots
@@ -728,13 +807,22 @@ class PagedDecodeEngine:
         Refused for a cache with ring layers: a shared page carries the
         paged layers' rows of a prefix and nothing of the window layers'
         state, so a request that aliased one would decode over rings it
-        never filled."""
+        never filled.  Refused for a family stepped with its draft
+        module too: the draft layer's row of a position is made from
+        the token AFTER it, which a page's key (the tokens of the page)
+        does not cover for its last row."""
         on = bool(getattr(self.pool, "sharing", False))
         if on and self._rings is not None:
             raise ValueError(
                 "prefix sharing is not built for a cache with ring "
                 "(window) layers: a shared page does not carry their "
                 "state; use PagePool(sharing=False)")
+        if on and self.rows_per_step > 1:
+            raise ValueError(
+                "prefix sharing is not built for a family stepped with "
+                "its draft module: the draft layer's last row of a "
+                "shared page depends on the token after the page; use "
+                "PagePool(sharing=False)")
         return on
 
     def _release_pages(self, pages, owner: str, site: str) -> None:
@@ -1051,13 +1139,37 @@ class PagedDecodeEngine:
         self.metrics.counter("decode.requests_submitted").inc()
         self._emit_queue_depth()
 
-    def _forward_last(self, w, ids, cache, pos0, row):
+    def _first_tokens(self, w, ids, cache, pos0, row):
         """The family's cached forward over ``ids`` (b, T) at ``pos0``
-        and the logits of chunk row ``row`` (static or traced), (b, V):
-        all any prefill program needs of them: the family's
-        ``forward_cached_row``."""
-        return module_of(self.config).forward_cached_row(
-            w, ids, cache, pos0, self.config, row, impl=self.attention_impl)
+        and the greedy token of chunk row ``row`` (static or traced),
+        (b,) int32 — all any prefill program needs of the logits: the
+        family's ``forward_cached_row``.  For a family stepped with its
+        draft module ``ids`` is the pair ``(ids, nxt)`` of
+        :meth:`_with_next`, the draft layer's rows are filled too
+        (``forward_cached_draft``) and what comes back is (b, 2): the
+        first token and the first draft."""
+        fam = module_of(self.config)
+        if self.rows_per_step == 1:
+            last, cache = fam.forward_cached_row(
+                w, ids, cache, pos0, self.config, row,
+                impl=self.attention_impl)
+            return jnp.argmax(last, axis=-1).astype(jnp.int32), cache
+        ids, nxt = ids
+        last, draft, cache = fam.forward_cached_draft(
+            w, ids, nxt, cache, pos0, self.config, row,
+            impl=self.attention_impl)
+        return jnp.stack(
+            [jnp.argmax(last, axis=-1), jnp.argmax(draft, axis=-1)],
+            axis=-1).astype(jnp.int32), cache
+
+    def _with_next(self, ids, nxt):
+        """What a prefill program takes as its ids: ``ids`` itself, or —
+        for a family stepped with its draft module — the pair with
+        ``nxt``, the token after each position (-1: the one the program
+        itself decides at its ``row``)."""
+        if self.rows_per_step == 1:
+            return ids
+        return ids, jnp.asarray(nxt, jnp.int32)
 
     # -- prefill + page scatter (ONE call per admission ROUND; one
     # compiled class per (prompt length, batch size)) ----------------------
@@ -1080,14 +1192,13 @@ class PagedDecodeEngine:
         key = (P, b, self.attention_impl)
         fn = self._prefill_store.get(key)
         if fn is None:
-            spec, fwd = self.cache, self._forward_last
+            spec, fwd = self.cache, self._first_tokens
             cap, cfg = self.capacity, self.config
             ppseq, ps = self.pages_per_seq, self.page_size
 
             def _fn(w, ids, pools, pages, *ring):
                 cache = spec.init_dense(b, cap, cfg.dtype, page_size=ps)
-                last, cache = fwd(w, ids, cache, 0, P - 1)
-                first = jnp.argmax(last, axis=-1).astype(jnp.int32)
+                first, cache = fwd(w, ids, cache, 0, P - 1)
                 return first, spec.scatter(
                     pools, cache, pages.reshape(b * ppseq), ps, *ring)
 
@@ -1099,9 +1210,13 @@ class PagedDecodeEngine:
             self._prefill_cache[key] = fn
         if self.prefill_time_charge is not None:
             self.prefill_time_charge(b * P)
+        nxt = None
+        if self.rows_per_step > 1:   # the ids shifted by one, then the
+            nxt = self._np.full((b, P), -1, self._np.int32)   # program's own
+            nxt[:, :-1] = self._np.asarray(prompt_ids)[:, 1:]
         first, self.pools = fn(
-            self.weights, prompt_ids, self.pools, jnp.asarray(pt_rows),
-            *self._ring_args(slots)
+            self.weights, self._with_next(prompt_ids, nxt), self.pools,
+            jnp.asarray(pt_rows), *self._ring_args(slots)
         )
         return first
 
@@ -1137,7 +1252,7 @@ class PagedDecodeEngine:
         key = ("shared", P, h, b, self.attention_impl)
         fn = self._prefill_store.get(key)
         if fn is None:
-            spec, fwd = self.cache, self._forward_last
+            spec, fwd = self.cache, self._first_tokens
             cap, cfg = self.capacity, self.config
             ppseq, ps = self.pages_per_seq, self.page_size
             pre = h * ps
@@ -1146,8 +1261,7 @@ class PagedDecodeEngine:
                 cache = spec.gather(
                     spec.init_dense(b, cap, cfg.dtype), pools,
                     spages.reshape(b * h), b, pre)
-                last, cache = fwd(w, ids_tail, cache, pre, P - pre - 1)
-                first = jnp.argmax(last, axis=-1).astype(jnp.int32)
+                first, cache = fwd(w, ids_tail, cache, pre, P - pre - 1)
                 return first, spec.scatter(
                     pools, cache, wpages.reshape(b * ppseq), ps)
 
@@ -1166,7 +1280,7 @@ class PagedDecodeEngine:
 
     # -- chunked prefill (co-scheduled with decode segments) ---------------
     def _chunk_prefill(self, ids_chunk, pt_row, base: int, creal: int,
-                       slot: int = 0):
+                       slot: int = 0, nxt_chunk=None):
         """Run ONE prefill chunk for one slot: gather the slot's pages
         into a dense per-slot cache, run the transformer over the
         ``chunk_tokens`` chunk at traced ``pos_start = base``, and
@@ -1180,7 +1294,9 @@ class PagedDecodeEngine:
         ``>= P`` that stay masked until decode overwrites them).  The
         gather covers ALL ``pages_per_seq`` table entries (trash entries
         gather masked garbage; the scatter-back writes it harmlessly to
-        the trash page) so page count is data too.
+        the trash page) so page count is data too.  ``nxt_chunk``: the
+        token after each of the chunk's positions, for a family stepped
+        with its draft module (:meth:`_with_next`).
 
         Bitwise contract: the dense cache has exactly the per-slot
         ``capacity`` rows a whole-prompt prefill uses, positions
@@ -1193,7 +1309,7 @@ class PagedDecodeEngine:
         key = ("chunk", self.chunk_tokens, 1, self.attention_impl)
         fn = self._prefill_store.get(key)
         if fn is None:
-            spec, fwd = self.cache, self._forward_last
+            spec, fwd = self.cache, self._first_tokens
             cap, cfg = self.capacity, self.config
             ps = self.page_size
 
@@ -1201,8 +1317,7 @@ class PagedDecodeEngine:
                 cache = spec.gather(
                     spec.init_dense(1, cap, cfg.dtype, page_size=ps), pools,
                     pages, 1, cap, *ring)
-                last, cache = fwd(w, ids, cache, pos0, creal - 1)
-                first = jnp.argmax(last, axis=-1).astype(jnp.int32)
+                first, cache = fwd(w, ids, cache, pos0, creal - 1)
                 return first, spec.scatter(pools, cache, pages, ps, *ring)
 
             fn = jax.jit(_fn, donate_argnums=(2,))
@@ -1212,7 +1327,7 @@ class PagedDecodeEngine:
         if self.prefill_time_charge is not None:
             self.prefill_time_charge(int(creal))
         first, self.pools = fn(
-            self.weights, ids_chunk, self.pools,
+            self.weights, self._with_next(ids_chunk, nxt_chunk), self.pools,
             jnp.asarray(pt_row, jnp.int32),
             jnp.int32(base), jnp.int32(creal), *self._ring_args((slot,)),
         )
@@ -1241,7 +1356,7 @@ class PagedDecodeEngine:
                 pages[i] if i < len(pages) else TRASH_PAGE
             )
         self.lengths[s] = 0
-        self.cur_tok[s, 0] = 0
+        self.cur_tok[s] = 0
         self.remaining[s] = 0
         self._chunk_state[s] = {
             "rid": rid, "ids": self._np.asarray(ids), "P": P,
@@ -1298,7 +1413,7 @@ class PagedDecodeEngine:
 
         ct = self.chunk_tokens
         if budget is None:
-            budget = max(ct, self.slots * self.seg_steps)
+            budget = max(ct, self.decode_rows_per_segment)
         advanced = 0
         spent_by: list = []   # rids whose chunks consumed budget here
         order = sorted(self._chunk_state)
@@ -1363,6 +1478,13 @@ class PagedDecodeEngine:
                         )
             chunk = self._np.zeros((1, ct), self._np.int32)
             chunk[0, :C] = st["ids"][0, base:base + C]
+            nxt = None
+            if self.rows_per_step > 1:
+                # the ids shifted by one; the prompt's last position
+                # takes the program's own first token (-1)
+                nxt = self._np.zeros((1, ct), self._np.int32)
+                nxt[0, :C] = self._np.append(
+                    st["ids"][0, base + 1:base + C + 1], -1)[:C]
             # a DISPATCH span: it ends when the chunk program is enqueued
             # (no sync is added to close it "when ready"); the chunk's
             # device time is the device trace's (prefill_dev_us_tok)
@@ -1374,7 +1496,7 @@ class PagedDecodeEngine:
                 )
             with annotate("prefill_chunk"):
                 first = self._chunk_prefill(
-                    jnp.asarray(chunk), self.page_table[s], base, C, s
+                    jnp.asarray(chunk), self.page_table[s], base, C, s, nxt
                 )
             if ev is not None:
                 self.tracer.end(ev)
@@ -1406,10 +1528,14 @@ class PagedDecodeEngine:
         (the chunk programs still in flight, 75 ms each on the v5e, end
         before it)."""
         rid = st["rid"]
-        tok = int(first[0])  # the readback: waits for the last chunk
+        # the readback: waits for the last chunk.  (1,) the first token,
+        # or (1, 2) with the first draft behind it
+        first = ([int(first[0])] if self.rows_per_step == 1
+                 else self._np.asarray(first).reshape(-1))
+        tok = int(first[0])
         t_done = self._clock()
         self.lengths[s] = st["P"]
-        self.cur_tok[s, 0] = tok
+        self.cur_tok[s] = first
         self.remaining[s] = st["max_new"] - 1
         self._tokens[rid] = [tok]
         self._first_tok_t[rid] = t_done
@@ -1629,7 +1755,8 @@ class PagedDecodeEngine:
             else:
                 first = self._prefill_scatter(
                     all_ids, pt_rows, free_slots[:len(batch)])
-            first = self._np.asarray(first)
+            # (b,) first tokens, or (b, 2) with the first drafts
+            first = self._np.asarray(first).reshape(len(batch), -1)
             # first token exists NOW (the prefill's readback): the
             # admission timestamp is each request's TTFT anchor
             t_adm = self._clock()
@@ -1643,11 +1770,11 @@ class PagedDecodeEngine:
                 s = free_slots[j]
                 self.page_table[s] = pt_rows[j]
                 self.lengths[s] = P
-                self.cur_tok[s, 0] = int(first[j])
+                self.cur_tok[s] = first[j]
                 self.remaining[s] = max_new - 1
                 self._slot_req[s] = rid
                 self._slot_pages[s] = page_lists[j]
-                self._tokens[rid] = [int(first[j])]
+                self._tokens[rid] = [int(first[j, 0])]
                 self._first_tok_t[rid] = t_adm
                 if sharing:
                     # intern happened pre-prefill (same-wave aliasing);
@@ -1775,7 +1902,7 @@ class PagedDecodeEngine:
             self.memprof.free(self._mem_node, f"kv:{rid}")
         self.page_table[slot] = TRASH_PAGE
         self.lengths[slot] = 0
-        self.cur_tok[slot, 0] = 0
+        self.cur_tok[slot] = 0
         self.remaining[slot] = 0
         self._slot_req[slot] = None
         self._slot_pages[slot] = []
@@ -1796,6 +1923,13 @@ class PagedDecodeEngine:
         return {"rid": rid, "tokens": tokens, "remaining": remaining}
 
     # -- the serving loop --------------------------------------------------
+    @property
+    def decode_rows_per_segment(self) -> int:
+        """Model-forward rows one segment's decode steps run: what the
+        default per-segment prefill budget is sized by (DEC006's
+        ``decode_budget``)."""
+        return self.slots * self.seg_steps * self.rows_per_step
+
     def step_segment(self) -> int:
         """Admit, advance pending prefill chunks (one chunk-token budget
         per segment), run ONE K-step segment, fold tokens, retire
@@ -1807,7 +1941,7 @@ class PagedDecodeEngine:
         # chunk-admitted request then spends whatever prefill budget is
         # left, so its first chunk still lands this segment
         ct = self.chunk_tokens
-        full = (max(ct, self.slots * self.seg_steps)
+        full = (max(ct, self.decode_rows_per_segment)
                 if ct is not None else 0)
         spent = self._advance_chunks() if self._chunk_state else 0
         with annotate("admit"):
@@ -1842,8 +1976,10 @@ class PagedDecodeEngine:
         # from the host's own lengths (slots not decoding sit at 0): once
         # per dispatched segment, into the engine's registry and the
         # process-wide always-on one
+        # (a step of R rows walks to its last row's block)
         share = kv_live_block_share(
-            self.lengths, self.kv_block_rows, self.capacity
+            self.lengths + (self.rows_per_step - 1) * (owed > 0),
+            self.kv_block_rows, self.capacity
         )
         for reg in (self.metrics, process_metrics()):
             reg.histogram(
@@ -1856,29 +1992,72 @@ class PagedDecodeEngine:
                 self.cur_tok, self.remaining,
             )
             toks = self._np.asarray(toks)  # the one readback per segment
-            if stats:  # counted on the device, same program: no new sync
-                self._observe_stats(stats[0], owed)
+            emitted, steps_ran, probe, span_args = self._emitted(toks, owed)
+            # counted on the device, same program: no new sync
+            self._observe_stats(
+                stats[0] if stats else {}, owed, steps_ran, probe, span_args)
             # the fold timestamp: every token this segment delivered
             # became host-visible at this readback (lifecycle-log
             # delivery events)
             t_sg1 = self._clock()
         with annotate("fold"):
-            return self._fold_segment(toks, owed, t_sg0, t_sg1)
+            return self._fold_segment(emitted, owed, t_sg0, t_sg1)
 
-    def _observe_stats(self, stats, owed) -> None:
+    def _emitted(self, toks, owed):
+        """What a segment's readback gave each slot: ``(tokens of slot s
+        in order, steps in which a slot still decoded, arrays for the
+        ``stats_probe``, arguments for the ``segment`` span)``.  One row a
+        step: slot ``s`` ran ``min(owed,
+        seg_steps)`` steps, a token each.  A verifying step (``toks``
+        (S, steps, 4) = ``[y0, y1, count, next draft]``): the first
+        ``count`` of each step's two; the draft the NEXT segment verifies
+        goes to ``cur_tok`` here, the counts into the ``mtp.*`` metrics."""
+        np = self._np
+        if self.rows_per_step == 1:
+            ran = np.minimum(owed, self.seg_steps)
+            return ([toks[s, :ran[s]] for s in range(self.slots)],
+                    min(int(owed.max()), self.seg_steps), {}, {})
+        counts = toks[..., 2]
+        took = np.arange(2)[None, None, :] < counts[..., None]
+        # the drafts the steps verified: each step's is the one the step
+        # before left (the first step's came with the segment)
+        drafts = np.concatenate(
+            [self.cur_tok[:, 1:], toks[:, :-1, 3]], axis=1)
+        self.cur_tok[:, 1] = toks[:, -1, 3]
+        verified = int((counts > 0).sum())
+        accepted = int((counts == 2).sum())
+        for reg in (self.metrics, process_metrics()):
+            reg.histogram("mtp.accept_rate", unit="ratio").observe(
+                accepted / max(verified, 1))
+            reg.histogram("mtp.tokens_per_step", unit="tokens").observe(
+                int(counts.sum()) / max(verified, 1))
+            reg.counter("mtp.drafts_verified").inc(verified)
+            reg.counter("mtp.drafts_accepted").inc(accepted)
+            # a rejected draft's row, in every pool, is written over
+            reg.counter("mtp.rows_rolled_back").inc(verified - accepted)
+        return ([toks[s, :, :2][took[s]] for s in range(self.slots)],
+                int((counts > 0).any(axis=0).sum()),
+                {"mtp_counts": counts, "mtp_drafts": drafts},
+                {"drafts": verified, "accepted": accepted,
+                 "tokens": int(counts.sum())})
+
+    def _observe_stats(self, stats, owed, steps_ran: int, probe,
+                       span_args) -> None:
         """What the segment's layers counted on the device, onto the
         next ``segment`` span and into the registries: an expert
         family's routing counts (an array, or ``stats["moe"]``) and a
         sparse-selection family's rows (``stats["dsa"]``, (steps, full
         layers, 2) = (latent rows the decoding slots' attention read,
-        rows those slots hold)).  Any other named array is the
+        rows those slots hold)).  Any other named array, and what
+        :meth:`_emitted` read for it (``probe``), is the
         ``stats_probe``'s, if one is set."""
         np = self._np
         if not isinstance(stats, dict):
             stats = {"moe": stats}
-        args = {}
+        args = dict(span_args)
         if "moe" in stats:
-            args.update(self._observe_moe(np.asarray(stats["moe"]), owed))
+            args.update(self._observe_moe(
+                np.asarray(stats["moe"]), steps_ran))
         if "dsa" in stats:
             read, held = np.asarray(stats["dsa"]).reshape(-1, 2).sum(axis=0)
             args.update(rows_selected=float(read), rows_live=float(held))
@@ -1888,20 +2067,20 @@ class PagedDecodeEngine:
                         "dsa.selected_share", unit="ratio"
                     ).observe(float(read / held))
         self._seg_span_args = args
-        if self.stats_probe is not None:
+        if self.stats_probe is not None and (stats or probe):
             self.stats_probe(
-                {k: np.asarray(v) for k, v in stats.items()
-                 if k not in ("moe", "dsa")},
+                {**{k: np.asarray(v) for k, v in stats.items()
+                    if k not in ("moe", "dsa")}, **probe},
                 list(self._slot_req), self.lengths.copy(), owed)
 
-    def _observe_moe(self, stats, owed) -> Dict[str, float]:
+    def _observe_moe(self, stats, steps_ran: int) -> Dict[str, float]:
         """An expert family's routing counts of one segment, ``stats``
         (steps, expert layers, 2) = (share of the experts picked, largest
         expert's picks over the mean), into the engine's registry and the
         process-wide one: the median over the layer-steps in which a
-        slot still decoded."""
+        slot still decoded (the first ``steps_ran``)."""
         np = self._np
-        ran = stats[:min(int(owed.max()), self.seg_steps)].reshape(-1, 2)
+        ran = stats[:steps_ran].reshape(-1, 2)
         touched, imbalance = (float(v) for v in np.median(ran, axis=0))
         for reg in (self.metrics, process_metrics()):
             reg.histogram(
@@ -1916,10 +2095,14 @@ class PagedDecodeEngine:
             stats[..., 0].mean() * getattr(
                 self.config, "n_held_experts", self.config.n_routed_experts))}
 
-    def _fold_segment(self, toks, owed, t_sg0: float, t_sg1: float) -> int:
-        """What follows a segment's readback at ``t_sg1``: the tokens go
-        to their requests, finished slots retire, the gauges are sampled.
-        Returns the tokens delivered."""
+    def _fold_segment(self, emitted, owed, t_sg0: float,
+                      t_sg1: float) -> int:
+        """What follows a segment's readback at ``t_sg1``: the tokens
+        ``emitted[s]`` go to their requests (a slot's count is what its
+        steps yielded — one a step, or one or two where drafts are
+        verified — never more than it owed), finished slots retire, the
+        gauges are sampled.  Returns the tokens delivered."""
+        ran = self._np.asarray([len(t) for t in emitted], self._np.int32)
         if self.tracer is not None:
             self.tracer.complete(
                 "segment", t_sg0, t_sg1, track="decode",
@@ -1939,15 +2122,14 @@ class PagedDecodeEngine:
                 if rid is None or owed[s] <= 0:
                     continue
                 self.reqtrace.segment(
-                    rid, t_sg0, t_sg1,
-                    tokens=int(min(int(owed[s]), self.seg_steps)),
+                    rid, t_sg0, t_sg1, tokens=int(ran[s]),
                     co_resident=residents,
                 )
-        # slot state advances host-side: each slot ran min(owed, K)
-        # active steps, its current token is the last one it emitted
-        ran = self._np.minimum(owed, self.seg_steps)
+        # slot state advances host-side exactly as the device's carry
+        # did: a cached row and one token less owed for every token
+        # emitted, the current token the last one emitted
         self.lengths = self.lengths + ran
-        self.remaining = self._np.maximum(owed - self.seg_steps, 0)
+        self.remaining = owed - ran
         delivered = retired = 0
         for s in range(self.slots):
             rid = self._slot_req[s]
@@ -1955,14 +2137,14 @@ class PagedDecodeEngine:
                 continue
             n = int(ran[s])
             if n:
-                self._tokens[rid].extend(int(t) for t in toks[s, :n])
-                self.cur_tok[s, 0] = toks[s, n - 1]
+                self._tokens[rid].extend(int(t) for t in emitted[s])
+                self.cur_tok[s, 0] = emitted[s][-1]
                 delivered += n
                 for rl in self._reqlogs:
                     rl.deliver(rid, t_sg1, n)
             # owed == 0 means the slot is mid-chunk-prefill (occupied,
             # decoding nothing yet) — it retires only after its fold
-            if 0 < owed[s] <= self.seg_steps:
+            if owed[s] > 0 and self.remaining[s] == 0:
                 self._retire(s)
                 retired += 1
         self.segments_run += 1

@@ -44,7 +44,13 @@ import jax
 import jax.numpy as jnp
 
 from ..core.graph import Task, TaskGraph
-from ..models import cache_spec, family_of, family_module, model_config
+from ..models import (
+    cache_spec,
+    draft_rows,
+    family_of,
+    family_module,
+    model_config,
+)
 from .gpt2_dag import DEFAULT_EFFECTIVE_FLOPS, ModelDAG, make_task_adder
 
 
@@ -98,12 +104,14 @@ def _chain(fam, config, spec, add, flops, f_embed, layer_fn, f_head,
     local param names share ONE fn object (they must then compute the
     same function), so per-task dispatch compiles each layer shape once,
     not once a layer.  ``shared_alias`` is what every layer aliases
-    beside its weights and its ``cache_{kind}``."""
+    beside its weights and its ``cache_{kind}``.  The spec's draft
+    layers are not the chain's: the paged builder hangs the ``draft``
+    task behind the logits task."""
     embed_flops, layer_flops, head_flops = flops
     add("embed", f_embed, [], {k: k for k in fam.EMBED_PARAMS}, embed_flops,
         "embed")
     prev, fns = "embed", {}
-    for i in range(spec.n_layers):
+    for i in range(spec.n_layers - spec.draft_layers):
         alias = dict(fam.layer_param_names(config, i))
         fn = fns.get(tuple(alias))
         if fn is None:
@@ -121,9 +129,11 @@ def _chain(fam, config, spec, add, flops, f_embed, layer_fn, f_head,
 def _name(family: str, what: str, spec, out_specs, geometry: str,
           config: Any) -> str:
     """``{family}{what}_{L}l_d{residual width}_{geometry}[_{dtype}]``;
-    the width is the last dimension on the embed edge."""
+    ``L`` counts the model's own layers (not a draft module's), the
+    width is the last dimension on the embed edge."""
     width = out_specs["embed"]["x"].shape[-1]
-    return (f"{family}{what}_{spec.n_layers}l_d{width}_{geometry}"
+    return (f"{family}{what}_{spec.n_layers - spec.draft_layers}l_d{width}"
+            f"_{geometry}"
             + ("" if config.dtype == jnp.float32
                else f"_{jnp.dtype(config.dtype).name}"))
 
@@ -231,14 +241,17 @@ def build_decode_dag(
 
 
 class PagedDecodeDAG(ModelDAG):
-    """ModelDAG for the paged decode step: inputs are ``{"ids": (S, 1)
+    """ModelDAG for the paged decode step: inputs are ``{"ids": (S, R)
     int32, "lengths": (S,) int32}`` — per-slot ragged positions instead
-    of one shared scalar — and the cache params are shared page pools
-    plus the ``page_table`` param (:mod:`..models.kv_pages`)."""
+    of one shared scalar, ``R`` = ``rows_per_step`` rows a slot (1, or
+    the family's ``DECODE_ROWS`` where it is stepped with its draft
+    module) — and the cache params are shared page pools plus the
+    ``page_table`` param (:mod:`..models.kv_pages`)."""
 
     slots: int = 1
     page_size: int = 0
     pages_per_seq: int = 0
+    rows_per_step: int = 1
 
     @property
     def attention_impl(self) -> Optional[str]:
@@ -300,6 +313,17 @@ def build_paged_decode_dag(
     for a family that declares ``DECODE_TAKES_LIVE`` — ``live``, the
     graph's ``active`` input.
 
+    A family that offers ``models.DRAFT_FUNCTIONS`` is stepped with its
+    own draft module and no other way: ``ids`` is ``(S, R)`` (``R`` its
+    ``DECODE_ROWS``: the current token and the drafts after it, at
+    positions ``lengths .. lengths + R - 1``, causal), every layer emits
+    ``R`` rows a slot, the logits task passes the residual on beside its
+    ``(S, R, V)`` logits, and one more task, ``draft`` — the sink —
+    runs the family's ``draft_decode`` over the spec's draft layers'
+    pools: its output is ``{"logits", "draft_logits", "{kind}_new"}``.
+    Which of the rows count is the loop's to decide
+    (``build_paged_decode_loop``).
+
     The step is scheduler-placed exactly like the dense decode DAG; the
     continuous-batching loop (``backends/decode_loop.py``) composes it
     into scanned K-step segments.
@@ -328,6 +352,8 @@ def build_paged_decode_dag(
     M = pages_per_seq * ps  # per-slot gathered capacity
     spec = fam.cache_spec(config)
     takes_live = getattr(fam, "DECODE_TAKES_LIVE", False)
+    R = draft_rows(config)
+    drafts = R > 1
 
     specs = {
         k: jax.ShapeDtypeStruct(shape, dtype)
@@ -337,7 +363,7 @@ def build_paged_decode_dag(
         lambda: spec.init_pools(n_pages, ps, config.dtype, slots=S)))
     specs["page_table"] = jax.ShapeDtypeStruct((S, pages_per_seq), jnp.int32)
     input_spec = {
-        "ids": jax.ShapeDtypeStruct((S, 1), jnp.int32),
+        "ids": jax.ShapeDtypeStruct((S, R), jnp.int32),
         "lengths": jax.ShapeDtypeStruct((S,), jnp.int32),
     }
     if takes_live:
@@ -366,15 +392,34 @@ def build_paged_decode_dag(
         return f_layer
 
     def f_head(p, prev):
-        return fam.decode_head(p, prev["x"], config)
+        logits = fam.decode_head(p, prev["x"], config)
+        if not drafts:
+            return logits
+        out = {"x": prev["x"], "lengths": prev["lengths"], "logits": logits}
+        if takes_live:
+            out["live"] = prev["live"]
+        return out
+
+    def f_draft(p, prev):
+        out = fam.draft_decode(
+            p, prev["x"], prev["logits"], prev["lengths"], prev.get("live"),
+            config, impl=attention_impl)
+        return {"logits": prev["logits"], **out}
 
     tasks: List[Task] = []
     out_specs: Dict[str, Any] = {}
-    _chain(
-        fam, config, spec,
-        make_task_adder(tasks, out_specs, specs, input_spec, effective_flops),
-        fam.decode_flops(config, S, M), f_embed, layer_fn, f_head,
-        {"page_table": "page_table"})
+    add = make_task_adder(tasks, out_specs, specs, input_spec,
+                          effective_flops)
+    _chain(fam, config, spec, add, fam.decode_flops(config, S, M), f_embed,
+           layer_fn, f_head, {"page_table": "page_table"})
+    if drafts:
+        alias = dict(fam.draft_param_names(config))
+        for i in range(spec.n_layers - spec.draft_layers, spec.n_layers):
+            alias.update({f"cache_{k}": f"cache_{k}_{i}"
+                          for k in spec.layer_kinds(i)})
+        alias["page_table"] = "page_table"
+        add("draft", f_draft, ["logits"], alias,
+            fam.draft_flops(config, S, M), "draft")
 
     def init_fn(key):
         params = fam.init_params(config, key)
@@ -397,10 +442,28 @@ def build_paged_decode_dag(
                 spec.init_dense(1, M, config.dtype, page_size=ps), params,
                 params["page_table"][s], 1, M,
                 *(() if rings is None else (jnp.asarray(rings[s]),)))
+            if drafts:
+                # row by row: row r's draft input is the main model's own
+                # argmax at row r, which the call decides where nxt < 0
+                ids, nxt, both = inputs["ids"][s:s + 1], [], []
+                for r in range(R):
+                    lg, dl, _ = fam.forward_cached_draft(
+                        weights, ids, jnp.asarray(
+                            [nxt + [-1] * (R - r)], jnp.int32), cache,
+                        inputs["lengths"][s], config, r, impl="xla")
+                    nxt.append(int(jnp.argmax(lg[0])))
+                    both.append((lg, dl))
+                outs.append({
+                    "logits": jnp.stack([lg for lg, _ in both], 1),
+                    "draft_logits": jnp.stack([dl for _, dl in both], 1)})
+                continue
             logits, _ = fam.forward_cached_row(
                 weights, inputs["ids"][s:s + 1], cache,
                 inputs["lengths"][s], config, 0, impl="xla")
             outs.append(logits[:, None, :])
+        if drafts:
+            return {k: jnp.concatenate([o[k] for o in outs], axis=0)
+                    for k in outs[0]}
         return jnp.concatenate(outs, axis=0)
 
     graph = TaskGraph(tasks, name=_name(
@@ -423,6 +486,7 @@ def build_paged_decode_dag(
     dag.slots = S
     dag.page_size = ps
     dag.pages_per_seq = pages_per_seq
+    dag.rows_per_step = graph.rows_per_step = R
     return dag
 
 

@@ -137,11 +137,30 @@ PAGED_FUNCTIONS = (
     "cache_spec", "layer_param_names", "decode_embed", "decode_layer",
     "decode_head", "decode_flops", "forward_cached_row",
 )
+#: what a family offers BESIDE :data:`PAGED_FUNCTIONS` to be stepped with
+#: its own draft module — a decode step of ``DECODE_ROWS`` rows a slot
+#: that verifies the drafts and yields one token or more (``build_paged_
+#: decode_dag`` adds the ``draft`` task, ``PagedDecodeEngine`` accepts and
+#: folds).  The seam decides, not a flag: a family that has them all is
+#: stepped that way and no other
+DRAFT_FUNCTIONS = (
+    "DECODE_ROWS", "draft_param_names", "draft_decode", "draft_flops",
+    "forward_cached_draft",
+)
 #: the functions the dense decode-step DAG calls (``build_decode_dag``)
 CACHED_FUNCTIONS = (
     "cache_spec", "layer_param_names", "cached_embed", "cached_layer",
     "head", "cached_flops", "forward_cached",
 )
+
+
+def draft_rows(config: Any) -> int:
+    """Rows a slot feeds one paged decode step of this config's family:
+    its ``DECODE_ROWS`` where it offers :data:`DRAFT_FUNCTIONS`, else 1."""
+    mod = module_of(config)
+    if all(hasattr(mod, n) for n in DRAFT_FUNCTIONS):
+        return int(mod.DECODE_ROWS)
+    return 1
 
 
 def resolve(name: str) -> Any:
@@ -187,6 +206,11 @@ for _f in (
     Family(
         "dots3", f"{__name__}.dots3", "Dots3Config", {"dots3-tiny": "tiny"},
         "n_layers", "max_positions",
+    ),
+    # served with its MTP module as the self-draft (DRAFT_FUNCTIONS)
+    Family(
+        "glm4_lite", f"{__name__}.glm4_lite", "Glm4LiteConfig",
+        {"glm4_lite-tiny": "tiny"}, "n_layers", "max_positions",
     ),
 ):
     register_family(_f)

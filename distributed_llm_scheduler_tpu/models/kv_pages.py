@@ -560,7 +560,13 @@ class CacheSpec:
     ``walk`` names the pool whose live blocks the decode step's paged
     kernel walks, ``(pool kind, rank)`` — what :meth:`resolve_impl` and
     :meth:`block_pages` ask about; ``None``: the first pool of layers
-    that are all alike."""
+    that are all alike.
+
+    **Draft layers.**  The last ``draft_layers`` entries are the layers
+    of a draft module the family steps itself with (``models.
+    DRAFT_FUNCTIONS``): pools like any other, over the same positions,
+    pages and table, written by the step's ``draft`` task and, in a
+    prefill program, by the family's ``forward_cached_draft``."""
 
     kind: str
     layers: Tuple[LayerCache, ...]
@@ -569,6 +575,7 @@ class CacheSpec:
     #: rows a ring layer keeps for a slot (>= its window)
     ring_rows: int = 0
     walk: Optional[Tuple[str, Optional[int]]] = None
+    draft_layers: int = 0
 
     @classmethod
     def uniform(cls, kind: str, n_layers: int,
@@ -853,6 +860,31 @@ def write_token_rows(
 #: the K/V name of :func:`write_token_rows` (one function since the pools
 #: hold every kind's row as one vector)
 write_token_kv = write_token_rows
+
+
+def write_step_rows(
+    pool: jax.Array,
+    rows: jax.Array,
+    page_table: jax.Array,
+    lengths: jax.Array,
+    active: jax.Array,
+) -> jax.Array:
+    """:func:`write_token_rows` for a step of ``R`` rows a slot (one that
+    verifies drafts): ``rows`` (S, R, ...) land at positions ``lengths
+    .. lengths + R - 1`` in ONE scatter.  A slot's later rows may lie on
+    its next page; every row of an inactive slot goes to the trash page."""
+    n_pages, ps, width = pool.shape
+    S, R = rows.shape[:2]
+    pos = lengths[:, None] + jnp.arange(R, dtype=lengths.dtype)[None, :]
+    on = active[:, None]
+    logical = jnp.where(on, pos // ps, 0)
+    pid = jnp.where(
+        on, page_table[jnp.arange(S)[:, None], logical], TRASH_PAGE)
+    slot = jnp.where(on, pos % ps, 0)
+    flat = pool.reshape(n_pages * ps, width)
+    flat = flat.at[(pid * ps + slot).reshape(-1)].set(
+        rows.reshape(S * R, width).astype(pool.dtype), mode="drop")
+    return flat.reshape(pool.shape)
 
 
 def write_prompt_kv(

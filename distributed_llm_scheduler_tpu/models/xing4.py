@@ -32,7 +32,8 @@ Parameters are flat (``wte``, ``h{i}_q_a_w`` ...), made by
 :func:`init_params` or by the benchmark's reference; weights are bfloat16
 when served, router, norms' statistics, softmax and the hyper-connection
 maps float32.  ``num_nextn_predict_layers`` (the MTP module) is not
-built: a step yields one token.
+built here: a step yields one token (``glm4_lite`` builds one, on this
+file's attention and experts).
 """
 
 from __future__ import annotations
@@ -555,8 +556,8 @@ def _moe_kernel(exp_ref, tile_ref, lo_ref, hi_ref, x_ref, g_ref, u_ref,
     o_ref[...] += jnp.dot(a, d_ref[0], preferred_element_type=jnp.float32)
 
 
-@functools.partial(jax.jit, static_argnames=("impl",))
-def _moe_experts(x, idx, gate, gu_w, down_w, *, impl):
+@functools.partial(jax.jit, static_argnames=("impl", "name"))
+def _moe_experts(x, idx, gate, gu_w, down_w, *, impl, name="_moe_experts"):
     """The routed experts' part of a layer, dropless.
 
     ``x`` (N, h); ``idx`` (N, k) int32 — each pick's index into the ``E``
@@ -565,7 +566,9 @@ def _moe_experts(x, idx, gate, gu_w, down_w, *, impl):
     (N, k) float32.  The picks are sorted by expert and each expert's
     run of rows meets only that expert's weights, so an expert nobody
     picked is not read and no pick is dropped.  Returns ``(y (N, h)
-    float32, sizes (E,) int32)`` — the picks each held expert got."""
+    float32, sizes (E,) int32)`` — the picks each held expert got.
+    ``name`` is the kernel's name in a device trace (a draft module's
+    experts run it under their own)."""
     N, k = idx.shape
     E, I2, h = gu_w.shape
     I = I2 // 2
@@ -625,7 +628,7 @@ def _moe_experts(x, idx, gate, gu_w, down_w, *, impl):
             interpret=impl == "pallas_interpret",
             compiler_params=pltpu.CompilerParams(
                 vmem_limit_bytes=64 << 20),
-            name="_moe_experts",
+            name=name,
         )(exp_of, tile_of, lo, hi, xs, gu_w, gu_w, down_w)
     wts = jnp.pad(gate.reshape(-1), (0, M - M0))[order]
     ys = jnp.where((sorted_e < E)[:, None], ys * wts[:, None], 0.0)
@@ -634,13 +637,16 @@ def _moe_experts(x, idx, gate, gu_w, down_w, *, impl):
 
 
 def moe_ffn(p, x, cfg: Xing4Config, held: Optional[Sequence[int]] = None,
-            shared: bool = True, live=None, impl: Optional[str] = None):
+            shared: bool = True, live=None, impl: Optional[str] = None,
+            name: Optional[str] = None):
     """An expert layer's FFN for tokens ``x`` (N, h): the part of the
     experts in ``held`` (``p['exp_*_w']`` holds exactly those, in that
     order; ``None`` = all) plus, when ``shared``, the shared expert.
     ``live`` (N,) bool takes tokens out of the routing (empty slots).
-    Returns ``(y, stats)`` with ``stats`` the float32 pair (share of the
-    held experts picked, largest expert's picks over the mean)."""
+    ``name``: :func:`_moe_experts`' name in a device trace, where it is
+    not its own.  Returns ``(y, stats)`` with ``stats`` the float32 pair
+    (share of the held experts picked, largest expert's picks over the
+    mean)."""
     idx, gate = moe_route(p, x, cfg)
     E = p["exp_gu_w"].shape[0]
     if held is not None:
@@ -650,7 +656,8 @@ def moe_ffn(p, x, cfg: Xing4Config, held: Optional[Sequence[int]] = None,
     if live is not None:
         idx = jnp.where(live[:, None], idx, E)
     y, sizes = _moe_experts(x, idx, gate, p["exp_gu_w"], p["exp_down_w"],
-                            impl=_kernel_impl(impl))
+                            impl=_kernel_impl(impl),
+                            **({} if name is None else {"name": name}))
     y = y.astype(x.dtype)
     if shared:
         y = y + _swiglu(x, p["shared_gu_w"], p["shared_down_w"])
@@ -661,10 +668,11 @@ def moe_ffn(p, x, cfg: Xing4Config, held: Optional[Sequence[int]] = None,
     return y, stats
 
 
-def ffn(p, x, cfg: Xing4Config, layer: int, live=None, impl=None):
+def ffn(p, x, cfg: Xing4Config, layer: int, live=None, impl=None,
+        name: Optional[str] = None):
     if cfg.is_dense(layer):
         return _swiglu(x, p["mlp_gu_w"], p["mlp_down_w"]), None
-    return moe_ffn(p, x, cfg, live=live, impl=impl)
+    return moe_ffn(p, x, cfg, live=live, impl=impl, name=name)
 
 
 # -- the block, prefill and decode --------------------------------------------

@@ -1074,15 +1074,22 @@ def resolve_mla_paged_impl(
 
 def _mla_paged_kernel(
     slot_ref, block_ref, fetch_ref, len_ref, q_ref, new_ref, *refs,
-    page_size, pages_per_seq, pages_per_block, rank, has_new,
+    page_size, pages_per_seq, pages_per_block, rank, has_new, q_rows=1,
 ):
     """One live (slot, page block) of the absorbed-MLA kernel: the walk,
     the online-softmax carry and the ragged last page are
     :func:`_paged_kernel`'s; a page is ``(page_size, width)`` rows that
     all heads share, scores are float32 over the whole row and the
-    float32 accumulator takes the row's first ``rank`` values."""
+    float32 accumulator takes the row's first ``rank`` values.
+
+    ``q_rows = R > 1``: the slot's ``R`` consecutive query rows (heads
+    of row ``r`` at ``q_ref[0, r * H:(r + 1) * H]``) in the one walk.
+    ``len_ref`` then holds the position of the LAST row; row ``r`` sits
+    at ``last - (R - 1 - r)``, its ``new_ref[0, r]`` is spliced there and
+    its heads see nothing past it."""
     del fetch_ref
     ppb = pages_per_block
+    R = q_rows
     c_refs = refs[:ppb]
     o_ref, acc_ref, m_ref, l_ref = refs[ppb:]
     t = pl.program_id(0)
@@ -1100,23 +1107,32 @@ def _mla_paged_kernel(
     last_page = last // page_size
 
     def attend(page, c_ref, ragged):
-        q = q_ref[0]                      # (H, width), already scaled
+        q = q_ref[0]                      # (R * H, width), already scaled
         rows = c_ref[0]                   # (page_size, width)
         if ragged:
             row = jax.lax.broadcasted_iota(
                 jnp.int32, (page_size, 1), 0) + page * page_size
-            if has_new:
+            if has_new and R == 1:
                 rows = jnp.where(row == last, new_ref[0], rows)
+            elif has_new:
+                for r in range(R):
+                    rows = jnp.where(row == last - (R - 1 - r),
+                                     new_ref[0, r:r + 1, :], rows)
             rows = jnp.where(row <= L, rows, jnp.zeros_like(rows))
         s = jax.lax.dot_general(
             q, rows, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # (H, page_size)
+        )  # (R * H, page_size)
         if ragged:
             pos = jax.lax.broadcasted_iota(
                 jnp.int32, s.shape, 1) + page * page_size
-            s = jnp.where(pos <= L, s, _NEG_INF)
-        m_prev = m_ref[...]                       # (H, 1)
+            if R == 1:
+                s = jnp.where(pos <= L, s, _NEG_INF)
+            else:
+                H = s.shape[0] // R
+                q_row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // H
+                s = jnp.where(pos <= L - (R - 1) + q_row, s, _NEG_INF)
+        m_prev = m_ref[...]                       # (R * H, 1)
         m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
@@ -1125,15 +1141,25 @@ def _mla_paged_kernel(
             p.astype(rows.dtype), rows[:, :rank],
             (((1,), (0,)), ((), ())),
             preferred_element_type=jnp.float32,
-        )  # (H, rank)
+        )  # (R * H, rank)
         m_ref[...] = m_new
 
+    # pages before the first query row's are whole for every row; the
+    # pages from its own to the last row's take the masks
+    first_page = (last_page if R == 1
+                  else jnp.maximum(last - (R - 1), 0) // page_size)
     for i in range(ppb):
         page = j * ppb + i
-        pl.when(page < last_page)(
-            functools.partial(attend, page, c_refs[i], False))
-        pl.when(page == last_page)(
-            functools.partial(attend, page, c_refs[i], True))
+        if R == 1:
+            pl.when(page < last_page)(
+                functools.partial(attend, page, c_refs[i], False))
+            pl.when(page == last_page)(
+                functools.partial(attend, page, c_refs[i], True))
+        else:
+            pl.when(page < first_page)(
+                functools.partial(attend, page, c_refs[i], False))
+            pl.when(jnp.logical_and(page >= first_page, page <= last_page))(
+                functools.partial(attend, page, c_refs[i], True))
 
     @pl.when(j == last_page // ppb)
     def _finalize():
@@ -1141,11 +1167,12 @@ def _mla_paged_kernel(
 
 
 @functools.partial(
-    jax.jit, static_argnames=("rank", "has_new", "interpret", "name")
+    jax.jit,
+    static_argnames=("rank", "has_new", "interpret", "name", "q_rows"),
 )
 def _mla_paged_flash(
     q, pool, page_table, lengths, new_row, *, rank, has_new, interpret,
-    name="_mla_paged_flash",
+    name="_mla_paged_flash", q_rows=1,
 ):
     """Absorbed MLA over the latent pool through the page table.
 
@@ -1155,16 +1182,25 @@ def _mla_paged_flash(
     ``lengths[s]``.  Returns (S, H, rank): ``softmax(q . row) . c`` per
     head, W_UV still to apply.  Work follows the live pages exactly as
     :func:`_paged_flash`'s does.  ``name`` is the kernel's name in a
-    device trace (the selected-row attention runs it under its own)."""
-    S, H, width = q.shape
+    device trace (the selected-row attention runs it under its own).
+
+    ``q_rows = R > 1`` (a step that verifies drafts): ``q`` (S, R * H,
+    width), row-major, ``new_row`` (S, R, width) spliced at ``lengths[s]
+    .. lengths[s] + R - 1``; the heads of row ``r`` see positions ``<=
+    lengths[s] + r``; one walk of the slot's live blocks serves every
+    row.  Returns (S, R * H, rank)."""
+    S, RH, width = q.shape
     _, page_size, _ = pool.shape
     ppseq = page_table.shape[1]
     ppb = latent_block_pages(page_size, ppseq, width, pool.dtype)
     q = q.astype(pool.dtype)
     new = (new_row.astype(pool.dtype) if has_new
-           else jnp.zeros((S, width), pool.dtype)).reshape(S, 1, width)
+           else jnp.zeros((S, q_rows * width), pool.dtype)
+           ).reshape(S, q_rows, width)
+    # the walk ends at the last query row's page
     slot_of, block_of, fetch, lengths, n_live = _live_block_tables(
-        page_table, lengths, page_size, ppb)
+        page_table, lengths if q_rows == 1 else lengths + (q_rows - 1),
+        page_size, ppb)
 
     def page_spec(i):
         return pl.BlockSpec(
@@ -1179,41 +1215,54 @@ def _mla_paged_flash(
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(n_live,),
-        in_specs=[slot_spec(H, width), slot_spec(1, width)]
+        in_specs=[slot_spec(RH, width), slot_spec(q_rows, width)]
         + [page_spec(i) for i in range(ppb)],
-        out_specs=slot_spec(H, rank),
+        out_specs=slot_spec(RH, rank),
         scratch_shapes=[
-            pltpu.VMEM((H, rank), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
-            pltpu.VMEM((H, 1), jnp.float32),
+            pltpu.VMEM((RH, rank), jnp.float32),
+            pltpu.VMEM((RH, 1), jnp.float32),
+            pltpu.VMEM((RH, 1), jnp.float32),
         ],
     )
+    kernel = functools.partial(
+        _mla_paged_kernel, page_size=page_size, pages_per_seq=ppseq,
+        pages_per_block=ppb, rank=rank, has_new=has_new,
+        **({} if q_rows == 1 else {"q_rows": q_rows}))
     return pl.pallas_call(
-        functools.partial(
-            _mla_paged_kernel, page_size=page_size, pages_per_seq=ppseq,
-            pages_per_block=ppb, rank=rank, has_new=has_new,
-        ),
+        kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((S, H, rank), pool.dtype),
+        out_shape=jax.ShapeDtypeStruct((S, RH, rank), pool.dtype),
         interpret=interpret,
         name=name,
     )(slot_of, block_of, fetch, lengths, q, new, *([pool] * ppb))
 
 
-def _mla_gather_attention(q, pool, page_table, lengths, new_row, rank):
+def _mla_gather_attention(q, pool, page_table, lengths, new_row, rank,
+                          q_rows=1):
     """The gather path of :func:`mla_paged_decode_attention`: the slot's
-    rows gathered dense through the table, masked past ``lengths``."""
-    S, H, width = q.shape
+    rows gathered dense through the table, masked past ``lengths`` (the
+    heads of query row ``r`` of ``q_rows``: past ``lengths + r``)."""
+    S, RH, width = q.shape
     rows = jnp.take(pool, page_table, axis=0).reshape(S, -1, width)
     M = rows.shape[1]
     if new_row is not None:
-        at = jnp.minimum(lengths, M - 1)
-        rows = rows.at[jnp.arange(S), at].set(new_row.astype(rows.dtype))
-    valid = jnp.arange(M)[None, :] <= lengths[:, None]
+        for r in range(q_rows):
+            at = jnp.minimum(lengths + r if r else lengths, M - 1)
+            rows = rows.at[jnp.arange(S), at].set(
+                (new_row if q_rows == 1 else new_row[:, r]
+                 ).astype(rows.dtype))
+    last = lengths if q_rows == 1 else lengths + (q_rows - 1)
+    valid = jnp.arange(M)[None, :] <= last[:, None]
     rows = jnp.where(valid[:, :, None], rows, jnp.zeros_like(rows))
     s = jnp.einsum("shw,smw->shm", q.astype(rows.dtype), rows,
                    preferred_element_type=jnp.float32)
-    s = jnp.where(valid[:, None, :], s, _NEG_INF)
+    if q_rows == 1:
+        seen = valid[:, None, :]
+    else:
+        q_pos = lengths[:, None] + jnp.repeat(
+            jnp.arange(q_rows), RH // q_rows)[None, :]      # (S, R * H)
+        seen = jnp.arange(M)[None, None, :] <= q_pos[:, :, None]
+    s = jnp.where(seen, s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     o = jnp.einsum("shm,smc->shc", p.astype(rows.dtype), rows[..., :rank],
                    preferred_element_type=jnp.float32)
@@ -1228,22 +1277,30 @@ def mla_paged_decode_attention(
     rank: int,
     new_row: Optional[jax.Array] = None,
     impl: Optional[str] = None,
+    q_rows: int = 1,
+    name: Optional[str] = None,
 ) -> jax.Array:
     """Single-token absorbed MLA over a latent page pool: slot ``s``
     attends rows ``m <= lengths[s]`` of its pages (``new_row`` first
     written at ``lengths[s]``).  Shapes as :func:`_mla_paged_flash`;
     ``impl`` as :func:`paged_decode_attention` — the kernel, the kernel
     interpreted, or the gather path for a geometry
-    :func:`mla_kernel_constraints` refuses."""
+    :func:`mla_kernel_constraints` refuses.  ``q_rows`` consecutive
+    query rows a slot (a step that verifies drafts) and the kernel's
+    ``name`` in a device trace as :func:`_mla_paged_flash` takes them."""
     impl = resolve_mla_paged_impl(
         impl, pool.shape[1], pool.shape[2], rank, pool.dtype)
+    more = {} if q_rows == 1 else {"q_rows": q_rows}
+    if name is not None:
+        more["name"] = name
     if impl in ("pallas", "pallas_interpret"):
         return _mla_paged_flash(
             q, pool, page_table, lengths, new_row, rank=rank,
             has_new=new_row is not None,
-            interpret=impl == "pallas_interpret",
+            interpret=impl == "pallas_interpret", **more,
         )
-    return _mla_gather_attention(q, pool, page_table, lengths, new_row, rank)
+    return _mla_gather_attention(
+        q, pool, page_table, lengths, new_row, rank, q_rows)
 
 
 # -- sparse selection over a latent page pool, and a window over a ring ------

@@ -345,6 +345,70 @@ def test_decode_pass_dec003_wiring():
     assert any("geometry" in d.message for d in rep2.by_code("DEC003"))
 
 
+def _draft_graph(rows, draft=True, sink=True, own_pool=True):
+    g = decode_graph()
+    tasks = list(g)
+    if draft:
+        tasks.append(Task(
+            "draft", 0.1, 1.0, ["logits"],
+            {"page_table", "cache_k_2" if own_pool else "cache_k_1"},
+            group="draft"))
+        if not sink:
+            tasks.append(Task("after", 0.1, 1.0, ["draft"], set()))
+    g = TaskGraph(tasks)
+    g.rows_per_step = rows
+    return g
+
+
+@pytest.mark.parametrize("kw, says", [
+    (dict(rows=2), None),
+    (dict(rows=1, draft=False), None),
+    (dict(rows=2, draft=False), "0 draft task"),
+    (dict(rows=1), "no draft to verify"),
+    (dict(rows=2, sink=False), "must be the sink"),
+    (dict(rows=2, own_pool=False), "hold a cache pool no layer task reads"),
+])
+def test_decode_pass_dec003_rows_a_step_and_the_draft_task(kw, says):
+    """More than one row a slot a step means drafts, and drafts mean one
+    ``draft`` task — the sink, with a pool of its own — and the reverse."""
+    from distributed_llm_scheduler_tpu.analysis import analyze_decode
+
+    found = [d for d in analyze_decode(_draft_graph(**kw)).by_code("DEC003")]
+    if says is None:
+        assert not found
+    else:
+        (d,) = found
+        assert says in d.message and d.data["rows_per_step"] == kw["rows"]
+
+
+def test_a_family_stepped_with_its_draft_lints_clean_and_budgets_its_rows():
+    """The real builder for ``glm4_lite``: no error on one node, and
+    DEC006's per-segment budget counts two rows a slot a step."""
+    from distributed_llm_scheduler_tpu.analysis import analyze_decode
+    from distributed_llm_scheduler_tpu.frontend.decode_dag import (
+        build_paged_decode_dag,
+    )
+    from distributed_llm_scheduler_tpu.models import model_config
+    from distributed_llm_scheduler_tpu.sched.policies import get_scheduler
+
+    dag = build_paged_decode_dag(model_config("glm4_lite-tiny"), slots=2,
+                                 page_size=8, n_pages=9, pages_per_seq=4)
+    assert dag.rows_per_step == 2
+    cluster = Cluster([DeviceState("n0", 64.0)])
+    s = get_scheduler("greedy").schedule(dag.graph, cluster)
+    rep = analyze_decode(dag.graph, cluster, s)
+    assert rep.ok and not rep.has("DEC003")
+    over = analyze_decode(dag.graph, cluster, s, chunk_tokens=12,
+                          decode_budget=2 * 4 * 1)
+    fits = analyze_decode(dag.graph, cluster, s, chunk_tokens=12,
+                          decode_budget=2 * 4 * dag.rows_per_step)
+
+    def exceeds(rep):
+        return any("exceeds" in d.message for d in rep.by_code("DEC006"))
+
+    assert exceeds(over) and not exceeds(fits)
+
+
 def test_paged_dag_lints_clean_on_one_node():
     """The real paged builder + a single-node schedule must produce no
     errors or warnings from the decode pass (the engine's own gate)."""

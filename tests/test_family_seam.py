@@ -164,8 +164,9 @@ def _tiny(family: str):
 # -- the contract -----------------------------------------------------------------
 
 
-def test_the_toy_is_registered_beside_the_five():
-    assert FAMILIES == ["dots3", "gpt2", "llama", "mixtral", "toy", "xing4"]
+def test_the_toy_is_registered_beside_the_six():
+    assert FAMILIES == ["dots3", "glm4_lite", "gpt2", "llama", "mixtral",
+                        "toy", "xing4"]
 
 
 @pytest.mark.parametrize("family", FAMILIES)
@@ -186,12 +187,21 @@ def test_family_contract(family):
 
     spec = mod.cache_spec(cfg)
     assert models.cache_spec(cfg) == spec
-    assert getattr(cfg, row.layers_field) == spec.n_layers
+    n_main = spec.n_layers - spec.draft_layers
+    assert getattr(cfg, row.layers_field) == n_main
     shapes = mod.param_shapes(cfg)
     for names in (mod.EMBED_PARAMS, mod.HEAD_PARAMS,
                   *(mod.layer_param_names(cfg, i).values()
-                    for i in range(spec.n_layers))):
+                    for i in range(n_main))):
         assert set(names) <= set(shapes), (family, names)
+    # stepped with its own draft module: the seam decides, all or nothing
+    drafts = models.offers(row, *models.DRAFT_FUNCTIONS)
+    assert drafts == (spec.draft_layers > 0) == (models.draft_rows(cfg) > 1)
+    assert drafts or not any(
+        hasattr(mod, n) for n in models.DRAFT_FUNCTIONS)
+    if drafts:
+        assert paged and set(
+            mod.draft_param_names(cfg).values()) <= set(shapes)
 
     def cache_of(dag):
         return {k: (v.shape, v.dtype) for k, v in dag.init_params().items()
@@ -205,13 +215,18 @@ def test_family_contract(family):
             cfg, slots=2, page_size=8, n_pages=5, pages_per_seq=2)
         assert cache_of(dag) == shapes_of(
             spec.init_pools(5, 8, cfg.dtype, slots=2))
-        assert dag.graph.name.startswith(f"{family}paged_{spec.n_layers}l_")
+        assert dag.graph.name.startswith(f"{family}paged_{n_main}l_")
         assert ("active" in dag.input_spec) == getattr(
             mod, "DECODE_TAKES_LIVE", False)
+        R = models.draft_rows(cfg)
+        assert dag.input_spec["ids"].shape == (2, R) and (
+            dag.rows_per_step == dag.graph.rows_per_step == R)
+        assert [t.task_id for t in dag.graph][-1] == (
+            "draft" if drafts else "logits")
     if dense:
         dag = build_decode_dag(cfg, batch=2, step_len=1, max_len=16)
         assert cache_of(dag) == shapes_of(spec.init_slabs(2, 16, cfg.dtype))
-        assert dag.graph.name.startswith(f"{family}dec_{spec.n_layers}l_")
+        assert dag.graph.name.startswith(f"{family}dec_{n_main}l_")
 
 
 def test_family_is_decided_by_type_not_by_class_name():
@@ -238,7 +253,8 @@ def test_family_is_decided_by_type_not_by_class_name():
     ("gpt2", "gpt2"), ("gpt2-medium", "gpt2"), ("gpt2-tiny", "gpt2"),
     ("llama", "llama"), ("llama-8b", "llama"), ("llama-tiny", "llama"),
     ("mixtral-8x7b", "mixtral"), ("mixtral-tiny", "mixtral"),
-    ("xing4-tiny", "xing4"), ("dots3-tiny", "dots3"), ("toy-tiny", "toy")])
+    ("xing4-tiny", "xing4"), ("dots3-tiny", "dots3"),
+    ("glm4_lite-tiny", "glm4_lite"), ("toy-tiny", "toy")])
 def test_variant_names_make_their_familys_config(model, family):
     assert models.family_of_model(model).name == family
     assert models.family_of(models.model_config(model)) == family
@@ -272,8 +288,10 @@ def test_what_each_family_offers():
               if models.offers(rows[f], *models.PAGED_FUNCTIONS)}
     dense = {f for f in rows
              if models.offers(rows[f], *models.CACHED_FUNCTIONS)}
-    assert served == {"gpt2", "xing4", "dots3", "toy"}
+    assert served == {"gpt2", "xing4", "dots3", "glm4_lite", "toy"}
     assert dense == {"gpt2", "llama", "mixtral"}
+    assert {f for f in rows if models.offers(
+        rows[f], *models.DRAFT_FUNCTIONS)} == {"glm4_lite"}
 
 
 @pytest.mark.parametrize("family", ["gpt2", "llama", "xing4"])
