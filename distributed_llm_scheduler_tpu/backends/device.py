@@ -1511,7 +1511,7 @@ class DeviceBackend:
         reps: int = 1,
         rebatch: bool = True,
         planned: Optional[bool] = None,
-        coalesce: Optional[bool] = None,
+        coalesce: bool = True,
         donate: Optional[bool] = None,
         compiled: bool = False,
         fence_rtt: Optional[float] = None,
@@ -1553,8 +1553,8 @@ class DeviceBackend:
         collective-ordering gate; a schedule whose per-node orders admit
         no global collective order raises (COL002) instead of silently
         re-linearizing.  Incompatible with every per-task feature
-        (``profile``/``segments``/``coalesce``/``keep_outputs``/
-        ``ext_outputs``) — see docs/ARCHITECTURE.md's execution ladder
+        (``profile``/``segments``/``keep_outputs``/``ext_outputs``) —
+        see docs/ARCHITECTURE.md's execution ladder
         for when to pick which rung.  ``stream_params`` composes via the
         static stream-safety prover (analysis/stream_pass.py): when
         every node's param union fits its HBM budget (STR001 on all
@@ -1578,21 +1578,21 @@ class DeviceBackend:
         forced off by ``keep_outputs`` (retained outputs must outlive the
         run — passing ``donate=True`` with ``keep_outputs`` raises).
 
-        ``coalesce`` (planned only): launch runs of consecutive
+        ``coalesce`` (the planned path's default; every other path
+        launches as it always did): launch runs of consecutive
         same-device tasks as ONE program each
         (:mod:`.dispatch_plan`, "fused launches"), with
         ``optimization_barrier`` between members so per-task outputs stay
         bit-identical: O(runs) launches a step where the per-task plan
         makes O(tasks), through executables keyed by a launch's
-        *structure*, so every layer wired alike shares one.  The default
-        (``None``) is the code's choice: it fuses the launches whose
-        structure repeats in the plan, unless a task ``fn`` carries host
-        effects (``jax.debug.callback`` and kin, read from each distinct
+        *structure*, so every layer wired alike shares one.  Every span
+        is fused, whether its structure occurs once in the plan or fifty
+        times, unless a task ``fn`` carries host effects
+        (``jax.debug.callback`` and kin, read from each distinct
         ``fn``'s jaxpr once: inside one XLA program an unordered callback
         loses its per-launch ordering) — such a graph keeps per-task
-        launches.  ``False`` forces per-task launches (the parity
-        reference); ``True`` fuses every run, repeated or not, and is the
-        caller's word that no ``fn`` needs per-launch ordering.
+        launches.  ``False`` asks for per-task launches (the parity
+        reference).
         ``keep_outputs`` and ``ext_outputs`` compose: every member is
         then exported.  ``DeviceReport.n_dispatches`` counts the launches.
 
@@ -1703,7 +1703,7 @@ class DeviceBackend:
             incompatible = [
                 name for name, flag in (
                     ("profile", profile),
-                    ("segments", segments), ("coalesce", coalesce),
+                    ("segments", segments),
                     ("keep_outputs", keep_outputs),
                     ("ext_outputs", ext_outputs is not None),
                     ("planned", bool(planned)),
@@ -1723,8 +1723,6 @@ class DeviceBackend:
                 "timing hooks), stream_params (param residency changes "
                 "mid-run), and segments (already fused)"
             )
-        if coalesce and not planned:
-            raise ValueError("coalesce=True requires the planned path")
         if not planned:
             coalesce = False
         if donate and keep_outputs:
@@ -1803,10 +1801,10 @@ class DeviceBackend:
         missing = sorted(graph_params - params.keys())
         if missing:
             raise ValueError(f"params missing for placement: {missing[:5]}")
-        if prep is None and coalesce is None and not self.host_effect_free(
-            graph, params, graph_input, ext_outputs
-        ):
-            coalesce = False
+        if prep is None and coalesce:
+            coalesce = self.host_effect_free(
+                graph, params, graph_input, ext_outputs
+            )
         # obs: explicit trace=/metrics= win; else the DLS_TRACE ambient
         # pair; else None — and every instrumented path below guards on
         # None, so a disabled run records nothing and pays only the checks

@@ -48,10 +48,8 @@ all fixed before the first launch.  This module moves it to plan time:
   wiring by member position, exported positions, donation pattern) and
   takes each member's weights positionally, so layer 7's launch calls
   the executable layer 0's compiled: O(runs) launches a step, O(distinct
-  structures) programs a process.  By default only structures that occur
-  more than once in the plan are fused (a launch nobody repeats would
-  pay a compile for one call a step; its tasks launch singly, as their
-  shared per-``fn`` programs).  The host-effect rule: a task ``fn`` whose
+  structures) programs a process, a structure met once in the plan
+  included.  The host-effect rule: a task ``fn`` whose
   jaxpr carries effects (``jax.debug.callback``, ``io_callback``) loses
   its per-launch ordering inside one XLA program, so ``execute()`` reads
   the effects of each distinct ``fn`` once and keeps such a graph on
@@ -630,12 +628,12 @@ class DispatchPlan:
         placed_params: Dict[Tuple[str, str], Any],
         ext_keys: Tuple[str, ...] = (),
         donate: bool = False,
-        coalesce: Optional[bool] = False,
+        coalesce: bool = False,
         keep_outputs: bool = False,
     ) -> "DispatchPlan":
-        """``coalesce``: ``False`` one launch a task; ``True`` every
-        same-device span one launch; ``None`` only the spans whose
-        structure repeats in this plan (``execute()``'s default)."""
+        """``coalesce``: ``False`` one launch a task; ``True`` one launch
+        a same-device span of :func:`_cut_runs`, whether its structure
+        occurs once in the plan or fifty times (``execute()``'s default)."""
         placement = schedule.placement
         if keep_outputs:
             donate = False  # retained outputs must all outlive the run
@@ -654,7 +652,7 @@ class DispatchPlan:
         # launches: one task each unless coalescing is on.  Coalescing
         # first re-linearizes the dispatch order (per-node order and topo
         # dispatch preserved), then cuts it into same-device spans.
-        if coalesce is not False and alive:
+        if coalesce and alive:
             alive = _relinearize(graph, schedule, alive, set(ext_keys))
             groups = _cut_runs(graph, placement, alive)
         else:
@@ -686,25 +684,6 @@ class DispatchPlan:
             fused.append(
                 launch_structure(graph, members, exports_from(members))
             )
-        if coalesce is None:
-            # the code's own choice: fuse what repeats.  A structure met
-            # once in the plan would compile a program for one call a
-            # step; its tasks launch singly instead, through the per-fn
-            # programs they share with every other layer
-            seen: Dict[Any, int] = {}
-            for f in fused:
-                if f is not None:
-                    seen[f.key] = seen.get(f.key, 0) + 1
-            regrouped: List[List[str]] = []
-            refused: List[Optional[FusedLaunch]] = []
-            for g, f in zip(groups, fused):
-                if f is not None and seen[f.key] == 1:
-                    regrouped.extend([t] for t in g)
-                    refused.extend([None] * len(g))
-                else:
-                    regrouped.append(g)
-                    refused.append(f)
-            groups, fused = regrouped, refused
         exports_of: List[Tuple[str, ...]] = [
             f.exports if f is not None else tuple(g)
             for g, f in zip(groups, fused)

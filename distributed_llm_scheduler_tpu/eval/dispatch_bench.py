@@ -11,9 +11,8 @@ inside the dispatch loop, fence excluded) for four configurations on the
 * ``legacy``          — the per-task ``_run`` loop (``planned=False``)
 * ``planned``         — plan-then-dispatch, one launch a task
                         (``coalesce=False``; donation on where supported)
-* ``coalesce``        — planned + fused multi-task launches, every run
-                        (``coalesce=True``), donation on; ``execute()``'s
-                        default fuses the runs whose structure repeats
+* ``coalesce``        — ``execute()``'s default: planned + fused
+                        multi-task launches, every run, donation on
 * ``coalesce_nodonate`` — planned + coalesced with donation off: the pure
                         dispatch-overhead configuration (donation trades
                         a little host time for peak-memory savings, so it
@@ -123,8 +122,8 @@ def run_dispatch_bench(
     legs = {
         "legacy": dict(planned=False),
         "planned": dict(coalesce=False),
-        "coalesce": dict(coalesce=True),
-        "coalesce_nodonate": dict(coalesce=True, donate=False),
+        "coalesce": dict(),
+        "coalesce_nodonate": dict(donate=False),
     }
     results: Dict[str, Dict[str, Any]] = {}
     for name, kw in legs.items():
@@ -165,7 +164,7 @@ def run_dispatch_bench(
             graph, schedule, params, ids, planned=False, keep_outputs=True
         )
         rc = backend.execute(
-            graph, schedule, params, ids, coalesce=True, keep_outputs=True
+            graph, schedule, params, ids, keep_outputs=True
         )
         bit_identical = set(rl.task_outputs) == set(rc.task_outputs) and all(
             _bit_identical(rl.task_outputs[t], rc.task_outputs[t])

@@ -1224,8 +1224,56 @@ PackPlan pack_plan(const Graph& g, const GroupStats& st) {
   return plan;
 }
 
+// run_chains_through (sched/pack.py): `order` with every chain run to its
+// end — after a task its node goes on with the earliest of its own tasks
+// that reads it and needs nothing the node does not hold by then (its own
+// ordered results, and other nodes' values one of those has read), and only
+// then with what `order` has next.
+std::vector<int32_t> run_chains_through(const Graph& g,
+                                        const std::vector<int32_t>& assign,
+                                        const std::vector<int32_t>& order) {
+  std::vector<int32_t> pos(g.n_tasks, -1);
+  for (size_t i = 0; i < order.size(); ++i) pos[order[i]] = (int32_t)i;
+  std::vector<uint8_t> done(g.n_tasks, 0);
+  std::vector<std::vector<int32_t>> held(g.n_tasks);  // nodes that read it
+  auto holds = [&](int node, int tid) {
+    for (int j = g.dep_off[tid]; j < g.dep_off[tid + 1]; ++j) {
+      int x = g.dep_ids[j];
+      if (assign[x] < 0) continue;
+      if (assign[x] == node ? !done[x]
+                            : std::find(held[x].begin(), held[x].end(),
+                                        node) == held[x].end())
+        return false;
+    }
+    return true;
+  };
+  std::vector<int32_t> out;
+  out.reserve(order.size());
+  for (int head : order) {
+    int tid = head;
+    while (tid >= 0 && !done[tid]) {
+      int node = assign[tid];
+      out.push_back(tid);
+      done[tid] = 1;
+      for (int j = g.dep_off[tid]; j < g.dep_off[tid + 1]; ++j) {
+        int x = g.dep_ids[j];
+        if (assign[x] >= 0 && assign[x] != node) held[x].push_back(node);
+      }
+      int next = -1;
+      for (int k = g.dpt_off[tid]; k < g.dpt_off[tid + 1]; ++k) {
+        int d = g.dpt_ids[k];
+        if (assign[d] != node || done[d] || !holds(node, d)) continue;
+        if (next < 0 || pos[d] < pos[next]) next = d;
+      }
+      tid = next;
+    }
+  }
+  return out;
+}
+
 // GroupPackScheduler.commit: assign per group placement in topo order with
-// the state machine's memory checks, then event-order the execution.
+// the state machine's memory checks, then event-order the execution and run
+// every chain through.
 void pack_commit(Run& run, const std::vector<int32_t>& placed,
                  const int32_t* group_ids, const double* link3,
                  const std::vector<int32_t>& topo) {
@@ -1264,7 +1312,7 @@ void pack_commit(Run& run, const std::vector<int32_t>& placed,
     }
   }
   EventOrder eo = event_order(g, run.assign, topo, link3);
-  run.order = std::move(eo.order);
+  run.order = run_chains_through(g, run.assign, eo.order);
 }
 
 // Group-pack policy (sched/pack.py): non-contiguous LPT packing of groups

@@ -10,10 +10,11 @@ properties that make that trade safe:
 * coalescing may only re-linearize: per-node schedule order and
   topological enqueue order survive, and task outputs stay bit-identical
   to the un-coalesced path (optimization_barrier guarantees this);
-* fused launches are what ``execute()`` does by default: launches of
-  one structure share ONE executable whatever their layer, a ``fn`` with
-  host effects keeps the graph on per-task launches, and the transfer
-  accounting is the per-task plan's;
+* fused launches are what ``execute()`` does by default: one launch a
+  same-device span, whether its structure occurs once in the plan or in
+  every layer; launches of one structure share ONE executable whatever
+  their layer, a ``fn`` with host effects keeps the graph on per-task
+  launches, and the transfer accounting is the per-task plan's;
 * what ``execute()`` derives from its arguments alone is kept on the
   backend between calls (``PreparedCall``) and is never stale: a call
   whose graph, schedule, flags or weights changed runs what a fresh
@@ -29,6 +30,8 @@ from distributed_llm_scheduler_tpu.backends.device import DeviceBackend
 from distributed_llm_scheduler_tpu.backends.dispatch_plan import (
     GRAPH_INPUT,
     DispatchPlan,
+    _cut_runs,
+    _relinearize,
     donation_supported,
 )
 from distributed_llm_scheduler_tpu.frontend.gpt2_dag import build_gpt2_dag
@@ -118,6 +121,14 @@ def _per_node_sequences(plan):
     for st in plan.steps:
         seq.setdefault(st.node_id, []).extend(st.tids)
     return seq
+
+
+def _spans(graph, schedule, backend):
+    order = backend.dispatch_order(graph, schedule)
+    return _cut_runs(
+        graph, schedule.placement,
+        _relinearize(graph, schedule, order, set()),
+    )
 
 
 def test_coalesce_preserves_per_node_order_and_topo(setup):
@@ -309,8 +320,11 @@ def test_default_plan_keeps_transfers_and_per_node_order(deep):
     placed, _ = backend.place_params(dag.graph, schedule, params)
     p_plain = DispatchPlan.build(backend, dag.graph, schedule, order, placed)
     p_fused = DispatchPlan.build(
-        backend, dag.graph, schedule, order, placed, coalesce=None
+        backend, dag.graph, schedule, order, placed, coalesce=True
     )
+    assert [st.tids for st in p_fused.steps] == [
+        st.tids for st in backend._prepared[dag.graph].plan.steps
+    ]   # what the default call above ran
     assert p_fused.transfer_edges == p_plain.transfer_edges
     assert _per_node_sequences(p_fused) == _per_node_sequences(p_plain)
     assert _per_node_sequences(p_fused) == {
@@ -362,9 +376,129 @@ def _default_plan(dag, params, backend, schedule):
     order = backend.dispatch_order(dag.graph, schedule)
     placed, _ = backend.place_params(dag.graph, schedule, params)
     return DispatchPlan.build(
-        backend, dag.graph, schedule, order, placed, coalesce=None,
+        backend, dag.graph, schedule, order, placed, coalesce=True,
         donate=donation_supported(),
     )
+
+
+FUSED_CASES = [(1, "heft"), (4, "pack")]
+
+
+@pytest.fixture(scope="module", params=FUSED_CASES,
+                ids=[f"{p}-{n}dev" for n, p in FUSED_CASES])
+def fused(request):
+    """A graph and backend of this group's own, run once with default
+    arguments: (dag, params, ids, backend, schedule, first report)."""
+    case = _deep(*request.param)
+    dag, params, ids, backend, schedule = case
+    return (*case, backend.execute(
+        dag.graph, schedule, params, ids, keep_outputs=True
+    ))
+
+
+def test_default_plan_fuses_every_span_repeated_or_not(fused):
+    """The default plan is exactly one launch a span of ``_cut_runs``,
+    whether the span's structure occurs once in the plan or in every
+    layer: a span met once is ONE step with all its members, never a
+    launch a task because nobody shares its structure."""
+    from collections import Counter
+
+    dag, _params, _ids, backend, schedule, rep = fused
+    plan = backend._prepared[dag.graph].plan
+    spans = _spans(dag.graph, schedule, backend)
+    assert [st.tids for st in plan.steps] == [tuple(sp) for sp in spans]
+    assert rep.n_dispatches == plan.n_launches == len(spans)
+    assert len(spans) < len(dag.graph.topo_order) // 4
+    assert all(st.group is (len(st.tids) > 1) for st in plan.steps)
+    calls = Counter(id(st.fn) for st in plan.steps if st.group)
+    met_once = [st for st in plan.steps if calls.get(id(st.fn)) == 1]
+    assert met_once, "every structure repeats: the case shows nothing"
+    assert max(len(st.tids) for st in met_once) >= 8
+
+
+def test_default_equals_per_task_plan_bit_for_bit(fused):
+    """Against ``coalesce=False`` on a backend that never fused: every
+    task's output and the logits bit for bit, the same transfer edges and
+    bytes (the per-node order is the test's above, on the same cases)."""
+    dag, params, ids, backend, schedule, rep = fused
+    other = DeviceBackend(backend.cluster)
+    ref = other.execute(
+        dag.graph, schedule, params, ids, coalesce=False, keep_outputs=True
+    )
+    assert ref.n_dispatches == len(dag.graph.topo_order) > rep.n_dispatches
+    assert not other._group_cache
+    assert set(rep.task_outputs) == set(ref.task_outputs)
+    for tid, out in ref.task_outputs.items():
+        assert np.array_equal(
+            np.asarray(out), np.asarray(rep.task_outputs[tid])
+        ), tid
+    assert np.array_equal(_logits(ref), _logits(rep))
+    assert rep.transfer_edges == ref.transfer_edges
+    assert rep.transfer_bytes == ref.transfer_bytes
+
+
+def test_a_second_call_builds_no_group_executable(fused):
+    """A span met once is compiled once a process, not once a call: the
+    next ``execute()`` on the backend hits the kept plan and the gauge of
+    fused executables stands still."""
+    from distributed_llm_scheduler_tpu.obs import process_metrics
+
+    def structures():
+        return process_metrics().snapshot()["gauges"][
+            "compile.group_structures"]["value"]
+
+    dag, params, ids, backend, schedule, first = fused
+    built, gauge = len(backend._group_cache), structures()
+    misses, c0 = backend.jit_cache_misses, _prepared_counts()
+    again = backend.execute(
+        dag.graph, schedule, params, ids, keep_outputs=True, warmup=False
+    )
+    assert _delta(c0) == (1, 0, 0)
+    assert structures() == gauge and len(backend._group_cache) == built
+    assert backend.jit_cache_misses == misses
+    assert again.n_dispatches == first.n_dispatches
+    assert np.array_equal(_logits(again), _logits(first))
+
+
+@pytest.mark.parametrize("n_devices,policy,launches,edges,programs", [
+    (1, "heft", 49, 0, 4), (4, "pack", 196, 392, 6),
+])
+def test_launch_counts_at_the_benchmark_shape(
+        n_devices, policy, launches, edges, programs):
+    """The DAG cells' own step — GPT-2 medium, batch 32 x 512, 8
+    microbatches, 1,561 tasks — planned and never run (no weights): the
+    launches (one a span), transfer edges and fused programs PERF.md and
+    the ledger's ``launches_step`` quote, under ``heft`` on one device and
+    ``pack`` on four (which runs a microbatch through a layer before it
+    takes the next: a span is such a chain, the same program in every
+    layer), donating as the chip does."""
+    import jax.numpy as jnp
+
+    dag = build_gpt2_dag(
+        GPT2Config.medium(dtype=jnp.bfloat16), batch=32, seq_len=512,
+        microbatches=8,
+    )
+    assert len(dag.graph.topo_order) == 1561
+    cluster = Cluster.from_jax_devices(
+        jax.devices()[:n_devices], hbm_cap_gb=15.75
+    )
+    schedule = get_scheduler(policy).schedule(dag.graph, cluster)
+    assert not schedule.failed
+    backend = DeviceBackend(cluster)
+    placed = {
+        (glob, node): None
+        for tid, node in schedule.placement.items()
+        for glob in dag.graph[tid].params_needed
+    }
+    plan = DispatchPlan.build(
+        backend, dag.graph, schedule,
+        backend.dispatch_order(dag.graph, schedule), placed,
+        coalesce=True, donate=True,
+    )
+    assert len(_spans(dag.graph, schedule, backend)) == launches
+    assert (plan.n_launches, plan.transfer_edges) == (launches, edges)
+    assert len(backend._group_cache) == programs
+    assert sum(len(st.tids) for st in plan.steps) == 1561
 
 
 def test_tasks_per_launch_lands_in_the_process_registry(deep):
@@ -770,7 +904,7 @@ STRUCTURE_CHANGES = {
     "ext_keys": lambda s, c, g: (s, {"ext_outputs": {"outside": np.ones(3)}}),
     "keep_outputs": lambda s, c, g: (s, {"keep_outputs": True}),
     "donate": lambda s, c, g: (s, {"donate": not donation_supported()}),
-    "coalesce": lambda s, c, g: (s, {"coalesce": True}),
+    "coalesce": lambda s, c, g: (s, {"coalesce": False}),
     "input_shape": lambda s, c, g: (s, {}),
 }
 
@@ -902,18 +1036,19 @@ def test_a_dead_graph_releases_its_entry_and_its_replicas():
     assert plan() is None and replica() is None
 
 
-@pytest.mark.parametrize("coalesce", [None, False, True])
-def test_no_kept_replica_is_deleted_by_a_donating_run(small, coalesce):
+@pytest.mark.parametrize("kw", [{}, {"coalesce": False}, {"reps": 2}],
+                         ids=["fused", "per_task", "fused_two_reps"])
+def test_no_kept_replica_is_deleted_by_a_donating_run(small, kw):
     if not donation_supported():
         pytest.skip("platform ignores donate_argnums")
     dag, params, ids, backend, schedule = small
     first = backend.execute(
-        dag.graph, schedule, params, ids, donate=True, coalesce=coalesce
+        dag.graph, schedule, params, ids, donate=True, **kw
     )
     for _ in range(2):
         rep = backend.execute(
-            dag.graph, schedule, params, ids, donate=True,
-            coalesce=coalesce, warmup=False,
+            dag.graph, schedule, params, ids, donate=True, warmup=False,
+            **kw,
         )
         entry = backend._prepared[dag.graph]
         assert not any(v.is_deleted() for v in entry.placed.values())

@@ -130,3 +130,82 @@ def test_refine_completes_under_pressure_cliff():
     rr = get_scheduler("roundrobin").schedule(graph, cluster)
     assert len(ref.completed) >= len(rr.completed)
     assert len(ref.completed) > 0
+
+
+def _two_chains(extra_dep=None):
+    """Two three-task chains on n0, a lone value ``r`` on n1."""
+    from distributed_llm_scheduler_tpu import Task, TaskGraph
+
+    deps = {"a0": [], "a1": ["a0"], "a2": ["a1"],
+            "b0": [], "b1": ["b0"], "b2": ["b1"], "r": []}
+    if extra_dep:
+        deps[extra_dep[0]] = deps[extra_dep[0]] + [extra_dep[1]]
+    graph = TaskGraph(
+        [Task(t, 0.01, 1e-3, d, set()) for t, d in deps.items()],
+        name="two_chains",
+    ).freeze()
+    placement = {t: "n1" if t == "r" else "n0" for t in deps}
+    return graph, placement
+
+
+@pytest.mark.parametrize("extra_dep,want", [
+    # lockstep becomes chain after chain
+    (None, ["r", "a0", "a1", "a2", "b0", "b1", "b2"]),
+    # a1 needs r from the other node and nothing on n0 has read r yet:
+    # the chain stops there rather than wait where the order did not
+    (("a1", "r"), ["r", "a0", "b0", "b1", "b2", "a1", "a2"]),
+    # b0 read r first, so n0 holds it when a1 comes up
+    (("b0", "r"), ["r", "a0", "a1", "a2", "b0", "b1", "b2"]),
+], ids=["lockstep", "stops_at_unheld_remote", "held_remote"])
+def test_run_chains_through(extra_dep, want):
+    from distributed_llm_scheduler_tpu.sched.pack import run_chains_through
+
+    graph, placement = _two_chains(extra_dep)
+    order = ["r", "a0", "b0", "a1", "b1", "a2", "b2"]
+    assert run_chains_through(graph, placement, order) == want
+
+
+def test_pack_runs_a_microbatch_through_a_layer_before_the_next():
+    """Placement is what LPT gave and the order is topological; on every
+    device a microbatch's pass through a layer (three tasks in a row) is
+    run to its end before the next microbatch's (the event simulation
+    alone alternates the microbatches a task at a time)."""
+    from distributed_llm_scheduler_tpu import Task, TaskGraph
+    from distributed_llm_scheduler_tpu.sched import pack
+
+    GB = 1024**3
+    tasks = []
+    for m in range(3):
+        prev = []
+        for i in range(4):
+            for part in "abc":
+                tid = f"mb{m}_layer_{i}_{part}"
+                tasks.append(Task(
+                    tid, 0.01, 1e-3, prev, {f"L{i}"},
+                    param_bytes={f"L{i}": GB}, group=f"layer_{i}",
+                ))
+                prev = [tid]
+    graph = TaskGraph(tasks, name="layers_of_three").freeze()
+
+    def lockstep_pairs(through):
+        old, pack.run_chains_through = pack.run_chains_through, through
+        try:
+            s = GroupPackScheduler(link=host_bound_link()).schedule(
+                graph, Cluster.uniform(2, 100.0))
+        finally:
+            pack.run_chains_through = old
+        assert not s.failed
+        seen = set()
+        for tid in s.assignment_order:
+            assert all(d in seen for d in graph[tid].dependencies), tid
+            seen.add(tid)
+        return s.placement, sum(
+            a[:-1] != b[:-1]
+            for tids in s.per_node.values()
+            for a, b in zip(tids, tids[1:]) if a[-1] != "c"
+        )
+
+    placement, broken = lockstep_pairs(pack.run_chains_through)
+    assert broken == 0
+    plain_placement, plain_broken = lockstep_pairs(lambda g, p, order: order)
+    assert plain_placement == placement and plain_broken > 0
