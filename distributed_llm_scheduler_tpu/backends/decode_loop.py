@@ -521,6 +521,13 @@ class PagedDecodeEngine:
         self._chunk_rr = 0
         # extra args of the next ``segment`` span (:meth:`_observe_moe`)
         self._seg_span_args: Dict[str, Any] = {}
+        # what stands between two deliveries to a decoding slot
+        # (:meth:`_observe_interval`): prefill programs enqueued since the
+        # last segment's dispatch and their real tokens, that segment's
+        # readback stamp, and the slots it left still decoding
+        self._prefill_ahead = [0, 0]
+        self._seg_prev_t1 = 0.0
+        self._seg_carry = np.zeros((slots,), bool)
         # drain seam (fleet failover): while set, submit() hard-rejects
         # new work — already-queued and in-flight requests keep running
         # to completion, which is what lets a sick replica empty itself
@@ -573,8 +580,8 @@ class PagedDecodeEngine:
         self._tokens: Dict[Any, list] = {}
         self.results: Dict[Any, Any] = {}
         # compile-class bookkeeping is split in two: `_prefill_cache` is
-        # the PER-RUN seen-set (cleared by reset(), so the
-        # ``decode.jit_cache_entries`` series a reused engine emits is
+        # the PER-RUN seen-set (cleared by reset(), so the soak
+        # sampler's ``jit.prefill_entries`` series of a reused engine is
         # identical to a fresh build's — the soak determinism gate) and
         # `_prefill_store` holds the compiled executables themselves,
         # which survive reset() so warm reruns never pay XLA again
@@ -674,10 +681,10 @@ class PagedDecodeEngine:
         The segment, prefill, and scatter executables are keyed to this
         instance (``_prefill_store``), so benchmarks warm up once, reset,
         and re-time the exact workload without paying compilation again.
-        The per-run seen-set ``_prefill_cache`` IS cleared: the
-        ``decode.jit_cache_entries`` series counts compile classes seen
-        *this run*, and a reused engine must emit the same series a fresh
-        build would."""
+        The per-run seen-set ``_prefill_cache`` IS cleared: the soak
+        sampler's ``jit.prefill_entries`` series (its length) counts
+        compile classes seen *this run*, and a reused engine must give
+        the same series a fresh build would."""
         from ..models.kv_pages import TRASH_PAGE
 
         self._prefill_cache = {}
@@ -719,6 +726,8 @@ class PagedDecodeEngine:
         self._chunk_state = {}
         self._chunk_rr = 0
         self._draining = False
+        self._prefill_ahead = [0, 0]
+        self._seg_carry = np.zeros((self.slots,), bool)
         # fresh request log per run (benches reset between reps); the
         # flight ring deliberately survives — it is the always-on
         # last-N record across runs
@@ -992,14 +1001,7 @@ class PagedDecodeEngine:
         ends.  Queued and in-flight requests are commitments — they keep
         admitting and decoding to completion, so a draining engine
         empties itself instead of wedging its queue.  Idempotent."""
-        if not self._draining:
-            self._draining = True
-            self.metrics.counter("decode.drains_begun").inc()
-            if self.tracer is not None:
-                self.tracer.instant(
-                    "drain_begin", track="decode", cat="decode",
-                    t=self._clock(),
-                )
+        self._draining = True
 
     def end_drain(self) -> None:
         """Re-open submission without a restart (``reset()`` and
@@ -1053,17 +1055,6 @@ class PagedDecodeEngine:
         ).set(used)
         if self.tracer is not None:
             self.tracer.counter("decode.page_pool_occupancy_pages", used)
-
-    def _emit_jit_cache_size(self) -> None:
-        """Sample the prefill compile-class cache size per tick — the
-        soak doctor's recompile-churn series: a healthy engine closes
-        its compile classes during warmup and this gauge goes flat."""
-        entries = len(self._prefill_cache)
-        self.metrics.gauge(
-            "decode.jit_cache_entries", unit="entries"
-        ).set(entries)
-        if self.tracer is not None:
-            self.tracer.counter("decode.jit_cache_entries", entries)
 
     def summary(self) -> Dict[str, Any]:
         """Engine-state snapshot: slot/queue/pool headroom at this
@@ -1171,6 +1162,17 @@ class PagedDecodeEngine:
             return ids
         return ids, jnp.asarray(nxt, jnp.int32)
 
+    def _prefill_enqueued(self, tokens: int) -> None:
+        """Right before every prefill dispatch (whole wave, stitched
+        tail or chunk), with its REAL token count: the program stands
+        between the decoding slots' last delivery and their next, so it
+        counts toward the next segment's ``prefill_programs_ahead`` /
+        ``prefill_tokens_ahead``; and the virtual-time seam is charged."""
+        self._prefill_ahead[0] += 1
+        self._prefill_ahead[1] += tokens
+        if self.prefill_time_charge is not None:
+            self.prefill_time_charge(tokens)
+
     # -- prefill + page scatter (ONE call per admission ROUND; one
     # compiled class per (prompt length, batch size)) ----------------------
     def _ring_args(self, slots) -> tuple:
@@ -1208,8 +1210,7 @@ class PagedDecodeEngine:
         # encounter of a compile class this run counts, warm or not
         if key not in self._prefill_cache:
             self._prefill_cache[key] = fn
-        if self.prefill_time_charge is not None:
-            self.prefill_time_charge(b * P)
+        self._prefill_enqueued(b * P)
         nxt = None
         if self.rows_per_step > 1:   # the ids shifted by one, then the
             nxt = self._np.full((b, P), -1, self._np.int32)   # program's own
@@ -1270,8 +1271,7 @@ class PagedDecodeEngine:
         if key not in self._prefill_cache:
             self._prefill_cache[key] = fn
         tail = prompt_ids[:, h * self.page_size:]
-        if self.prefill_time_charge is not None:
-            self.prefill_time_charge(b * (P - h * self.page_size))
+        self._prefill_enqueued(b * (P - h * self.page_size))
         first, self.pools = fn(
             self.weights, tail, self.pools,
             jnp.asarray(shared_rows), jnp.asarray(wt_rows),
@@ -1324,8 +1324,7 @@ class PagedDecodeEngine:
             self._prefill_store[key] = fn
         if key not in self._prefill_cache:
             self._prefill_cache[key] = fn
-        if self.prefill_time_charge is not None:
-            self.prefill_time_charge(int(creal))
+        self._prefill_enqueued(int(creal))
         first, self.pools = fn(
             self.weights, self._with_next(ids_chunk, nxt_chunk), self.pools,
             jnp.asarray(pt_row, jnp.int32),
@@ -1487,12 +1486,17 @@ class PagedDecodeEngine:
                     st["ids"][0, base + 1:base + C + 1], -1)[:C]
             # a DISPATCH span: it ends when the chunk program is enqueued
             # (no sync is added to close it "when ready"); the chunk's
-            # device time is the device trace's (prefill_dev_us_tok)
+            # device time is the device trace's (prefill_dev_us_tok), and
+            # the host pays it inside the NEXT ``segment`` span, whose
+            # readback waits behind it (or, for a prompt's last chunk,
+            # in ``_fold_chunked``): ``seq`` is that segment's ordinal,
+            # and the segment counts the chunk in ``prefill_programs_ahead``
             ev = None
             if self.tracer is not None:
                 ev = self.tracer.begin(
                     "prefill_chunk", track="decode", cat="decode",
                     rid=str(st["rid"]), base=base, tokens=C,
+                    seq=self.segments_run,
                 )
             with annotate("prefill_chunk"):
                 first = self._chunk_prefill(
@@ -1745,18 +1749,19 @@ class PagedDecodeEngine:
             # unconditional read: t_pf0 is each batched request's
             # admission timestamp in the lifecycle log
             t_pf0 = self._clock()
-            all_ids = jnp.concatenate(
-                [ids for _, ids, _, _ in batch], axis=0
-            )
-            if sharing and h0 > 0:
-                first = self._prefill_scatter_shared(
-                    all_ids, h0, sh_rows, wt_rows
+            with annotate("prefill"):
+                all_ids = jnp.concatenate(
+                    [ids for _, ids, _, _ in batch], axis=0
                 )
-            else:
-                first = self._prefill_scatter(
-                    all_ids, pt_rows, free_slots[:len(batch)])
-            # (b,) first tokens, or (b, 2) with the first drafts
-            first = self._np.asarray(first).reshape(len(batch), -1)
+                if sharing and h0 > 0:
+                    first = self._prefill_scatter_shared(
+                        all_ids, h0, sh_rows, wt_rows
+                    )
+                else:
+                    first = self._prefill_scatter(
+                        all_ids, pt_rows, free_slots[:len(batch)])
+                # (b,) first tokens, or (b, 2) with the first drafts
+                first = self._np.asarray(first).reshape(len(batch), -1)
             # first token exists NOW (the prefill's readback): the
             # admission timestamp is each request's TTFT anchor
             t_adm = self._clock()
@@ -1812,9 +1817,6 @@ class PagedDecodeEngine:
             if sharing:
                 self.metrics.counter("decode.prefix_shared_pages").inc(
                     h0 * len(batch)
-                )
-                self.metrics.counter("decode.prefix_tokens_skipped").inc(
-                    h0 * self.page_size * len(batch)
                 )
             if ev_wave is not None:
                 self.tracer.end(ev_wave)
@@ -1904,13 +1906,13 @@ class PagedDecodeEngine:
         self.lengths[slot] = 0
         self.cur_tok[slot] = 0
         self.remaining[slot] = 0
+        self._seg_carry[slot] = False
         self._slot_req[slot] = None
         self._slot_pages[slot] = []
         self._first_tok_t.pop(rid, None)
         t_pre = self._clock()
         for rl in self._reqlogs:
             rl.preempt(rid, t_pre, cause)
-        self.metrics.counter("decode.requests_preempted").inc()
         if self.tracer is not None:
             self.tracer.instant(
                 "preempt", track="decode", cat="decode", t=t_pre,
@@ -1987,6 +1989,7 @@ class PagedDecodeEngine:
             ).observe(share)
         with annotate("segment"):
             t_sg0 = self._clock()
+            ahead, self._prefill_ahead = self._prefill_ahead, [0, 0]
             toks, self.pools, *stats = self._seg(
                 self.weights, self.pools, self.page_table, self.lengths,
                 self.cur_tok, self.remaining,
@@ -2000,8 +2003,42 @@ class PagedDecodeEngine:
             # became host-visible at this readback (lifecycle-log
             # delivery events)
             t_sg1 = self._clock()
+        self._observe_interval(owed, steps_ran, ahead, t_sg1)
         with annotate("fold"):
             return self._fold_segment(emitted, owed, t_sg0, t_sg1)
+
+    def _observe_interval(self, owed, steps_ran: int, ahead,
+                          t_sg1: float) -> None:
+        """What stood between the previous delivery to this segment's
+        *continuing* slots and this one, onto the ``segment`` span and —
+        tracer or not — into the engine's registry and the process-wide
+        one.  A continuing slot decoded in the previous dispatched
+        segment too and still holds the same request, which received
+        nothing in between: the two readback stamps' difference is the
+        gap between two deliveries as its user saw it.  ``ahead``:
+        prefill programs (and their real tokens) enqueued since the
+        previous segment's dispatch; the device runs them first, so this
+        segment's readback waited for them.  A segment after an empty
+        engine, or whose decoding slots are all new, has no period."""
+        programs, tokens = ahead
+        continuing = int((self._seg_carry & (owed > 0)).sum())
+        args = {"seq": self.segments_run, "steps_ran": steps_ran,
+                "continuing": continuing,
+                "prefill_programs_ahead": programs,
+                "prefill_tokens_ahead": tokens}
+        if continuing:
+            args["period_s"] = t_sg1 - self._seg_prev_t1
+            period_ms = args["period_s"] * 1e3
+            for reg in (self.metrics, process_metrics()):
+                reg.histogram("decode.step_interval_ms", unit="ms").observe(
+                    period_ms / max(steps_ran, 1))
+                reg.histogram("decode.seg_period_ms", unit="ms").observe(
+                    period_ms)
+                reg.counter("decode.segments_continuing").inc()
+                if programs:
+                    reg.counter("decode.segments_behind_prefill").inc()
+        self._seg_prev_t1 = t_sg1
+        self._seg_span_args.update(args)
 
     def _emitted(self, toks, owed):
         """What a segment's readback gave each slot: ``(tokens of slot s
@@ -2130,6 +2167,7 @@ class PagedDecodeEngine:
         # emitted, the current token the last one emitted
         self.lengths = self.lengths + ran
         self.remaining = owed - ran
+        self._seg_carry = self.remaining > 0
         delivered = retired = 0
         for s in range(self.slots):
             rid = self._slot_req[s]
@@ -2152,7 +2190,6 @@ class PagedDecodeEngine:
         self.metrics.counter("decode.tokens_delivered").inc(delivered)
         self._emit_pool_occupancy()
         self._emit_queue_depth()
-        self._emit_jit_cache_size()
         if self.tracer is not None:
             self.tracer.complete(
                 "fold", t_sg1, self._clock(), track="decode", cat="decode",

@@ -34,11 +34,11 @@ Design constraints, in order:
   ``execute`` (``dispatch_order``, ``place_params``, ``plan_build``,
   ``warmup``, ``fence_rtt``, ``stage_input``, ``dispatch_loop``,
   ``fence``, ``report``) and of the serving tick (``admit``,
-  ``prefill_chunk``, ``segment``, ``fold``, ``idle_wait``) are entered
-  as ``jax.profiler.TraceAnnotation("dls/<name>")`` (:func:`annotate`),
-  tracer or not: a profile taken by anyone shows the host phases on the
-  device trace's own clock.  Nothing per launch or per token is
-  annotated.
+  ``prefill``, ``prefill_chunk``, ``segment``, ``fold``, ``idle_wait``)
+  are entered as ``jax.profiler.TraceAnnotation("dls/<name>")``
+  (:func:`annotate`), tracer or not: a profile taken by anyone shows
+  the host phases on the device trace's own clock.  Nothing per launch
+  or per token is annotated.
 
 Track names are free-form strings; by convention ``"host"``
 (:data:`HOST_TRACK`) carries the execute phases and every device node_id
