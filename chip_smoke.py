@@ -630,8 +630,9 @@ def phase_kernels(meter: CompileMeter, interpret: bool = False,
 
     def latent_cases():
         """The Xing4.0 block's three kernels (``models/xing4.py``,
-        ``ops/attention._mla_paged_flash``) compiled at a tiny aligned
-        geometry against their gather / XLA paths: whether they start."""
+        ``ops/attention._mla_paged_flash``) and the prefill's
+        ``_mla_chunk_flash`` compiled at a tiny aligned geometry against
+        their gather / XLA paths: whether they start."""
         from distributed_llm_scheduler_tpu.models import xing4 as X
 
         rng = np.random.RandomState(8)
@@ -657,7 +658,23 @@ def phase_kernels(meter: CompileMeter, interpret: bool = False,
              "hca_alpha": jnp.full((3,), 0.5, jnp.float32),
              "hca_b": arr(24, dtype=jnp.float32)}
         streams = arr(N, 4, h)
+        # the prefill's expanded-MLA kernel through the family's own
+        # entry: 32 queries at 560 over a cache of 640 rows, two key
+        # blocks, the last one ragged
+        ccfg = X.Xing4Config.tiny(
+            n_heads=H, kv_lora_rank=rank, qk_nope_head_dim=64,
+            qk_rope_head_dim=64, v_head_dim=128, dtype=dt)
+        cq = (arr(1, 32, H, 64, scale=0.3), arr(1, 32, H, 64, scale=0.3))
+        crows = arr(1, 640, W)
+        cp = {"kv_b_w": arr(rank, H * (64 + 128), scale=0.1)}
+
+        def chunk_attn(impl):
+            return X.mla_expanded_attention(
+                cp, *cq, crows, jnp.int32(560), ccfg, impl)
+
         return [
+            _kernel_case("mla_chunk_flash_bf16", lambda: chunk_attn(kern),
+                         lambda: chunk_attn("xla")),
             _kernel_case(
                 "mla_paged_flash_ps16_bf16",
                 lambda: A._mla_paged_flash(

@@ -33,6 +33,7 @@ from ..core.schedule import Schedule
 from ..models import cache_spec, draft_rows, module_of
 from ..obs import process_metrics
 from ..obs.trace import annotate
+from ..ops.attention import chunk_attention_log
 
 
 def compose_step_fn(
@@ -587,6 +588,9 @@ class PagedDecodeEngine:
         # which survive reset() so warm reruns never pay XLA again
         self._prefill_cache: Dict[Any, Any] = {}
         self._prefill_store: Dict[Any, Any] = {}
+        # the compile classes of it whose expanded-MLA attention traced
+        # to the chunk kernel (:meth:`_first_tokens_logged`)
+        self._prefill_attn_kernel: set = set()
         self.segments_run = 0
         # obs: the tracer is optional (ambient under DLS_TRACE, else off);
         # the registry always exists so benches can snapshot per-engine
@@ -1153,6 +1157,30 @@ class PagedDecodeEngine:
             [jnp.argmax(last, axis=-1), jnp.argmax(draft, axis=-1)],
             axis=-1).astype(jnp.int32), cache
 
+    def _prefill_dispatched(self, key) -> None:
+        """Right after every prefill dispatch of compile class ``key``
+        (traced by then): one whose attention is the chunk kernel counts
+        into ``decode.prefill_attn_kernel_programs``, to be read beside
+        ``decode.chunk_waves`` and ``decode.admission_waves``."""
+        if key in self._prefill_attn_kernel:
+            self.metrics.counter("decode.prefill_attn_kernel_programs").inc()
+
+    def _first_tokens_logged(self, key):
+        """:meth:`_first_tokens` for the prefill program of compile
+        class ``key``, noting WHILE THE PROGRAM IS TRACED what its chunk
+        attention resolved to (:func:`...ops.attention.
+        chunk_attention_log`; the choice is the shape's, made at trace
+        time): the class is in ``_prefill_attn_kernel`` iff every
+        expanded-MLA attention in it is the kernel."""
+        def fwd(*args):
+            with chunk_attention_log() as impls:
+                out = self._first_tokens(*args)
+            if impls and "xla" not in impls:
+                self._prefill_attn_kernel.add(key)
+            return out
+
+        return fwd
+
     def _with_next(self, ids, nxt):
         """What a prefill program takes as its ids: ``ids`` itself, or —
         for a family stepped with its draft module — the pair with
@@ -1194,7 +1222,7 @@ class PagedDecodeEngine:
         key = (P, b, self.attention_impl)
         fn = self._prefill_store.get(key)
         if fn is None:
-            spec, fwd = self.cache, self._first_tokens
+            spec, fwd = self.cache, self._first_tokens_logged(key)
             cap, cfg = self.capacity, self.config
             ppseq, ps = self.pages_per_seq, self.page_size
 
@@ -1219,6 +1247,7 @@ class PagedDecodeEngine:
             self.weights, self._with_next(prompt_ids, nxt), self.pools,
             jnp.asarray(pt_rows), *self._ring_args(slots)
         )
+        self._prefill_dispatched(key)
         return first
 
     def _prefill_scatter_shared(
@@ -1253,7 +1282,7 @@ class PagedDecodeEngine:
         key = ("shared", P, h, b, self.attention_impl)
         fn = self._prefill_store.get(key)
         if fn is None:
-            spec, fwd = self.cache, self._first_tokens
+            spec, fwd = self.cache, self._first_tokens_logged(key)
             cap, cfg = self.capacity, self.config
             ppseq, ps = self.pages_per_seq, self.page_size
             pre = h * ps
@@ -1276,6 +1305,7 @@ class PagedDecodeEngine:
             self.weights, tail, self.pools,
             jnp.asarray(shared_rows), jnp.asarray(wt_rows),
         )
+        self._prefill_dispatched(key)
         return first
 
     # -- chunked prefill (co-scheduled with decode segments) ---------------
@@ -1309,7 +1339,7 @@ class PagedDecodeEngine:
         key = ("chunk", self.chunk_tokens, 1, self.attention_impl)
         fn = self._prefill_store.get(key)
         if fn is None:
-            spec, fwd = self.cache, self._first_tokens
+            spec, fwd = self.cache, self._first_tokens_logged(key)
             cap, cfg = self.capacity, self.config
             ps = self.page_size
 
@@ -1330,6 +1360,7 @@ class PagedDecodeEngine:
             jnp.asarray(pt_row, jnp.int32),
             jnp.int32(base), jnp.int32(creal), *self._ring_args((slot,)),
         )
+        self._prefill_dispatched(key)
         return first
 
     def _admit_chunked(self, s: int) -> None:

@@ -51,6 +51,7 @@ from ..ops.attention import (
     kth_largest_mask,
     lane_width,
     latent_window_attention,
+    mla_chunk_attention,
 )
 from .xing4 import (
     _swiglu,
@@ -404,53 +405,70 @@ def _kv_block(rows: int) -> int:
 
 
 def expanded_attention(p, q_nope, q_rope, rows, mask_of, n_blocks, kb: int,
-                       a: AttnDims):
-    """Expanded MLA of a chunk's queries ``q_*`` (b, T, H, .) over ``rows``
-    (b, M, width): K and V rebuilt from the latents ``kb`` rows at a
-    time, block ``j`` under ``mask_of(j)`` (b or 1, T, kb) bool, blocks
-    ``[0, n_blocks)`` (the count may be data), an online-softmax carry in
-    float32.  Returns (b, T, H, dv)."""
+                       a: AttnDims, impl=None, pos0=0, window=None,
+                       keys_before=None, mask=None):
+    """Expanded MLA of a chunk's queries ``q_*`` (b, T, H, .) at
+    positions ``pos0 + t`` over ``rows`` (b, M, width) — from position 0,
+    or ``keys_before`` rows ahead of the chunk and the chunk's — under
+    the layer's mask: the last ``window`` positions (none: every earlier
+    one) and the selection ``mask`` (b, T, M) bool.  Through
+    :func:`...ops.attention.mla_chunk_attention`: the kernel where the
+    shape takes it, else the loop below (kept here: its lowered text is
+    pinned by ``tests/fixtures/serving_lowered_sha256.json``) — K and V
+    rebuilt from the latents ``kb`` rows at a time, block ``j`` under
+    ``mask_of(j)`` (b or 1, T, kb) bool (the same mask, a block at a
+    time), blocks ``[0, n_blocks)`` (the count may be data), an
+    online-softmax carry in float32.  Returns (b, T, H, dv)."""
     b, T, H, _ = q_nope.shape
     rank, dr, dv = a.kv_lora_rank, a.qk_rope_head_dim, a.v_head_dim
     w = p["kv_b_w"].reshape(rank, H, a.qk_nope_head_dim + dv)
     w_uk, w_uv = w[..., :a.qk_nope_head_dim], w[..., a.qk_nope_head_dim:]
-    qn = (q_nope.astype(jnp.float32) * a.softmax_scale).astype(q_nope.dtype)
-    qr = (q_rope.astype(jnp.float32) * a.softmax_scale).astype(q_rope.dtype)
-    low = jnp.finfo(jnp.float32).min
 
-    def body(j, carry):
-        m, l, acc = carry
-        blk = jax.lax.dynamic_slice_in_dim(rows, j * kb, kb, axis=1)
-        c, k_r = blk[..., :rank], blk[..., rank:rank + dr]
-        k_nope = jnp.einsum("bmc,chd->bmhd", c, w_uk)
-        v = jnp.einsum("bmc,chd->bmhd", c, w_uv)
-        s = (jnp.einsum("bthd,bmhd->bhtm", qn, k_nope,
-                        preferred_element_type=jnp.float32)
-             + jnp.einsum("bthd,bmd->bhtm", qr, k_r,
-                          preferred_element_type=jnp.float32))
-        ok = mask_of(j)[:, None]
-        s = jnp.where(ok, s, low)
-        m_new = jnp.maximum(m, s.max(-1))
-        alpha = jnp.exp(m - m_new)
-        pr = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
-        l = l * alpha + pr.sum(-1)
-        acc = acc * alpha[..., None] + jnp.einsum(
-            "bhtm,bmhd->bhtd", pr.astype(v.dtype), v,
-            preferred_element_type=jnp.float32)
-        return m_new, l, acc
+    def xla_loop():
+        qn = (q_nope.astype(jnp.float32) * a.softmax_scale).astype(
+            q_nope.dtype)
+        qr = (q_rope.astype(jnp.float32) * a.softmax_scale).astype(
+            q_rope.dtype)
+        low = jnp.finfo(jnp.float32).min
 
-    init = (jnp.full((b, H, T), low, jnp.float32),
-            jnp.zeros((b, H, T), jnp.float32),
-            jnp.zeros((b, H, T, dv), jnp.float32))
-    _, l, acc = jax.lax.fori_loop(0, n_blocks, body, init)
-    return (acc / l[..., None]).astype(q_nope.dtype).transpose(0, 2, 1, 3)
+        def body(j, carry):
+            m, l, acc = carry
+            blk = jax.lax.dynamic_slice_in_dim(rows, j * kb, kb, axis=1)
+            c, k_r = blk[..., :rank], blk[..., rank:rank + dr]
+            k_nope = jnp.einsum("bmc,chd->bmhd", c, w_uk)
+            v = jnp.einsum("bmc,chd->bmhd", c, w_uv)
+            s = (jnp.einsum("bthd,bmhd->bhtm", qn, k_nope,
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("bthd,bmd->bhtm", qr, k_r,
+                              preferred_element_type=jnp.float32))
+            ok = mask_of(j)[:, None]
+            s = jnp.where(ok, s, low)
+            m_new = jnp.maximum(m, s.max(-1))
+            alpha = jnp.exp(m - m_new)
+            pr = jnp.where(ok, jnp.exp(s - m_new[..., None]), 0.0)
+            l = l * alpha + pr.sum(-1)
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "bhtm,bmhd->bhtd", pr.astype(v.dtype), v,
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        init = (jnp.full((b, H, T), low, jnp.float32),
+                jnp.zeros((b, H, T), jnp.float32),
+                jnp.zeros((b, H, T, dv), jnp.float32))
+        _, l, acc = jax.lax.fori_loop(0, n_blocks, body, init)
+        return (acc / l[..., None]).astype(q_nope.dtype).transpose(0, 2, 1, 3)
+
+    return mla_chunk_attention(
+        q_nope, q_rope, w_uk, w_uv, rows, pos0, scale=a.softmax_scale,
+        rank=rank, xla_loop=xla_loop, window=window,
+        keys_before=keys_before, mask=mask, impl=impl)
 
 
 # -- the two attentions over a chunk (prefill) -----------------------------------
 
 
 def _full_prefill_attention(p, xn, rows_c, rows_i, pos0, b, T,
-                            cfg: Dots3Config, a: AttnDims):
+                            cfg: Dots3Config, a: AttnDims, impl=None):
     """A full layer over a chunk: the chunk's latent rows and indexer
     keys written at ``pos0``, every query's index scores over the rows
     it may see, the exact ``index_topk`` selection as a mask, expanded
@@ -483,14 +501,14 @@ def _full_prefill_attention(p, xn, rows_c, rows_i, pos0, b, T,
         p, q_nope.reshape(b, T, a.n_heads, -1),
         q_rope.reshape(b, T, a.n_heads, -1), rows_c,
         lambda j: jax.lax.dynamic_slice_in_dim(picked, j * kb, kb, axis=2),
-        live, kb, a)
+        live, kb, a, impl, pos0=pos0, mask=picked)
     o = o.reshape(b * T, a.n_heads, -1).astype(jnp.float32) * head_gate(
         p, xn)[:, :, None]
     return o.astype(xn.dtype).reshape(b * T, -1), rows_c, rows_i
 
 
 def _sliding_prefill_attention(p, xn, ring, pos0, last, b, T,
-                               cfg: Dots3Config, a: AttnDims):
+                               cfg: Dots3Config, a: AttnDims, impl=None):
     """A sliding layer over a chunk: the ``sliding_window - 1`` rows
     before the chunk read out of the ring (before the chunk overwrites
     any), expanded MLA over those and the chunk's own under the window,
@@ -520,7 +538,8 @@ def _sliding_prefill_attention(p, xn, ring, pos0, last, b, T,
     o = expanded_attention(
         p, q_nope.reshape(b, T, a.n_heads, -1),
         q_rope.reshape(b, T, a.n_heads, -1), keys, mask_of,
-        keys.shape[1] // kb, kb, a)
+        keys.shape[1] // kb, kb, a, impl, pos0=pos0,
+        window=cfg.sliding_window, keys_before=back)
     o = o.reshape(b * T, a.n_heads, -1).astype(jnp.float32) * head_gate(
         p, xn)[:, :, None]
     keep = jnp.logical_and(t <= last, t > last - R)
@@ -560,11 +579,11 @@ def prefill_layer(p, x, cache, pos0, last, cfg: Dots3Config, layer: int,
     xn = rms_norm(xf, p["attn_norm_g"], cfg.rms_eps)
     if cfg.is_full(layer):
         o, rows_c, rows_i = _full_prefill_attention(
-            p, xn, cache["c"], cache["i"], pos0, b, T, cfg, a)
+            p, xn, cache["c"], cache["i"], pos0, b, T, cfg, a, impl)
         cache = {"c": rows_c, "i": rows_i}
     else:
         o, ring = _sliding_prefill_attention(
-            p, xn, cache["w"], pos0, last, b, T, cfg, a)
+            p, xn, cache["w"], pos0, last, b, T, cfg, a, impl)
         cache = {"w": ring}
     xf = xf + o @ p["o_w"]
     y, _ = ffn(p, rms_norm(xf, p["ffn_norm_g"], cfg.rms_eps), cfg, layer,

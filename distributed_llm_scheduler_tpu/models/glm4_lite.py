@@ -271,7 +271,7 @@ def _prefill_block(p, x, rows, pos0, cfg: Glm4LiteConfig, dense: bool,
         rows, row.reshape(b, T, -1).astype(rows.dtype), pos0, axis=1)
     o = mla_expanded_attention(
         p, q_nope.reshape(b, T, cfg.n_heads, -1),
-        q_rope.reshape(b, T, cfg.n_heads, -1), new_rows, pos0, cfg)
+        q_rope.reshape(b, T, cfg.n_heads, -1), new_rows, pos0, cfg, impl)
     xf = xf + o.reshape(b * T, -1) @ p["o_w"]
     y, _ = _ffn(p, rms_norm(xf, p["ffn_norm_g"], cfg.rms_eps), cfg, dense,
                 impl=impl)

@@ -51,6 +51,7 @@ from jax.experimental.pallas import tpu as pltpu
 
 from ..ops.attention import (
     lane_width,
+    mla_chunk_attention,
     mla_paged_decode_attention,
     resolve_attention_impl,
 )
@@ -439,12 +440,15 @@ def _kv_b_split(p, cfg: Xing4Config):
     return w[..., :cfg.qk_nope_head_dim], w[..., cfg.qk_nope_head_dim:]
 
 
-def mla_expanded_attention(p, q_nope, q_rope, rows, pos0, cfg: Xing4Config):
+def mla_expanded_attention(p, q_nope, q_rope, rows, pos0, cfg: Xing4Config,
+                           impl: Optional[str] = None):
     """Expanded MLA of a chunk over its sequence's cached rows.
 
     ``q_*`` (b, T, H, .) sit at positions ``pos0 + t``; ``rows`` (b, cap,
-    width) already hold the chunk's own rows.  K and V are rebuilt from
-    the latents :data:`PREFILL_KV_BLOCK` rows at a time and only for the
+    width) already hold the chunk's own rows.  Through
+    :func:`...ops.attention.mla_chunk_attention`: the kernel where the
+    shape takes it, else the loop below — K and V rebuilt from the
+    latents :data:`PREFILL_KV_BLOCK` rows at a time and only for the
     blocks a query can see (the trip count is data), with an
     online-softmax carry in float32.  Returns (b, T, H * dv)."""
     b, T, H, dn = q_nope.shape
@@ -453,39 +457,45 @@ def mla_expanded_attention(p, q_nope, q_rope, rows, pos0, cfg: Xing4Config):
     kb = PREFILL_KV_BLOCK if cap % PREFILL_KV_BLOCK == 0 else cap
     w_uk, w_uv = _kv_b_split(p, cfg)
     scale = cfg.softmax_scale
-    qn = (q_nope.astype(jnp.float32) * scale).astype(q_nope.dtype)
-    qr = (q_rope.astype(jnp.float32) * scale).astype(q_rope.dtype)
-    q_pos = pos0 + jnp.arange(T, dtype=jnp.int32)
 
-    def body(j, carry):
-        m, l, acc = carry
-        blk = jax.lax.dynamic_slice_in_dim(rows, j * kb, kb, axis=1)
-        c, k_r = blk[..., :rank], blk[..., rank:rank + dr]
-        k_nope = jnp.einsum("bmc,chd->bmhd", c, w_uk)
-        v = jnp.einsum("bmc,chd->bmhd", c, w_uv)
-        s = (jnp.einsum("bthd,bmhd->bhtm", qn, k_nope,
-                        preferred_element_type=jnp.float32)
-             + jnp.einsum("bthd,bmd->bhtm", qr, k_r,
-                          preferred_element_type=jnp.float32))
-        k_pos = j * kb + jnp.arange(kb, dtype=jnp.int32)
-        s = jnp.where(k_pos[None, :] <= q_pos[:, None], s,
-                      jnp.finfo(jnp.float32).min)
-        m_new = jnp.maximum(m, s.max(-1))
-        alpha = jnp.exp(m - m_new)
-        pr = jnp.exp(s - m_new[..., None])
-        l = l * alpha + pr.sum(-1)
-        acc = acc * alpha[..., None] + jnp.einsum(
-            "bhtm,bmhd->bhtd", pr.astype(v.dtype), v,
-            preferred_element_type=jnp.float32)
-        return m_new, l, acc
+    def xla_loop():
+        qn = (q_nope.astype(jnp.float32) * scale).astype(q_nope.dtype)
+        qr = (q_rope.astype(jnp.float32) * scale).astype(q_rope.dtype)
+        q_pos = pos0 + jnp.arange(T, dtype=jnp.int32)
 
-    init = (jnp.full((b, H, T), jnp.finfo(jnp.float32).min, jnp.float32),
-            jnp.zeros((b, H, T), jnp.float32),
-            jnp.zeros((b, H, T, dv), jnp.float32))
-    live = jnp.minimum((pos0 + T + kb - 1) // kb, cap // kb)
-    _, l, acc = jax.lax.fori_loop(0, live, body, init)
-    out = (acc / l[..., None]).astype(q_nope.dtype)
-    return out.transpose(0, 2, 1, 3).reshape(b, T, H * dv)
+        def body(j, carry):
+            m, l, acc = carry
+            blk = jax.lax.dynamic_slice_in_dim(rows, j * kb, kb, axis=1)
+            c, k_r = blk[..., :rank], blk[..., rank:rank + dr]
+            k_nope = jnp.einsum("bmc,chd->bmhd", c, w_uk)
+            v = jnp.einsum("bmc,chd->bmhd", c, w_uv)
+            s = (jnp.einsum("bthd,bmhd->bhtm", qn, k_nope,
+                            preferred_element_type=jnp.float32)
+                 + jnp.einsum("bthd,bmd->bhtm", qr, k_r,
+                              preferred_element_type=jnp.float32))
+            k_pos = j * kb + jnp.arange(kb, dtype=jnp.int32)
+            s = jnp.where(k_pos[None, :] <= q_pos[:, None], s,
+                          jnp.finfo(jnp.float32).min)
+            m_new = jnp.maximum(m, s.max(-1))
+            alpha = jnp.exp(m - m_new)
+            pr = jnp.exp(s - m_new[..., None])
+            l = l * alpha + pr.sum(-1)
+            acc = acc * alpha[..., None] + jnp.einsum(
+                "bhtm,bmhd->bhtd", pr.astype(v.dtype), v,
+                preferred_element_type=jnp.float32)
+            return m_new, l, acc
+
+        init = (jnp.full((b, H, T), jnp.finfo(jnp.float32).min, jnp.float32),
+                jnp.zeros((b, H, T), jnp.float32),
+                jnp.zeros((b, H, T, dv), jnp.float32))
+        live = jnp.minimum((pos0 + T + kb - 1) // kb, cap // kb)
+        _, l, acc = jax.lax.fori_loop(0, live, body, init)
+        return (acc / l[..., None]).astype(q_nope.dtype).transpose(0, 2, 1, 3)
+
+    out = mla_chunk_attention(
+        q_nope, q_rope, w_uk, w_uv, rows, pos0, scale=scale, rank=rank,
+        xla_loop=xla_loop, impl=impl)
+    return out.reshape(b, T, H * dv)
 
 
 def mla_absorbed_query(p, q_nope, q_rope, cfg: Xing4Config):
@@ -696,7 +706,8 @@ def prefill_layer(p, X, rows, pos0, cfg: Xing4Config, layer: int, impl=None):
             rows, row.reshape(b, T, -1).astype(rows.dtype), pos0, axis=1)
         o = mla_expanded_attention(
             p, q_nope.reshape(b, T, cfg.n_heads, -1),
-            q_rope.reshape(b, T, cfg.n_heads, -1), new_rows, pos0, cfg)
+            q_rope.reshape(b, T, cfg.n_heads, -1), new_rows, pos0, cfg,
+            impl)
         return o.reshape(b * T, -1) @ p["o_w"], new_rows
 
     Xf = X.reshape(b * T, n, h)

@@ -1609,6 +1609,296 @@ def kth_largest_mask(scores, allowed, k: int):
         tied, jnp.cumsum(tied, axis=-1, dtype=jnp.int32) <= room[..., None]))
 
 
+# -- expanded MLA over a prefill chunk ---------------------------------------
+#
+# A chunk's queries (b, T, H, .) over the latent rows ``[c | k_r | 0]`` of
+# their sequence: K and V of a key block are rebuilt from the block's
+# latents with W_UK / W_UV, scored, masked, and folded into an
+# online-softmax carry.  Written in plain XLA (the loops the model files
+# keep: ``xing4.mla_expanded_attention``, ``dots3.expanded_attention``)
+# every (heads, T, block) score tile crosses HBM several times an
+# iteration; the kernel keeps it in VMEM.  One algorithm, sized by what
+# the call observes (heads 20-128, nope 128 / 192, value 128 / 256, rank
+# 512 / 1,024), with the mask the layer type supplies: position bounds
+# computed in the kernel, and optionally an explicit selection streamed as
+# int8 tiles beside the keys.
+
+#: rows of a query tile and of a key block at most; the key block is the
+#: kernel's own tile, from row 0 of the keys whatever the chunk, so a
+#: chunked prompt's rows are a whole-prompt run's
+_CHUNK_Q_TILE = 512
+_CHUNK_KV_BLOCK = 512
+
+_chunk_impl_log: Optional[list] = None
+
+
+class chunk_attention_log:
+    """``with chunk_attention_log() as impls``: what every
+    :func:`mla_chunk_attention` call traced inside resolved to
+    (``"xla"`` / ``"pallas"`` / ``"pallas_interpret"``), in call order:
+    how an engine learns, while one of its prefill programs is traced,
+    whether that program's attention is the kernel.  (A class, not
+    ``contextlib``: an import line at the top of this file would move
+    every kernel above by a line, and Mosaic's payload carries source
+    lines — every program holding one would miss the compile cache.)"""
+
+    def __enter__(self) -> list:
+        global _chunk_impl_log
+        self._outer, _chunk_impl_log = _chunk_impl_log, []
+        return _chunk_impl_log
+
+    def __exit__(self, *exc) -> None:
+        global _chunk_impl_log
+        _chunk_impl_log = self._outer
+
+
+def mla_chunk_constraints(
+    q_tokens: int, nope_dim: int, rope_dim: int, v_dim: int, rank: int,
+    row_width: int, dtype: Any = jnp.float32,
+) -> list:
+    """Tiling rules for the COMPILED chunk kernel, in the manner of
+    :func:`mla_kernel_constraints` (empty = eligible; ``auto`` takes the
+    XLA loop otherwise, an explicit ``"pallas"`` raises).  The number of
+    keys, heads and sequences is free: a last key block may be ragged."""
+    sublane = _sublane_rows(dtype)
+    out = []
+    if q_tokens % sublane:
+        out.append(
+            f"q_tokens {q_tokens} is not a multiple of the {sublane}-row "
+            f"sublane tile of a {jnp.dtype(dtype).name} query tile")
+    if rank % 128 or not 0 < rank + rope_dim <= row_width:
+        out.append(
+            f"latent rank {rank} is not a positive multiple of the "
+            f"128-lane tile with its {rope_dim} rotary values inside the "
+            f"{row_width}-wide row")
+    for name, dim in (("nope", nope_dim), ("rope", rope_dim)):
+        if dim % 64:
+            out.append(
+                f"{name} head dim {dim} is not a multiple of half a "
+                "128-lane tile (a contraction the MXU pads)")
+    if v_dim % 128:
+        out.append(
+            f"value head dim {v_dim} is not a multiple of the 128-lane "
+            "tile of the accumulator and the output")
+    return out
+
+
+def resolve_mla_chunk_impl(
+    impl: Optional[str], q_tokens: int, nope_dim: int, rope_dim: int,
+    v_dim: int, rank: int, row_width: int, dtype: Any,
+) -> str:
+    """What :func:`mla_chunk_attention` runs at this shape
+    (:func:`resolve_attention_impl`'s rule; interpret mode has no
+    tiling)."""
+    return resolve_attention_impl(
+        impl,
+        lambda i: i == "pallas_interpret" or not mla_chunk_constraints(
+            q_tokens, nope_dim, rope_dim, v_dim, rank, row_width, dtype),
+    )
+
+
+def _chunk_heads(n_heads: int) -> int:
+    """Heads a grid step computes: enough that a step's matmuls hide its
+    fixed cost and the key block's DMA, few enough that the per-head
+    operands and carries stay a few MB of VMEM."""
+    return next(g for g in (4, 2, 1) if n_heads % g == 0)
+
+
+def _mla_chunk_kernel(
+    pos_ref, qn_ref, qr_ref, wk_ref, wv_ref, rows_ref, *refs,
+    rank, rope_dim, window, n_keys, has_mask,
+):
+    """One (sequence, head group, query tile, key block).
+
+    ``pos_ref`` = [position of query row 0, position of key row 0];
+    ``qn_ref`` / ``qr_ref`` (1, G, tq, dn / dr), scaled; ``wk_ref`` /
+    ``wv_ref`` (G, rank, dn / dv); ``rows_ref`` (1, kb, width); with
+    ``has_mask`` an int8 ``mask_ref`` (1, tq, kb) next; then ``o_ref``
+    (1, G, tq, dv) and the float32 carry ``acc`` (G, tq, dv), ``m``,
+    ``l`` (G, tq, 1).  A query at ``q_pos`` sees the key at ``k_pos``
+    iff ``k_pos <= q_pos`` (and, with ``window``, ``k_pos > q_pos -
+    window`` and ``k_pos >= 0``: a ring's rows from before position 0
+    are nobody's) and the selection allows it.  bf16 (the rows' dtype)
+    into the MXU, float32 out of it; scores, mask, max, sum and the
+    probabilities never leave VMEM."""
+    if has_mask:
+        mask_ref, o_ref, acc_ref, m_ref, l_ref = refs
+    else:
+        o_ref, acc_ref, m_ref, l_ref = refs
+    G, tq = qn_ref.shape[1], qn_ref.shape[2]
+    kb = rows_ref.shape[1]
+    i, j = pl.program_id(2), pl.program_id(3)
+    q0 = pos_ref[0] + i * tq
+    k0 = pos_ref[1] + j * kb
+
+    @pl.when(j == 0)
+    def _init():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+        m_ref[...] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+
+    # a block wholly after the tile's last query, or wholly before the
+    # window of its first, leaves the carry as it is: not computed
+    seen = k0 <= q0 + (tq - 1)
+    if window is not None:
+        seen = jnp.logical_and(seen, k0 + (kb - 1) > q0 - window)
+
+    @pl.when(seen)
+    def _attend():
+        blk = rows_ref[0]                                  # (kb, width)
+        q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (tq, kb), 0)
+        col = jax.lax.broadcasted_iota(jnp.int32, (tq, kb), 1)
+        k_pos = k0 + col
+        ok = k_pos <= q_pos
+        if window is not None:
+            ok = jnp.logical_and(ok, jnp.logical_and(
+                k_pos > q_pos - window, k_pos >= 0))
+        if n_keys % kb:       # the last block is ragged: no row past it
+            ok = jnp.logical_and(ok, j * kb + col < n_keys)
+            row = j * kb + jax.lax.broadcasted_iota(jnp.int32, (kb, 1), 0)
+            blk = jnp.where(row < n_keys, blk, jnp.zeros_like(blk))
+        if has_mask:
+            ok = jnp.logical_and(ok, mask_ref[0].astype(jnp.int32) != 0)
+        c, k_r = blk[:, :rank], blk[:, rank:rank + rope_dim]
+        nt = (((1,), (1,)), ((), ()))
+        for h in range(G):
+            k_n = jnp.dot(c, wk_ref[h], preferred_element_type=jnp.float32
+                          ).astype(blk.dtype)              # (kb, dn)
+            v = jnp.dot(c, wv_ref[h], preferred_element_type=jnp.float32
+                        ).astype(blk.dtype)                # (kb, dv)
+            s = (jax.lax.dot_general(
+                    qn_ref[0, h], k_n, nt,
+                    preferred_element_type=jnp.float32)
+                 + jax.lax.dot_general(
+                    qr_ref[0, h], k_r, nt,
+                    preferred_element_type=jnp.float32))   # (tq, kb)
+            s = jnp.where(ok, s, _NEG_INF)
+            m_prev = m_ref[h]                              # (tq, 1)
+            m_new = jnp.maximum(m_prev, s.max(axis=1, keepdims=True))
+            alpha = jnp.exp(m_prev - m_new)
+            # a row that may see nothing here keeps its carry
+            p = jnp.where(ok, jnp.exp(s - m_new), 0.0)
+            l_ref[h] = l_ref[h] * alpha + p.sum(axis=1, keepdims=True)
+            acc_ref[h] = acc_ref[h] * alpha + jnp.dot(
+                p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+            m_ref[h] = m_new
+
+    @pl.when(j == pl.num_programs(3) - 1)
+    def _finalize():
+        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("rank", "window", "q_tile", "kv_block", "interpret"))
+def _mla_chunk_flash(qn, qr, w_uk, w_uv, rows, pos0, key_pos0, mask, *,
+                     rank, window, q_tile, kv_block, interpret):
+    """Expanded MLA of scaled queries ``qn`` (b, T, H, dn) / ``qr`` (b,
+    T, H, dr) at positions ``pos0 + t`` over ``rows`` (b, M, width) at
+    positions ``key_pos0 + m``; ``w_uk`` (rank, H, dn), ``w_uv`` (rank,
+    H, dv); ``mask`` None or (b, T, M) bool.  Only the key blocks up to
+    the last query's are walked (the grid's last axis is data), and of
+    those a query tile computes the ones its rows can see.  Returns (b,
+    T, H, dv) in the rows' dtype."""
+    b, T, H, dn = qn.shape
+    dr, dv = qr.shape[-1], w_uv.shape[-1]
+    M, width = rows.shape[1], rows.shape[2]
+    dt = rows.dtype
+    tq, kb, G = min(q_tile, T), min(kv_block, M), _chunk_heads(H)
+    nq, nk = -(-T // tq), -(-M // kb)
+    pos = jnp.stack([jnp.asarray(pos0, jnp.int32),
+                     jnp.asarray(key_pos0, jnp.int32)])
+    live = jnp.clip((pos[0] + (T - 1) - pos[1]) // kb + 1, 1, nk)
+
+    def block_of(i, j, pos):
+        """The key block step ``(i, j)`` holds: ``j`` held inside what
+        tile ``i`` computes, so a step that computes nothing fetches
+        nothing new."""
+        first = pos[0] + i * tq - pos[1]       # tile's first query, as a row
+        hi = (first + (tq - 1)) // kb
+        lo = 0 if window is None else jnp.maximum(first - window + 1, 0) // kb
+        return jnp.clip(j, lo, jnp.minimum(hi, nk - 1))
+
+    heads = lambda d: pl.BlockSpec(
+        (1, G, tq, d), lambda s, g, i, j, pos: (s, g, i, 0))
+    weight = lambda d: pl.BlockSpec(
+        (G, rank, d), lambda s, g, i, j, pos: (g, 0, 0))
+    in_specs = [
+        heads(dn), heads(dr), weight(dn), weight(dv),
+        pl.BlockSpec((1, kb, width),
+                     lambda s, g, i, j, pos: (s, block_of(i, j, pos), 0)),
+    ]
+    args = [qn.astype(dt).transpose(0, 2, 1, 3),
+            qr.astype(dt).transpose(0, 2, 1, 3),
+            w_uk.astype(dt).transpose(1, 0, 2),
+            w_uv.astype(dt).transpose(1, 0, 2), rows]
+    if mask is not None:
+        in_specs.append(pl.BlockSpec(
+            (1, tq, kb),
+            lambda s, g, i, j, pos: (s, i, block_of(i, j, pos))))
+        args.append(jnp.broadcast_to(mask, (b, T, M)).astype(jnp.int8))
+    out = pl.pallas_call(
+        functools.partial(
+            _mla_chunk_kernel, rank=rank, rope_dim=dr, window=window,
+            n_keys=M, has_mask=mask is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(b, H // G, nq, live),
+            in_specs=in_specs,
+            out_specs=heads(dv),
+            scratch_shapes=[
+                pltpu.VMEM((G, tq, dv), jnp.float32),
+                pltpu.VMEM((G, tq, 1), jnp.float32),
+                pltpu.VMEM((G, tq, 1), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((b, H, T, dv), dt),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 << 20),
+        name="_mla_chunk_flash",
+    )(pos, *args)
+    return out.transpose(0, 2, 1, 3)
+
+
+def mla_chunk_attention(
+    q_nope, q_rope, w_uk, w_uv, rows, pos0, *, scale: float, rank: int,
+    xla_loop, window: Optional[int] = None,
+    keys_before: Optional[int] = None, mask=None,
+    impl: Optional[str] = None,
+):
+    """Expanded MLA of a prefill chunk: the one entry every latent
+    family's prefill goes through.
+
+    ``q_nope`` (b, T, H, dn) and rotated ``q_rope`` (b, T, H, dr) sit at
+    positions ``pos0 + t`` (``pos0`` may be traced); ``rows`` (b, M,
+    width) hold ``[c | k_r | 0]``, the chunk's own among them: a cache
+    from position 0, or with ``keys_before`` that many rows ahead of the
+    chunk and then the chunk's (what a ring held, read out in order);
+    ``w_uk`` (rank, H, dn) and ``w_uv`` (rank, H, dv) rebuild K and V.
+    The mask is the layer's: causal, or with ``window`` the last
+    ``window`` positions and nothing before position 0, and under
+    ``mask`` (b or 1, T, M) bool only what it allows.
+    ``impl`` as :func:`mla_paged_decode_attention`: the kernel, the
+    kernel interpreted, or — off the TPU, or for a shape
+    :func:`mla_chunk_constraints` refuses — ``xla_loop()``, the caller's
+    own plain-XLA loop of the same arithmetic (kept in the model files,
+    whose lowered text is pinned).  Returns (b, T, H, dv)."""
+    T, dn = q_nope.shape[1], q_nope.shape[3]
+    impl = resolve_mla_chunk_impl(
+        impl, T, dn, q_rope.shape[3], w_uv.shape[2], rank, rows.shape[2],
+        rows.dtype)
+    if _chunk_impl_log is not None:
+        _chunk_impl_log.append(impl)
+    if impl == "xla":
+        return xla_loop()
+    qn = (q_nope.astype(jnp.float32) * scale).astype(q_nope.dtype)
+    qr = (q_rope.astype(jnp.float32) * scale).astype(q_rope.dtype)
+    key_pos0 = 0 if keys_before is None else pos0 - keys_before
+    return _mla_chunk_flash(
+        qn, qr, w_uk, w_uv, rows, pos0, key_pos0, mask, rank=rank,
+        window=window, q_tile=_CHUNK_Q_TILE, kv_block=_CHUNK_KV_BLOCK,
+        interpret=impl == "pallas_interpret").astype(q_nope.dtype)
+
 
 def gqa_mha(
     q: jax.Array,

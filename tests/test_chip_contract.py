@@ -635,3 +635,93 @@ def test_aot_serving_segment_compiles_for_v5e_without_weights(v5e):
         int(np.prod(v.shape)) * v.dtype.itemsize for v in weights.values())
     code = compiled.memory_analysis().generated_code_size_in_bytes
     assert code < weight_bytes / 2, (code, weight_bytes)
+
+
+# b, T, H, nope, rope, value, rank, row width, keys, window, selection:
+# the three long-context cells' published widths and served geometry
+_MLA_CHUNK = {
+    "dots3-full-selected": (1, 512, 128, 128, 64, 128, 512, 640, 17920,
+                            None, True),
+    "dots3-sliding-window": (1, 512, 64, 192, 64, 128, 1024, 1152, 1024,
+                             513, False),
+    "xing4-causal": (1, 512, 32, 128, 64, 128, 512, 640, 17408, None,
+                     False),
+    "glm-causal-two-prompts": (2, 512, 20, 192, 64, 256, 512, 640, 6272,
+                               None, False),
+}
+
+
+def _score_tiles(text, heads_keys):
+    """float32 (or predicate) buffers of heads x 512 queries x a key
+    block in compiled HLO: what the XLA loop writes to HBM an iteration."""
+    import re
+
+    return sorted({m.group(0) for H, kb in heads_keys for m in re.finditer(
+        rf"(f32|pred)\[(1,)?{H},512,{kb}\]", text)})
+
+
+@pytest.mark.parametrize("case", sorted(_MLA_CHUNK))
+def test_aot_mla_chunk_kernel_compiles_for_v5e(v5e, case):
+    """The prefill's expanded-MLA kernel at each served shape: Mosaic
+    takes the tiles (192-wide and 64-wide contractions, a ragged last key
+    block at 6,272 rows, the int8 selection, the grid's data-dependent
+    last axis) inside 64 MB of VMEM, and no score tile is a buffer."""
+    from distributed_llm_scheduler_tpu.ops import attention as A
+
+    b, T, H, dn, dr, dv, rank, width, M, window, selected = _MLA_CHUNK[case]
+    bf = jnp.bfloat16
+    assert not A.mla_chunk_constraints(T, dn, dr, dv, rank, width, bf)
+    args = [v5e((b, T, H, dn), bf), v5e((b, T, H, dr), bf),
+            v5e((rank, H, dn), bf), v5e((rank, H, dv), bf),
+            v5e((b, M, width), bf), v5e((), jnp.int32), v5e((), jnp.int32)]
+    if selected:
+        args.append(v5e((b, T, M), jnp.bool_))
+
+    def call(qn, qr, w_uk, w_uv, rows, pos0, key_pos0, mask=None):
+        return A._mla_chunk_flash(
+            qn, qr, w_uk, w_uv, rows, pos0, key_pos0, mask, rank=rank,
+            window=window, q_tile=A._CHUNK_Q_TILE,
+            kv_block=A._CHUNK_KV_BLOCK, interpret=False)
+
+    text = jax.jit(call).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in text and "_mla_chunk_flash" in text
+    assert not _score_tiles(text, [(H, 512), (H, 1024), (H, M)])
+
+
+def test_aot_dots3_chunk_program_holds_no_score_tile(v5e, monkeypatch):
+    """A whole chunk program at dots3-note-prev's published widths, one
+    layer of each kind (full + dense MLP, full + experts, sliding), 512
+    tokens into a cache of 17,920 rows: on the TPU ``auto`` resolves both
+    attentions to the kernel by shape, and the compiled program holds no
+    float32 buffer of heads x 512 x key block — the loop's wrote
+    f32[1,128,512,512] and f32[1,64,512,1024] (~15 s)."""
+    from pathlib import Path
+
+    from distributed_llm_scheduler_tpu.models import dots3
+    from distributed_llm_scheduler_tpu.ops import attention as A
+
+    hf = json.loads((Path(__file__).resolve().parents[1] / "benchmark"
+                     / "configs" / "dots3-note-prev-ep8.json").read_text())
+    hf = dict(hf, num_hidden_layers=3, layer_types=hf["layer_types"][:3])
+    cfg = dots3.Dots3Config.from_hf(hf, dtype=jnp.bfloat16, ring_rows=768)
+    assert [cfg.is_full(i) for i in range(3)] == [True, True, False]
+    weights = {k: v5e(s, dt) for k, (s, dt) in dots3.param_shapes(cfg).items()}
+    cache = jax.tree_util.tree_map(
+        lambda x: v5e(x.shape, x.dtype),
+        jax.eval_shape(lambda: dots3.init_cache(cfg, 1, 17920)))
+    tiles = [(128, 512), (64, 1024), (64, 512)]
+
+    def chunk(impl):
+        return jax.jit(lambda w, ids, cache, pos0, row: (
+            dots3.forward_cached_row(w, ids, cache, pos0, cfg, row,
+                                     impl=impl))).lower(
+            weights, v5e((1, 512), jnp.int32), cache, v5e((), jnp.int32),
+            v5e((), jnp.int32))
+
+    monkeypatch.setattr(A, "_auto_impl", lambda: "pallas")
+    with A.chunk_attention_log() as impls:
+        text = chunk(None).compile().as_text()
+    assert impls == ["pallas"] * 3
+    assert "_mla_chunk_flash" in text and not _score_tiles(text, tiles)
+    # the criterion bites: the XLA loop's lowered program names them
+    assert re.search(r"tensor<1x128x512x512xf32>", chunk("xla").as_text())

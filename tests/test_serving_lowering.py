@@ -12,6 +12,12 @@ did not mean to.  The text is ``jit(...).lower(...).as_text()`` — no
 source locations, so it does not follow line numbers — of the decode
 segment, the whole-prompt prefill and the chunk program of each family's
 tiny variant, under the gather path and under the interpreted kernels.
+
+PR 39 regenerated four of the eighteen on its own tree: the whole-prompt
+and chunk programs of ``xing4-tiny`` and ``dots3-tiny`` under
+``pallas_interpret``, whose expanded MLA became ``_mla_chunk_flash``.
+Every segment, every ``gpt2-tiny`` program and every ``xla`` program is
+the digest taken on PR 32's tree.
 """
 
 from __future__ import annotations

@@ -394,14 +394,23 @@ def test_gpt2_served_tokens_are_the_parents(chunk, sharing):
     assert {k: [int(t) for t in v] for k, v in out.items()} == GOLDEN
 
 
-def test_chip_smoke_probes_the_latent_kernels():
-    """``chip_smoke.py``'s kernels phase runs the three kernels against
-    their gather / XLA paths (interpreted here, compiled on the chip)."""
+PROBED = ("mla_paged_flash_ps16_bf16", "moe_experts_bf16", "hc_maps_bf16",
+          "mla_chunk_flash_bf16")
+
+
+@pytest.fixture(scope="module")
+def smoke_probes():
     import chip_smoke as cs
 
     ph = cs.phase_kernels(cs.CompileMeter(), interpret=True, n_head=4,
                           head_dim=16, flash_T=32)
-    probes = {c["name"]: c for c in ph["probe"]}
-    for name in ("mla_paged_flash_ps16_bf16", "moe_experts_bf16",
-                 "hc_maps_bf16"):
-        assert probes[name]["ok"], probes[name]
+    return {c["name"]: c for c in ph["probe"]}
+
+
+@pytest.mark.parametrize("name", PROBED)
+def test_chip_smoke_probes_the_latent_kernels(smoke_probes, name):
+    """``chip_smoke.py``'s kernels phase runs the Xing4.0 block's three
+    kernels and the prefill's chunk kernel against their gather / XLA
+    paths (interpreted here, compiled on the chip)."""
+    assert smoke_probes[name]["ok"], smoke_probes[name]
+
