@@ -191,7 +191,8 @@ def analyze_decode(
     # DEC005: fused-kernel eligibility of the pool geometry --------------
     # a kv pool is stored (n_pages, page_size, n_kv_heads * head_dim)
     # (``models/kv_pages.CacheSpec``); the paged builder stamps the
-    # head_dim that splits its row on the graph.  A latent pool has no
+    # head_dim that splits its row, and the query-head counts that read
+    # it, on the graph.  A latent pool has no
     # heads and goes by its own rules (``mla_kernel_constraints``).
     pool_spec = None
     hd = getattr(graph, "kv_head_dim", None)
@@ -206,9 +207,13 @@ def analyze_decode(
 
             _n_pages, page_size, width = pool_spec.shape
             n_kv = width // hd   # DEC006 below reads these too
-            violated = paged_kernel_constraints(
-                page_size, hd, n_kv, dtype=pool_spec.dtype
-            )
+            # every query-head count that reads the rows (a family whose
+            # layers differ in them stamps each): the group mapping's rule
+            violated = list(dict.fromkeys(
+                v for hq in getattr(graph, "kv_q_heads", None) or (None,)
+                for v in paged_kernel_constraints(
+                    page_size, hd, n_kv, n_q_heads=hq, dtype=pool_spec.dtype)
+            ))
             if violated:
                 rep.add(
                     "DEC005",

@@ -902,16 +902,16 @@ class PagedDecodeEngine:
     ) -> int:
         """Free-list pages admission must find for this request NOW:
         the first chunk only when it admits chunked (later chunks alloc
-        lazily per segment), the fresh-tail footprint otherwise.  The
+        lazily per segment; more than the pool has free where taking
+        them would leave the slots mid-prefill no order to finish in,
+        :meth:`_safe_after`), the fresh-tail footprint otherwise.  The
         serving frontend's backlog check calls this so its headroom
         arithmetic matches the engine allocator's."""
         from ..models.kv_pages import pages_needed
 
         P = int(prompt_ids.shape[1])
         if self.chunk_eligible(P):
-            return pages_needed(
-                min(self.chunk_tokens, P), self.page_size
-            )
+            return self._chunked_need(P, max_new_tokens)
         return self.fresh_pages_needed(prompt_ids, max_new_tokens)
 
     def is_prefilling(self, rid: Any) -> bool:
@@ -1466,7 +1466,7 @@ class PagedDecodeEngine:
                 self._slot_pages[s]
             )
             if need > 0:
-                if not self.pool.can_alloc(need):
+                if not self._safe_after(need, s):
                     self.metrics.counter("decode.chunk_stalls").inc()
                     if self.tracer is not None:
                         # the counter TOTAL rides the ring so the
@@ -1655,8 +1655,8 @@ class PagedDecodeEngine:
             if self.chunk_eligible(int(P)):
                 # long prompt: claim a slot + first-chunk pages only and
                 # prefill one chunk per segment (no whole-prompt wave)
-                if pages_needed(
-                    min(self.chunk_tokens, int(P)), self.page_size
+                if self._chunked_need(
+                    int(P), self._queue[0][2]
                 ) > self.pool.free_pages:
                     self._trace_queue_block("page_pool")
                     break  # backpressure: head waits for frees
@@ -2116,9 +2116,11 @@ class PagedDecodeEngine:
         family's routing counts (an array, or ``stats["moe"]``) and a
         sparse-selection family's rows (``stats["dsa"]``, (steps, full
         layers, 2) = (latent rows the decoding slots' attention read,
-        rows those slots hold)).  Any other named array, and what
-        :meth:`_emitted` read for it (``probe``), is the
-        ``stats_probe``'s, if one is set."""
+        rows those slots hold)) or a window-layer family's
+        (``stats["attn"]``, (steps, layers, 2) = (rows the decoding
+        slots' attention read in the full layers, in the window
+        layers)).  Any other named array, and what :meth:`_emitted` read
+        for it (``probe``), is the ``stats_probe``'s, if one is set."""
         np = self._np
         if not isinstance(stats, dict):
             stats = {"moe": stats}
@@ -2134,11 +2136,19 @@ class PagedDecodeEngine:
                     reg.histogram(
                         "dsa.selected_share", unit="ratio"
                     ).observe(float(read / held))
+        if "attn" in stats:
+            full, ring = np.asarray(stats["attn"]).reshape(-1, 2).sum(axis=0)
+            args.update(rows_full=float(full), rows_window=float(ring))
+            if full + ring:
+                for reg in (self.metrics, process_metrics()):
+                    reg.histogram(
+                        "attn.full_row_share", unit="ratio"
+                    ).observe(float(full / (full + ring)))
         self._seg_span_args = args
         if self.stats_probe is not None and (stats or probe):
             self.stats_probe(
                 {**{k: np.asarray(v) for k, v in stats.items()
-                    if k not in ("moe", "dsa")}, **probe},
+                    if k not in ("moe", "dsa", "attn")}, **probe},
                 list(self._slot_req), self.lengths.copy(), owed)
 
     def _observe_moe(self, stats, steps_ran: int) -> Dict[str, float]:
@@ -2259,3 +2269,48 @@ class PagedDecodeEngine:
             (self.pool.n_pages - 1) - self.pool.free_pages
         )
         return self.results
+
+    def _safe_after(self, take: int, s: Optional[int] = None,
+                    horizon: Optional[int] = None) -> bool:
+        """Whether ``take`` more pages may go to slot ``s`` mid-prefill —
+        or to a new chunked request of ``horizon`` pages in all — and
+        leave every slot mid-prefill an order to finish in (the banker's
+        rule).  Chunks allocate lazily, so on a pool smaller than ``slots
+        x pages_per_seq`` two long prompts can grow into pages neither
+        can finish in and wait on each other for ever.  A decoding slot
+        holds its whole horizon and returns it when it retires, so what
+        the slots mid-prefill can count on is the free pages and the
+        decoding slots'; least still owed first, each must fit what is
+        there and then returns what it held."""
+        from ..models.kv_pages import pages_needed
+
+        there = self.pool.free_pages - take
+        if there < 0:
+            return False
+        owed = [] if horizon is None else [(horizon - take, take)]
+        for t in range(self.slots):
+            held = len(self._slot_pages[t]) + (take if t == s else 0)
+            st = self._chunk_state.get(t)
+            if st is None:
+                there += held
+            else:
+                owed.append((pages_needed(
+                    st["P"] + st["max_new"], self.page_size) - held, held))
+        for need, held in sorted(owed):
+            if need > there:
+                return False
+            there += held
+        return True
+
+    def _chunked_need(self, prompt_len: int, max_new: int) -> int:
+        """What a chunk-eligible request needs ``pool.free_pages`` to be
+        to enter: its first chunk's pages, or more than there are where
+        it may not enter yet (:meth:`_safe_after`)."""
+        from ..models.kv_pages import pages_needed
+
+        first = pages_needed(
+            min(self.chunk_tokens, prompt_len), self.page_size)
+        if self._safe_after(first, horizon=pages_needed(
+                prompt_len + max_new, self.page_size)):
+            return first
+        return self.pool.free_pages + 1

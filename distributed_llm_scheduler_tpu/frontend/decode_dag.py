@@ -101,8 +101,8 @@ def _chain(fam, config, spec, add, flops, f_embed, layer_fn, f_head,
     """The skeleton both builders share: embed task -> one task a layer
     -> logits task, through ``add`` (:func:`.gpt2_dag.make_task_adder`).
     ``layer_fn(i)`` makes layer ``i``'s task fn; layers with the same
-    local param names share ONE fn object (they must then compute the
-    same function), so per-task dispatch compiles each layer shape once,
+    local param names and pool kinds share ONE fn object (they must then
+    compute the same function), so per-task dispatch compiles each once,
     not once a layer.  ``shared_alias`` is what every layer aliases
     beside its weights and its ``cache_{kind}``.  The spec's draft
     layers are not the chain's: the paged builder hangs the ``draft``
@@ -113,9 +113,9 @@ def _chain(fam, config, spec, add, flops, f_embed, layer_fn, f_head,
     prev, fns = "embed", {}
     for i in range(spec.n_layers - spec.draft_layers):
         alias = dict(fam.layer_param_names(config, i))
-        fn = fns.get(tuple(alias))
+        fn = fns.get(key := (*alias, *spec.layer_kinds(i)))
         if fn is None:
-            fn = fns[tuple(alias)] = layer_fn(i)
+            fn = fns[key] = layer_fn(i)
         alias.update(
             {f"cache_{k}": f"cache_{k}_{i}" for k in spec.layer_kinds(i)})
         alias.update(shared_alias)
@@ -475,6 +475,10 @@ def build_paged_decode_dag(
         # what splits a stored K/V row into heads: the DEC005 / DEC006
         # eligibility checks see the graph and its param specs only
         graph.kv_head_dim = spec.head_dim
+        # and the query-head counts that read the rows, where the layers
+        # (or the model) say them: the kernel maps them onto the KV heads
+        graph.kv_q_heads = tuple(sorted(
+            {lc.q_heads or spec.q_heads for lc in spec.layers} - {None}))
     dag = PagedDecodeDAG(
         graph=graph,
         config=config,

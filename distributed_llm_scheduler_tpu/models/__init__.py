@@ -212,6 +212,11 @@ for _f in (
         "glm4_lite", f"{__name__}.glm4_lite", "Glm4LiteConfig",
         {"glm4_lite-tiny": "tiny"}, "n_layers", "max_positions",
     ),
+    # a kv cache with rotary keys, per-layer query heads and ring layers
+    Family(
+        "laguna", f"{__name__}.laguna", "LagunaConfig",
+        {"laguna-tiny": "tiny"}, "n_layers", "max_positions",
+    ),
 ):
     register_family(_f)
 del _f

@@ -506,11 +506,14 @@ class LayerCache:
     the absorbed kernel accumulates; the rest is the rotated key), and —
     for a **ring layer** — the ``window`` of positions a query may see
     (itself included).  ``window`` ``None``: the layer caches the whole
-    context in pages of the shared pool, through the page table."""
+    context in pages of the shared pool, through the page table.
+    ``q_heads``: the query heads that read this layer's kv rows, where
+    the layers differ in them (else :attr:`CacheSpec.q_heads`)."""
 
     rows: Tuple[Tuple[str, Tuple[int, ...]], ...]
     rank: Optional[int] = None
     window: Optional[int] = None
+    q_heads: Optional[int] = None
 
 
 @dataclasses.dataclass(frozen=True)
@@ -555,7 +558,9 @@ class CacheSpec:
     table.  The dense cache the prefill programs hand the family holds,
     per pool kind, the layers that keep that kind stacked in layer
     order: ``{kind: (layers with it, b, cap, *row)}``, a ring kind
-    ``cap`` = the ring's rows.
+    ``cap`` = the ring's rows (of a ``"kv"`` spec heads ahead of
+    positions as everywhere: a ring layer names its two pools apart
+    from the paged layers', ``wk`` / ``wv`` beside ``k`` / ``v``).
 
     ``walk`` names the pool whose live blocks the decode step's paged
     kernel walks, ``(pool kind, rank)`` — what :meth:`resolve_impl` and
@@ -648,7 +653,7 @@ class CacheSpec:
     @property
     def head_dim(self) -> Optional[int]:
         """What splits a stored kv row into heads; a latent row has none."""
-        return self.rows[0][1][-1] if self.kind == "kv" else None
+        return self._walked()[0][-1] if self.kind == "kv" else None
 
     def _walked(self) -> Tuple[Tuple[int, ...], Optional[int]]:
         """``(row, rank)`` of the pool the decode step's kernel walks."""
@@ -657,6 +662,14 @@ class CacheSpec:
         kind, rank = self.walk
         return next(row for lc in self.layers for k, row in lc.rows
                     if k == kind), rank
+
+    def _walked_q_heads(self) -> Optional[int]:
+        """The query heads that read the walked pool: its first layer's
+        where a kv spec says them per layer, else the model's one number."""
+        kind = self.walk[0] if self.walk else self.layers[0].rows[0][0]
+        first = next(lc for lc in self.layers
+                     if kind in (k for k, _ in lc.rows))
+        return first.q_heads or self.q_heads
 
     def resolve_impl(self, impl: Optional[str], slots: int, n_pages: int,
                      page_size: int, dtype: Any) -> str:
@@ -672,7 +685,7 @@ class CacheSpec:
                 impl, page_size, row[0], rank, dtype)
         n_kv, hd = row
         return resolve_paged_impl(
-            impl, (slots, self.q_heads or n_kv, 1, hd),
+            impl, (slots, self._walked_q_heads() or n_kv, 1, hd),
             (n_pages, page_size, n_kv * hd), dtype)
 
     def block_pages(self, page_size: int, pages_per_seq: int,
@@ -759,8 +772,9 @@ class CacheSpec:
         out = dict(cache)
         for i, kind, row, n, window in self._pools():
             take, rows_n = pages, n_rows
-            if window is not None:
-                take, rows_n = ring, out[kind].shape[2]
+            if window is not None:   # the ring whole: its dense rows
+                take = ring
+                rows_n = out[kind].shape[3 if self.kind == "kv" else 2]
             rows = jnp.take(pools[f"cache_{kind}_{i}"], take, axis=0)
             rows = self._from_rows(rows.reshape(batch, rows_n, *row))
             at = ((n, slice(None), slice(None), slice(0, rows_n))

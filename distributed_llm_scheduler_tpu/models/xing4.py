@@ -648,16 +648,16 @@ def _moe_experts(x, idx, gate, gu_w, down_w, *, impl, name="_moe_experts"):
 
 def moe_ffn(p, x, cfg: Xing4Config, held: Optional[Sequence[int]] = None,
             shared: bool = True, live=None, impl: Optional[str] = None,
-            name: Optional[str] = None):
+            name: Optional[str] = None, route=None):
     """An expert layer's FFN for tokens ``x`` (N, h): the part of the
     experts in ``held`` (``p['exp_*_w']`` holds exactly those, in that
     order; ``None`` = all) plus, when ``shared``, the shared expert.
     ``live`` (N,) bool takes tokens out of the routing (empty slots).
     ``name``: :func:`_moe_experts`' name in a device trace, where it is
-    not its own.  Returns ``(y, stats)`` with ``stats`` the float32 pair
-    (share of the held experts picked, largest expert's picks over the
-    mean)."""
-    idx, gate = moe_route(p, x, cfg)
+    not its own; ``route``: a family's own :func:`moe_route`.  Returns
+    ``(y, stats)``: the float32 pair (share of the held experts picked,
+    largest expert's picks over the mean)."""
+    idx, gate = (route or moe_route)(p, x, cfg)
     E = p["exp_gu_w"].shape[0]
     if held is not None:
         local = np.full((cfg.n_routed_experts,), E, np.int32)

@@ -164,9 +164,9 @@ def _tiny(family: str):
 # -- the contract -----------------------------------------------------------------
 
 
-def test_the_toy_is_registered_beside_the_six():
-    assert FAMILIES == ["dots3", "glm4_lite", "gpt2", "llama", "mixtral",
-                        "toy", "xing4"]
+def test_the_toy_is_registered_beside_the_seven():
+    assert FAMILIES == ["dots3", "glm4_lite", "gpt2", "laguna", "llama",
+                        "mixtral", "toy", "xing4"]
 
 
 @pytest.mark.parametrize("family", FAMILIES)
@@ -254,7 +254,8 @@ def test_family_is_decided_by_type_not_by_class_name():
     ("llama", "llama"), ("llama-8b", "llama"), ("llama-tiny", "llama"),
     ("mixtral-8x7b", "mixtral"), ("mixtral-tiny", "mixtral"),
     ("xing4-tiny", "xing4"), ("dots3-tiny", "dots3"),
-    ("glm4_lite-tiny", "glm4_lite"), ("toy-tiny", "toy")])
+    ("glm4_lite-tiny", "glm4_lite"), ("laguna-tiny", "laguna"),
+    ("toy-tiny", "toy")])
 def test_variant_names_make_their_familys_config(model, family):
     assert models.family_of_model(model).name == family
     assert models.family_of(models.model_config(model)) == family
@@ -288,7 +289,7 @@ def test_what_each_family_offers():
               if models.offers(rows[f], *models.PAGED_FUNCTIONS)}
     dense = {f for f in rows
              if models.offers(rows[f], *models.CACHED_FUNCTIONS)}
-    assert served == {"gpt2", "xing4", "dots3", "glm4_lite", "toy"}
+    assert served == {"gpt2", "xing4", "dots3", "glm4_lite", "laguna", "toy"}
     assert dense == {"gpt2", "llama", "mixtral"}
     assert {f for f in rows if models.offers(
         rows[f], *models.DRAFT_FUNCTIONS)} == {"glm4_lite"}

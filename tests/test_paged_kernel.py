@@ -116,6 +116,12 @@ BLOCK_FIXTURES = [
      [143, 144, 145, 0], True, BF16),
     ("block_xl_row_capacity_edges_bf16", 3, 25, 25, 64, 16, 20,
      [319, 0, 320], True, BF16),
+    # groups that are no power of two over 8 KV heads of 128 (a 1,024-wide
+    # row: a full layer's 48 query heads and a window layer's 72), page
+    # 128, bf16: a block is 2 pages = 256 rows
+    ("block_gqa_6to1_bf16", 3, 48, 8, 128, 128, 5, [255, 0, 600], True,
+     BF16),
+    ("block_gqa_9to1_bf16", 2, 72, 8, 128, 128, 3, [383, 256], True, BF16),
 ]
 FIXTURES += BLOCK_FIXTURES
 
