@@ -1134,7 +1134,7 @@ class PagedDecodeEngine:
         self.metrics.counter("decode.requests_submitted").inc()
         self._emit_queue_depth()
 
-    def _first_tokens(self, w, ids, cache, pos0, row):
+    def _first_tokens(self, w, ids, cache, pos0, row, **pages):
         """The family's cached forward over ``ids`` (b, T) at ``pos0``
         and the greedy token of chunk row ``row`` (static or traced),
         (b,) int32 — all any prefill program needs of the logits: the
@@ -1147,7 +1147,7 @@ class PagedDecodeEngine:
         if self.rows_per_step == 1:
             last, cache = fam.forward_cached_row(
                 w, ids, cache, pos0, self.config, row,
-                impl=self.attention_impl)
+                impl=self.attention_impl, **pages)
             return jnp.argmax(last, axis=-1).astype(jnp.int32), cache
         ids, nxt = ids
         last, draft, cache = fam.forward_cached_draft(
@@ -1172,9 +1172,9 @@ class PagedDecodeEngine:
         chunk_attention_log`; the choice is the shape's, made at trace
         time): the class is in ``_prefill_attn_kernel`` iff every
         expanded-MLA attention in it is the kernel."""
-        def fwd(*args):
+        def fwd(*args, **pages):
             with chunk_attention_log() as impls:
-                out = self._first_tokens(*args)
+                out = self._first_tokens(*args, **pages)
             if impls and "xla" not in impls:
                 self._prefill_attn_kernel.add(key)
             return out
@@ -1312,46 +1312,44 @@ class PagedDecodeEngine:
     def _chunk_prefill(self, ids_chunk, pt_row, base: int, creal: int,
                        slot: int = 0, nxt_chunk=None):
         """Run ONE prefill chunk for one slot: gather the slot's pages
-        into a dense per-slot cache, run the transformer over the
-        ``chunk_tokens`` chunk at traced ``pos_start = base``, and
-        scatter every page back through the slot's table row.
+        into a dense per-slot cache, run the transformer over the chunk
+        at traced ``pos_start = base``, and scatter every page back
+        through the slot's table row — or, where :meth:`_chunk_in_pages`
+        says so, leave the paged layers in their pages: the family writes
+        the chunk's rows where they lie and attends through the table row
+        (counted: ``decode.prefill_paged_chunk_programs``).
 
         ONE compile class per ``("chunk", chunk_tokens, 1, impl)`` —
-        prompt length, chunk index, and the final chunk's real length
-        ``creal`` are all DATA (the final chunk is padded to
-        ``chunk_tokens`` with token 0; causal masking keeps pad rows out
-        of every real row's scores, and their K/V rows land at positions
-        ``>= P`` that stay masked until decode overwrites them).  The
-        gather covers ALL ``pages_per_seq`` table entries (trash entries
-        gather masked garbage; the scatter-back writes it harmlessly to
-        the trash page) so page count is data too.  ``nxt_chunk``: the
+        prompt length, chunk index and the final chunk's real length
+        ``creal`` are DATA (the final chunk is padded with token 0;
+        causal masking keeps pad rows out of every real row's scores,
+        and their K/V rows land at positions ``>= P`` that stay masked
+        until decode overwrites them), and so is the page count: the
+        gather covers ALL ``pages_per_seq`` table entries (a trash entry
+        gathers masked garbage and takes it back).  ``nxt_chunk``: the
         token after each of the chunk's positions, for a family stepped
         with its draft module (:meth:`_with_next`).
 
-        Bitwise contract: the dense cache has exactly the per-slot
-        ``capacity`` rows a whole-prompt prefill uses, positions
-        ``[0, base)`` hold the bytes the earlier chunks scattered, and
-        ``forward_cached`` masks cache columns beyond the write cursor
-        AFTER the scores — the same stitching argument as
-        :meth:`_prefill_scatter_shared`, so the chunk's rows, the final
-        logits row, and every downstream decode step match a
-        whole-prompt run bit for bit."""
+        Bitwise contract (:meth:`_prefill_scatter_shared`'s): positions
+        ``[0, base)`` hold the bytes the earlier chunks wrote and columns
+        past the write cursor are masked AFTER the scores, so the chunk's
+        rows and every later step match a whole-prompt run bit for bit."""
         key = ("chunk", self.chunk_tokens, 1, self.attention_impl)
-        fn = self._prefill_store.get(key)
+        fn, in_pages = self._prefill_store.get(key), self._chunk_in_pages()
         if fn is None:
             spec, fwd = self.cache, self._first_tokens_logged(key)
-            cap, cfg = self.capacity, self.config
-            ps = self.page_size
+            cap, cfg, ps = self.capacity, self.config, self.page_size
 
             def _fn(w, ids, pools, pages, pos0, creal, *ring):
+                kw = {"pages": pages[None]} if in_pages else {}
                 cache = spec.gather(
-                    spec.init_dense(1, cap, cfg.dtype, page_size=ps), pools,
-                    pages, 1, cap, *ring)
-                first, cache = fwd(w, ids, cache, pos0, creal - 1)
-                return first, spec.scatter(pools, cache, pages, ps, *ring)
+                    spec.init_dense(1, cap, cfg.dtype, ps, in_pages), pools,
+                    pages, 1, cap, *ring, in_pages=in_pages)
+                first, cache = fwd(w, ids, cache, pos0, creal - 1, **kw)
+                return first, spec.scatter(
+                    pools, cache, pages, ps, *ring, in_pages=in_pages)
 
-            fn = jax.jit(_fn, donate_argnums=(2,))
-            self._prefill_store[key] = fn
+            fn = self._prefill_store[key] = jax.jit(_fn, donate_argnums=(2,))
         if key not in self._prefill_cache:
             self._prefill_cache[key] = fn
         self._prefill_enqueued(int(creal))
@@ -1361,6 +1359,8 @@ class PagedDecodeEngine:
             jnp.int32(base), jnp.int32(creal), *self._ring_args((slot,)),
         )
         self._prefill_dispatched(key)
+        if in_pages:
+            self.metrics.counter("decode.prefill_paged_chunk_programs").inc()
         return first
 
     def _admit_chunked(self, s: int) -> None:
@@ -2314,3 +2314,24 @@ class PagedDecodeEngine:
                 prompt_len + max_new, self.page_size)):
             return first
         return self.pool.free_pages + 1
+
+    def _chunk_in_pages(self) -> bool:
+        """Whether a chunk program leaves the paged layers in their pages
+        instead of gathering the slot's whole capacity dense, turning it
+        heads-first for the family and scattering every page back.
+        Decided by what can be observed, no knob: a ``kv`` cache of one
+        row a step whose family's prefill takes the table row
+        (``PREFILL_TAKES_PAGES``), chunks of whole pages, and a shape the
+        paged chunk kernel admits under this engine's attention impl.
+        Everything else keeps the round trip: a latent row needs no turn
+        and pays little for it, GPT-2's head of 64 is no whole lane tile,
+        and the gather path (``xla``) has no paged form."""
+        from ..ops.gqa_attention import gqa_paged_chunk_impl
+
+        return (
+            self.cache.kind == "kv" and self.rows_per_step == 1
+            and getattr(module_of(self.config), "PREFILL_TAKES_PAGES", False)
+            and self.chunk_tokens % self.page_size == 0
+            and gqa_paged_chunk_impl(
+                self.attention_impl, self.page_size, self.cache.head_dim,
+                self.config.dtype) != "xla")

@@ -21,10 +21,11 @@ Three pieces, split by where they run:
   page pools, ``(n_pages, page_size, row_width)``: a token's cached
   values as one vector on the lanes, pages on the leading axis so one
   gather assembles a sequence and a kernel reads a page where it lies.
-* scatter helpers (:func:`write_token_kv`, :func:`write_prompt_kv`) —
-  static-shape jittable writes: one token's K/V row into its page slot
-  (traced page id + slot), or a whole prefilled prompt page-reshaped
-  into its allocated pages.
+* scatter helpers (:func:`write_token_kv`, :func:`write_prompt_kv`,
+  :func:`write_chunk_pages`) — static-shape jittable writes: one token's
+  K/V row into its page slot (traced page id + slot), a whole prefilled
+  prompt page-reshaped into its allocated pages, or a prefill chunk's
+  whole pages at a traced position through the table row.
 
 Physical page 0 is RESERVED as the trash page: unallocated page-table
 entries point at it, and inactive batch slots redirect their writes to
@@ -739,13 +740,18 @@ class CacheSpec:
         }
 
     def init_dense(self, batch: int, cap: int, dtype: Any,
-                   page_size: Optional[int] = None) -> Dict[str, Any]:
+                   page_size: Optional[int] = None,
+                   in_pages: bool = False) -> Dict[str, Any]:
         """The family's zeroed dense cache ``{kind: (L, b, ...)}``; per
         layer, ``L`` counts the layers that keep the kind and a ring
-        kind's ``cap`` is the ring (whole pages of ``page_size``)."""
+        kind's ``cap`` is the ring (whole pages of ``page_size``).
+        ``in_pages``: the ring kinds alone — the paged layers stay in
+        their pages (:meth:`gather`)."""
         ring = self.ring_pages(page_size or 1) * (page_size or 1)
         shapes: Dict[str, Any] = {}
         for _, kind, row, n, window in self._pools():
+            if in_pages and window is None:
+                continue
             shapes[kind] = (n + 1, *self._dense(
                 kind, (batch, cap if window is None else ring, *row)))
         return {kind: jnp.zeros(shape, dtype)
@@ -764,13 +770,20 @@ class CacheSpec:
 
     def gather(self, cache: Dict[str, Any], pools: Dict[str, Any],
                pages: jax.Array, batch: int, n_rows: int,
-               ring: Optional[jax.Array] = None) -> Dict[str, Any]:
+               ring: Optional[jax.Array] = None,
+               in_pages: bool = False) -> Dict[str, Any]:
         """``cache`` with rows ``[0, n_rows)`` of every layer filled from
         the pools through ``pages`` (flat physical ids, ``batch`` runs);
         a ring layer's whole ring through ``ring`` (the slots' rows of
-        :meth:`ring_table`, flat)."""
+        :meth:`ring_table`, flat).  ``in_pages``: a paged layer is not
+        made dense — its kind's entry is a tuple of the pools themselves,
+        in the stored form, one a layer that keeps the kind, for a family
+        whose prefill writes and reads them through the page table."""
         out = dict(cache)
         for i, kind, row, n, window in self._pools():
+            if in_pages and window is None:
+                out[kind] = out.get(kind, ()) + (pools[f"cache_{kind}_{i}"],)
+                continue
             take, rows_n = pages, n_rows
             if window is not None:   # the ring whole: its dense rows
                 take = ring
@@ -788,13 +801,18 @@ class CacheSpec:
 
     def scatter(self, pools: Dict[str, Any], cache: Dict[str, Any],
                 pages: jax.Array, page_size: int,
-                ring: Optional[jax.Array] = None) -> Dict[str, Any]:
+                ring: Optional[jax.Array] = None,
+                in_pages: bool = False) -> Dict[str, Any]:
         """``pools`` with every page in ``pages`` (flat physical ids,
         covering each sequence's whole capacity) rewritten from the dense
         ``cache``, a ring layer's through ``ring``; out-of-range ids are
-        dropped."""
+        dropped.  ``in_pages``: a paged layer's entry IS its pool
+        (:meth:`gather`), the rows already written where they lie."""
         new = dict(pools)
         for i, kind, _, n, window in self._pools():
+            if in_pages and window is None:
+                new[f"cache_{kind}_{i}"] = cache[kind][n]
+                continue
             into = pages if window is None else ring
             rows = self.to_rows(cache[kind][n])
             paged = rows.reshape(into.shape[0], page_size, -1)
@@ -921,6 +939,26 @@ def write_prompt_kv(
     return pool.at[pages].set(paged, mode="drop")
 
 
+def write_chunk_pages(
+    pool: jax.Array, rows: jax.Array, pages: jax.Array, pos0: jax.Array
+) -> jax.Array:
+    """Write a prefill chunk's rows where they lie: ``rows`` (b, T, ...)
+    at positions ``pos0 + t`` — ``pos0`` (may be traced) and ``T`` whole
+    pages — into pages ``pages[s, pos0 // page_size + j]`` of ``pool``
+    (n_pages, page_size, row_width); ``pages`` (b, pages_per_seq) the
+    sequences' table rows.  Only these ``T / page_size`` pages a sequence
+    are touched; a page past the table's end goes to the trash page, as a
+    table entry past the sequence's claimed pages already does."""
+    b, T = rows.shape[:2]
+    at = pos0 // pool.shape[1] + jnp.arange(
+        T // pool.shape[1], dtype=jnp.int32)
+    ids = jnp.where(
+        at < pages.shape[1],
+        jnp.take(pages, jnp.minimum(at, pages.shape[1] - 1), axis=1),
+        TRASH_PAGE)
+    return write_prompt_kv(pool, rows.reshape(b * T, -1), ids.reshape(-1))
+
+
 def gather_kv(
     pool: jax.Array, page_table: jax.Array, head_dim: int
 ) -> jax.Array:
@@ -996,6 +1034,7 @@ __all__ = [
     "init_paged_kv",
     "page_table_array",
     "write_token_kv",
+    "write_chunk_pages",
     "write_prompt_kv",
     "gather_kv",
     "gather_kv_flat",

@@ -36,8 +36,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..ops.attention import paged_decode_attention
-from ..ops.gqa_attention import gqa_chunk_attention, kv_window_attention
+from ..ops.gqa_attention import (
+    gqa_chunk_attention,
+    gqa_paged_chunk_attention,
+    kv_window_attention,
+)
 from .dots3 import head_gate
+from .kv_pages import write_chunk_pages
 from .xing4 import _swiglu, moe_ffn, rms_norm, yarn_inv_freq
 
 FULL, SLIDING = "full", "sliding"
@@ -370,15 +375,19 @@ def _kinds(cfg: LagunaConfig, layer: int) -> Tuple[str, str]:
 
 
 def prefill_layer(p, x, cache, pos0, last, cfg: LagunaConfig, layer: int,
-                  impl=None):
+                  impl=None, pages=None):
     """One layer over a chunk ``x`` (b, T, h) at positions ``pos0 + t``
     whose last real row is ``last``; ``cache`` the layer's own rows by
     kind, (b, Hkv, cap or ring, hd).  A full layer writes the chunk's
-    rotated K and V at ``pos0`` and attends the cache; a sliding layer
-    reads the ``sliding_window`` rows before the chunk out of its ring
-    (before the chunk overwrites any), attends those and the chunk's own
-    under the window, then writes the chunk's real rows (of a chunk
-    longer than the ring the last ring's worth) at ``(pos0 + t) mod
+    rotated K and V at ``pos0`` and attends the cache — with ``pages``
+    (b, pages_per_seq), the sequences' table rows, ``cache`` holds its
+    two pools as they are stored: the chunk's rows (whole pages) go into
+    the pages that hold their positions and the attention reads K and V
+    through the table, so nothing of the slot's other rows moves.  A
+    sliding layer reads the ``sliding_window`` rows before the chunk out
+    of its ring (before the chunk overwrites any), attends those and the
+    chunk's own under the window, then writes the chunk's real rows (of a
+    chunk longer than the ring the last ring's worth) at ``(pos0 + t) mod
     ring``.  Returns ``(x', cache')``."""
     b, T, h = x.shape
     kk, kv = _kinds(cfg, layer)
@@ -387,17 +396,27 @@ def prefill_layer(p, x, cache, pos0, last, cfg: LagunaConfig, layer: int,
     t = jnp.arange(T, dtype=jnp.int32)
     q, k, v = qkv(p, xn, jnp.tile(pos0 + t, b), cfg, layer)
     q = q.reshape(b, T, -1, cfg.head_dim)
-    new_k, new_v = (
-        r.reshape(b, T, -1, cfg.head_dim).transpose(0, 2, 1, 3).astype(
-            cache[kk].dtype) for r in (k, v))
-    if cfg.is_full(layer):
+
+    def heads_first(r):     # the dense cache keeps heads ahead of positions
+        return r.reshape(b, T, -1, cfg.head_dim).transpose(0, 2, 1, 3).astype(
+            cache[kk].dtype)
+
+    if pages is not None and cfg.is_full(layer):
+        keys, vals = (
+            write_chunk_pages(cache[kind], r.reshape(b, T, -1), pages, pos0)
+            for kind, r in ((kk, k), (kv, v)))
+        o = gqa_paged_chunk_attention(q, keys, vals, pages, pos0,
+                                      scale=cfg.softmax_scale, impl=impl)
+        cache = {kk: keys, kv: vals}
+    elif cfg.is_full(layer):
         keys = jax.lax.dynamic_update_slice_in_dim(
-            cache[kk], new_k, pos0, axis=2)
+            cache[kk], heads_first(k), pos0, axis=2)
         vals = jax.lax.dynamic_update_slice_in_dim(
-            cache[kv], new_v, pos0, axis=2)
+            cache[kv], heads_first(v), pos0, axis=2)
         o = chunk_attention(q, keys, vals, pos0, cfg, impl)
         cache = {kk: keys, kv: vals}
     else:
+        new_k, new_v = heads_first(k), heads_first(v)
         R, back = cache[kk].shape[2], cfg.sliding_window
         before = (pos0 - back + jnp.arange(back, dtype=jnp.int32)) % R
         o = chunk_attention(
@@ -465,6 +484,10 @@ HEAD_PARAMS = ("norm_f_g", "head_w")
 #: the step's graph takes ``active`` (the slots that decode) as an input
 #: and carries it on every edge as ``live``
 DECODE_TAKES_LIVE = True
+#: :func:`forward_cached_row` takes ``pages``: where the chunk kernel
+#: admits the shape, a chunk program leaves the full layers' K and V in
+#: their pages (the engine asks, ``PagedDecodeEngine._chunk_in_pages``)
+PREFILL_TAKES_PAGES = True
 
 
 def layer_param_names(cfg: LagunaConfig, layer: int) -> Dict[str, str]:
@@ -526,7 +549,10 @@ def init_cache(cfg: LagunaConfig, batch: int, cap: int, dtype=None,
         batch, cap, dtype or cfg.dtype, page_size=page_size)
 
 
-def _prefill(params, ids, cache, pos_start, last, cfg, impl=None):
+def _prefill(params, ids, cache, pos_start, last, cfg, impl=None, pages=None):
+    """``cache`` by kind: the layers that keep it stacked — or, with
+    ``pages``, a full layer's kinds as tuples of their pools
+    (:func:`prefill_layer`), which come back as tuples."""
     x = params["wte"][ids]
     seen: Dict[str, int] = {}
     out = {k: [] for k in cache}
@@ -535,10 +561,11 @@ def _prefill(params, ids, cache, pos_start, last, cfg, impl=None):
         n = seen[kinds[0]] = seen.get(kinds[0], -1) + 1
         x, mine = prefill_layer(
             layer_params(params, cfg, i), x, {k: cache[k][n] for k in kinds},
-            pos_start, last, cfg, i, impl)
+            pos_start, last, cfg, i, impl, pages)
         for k, v in mine.items():
             out[k].append(v)
-    return x, {k: jnp.stack(v) for k, v in out.items()}
+    return x, {k: tuple(v) if isinstance(cache[k], tuple) else jnp.stack(v)
+               for k, v in out.items()}
 
 
 def forward_cached(params, ids, cache, pos_start, cfg: LagunaConfig,
@@ -552,11 +579,15 @@ def forward_cached(params, ids, cache, pos_start, cfg: LagunaConfig,
 
 
 def forward_cached_row(params, ids, cache, pos_start, cfg: LagunaConfig,
-                       row, impl=None):
+                       row, impl=None, pages=None):
     """:func:`forward_cached` with the logits of chunk row ``row`` only,
     (b, V); ``row`` is the chunk's last real row — the rows after it are
-    padding and a sliding layer's ring does not take them."""
-    x, cache = _prefill(params, ids, cache, pos_start, row, cfg, impl)
+    padding and a sliding layer's ring does not take them.  ``pages``
+    (b, pages_per_seq), :data:`PREFILL_TAKES_PAGES`: the full layers'
+    kinds of ``cache`` are tuples of their pools, written and read
+    through these table rows where they lie (``ids`` whole pages, at a
+    page's first position)."""
+    x, cache = _prefill(params, ids, cache, pos_start, row, cfg, impl, pages)
     return head(params, jax.lax.dynamic_index_in_dim(
         x, row, 1, keepdims=False), cfg), cache
 

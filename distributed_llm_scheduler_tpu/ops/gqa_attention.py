@@ -9,10 +9,12 @@ program that holds one (PERF.md section 6, PR 39).  What is shared is
 imported: the ring's validity rule, the chunk tiles' sizes, the impl
 dispatch and the log an engine reads while it traces a prefill program.
 
-Both kernels tell query heads apart as :func:`.attention._paged_flash`
+The kernels tell query heads apart as :func:`.attention._paged_flash`
 does where K / V rows lie head beside head on the lanes (the ring), and
-by a grid axis over the KV heads where the family's dense cache keeps
-heads ahead of positions (the chunk).
+by a grid axis over the KV heads for a chunk: a block of the family's
+dense cache, which keeps heads ahead of positions — or, for a layer
+whose rows stay in their pages, a KV head's lane tile of each page,
+through the page table (the last section).
 """
 
 from __future__ import annotations
@@ -198,17 +200,30 @@ def gqa_chunk_constraints(head_dim: int) -> list:
     return []
 
 
-def _gqa_chunk_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
-                      l_ref, *, window, n_keys):
-    """One (sequence, KV head, query tile, key block): ``q_ref`` (1, 1, G,
-    tq, hd) the group's query heads, scaled; ``k_ref`` / ``v_ref`` (1, 1,
-    kb, hd).  ``pos_ref`` = [position of query row 0, of key row 0].  The
-    mask is :func:`.attention._mla_chunk_kernel`'s: causal, and with
+def _gqa_chunk_kernel(pos_ref, *refs, window, n_keys, block_pages=0):
+    """One (sequence, KV head, query tile, key block).  ``refs`` = the
+    query ``q_ref`` (1, 1, G, tq, hd), the group's query heads, scaled;
+    the block's K and its V; the output and the scratch.  K and V are one
+    ref each, (1, 1, kb, hd) of a dense cache — or, with ``block_pages``,
+    that many page refs each behind the page table (a scalar-prefetch
+    operand only the index maps read): one head of one physical page
+    where it lies in the pool, (1, page_size, hd), stacked into the
+    block here.  ``pos_ref`` = [position of query row 0, of key row 0].
+    The mask is :func:`.attention._mla_chunk_kernel`'s: causal, and with
     ``window`` the last ``window`` positions and nothing before position
     0; a block no row of the tile can see is not computed.  Scores, mask
     and probabilities never leave VMEM."""
+    o_ref, acc_ref, m_ref, l_ref = refs[-4:]
+    if block_pages:
+        q_ref, pages = refs[1], refs[2:-4]
+        k_refs, v_refs = pages[:block_pages], pages[block_pages:]
+        kb = block_pages * k_refs[0].shape[1]
+        rows = lambda rs: jnp.concatenate([r[0] for r in rs], axis=0)
+    else:
+        q_ref, k_refs, v_refs = refs[:3]
+        kb = k_refs.shape[2]
+        rows = lambda r: r[0, 0]
     G, tq = q_ref.shape[2], q_ref.shape[3]
-    kb = k_ref.shape[2]
     i, j = pl.program_id(2), pl.program_id(3)
     q0 = pos_ref[0] + i * tq
     k0 = pos_ref[1] + j * kb
@@ -225,7 +240,7 @@ def _gqa_chunk_kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref,
 
     @pl.when(seen)
     def _attend():
-        k, v = k_ref[0, 0], v_ref[0, 0]
+        k, v = rows(k_refs), rows(v_refs)
         q_pos = q0 + jax.lax.broadcasted_iota(jnp.int32, (tq, kb), 0)
         col = jax.lax.broadcasted_iota(jnp.int32, (tq, kb), 1)
         k_pos = k0 + col
@@ -312,23 +327,142 @@ def gqa_chunk_attention(q, k, v, pos0, *, scale: float, xla_loop,
     mla_chunk_attention`'s — the kernel, interpreted, or ``xla_loop()``,
     the caller's plain-XLA loop — and logged to :class:`.attention.
     chunk_attention_log` like it.  Returns (b, T, Hq, hd)."""
-    b, T, Hq, hd = q.shape
-    Hkv = k.shape[1]
     impl = resolve_attention_impl(
         impl, lambda i: i == "pallas_interpret" or not gqa_chunk_constraints(
-            hd))
+            q.shape[3]))
     if _att._chunk_impl_log is not None:
         _att._chunk_impl_log.append(impl)
     if impl == "xla":
         return xla_loop()
-    pad = -T % _sublane_rows(k.dtype)
-    qg = (q.astype(jnp.float32) * scale).astype(q.dtype).reshape(
-        b, T, Hkv, Hq // Hkv, hd).transpose(0, 2, 3, 1, 4)
-    if pad:     # rows past the chunk's: computed, then dropped
-        qg = jnp.pad(qg, ((0, 0),) * 3 + ((0, pad), (0, 0)))
     out = _gqa_chunk_flash(
-        qg, k, v, pos0, 0 if keys_before is None else pos0 - keys_before,
+        _grouped(q, scale, k.shape[1], k.dtype), k, v, pos0,
+        0 if keys_before is None else pos0 - keys_before,
         window=window, q_tile=_CHUNK_Q_TILE, kv_block=_CHUNK_KV_BLOCK,
         interpret=impl == "pallas_interpret")
-    return out[:, :, :, :T].transpose(0, 3, 1, 2, 4).reshape(
-        b, T, Hq, hd).astype(q.dtype)
+    return _ungrouped(out, q)
+
+
+def _grouped(q, scale: float, n_kv_heads: int, dtype: Any):
+    """Queries (b, T, Hq, hd) scaled and laid out for the chunk kernels,
+    (b, Hkv, G, T', hd): ``T`` padded to ``dtype``'s sublane tile — rows
+    past the chunk's are computed, then dropped (:func:`_ungrouped`)."""
+    b, T, Hq, hd = q.shape
+    pad = -T % _sublane_rows(dtype)
+    qg = (q.astype(jnp.float32) * scale).astype(q.dtype).reshape(
+        b, T, n_kv_heads, Hq // n_kv_heads, hd).transpose(0, 2, 3, 1, 4)
+    return jnp.pad(qg, ((0, 0),) * 3 + ((0, pad), (0, 0))) if pad else qg
+
+
+def _ungrouped(out, q):
+    """A chunk kernel's (b, Hkv, G, T', hd) as ``q``'s (b, T, Hq, hd)."""
+    return out[:, :, :, :q.shape[1]].transpose(0, 3, 1, 2, 4).reshape(
+        q.shape).astype(q.dtype)
+
+
+# -- a prefill chunk over K / V rows that stay in their pages ------------------
+
+
+def gqa_paged_chunk_constraints(page_size: int, head_dim: int,
+                                dtype: Any = jnp.float32) -> list:
+    """Tiling rules for the COMPILED paged chunk kernel (empty =
+    eligible): a page ref is one KV head of one page, ``(page_size,
+    head_dim)`` cut out of the pool's row on a lane-tile boundary."""
+    out = gqa_chunk_constraints(head_dim)
+    sublane = _sublane_rows(dtype)
+    if page_size % sublane:
+        out.append(f"page_size {page_size} is not a multiple of the "
+                   f"{sublane}-row sublane tile for {jnp.dtype(dtype).name}")
+    return out
+
+
+def gqa_paged_chunk_impl(impl: Optional[str], page_size: int, head_dim: int,
+                         dtype: Any) -> str:
+    """What :func:`gqa_paged_chunk_attention` runs at this geometry on
+    this backend, asked from the host (:func:`.attention.
+    resolve_attention_impl`'s rule).  ``"xla"``: it does not — there is
+    no gather form of it, the caller keeps its dense cache."""
+    return resolve_attention_impl(
+        impl, lambda i: i == "pallas_interpret"
+        or not gqa_paged_chunk_constraints(page_size, head_dim, dtype))
+
+
+@functools.partial(
+    jax.jit, static_argnames=("q_tile", "block_pages", "interpret"))
+def _gqa_chunk_flash_paged(q, k_pool, v_pool, pages, pos0, *, q_tile,
+                           block_pages, interpret):
+    """:func:`_gqa_chunk_flash` over pools in their stored form
+    ``(n_pages, page_size, Hkv * hd)``: key block ``j`` of sequence ``s``
+    is the ``block_pages`` pages ``pages[s, j * block_pages + i]``, each
+    fetched where it lies — KV head ``h`` is lanes ``[h * hd, (h + 1) *
+    hd)`` of the row, a whole lane tile, so a page ref is a block of the
+    argument itself and nothing is gathered or turned in HBM.  Positions
+    from 0, causal, no window.  A page past the table's end (a capacity
+    that is no whole block) repeats the last one; its rows lie past every
+    real query and are masked."""
+    b, Hkv, G, T, hd = q.shape
+    ps, ppseq = k_pool.shape[1], pages.shape[1]
+    bp = block_pages
+    tq, kb = min(q_tile, T), bp * ps
+    nq, nk = -(-T // tq), -(-ppseq // bp)
+    pos = jnp.stack([jnp.asarray(pos0, jnp.int32), jnp.zeros((), jnp.int32)])
+    live = jnp.clip((pos[0] + (T - 1)) // kb + 1, 1, nk)
+    if not interpret:
+        k_pool = pltpu.with_memory_space_constraint(k_pool, pltpu.HBM)
+        v_pool = pltpu.with_memory_space_constraint(v_pool, pltpu.HBM)
+
+    def page(i):
+        def at(s, h, t, j, pos, table):
+            block = jnp.minimum(j, (pos[0] + t * tq + (tq - 1)) // kb)
+            return (table[s * ppseq + jnp.minimum(block * bp + i, ppseq - 1)],
+                    0, h)
+
+        return pl.BlockSpec((1, ps, hd), at)
+
+    heads = pl.BlockSpec((1, 1, G, tq, hd),
+                         lambda s, h, t, j, pos, table: (s, h, 0, t, 0))
+    page_refs = [page(i) for i in range(bp)]
+    return pl.pallas_call(
+        functools.partial(_gqa_chunk_kernel, window=None, n_keys=ppseq * ps,
+                          block_pages=bp),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2, grid=(b, Hkv, nq, live),
+            in_specs=[heads] + page_refs + page_refs, out_specs=heads,
+            scratch_shapes=[pltpu.VMEM((G, tq, hd), jnp.float32),
+                            pltpu.VMEM((G, tq, 1), jnp.float32),
+                            pltpu.VMEM((G, tq, 1), jnp.float32)]),
+        out_shape=jax.ShapeDtypeStruct(q.shape, k_pool.dtype),
+        interpret=interpret,
+        compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 << 20),
+        name="_gqa_chunk_flash_paged",
+    )(pos, pages.astype(jnp.int32).reshape(-1), q.astype(k_pool.dtype),
+      *([k_pool] * bp), *([v_pool] * bp))
+
+
+def gqa_paged_chunk_attention(q, k_pool, v_pool, pages, pos0, *,
+                              scale: float, impl: Optional[str] = None):
+    """:func:`gqa_chunk_attention` for a layer whose K and V stay in
+    their pages: ``q`` (b, T, Hq, hd) at positions ``pos0 + t`` over the
+    rows ``k_pool`` / ``v_pool`` (n_pages, page_size, Hkv * hd) hold for
+    each sequence behind its table row ``pages`` (b, pages_per_seq) —
+    position ``p`` in row ``p mod page_size`` of page ``pages[s, p //
+    page_size]``, the chunk's own rows already written
+    (:func:`...models.kv_pages.write_chunk_pages`).  Causal, every live
+    key, the dense kernel's tiles and float32 carry: a key block is the
+    pages that make ``_CHUNK_KV_BLOCK`` rows.  ``impl`` must resolve to
+    the kernel (:func:`gqa_paged_chunk_impl`: the caller asked first);
+    logged to :class:`.attention.chunk_attention_log`.  Returns (b, T,
+    Hq, hd)."""
+    ps, hd = k_pool.shape[1], q.shape[3]
+    impl = gqa_paged_chunk_impl(impl, ps, hd, k_pool.dtype)
+    if impl == "xla":
+        raise ValueError("the paged chunk attention has no gather form: "
+                         "ask gqa_paged_chunk_impl before leaving a cache "
+                         "in its pages")
+    if _att._chunk_impl_log is not None:
+        _att._chunk_impl_log.append(impl)
+    out = _gqa_chunk_flash_paged(
+        _grouped(q, scale, k_pool.shape[2] // hd, k_pool.dtype), k_pool,
+        v_pool, pages, pos0, q_tile=_CHUNK_Q_TILE,
+        block_pages=max(1, min(_CHUNK_KV_BLOCK // ps, pages.shape[1])),
+        interpret=impl == "pallas_interpret")
+    return _ungrouped(out, q)

@@ -725,3 +725,59 @@ def test_aot_dots3_chunk_program_holds_no_score_tile(v5e, monkeypatch):
     assert "_mla_chunk_flash" in text and not _score_tiles(text, tiles)
     # the criterion bites: the XLA loop's lowered program names them
     assert re.search(r"tensor<1x128x512x512xf32>", chunk("xla").as_text())
+
+
+def test_aot_laguna_chunk_program_leaves_its_pools_in_their_pages(
+        v5e, monkeypatch):
+    """A chunk program at Laguna-S-2.1's published widths and the cell's
+    engine (5,121 pages of 128 rows, 264 a slot, 512-token chunks), one
+    full and one sliding layer, as ``PagedDecodeEngine._chunk_prefill``
+    builds it where ``_chunk_in_pages`` holds: the full layer's two pools
+    (1.34 GB each, donated) are written by one in-place scatter of the
+    chunk's 4 pages and read by ``_gqa_chunk_flash_paged`` where they lie
+    — no buffer has a pool's shape or the slot's 33,792 rows but the pools
+    themselves, and the temporaries are a chunk's, not a slot's (the
+    dense round trip: +0.3 GB a full layer)."""
+    from pathlib import Path
+
+    from distributed_llm_scheduler_tpu.models import laguna
+    from distributed_llm_scheduler_tpu.ops import attention as A
+
+    hf = json.loads((Path(__file__).resolve().parents[1] / "benchmark"
+                     / "configs" / "laguna-s-2.1-ep4.json").read_text())
+    geo = hf["engine"]
+    ps, ppseq, n_pages, rp, T = (geo[k] for k in (
+        "page_size", "pages_per_seq", "n_pages", "ring_pages",
+        "chunk_tokens"))
+    hf = dict(hf, num_hidden_layers=2)
+    cfg = laguna.LagunaConfig.from_hf(hf, dtype=jnp.bfloat16,
+                                      ring_rows=rp * ps)
+    assert [cfg.is_full(i) for i in range(2)] == [True, False]
+    spec, cap, i32 = laguna.cache_spec(cfg), ppseq * ps, jnp.int32
+    weights = {k: v5e(s, dt) for k, (s, dt) in laguna.param_shapes(cfg).items()}
+    pools = {k: v5e(v.shape, v.dtype) for k, v in jax.eval_shape(
+        lambda: spec.init_pools(n_pages, ps, cfg.dtype, slots=32)).items()}
+
+    def chunk(w, ids, pools, pages, pos0, creal, ring):
+        cache = spec.gather(
+            spec.init_dense(1, cap, cfg.dtype, ps, True), pools, pages, 1,
+            cap, ring, in_pages=True)
+        last, cache = laguna.forward_cached_row(
+            w, ids, cache, pos0, cfg, creal - 1, pages=pages[None])
+        return (jnp.argmax(last, -1).astype(i32),
+                spec.scatter(pools, cache, pages, ps, ring, in_pages=True))
+
+    monkeypatch.setattr(A, "_auto_impl", lambda: "pallas")
+    with A.chunk_attention_log() as impls:
+        done = jax.jit(chunk, donate_argnums=(2,)).lower(
+            weights, v5e((1, T), i32), pools, v5e((ppseq,), i32),
+            v5e((), i32), v5e((), i32), v5e((rp,), i32)).compile()
+    assert impls == ["pallas", "pallas"]
+    text = done.as_text()
+    assert "_gqa_chunk_flash_paged" in text
+    for shape in (f"{n_pages},{ps},1024", rf"1,8,{cap},128", rf"{cap},8,128",
+                  rf"(1,)?{cap},1024", f"{ppseq},{ps},1024"):
+        assert not re.search(rf"bf16\[{shape}\]\S* copy\(", text), shape
+        assert not re.search(rf"copy-start\S*\(bf16\[{shape}\]", text), shape
+    assert not re.search(rf"bf16\[(1,8,{cap},128|{ppseq},{ps},1024)\]", text)
+    assert done.memory_analysis().temp_size_in_bytes < 0.2e9

@@ -27,7 +27,7 @@ from distributed_llm_scheduler_tpu.backends.device import (  # noqa: E402
 from distributed_llm_scheduler_tpu.frontend.decode_dag import (  # noqa: E402
     build_paged_decode_dag,
 )
-from distributed_llm_scheduler_tpu.models import laguna  # noqa: E402
+from distributed_llm_scheduler_tpu.models import kv_pages, laguna  # noqa: E402
 from distributed_llm_scheduler_tpu.models.kv_pages import PagePool  # noqa: E402
 from distributed_llm_scheduler_tpu.ops import gqa_attention as G  # noqa: E402
 
@@ -65,17 +65,18 @@ def _config(hf=HF):
     return laguna.LagunaConfig.from_hf(hf, dtype=jnp.float32, ring_rows=8)
 
 
-def _engine(cfg, params, impl=None, chunk=16, slots=S, n_pages=None):
-    n_pages = n_pages or slots * PPSEQ + 1
+def _engine(cfg, params, impl=None, chunk=16, slots=S, n_pages=None,
+            ppseq=PPSEQ):
+    n_pages = n_pages or slots * ppseq + 1
     ddag = build_paged_decode_dag(
-        cfg, slots=slots, page_size=PS, n_pages=n_pages, pages_per_seq=PPSEQ,
+        cfg, slots=slots, page_size=PS, n_pages=n_pages, pages_per_seq=ppseq,
         attention_impl=impl)
     cluster = Cluster.from_jax_devices(jax.devices()[:1])
     plan = get_scheduler("heft").schedule(ddag.graph, cluster)
     pool = PagePool(n_pages=n_pages, page_size=PS)
     return DeviceBackend(cluster).paged_decode_engine(
         ddag.graph, plan, cfg, params, pool, slots=slots,
-        pages_per_seq=PPSEQ, seg_steps=4, attention_impl=impl,
+        pages_per_seq=ppseq, seg_steps=4, attention_impl=impl,
         chunk_tokens=chunk)
 
 
@@ -173,6 +174,67 @@ def test_engine_with_interpreted_kernels_serves_the_same_tokens(served):
     assert count(eng, "prefill_attn_kernel_programs") == (
         count(eng, "chunk_waves") + count(eng, "admission_waves")) > 0
     assert count(eng0, "prefill_attn_kernel_programs") == 0
+    # and every chunk program (a's 40 tokens: 16, 16, 8) left the full
+    # layers in their pages; the gather path has no paged form
+    assert eng._chunk_in_pages() and not eng0._chunk_in_pages()
+    assert count(eng, "prefill_paged_chunk_programs") == count(
+        eng, "chunk_waves") == 3
+    assert count(eng0, "prefill_paged_chunk_programs") == 0 < count(
+        eng0, "chunk_waves")
+
+
+def test_a_chunk_writes_its_own_pages_and_the_dense_path_agrees(served):
+    """Two engines on the interpreted kernels, one held to the dense round
+    trip: a short request decodes in one slot while a prompt of three
+    chunks is prefilled in another.  Every chunk program of the paged
+    path changes the chunk's own pages of the four paged pools and no
+    other page but the trash page — not the decoding slot's, not the
+    earlier chunks' — and when the prompt is in, the two engines' pools
+    agree on every page a request holds and on every ring."""
+    cfg, params, reqs, out, _ = served
+    paged, dense = (_engine(cfg, params, impl="pallas_interpret")
+                    for _ in range(2))
+    dense._chunk_in_pages = lambda: False
+    real, seen = paged._chunk_prefill, []
+
+    def watched(ids, pt_row, base, creal, slot=0, nxt_chunk=None):
+        before = {k: np.asarray(v) for k, v in paged.pools.items()}
+        first = real(ids, pt_row, base, creal, slot, nxt_chunk)
+        seen.append((before, {k: np.asarray(v) for k, v in
+                              paged.pools.items()}, np.array(pt_row), base))
+        return first
+
+    paged._chunk_prefill = watched
+    in_pool = lambda name: name.split("_")[1] in ("k", "v")  # noqa: E731
+    for eng in (paged, dense):
+        eng.submit("b", *reqs["b"])     # 10 tokens: prefilled whole
+        eng.submit("a", *reqs["a"])     # 40 tokens: chunks of 16, 16, 8
+        eng.step_segment()
+        while eng.is_prefilling("a"):
+            eng.step_segment()
+    assert [base for *_, base in seen] == [0, 16, 32]
+    held = set(paged._slot_pages[0]) | set(paged._slot_pages[1])
+    assert len(held) > 8 and 0 not in held
+    for before, after, row, base in seen:
+        mine = set(row[base // PS: base // PS + 16 // PS].tolist()) - {0}
+        assert mine and mine <= held
+        for name in before:
+            moved = {int(i) for i in np.nonzero(
+                (before[name] != after[name]).any(axis=(1, 2)))[0]}
+            if in_pool(name):       # a full layer's: out of the shared pool
+                assert mine <= moved <= mine | {0}, (name, base)
+    for name in paged.pools:
+        a, b = np.asarray(paged.pools[name]), np.asarray(dense.pools[name])
+        pages = sorted(held) if in_pool(name) else slice(1, None)
+        np.testing.assert_array_equal(a[pages], b[pages], err_msg=name)
+    assert paged.metrics.counter(
+        "decode.prefill_paged_chunk_programs").value == 3
+    assert dense.metrics.counter(
+        "decode.prefill_paged_chunk_programs").value == 0
+    for eng in (paged, dense):
+        got = eng.run()
+        for rid in ("a", "b"):
+            np.testing.assert_array_equal(got[rid], out[rid])
 
 
 # -- the two kernels against the gather path ---------------------------------------
@@ -236,6 +298,139 @@ def test_chunk_kernel_is_the_loop(pos0, window, before):
         got = laguna.chunk_attention(q[:, :13], k, v, pos0, cfg,
                                      "pallas_interpret", window, before)
         np.testing.assert_allclose(got, want[:, :13], rtol=2e-5, atol=2e-5)
+
+
+#: (pages a slot, chunk's first position, its real rows, sequences):
+#: position 0; one chunk in, two sequences with a table row each; a last
+#: chunk whose live rows end inside a page; the last chunk of a 264-page
+#: slot (the cell's 33,792 rows at a sixteenth); a capacity of 10 pages
+#: that is no whole key block, the chunk's pad rows past it
+PAGED_CHUNKS = [(12, 0, 32, 1), (12, 32, 32, 2), (12, 64, 21, 1),
+                (264, 2080, 32, 1), (10, 64, 13, 1)]
+
+
+@pytest.mark.parametrize("ppseq,base,creal,b", PAGED_CHUNKS)
+def test_paged_chunk_kernel_is_the_loop(ppseq, base, creal, b, monkeypatch):
+    """``_gqa_chunk_flash_paged`` interpreted against the model file's
+    loop over the same rows gathered dense: the chunk's rows written
+    through table rows that are NOT in ascending physical order and whose
+    tails are the trash page, key blocks of 4 pages and query tiles of 2
+    (the cell's 512-row blocks of 128-row pages at a sixteenth); groups
+    of 3 and 5.  Only the chunk's own pages change."""
+    cfg = _config()
+    ps, T, Hkv, hd = PS, 32, 2, 8
+    monkeypatch.setattr(G, "_CHUNK_KV_BLOCK", 4 * ps)
+    monkeypatch.setattr(G, "_CHUNK_Q_TILE", 2 * ps)
+    rng = np.random.default_rng(base + ppseq)
+    mk = lambda *s: jnp.asarray(rng.normal(size=s), jnp.float32)  # noqa: E731
+    n_pages = (b + 1) * ppseq + 1
+    claimed = -(-(base + creal) // ps)
+    rows = np.zeros((b, ppseq), np.int32)
+    rows[:, :claimed] = rng.permutation(np.arange(1, n_pages))[
+        :b * claimed].reshape(b, claimed)
+    assert (np.diff(rows[:, :claimed], axis=1) < 0).any(axis=1).all()
+    pages = jnp.asarray(rows)
+    k0, v0 = mk(n_pages, ps, Hkv * hd), mk(n_pages, ps, Hkv * hd)
+    k_new, v_new = mk(b, T, Hkv * hd), mk(b, T, Hkv * hd)
+    k1 = kv_pages.write_chunk_pages(k0, k_new, pages, jnp.int32(base))
+    v1 = kv_pages.write_chunk_pages(v0, v_new, pages, jnp.int32(base))
+    mine = []
+    for s in range(b):      # a page of the chunk past the table: to trash
+        own = [int(rows[s, j]) for j in range(base // ps, (base + T) // ps)
+               if j < ppseq and rows[s, j]]
+        assert own
+        np.testing.assert_array_equal(
+            np.asarray(k1)[own].reshape(-1, Hkv * hd),
+            np.asarray(k_new)[s, :len(own) * ps])
+        mine += own
+    others = np.setdiff1d(np.arange(1, n_pages), mine)
+    np.testing.assert_array_equal(np.asarray(k1)[others],
+                                  np.asarray(k0)[others])
+    dense = lambda pool: jnp.take(pool, pages, axis=0).reshape(  # noqa: E731
+        b, ppseq * ps, Hkv, hd).transpose(0, 2, 1, 3)
+    for H in (6, 10):
+        q = mk(b, T, H, hd)
+        want = laguna.chunk_attention(q, dense(k1), dense(v1), base, cfg,
+                                      "xla")
+        got = G.gqa_paged_chunk_attention(
+            q, k1, v1, pages, jnp.int32(base), scale=cfg.softmax_scale,
+            impl="pallas_interpret")
+        np.testing.assert_allclose(got[:, :creal], want[:, :creal],
+                                   rtol=2e-5, atol=2e-5)
+        # and the dense kernel on the same tiles is the same to the bit
+        same = laguna.chunk_attention(q, dense(k1), dense(v1), base, cfg,
+                                      "pallas_interpret")
+        if ppseq % 4 == 0:
+            np.testing.assert_array_equal(got[:, :creal], same[:, :creal])
+
+
+def test_the_paged_chunk_kernel_is_taken_by_shape(monkeypatch):
+    """The cell's geometry takes it; a head that is no whole lane tile
+    (GPT-2's 64) or a page under the sublane tile does not, and then
+    ``auto`` keeps the dense round trip where an explicit request
+    raises.  The gather path has no paged form."""
+    from distributed_llm_scheduler_tpu.ops import attention as A
+
+    bf16 = jnp.bfloat16
+    assert G.gqa_paged_chunk_constraints(128, 128, bf16) == []
+    assert "head_dim 64" in G.gqa_paged_chunk_constraints(16, 64, bf16)[0]
+    assert "page_size 8" in G.gqa_paged_chunk_constraints(8, 128, bf16)[0]
+    monkeypatch.setattr(A, "_auto_impl", lambda: "pallas")
+    assert G.gqa_paged_chunk_impl(None, 128, 128, bf16) == "pallas"
+    assert G.gqa_paged_chunk_impl("auto", 16, 64, bf16) == "xla"
+    assert G.gqa_paged_chunk_impl(
+        "pallas_interpret", 16, 64, bf16) == "pallas_interpret"
+    with pytest.raises(ValueError, match="does not qualify"):
+        G.gqa_paged_chunk_impl("pallas", 16, 64, bf16)
+    monkeypatch.setattr(A, "_auto_impl", lambda: "xla")
+    assert G.gqa_paged_chunk_impl(None, 128, 128, bf16) == "xla"
+    with pytest.raises(ValueError, match="no gather form"):
+        G.gqa_paged_chunk_attention(
+            jnp.zeros((1, 8, 6, 8)), jnp.zeros((3, 8, 16)),
+            jnp.zeros((3, 8, 16)), jnp.zeros((1, 2), jnp.int32), 0,
+            scale=1.0, impl="xla")
+
+
+def _walk(jaxpr):
+    """Every equation of ``jaxpr`` and of the jaxprs in its parameters."""
+    for eqn in jaxpr.eqns:
+        yield eqn
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (list, tuple)) else (v,):
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _walk(sub)
+
+
+def test_the_paged_chunk_program_holds_nothing_of_a_slots_capacity():
+    """The chunk program on the paged path, equation by equation: no
+    value of ``capacity x Hkv x head_dim`` elements or more but the pools
+    themselves (each written where it lies) — where the dense round trip
+    holds the slot's whole cache four ways."""
+    cfg, params = _config(), R.make_params(HF, 3)
+    ppseq, found = 128, {}
+    for name in ("paged", "dense"):
+        eng = _engine(cfg, params, impl="pallas_interpret", slots=2,
+                      ppseq=ppseq)
+        if name == "dense":
+            eng._chunk_in_pages = lambda: False
+        eng.submit("a", np.ones((1, 20), np.int32), 2)
+        eng.run()
+        (key, fn), = [(k, f) for k, f in eng._prefill_store.items()
+                      if k[0] == "chunk"]
+        i32 = jnp.int32
+        jaxpr = jax.make_jaxpr(fn)(
+            eng.weights, jnp.zeros((1, key[1]), i32), eng.pools,
+            jnp.zeros((ppseq,), i32), i32(0), i32(1), *eng._ring_args((0,)))
+        pool_shapes = {v.shape for v in eng.pools.values()}
+        floor = ppseq * PS * cfg.n_kv_heads * cfg.head_dim
+        found[name] = sorted(
+            (eqn.primitive.name, v.aval.shape) for eqn in _walk(jaxpr.jaxpr)
+            for v in eqn.outvars
+            if hasattr(v.aval, "shape") and v.aval.shape not in pool_shapes
+            and int(np.prod(v.aval.shape)) >= floor)
+    assert found["paged"] == [], found["paged"]
+    assert len(found["dense"]) >= 8      # K and V of two layers, out and back
 
 
 # -- a chip's share of the experts -----------------------------------------------

@@ -251,3 +251,6 @@ def test_the_engine_counts_the_prefill_programs_on_the_kernel(model):
     latent = model != "gpt2-tiny"
     assert on.get("prefill_attn_kernel_programs", 0) == (4 if latent else 0)
     assert "prefill_attn_kernel_programs" not in counts["xla"]
+    # both keep the dense round trip of the chunk program: a latent row
+    # needs no turn, GPT-2's head of 64 is no whole lane tile
+    assert "prefill_paged_chunk_programs" not in {**on, **counts["xla"]}
