@@ -47,8 +47,11 @@ def analyze_decode(
       ``build_decode_loop`` / ``build_paged_decode_loop`` will reject it
       (scan-loop ineligible).
     * ``DEC003`` (error): inconsistent paged wiring — a task reads pools
-      without the page table (or vice versa), or the per-layer pools
-      disagree on geometry, or the rows a slot feeds a step
+      without the page table (or vice versa; a STATE pool,
+      ``graph.state_kinds``, is a slot's and needs no table), or the
+      per-layer pools disagree on geometry, or a state pool is held by
+      more than one task (its task hands the whole pool back: the loop
+      composer takes ONE writer's), or the rows a slot feeds a step
       (``graph.rows_per_step``, stamped by the paged builder: 1, or a
       family's ``DECODE_ROWS`` where it is stepped with its draft module)
       and the ``draft`` task disagree: more than one row without a
@@ -123,8 +126,30 @@ def analyze_decode(
 
     # DEC003: paged wiring consistency ----------------------------------
     if paged:
+        state_pools = {f"cache_{k}" for k in getattr(graph, "state_kinds", ())}
+
+        def is_state(p: str) -> bool:
+            return p.rsplit("_", 1)[0] in state_pools
+
+        writers: Dict[str, list] = {}
         for t in tasks:
-            has_pool = any(_is_cache_param(p) for p in t.params_needed)
+            for p in t.params_needed:
+                if is_state(p):
+                    writers.setdefault(p, []).append(t.task_id)
+        for p, tids in sorted(writers.items()):
+            if len(tids) > 1:
+                rep.add(
+                    "DEC003",
+                    Severity.ERROR,
+                    f"state pool {p!r} is held by {len(tids)} tasks "
+                    f"({tids[:4]}): a state layer's task hands the whole "
+                    "pool back, and the loop composer keeps one writer's",
+                    param=p,
+                    data={"tasks": tids},
+                )
+        for t in tasks:
+            has_pool = any(_is_cache_param(p) and not is_state(p)
+                           for p in t.params_needed)
             has_table = "page_table" in t.params_needed
             if has_pool != has_table:
                 what = (

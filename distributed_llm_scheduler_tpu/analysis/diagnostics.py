@@ -117,6 +117,7 @@ CODES: Dict[str, str] = {
     "PGL006": "refcount underflow/overflow on a shared page",
     "PGL007": "write or cow split violates copy-on-write discipline",
     "PGL008": "the cache keeps pages the ownership stream does not cover",
+    "PGL009": "the cache keeps per-slot state no page of the stream stands for",
     # -- request-lifecycle protocol (lifecycle_pass) --------------------
     "LCY001": "illegal lifecycle transition (state/timestamp mismatch)",
     "LCY002": "non-monotone per-request timestamps (time travel)",

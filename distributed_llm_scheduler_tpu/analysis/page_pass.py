@@ -1,4 +1,4 @@
-"""Pass — page-lifetime prover (PGL001-PGL008).
+"""Pass — page-lifetime prover (PGL001-PGL009).
 
 Replays the append-only ownership event stream recorded by the
 :class:`~..models.kv_pages.PageOwnershipLog` seam against a REF-COUNTED
@@ -44,6 +44,11 @@ PGL007  copy-on-write violation: a ``write`` on a page with
         allocated before the source reference was dropped
 PGL008  the cache keeps pages the stream does not cover (a ring
         layer's slot-owned pages): an explicit refusal, never a pass
+PGL009  the cache keeps a state a slot that is no page (a state
+        layer): nothing in the stream, and no hash of a page's
+        tokens, stands for it — refused like PGL008, under its own
+        code because the cure differs (a snapshot of the state, not
+        a page in the stream)
 ======  ==========================================================
 
 A step that verifies drafts (``rows_per_step`` > 1) writes one row past
@@ -125,6 +130,18 @@ def analyze_pages(
             Severity.ERROR,
             f"page lifetimes are not proven for this cache: {uncovered}",
             data={"uncovered": uncovered},
+        )
+    unkeyed = (source.get("unkeyed") if isinstance(source, dict)
+               else getattr(source, "unkeyed", None))
+    if unkeyed:
+        # a state a slot is no page at all: nothing in the stream, and no
+        # hash of a page's tokens, stands for it
+        rep.add(
+            "PGL009",
+            Severity.ERROR,
+            f"the cache keeps state the page stream cannot stand for: "
+            f"{unkeyed}",
+            data={"unkeyed": unkeyed},
         )
 
     # page -> seq of the alloc event currently covering it

@@ -200,7 +200,9 @@ def compose_paged_step_fn(
     pool, or pools that differ between layers): layer ``i``'s task emits
     ``{kind}_new`` for each of its pool kinds.  A ring layer's row goes
     through the static ring table at ``lengths mod ring`` instead of the
-    page table (:class:`...models.kv_pages.CacheSpec`).  Layer tasks
+    page table (:class:`...models.kv_pages.CacheSpec`); a state layer's
+    ``{kind}_new`` IS its pool, the decoding slots' states already
+    updated in place by the task, and replaces it.  Layer tasks
     that emit ``stats`` (an expert layer's routing counts) have them
     stacked, layer-major — per name where a layer's ``stats`` is a dict
     of named counts, over the layers that emit that name.
@@ -263,10 +265,10 @@ def compose_paged_step_fn(
             o = outs[f"layer_{i}" if i < n_main else "draft"]
             window = spec.layer(i).window
             for kind in spec.layer_kinds(i):
-                new_pools[f"cache_{kind}_{i}"] = write(
-                    new_pools[f"cache_{kind}_{i}"], o[f"{kind}_new"],
-                    page_table, lengths, active, window,
-                )
+                new_pools[f"cache_{kind}_{i}"] = (
+                    o[f"{kind}_new"] if spec.layer(i).state else write(
+                        new_pools[f"cache_{kind}_{i}"], o[f"{kind}_new"],
+                        page_table, lengths, active, window))
             if isinstance(o.get("stats"), dict):
                 for k, v in o["stats"].items():
                     named.setdefault(k, []).append(v)
@@ -567,7 +569,13 @@ class PagedDecodeEngine:
             self.cache.ring_table(slots, pool.page_size)
             if self.cache.has_rings else None
         )
-        self.sharing    # a cache with ring layers refuses a sharing pool
+        self.sharing    # a cache with ring or state layers refuses sharing
+        if self.cache.has_state and chunk_tokens is None:
+            raise ValueError(
+                "a cache with state layers is prefilled through the chunk "
+                "program only (a scan is stopped at the chunk's last real "
+                "row, which is data there): build the engine with "
+                "chunk_tokens")
         self.page_table = np.full(
             (slots, pages_per_seq), TRASH_PAGE, np.int32
         )
@@ -678,6 +686,11 @@ class PagedDecodeEngine:
                 f"{self._rings.size} slot-owned ring pages in each window "
                 "layer's pool are written by their slots and never pass "
                 "through the allocator")
+        if log is not None and self.cache.has_state:
+            log.unkeyed = (
+                f"{self.slots} slot-owned states in each state layer's pool "
+                "are overwritten by every step and chunk; no page, and no "
+                "hash of a page's tokens, stands for one")
 
     def reset(self) -> None:
         """Fresh pool/table/queue state, compiled programs kept.
@@ -820,7 +833,8 @@ class PagedDecodeEngine:
         Refused for a cache with ring layers: a shared page carries the
         paged layers' rows of a prefix and nothing of the window layers'
         state, so a request that aliased one would decode over rings it
-        never filled.  Refused for a family stepped with its draft
+        never filled; nor of a state layer's.  Refused for a family
+        stepped with its draft
         module too: the draft layer's row of a position is made from
         the token AFTER it, which a page's key (the tokens of the page)
         does not cover for its last row."""
@@ -830,6 +844,11 @@ class PagedDecodeEngine:
                 "prefix sharing is not built for a cache with ring "
                 "(window) layers: a shared page does not carry their "
                 "state; use PagePool(sharing=False)")
+        if on and self.cache.has_state:
+            raise ValueError(
+                "prefix sharing is not built for a cache with state "
+                "layers: a page's hash identifies its rows, not the state "
+                "a slot holds after them; use PagePool(sharing=False)")
         if on and self.rows_per_step > 1:
             raise ValueError(
                 "prefix sharing is not built for a family stepped with "
@@ -891,8 +910,13 @@ class PagedDecodeEngine:
         is longer than one chunk, and the padded chunk grid fits the
         per-slot capacity (the final chunk is padded to ``chunk_tokens``
         rows, so ``ceil(P/chunk) * chunk`` dense-cache rows must exist —
-        otherwise the request falls back to whole-prompt admission)."""
+        otherwise the request falls back to whole-prompt admission).  A
+        cache with state layers has no whole-prompt program: every prompt
+        admits chunked, a short one as one padded chunk (``submit``
+        refuses what the grid cannot hold)."""
         ct = self.chunk_tokens
+        if self.cache.has_state:
+            return True
         if ct is None or prompt_len <= ct:
             return False
         return -(-prompt_len // ct) * ct <= self.capacity
@@ -1118,6 +1142,13 @@ class PagedDecodeEngine:
                 f"{self.capacity} ({self.pages_per_seq} pages x "
                 f"{self.page_size})"
             )
+        if self.cache.has_state and -(-prompt_ids.shape[1] // (
+                self.chunk_tokens)) * self.chunk_tokens > self.capacity:
+            raise ValueError(
+                f"a prompt of {prompt_ids.shape[1]} tokens in chunks of "
+                f"{self.chunk_tokens} passes the per-slot capacity "
+                f"{self.capacity}, and a cache with state layers has no "
+                "whole-prompt program")
         self._queue.append((rid, prompt_ids, max_new_tokens))
         t_sub = self._clock()
         self._submit_t[rid] = t_sub
@@ -1204,11 +1235,16 @@ class PagedDecodeEngine:
     # -- prefill + page scatter (ONE call per admission ROUND; one
     # compiled class per (prompt length, batch size)) ----------------------
     def _ring_args(self, slots) -> tuple:
-        """What a prefill program takes beside the page rows where the
-        cache has ring layers: the ``slots``' own ring pages, flat."""
-        if self._rings is None:
-            return ()
-        return (jnp.asarray(self._rings[list(slots)].reshape(-1)),)
+        """What a prefill program takes beside the page rows of what the
+        ``slots`` own outright (``CacheSpec.owned`` names them for
+        ``gather`` / ``scatter``): their ring pages, flat, where the
+        cache has ring layers, then their rows of the state layers'
+        pools where it has those."""
+        ring = (() if self._rings is None else
+                (jnp.asarray(self._rings[list(slots)].reshape(-1)),))
+        if not self.cache.has_state:
+            return ring
+        return ring + (jnp.asarray(self.cache.state_rows(list(slots))),)
 
     def _prefill_scatter(self, prompt_ids: jax.Array, pt_rows, slots=()):
         """Prefill ``b`` same-length prompts and scatter all their cache
@@ -1230,7 +1266,8 @@ class PagedDecodeEngine:
                 cache = spec.init_dense(b, cap, cfg.dtype, page_size=ps)
                 first, cache = fwd(w, ids, cache, 0, P - 1)
                 return first, spec.scatter(
-                    pools, cache, pages.reshape(b * ppseq), ps, *ring)
+                    pools, cache, pages.reshape(b * ppseq), ps,
+                    **spec.owned(*ring))
 
             fn = jax.jit(_fn, donate_argnums=(2,))
             self._prefill_store[key] = fn
@@ -1321,10 +1358,14 @@ class PagedDecodeEngine:
 
         ONE compile class per ``("chunk", chunk_tokens, 1, impl)`` —
         prompt length, chunk index and the final chunk's real length
-        ``creal`` are DATA (the final chunk is padded with token 0;
-        causal masking keeps pad rows out of every real row's scores,
-        and their K/V rows land at positions ``>= P`` that stay masked
-        until decode overwrites them), and so is the page count: the
+        ``creal`` are DATA (the final chunk is padded with token 0; in an
+        attention layer causal masking keeps pad rows out of every real
+        row's scores, and their K/V rows land at positions ``>= P`` that
+        stay masked until decode overwrites them; a layer that SCANS the
+        chunk has no such mask, and its family must stop its state at
+        row ``creal - 1`` itself — ``forward_cached_row``'s ``row`` —
+        and start it from zero where ``pos_start`` is 0, whatever the
+        slot's rows hold), and so is the page count: the
         gather covers ALL ``pages_per_seq`` table entries (a trash entry
         gathers masked garbage and takes it back).  ``nxt_chunk``: the
         token after each of the chunk's positions, for a family stepped
@@ -1342,12 +1383,13 @@ class PagedDecodeEngine:
 
             def _fn(w, ids, pools, pages, pos0, creal, *ring):
                 kw = {"pages": pages[None]} if in_pages else {}
+                own = spec.owned(*ring)
                 cache = spec.gather(
                     spec.init_dense(1, cap, cfg.dtype, ps, in_pages), pools,
-                    pages, 1, cap, *ring, in_pages=in_pages)
+                    pages, 1, cap, in_pages=in_pages, **own)
                 first, cache = fwd(w, ids, cache, pos0, creal - 1, **kw)
                 return first, spec.scatter(
-                    pools, cache, pages, ps, *ring, in_pages=in_pages)
+                    pools, cache, pages, ps, in_pages=in_pages, **own)
 
             fn = self._prefill_store[key] = jax.jit(_fn, donate_argnums=(2,))
         if key not in self._prefill_cache:
@@ -1523,11 +1565,19 @@ class PagedDecodeEngine:
             # in ``_fold_chunked``): ``seq`` is that segment's ordinal,
             # and the segment counts the chunk in ``prefill_programs_ahead``
             ev = None
+            # a state layer's chunk begins from the state the slot holds
+            # (``base > 0``) or from zero, and stops it at ``creal``
+            carried = ({"state_carried": base > 0, "creal": C}
+                       if self.cache.has_state else {})
+            if carried:
+                self.metrics.counter(
+                    "ssm.chunks_carried" if base else "ssm.first_chunks"
+                ).inc()
             if self.tracer is not None:
                 ev = self.tracer.begin(
                     "prefill_chunk", track="decode", cat="decode",
                     rid=str(st["rid"]), base=base, tokens=C,
-                    seq=self.segments_run,
+                    seq=self.segments_run, **carried,
                 )
             with annotate("prefill_chunk"):
                 first = self._chunk_prefill(
@@ -1931,6 +1981,10 @@ class PagedDecodeEngine:
         )
         remaining = int(self.remaining[slot])
         self._release_pages(self._slot_pages[slot], str(rid), "preempt")
+        if self.cache.has_state:
+            # no snapshot is kept: the resume re-prefills prompt + tokens
+            # and rebuilds the state chunk by chunk from zero
+            self.metrics.counter("decode.state_rebuilds").inc()
         if self.memprof is not None:
             self.memprof.free(self._mem_node, f"kv:{rid}")
         self.page_table[slot] = TRASH_PAGE
@@ -2119,7 +2173,9 @@ class PagedDecodeEngine:
         rows those slots hold)) or a window-layer family's
         (``stats["attn"]``, (steps, layers, 2) = (rows the decoding
         slots' attention read in the full layers, in the window
-        layers)).  Any other named array, and what :meth:`_emitted` read
+        layers)) or a state-layer family's (``stats["ssm"]``, (steps,
+        state layers) = the slots each layer stepped, every layer the
+        same).  Any other named array, and what :meth:`_emitted` read
         for it (``probe``), is the ``stats_probe``'s, if one is set."""
         np = self._np
         if not isinstance(stats, dict):
@@ -2144,11 +2200,17 @@ class PagedDecodeEngine:
                     reg.histogram(
                         "attn.full_row_share", unit="ratio"
                     ).observe(float(full / (full + ring)))
+        if "ssm" in stats:
+            stepped = np.asarray(stats["ssm"])[:, 0]
+            args["ssm_slots"] = float(stepped.sum())
+            for reg in (self.metrics, process_metrics()):
+                reg.histogram("ssm.slots_stepped", unit="slots").observe(
+                    float(stepped[:max(steps_ran, 1)].mean()))
         self._seg_span_args = args
         if self.stats_probe is not None and (stats or probe):
             self.stats_probe(
                 {**{k: np.asarray(v) for k, v in stats.items()
-                    if k not in ("moe", "dsa", "attn")}, **probe},
+                    if k not in ("moe", "dsa", "attn", "ssm")}, **probe},
                 list(self._slot_req), self.lengths.copy(), owed)
 
     def _observe_moe(self, stats, steps_ran: int) -> Dict[str, float]:

@@ -103,8 +103,9 @@ def _chain(fam, config, spec, add, flops, f_embed, layer_fn, f_head,
     ``layer_fn(i)`` makes layer ``i``'s task fn; layers with the same
     local param names and pool kinds share ONE fn object (they must then
     compute the same function), so per-task dispatch compiles each once,
-    not once a layer.  ``shared_alias`` is what every layer aliases
-    beside its weights and its ``cache_{kind}``.  The spec's draft
+    not once a layer.  ``shared_alias`` is what every layer that caches
+    rows aliases beside its weights and its ``cache_{kind}`` (a state
+    layer, or one that caches nothing, reads no table).  The spec's draft
     layers are not the chain's: the paged builder hangs the ``draft``
     task behind the logits task."""
     embed_flops, layer_flops, head_flops = flops
@@ -118,7 +119,8 @@ def _chain(fam, config, spec, add, flops, f_embed, layer_fn, f_head,
             fn = fns[key] = layer_fn(i)
         alias.update(
             {f"cache_{k}": f"cache_{k}_{i}" for k in spec.layer_kinds(i)})
-        alias.update(shared_alias)
+        if spec.layer_kinds(i) and not spec.layer(i).state:
+            alias.update(shared_alias)
         tid = f"layer_{i}"
         add(tid, fn, [prev], alias, layer_flops[i], tid)
         prev = tid
@@ -438,10 +440,12 @@ def build_paged_decode_dag(
         outs = []
         rings = spec.ring_table(S, ps) if spec.has_rings else None
         for s in range(S):
+            owned = {} if rings is None else {"ring": jnp.asarray(rings[s])}
+            if spec.has_state:
+                owned["state"] = jnp.asarray(spec.state_rows([s]))
             cache = spec.gather(
                 spec.init_dense(1, M, config.dtype, page_size=ps), params,
-                params["page_table"][s], 1, M,
-                *(() if rings is None else (jnp.asarray(rings[s]),)))
+                params["page_table"][s], 1, M, **owned)
             if drafts:
                 # row by row: row r's draft input is the main model's own
                 # argmax at row r, which the call decides where nxt < 0
@@ -479,6 +483,8 @@ def build_paged_decode_dag(
         # (or the model) say them: the kernel maps them onto the KV heads
         graph.kv_q_heads = tuple(sorted(
             {lc.q_heads or spec.q_heads for lc in spec.layers} - {None}))
+    # the pool kinds that are a state a slot: one task each, no table (DEC003)
+    graph.state_kinds = tuple(sorted({k for _, k, _, _ in spec._states()}))
     dag = PagedDecodeDAG(
         graph=graph,
         config=config,

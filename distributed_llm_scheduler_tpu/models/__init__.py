@@ -217,6 +217,11 @@ for _f in (
         "laguna", f"{__name__}.laguna", "LagunaConfig",
         {"laguna-tiny": "tiny"}, "n_layers", "max_positions",
     ),
+    # every layer one sublayer; mixers that keep a state a slot, not rows
+    Family(
+        "nemotron_h", f"{__name__}.nemotron_h", "NemotronHConfig",
+        {"nemotron-h-tiny": "tiny"}, "n_layers", "max_positions",
+    ),
 ):
     register_family(_f)
 del _f

@@ -88,6 +88,9 @@ class PageOwnershipLog:
         #: set by an engine whose cache keeps pages this stream never
         #: sees (ring layers): what they are; the prover refuses (PGL008)
         self.uncovered: Optional[str] = None
+        #: set by an engine whose cache keeps a state a slot (state
+        #: layers): what it is; the prover refuses (PGL009)
+        self.unkeyed: Optional[str] = None
 
     def record(
         self,
@@ -126,6 +129,8 @@ class PageOwnershipLog:
         }
         if self.uncovered:
             out["uncovered"] = self.uncovered
+        if self.unkeyed:
+            out["unkeyed"] = self.unkeyed
         return out
 
 
@@ -509,12 +514,16 @@ class LayerCache:
     (itself included).  ``window`` ``None``: the layer caches the whole
     context in pages of the shared pool, through the page table.
     ``q_heads``: the query heads that read this layer's kv rows, where
-    the layers differ in them (else :attr:`CacheSpec.q_heads`)."""
+    the layers differ in them (else :attr:`CacheSpec.q_heads`).
+    ``state``: a **state layer** — each of ``rows`` is one fixed-size
+    array a SLOT (a recurrent state), not a row a token.  ``rows`` may be
+    empty: the layer caches nothing."""
 
     rows: Tuple[Tuple[str, Tuple[int, ...]], ...]
     rank: Optional[int] = None
     window: Optional[int] = None
     q_heads: Optional[int] = None
+    state: bool = False
 
 
 @dataclasses.dataclass(frozen=True)
@@ -568,6 +577,20 @@ class CacheSpec:
     :meth:`block_pages` ask about; ``None``: the first pool of layers
     that are all alike.
 
+    **State layers.**  A layer with ``state`` keeps, for each of its
+    pool kinds, ONE array a slot whatever the context's length (a
+    recurrent mixer's state, its convolution's last inputs): pool ``(1 +
+    slots, *shape)``, row 0 the trash row, slot ``s`` row ``1 + s``
+    (:meth:`state_rows`) — slot-owned like a ring, so the allocator,
+    admission and ``pages_needed`` never hear of it.  It is not indexed
+    by position: a decode step and a prefill chunk each OVERWRITE it, and
+    the step's layer task hands back the whole pool (updated in place
+    for the slots that decode).  The dense cache of a prefill program
+    holds, per state kind, the slots' states stacked in layer order
+    ``(layers with it, b, *shape)``.  ``dtypes`` gives a pool kind its
+    own dtype where it is not the cache's (a float32 state beside bf16
+    K/V).
+
     **Draft layers.**  The last ``draft_layers`` entries are the layers
     of a draft module the family steps itself with (``models.
     DRAFT_FUNCTIONS``): pools like any other, over the same positions,
@@ -582,6 +605,8 @@ class CacheSpec:
     ring_rows: int = 0
     walk: Optional[Tuple[str, Optional[int]]] = None
     draft_layers: int = 0
+    #: ``(pool kind, dtype)`` of the kinds not kept in the cache's dtype
+    dtypes: Tuple[Tuple[str, Any], ...] = ()
 
     @classmethod
     def uniform(cls, kind: str, n_layers: int,
@@ -628,6 +653,31 @@ class CacheSpec:
     def has_rings(self) -> bool:
         return any(lc.window is not None for lc in self.layers)
 
+    @property
+    def has_state(self) -> bool:
+        return any(lc.state for lc in self.layers)
+
+    @staticmethod
+    def state_rows(slots):
+        """The rows of a state layer's pools that ``slots`` own, int32
+        (numpy): slot ``s`` owns row ``1 + s``; row 0 is the trash row."""
+        import numpy as np
+
+        return 1 + np.asarray(slots, np.int32)
+
+    def owned(self, *args) -> Dict[str, Any]:
+        """:meth:`gather` / :meth:`scatter`'s keywords out of the
+        positional arguments a prefill program takes for what its slots
+        own outright: the ring pages where the spec has ring layers,
+        then the state rows where it has state layers."""
+        names = [n for n, has in (("ring", self.has_rings),
+                                  ("state", self.has_state)) if has]
+        return dict(zip(names, args, strict=True))
+
+    def kind_dtype(self, kind: str, dtype: Any) -> Any:
+        """The dtype pool kind ``kind`` is kept in: its own, or ``dtype``."""
+        return dict(self.dtypes).get(kind, dtype)
+
     def ring_pages(self, page_size: int) -> int:
         """Pages a slot owns in each ring layer's pool."""
         return -(-self.ring_rows // page_size)
@@ -641,15 +691,25 @@ class CacheSpec:
         return (1 + np.arange(slots * rp, dtype=np.int32)).reshape(slots, rp)
 
     def _pools(self):
-        """Every pool as ``(layer, kind, row, n, window)``, ``n`` the
-        layer's index among the layers that keep ``kind`` (the layer
-        itself where all are alike)."""
+        """Every pool of rows a token as ``(layer, kind, row, n, window)``,
+        ``n`` the layer's index among the layers that keep ``kind`` (the
+        layer itself where all are alike); a state layer's are
+        :meth:`_states`'."""
         seen: Dict[str, int] = {}
         for i, lc in enumerate(self.layers):
-            for kind, row in lc.rows:
+            for kind, row in () if lc.state else lc.rows:
                 n = seen.get(kind, 0)
                 seen[kind] = n + 1
                 yield i, kind, row, n, lc.window
+
+    def _states(self):
+        """Every state layer's pool as ``(layer, kind, shape, n)``."""
+        seen: Dict[str, int] = {}
+        for i, lc in enumerate(self.layers):
+            for kind, shape in lc.rows if lc.state else ():
+                n = seen.get(kind, 0)
+                seen[kind] = n + 1
+                yield i, kind, shape, n
 
     @property
     def head_dim(self) -> Optional[int]:
@@ -716,17 +776,23 @@ class CacheSpec:
     def init_pools(self, n_pages: int, page_size: int, dtype: Any,
                    slots: Optional[int] = None) -> Dict[str, jax.Array]:
         """Zeroed pools keyed ``cache_{kind}_{i}``, in the stored form;
-        a ring layer's holds ``slots`` rings and the trash page."""
-        if self.has_rings and slots is None:
-            raise ValueError("a spec with ring layers sizes its ring pools "
-                             "by the engine's slots")
+        a ring layer's holds ``slots`` rings and the trash page, a state
+        layer's ``slots`` states and the trash row."""
+        if (self.has_rings or self.has_state) and slots is None:
+            raise ValueError("a spec with ring or state layers sizes their "
+                             "pools by the engine's slots")
         ring = 1 + (slots or 0) * self.ring_pages(page_size)
-        return {
+        pools = {
             f"cache_{kind}_{i}": jnp.zeros(
                 (n_pages if window is None else ring, page_size,
-                 math.prod(row)), dtype)
+                 math.prod(row)), self.kind_dtype(kind, dtype))
             for i, kind, row, _, window in self._pools()
         }
+        pools.update({
+            f"cache_{kind}_{i}": jnp.zeros(
+                (1 + slots, *shape), self.kind_dtype(kind, dtype))
+            for i, kind, shape, _ in self._states()})
+        return pools
 
     def init_slabs(self, batch: int, cap: int,
                    dtype: Any) -> Dict[str, jax.Array]:
@@ -746,7 +812,7 @@ class CacheSpec:
         layer, ``L`` counts the layers that keep the kind and a ring
         kind's ``cap`` is the ring (whole pages of ``page_size``).
         ``in_pages``: the ring kinds alone — the paged layers stay in
-        their pages (:meth:`gather`)."""
+        their pages (:meth:`gather`).  A state kind: ``(L, b, *shape)``."""
         ring = self.ring_pages(page_size or 1) * (page_size or 1)
         shapes: Dict[str, Any] = {}
         for _, kind, row, n, window in self._pools():
@@ -754,7 +820,9 @@ class CacheSpec:
                 continue
             shapes[kind] = (n + 1, *self._dense(
                 kind, (batch, cap if window is None else ring, *row)))
-        return {kind: jnp.zeros(shape, dtype)
+        for _, kind, shape, n in self._states():
+            shapes[kind] = (n + 1, batch, *shape)
+        return {kind: jnp.zeros(shape, self.kind_dtype(kind, dtype))
                 for kind, shape in shapes.items()}
 
     def _dense(self, kind: str, shape):
@@ -771,15 +839,21 @@ class CacheSpec:
     def gather(self, cache: Dict[str, Any], pools: Dict[str, Any],
                pages: jax.Array, batch: int, n_rows: int,
                ring: Optional[jax.Array] = None,
-               in_pages: bool = False) -> Dict[str, Any]:
+               in_pages: bool = False,
+               state: Optional[jax.Array] = None) -> Dict[str, Any]:
         """``cache`` with rows ``[0, n_rows)`` of every layer filled from
         the pools through ``pages`` (flat physical ids, ``batch`` runs);
         a ring layer's whole ring through ``ring`` (the slots' rows of
         :meth:`ring_table`, flat).  ``in_pages``: a paged layer is not
         made dense — its kind's entry is a tuple of the pools themselves,
         in the stored form, one a layer that keeps the kind, for a family
-        whose prefill writes and reads them through the page table."""
+        whose prefill writes and reads them through the page table.  A
+        state layer's states through ``state`` (the sequences' rows of
+        :meth:`state_rows`, ``(batch,)``)."""
         out = dict(cache)
+        for i, kind, _, n in self._states():
+            out[kind] = out[kind].at[n].set(
+                jnp.take(pools[f"cache_{kind}_{i}"], state, axis=0))
         for i, kind, row, n, window in self._pools():
             if in_pages and window is None:
                 out[kind] = out.get(kind, ()) + (pools[f"cache_{kind}_{i}"],)
@@ -802,13 +876,19 @@ class CacheSpec:
     def scatter(self, pools: Dict[str, Any], cache: Dict[str, Any],
                 pages: jax.Array, page_size: int,
                 ring: Optional[jax.Array] = None,
-                in_pages: bool = False) -> Dict[str, Any]:
+                in_pages: bool = False,
+                state: Optional[jax.Array] = None) -> Dict[str, Any]:
         """``pools`` with every page in ``pages`` (flat physical ids,
         covering each sequence's whole capacity) rewritten from the dense
         ``cache``, a ring layer's through ``ring``; out-of-range ids are
         dropped.  ``in_pages``: a paged layer's entry IS its pool
-        (:meth:`gather`), the rows already written where they lie."""
+        (:meth:`gather`), the rows already written where they lie.  A
+        state layer's rows ``state`` are overwritten whole."""
         new = dict(pools)
+        for i, kind, _, n in self._states():
+            pool = new[f"cache_{kind}_{i}"]
+            new[f"cache_{kind}_{i}"] = pool.at[state].set(
+                cache[kind][n].astype(pool.dtype))
         for i, kind, _, n, window in self._pools():
             if in_pages and window is None:
                 new[f"cache_{kind}_{i}"] = cache[kind][n]

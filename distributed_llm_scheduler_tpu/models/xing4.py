@@ -846,3 +846,152 @@ def forward(params, ids, cfg: Xing4Config, impl=None):
     b, T = ids.shape
     return forward_cached(
         params, ids, init_cache(cfg, b, T), 0, cfg, impl)[0]
+
+
+# -- ungated experts: two matrices an expert -------------------------------------
+# Below everything else on purpose: Mosaic's payload carries source
+# lines, so a line added above ``_moe_kernel`` (or above a frame that
+# calls it) recompiles every program of the gated families (PERF.md
+# section 6, PR 39).  That is also why ``_moe_experts`` keeps its own copy
+# of the schedule below instead of calling :func:`_expert_schedule`.
+
+_UNGATED_ACTS = {"relu2": lambda v: jnp.square(jax.nn.relu(v))}
+
+
+def _expert_schedule(idx, E: int, tm: int):
+    """``_moe_experts``' grouped schedule for picks ``idx`` (N, k) over
+    ``E`` held experts (``E`` = not held / not live) in m tiles of ``tm``
+    rows: ``(order, sorted_e, sizes, M, (exp_of, tile_of, lo, hi),
+    n_items)`` — the picks sorted by expert, each held expert's count,
+    the padded row count, and the work items (expert, m tile, the rows
+    ``[lo, hi)`` of the tile that are the expert's), expert-major."""
+    N, k = idx.shape
+    M0 = N * k
+    M = -(-M0 // tm) * tm
+    flat = jnp.pad(idx.reshape(-1), (0, M - M0), constant_values=E)
+    order = jnp.argsort(flat, stable=True)
+    sizes = (flat[:, None] == jnp.arange(E)[None, :]).sum(0, dtype=jnp.int32)
+    off = jnp.cumsum(sizes) - sizes
+    t0, t1 = off // tm, (off + sizes - 1) // tm
+    n_tiles = jnp.where(sizes > 0, t1 - t0 + 1, 0)
+    ends = jnp.cumsum(n_tiles)
+    w = jnp.arange(E + M // tm, dtype=jnp.int32)
+    exp_of = jnp.minimum(
+        (w[:, None] >= ends[None, :]).sum(1, dtype=jnp.int32), E - 1)
+    tile_of = jnp.clip(
+        t0[exp_of] + w - (ends - n_tiles)[exp_of], 0, M // tm - 1)
+    lo = jnp.maximum(off[exp_of], tile_of * tm) - tile_of * tm
+    hi = jnp.minimum(
+        (off + sizes)[exp_of], (tile_of + 1) * tm) - tile_of * tm
+    return (order, flat[order], sizes, M, (exp_of, tile_of, lo, hi),
+            jnp.maximum(ends[-1], 1))
+
+
+def _moe_kernel_ungated(exp_ref, tile_ref, lo_ref, hi_ref, x_ref, u_ref,
+                        d_ref, o_ref, *, n_it, act):
+    """:func:`_moe_kernel` with ``act(x W_u)`` for ``silu(x W_g) * (x
+    W_u)``: one (work item, I tile)."""
+    t = pl.program_id(0)
+    w, it = t // n_it, t % n_it
+    first = jnp.logical_or(w == 0, tile_ref[w] != tile_ref[
+        jnp.maximum(w - 1, 0)])
+
+    @pl.when(jnp.logical_and(first, it == 0))
+    def _zero():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    x = x_ref[...]
+    u = jax.lax.dot_general(x, u_ref[0], (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    row = jax.lax.broadcasted_iota(jnp.int32, u.shape, 0)
+    mine = jnp.logical_and(row >= lo_ref[w], row < hi_ref[w])
+    a = jnp.where(mine, _UNGATED_ACTS[act](u), 0.0).astype(x.dtype)
+    o_ref[...] += jnp.dot(a, d_ref[0], preferred_element_type=jnp.float32)
+
+
+def ungated_i_tile(I: int) -> int:
+    """The I tile of the ungated kernel: the largest divisor of ``I``
+    that is whole 16-row sublane tiles and keeps an (up, down) pair of
+    tiles, double-buffered, well inside VMEM — at most 512 rows; ``I``
+    itself where it has none (toy widths)."""
+    return next((c for c in range(min(I, 512), 15, -1)
+                 if I % c == 0 and c % 16 == 0), I)
+
+
+@functools.partial(jax.jit, static_argnames=("act", "impl", "name"))
+def _moe_experts_ungated(x, idx, gate, up_w, down_w, *, act, impl,
+                         name="_moe_experts"):
+    """:func:`_moe_experts` for experts of TWO matrices, ``act(x W_u)
+    W_d``: ``up_w`` / ``down_w`` (E, I, h).  The same sort, work items and
+    scalar prefetch; the kernel body and the ``ragged_dot`` twin differ
+    in the activation only, and the device trace knows it by the same
+    name."""
+    N, k = idx.shape
+    E, I, h = up_w.shape
+    tm = min(128, -(-N * k // 16) * 16)
+    order, sorted_e, sizes, M, items, n_items = _expert_schedule(idx, E, tm)
+    xs = x[jnp.minimum(order // k, N - 1)]
+    if impl == "xla":
+        sz = jnp.concatenate([sizes, (M - sizes.sum())[None]])
+        u = jax.lax.ragged_dot(
+            xs, jnp.pad(up_w, ((0, 1), (0, 0), (0, 0))).transpose(0, 2, 1),
+            sz, preferred_element_type=jnp.float32)
+        ys = jax.lax.ragged_dot(
+            _UNGATED_ACTS[act](u).astype(x.dtype),
+            jnp.pad(down_w, ((0, 1), (0, 0), (0, 0))), sz,
+            preferred_element_type=jnp.float32)
+    else:
+        ti = ungated_i_tile(I)
+        n_it = I // ti
+
+        def tiles(t, e, tl, lo, hi):
+            return e[t // n_it], t % n_it, 0
+
+        def rows(t, e, tl, lo, hi):
+            return tl[t // n_it], 0
+
+        ys = pl.pallas_call(
+            functools.partial(_moe_kernel_ungated, n_it=n_it, act=act),
+            grid_spec=pltpu.PrefetchScalarGridSpec(
+                num_scalar_prefetch=4, grid=(n_items * n_it,),
+                in_specs=[pl.BlockSpec((tm, h), rows),
+                          pl.BlockSpec((1, ti, h), tiles),
+                          pl.BlockSpec((1, ti, h), tiles)],
+                out_specs=pl.BlockSpec((tm, h), rows),
+            ),
+            out_shape=jax.ShapeDtypeStruct((M, h), jnp.float32),
+            interpret=impl == "pallas_interpret",
+            compiler_params=pltpu.CompilerParams(
+                vmem_limit_bytes=64 << 20),
+            name=name,
+        )(*items, xs, up_w, down_w)
+    wts = jnp.pad(gate.reshape(-1), (0, M - N * k))[order]
+    ys = jnp.where((sorted_e < E)[:, None], ys * wts[:, None], 0.0)
+    back = jnp.argsort(order)[:N * k]
+    return ys[back].reshape(N, k, h).sum(1), sizes
+
+
+def moe_ffn_ungated(p, x, cfg, act: str, held: Optional[Sequence[int]] = None,
+                    live=None, impl: Optional[str] = None, route=None):
+    """:func:`moe_ffn` for a layer whose experts are ungated —
+    ``p['exp_up_w']`` / ``p['exp_down_w']`` (E, I, h), the shared expert
+    ``p['shared_up_w']`` (h, Is) / ``p['shared_down_w']`` (Is, h) — under
+    activation ``act`` (a key of ``_UNGATED_ACTS``).  Routing, ``held``,
+    ``live`` and the stats are :func:`moe_ffn`'s."""
+    idx, gate = (route or moe_route)(p, x, cfg)
+    E = p["exp_up_w"].shape[0]
+    if held is not None:
+        local = np.full((cfg.n_routed_experts,), E, np.int32)
+        local[np.asarray(held)] = np.arange(E)
+        idx = jnp.asarray(local)[idx]
+    if live is not None:
+        idx = jnp.where(live[:, None], idx, E)
+    y, sizes = _moe_experts_ungated(
+        x, idx, gate, p["exp_up_w"], p["exp_down_w"], act=act,
+        impl=_kernel_impl(impl))
+    y = y.astype(x.dtype) + _UNGATED_ACTS[act](
+        x @ p["shared_up_w"]) @ p["shared_down_w"]
+    picks = sizes.astype(jnp.float32)
+    return y, jnp.stack([
+        (sizes > 0).mean(dtype=jnp.float32),
+        picks.max() / jnp.maximum(picks.mean(), 1e-9)])

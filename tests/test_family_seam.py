@@ -164,9 +164,9 @@ def _tiny(family: str):
 # -- the contract -----------------------------------------------------------------
 
 
-def test_the_toy_is_registered_beside_the_seven():
+def test_the_toy_is_registered_beside_the_eight():
     assert FAMILIES == ["dots3", "glm4_lite", "gpt2", "laguna", "llama",
-                        "mixtral", "toy", "xing4"]
+                        "mixtral", "nemotron_h", "toy", "xing4"]
 
 
 @pytest.mark.parametrize("family", FAMILIES)
@@ -289,7 +289,8 @@ def test_what_each_family_offers():
               if models.offers(rows[f], *models.PAGED_FUNCTIONS)}
     dense = {f for f in rows
              if models.offers(rows[f], *models.CACHED_FUNCTIONS)}
-    assert served == {"gpt2", "xing4", "dots3", "glm4_lite", "laguna", "toy"}
+    assert served == {"gpt2", "xing4", "dots3", "glm4_lite", "laguna",
+                      "nemotron_h", "toy"}
     assert dense == {"gpt2", "llama", "mixtral"}
     assert {f for f in rows if models.offers(
         rows[f], *models.DRAFT_FUNCTIONS)} == {"glm4_lite"}
