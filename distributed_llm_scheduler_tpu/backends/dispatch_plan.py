@@ -37,7 +37,8 @@ all fixed before the first launch.  This module moves it to plan time:
   ``Schedule.per_node`` order and topological dispatch order are preserved
   exactly.  Each run is cut into launches (:func:`_cut_runs`: where
   nothing the launch produced is still awaited by the rest of its run,
-  else at :data:`_GROUP_CAP` members) and every launch is ONE jitted
+  before the head of the next chain, else at :data:`_GROUP_CAP` members)
+  and every launch is ONE jitted
   multi-task call: members read in-run values directly and everything
   else (earlier task outputs, ext values, the staged graph input) as
   launch arguments, so per-task placement semantics survive intact.
@@ -270,11 +271,25 @@ def _cut_runs(graph, placement, order: Sequence[str]) -> List[List[str]]:
 
     A span closes where nothing it produced is still read by the rest of
     its same-device run — once it holds a quarter of :data:`_GROUP_CAP`, so
-    that independent chains a policy runs side by side (pack: waves of a
-    few microbatches through one layer) end together and the next wave
+    that independent chains a policy runs side by side (waves of a few
+    microbatches through one layer) end together and the next wave
     starts the same program again — and at :data:`_GROUP_CAP` members
     where the run never comes clean (one chip: the residual stream is
-    always in flight, and the cap's spans repeat down the layers)."""
+    always in flight, and the cap's spans repeat down the layers).
+
+    A span also holds one chain.  A chain's *head* reads nothing its run
+    has produced (the next microbatch's first task on this chip); a span
+    closes before one once it holds that quarter of the cap, or whatever
+    it holds where it is the remainder a cap cut left — so a chain longer
+    than the cap (pack: a microbatch's pass through a chip's run of
+    layers) starts every microbatch's spans at the same task, its value
+    leaves for the next chip when its own chain ends, and a run that never
+    comes clean (each microbatch's logits are read by the concat at its
+    end) is still cut chain by chain.  A head whose ``fn`` no other task
+    of the run carries (a microbatch's own slice of the input) makes every
+    span that holds it a program of its own; where the policy runs the
+    chain through — the next task reads the head — the head is launched
+    alone, and the spans after it are the ones every chain shares."""
     spans: List[List[str]] = []
     i, n = 0, len(order)
     while i < n:
@@ -285,21 +300,38 @@ def _cut_runs(graph, placement, order: Sequence[str]) -> List[List[str]]:
         run = order[i:j]
         idx = {t: k for k, t in enumerate(run)}
         last_read: Dict[int, int] = {}
+        reads_run: set = set()  # positions that read a value of this run
+        fn_uses: Dict[Any, int] = {}
         for k, t in enumerate(run):
+            fn = graph[t].fn
+            fn_uses[fn] = fn_uses.get(fn, 0) + 1
             for d in _arg_ids(graph[t]):
                 p = idx.get(d)
                 if p is not None:
                     last_read[p] = k
+                    reads_run.add(k)
         cur: List[str] = []
         open_until = -1
+        remainder = False  # cur began where the cap cut a chain
         for k, t in enumerate(run):
+            head = k not in reads_run
+            alone = (
+                head and fn_uses[graph[t].fn] == 1 and k + 1 < len(run)
+                and t in _arg_ids(graph[run[k + 1]])
+            )
+            if cur and head and (
+                alone or remainder or 4 * len(cur) >= _GROUP_CAP
+            ):
+                spans.append(cur)
+                cur, open_until, remainder = [], -1, False
             cur.append(t)
             open_until = max(open_until, last_read.get(k, -1))
-            if len(cur) >= _GROUP_CAP or (
+            capped = len(cur) >= _GROUP_CAP
+            if capped or alone or (
                 open_until <= k and 4 * len(cur) >= _GROUP_CAP
             ):
                 spans.append(cur)
-                cur, open_until = [], -1
+                cur, open_until, remainder = [], -1, capped
         if cur:
             spans.append(cur)
         i = j

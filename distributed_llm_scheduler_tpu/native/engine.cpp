@@ -1168,8 +1168,142 @@ void run_pipeline(Run& run, const double* link3, const int32_t* group_ids) {
   run.order = std::move(eo.order);
 }
 
+// Group-to-group edges (sched/pack.py _group_readers): readers[a] = the
+// groups a task of which reads a task of group a, ascending, unique.
+std::vector<std::vector<int32_t>> group_readers(const Graph& g,
+                                                const int32_t* group_ids,
+                                                int n_groups) {
+  std::vector<std::vector<int32_t>> readers(n_groups);
+  for (int t = 0; t < g.n_tasks; ++t) {
+    int b = group_ids[t];
+    for (int k = g.dep_off[t]; k < g.dep_off[t + 1]; ++k) {
+      int a = group_ids[g.dep_ids[k]];
+      if (a != b) readers[a].push_back(b);
+    }
+  }
+  for (auto& rs : readers) {
+    std::sort(rs.begin(), rs.end());
+    rs.erase(std::unique(rs.begin(), rs.end()), rs.end());
+  }
+  return readers;
+}
+
+// sched/pack.py _graph_rank: Kahn's walk over the group edges taking the
+// lowest ready index; groups on a cycle follow by index.
+std::vector<int32_t> graph_rank(
+    const std::vector<std::vector<int32_t>>& readers) {
+  int n = (int)readers.size();
+  std::vector<int32_t> waits(n, 0), rank(n, -1);
+  for (int a = 0; a < n; ++a)
+    for (int b : readers[a]) ++waits[b];
+  std::priority_queue<int32_t, std::vector<int32_t>, std::greater<int32_t>>
+      ready;
+  for (int gi = 0; gi < n; ++gi)
+    if (!waits[gi]) ready.push(gi);
+  int k = 0;
+  while (!ready.empty()) {
+    int a = ready.top();
+    ready.pop();
+    rank[a] = k++;
+    for (int b : readers[a])
+      if (!--waits[b]) ready.push(b);
+  }
+  for (int gi = 0; gi < n; ++gi)
+    if (rank[gi] < 0) rank[gi] = k++;
+  return rank;
+}
+
+// sched/pack.py make_runs_contiguous, line for line: every class of
+// interchangeable groups — placed, one (param-union bytes, activation
+// peak), no parameter another group needs — goes back to the devices LPT
+// chose for it as consecutive runs in the graph's order.  Every device
+// keeps its COUNT of the class, so its param union keeps its bytes, its
+// activation peak its value and LPT's fit test its answer, by construction.
+void make_runs_contiguous(const Graph& g, const GroupStats& st,
+                          const std::vector<std::vector<int32_t>>& readers,
+                          std::vector<int32_t>& dev_of) {
+  std::vector<int32_t> owners(g.n_params, 0);
+  for (int gi = 0; gi < st.n_groups; ++gi)
+    for (int p : st.gparams[gi]) ++owners[p];
+  // classes keyed by (size, activ), in order of their first member
+  std::map<std::pair<double, double>, size_t> class_of;
+  std::vector<std::vector<int32_t>> classes;
+  for (int gi = 0; gi < st.n_groups; ++gi) {
+    if (dev_of[gi] < 0) continue;
+    bool own = true;
+    for (int p : st.gparams[gi])
+      if (owners[p] != 1) own = false;
+    if (!own) continue;
+    auto at = class_of.emplace(
+        std::make_pair(st.pg_of[gi], st.activ[gi]), classes.size());
+    if (at.second) classes.emplace_back();
+    classes[at.first->second].push_back(gi);
+  }
+  auto spread = [&](const std::vector<int32_t>& ms) {
+    for (int gi : ms)
+      if (dev_of[gi] != dev_of[ms[0]]) return true;
+    return false;
+  };
+  classes.erase(std::remove_if(classes.begin(), classes.end(),
+                               [&](const std::vector<int32_t>& ms) {
+                                 return !spread(ms);
+                               }),
+                classes.end());
+  if (classes.empty()) return;
+  std::vector<int32_t> rank = graph_rank(readers);
+  std::vector<std::vector<int32_t>> read_by(st.n_groups);
+  for (int a = 0; a < st.n_groups; ++a)
+    for (int b : readers[a]) read_by[b].push_back(a);
+  for (auto& members : classes) {
+    std::sort(members.begin(), members.end(),
+              [&](int a, int b) { return rank[a] < rank[b]; });
+    std::vector<uint8_t> inside(st.n_groups, 0);
+    std::vector<int32_t> count(g.n_nodes, 0);
+    for (int gi : members) {
+      inside[gi] = 1;
+      ++count[dev_of[gi]];
+    }
+    // the device of a group outside the class next to the run's end,
+    // lowest index, other than `taken`; -1: none holds members
+    auto neighbour = [&](const std::vector<int32_t>& groups, int taken) {
+      int best = -1;
+      for (int x : groups) {
+        int d = dev_of[x];
+        if (inside[x] || d < 0 || !count[d] || d == taken) continue;
+        if (best < 0 || d < best) best = d;
+      }
+      return best;
+    };
+    int first = neighbour(read_by[members.front()], -1);
+    int last = neighbour(readers[members.back()], first);
+    std::vector<int32_t> runs;
+    if (first >= 0) runs.push_back(first);
+    for (int d = 0; d < g.n_nodes; ++d)
+      if (count[d] && d != first && d != last) runs.push_back(d);
+    if (last >= 0) runs.push_back(last);
+    std::vector<int32_t> runs_of(dev_of);
+    size_t it = 0;
+    for (int d : runs)
+      for (int c = 0; c < count[d]; ++c) runs_of[members[it++]] = d;
+    // the group edges at the class's members that cross devices
+    auto crossing = [&](const std::vector<int32_t>& at) {
+      int n = 0;
+      for (int gi : members) {
+        for (int b : readers[gi])
+          if (dev_of[b] >= 0 && at[gi] != at[b]) ++n;
+        for (int a : read_by[gi])
+          if (!inside[a] && dev_of[a] >= 0 && at[gi] != at[a]) ++n;
+      }
+      return n;
+    };
+    // a class the runs bring no fewer crossings keeps LPT's labels
+    if (crossing(runs_of) < crossing(dev_of)) dev_of = runs_of;
+  }
+}
+
 // Group-pack planning (sched/pack.py GroupPackScheduler.plan): LPT packing
-// of groups onto devices by resulting param-union load.  `placed` maps
+// of groups onto devices by resulting param-union load, then interchangeable
+// groups handed out as consecutive runs.  `placed` maps
 // group -> device (-1: fits nowhere); `plan_order` lists the PLACED groups
 // in placement order — the Python dict's insertion order, which the refine
 // search's iteration order depends on.
@@ -1178,7 +1312,8 @@ struct PackPlan {
   std::vector<int32_t> plan_order;
 };
 
-PackPlan pack_plan(const Graph& g, const GroupStats& st) {
+PackPlan pack_plan(const Graph& g, const GroupStats& st,
+                   const int32_t* group_ids) {
   int n_dev = g.n_nodes;
   PackPlan plan;
   plan.placed.assign(st.n_groups, -1);
@@ -1221,6 +1356,8 @@ PackPlan pack_plan(const Graph& g, const GroupStats& st) {
     for (int p : st.gparams[gi]) dev_params[best_d][p] = 1;
     dev_act[best_d] = std::max(dev_act[best_d], st.activ[gi]);
   }
+  make_runs_contiguous(g, st, group_readers(g, group_ids, st.n_groups),
+                       plan.placed);
   return plan;
 }
 
@@ -1321,7 +1458,7 @@ void run_pack(Run& run, const double* link3, const int32_t* group_ids) {
   const Graph& g = run.g;
   std::vector<int32_t> topo = g.toposort();
   GroupStats st = group_stats(g, group_ids);
-  PackPlan plan = pack_plan(g, st);
+  PackPlan plan = pack_plan(g, st, group_ids);
   pack_commit(run, plan.placed, group_ids, link3, topo);
 }
 
@@ -1423,7 +1560,7 @@ void run_refine(Run& run, const double* link3, const int32_t* group_ids,
   constexpr double TOL = 1e-9;
   std::vector<int32_t> topo = g.toposort();
   GroupStats st = group_stats(g, group_ids);
-  PackPlan plan = pack_plan(g, st);
+  PackPlan plan = pack_plan(g, st, group_ids);
 
   if (plan.plan_order.empty() || n_dev <= 1) {
     pack_commit(run, plan.placed, group_ids, link3, topo);

@@ -1,20 +1,31 @@
-"""Group-pack policy: balanced, locality-first group packing.
+"""Group-pack policy: balanced group packing, equal groups in runs.
 
 Born from a bench regime where the host link is slow next to compute:
 with parameter loads dominating, makespan floors at the heaviest
-device's param bytes, and *contiguity* — the pipeline policy's defining
-constraint — stops paying for itself because ICI transfers are two orders
-of magnitude cheaper than host loads.  This policy drops contiguity and
-solves the remaining problem directly:
+device's param bytes, so the policy balances those bytes first and asks
+for no contiguity that would cost balance:
 
 1. bucket tasks by ``group`` (one weight-set per group, exactly the unit
    the reference's param-cache model revolves around — reference
    ``schedulers.py:63-76`` charges per-param load once per node);
 2. pack groups onto devices, largest parameter footprint first, each onto
    the device minimizing the resulting param-union load time — classic
-   LPT bin balancing with union-aware sizes, so weight-tied groups
-   gravitate to the device already holding their shared table;
-3. order execution with the dependency-aware event simulation
+   LPT bin balancing with union-aware sizes.  A group tied to a table
+   another device already holds goes to that device only while that is
+   still the lighter union: at the placed benchmark's shape (GPT-2 medium,
+   four chips) ``embed`` lands on core_0 and ``head`` on core_1, each chip
+   with its own ``wte``, and the 24 equal ``layer_i`` are dealt out in turn
+   — 4 / 4 / 8 / 8 of them, no two consecutive ones on one chip;
+3. hand every class of groups LPT cannot tell apart back to the devices
+   it chose as consecutive runs (:func:`make_runs_contiguous`): the same
+   number on every device, so the same bytes, peaks and fits, but a
+   microbatch leaves a chip once per run instead of once per layer.  On
+   the wire a hop is cheap (ICI is two orders of magnitude faster than a
+   host load); on the chip each hop costs the *host* a put and a launch
+   (~0.16 and ~0.13-0.2 ms), and the host is what a placed step waits for:
+   25 hops a microbatch were 196 launches and 200 puts a step, 3 hops are
+   64 and 24 (PERF.md section 6, PR 43);
+4. order execution with the dependency-aware event simulation
    (:mod:`.eventsim`), which recovers 1F1B-style interleaving from the
    DAG structure, and run every chain through
    (:func:`run_chains_through`): the simulation commits a node one task
@@ -22,17 +33,19 @@ solves the remaining problem directly:
    lockstep, a task of each in turn, and the first one's result reaches
    the next chip no sooner than the second's.
 
-On the flagship bench graph this replays at 21.6 ms vs greedy's 23.3 ms
-and pipeline's 23.3 ms under the measured link (load spread 26-31 MB/core
-vs a 29 MB perfect split).  In compute-bound regimes it degrades toward
-plain load balancing — the evaluator sweep keeps all policies comparable.
+In compute-bound regimes it degrades toward plain load balancing — the
+evaluator sweep keeps all policies comparable.  (The 21.6-against-23.3 ms
+that earlier headers quoted for pack against greedy and pipeline were
+cost-model replays under an estimated link, not speeds on a chip.)
 """
 
 from __future__ import annotations
 
+import heapq
 from typing import Dict, List, Optional, Set, Tuple
 
 from ..backends.sim import LinkModel
+from ..obs import process_metrics
 from .base import BaseScheduler, SchedulerRun
 from .eventsim import dependency_aware_order
 from .pipeline import _group_stats
@@ -50,9 +63,9 @@ def run_chains_through(graph, placement, order: List[str]) -> List[str]:
     node that runs tasks one at a time a chain started is best finished:
     its last value leaves for the next node after the chain's own tasks,
     not after those of every chain interleaved with it.  It is also what
-    lets the placed executor launch a microbatch's pass through a layer as
-    one program (``backends/dispatch_plan._cut_runs`` closes a span where
-    a chain ends)."""
+    lets the placed executor launch a microbatch's pass through a node's
+    run of layers as programs of its own (``backends/dispatch_plan.
+    _cut_runs`` closes a span where a chain ends)."""
     pos = {tid: i for i, tid in enumerate(order)}
     done: Set[str] = set()
     held: Set[Tuple[str, str]] = set()  # (node, value read from elsewhere)
@@ -83,8 +96,144 @@ def run_chains_through(graph, placement, order: List[str]) -> List[str]:
     return out
 
 
+def _group_readers(graph, gidx: Dict[str, int]) -> List[Set[int]]:
+    """``readers[a]``: the groups (by index) a task of which reads a task
+    of group ``a`` — the group-to-group edges of the graph."""
+    readers: List[Set[int]] = [set() for _ in gidx]
+    for t in graph.tasks():
+        b = gidx[t.group or t.task_id]
+        for d in t.dependencies:
+            a = gidx[graph[d].group or d]
+            if a != b:
+                readers[a].add(b)
+    return readers
+
+
+def _graph_rank(readers: List[Set[int]]) -> List[int]:
+    """Position of every group in the order the graph gives them: group B
+    after A where B reads A, first appearance (the index) otherwise — Kahn's
+    walk taking the lowest ready index; groups on a cycle (two groups whose
+    tasks read each other) follow by index."""
+    n = len(readers)
+    waits = [0] * n
+    for a in range(n):
+        for b in readers[a]:
+            waits[b] += 1
+    ready = [g for g in range(n) if not waits[g]]
+    heapq.heapify(ready)
+    rank = [-1] * n
+    k = 0
+    while ready:
+        a = heapq.heappop(ready)
+        rank[a] = k
+        k += 1
+        for b in readers[a]:
+            waits[b] -= 1
+            if not waits[b]:
+                heapq.heappush(ready, b)
+    for g in range(n):
+        if rank[g] < 0:
+            rank[g] = k
+            k += 1
+    return rank
+
+
+def make_runs_contiguous(
+    dev_of: List[int], size: List[float], activ: List[float],
+    gparams: List[Set[str]], readers: List[Set[int]],
+) -> int:
+    """Hand every class of interchangeable groups back to the devices LPT
+    chose for it as consecutive runs; ``dev_of`` (group index -> device,
+    -1 unplaced) is rewritten in place, the number of groups that moved
+    returned.
+
+    A class is the placed groups of one ``(size, activ)`` — parameter-union
+    bytes and activation peak — none of whose parameters another group
+    needs.  LPT cannot tell them apart: which device got which was decided
+    by the sort index alone.  Every device keeps its COUNT of the class, so
+    its parameter union keeps its bytes (the members' parameters are theirs
+    alone and weigh the same), its activation peak keeps its value (the
+    members' peaks are equal) and ``lg + max(dev_act, activ) <=
+    total_memory`` holds on every device exactly as it held when LPT placed
+    the last group there: per-device loads, peaks and fits are unchanged by
+    construction, under unequal caps too.  A class on one device, or of one
+    group, has nothing to hand back.
+
+    The class's groups are taken in the graph's order (:func:`_graph_rank`)
+    and the devices in index order, except that the device of a group
+    outside the class that the first member reads comes first and the
+    device of one that reads the last member comes last, where they hold
+    members at all (both ties to the lower index).  A class the runs do
+    not bring fewer cross-device group edges — groups side by side that
+    read one another nowhere, vocabulary shards — keeps LPT's labels."""
+    owners: Dict[str, int] = {}
+    for ps in gparams:
+        for p in ps:
+            owners[p] = owners.get(p, 0) + 1
+    classes: Dict[Tuple[float, float], List[int]] = {}
+    for gi, d in enumerate(dev_of):
+        if d >= 0 and all(owners[p] == 1 for p in gparams[gi]):
+            classes.setdefault((size[gi], activ[gi]), []).append(gi)
+    classes = {
+        k: ms for k, ms in classes.items()
+        if len({dev_of[gi] for gi in ms}) > 1
+    }
+    if not classes:
+        return 0
+    rank = _graph_rank(readers)
+    read_by: List[List[int]] = [[] for _ in dev_of]
+    for a, rs in enumerate(readers):
+        for b in rs:
+            read_by[b].append(a)
+    moved = 0
+    for members in classes.values():
+        members.sort(key=rank.__getitem__)
+        inside = set(members)
+        count: Dict[int, int] = {}
+        for gi in members:
+            count[dev_of[gi]] = count.get(dev_of[gi], 0) + 1
+
+        def neighbour(groups, taken: Optional[int]) -> Optional[int]:
+            devs = [
+                dev_of[x] for x in groups
+                if x not in inside and dev_of[x] in count
+                and dev_of[x] != taken
+            ]
+            return min(devs) if devs else None
+
+        first = neighbour(read_by[members[0]], None)
+        last = neighbour(readers[members[-1]], first)
+        runs = [first] if first is not None else []
+        runs += [d for d in sorted(count) if d not in (first, last)]
+        if last is not None:
+            runs.append(last)
+        runs_of = list(dev_of)
+        it = iter(members)
+        for d in runs:
+            for _ in range(count[d]):
+                runs_of[next(it)] = d
+
+        def crossing(at: List[int]) -> int:
+            # the group edges at the class's members that cross devices
+            # (an edge between two members is counted at its source)
+            return sum(
+                sum(at[gi] != at[b] for b in readers[gi] if dev_of[b] >= 0)
+                + sum(
+                    at[gi] != at[a] for a in read_by[gi]
+                    if a not in inside and dev_of[a] >= 0
+                )
+                for gi in members
+            )
+
+        if crossing(runs_of) < crossing(dev_of):
+            moved += sum(a != b for a, b in zip(dev_of, runs_of))
+            dev_of[:] = runs_of
+    return moved
+
+
 class GroupPackScheduler(BaseScheduler):
-    """Non-contiguous balanced group packing (LPT over param-union loads)."""
+    """Balanced group packing (LPT over param-union loads), interchangeable
+    groups handed out as consecutive runs."""
 
     name = "pack"
 
@@ -92,9 +241,10 @@ class GroupPackScheduler(BaseScheduler):
         self.link = link or LinkModel()
 
     def plan(self, graph, devices) -> Dict[str, int]:
-        """LPT group packing: group name -> device index (unplaceable
-        groups absent).  The refinement policy (:mod:`.refine`) reuses this
-        as its search seed."""
+        """LPT group packing, then :func:`make_runs_contiguous`: group name
+        -> device index (unplaceable groups absent), in LPT's placement
+        order.  The refinement policy (:mod:`.refine`) reuses this as its
+        search seed."""
         n_dev = len(devices)
         groups, compute, activ, gparams = _group_stats(graph)
 
@@ -102,13 +252,13 @@ class GroupPackScheduler(BaseScheduler):
             # sorted-name accumulation: deterministic and native-parity-safe
             return sum(graph.param_size_gb(p) for p in sorted(names))
 
+        size = [union_gb(ps) for ps in gparams]
         dev_params: List[Set[str]] = [set() for _ in range(n_dev)]
         dev_act = [0.0] * n_dev
-        placed: Dict[str, int] = {}
+        dev_of = [-1] * len(groups)
+        placed_order: List[int] = []
         # largest parameter footprint first (LPT), ties by group order
-        order = sorted(
-            range(len(groups)), key=lambda i: (-union_gb(gparams[i]), i)
-        )
+        order = sorted(range(len(groups)), key=lambda i: (-size[i], i))
         for gi in order:
             best_d, best_load = None, None
             for d in range(n_dev):
@@ -122,10 +272,22 @@ class GroupPackScheduler(BaseScheduler):
                     best_d, best_load = d, lg
             if best_d is None:
                 continue  # group fits nowhere: its tasks fail below
-            placed[groups[gi]] = best_d
+            dev_of[gi] = best_d
+            placed_order.append(gi)
             dev_params[best_d] |= gparams[gi]
             dev_act[best_d] = max(dev_act[best_d], activ[gi])
-        return placed
+
+        readers = _group_readers(
+            graph, {g: i for i, g in enumerate(groups)}
+        )
+        moved = make_runs_contiguous(dev_of, size, activ, gparams, readers)
+        registry = process_metrics()
+        registry.gauge("sched.pack.groups_made_contiguous").set(moved)
+        registry.gauge("sched.pack.cross_node_group_edges").set(sum(
+            dev_of[a] >= 0 and dev_of[b] >= 0 and dev_of[a] != dev_of[b]
+            for a, rs in enumerate(readers) for b in rs
+        ))
+        return {groups[gi]: dev_of[gi] for gi in placed_order}
 
     def run_policy(self, run: SchedulerRun) -> None:
         self.commit(run, self.plan(run.graph, run.cluster.devices))

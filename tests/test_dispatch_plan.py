@@ -381,11 +381,13 @@ def _default_plan(dag, params, backend, schedule):
     )
 
 
-FUSED_CASES = [(1, "heft"), (4, "pack")]
+# the third: eight equal layers over four chips, which ``pack`` hands out
+# as runs of two — a microbatch's chain on a chip is two layers long
+FUSED_CASES = [(1, "heft"), (4, "pack"), (4, "pack", 8)]
 
 
 @pytest.fixture(scope="module", params=FUSED_CASES,
-                ids=[f"{p}-{n}dev" for n, p in FUSED_CASES])
+                ids=["-".join(map(str, c)) for c in FUSED_CASES])
 def fused(request):
     """A graph and backend of this group's own, run once with default
     arguments: (dag, params, ids, backend, schedule, first report)."""
@@ -461,7 +463,7 @@ def test_a_second_call_builds_no_group_executable(fused):
 
 
 @pytest.mark.parametrize("n_devices,policy,launches,edges,programs", [
-    (1, "heft", 49, 0, 4), (4, "pack", 196, 392, 6),
+    (1, "heft", 49, 0, 4), (4, "pack", 64, 48, 3),
 ])
 def test_launch_counts_at_the_benchmark_shape(
         n_devices, policy, launches, edges, programs):
@@ -469,9 +471,10 @@ def test_launch_counts_at_the_benchmark_shape(
     microbatches, 1,561 tasks — planned and never run (no weights): the
     launches (one a span), transfer edges and fused programs PERF.md and
     the ledger's ``launches_step`` quote, under ``heft`` on one device and
-    ``pack`` on four (which runs a microbatch through a layer before it
-    takes the next: a span is such a chain, the same program in every
-    layer), donating as the chip does."""
+    ``pack`` on four (a chip holds a run of 4 or 8 consecutive layers and
+    runs a microbatch through them before it takes the next: a span is
+    four layers of one microbatch, the same program on every chip),
+    donating as the chip does."""
     import jax.numpy as jnp
 
     dag = build_gpt2_dag(
@@ -499,6 +502,72 @@ def test_launch_counts_at_the_benchmark_shape(
     assert (plan.n_launches, plan.transfer_edges) == (launches, edges)
     assert len(backend._group_cache) == programs
     assert sum(len(st.tids) for st in plan.steps) == 1561
+    if n_devices == 4:
+        # every span but the last (``output_concat``) is one microbatch's
+        for st in plan.steps[:-1]:
+            assert len({t.split("_")[0] for t in st.tids}) == 1, st.tids
+        assert sum(len(st.xfer_slots) for st in plan.steps) == 24
+        sizes = sorted(len(st.tids) for st in plan.steps)
+        assert sizes == [1] * 8 + [2] * 7 + [3] + [32] * 48
+
+
+def _chains_on_a_node(lengths, sink, own_head_fn=False):
+    """``len(lengths)`` chains on one node, chain ``c`` a line of
+    ``lengths[c]`` tasks whose head reads a value of another node; with
+    ``sink`` one last task reads every chain's end, so the run never comes
+    clean; ``own_head_fn`` gives every head a ``fn`` of its own."""
+    from distributed_llm_scheduler_tpu import Task, TaskGraph
+
+    def f(p, x):
+        return x
+
+    tasks = [Task("far", 1e-6, 1e-4, fn=f)]
+    order, ends = [], []
+    for c, n in enumerate(lengths):
+        prev = "far"
+        for i in range(n):
+            tid = f"c{c}_{i}"
+            fn = (lambda p, x: x) if own_head_fn and i == 0 else f
+            tasks.append(Task(
+                tid, 1e-6, 1e-4, dependencies=[prev], fn=fn,
+                arg_tasks=[prev],
+            ))
+            order.append(tid)
+            prev = tid
+        ends.append(prev)
+    if sink:
+        tasks.append(Task(
+            "sink", 1e-6, 1e-4, dependencies=ends, fn=f, arg_tasks=ends,
+        ))
+        order.append("sink")
+    graph = TaskGraph(tasks, name="chains_on_a_node").freeze()
+    placement = {t: "n0" for t in order}
+    placement["far"] = "n1"
+    return graph, placement, order
+
+
+@pytest.mark.parametrize("lengths,sink,own_head_fn,want", [
+    # a head where the span is the remainder a cap cut left: cut
+    ((34, 34), True, False, [32, 2, 32, 3]),
+    # a head under a quarter of the cap is no cut; at 10 members it is
+    ((5, 5, 5, 5), True, False, [10, 11]),
+    # a head once the span holds a quarter of the cap
+    ((9, 9), True, False, [9, 10]),
+    # no head ever: the cap alone
+    ((70,), False, False, [32, 32, 6]),
+    # a head with a fn of its own, run through: launched alone, and the
+    # cap's spans after it are the ones every chain shares
+    ((33, 33), True, True, [1, 32, 1, 32, 1]),
+    # the same heads side by side (the next task is no reader): together
+    ((1, 1, 1, 1, 1, 1, 1, 1), False, True, [8]),
+], ids=["head_after_cap_remainder", "head_under_a_quarter", "head_at_a_quarter",
+        "no_head_cap_only", "own_fn_head_alone", "own_fn_heads_side_by_side"])
+def test_cut_runs_closes_a_span_where_a_chain_ends(
+        lengths, sink, own_head_fn, want):
+    graph, placement, order = _chains_on_a_node(lengths, sink, own_head_fn)
+    spans = _cut_runs(graph, placement, order)
+    assert [t for sp in spans for t in sp] == order
+    assert [len(sp) for sp in spans] == want
 
 
 def test_tasks_per_launch_lands_in_the_process_registry(deep):

@@ -188,3 +188,33 @@ def test_refine_parity_misaligned_node_ids():
     py = get_scheduler("refine").schedule(graph, cluster)
     nat = NativeScheduler("refine").schedule(graph, cluster)
     assert_same_schedule(py, nat, "refine misaligned node ids")
+
+
+@pytest.mark.parametrize("policy,microbatches,n_tasks", [
+    ("pack", 8, 1561), ("refine", 2, 391),
+])
+def test_parity_where_packs_runs_engage(policy, microbatches, n_tasks):
+    """The DAG cells' own graph at tiny widths — 24 equal layer groups; 8
+    microbatches, 1,561 tasks, for ``pack``; 2 for ``refine``, whose climb
+    from that seed replays the graph 400 times in Python — on four
+    devices: LPT deals the layers out in turn and ``make_runs_contiguous``
+    hands them back as runs (the tiny graphs above have no class of two
+    groups spread over two devices), so the C++ pass is held to the Python
+    one where it moves groups."""
+    import dataclasses
+
+    from distributed_llm_scheduler_tpu.frontend.gpt2_dag import build_gpt2_dag
+    from distributed_llm_scheduler_tpu.models.gpt2 import GPT2Config
+    from distributed_llm_scheduler_tpu.obs import process_metrics
+
+    graph = build_gpt2_dag(
+        dataclasses.replace(GPT2Config.tiny(), n_layer=24),
+        batch=microbatches, seq_len=16, microbatches=microbatches,
+    ).graph
+    assert len(graph.topo_order) == n_tasks
+    py = ALL_SCHEDULERS[policy]().schedule(graph, Cluster.uniform(4, 4.0))
+    assert process_metrics().snapshot()["gauges"][
+        "sched.pack.groups_made_contiguous"]["value"] > 0
+    nat = NativeScheduler(policy).schedule(graph, Cluster.uniform(4, 4.0))
+    assert not py.failed
+    assert_same_schedule(py, nat, f"{policy}/24 equal layers")
