@@ -21,7 +21,14 @@ are the model file's.
   mid-prefill or is empty costs no byte and keeps its bytes.  One grid
   step does a slot's depthwise convolution (shift the last ``K - 1``
   inputs, add the new one), ``silu``, ``dt``, the decay, the update and
-  ``y`` over its whole state.
+  ``y`` over its whole state.  The state's tiles stay ``(P sublanes, N
+  lanes)`` as the pool stores them, so ``dt x`` has to cross the lanes
+  once — a square transpose a slot, one lane broadcast a tile — and
+  nothing else does: ``y = h . C`` sums over the lanes, eight reductions
+  a head on the cross-lane unit, so it is the MXU's, which has nothing
+  else to do here — a group's rows against ``C`` in a float32 matmul
+  that leaves ``y`` along the lanes, where the output wants it.  The
+  slot's arithmetic then fits under its DMA (PERF.md section 5).
 * :func:`ssd_chunk` / ``_ssd_chunk`` — a chunk's scan for one sequence in
   the chunked (state-space dual) form: blocks of ``block`` tokens, inside
   a block a masked ``(C B^T) . decay`` product, between blocks the carried
@@ -110,11 +117,24 @@ def _ssm_step_kernel(rows_ref, new_ref, dt_ref, w_ref, b_ref, hp_ref,
     """One decoding slot.  Channels lie ``N`` to a row: ``R`` rows hold
     the convolution's ``W = R N`` channels — first the heads' ``x``
     (``N / P`` heads a row), then a row of ``B`` a group, then of ``C``.
-    ``hp_ref`` (3, H): ``dt_bias``, ``A_log``, ``D``."""
+    ``dt_ref`` (1, N / P, H P / N) and ``hp_ref`` (3, N / P, H P / N) —
+    ``dt_bias``, ``A_log``, ``D`` — hold head ``r N / P + e`` at ``[e,
+    r]``, where its ``x`` lies once transposed.
+
+    On the lanes: the channels, through the convolution and ``silu``;
+    after ONE square transpose the ``x`` rows ``r`` (``p`` on the
+    sublanes, as in the state's tiles), where ``dt x`` and ``D x`` are
+    plain products with a head's ``dt`` / ``D`` spread down its ``P``
+    sublanes; ``N`` in the update ``h dA + (dt x) (x) B`` — float32 on
+    the VPU in that order, ``B`` a row, ``dt x`` one lane broadcast a
+    tile and ``dA`` one a head: all the cross-lane work a head asks for;
+    and ``(head, p)`` in ``y``, because the MXU sums ``h . C`` over ``N``
+    — a group's ``H / G`` heads of new rows against the ``G`` rows of
+    ``C``, row ``g`` kept — and ``D x`` is transposed back beside it."""
     H, P, G = heads, head_dim, groups
     N = h_ref.shape[-1]
     K1 = conv_ref.shape[1]
-    per_row, x_rows = N // P, H * P // N
+    per_row, x_rows, hpg = N // P, H * P // N, H // G
     new = new_ref[0]
     acc = b_ref[...] + w_ref[K1] * new.astype(jnp.float32)
     for j in range(K1):
@@ -123,26 +143,37 @@ def _ssm_step_kernel(rows_ref, new_ref, dt_ref, w_ref, b_ref, hp_ref,
         conv_out[0, j] = conv_ref[0, j + 1]
     conv_out[0, K1 - 1] = new
     xbc = jax.nn.silu(acc)                                   # (R, N)
-    # the heads' x with P on the sublanes: one square transpose
-    x = xbc[:x_rows]
-    xt = jnp.concatenate(
-        [x, jnp.zeros((N - x_rows, N), jnp.float32)], 0).T   # (N, N)
-    dt = jax.nn.softplus(dt_ref[0] + hp_ref[0:1, :])         # (1, H)
-    dA = jnp.exp(dt * -jnp.exp(hp_ref[1:2, :]))
-    lane = jax.lax.broadcasted_iota(jnp.int32, (P, N), 1)
-    yt = [jnp.zeros((P, N), jnp.float32) for _ in range(per_row)]
-    for hd in range(H):
-        r, e, g = hd // per_row, hd % per_row, hd // (H // G)
-        col = xt[e * P:(e + 1) * P, r:r + 1]                 # (P, 1)
+    dt = jax.nn.softplus(dt_ref[0] + hp_ref[0])              # (N / P, x_rows)
+    dA = jnp.exp(dt * -jnp.exp(hp_ref[1]))
+
+    def turned(v):                  # padded to a square tile, transposed
+        return jnp.pad(v, [(0, N - n) for n in v.shape]).T
+
+    def down(v):                    # a head's value down its P sublanes
+        return jnp.concatenate([jnp.broadcast_to(v[e:e + 1], (P, x_rows))
+                                for e in range(per_row)], 0)
+
+    # the heads' x with P on the sublanes: xt[e P + p, r] = x[r N / P + e, p]
+    xt = turned(xbc[:x_rows])[:, :x_rows]
+    dtx, dAd = xt * down(dt), down(dA)
+    skip = turned(xt * down(hp_ref[2]))[:x_rows]             # D x, lane-dense
+    C = xbc[x_rows + G:x_rows + 2 * G]                       # (G, N)
+    g_rows = hpg * P // N
+    for g in range(G):
         Bg = xbc[x_rows + g:x_rows + g + 1]                  # (1, N)
-        Cg = xbc[x_rows + G + g:x_rows + G + g + 1]
-        hn = (h_ref[0, hd] * dA[:, hd:hd + 1]
-              + (col * dt[:, hd:hd + 1]) * Bg)
-        h_out[0, hd] = hn
-        ycol = ((hn * Cg).sum(axis=1, keepdims=True)
-                + hp_ref[2:3, hd:hd + 1] * col)
-        yt[e] = yt[e] + jnp.where(lane == r, ycol, 0.0)
-    y_ref[0] = jnp.concatenate(yt, 0).T[:x_rows]
+        for hd in range(g * hpg, (g + 1) * hpg):
+            r, e = hd // per_row, hd % per_row
+            at = (slice(e * P, (e + 1) * P), slice(r, r + 1))
+            h_out[0, hd] = h_ref[0, hd] * dAd[at] + dtx[at] * Bg
+        # the group's (head, p) rows against every group's C, contracted
+        # over N: row g has this group's y along the lanes
+        yg = jax.lax.dot_general(
+            C, h_out[0, g * hpg:(g + 1) * hpg].reshape(hpg * P, N), _NT,
+            precision=_HIGHEST, preferred_element_type=jnp.float32)
+        for j in range(g_rows):
+            row = g * g_rows + j
+            y_ref[0, row:row + 1] = (yg[g:g + 1, j * N:(j + 1) * N]
+                                     + skip[row:row + 1])
 
 
 @functools.partial(jax.jit, static_argnames=("heads", "head_dim", "groups",
@@ -177,6 +208,11 @@ def _ssm_step(new, dt_raw, conv_w, conv_b, dt_bias, a_log, d_skip, conv_pool,
     def whole(*shape):
         return pl.BlockSpec(shape, lambda i, lr: (0,) * len(shape))
 
+    lay = (N // P, H * P // N)
+
+    def by_row(v):      # (n, H): head r N / P + e to [n, e, r]
+        return v.reshape(-1, lay[1], lay[0]).swapaxes(1, 2)
+
     y, conv_pool, ssm_pool = pl.pallas_call(
         functools.partial(_ssm_step_kernel, heads=H, head_dim=P, groups=G),
         grid_spec=pltpu.PrefetchScalarGridSpec(
@@ -184,8 +220,8 @@ def _ssm_step(new, dt_raw, conv_w, conv_b, dt_bias, a_log, d_skip, conv_pool,
             grid=(jnp.maximum(n_live, 1),),
             in_specs=[
                 pl.BlockSpec((1, R, N), slot),
-                pl.BlockSpec((1, 1, H), slot),
-                whole(K1 + 1, R, N), whole(R, N), whole(3, H),
+                pl.BlockSpec((1, *lay), slot),
+                whole(K1 + 1, R, N), whole(R, N), whole(3, *lay),
                 pl.BlockSpec((1, K1, R, N), lambda i, lr: (lr[i], 0, 0, 0)),
                 pl.BlockSpec((1, H, P, N), lambda i, lr: (lr[i], 0, 0, 0)),
             ],
@@ -205,10 +241,11 @@ def _ssm_step(new, dt_raw, conv_w, conv_b, dt_bias, a_log, d_skip, conv_pool,
         interpret=impl == "pallas_interpret",
         compiler_params=pltpu.CompilerParams(vmem_limit_bytes=64 << 20),
         name="_ssm_step",
-    )(live_rows, new.reshape(S, R, N), dt_raw.astype(f32).reshape(S, 1, H),
+    )(live_rows, new.reshape(S, R, N), by_row(dt_raw.astype(f32)),
       conv_w.T.astype(f32).reshape(K1 + 1, R, N),
       conv_b.astype(f32).reshape(R, N),
-      jnp.stack([dt_bias, a_log, d_skip]).astype(f32), conv_pool, ssm_pool)
+      by_row(jnp.stack([dt_bias, a_log, d_skip]).astype(f32)), conv_pool,
+      ssm_pool)
     # a slot the grid did not visit has no y: nobody reads it, but it
     # must not be whatever the buffer held
     return (jnp.where(live[:, None, None], y.reshape(S, H, P), 0.0),
