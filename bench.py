@@ -38,9 +38,8 @@ import time
 
 #: the platform this benchmark measures; any other is an error
 CHIP_PLATFORM = "tpu"
-#: amortized repetitions per timed window: per-task path, segmented /
-#: compiled paths, fused forward
-PT_REPS, SEG_REPS, FUSED_REPS = 6, 16, 32
+#: amortized repetitions per timed window: placed DAG, fused forward
+PT_REPS, FUSED_REPS = 6, 32
 
 
 def log(msg: str) -> None:
@@ -223,7 +222,7 @@ def measure(
     fused_wall_s = max(statistics.median(fused_scalar_samples), 1e-9)
     spread["fused_scalar"] = spread_stats(fused_scalar_samples)
     # like-for-like baseline: the scalar-reduced variant above never
-    # writes the ~400 MB logits, but every DAG/segment execution must.
+    # writes the ~400 MB logits, but every DAG execution must.
     # In-flight logits bound the rep count; the scalar variant stays as
     # the MFU anchor (purest compute measurement).
     like_reps = min(FUSED_REPS, _output_capped_reps(fused, FUSED_REPS))
@@ -257,51 +256,6 @@ def measure(
         + (f", MFU {fused_mfu:.1%}" if fused_mfu is not None else "")
         + f") (dispatch overhead {overhead:+.1%}); "
         f"matches fused: {oracle['per_task']}")
-    # segment-fused execution: per-task launches collapse into one XLA
-    # program per device-contiguous run
-    srep = backend.execute(graph, sched_one, params, ids, segments=True)
-    oracle["segmented"] = oracle_close(fused, srep.output, dtype_name)
-    seg_samples = repeat_capture(lambda: backend.execute(
-        graph, sched_one, params, ids, segments=True,
-        warmup=False, reps=SEG_REPS, fence_rtt=rtt,
-    ).makespan_s, 3)
-    seg_makespan = statistics.median(seg_samples)
-    spread["segmented"] = spread_stats(seg_samples)
-    seg_mfu = compute_mfu(flops, seg_makespan, dev)
-    log(f"bench: segment-fused single-chip makespan "
-        f"{seg_makespan*1e3:.2f} ms ({srep.n_dispatches} launches vs "
-        f"{rep.n_dispatches}); matches fused: {oracle['segmented']}"
-        + (f"; MFU {seg_mfu:.1%}" if seg_mfu is not None else ""))
-    # whole-program compiled execution: the entire scheduled run lowered
-    # into ONE launch (backends/compiled_schedule.py) — the last rung of
-    # the dispatch ladder; host work per run is O(devices), not O(tasks)
-    crep = backend.execute(
-        graph, sched_one, params, ids, compiled=True, fence_rtt=rtt,
-    )
-    oracle["compiled"] = oracle_close(fused, crep.output, dtype_name)
-    comp_samples = repeat_capture(lambda: backend.execute(
-        graph, sched_one, params, ids, compiled=True,
-        warmup=False, reps=SEG_REPS, fence_rtt=rtt,
-    ), 3)
-    comp_makespan = statistics.median([r.makespan_s for r in comp_samples])
-    # dispatch wall from single-rep runs: re-enqueueing the same
-    # executable while its previous execution is in flight can block
-    # the host, so the multi-rep samples above would report device
-    # compute as "dispatch".  Each single-rep run fences, so every
-    # launch below is a clean enqueue.
-    comp_overhead_ms = statistics.median(repeat_capture(
-        lambda: backend.execute(
-            graph, sched_one, params, ids, compiled=True,
-            warmup=False, reps=1, fence_rtt=rtt,
-        ).dispatch_overhead_s, 3,
-    )) * 1e3
-    spread["compiled"] = spread_stats([r.makespan_s for r in comp_samples])
-    comp_mfu = compute_mfu(flops, comp_makespan, dev)
-    log(f"bench: whole-program compiled makespan "
-        f"{comp_makespan*1e3:.2f} ms ({crep.n_dispatches} launches, "
-        f"dispatch wall {comp_overhead_ms:.2f} ms/rep); "
-        f"matches fused: {oracle['compiled']}"
-        + (f"; MFU {comp_mfu:.1%}" if comp_mfu is not None else ""))
     if mfu is not None:
         log(f"bench: single-chip MFU {mfu:.1%} "
             f"({flops/1e12:.2f} TFLOP over {pt_makespan*1e3:.2f} ms)")
@@ -417,11 +371,6 @@ def measure(
         mfu_single_chip=mfu,
         dispatch_overhead=overhead,
         link_provenance=link_prov,
-        segmented_makespan_s=seg_makespan,
-        mfu_segmented=seg_mfu,
-        compiled_makespan_s=comp_makespan,
-        mfu_compiled=comp_mfu,
-        compiled_dispatch_overhead_ms=comp_overhead_ms,
         fused_forward_s=fused_like_s,
         fused_scalar_s=fused_wall_s,
         fence_rtt_s=rtt,

@@ -10,8 +10,8 @@ vocab 50257, f32; random weights from a fixed seed):
   (the Pallas paged kernel on a TPU) and again with ``--attention-impl
   xla`` (the gather path), whole-prompt and with ``--chunk-tokens 8``;
 * **execute** — ``execute --model gpt2 --batch 8 --seq-len 512
-  --microbatches 8 --num-nodes N`` through the placed-DAG executor,
-  per-task planned path and ``--segments``;
+  --microbatches 8 --num-nodes N`` through the placed-DAG executor
+  (the planned path, same-device spans fused);
 * **kernels** — the three Pallas attention kernels, compiled, against
   their XLA references at the geometry the other phases used;
 * **state** — what ran: resolved attention impl, device memory after the
@@ -30,9 +30,8 @@ is ``{"ok": true, "device": {"platform": ..., "kind": ..., "count": ...}}``.
 
     python chip_smoke.py              # one chip: every phase above
     python chip_smoke.py --chips 4    # a four-chip host: ONLY the placed
-                                      # execute over four devices (per-task
-                                      # path) and the measured chip-to-chip
-                                      # link
+                                      # execute over four devices and the
+                                      # measured chip-to-chip link
 """
 
 from __future__ import annotations
@@ -321,7 +320,7 @@ def _step_logit_parity(model: str, kernel_impl: Optional[str],
         inputs = dag.make_inputs(lengths=lengths)
         sched = get_scheduler("greedy").schedule(dag.graph, cluster)
         rep = DeviceBackend(cluster).execute(
-            dag.graph, sched, params, inputs, segments=True
+            dag.graph, sched, params, inputs
         )
         logits[name] = np.asarray(rep.output, np.float32)
         if name == "kernel":
@@ -415,12 +414,10 @@ def phase_serve(meter: CompileMeter, model: str = "gpt2",
 def phase_execute(meter: CompileMeter, model: str = "gpt2", batch: int = 8,
                   seq_len: int = 512, microbatches: int = 8,
                   num_nodes: int = 1,
-                  schedulers: tuple = ("heft",),
-                  segment_modes: tuple = (False, True)) -> Dict[str, Any]:
+                  schedulers: tuple = ("heft",)) -> Dict[str, Any]:
     """The placed-DAG executor through ``execute``: the planned path (fused
-    same-device launches by default: ``n_dispatches`` beside ``n_tasks``
-    shows how many programs a step took) and ``--segments``
-    (``segment_modes``), per scheduler.
+    same-device launches: ``n_dispatches`` beside ``n_tasks`` shows how
+    many programs a step took), one leg per scheduler.
 
     Gates: the CLI exits 0 on ``num_nodes`` devices; with more than one
     node, every device reports a non-zero HBM peak and at least one edge
@@ -451,65 +448,60 @@ def phase_execute(meter: CompileMeter, model: str = "gpt2", batch: int = 8,
             want = jax.jit(dag.reference_forward)(params, ids)
             dtype_name = jnp.dtype(dag.config.dtype).name
             backend = DeviceBackend(cluster)
-            for segments in segment_modes:
-                name = sched_name + ("_segments" if segments else "")
-                leg: Dict[str, Any] = {}
-                argv = ["execute", "--model", model, "--batch", str(batch),
-                        "--seq-len", str(seq_len), "--microbatches",
-                        str(microbatches), "--num-nodes", str(num_nodes),
-                        "--scheduler", sched_name]
-                with timed(meter, leg):
-                    res = run_cli(argv + (["--segments"] if segments
-                                          else []))
-                s = res["printed"] or {}
-                leg.update(rc=res["rc"], **{
-                    k: s.get(k) for k in (
-                        "n_devices", "makespan_ms", "n_dispatches",
-                        "transfer_edges", "peak_hbm_gb", "planned",
-                        "device", "attention_impl")
-                })
-                with timed(meter, leg.setdefault("oracle", {})):
-                    rep = backend.execute(
-                        dag.graph, schedule, params, ids, segments=segments
-                    )
-                    got = np.asarray(rep.output, np.float32)
-                ref = np.asarray(want, np.float32)
-                leg["n_tasks"] = len(dag.graph.topo_order)
-                leg["oracle"].update(
-                    n_dispatches=rep.n_dispatches,
-                    bitwise=bool(np.array_equal(got, ref)),
-                    max_abs_diff=round(float(np.abs(got - ref).max()), 6),
-                    rel_fro=float(np.linalg.norm((got - ref).ravel())
-                                  / max(np.linalg.norm(ref.ravel()), 1e-12)),
-                    finite=bool(np.isfinite(got).all()),
-                    close=bool(oracle_close(want, rep.output, dtype_name)),
-                )
-                peaks = leg.get("peak_hbm_gb") or {}
-                # per-device peaks exist where the platform reports
-                # memory_stats (a TPU does; the CPU test mesh does not)
-                peaks_ok = memory_stats() is None or (
-                    len(peaks) == num_nodes
-                    and all(v > 0 for v in peaks.values())
-                )
-                leg["ok"] = bool(
-                    res["rc"] == 0
-                    and leg["n_devices"] == num_nodes
-                    and leg["oracle"]["finite"] and leg["oracle"]["close"]
-                    and peaks_ok
-                    and (num_nodes == 1
-                         or (leg["transfer_edges"] or 0) > 0)
-                )
-                ph["legs"][name] = leg
-                log(f"execute[{name}]: rc={leg['rc']} devices="
-                    f"{leg['n_devices']} launches={leg['n_dispatches']} "
-                    f"(oracle run {rep.n_dispatches}) of "
-                    f"{leg['n_tasks']} tasks makespan="
-                    f"{leg['makespan_ms']}ms "
-                    f"edges={leg['transfer_edges']} oracle="
-                    f"{leg['oracle']['close']} (bitwise="
-                    f"{leg['oracle']['bitwise']} max|d|="
-                    f"{leg['oracle']['max_abs_diff']}) wall={leg['wall_s']}s "
-                    f"compile={leg['compile_s']}s")
+            leg: Dict[str, Any] = {}
+            argv = ["execute", "--model", model, "--batch", str(batch),
+                    "--seq-len", str(seq_len), "--microbatches",
+                    str(microbatches), "--num-nodes", str(num_nodes),
+                    "--scheduler", sched_name]
+            with timed(meter, leg):
+                res = run_cli(argv)
+            s = res["printed"] or {}
+            leg.update(rc=res["rc"], **{
+                k: s.get(k) for k in (
+                    "n_devices", "makespan_ms", "n_dispatches",
+                    "transfer_edges", "peak_hbm_gb", "planned",
+                    "device", "attention_impl")
+            })
+            with timed(meter, leg.setdefault("oracle", {})):
+                rep = backend.execute(dag.graph, schedule, params, ids)
+                got = np.asarray(rep.output, np.float32)
+            ref = np.asarray(want, np.float32)
+            leg["n_tasks"] = len(dag.graph.topo_order)
+            leg["oracle"].update(
+                n_dispatches=rep.n_dispatches,
+                bitwise=bool(np.array_equal(got, ref)),
+                max_abs_diff=round(float(np.abs(got - ref).max()), 6),
+                rel_fro=float(np.linalg.norm((got - ref).ravel())
+                              / max(np.linalg.norm(ref.ravel()), 1e-12)),
+                finite=bool(np.isfinite(got).all()),
+                close=bool(oracle_close(want, rep.output, dtype_name)),
+            )
+            peaks = leg.get("peak_hbm_gb") or {}
+            # per-device peaks exist where the platform reports
+            # memory_stats (a TPU does; the CPU test mesh does not)
+            peaks_ok = memory_stats() is None or (
+                len(peaks) == num_nodes
+                and all(v > 0 for v in peaks.values())
+            )
+            leg["ok"] = bool(
+                res["rc"] == 0
+                and leg["n_devices"] == num_nodes
+                and leg["oracle"]["finite"] and leg["oracle"]["close"]
+                and peaks_ok
+                and (num_nodes == 1
+                     or (leg["transfer_edges"] or 0) > 0)
+            )
+            ph["legs"][sched_name] = leg
+            log(f"execute[{sched_name}]: rc={leg['rc']} devices="
+                f"{leg['n_devices']} launches={leg['n_dispatches']} "
+                f"(oracle run {rep.n_dispatches}) of "
+                f"{leg['n_tasks']} tasks makespan="
+                f"{leg['makespan_ms']}ms "
+                f"edges={leg['transfer_edges']} oracle="
+                f"{leg['oracle']['close']} (bitwise="
+                f"{leg['oracle']['bitwise']} max|d|="
+                f"{leg['oracle']['max_abs_diff']}) wall={leg['wall_s']}s "
+                f"compile={leg['compile_s']}s")
             del want, params, backend
             gc.collect()
     ph["ok"] = all(leg["ok"] for leg in ph["legs"].values())
@@ -858,11 +850,8 @@ def main(argv: Optional[List[str]] = None) -> int:
             log(f"{name}: FAILED\n{phases[name]['error']}")
 
     if args.chips == 4:
-        # per-task planned path only: on four devices ``--segments``
-        # compiles one program per device-contiguous run — 793 of them
-        # under round-robin, 150 s a leg (PERF.md, PR 21)
         run("execute_4chip", phase_execute, meter, num_nodes=4,
-            schedulers=("pack", "roundrobin"), segment_modes=(False,))
+            schedulers=("pack", "roundrobin"))
         run("link", phase_link, meter)
     else:
         run("serve", phase_serve, meter)

@@ -473,7 +473,7 @@ def cmd_lint(args) -> int:
         param_specs=param_specs if cfg.quantize == "int8" else None,
         compiled_gb=compiled_gb,
         analytic_gb=analytic_gb,
-        # typecheck (TYP001-TYP004) inputs: param *specs* carry the same
+        # typecheck (TYP001-TYP003) inputs: param *specs* carry the same
         # avals as initialized weights without materializing any arrays
         params=param_specs,
         graph_input=getattr(dag, "input_spec", None),
@@ -522,10 +522,6 @@ def cmd_execute(args) -> int:
     from .backends.device import DeviceBackend
 
     cfg = _config_from(args)
-    if args.profile and args.segments:
-        print("--segments fuses away task boundaries; per-task --profile "
-              "timings need per-task dispatch", file=sys.stderr)
-        return 2
     if args.trace and not args.profile:
         # fail BEFORE the device run: timings only exist in profile mode
         print("--trace needs per-task timings; add --profile",
@@ -578,8 +574,7 @@ def cmd_execute(args) -> int:
             return 2
     rep = backend.execute(
         dag.graph, schedule, params, ids, profile=args.profile,
-        segments=args.segments, keep_outputs=bool(inject),
-        stream_params=args.stream_params,
+        keep_outputs=bool(inject), stream_params=args.stream_params,
     )
     summary = rep.summary()
     summary["device"] = device_info()
@@ -595,7 +590,7 @@ def cmd_execute(args) -> int:
     if inject:
         recovery = _injected_recovery(
             inject, dag, schedule, cluster, cfg, rep, params, ids,
-            segments=args.segments, stream_params=args.stream_params,
+            stream_params=args.stream_params,
         )
         summary["recovery"] = recovery
         print(json.dumps(summary, indent=1, default=str))
@@ -662,7 +657,7 @@ def _parse_injection(spec: str, cluster):
 
 def _injected_recovery(
     inject, dag, schedule, cluster, cfg, first_rep, params, ids,
-    segments: bool, stream_params: bool = False,
+    stream_params: bool = False,
 ):
     """Fault injection for `execute --inject-failure NODE[:FRAC]`: treat
     the first FRAC of the assignment order as completed when NODE dies,
@@ -697,7 +692,7 @@ def _injected_recovery(
     ext = {t: first_rep.task_outputs[t] for t in available}
     rec = DeviceBackend(survivors).execute(
         remainder, new_s, params, ids,
-        ext_outputs=ext, segments=segments, keep_outputs=True,
+        ext_outputs=ext, keep_outputs=True,
         stream_params=stream_params,
     )
     # compare the ORIGINAL graph's final task: retained if it survived the
@@ -2448,9 +2443,6 @@ def main(argv=None) -> int:
     p = sub.add_parser("execute", help="run a scheduled DAG on live devices")
     _add_common(p)
     p.add_argument("--profile", action="store_true")
-    p.add_argument("--segments", action="store_true",
-                   help="fuse each device's contiguous scheduled run into "
-                        "one XLA launch (incompatible with --profile)")
     p.add_argument("--trace", default=None,
                    help="write measured task timeline (needs --profile) as "
                         "a Chrome/Perfetto trace JSON to this path")
@@ -2459,8 +2451,7 @@ def main(argv=None) -> int:
                    help="planned param streaming (prefetch + Belady "
                         "eviction) under each node's HBM budget — executes "
                         "models whose weights exceed the budget (bandwidth "
-                        "for capacity); composes with --segments (one "
-                        "batched load per fused program)")
+                        "for capacity)")
     p.add_argument("--inject-failure", default=None, metavar="NODE[:FRAC]",
                    dest="inject_failure",
                    help="fault injection: kill NODE (id or index) after "

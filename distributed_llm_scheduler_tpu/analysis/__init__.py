@@ -30,11 +30,7 @@ from .diagnostics import (
     Diagnostic,
     Severity,
 )
-from .collective_pass import (
-    analyze_collectives,
-    analyze_collectives_jaxpr,
-    analyze_schedule_lowerability,
-)
+from .collective_pass import analyze_collectives_jaxpr
 from .cost_pass import analyze_cost
 from .decode_pass import analyze_decode
 from .determinism_pass import analyze_determinism
@@ -51,11 +47,7 @@ from .pipeline_pass import analyze_pipeline
 from .quant_pass import analyze_quantization
 from .schedule_pass import analyze_schedule
 from .sharding_pass import analyze_sharding
-from .stream_pass import (
-    analyze_streaming,
-    compiled_stream_refusal,
-    stream_verdict,
-)
+from .stream_pass import analyze_streaming
 from .typecheck_pass import analyze_typecheck
 
 __all__ = [
@@ -69,7 +61,6 @@ __all__ = [
     "Severity",
     "StageOp",
     "analyze",
-    "analyze_collectives",
     "analyze_collectives_jaxpr",
     "analyze_cost",
     "analyze_decode",
@@ -78,7 +69,6 @@ __all__ = [
     "analyze_happens_before",
     "analyze_lifecycle",
     "analyze_pages",
-    "analyze_schedule_lowerability",
     "analyze_serve_artifact",
     "analyze_graph",
     "analyze_memory",
@@ -88,14 +78,12 @@ __all__ = [
     "analyze_sharding",
     "analyze_streaming",
     "analyze_typecheck",
-    "compiled_stream_refusal",
     "fix_duplicate_dependencies",
     "fix_per_node_order",
     "gate_enabled",
     "node_memory_slice",
     "pre_execution_gate",
     "stage_programs_1f1b",
-    "stream_verdict",
     "sweep_parallel_collectives",
 ]
 
@@ -136,7 +124,7 @@ def analyze(
     """Run every pass the provided inputs make applicable.
 
     Graph hygiene always runs; schedule-consistency, memory, pipeline,
-    typecheck (TYP001-TYP004, fed by ``params`` — concrete arrays or a
+    typecheck (TYP001-TYP003, fed by ``params`` — concrete arrays or a
     spec table — and ``graph_input`` when available), and stream-safety
     (STR001-STR003) passes run when ``cluster`` and ``schedule`` are
     given; the sharding pass runs when ``param_shapes`` + ``mesh_axes``
@@ -147,7 +135,7 @@ def analyze(
     pre-preflight snapshot) is given; the MPMD happens-before pass runs
     when ``stage_programs`` (per-stage op sequences, see
     :mod:`.hb_pass`) is given; the donation pass runs when ``plan`` (a
-    DispatchPlan/CompiledSchedule or their metadata dict, see
+    DispatchPlan or its metadata dict, see
     :mod:`.donation_pass`) is given; the page-lifetime prover runs when
     ``page_events`` (a ``PageOwnershipLog``/snapshot, see
     :mod:`.page_pass`) is given; the request-lifecycle checker runs when
@@ -234,7 +222,6 @@ def pre_execution_gate(
     cluster: Cluster,
     schedule: Schedule,
     backend: str = "sim",
-    program: Optional[Any] = None,
     plan: Optional[Any] = None,
     stage_programs: Optional[Dict[str, Any]] = None,
     precomputed: Optional[AnalysisReport] = None,
@@ -252,18 +239,11 @@ def pre_execution_gate(
     silently falls back to running the passes itself.  Reports from
     other sources (e.g. ``IncrementalAnalyzer.report``) must not be
     passed here: they cover a narrower pass suite than the gate
-    filters.  Extras (``program`` / ``plan`` / ``stage_programs``)
-    still run fresh: the precomputed report predates those artifacts.
+    filters.  Extras (``plan`` / ``stage_programs``) still run fresh:
+    the precomputed report predates those artifacts.
 
-    ``program`` (compiled execution path): the lowered
-    :class:`..sched.linearize.ProgramIR` — the collective-ordering pass
-    then joins the gate (COL001 divergent sequences, COL004 malformed
-    permutations; COL002 deadlocks surface earlier, at linearization,
-    because without a global order there is no program to pass here).
-
-    ``plan`` (dispatch/compiled execution paths): a DispatchPlan,
-    CompiledSchedule, or their donation metadata — the donation-alias
-    pass joins the gate (DON001-DON003: a donated buffer read, donated
+    ``plan``: a DispatchPlan or its donation metadata — the
+    donation-alias pass joins the gate (DON001-DON003: a donated buffer read, donated
     twice, or donated across a device boundary corrupts silently).
 
     ``stage_programs`` (MPMD lowerings): per-stage op sequences — the
@@ -288,9 +268,6 @@ def pre_execution_gate(
         rep = analyze_graph(graph)
         rep.extend(analyze_decode(graph, cluster, schedule))
         rep.extend(analyze_schedule(graph, cluster, schedule))
-    if program is not None:
-        rep.extend(analyze_collectives(program))
-        codes = codes | {"COL001", "COL002", "COL004"}
     if plan is not None:
         rep.extend(analyze_donation(plan))
         codes = codes | {"DON001", "DON002", "DON003"}

@@ -1,41 +1,25 @@
-"""Collective-ordering analysis (COL00x): deadlock-freedom for lowered
-programs and SPMD strategies.
+"""Collective-ordering analysis (COL00x): deadlock-freedom for SPMD
+strategies.
 
 Collectives are rendezvous points: when per-device programs disagree on
 which collective comes next on a mesh axis, a real multi-chip mesh hangs
 (the CPU-faked mesh would too, if the divergence survived lowering).
-This pass verifies the property statically, in two forms:
-
-* **Lowered programs** (:func:`analyze_collectives`): given the
-  phase/exchange IR the compiled path lowers
-  (:class:`..sched.linearize.ProgramIR`) — or, for tests and future
-  true-MPMD lowerings, an explicit ``device -> sequence`` mapping — check
-  that every device issues the identical collective sequence (COL001)
-  and that each emitted permutation is a valid partial permutation over
-  the mesh axis (COL004: repeated sources or destinations make the
-  rendezvous ill-defined).  A schedule whose per-node orders admit no
-  global linearization at all is reported as COL002 (the lowering
-  cannot even start; see :class:`..sched.linearize.OrderingDeadlock`).
-
-* **SPMD strategies** (:func:`analyze_collectives_jaxpr`): walk a traced
-  jaxpr (e.g. ``parallel/ring_attention.py``'s shard_map body) and check
-  that ``cond``/``switch`` branches issue matching collective sequences
-  per axis (COL003) — divergent branch sequences are exactly how a
-  "same program" SPMD lowering smuggles in per-device divergence —
-  plus COL004 permutation validity on every ``ppermute`` encountered.
-
-Wired into :func:`..analysis.pre_execution_gate` via its ``program=``
-parameter: the compiled execution path passes its IR and COL001/COL002
-join the gated codes, so an ill-ordered schedule errors before any
-device work is enqueued.
+:func:`analyze_collectives_jaxpr` verifies the property statically: it
+walks a traced jaxpr (e.g. ``parallel/ring_attention.py``'s shard_map
+body) and checks that ``cond``/``switch`` branches issue matching
+collective sequences per axis (COL003) — divergent branch sequences are
+exactly how a "same program" SPMD lowering smuggles in per-device
+divergence — plus COL004 permutation validity on every ``ppermute``
+encountered (repeated sources or destinations make the rendezvous
+ill-defined).  ``parallel_sweep.py`` runs it over every strategy in
+``parallel/``; the MPMD happens-before checks (COL005-COL007) live in
+:mod:`.hb_pass`.
 """
 
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, List, Sequence, Tuple
 
-from ..core.graph import TaskGraph
-from ..core.schedule import Schedule
 from .diagnostics import AnalysisReport, Severity
 
 #: collective primitives that rendezvous over a mesh axis (jaxpr walk)
@@ -48,10 +32,7 @@ _COLLECTIVE_PRIMS = frozenset(
 
 
 def _check_perm(
-    rep: AnalysisReport,
-    perm: Sequence[Tuple[int, int]],
-    n_devices: Optional[int],
-    where: str,
+    rep: AnalysisReport, perm: Sequence[Tuple[int, int]], where: str,
 ) -> None:
     srcs = [s for s, _ in perm]
     dsts = [d for _, d in perm]
@@ -60,10 +41,6 @@ def _check_perm(
         bad.append("repeated source")
     if len(set(dsts)) != len(dsts):
         bad.append("repeated destination")
-    if n_devices is not None and any(
-        not (0 <= i < n_devices) for i in srcs + dsts
-    ):
-        bad.append(f"index outside mesh of {n_devices}")
     if bad:
         rep.add(
             "COL004",
@@ -71,95 +48,6 @@ def _check_perm(
             f"{where}: perm {list(perm)} is not a valid partial "
             f"permutation ({', '.join(bad)})",
         )
-
-
-def analyze_collectives(
-    program: Any,
-    graph: Optional[TaskGraph] = None,
-    schedule: Optional[Schedule] = None,
-) -> AnalysisReport:
-    """COL001/COL004 over a lowered program.
-
-    ``program`` is a :class:`..sched.linearize.ProgramIR` (or anything
-    with ``devices`` and ``collective_sequence(device)``), or a plain
-    ``device -> [(primitive, perm, value_id), ...]`` mapping.  ``graph``/
-    ``schedule`` are accepted for interface symmetry with the other
-    passes and unused (the IR already encodes the placement).
-    """
-    del graph, schedule
-    rep = AnalysisReport()
-    if isinstance(program, dict):
-        seqs: Dict[str, List] = {d: list(s) for d, s in program.items()}
-        n_devices: Optional[int] = len(seqs) or None
-    else:
-        seqs = {
-            d: program.collective_sequence(d) for d in program.devices
-        }
-        n_devices = len(program.devices)
-    if not seqs:
-        return rep
-    ref_dev = next(iter(seqs))
-    ref = seqs[ref_dev]
-    for dev, seq in seqs.items():
-        if seq == ref:
-            continue
-        # first divergence position, for an actionable message
-        pos = next(
-            (
-                i for i, (a, b) in enumerate(zip(ref, seq))
-                if a != b
-            ),
-            min(len(ref), len(seq)),
-        )
-        a = ref[pos] if pos < len(ref) else "<end of program>"
-        b = seq[pos] if pos < len(seq) else "<end of program>"
-        rep.add(
-            "COL001",
-            Severity.ERROR,
-            f"collective sequence diverges at position {pos}: "
-            f"{ref_dev} issues {a}, {dev} issues {b} — a real mesh "
-            "deadlocks here",
-            node=dev,
-        )
-    for prim, perm, val in ref:
-        if prim == "ppermute":
-            _check_perm(rep, perm, n_devices, f"value {val!r}")
-    return rep
-
-
-def analyze_schedule_lowerability(
-    graph: TaskGraph,
-    schedule: Schedule,
-    device_order: Optional[Sequence[str]] = None,
-) -> Tuple[AnalysisReport, Optional[Any]]:
-    """Attempt the strict linearization + phase cut; COL002 on deadlock.
-
-    Returns ``(report, ir)`` — ``ir`` is ``None`` exactly when the
-    report carries the COL002 error (there is no program to lower).  The
-    compiled path calls this before building anything; the ``lint`` CLI
-    reaches it through :func:`analyze`.
-    """
-    from ..sched.linearize import OrderingDeadlock, linearize
-
-    rep = AnalysisReport()
-    try:
-        ir = linearize(graph, schedule, device_order=device_order)
-    except OrderingDeadlock as e:
-        first = sorted(e.heads)[0] if e.heads else None
-        rep.add(
-            "COL002",
-            Severity.ERROR,
-            str(e),
-            node=first,
-            task=e.heads[first][0] if first else None,
-            data={"heads": {
-                n: {"head": t, "waits_on": list(d)}
-                for n, (t, d) in e.heads.items()
-            }},
-        )
-        return rep, None
-    rep.extend(analyze_collectives(ir))
-    return rep, ir
 
 
 # -- jaxpr walk (SPMD strategies) ---------------------------------------
@@ -178,7 +66,7 @@ def _walk_jaxpr(jaxpr: Any, rep: AnalysisReport, where: str) -> List[Tuple]:
             perm = eqn.params.get("perm")
             seq.append((name, axes, tuple(perm) if perm else None))
             if name == "ppermute" and perm:
-                _check_perm(rep, perm, None, where)
+                _check_perm(rep, perm, where)
             continue
         if name == "cond":
             branches = eqn.params.get("branches", ())

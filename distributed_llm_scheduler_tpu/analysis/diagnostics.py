@@ -82,8 +82,6 @@ CODES: Dict[str, str] = {
     "CST002": "analytic memory estimate over-predicts XLA preflight",
     "CST003": "task missing from XLA preflight measurement",
     # -- collective ordering (collective_pass) --------------------------
-    "COL001": "devices would issue divergent collective sequences",
-    "COL002": "per-node orders deadlock: no valid global collective order",
     "COL003": "collective sequence diverges across control-flow branches",
     "COL004": "collective permutation is not a valid partial permutation",
     # -- MPMD happens-before model (hb_pass) ----------------------------
@@ -101,13 +99,10 @@ CODES: Dict[str, str] = {
     "TYP001": "producer/consumer aval disagreement on a dependency edge",
     "TYP002": "illegal dtype promotion across a quantized edge",
     "TYP003": "edge aval bytes diverge from the cost-model charge",
-    "TYP004": "program fan-in unsatisfiable: argument not available "
-              "on device at dispatch",
     # -- stream-safety prover (stream_pass) -----------------------------
-    "STR001": "streamed schedule is compilable as-is (params fit resident)",
-    "STR002": "streamed schedule compilable only with a pinned prefix",
-    "STR003": "streamed schedule is interpreter-only (must evict from "
-              "the first task)",
+    "STR001": "streamed node never evicts (its param union fits resident)",
+    "STR002": "streamed node evicts after a prefix of its tasks",
+    "STR003": "streamed node must evict from its first task",
     # -- page-lifetime prover (page_pass) -------------------------------
     "PGL001": "orphaned page: allocated but never freed",
     "PGL002": "double-free in the page ownership event stream",

@@ -2,13 +2,10 @@
 must be dead after its donating launch.
 
 ``DispatchPlan.build`` computes donation from a slot lifetime analysis
-(last consuming group, fence/final/keep protection) and
-``CompiledSchedule`` donates only the per-run transient graph-input
-leaves — both are safe *by construction*.  This pass re-derives the
-safety from the plan metadata alone, so a hand-built or mutated plan
-(tests, future planners, external tooling) is verified independently of
-the builder that produced it, the same defense-in-depth the COL00x pass
-gives the lowered collective order.
+(last consuming group, fence/final/keep protection), safe *by
+construction*.  This pass re-derives the safety from the plan metadata
+alone, so a hand-built or mutated plan (tests, future planners, external
+tooling) is verified independently of the builder that produced it.
 
 * **DON001 (error)** — read-after-donation: a slot some launch donated
   is read again later — by a later launch's arguments, by the end-of-run
@@ -16,20 +13,15 @@ gives the lowered collective order.
   position of the donating launch itself.  XLA freed the buffer; the
   read returns garbage or crashes.
 * **DON002 (error)** — double donation: one slot donated by two
-  launches (or twice by one), or — compiled path — a donation vector
-  touching the parameter slab, whose rows are aliased slices shared by
-  every task view and reused across reps.
-* **DON003 (error)** — donation across a transfer/collective boundary: a
-  donated slot that a launch on a DIFFERENT device still pulls through
-  the transfer path (``xfer_slots``), or — compiled path — a donated
-  argument that is not a per-run transient input.  The remote read races
-  the free; on hardware this corrupts the wire value rather than
-  faulting.
+  launches (or twice by one).
+* **DON003 (error)** — donation across a transfer boundary: a donated
+  slot that a launch on a DIFFERENT device still pulls through the
+  transfer path (``xfer_slots``).  The remote read races the free; on
+  hardware this corrupts the wire value rather than faulting.
 
-Consumes only exposed metadata: :meth:`DispatchPlan.donation_table` /
-:meth:`CompiledSchedule.donation_summary` (duck-typed, so plain dicts
-work in tests).  Wired into ``analyze()``, the pre-execution gate
-(``plan=`` parameter), and both backends' build paths.
+Consumes only exposed metadata: :meth:`DispatchPlan.donation_table`
+(duck-typed, so plain dicts work in tests).  Wired into ``analyze()``
+and the pre-execution gate (``plan=`` parameter).
 """
 
 from __future__ import annotations
@@ -39,23 +31,17 @@ from typing import Any, Dict
 from .diagnostics import AnalysisReport, Severity
 
 
-def analyze_donation(plan_or_summary: Any) -> AnalysisReport:
-    """DON001-DON003 over a :class:`..backends.dispatch_plan.DispatchPlan`,
-    a :class:`..backends.compiled_schedule.CompiledSchedule`, or either
-    one's exported metadata (``donation_table()`` / ``donation_summary()``
-    dict)."""
-    obj = plan_or_summary
+def analyze_donation(plan_or_table: Any) -> AnalysisReport:
+    """DON001-DON003 over a :class:`..backends.dispatch_plan.DispatchPlan`
+    or its exported metadata (the ``donation_table()`` dict)."""
+    obj = plan_or_table
     if hasattr(obj, "donation_table"):
         obj = obj.donation_table()
-    elif hasattr(obj, "donation_summary"):
-        obj = obj.donation_summary()
     if isinstance(obj, dict) and "steps" in obj:
         return _analyze_plan_table(obj)
-    if isinstance(obj, dict) and "donated_argnums" in obj:
-        return _analyze_compiled_summary(obj)
     raise TypeError(
-        "analyze_donation wants a DispatchPlan, a CompiledSchedule, or "
-        f"their donation metadata dicts; got {type(plan_or_summary)!r}"
+        "analyze_donation wants a DispatchPlan or its donation table; "
+        f"got {type(plan_or_table)!r}"
     )
 
 
@@ -183,34 +169,4 @@ def _analyze_plan_table(table: Dict[str, Any]) -> AnalysisReport:
                     "still owns that buffer",
                     data={"slot": s},
                 )
-    return rep
-
-
-def _analyze_compiled_summary(summary: Dict[str, Any]) -> AnalysisReport:
-    """Invariant check of a CompiledSchedule donation vector: only the
-    per-run transient input leaves may be donated; the param slab rows
-    are aliased slices live across reps."""
-    rep = AnalysisReport()
-    params = set(summary.get("param_argnums", ()))
-    inputs = set(summary.get("input_argnums", ()))
-    for a in summary.get("donated_argnums", ()):
-        if a in params:
-            rep.add(
-                "DON002",
-                Severity.ERROR,
-                f"compiled program donates argument {a}: the parameter "
-                "slab — its rows are aliased slices every task view "
-                "shares and every rep re-reads; donating it double-frees "
-                "the aliases",
-                data={"argnum": a},
-            )
-        elif a not in inputs:
-            rep.add(
-                "DON003",
-                Severity.ERROR,
-                f"compiled program donates argument {a}, which is not a "
-                "per-run transient input — remote devices still read it "
-                "through the program's collectives",
-                data={"argnum": a},
-            )
     return rep

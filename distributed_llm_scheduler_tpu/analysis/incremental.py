@@ -16,19 +16,15 @@ exploits how each pass's diagnostics *factor* over provenance slices:
 * TYP003 factors **per dependency edge** — a move changes the
   cross-device-ness only of edges incident to the moved task, so only
   their ``("typ-edge", u, v)`` slices recompute;
-* schedule consistency, collective lowerability (COL), and program
-  arity (TYP004) are **invariant under the move rule** below: with a
-  clean baseline, ``move_task`` preserves every property they check, so
-  their slices are cached.  (Proof sketch: the global
+* schedule consistency is **invariant under the move rule** below:
+  with a clean baseline, ``move_task`` preserves every property it
+  checks, so its slice is cached.  (Proof sketch: the global
   ``assignment_order`` never changes and stays SCH009-clean; the moved
   task is re-inserted so every per-node list remains a subsequence of
-  it, which keeps SCH005 clean and — because the earliest unemitted
-  placed task is then always an emittable queue head — keeps
-  ``strict_dispatch_order`` deadlock-free; a successful ``linearize``
-  satisfies register availability by construction.)
+  it, which keeps SCH005 clean.)
 
-When the baseline is *not* clean of graph/SCH/COL/TYP004 errors the
-invariants above do not hold; the analyzer then degrades to a full
+When the baseline is *not* clean of graph/SCH errors the
+invariant above does not hold; the analyzer then degrades to a full
 recompute per move — still exact, just not fast.  ``verify()`` is the
 contract's enforcement: it re-runs the full suite fresh on the current
 (post-moves) schedule and asserts the cached state matches diagnostic-
@@ -36,7 +32,7 @@ for-diagnostic (compared on ``(code, severity, message, task, node,
 param)`` — the same identity ``Diagnostic.__eq__`` uses).
 
 The suite covers the placement-relevant families the ISSUE names —
-MEM/SCH/TYP/COL (+DON when donation metadata is supplied) plus graph
+MEM/SCH/TYP (+DON when donation metadata is supplied) plus graph
 hygiene; decode/pipeline/sharding passes are placement-shape-independent
 or schedule-free and stay with the batch :func:`..analyze` entry point.
 """
@@ -51,14 +47,12 @@ from typing import Any, Dict, List, Optional, Tuple
 from ..core.cluster import Cluster
 from ..core.graph import TaskGraph
 from ..core.schedule import Schedule
-from .collective_pass import analyze_schedule_lowerability
 from .diagnostics import AnalysisReport, Diagnostic, Severity
 from .donation_pass import analyze_donation
 from .graph_pass import analyze_graph
 from .memory_pass import _param_sizes_gb, analyze_memory, node_memory_slice
 from .schedule_pass import analyze_schedule
 from .typecheck_pass import (
-    check_program_arity,
     check_quantized_edges,
     check_transfer_bytes,
     propagate_schedule_avals,
@@ -189,15 +183,6 @@ class IncrementalAnalyzer:
         typ3: Dict[Edge, List[Diagnostic]] = {}
         for d in t3.diagnostics:
             typ3.setdefault((d.task, d.data.get("consumer")), []).append(d)
-        colrep, ir = analyze_schedule_lowerability(
-            self.graph, schedule, device_order=self._node_ids
-        )
-        slices[("col",)] = colrep.diagnostics
-        slices[("typ-ir",)] = (
-            check_program_arity(self.graph, ir).diagnostics
-            if ir is not None
-            else []
-        )
         slices[("don",)] = (
             analyze_donation(self._plan).diagnostics
             if self._plan is not None
@@ -212,7 +197,7 @@ class IncrementalAnalyzer:
     def _baseline_clean(self) -> bool:
         """Exactness precondition for the fast path: no errors in the
         slices whose invariance the move rule relies on."""
-        for key in (("graph",), ("sched",), ("col",), ("typ-ir",)):
+        for key in (("graph",), ("sched",)):
             if any(
                 d.severity == Severity.ERROR for d in self._slices.get(key, [])
             ):
@@ -240,15 +225,14 @@ class IncrementalAnalyzer:
         out.extend(self._slices.get(("typ-graph",), []))
         for e in sorted(self._typ3, key=lambda e: (str(e[0]), str(e[1]))):
             out.extend(self._typ3[e])
-        for key in (("typ-ir",), ("col",), ("don",)):
-            out.extend(self._slices.get(key, []))
+        out.extend(self._slices.get(("don",), []))
         return out
 
     @property
     def report(self) -> AnalysisReport:
         """The current cached state as one report, stamped with the
         current schedule signature.  NOTE: this is the incremental suite
-        (graph/SCH/MEM/TYP/COL/DON), not the full :func:`..analyze` set —
+        (graph/SCH/MEM/TYP/DON), not the full :func:`..analyze` set —
         do not feed it to ``pre_execution_gate(precomputed=...)``, which
         expects the decode/pipeline passes to be present."""
         rep = AnalysisReport(self._all_diagnostics())
@@ -269,7 +253,7 @@ class IncrementalAnalyzer:
         The task keeps its global ``assignment_order`` position; it is
         inserted into ``dst``'s list at the position that keeps the list
         a subsequence of the global order (the invariant the cached
-        SCH/COL/TYP004 slices rely on).  Returns the diagnostic delta;
+        SCH slice relies on).  Returns the diagnostic delta;
         ``move_task(tid, delta.src)`` is an exact undo.
         """
         # dls-lint: allow(DET001) delta.wall_s is reported metadata

@@ -1,32 +1,22 @@
 """Pass 9 — static stream-safety prover for ``stream_params`` schedules.
 
-The interpreted device backend streams parameters through a per-node HBM
-budget with Belady eviction (``backends/device._ParamStreamer``); the
-compiled path instead loads every parameter a device will ever touch as
-one resident slab.  Whether a *streamed* schedule can take the compiled
-rung is therefore a static question about the residency plan, answered
-here by replaying it symbolically — per node, in that node's dispatch
-order, accumulating the first-use union of parameter working sets
-against the same budget the streamer enforces
+The device backend streams parameters through a per-node HBM budget with
+Belady eviction (``backends/device._ParamStreamer``).  How much a
+schedule will have to evict is a static question about the residency
+plan, answered here by replaying it symbolically — per node, in that
+node's dispatch order, accumulating the first-use union of parameter
+working sets against the same budget the streamer enforces
 (``device.total_memory`` GB, sizes from the graph's authoritative
 ``param_size_gb`` table):
 
 * ``STR001`` (info) — the node's full parameter union fits the budget:
-  the streamed schedule compiles **as-is** (the slab load subsumes the
-  plan; streaming was never needed on this node).
+  the streamer never evicts on this node (resident placement would do).
 * ``STR002`` (warning) — the union overflows, but a nonempty prefix of
-  the node's task order fits: compilable **with a pinned prefix** (pin
-  the prefix's params resident, stream the suffix interpreted).  The
-  payload carries the split point.
+  the node's task order fits: eviction starts at the split point the
+  payload carries.
 * ``STR003`` (warning) — no useful prefix fits (the first
-  parameter-bearing task already overflows): **interpreter-only**, the
-  node must evict from its very first task.
-
-:func:`stream_verdict` folds a report to the schedule-wide class;
-``backends/device.execute(compiled=True, stream_params=True)`` uses it to
-replace the historical unconditional refusal with a diagnostic-driven
-one (:func:`compiled_stream_refusal`) — the first concrete step on the
-ROADMAP's "lower the streamed schedules" item.
+  parameter-bearing task already overflows): the node must evict from
+  its very first task.
 """
 
 from __future__ import annotations
@@ -93,8 +83,7 @@ def analyze_streaming(
                 "STR001",
                 Severity.INFO,
                 f"{nid} streams {total:.2f} GB of params within its "
-                f"{budget:.2f} GB budget: compilable as-is (the resident "
-                f"slab subsumes the streaming plan)",
+                f"{budget:.2f} GB budget: nothing is ever evicted",
                 node=nid,
                 data={"union_gb": total, "budget_gb": budget},
             )
@@ -103,9 +92,9 @@ def analyze_streaming(
                 "STR002",
                 Severity.WARNING,
                 f"{nid} needs {total:.2f} GB of params against a "
-                f"{budget:.2f} GB budget; compilable only with the first "
-                f"{prefix_len} task(s) pinned ({prefix_gb:.2f} GB), "
-                f"streaming resumes at {spill_task!r}",
+                f"{budget:.2f} GB budget; the first {prefix_len} task(s) "
+                f"fit ({prefix_gb:.2f} GB), eviction starts at "
+                f"{spill_task!r}",
                 node=nid,
                 task=spill_task,
                 data={
@@ -122,7 +111,7 @@ def analyze_streaming(
                 Severity.WARNING,
                 f"{nid} must evict from its first parameter-bearing task "
                 f"({spill_task!r}): {total:.2f} GB of params against "
-                f"{budget:.2f} GB, interpreter-only",
+                f"{budget:.2f} GB",
                 node=nid,
                 task=spill_task,
                 data={
@@ -132,34 +121,3 @@ def analyze_streaming(
                 },
             )
     return rep
-
-
-def stream_verdict(report: AnalysisReport) -> str:
-    """Fold a stream-pass report to the schedule-wide classification:
-    ``"compilable"`` / ``"pinned-prefix"`` / ``"interpreter-only"``
-    (worst node wins; nodes without STR findings are compilable)."""
-    if report.has("STR003"):
-        return "interpreter-only"
-    if report.has("STR002"):
-        return "pinned-prefix"
-    return "compilable"
-
-
-def compiled_stream_refusal(report: AnalysisReport) -> AnalysisReport:
-    """The gate-grade form of a non-compilable verdict: STR002/STR003
-    findings promoted to errors (unchanged messages), so the compiled
-    path's refusal carries the per-node diagnosis instead of a blanket
-    'incompatible with stream_params'."""
-    out = AnalysisReport()
-    for d in report.diagnostics:
-        if d.code in ("STR002", "STR003"):
-            out.add(
-                d.code,
-                Severity.ERROR,
-                d.message,
-                task=d.task,
-                node=d.node,
-                param=d.param,
-                data=dict(d.data),
-            )
-    return out

@@ -1,9 +1,8 @@
 """Pass 8 — schedule typechecking over ``ShapeDtypeStruct`` avals.
 
 An abstract interpreter that symbolically executes the placed schedule
-edge-by-edge, the same ``jax.eval_shape`` propagation the whole-program
-lowering performs (``backends/dispatch_plan.propagate_avals``) but run
-*tolerantly* at lint time, before any trace:
+edge-by-edge by ``jax.eval_shape`` propagation, run *tolerantly* at lint
+time, before any trace:
 
 * ``TYP001`` (error) — a task's fn does not typecheck against the avals
   its dependency edges deliver, or its declared ``out_shape`` disagrees
@@ -20,13 +19,6 @@ lowering performs (``backends/dispatch_plan.propagate_avals``) but run
   (``TaskGraph.output_gb``: ``out_bytes`` when the XLA preflight set it,
   else ``memory_required``) — the same basis the CST pass calibrates and
   the MEM pass replays, so their payloads are directly comparable.
-* ``TYP004`` (error) — the linearized :class:`..sched.linearize.ProgramIR`
-  dispatches a task whose argument is not available on its device at
-  that phase (not computed locally earlier, not exchanged at an earlier
-  boundary), or an exchange whose source value does not exist.  This is
-  exactly the class of failure that otherwise surfaces as a ``KeyError``
-  (or, worse, a silent zeros placeholder) inside
-  ``CompiledSchedule.build``'s branch construction.
 
 Params are symbolic throughout: a ModelDAG ``param_specs`` table (shape
 structs / QParam spec pytrees) works directly, no weight init needed.
@@ -352,124 +344,6 @@ def check_transfer_bytes(
     return rep
 
 
-def check_program_arity(graph: TaskGraph, ir: Any) -> AnalysisReport:
-    """TYP004: every argument of every dispatched task must be available
-    on its device at its phase — computed there earlier, or delivered by
-    an exchange at a strictly earlier boundary (exchanges at boundary
-    ``b`` publish into phases ``> b``) — and every exchange must name a
-    value its source device has actually computed.  A violation is the
-    static form of the ``KeyError`` / silent-zeros failure inside
-    ``CompiledSchedule.build``."""
-    rep = AnalysisReport()
-    devices = set(ir.devices)
-    phase_of: Dict[str, int] = {}
-    node_of: Dict[str, str] = {}
-    pos_in_phase: Dict[str, int] = {}
-    for ph in ir.phases:
-        for n, tids in ph.compute.items():
-            for i, t in enumerate(tids):
-                phase_of[t] = ph.index
-                node_of[t] = n
-                pos_in_phase[t] = i
-    # (value, dst) -> earliest boundary it is exchanged at
-    delivered: Dict[Tuple[str, str], int] = {}
-    for ph in ir.phases:
-        for ex in ph.exchanges:
-            src_phase = phase_of.get(ex.tid)
-            if src_phase is None or node_of.get(ex.tid) != ex.src:
-                rep.add(
-                    "TYP004",
-                    Severity.ERROR,
-                    f"exchange at boundary {ph.index} ships {ex.tid!r} from "
-                    f"{ex.src} but {ex.src} never computes it",
-                    task=ex.tid,
-                    node=ex.src,
-                    data={"boundary": ph.index},
-                )
-                continue
-            if src_phase > ph.index:
-                rep.add(
-                    "TYP004",
-                    Severity.ERROR,
-                    f"exchange at boundary {ph.index} ships {ex.tid!r} "
-                    f"before {ex.src} computes it (phase {src_phase})",
-                    task=ex.tid,
-                    node=ex.src,
-                    data={"boundary": ph.index, "src_phase": src_phase},
-                )
-                continue
-            if ex.dst not in devices or ex.src not in devices:
-                rep.add(
-                    "TYP004",
-                    Severity.ERROR,
-                    f"exchange of {ex.tid!r} names a device outside the "
-                    f"mesh ({ex.src} -> {ex.dst})",
-                    task=ex.tid,
-                    data={"src": ex.src, "dst": ex.dst},
-                )
-                continue
-            key = (ex.tid, ex.dst)
-            if key not in delivered or ph.index < delivered[key]:
-                delivered[key] = ph.index
-    for ph in ir.phases:
-        for n, tids in ph.compute.items():
-            for i, t in enumerate(tids):
-                if t not in graph:
-                    rep.add(
-                        "TYP004",
-                        Severity.ERROR,
-                        f"program dispatches {t!r} which is not a graph task",
-                        task=t,
-                        node=n,
-                    )
-                    continue
-                for d in graph[t].arg_tasks or graph[t].dependencies:
-                    if d not in phase_of:
-                        rep.add(
-                            "TYP004",
-                            Severity.ERROR,
-                            f"{t!r} on {n} (phase {ph.index}) consumes "
-                            f"{d!r}, which the program never computes",
-                            task=t,
-                            node=n,
-                            data={"phase": ph.index, "arg": d},
-                        )
-                        continue
-                    if node_of[d] == n:
-                        ok = phase_of[d] < ph.index or (
-                            phase_of[d] == ph.index and pos_in_phase[d] < i
-                        )
-                        if not ok:
-                            rep.add(
-                                "TYP004",
-                                Severity.ERROR,
-                                f"{t!r} on {n} (phase {ph.index}) consumes "
-                                f"{d!r} before it runs (phase "
-                                f"{phase_of[d]})",
-                                task=t,
-                                node=n,
-                                data={"phase": ph.index, "arg": d},
-                            )
-                    else:
-                        b = delivered.get((d, n))
-                        if b is None or b >= ph.index:
-                            rep.add(
-                                "TYP004",
-                                Severity.ERROR,
-                                f"{t!r} on {n} (phase {ph.index}) consumes "
-                                f"{d!r} from {node_of[d]} with no exchange "
-                                f"at an earlier boundary",
-                                task=t,
-                                node=n,
-                                data={
-                                    "phase": ph.index,
-                                    "arg": d,
-                                    "producer_node": node_of[d],
-                                },
-                            )
-    return rep
-
-
 def analyze_typecheck(
     graph: TaskGraph,
     cluster: Optional[Cluster] = None,
@@ -478,13 +352,10 @@ def analyze_typecheck(
     params: Optional[Dict[str, Any]] = None,
     param_specs: Optional[Dict[str, Any]] = None,
     graph_input: Any = None,
-    ir: Any = None,
 ) -> AnalysisReport:
     """Run the full typecheck pass: TYP001/TYP002 always (they are
-    placement-independent), TYP003/TYP004 when a placement exists.
-    ``ir`` skips the internal :func:`..sched.linearize.linearize` when the
-    caller already lowered; an un-linearizable schedule (per-node order
-    deadlock) skips TYP004 — that is COL002's finding, not ours."""
+    placement-independent), TYP003 when a placement exists."""
+    del cluster  # accepted for interface symmetry with the other passes
     avals, rep = propagate_schedule_avals(
         graph,
         params=params,
@@ -494,16 +365,4 @@ def analyze_typecheck(
     rep.extend(check_quantized_edges(graph, avals, param_specs))
     if schedule is not None:
         rep.extend(check_transfer_bytes(graph, schedule, avals))
-        if ir is None:
-            try:
-                from ..sched.linearize import linearize
-
-                device_order = (
-                    [d.node_id for d in cluster] if cluster is not None else None
-                )
-                ir = linearize(graph, schedule, device_order=device_order)
-            except Exception:
-                ir = None  # deadlocked/corrupt schedule: COL002/SCH territory
-        if ir is not None:
-            rep.extend(check_program_arity(graph, ir))
     return rep

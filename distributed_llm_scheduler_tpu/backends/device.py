@@ -38,6 +38,7 @@ from __future__ import annotations
 import functools
 import time
 import warnings
+import weakref
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -83,9 +84,8 @@ class DeviceReport:
     timings: Dict[str, TaskTiming] = field(default_factory=dict)
     # per-device HBM peaks, when the platform reports memory_stats
     peak_hbm_bytes: Dict[str, int] = field(default_factory=dict)
-    # executable launches issued (== placed tasks per-task; == segments
-    # under segment fusion; == plan steps — coalesced groups count once —
-    # under planned dispatch)
+    # executable launches issued (== plan steps under planned dispatch,
+    # a fused span counting once; == placed tasks on the per-task loop)
     n_dispatches: int = 0
     # host wall seconds spent inside the dispatch loop, per rep (launch +
     # staging; end-of-run fence excluded).  Launches return at enqueue, so
@@ -94,11 +94,11 @@ class DeviceReport:
     # block on device compute it is an upper bound.
     dispatch_overhead_s: float = 0.0
     # the call's wall time tiled into phases, always on.  Per rep, the
-    # loop wall: planned and compiled dispatch report {loop_s, stage_s
-    # (input placement + batched transfers), launch_s}, with loop_s their
-    # sum; the legacy paths report {loop_s}.  Per call: order_s
-    # (dispatch_order), place_s (place_params), plan_s (plan, program or
-    # segment build), warmup_s, rtt_s (the fence round-trip probe),
+    # loop wall: planned dispatch reports {loop_s, stage_s (input
+    # placement + batched transfers), launch_s}, with loop_s their sum;
+    # the per-task loop reports {loop_s}.  Per call: order_s
+    # (dispatch_order), place_s (place_params), plan_s (plan build),
+    # warmup_s, rtt_s (the fence round-trip probe),
     # fence_s (the host waiting in the end-of-run fence: how far the
     # device is behind the host), report_s (memory stats, registry, this
     # report) and other_s = wall_s less all of these: see leaf_phases()
@@ -107,13 +107,8 @@ class DeviceReport:
     wall_s: float = 0.0
     # True when the run used the pre-planned fast path (dispatch_plan)
     planned: bool = False
-    # True when the run used the whole-program compiled path
-    # (compiled_schedule): ONE launch per run, cross-device edges as
-    # in-program collectives
-    compiled: bool = False
-    # execute(keep_outputs=True): per-task outputs retained for elastic
-    # recovery (every executed task per-task; segment exports under
-    # segment fusion).  Keys feed reschedule()/execute(ext_outputs=...)
+    # execute(keep_outputs=True): every executed task's output, retained
+    # for elastic recovery.  Keys feed reschedule()/execute(ext_outputs=...)
     task_outputs: Dict[str, Any] = field(default_factory=dict)
     # execute(stream_params=True): streaming statistics.  ``streamed`` is
     # the explicit mode flag — a streamed run that happened to load zero
@@ -188,7 +183,6 @@ class DeviceReport:
                 k: v * 1e3 for k, v in self.dispatch_phases.items()
             },
             "planned": self.planned,
-            "compiled": self.compiled,
             "peak_hbm_gb": {
                 k: v / 1024**3 for k, v in self.peak_hbm_bytes.items()
             },
@@ -257,13 +251,6 @@ class DeviceBackend:
         # from _jit_cache so tasks sharing one fn but dying-buffer patterns
         # that differ never collide
         self._donate_jit_cache: Dict[Tuple[Any, Tuple[int, ...]], Any] = {}
-        # graph -> {(tids, exports): jitted segment fn}; weak so a dead
-        # graph releases its compiled segments
-        import weakref
-
-        self._seg_cache: "weakref.WeakKeyDictionary" = (
-            weakref.WeakKeyDictionary()
-        )
         # (launch structure, donate_argnums) -> jitted fused launch
         # (dispatch_plan.launch_structure: member fn objects, in-run
         # wiring by position, exported positions).  No task id, graph or
@@ -271,16 +258,9 @@ class DeviceBackend:
         # execute() calls wired alike share one executable
         self._group_cache: Dict[Any, Callable[..., Any]] = {}
         # graph -> True when no task fn carries a jaxpr effect (host
-        # callbacks): what lets execute() fuse launches by default
+        # callbacks): what lets execute() fuse launches by default; weak
+        # so a dead graph releases its entry
         self._effect_free: "weakref.WeakKeyDictionary" = (
-            weakref.WeakKeyDictionary()
-        )
-        # graph -> {program signature: jitted whole-program callable}
-        # (compiled_schedule); the signature pins every structural input
-        # (IR, slab layout, input avals, donation), so repeated executes
-        # of one schedule reuse the XLA executable while slabs restage
-        # from the CURRENT params
-        self._prog_cache: "weakref.WeakKeyDictionary" = (
             weakref.WeakKeyDictionary()
         )
         # graph -> PreparedCall (dispatch_plan): what the planned path of
@@ -334,8 +314,8 @@ class DeviceBackend:
         compute.  Deliberately NO ``block_until_ready`` on the outputs
         first: it would add host work the single-RTT correction does not
         net out, and it adds nothing — the dependent readback already
-        implies completion.  Shared by the per-task and segment-fused
-        paths so their makespan measurements cannot drift.
+        implies completion.  Shared by the plan and the per-task loop so
+        their makespan measurements cannot drift.
         """
         from ..utils.costmodel import readback_fence
 
@@ -804,13 +784,8 @@ class DeviceBackend:
         schedule: Schedule,
         placed_params: Dict[Tuple[str, str], Any],
         graph_input: Any,
-        segments: bool = False,
         ext_outputs: Optional[Dict[str, Any]] = None,
         streamer: Optional["DeviceBackend._ParamStreamer"] = None,
-        rebatch: bool = True,
-        segments_pre: Optional[
-            List[Tuple[str, Tuple[str, ...], Tuple[str, ...]]]
-        ] = None,
     ) -> float:
         """Compile every (fn, placement-device) combination ahead of time;
         returns seconds.
@@ -820,16 +795,10 @@ class DeviceBackend:
         compilation — the analog of XLA's compile-once/run-many contract.
         """
         t0 = time.perf_counter()
-        if segments:
-            self._run_segmented(
-                graph, schedule, placed_params, graph_input, ext_outputs,
-                rebatch=rebatch, streamer=streamer, segments_pre=segments_pre,
-            )
-        else:
-            self._run(
-                graph, schedule, placed_params, graph_input, profile=False,
-                ext_outputs=ext_outputs, streamer=streamer,
-            )
+        self._run(
+            graph, schedule, placed_params, graph_input, profile=False,
+            ext_outputs=ext_outputs, streamer=streamer,
+        )
         return time.perf_counter() - t0
 
     # -- dispatch order ----------------------------------------------------
@@ -897,392 +866,6 @@ class DeviceBackend:
             t for t in graph.topo_order if t in placement and t not in emitted
         )
         return order
-
-    # -- segment fusion ----------------------------------------------------
-    @staticmethod
-    def build_segments(
-        graph: TaskGraph,
-        schedule: Schedule,
-        order: List[str],
-        max_union_gb: Optional[Dict[str, float]] = None,
-        param_gb: Optional[Dict[str, float]] = None,
-    ) -> List[Tuple[str, Tuple[str, ...], Tuple[str, ...]]]:
-        """Partition the dispatch order into per-device segments.
-
-        A segment is a maximal run of consecutive (in dispatch order) tasks
-        placed on the same device; each becomes ONE jitted executable, so
-        XLA fuses across task boundaries and the host issues one launch per
-        segment instead of one per task — the task-batching answer to
-        SURVEY.md §7 hard-part #1 (per-task dispatch overhead swamping many
-        small tasks), applied *post-placement* so the scheduler's decisions
-        are untouched.  Segment boundaries are exactly the schedule's
-        device switches: on one chip the whole DAG is one program (the
-        fused forward, recovered automatically); a pipeline's 1F1B
-        interleaving yields one segment per microbatch-stage visit, with
-        real transfers between them.
-
-        Returns (node_id, tids, exports): ``exports`` are the tasks whose
-        outputs are consumed by later segments or by nobody (leaves —
-        kept for the end-of-run fence and the final output).
-
-        ``max_union_gb`` (budget-aware segmentation, for segment-granular
-        parameter streaming): a per-node cap on a segment's param-global
-        union — a run splits when adding a task would push its union past
-        the cap, so each fused program's weights fit the streaming budget
-        and eviction happens between segments.  A single task whose own
-        params exceed the cap still gets a (over-budget) segment — the
-        same escape as the streamer's pinned-params rule.  Without the
-        cap, one device's whole run is one segment and an oversubscribed
-        model's union could never fit.
-
-        ``param_gb`` overrides per-name sizes (callers with the actual
-        host arrays pass TRUE device bytes); missing names fall back to
-        the graph-wide declared sizes.
-        """
-        placement = schedule.placement
-        runs: List[Tuple[str, List[str]]] = []
-        run_names: set = set()   # current run's param-global names
-        run_total = 0.0          # its union GB — running total, O(1)/task
-        sizes = param_gb or {}
-
-        def size_of(g: str) -> float:
-            # caller-supplied TRUE bytes when available (declared/default
-            # sizes can under-count and defeat the split); graph-wide
-            # declared sizes otherwise
-            s = sizes.get(g)
-            return s if s is not None else graph.param_size_gb(g)
-
-        for tid in order:
-            if tid not in placement:
-                continue
-            node = placement[tid]
-            globs = list(dict.fromkeys(
-                g for _, g in graph[tid].param_items()
-            ))
-            same_node = bool(runs) and runs[-1][0] == node
-            if same_node and max_union_gb and node in max_union_gb:
-                extra = sum(
-                    size_of(g) for g in globs if g not in run_names
-                )
-                if run_total + extra > max_union_gb[node] and run_names:
-                    same_node = False  # budget split (never an empty run)
-            if same_node:
-                runs[-1][1].append(tid)
-            else:
-                runs.append((node, [tid]))
-                run_names = set()
-                run_total = 0.0
-            for g in globs:
-                if g not in run_names:
-                    run_names.add(g)
-                    run_total += size_of(g)
-        consumers: Dict[str, set] = {tid: set() for tid in placement}
-        for seg_i, (_, tids) in enumerate(runs):
-            for tid in tids:
-                for d in graph[tid].arg_tasks or graph[tid].dependencies:
-                    if d in consumers:
-                        consumers[d].add(seg_i)
-        segments = []
-        for seg_i, (node, tids) in enumerate(runs):
-            exports = tuple(
-                t for t in tids
-                if consumers[t] - {seg_i} or not consumers[t]
-            )
-            segments.append((node, tuple(tids), exports))
-        return segments
-
-    def _segment_callable(self, graph: TaskGraph, tids: Tuple[str, ...],
-                          exports: Tuple[str, ...],
-                          rebatch: bool = True):
-        """One jitted fn running ``tids`` in order: (params-by-global-name,
-        external-inputs-by-task-id) -> {export tid: output}.
-
-        Cached per (graph, tids, exports, rebatch): the graph key (a
-        WeakKey, so dead graphs release their executables) prevents a
-        backend reused across graphs with colliding task ids from running
-        stale fns, and ``exports`` is part of the key because the same run
-        under a different downstream placement must return a different
-        output set.
-
-        ``rebatch=True`` applies the segment re-batching pass
-        (:mod:`.rebatch`): sibling tasks (isomorphic microbatch chains)
-        marked batch-axis-0 polymorphic execute as ONE call on
-        concatenated inputs — recovering the fused forward's full-batch
-        op shapes that the microbatch split fragments.  Placement,
-        transfers, and the export contract are unchanged; graphs with no
-        eligible siblings compile to exactly the unbatched program.
-        """
-        per_graph = self._seg_cache.setdefault(graph, {})
-        key = (tids, exports, rebatch)
-        fn = per_graph.get(key)
-        if fn is not None:
-            return fn
-
-        if rebatch:
-            from .rebatch import build_rebatched_seg_fn, plan_rebatch
-
-            plan = plan_rebatch(graph, tids)
-            if plan.classes:
-                fn = jax.jit(
-                    build_rebatched_seg_fn(graph, tids, exports, plan)
-                )
-                per_graph[key] = fn
-                return fn
-
-        # extract per-task (fn, params, args) up front: the closure must
-        # NOT capture `graph`, or the cache value would strongly reference
-        # its own WeakKey and the graph could never be collected
-        steps = tuple(
-            (
-                tid,
-                graph[tid].fn,
-                tuple(graph[tid].param_items()),
-                tuple(graph[tid].arg_tasks or graph[tid].dependencies),
-            )
-            for tid in tids
-        )
-
-        def seg_fn(seg_params, ext):
-            vals: Dict[str, Any] = {}
-            for tid, task_fn, pitems, aids in steps:
-                pd = {loc: seg_params[g] for loc, g in pitems}
-                if aids:
-                    # KeyError here = a segment-boundary bookkeeping bug;
-                    # never silently pass None into a task fn
-                    args = [vals[d] if d in vals else ext[d] for d in aids]
-                else:
-                    args = [ext["__input__"]]
-                vals[tid] = task_fn(pd, *args)
-            return {t: vals[t] for t in exports}
-
-        fn = jax.jit(seg_fn)
-        per_graph[key] = fn
-        return fn
-
-    # fraction of a node's streaming budget one segment's param union may
-    # occupy: 0.5 leaves room for the NEXT segment's union to prefetch
-    # while the current fused program runs (double buffering)
-    STREAM_SEGMENT_FRAC = 0.5
-
-    def _stream_segment_caps(self) -> Dict[str, float]:
-        return {
-            d.node_id: d.total_memory * self.STREAM_SEGMENT_FRAC
-            for d in self.cluster
-        }
-
-    @staticmethod
-    def segment_stream_plan(
-        graph: TaskGraph,
-        segments: List[Tuple[str, Tuple[str, ...], Tuple[str, ...]]],
-    ) -> Dict[str, List[Tuple[str, Tuple[str, ...]]]]:
-        """Per-node streamer plan at SEGMENT granularity: each entry is
-        (synthetic segment id, the segment's param-global union).  The
-        streamer's plan interface is unit-agnostic, so the same prefetch +
-        Belady machinery that serves per-task streaming serves segments —
-        one batched load per segment, next segment prefetched while the
-        current fused program runs."""
-        plan: Dict[str, List[Tuple[str, Tuple[str, ...]]]] = {}
-        for i, (node, tids, _exports) in enumerate(segments):
-            seen: Dict[str, None] = {}
-            for tid in tids:
-                for _, g in graph[tid].param_items():
-                    seen.setdefault(g)
-            plan.setdefault(node, []).append((f"__seg{i}", tuple(seen)))
-        return plan
-
-    def _run_segmented(
-        self,
-        graph: TaskGraph,
-        schedule: Schedule,
-        placed_params: Dict[Tuple[str, str], Any],
-        graph_input: Any,
-        ext_outputs: Optional[Dict[str, Any]] = None,
-        fence: bool = True,
-        rebatch: bool = True,
-        streamer: Optional["DeviceBackend._ParamStreamer"] = None,
-        segments_pre: Optional[
-            List[Tuple[str, Tuple[str, ...], Tuple[str, ...]]]
-        ] = None,
-        order: Optional[List[str]] = None,
-        tracer: Any = None,
-        metrics: Any = None,
-        mem: Any = None,
-    ) -> Tuple[
-        Any, Dict[str, TaskTiming], int, int, int, int, Dict[str, Any],
-        Dict[str, float],
-    ]:
-        """Segment-fused execution: same placement, one launch per segment.
-        Tasks with failed upstreams are dropped at segment-build time (host
-        side), preserving fail-and-continue.  Cross-segment inputs are
-        deduplicated per segment — a remote value consumed by several tasks
-        of one segment transfers once, so transfer counts can be LOWER than
-        per-task dispatch (an inherent win of batching, reported as
-        measured).
-
-        ``streamer``: segment-granular parameter streaming (oversubscribed
-        models at fused dispatch speed): runs are budget-split so each
-        segment's param union fits ``STREAM_SEGMENT_FRAC`` of the node's
-        budget (leaving room to prefetch the NEXT segment's union while
-        the current program runs — double buffering), each union loads as
-        one batched transfer, and eviction fences anchor on segment
-        outputs.  The streamer must have been built with
-        :meth:`segment_stream_plan` over the same budget-split segments
-        (``execute`` guarantees this; a drop-filter divergence only costs
-        prefetch accuracy, never correctness)."""
-        placement = schedule.placement
-        if order is None:
-            order = self.dispatch_order(graph, schedule)
-        # drop tasks whose (transitive) producers are unplaced/skipped —
-        # the host-side equivalent of the per-task path's upstream check.
-        # ext_outputs (elastic recovery) count as alive producers.
-        alive: set = set(ext_outputs or ())
-        for tid in order:
-            aids = graph[tid].arg_tasks or graph[tid].dependencies
-            if all(d in alive for d in aids):
-                alive.add(tid)
-        order = [t for t in order if t in alive and t not in (ext_outputs or ())]
-        # caller-precomputed segments (execute builds them once for the
-        # streamer plan, the warmup, and every timed rep — a rebuild here
-        # would land inside the makespan window).  Only reusable when no
-        # task was drop-filtered: the precomputation ran unfiltered.
-        segments = None
-        if segments_pre is not None:
-            if sum(len(t) for _n, t, _e in segments_pre) == len(order):
-                segments = segments_pre
-        if segments is None:
-            segments = self.build_segments(
-                graph, schedule, order,
-                max_union_gb=(
-                    self._stream_segment_caps() if streamer else None
-                ),
-                # the drop-filter rebuild must size by true bytes too, or
-                # under-declared params defeat the budget split on exactly
-                # this path (the streamer holds the host arrays)
-                param_gb=(
-                    {
-                        g: _array_bytes(streamer.host_params[g]) / (1024**3)
-                        for g in graph.unique_params()
-                        if g in streamer.host_params
-                    }
-                    if streamer else None
-                ),
-            )
-
-        outputs: Dict[str, Any] = dict(ext_outputs or {})
-        transfer_edges = 0
-        transfer_bytes = 0
-        # obs: one span per fused segment on its device track, flow
-        # arrows for cross-segment transfers (producer export -> consumer
-        # segment); all behind None checks
-        done_at: Optional[Dict[str, Tuple[str, float]]] = (
-            {} if tracer is not None else None
-        )
-        t_loop0 = time.perf_counter()
-        for seg_i, (node, tids, exports) in enumerate(segments):
-            dev = self.cluster[node].jax_device
-            union: Dict[str, Any] = {}
-            ext: Dict[str, Any] = {}
-            inside = set(tids)
-            needs_input = False
-            union_names: Dict[str, None] = {}
-            flow_srcs = [] if tracer is not None else None
-            t_s0 = time.perf_counter() if tracer is not None else 0.0
-            for tid in tids:
-                task = graph[tid]
-                for _, g in task.param_items():
-                    union_names.setdefault(g)
-                aids = task.arg_tasks or task.dependencies
-                if not aids:
-                    needs_input = True
-                for d in aids:
-                    if d not in inside and d not in ext:
-                        x = outputs[d]
-                        if placement.get(d) != node:
-                            transfer_edges += 1
-                            nb = _array_bytes(x)
-                            transfer_bytes += nb
-                            x = jax.device_put(x, dev)
-                            if tracer is not None:
-                                flow_srcs.append((d, nb))
-                            if metrics is not None:
-                                metrics.counter(
-                                    "transfer.bytes."
-                                    f"{placement.get(d, 'ext')}->{node}",
-                                    unit="bytes",
-                                ).inc(nb)
-                            if mem is not None:
-                                mem.alloc(
-                                    node, f"xfer:{d}", nb, "transfers"
-                                )
-                        ext[d] = x
-            if streamer is not None:
-                union = streamer.get_task(
-                    f"__seg{seg_i}", node,
-                    [(g, g) for g in union_names],
-                )
-            else:
-                union = {
-                    g: placed_params[(g, node)] for g in union_names
-                }
-            if needs_input:
-                ext["__input__"] = jax.device_put(graph_input, dev)
-                if mem is not None:
-                    mem.alloc(
-                        node, "input", _array_bytes(graph_input),
-                        "activations",
-                    )
-            fn = self._segment_callable(graph, tids, exports, rebatch)
-            seg_out = fn(union, ext)
-            if mem is not None:
-                for e in exports:
-                    mem.alloc(
-                        node, f"out:{e}", _array_bytes(seg_out[e]),
-                        "activations",
-                    )
-            if tracer is not None:
-                t_s1 = time.perf_counter()
-                tracer.complete(
-                    f"seg{seg_i}", t_s0, t_s1, track=node, cat="launch",
-                    tasks=len(tids), exports=len(exports),
-                )
-                for e in exports:
-                    done_at[e] = (node, t_s1)
-                for d, nb in flow_srcs:
-                    src_pt = done_at.get(d)
-                    if src_pt is not None:
-                        tracer.flow(
-                            "transfer", src_pt[0], src_pt[1], node, t_s0,
-                            src=d, dst=f"seg{seg_i}", bytes=nb,
-                        )
-            outputs.update(seg_out)
-            if streamer is not None and exports:
-                streamer.note_task(
-                    node, list(union_names), seg_out[exports[-1]]
-                )
-        loop_s = time.perf_counter() - t_loop0
-
-        n_fences = 0
-        last_on_device: Dict[str, Any] = {}
-        for node, tids, exports in segments:
-            if exports:
-                last_on_device[node] = outputs[exports[-1]]
-        # guard on executed segments, not `outputs` — ext_outputs seeds can
-        # make `outputs` non-empty when nothing actually ran
-        fence_s = 0.0
-        if last_on_device and fence:
-            n_fences, fence_s = self._timed_fence(last_on_device, tracer)
-        # same semantics as the per-task path: None when the graph's last
-        # task didn't execute (callers detect incomplete runs by this)
-        final = outputs.get(graph.topo_order[-1]) if graph.topo_order else None
-        executed = {
-            k: v for k, v in outputs.items()
-            if not ext_outputs or k not in ext_outputs
-        }
-        return (
-            final, {}, transfer_edges, transfer_bytes, n_fences,
-            len(segments), executed,
-            {"loop_s": loop_s, "fence_s": fence_s},
-        )
 
     # -- execution ---------------------------------------------------------
     def _run(
@@ -1503,17 +1086,14 @@ class DeviceBackend:
         graph_input: Any,
         profile: bool = False,
         warmup: bool = True,
-        segments: bool = False,
         ext_outputs: Optional[Dict[str, Any]] = None,
         keep_outputs: bool = False,
         stream_params: bool = False,
         stream_lookahead: int = 8,
         reps: int = 1,
-        rebatch: bool = True,
         planned: Optional[bool] = None,
         coalesce: bool = True,
         donate: Optional[bool] = None,
-        compiled: bool = False,
         fence_rtt: Optional[float] = None,
         trace: Any = None,
         metrics: Any = None,
@@ -1522,46 +1102,26 @@ class DeviceBackend:
     ) -> DeviceReport:
         """Place params, compile, run, measure.
 
-        ``planned`` selects the pre-planned fast dispatch path
-        (:mod:`.dispatch_plan`): an immutable launch plan built at
-        warmup (resolved executables, prebuilt param bindings, integer
-        value-table indices, batched per-launch ``device_put`` staging),
-        so the hot loop issues only cached-executable calls — one per
-        fused same-device run (``coalesce`` below), not one per task.
-        The plan, the dispatch order, the gate's pass and the placed
-        weights stay on the backend (``PreparedCall``, one per live
-        graph): a call with the same graph, an equal
-        ``schedule.signature()``, the same flags and input shapes builds
-        nothing, and puts only the parameters whose ``params[name]`` is
-        no longer the ``jax.Array`` placed (a host array is put every
-        call); ``memprof`` calls start from nothing.  Default
-        (``None``) auto-enables it whenever compatible — ``profile``
-        (needs per-task timing hooks), ``stream_params`` (param residency
-        changes mid-run), and ``segments`` (already fused) keep the
-        legacy paths.  Placement, dispatch order, transfer counting, and
-        the end-of-run fence are identical to the legacy loop; outputs
-        are bit-identical.
-
-        ``compiled`` selects the whole-program path
-        (:mod:`.compiled_schedule`): the entire placed run lowers into
-        ONE jitted program (per-device compute under a ``lax.switch``
-        over the mesh index, cross-device edges as in-program
-        ``ppermute`` collectives), so the host issues one staging put
-        per input leaf plus a single launch per run.  Outputs stay
-        bit-identical to the interpreted paths (per-task
-        ``optimization_barrier`` islands).  Lowering runs the COL00x
-        collective-ordering gate; a schedule whose per-node orders admit
-        no global collective order raises (COL002) instead of silently
-        re-linearizing.  Incompatible with every per-task feature
-        (``profile``/``segments``/``keep_outputs``/``ext_outputs``) —
-        see docs/ARCHITECTURE.md's execution ladder
-        for when to pick which rung.  ``stream_params`` composes via the
-        static stream-safety prover (analysis/stream_pass.py): when
-        every node's param union fits its HBM budget (STR001 on all
-        nodes) the run compiles as-is — the resident slab subsumes the
-        streaming plan — otherwise the call raises ``AnalysisError``
-        carrying the per-node STR002/STR003 diagnosis instead of the
-        historical blanket refusal.
+        There are two ways to run a placed step.  The **plan**
+        (:mod:`.dispatch_plan`, ``planned``, the default): an immutable
+        launch plan built once (resolved executables, prebuilt param
+        bindings, integer value-table indices, batched per-launch
+        ``device_put`` staging), so the hot loop issues only
+        cached-executable calls — one per fused same-device run
+        (``coalesce`` below), not one per task.  The plan, the dispatch
+        order, the gate's pass and the placed weights stay on the
+        backend (``PreparedCall``, one per live graph): a call with the
+        same graph, an equal ``schedule.signature()``, the same flags
+        and input shapes builds nothing, and puts only the parameters
+        whose ``params[name]`` is no longer the ``jax.Array`` placed (a
+        host array is put every call); ``memprof`` calls start from
+        nothing.  The **per-task loop** (:meth:`_run`, ``planned=False``):
+        one launch a task, order and placement derived anew every call;
+        kept because ``profile`` (per-task timing hooks) and
+        ``stream_params`` (param residency changes mid-run) need it, and
+        chosen for them when ``planned`` is ``None``.  Placement,
+        dispatch order, transfer counting and the end-of-run fence are
+        the same on both; outputs are bit-identical.
 
         ``pre_report``: a report ``analysis.analyze()`` just produced
         for this exact (graph, schedule) — the pre-execution gate then
@@ -1578,9 +1138,8 @@ class DeviceBackend:
         forced off by ``keep_outputs`` (retained outputs must outlive the
         run — passing ``donate=True`` with ``keep_outputs`` raises).
 
-        ``coalesce`` (the planned path's default; every other path
-        launches as it always did): launch runs of consecutive
-        same-device tasks as ONE program each
+        ``coalesce`` (planned only, its default): launch runs of
+        consecutive same-device tasks as ONE program each
         (:mod:`.dispatch_plan`, "fused launches"), with
         ``optimization_barrier`` between members so per-task outputs stay
         bit-identical: O(runs) launches a step where the per-task plan
@@ -1616,27 +1175,22 @@ class DeviceBackend:
         (``task_outputs``) so a LATER failure can recover without
         recomputation: pass the surviving subset to ``surviving_work``'s
         ``have_outputs`` and to the re-execution's ``ext_outputs``.
-        Per-task dispatch keeps every executed task's output; segment
-        fusion keeps segment exports only (internal values never left
-        their fused program).  Costs device memory proportional to
+        Every executed task's output is kept (a fused launch then
+        exports each member).  Costs device memory proportional to
         activations held.
 
         ``stream_params=True`` replaces up-front param placement with
         planned streaming under each node's ``total_memory`` budget
         (:class:`_ParamStreamer`): batched loads prefetched
-        ``stream_lookahead`` units ahead of the dispatch cursor, Belady
+        ``stream_lookahead`` tasks ahead of the dispatch cursor, Belady
         (farthest-next-use) eviction, and minimal-wait deletion — a node
         whose assigned weights exceed its HBM budget still executes,
         trading host-link bandwidth for capacity (the reference's
-        param-cache eviction made physical) while loads overlap compute.
-        Composes with ``segments=True``: the streaming unit becomes the
-        SEGMENT (one batched load per fused program's param union, next
-        segment prefetched while the current one runs), so oversubscribed
-        models run at fused dispatch granularity; a segment whose union
-        alone exceeds the budget runs over-budget with the peak recorded
-        (same escape as a single task's pinned params).  The report
-        carries ``param_loads``/``param_load_calls``/
-        ``param_load_bytes``/``param_evictions``/``peak_param_bytes``.
+        param-cache eviction made physical) while loads overlap compute;
+        a task whose own params exceed the budget runs over-budget with
+        the peak recorded.  The report carries ``param_loads``/
+        ``param_load_calls``/``param_load_bytes``/``param_evictions``/
+        ``peak_param_bytes``.
 
         ``profile=True`` records per-task wall times via per-task
         ``block_until_ready`` (Gantt charts / diagnostics, and what
@@ -1644,13 +1198,6 @@ class DeviceBackend:
         includes the task's host dispatch.  ``profile=False`` measures
         makespan ending at a single combined readback fence, its
         round-trip netted out.
-
-        ``segments=True`` fuses each device's contiguous scheduled run into
-        one XLA executable (:meth:`build_segments`): identical placement
-        and transfers, one launch per segment — the production execution
-        mode where per-task dispatch overhead would otherwise dominate
-        (e.g. hundreds of sub-ms tasks).  Incompatible with ``profile``
-        (task boundaries vanish inside the fused programs).
 
         ``trace`` / ``metrics`` attach an :class:`..obs.trace.Tracer` /
         :class:`..obs.metrics.MetricsRegistry` to this run: host phase
@@ -1662,9 +1209,9 @@ class DeviceBackend:
         hot paths guard every record behind a ``None`` check).
 
         ``memprof`` attaches an :class:`..obs.memprof.MemoryProfiler`:
-        the run records param staging / slab construction, task-output
-        births, donation-driven frees, transfer copies, and input
-        staging as allocation events on per-device timelines, and the
+        the run records param staging, task-output births,
+        donation-driven frees, transfer copies, and input staging as
+        allocation events on per-device timelines, and the
         report carries ``memory`` (the profiler summary, platform
         ``memory_stats()`` peaks reconciled in where reported).  Warmup
         runs unrecorded, same as the tracer — only the timed reps land
@@ -1673,55 +1220,13 @@ class DeviceBackend:
         # the call's own wall starts here: checks, the pre-execution gate
         # and whatever else no phase below covers end up in ``other_s``
         t_call0 = time.perf_counter()
-        if segments and profile:
-            raise ValueError(
-                "profile=True needs per-task dispatch; run without segments"
-            )
-        if compiled:
-            if stream_params:
-                # historically an unconditional refusal; now the static
-                # stream-safety prover (analysis/stream_pass.py) decides:
-                # a schedule whose per-node param unions fit their HBM
-                # budgets compiles as-is — the resident slab load IS the
-                # whole residency plan — while anything that would need
-                # eviction stays on the interpreted streaming rung and is
-                # refused with the per-node STR diagnosis attached
-                from ..analysis import (
-                    AnalysisError,
-                    analyze_streaming,
-                    compiled_stream_refusal,
-                    stream_verdict,
-                )
-
-                srep = analyze_streaming(graph, self.cluster, schedule)
-                if stream_verdict(srep) != "compilable":
-                    raise AnalysisError(compiled_stream_refusal(srep))
-                stream_params = False
-            # the whole run is ONE XLA program: there are no per-task
-            # boundaries to time/stream/retain, no host-mediated segments,
-            # and external values would have to be program inputs
-            incompatible = [
-                name for name, flag in (
-                    ("profile", profile),
-                    ("segments", segments),
-                    ("keep_outputs", keep_outputs),
-                    ("ext_outputs", ext_outputs is not None),
-                    ("planned", bool(planned)),
-                ) if flag
-            ]
-            if incompatible:
-                raise ValueError(
-                    "compiled=True lowers the whole run into one program "
-                    f"and is incompatible with {incompatible}"
-                )
-            planned = False
         if planned is None:
-            planned = not (profile or stream_params or segments)
-        elif planned and (profile or stream_params or segments):
+            planned = not (profile or stream_params)
+        elif planned and (profile or stream_params):
             raise ValueError(
                 "planned dispatch is incompatible with profile (per-task "
-                "timing hooks), stream_params (param residency changes "
-                "mid-run), and segments (already fused)"
+                "timing hooks) and stream_params (param residency changes "
+                "mid-run)"
             )
         if not planned:
             coalesce = False
@@ -1730,15 +1235,13 @@ class DeviceBackend:
                 "donate=True deletes dying intermediates; keep_outputs "
                 "must retain them — drop one of the two"
             )
-        if planned or compiled:
+        if planned:
             from .dispatch_plan import donation_supported
 
             if donate is None:
                 donate = donation_supported() and not keep_outputs
         elif donate:
-            raise ValueError(
-                "donate=True requires the planned or compiled path"
-            )
+            raise ValueError("donate=True requires the planned path")
         else:
             donate = False
         if reps < 1:
@@ -1770,12 +1273,7 @@ class DeviceBackend:
             prep = self._prepared.get(graph)
             if prep is not None and prep.key != prep_key:
                 prep = None
-        if (
-            self.pre_analysis and not compiled
-            and not (prep is not None and prep.gate_passed)
-        ):
-            # the compiled path gates inside CompiledSchedule.build with
-            # the lowered program attached (COL00x joins the checks).
+        if self.pre_analysis and not (prep is not None and prep.gate_passed):
             # ``pre_report``: a fresh ``analyze()`` report for this exact
             # schedule skips the duplicate base passes (signature-checked)
             from ..analysis import pre_execution_gate
@@ -1823,56 +1321,30 @@ class DeviceBackend:
         ev_exec = None
         if tracer is not None:
             ev_exec = tracer.begin(
-                "execute", cat=CAT_CALL, policy=schedule.policy,
-                segments=segments, reps=reps,
+                "execute", cat=CAT_CALL, policy=schedule.policy, reps=reps,
             )
-        # one linearization for the stream plan, the segment build, and
-        # every rep: dispatch_order is a pure function of (graph,
-        # schedule) and costs ~ms on 500-task DAGs
-        order_once: List[str] = []
-        if not compiled:
-            with clock.phase("order_s", "dispatch_order", CAT_SCHEDULE) as a:
-                order_once = (
-                    prep.order if prep is not None
-                    else self.dispatch_order(graph, schedule)
-                )
-                a["tasks"] = len(order_once)
-        segments_pre = None
+        # one linearization for the stream plan and every rep:
+        # dispatch_order is a pure function of (graph, schedule) and
+        # costs ~ms on 500-task DAGs
+        with clock.phase("order_s", "dispatch_order", CAT_SCHEDULE) as a:
+            order_once = (
+                prep.order if prep is not None
+                else self.dispatch_order(graph, schedule)
+            )
+            a["tasks"] = len(order_once)
         if stream_params:
             placed, bytes_per_node = {}, {d.node_id: 0 for d in self.cluster}
             # per-node dispatch plan for the streamer's prefetch + Belady
             # eviction: the schedule fixes each node's task order, so the
-            # streamer knows exactly which params are needed next.  Under
-            # segment fusion the streaming unit is the SEGMENT (one
-            # batched load per fused program, next segment prefetched
-            # while the current one runs)
-            if segments:
-                with clock.phase("plan_s", "segment_build", CAT_PLAN) as a:
-                    segments_pre = self.build_segments(
-                        graph, schedule, order_once,
-                        max_union_gb=self._stream_segment_caps(),
-                        # size by the ACTUAL host arrays: declared/default
-                        # sizes can under-count and defeat the budget split
-                        param_gb={
-                            g: _array_bytes(params[g]) / (1024**3)
-                            for g in graph.unique_params()
-                        },
-                    )
-                    a["segments"] = len(segments_pre)
-                stream_plan = self.segment_stream_plan(graph, segments_pre)
-            else:
-                stream_plan = {}
-                for tid in order_once:
-                    node = schedule.placement.get(tid)
-                    if node is None:
-                        continue
-                    stream_plan.setdefault(node, []).append(
-                        (tid, tuple(g for _, g in graph[tid].param_items()))
-                    )
-        elif compiled:
-            # the compiled path loads params as sharded slabs inside
-            # CompiledSchedule.build — per-global placement never happens
-            placed, bytes_per_node = {}, {}
+            # streamer knows exactly which params are needed next
+            stream_plan = {}
+            for tid in order_once:
+                node = schedule.placement.get(tid)
+                if node is None:
+                    continue
+                stream_plan.setdefault(node, []).append(
+                    (tid, tuple(g for _, g in graph[tid].param_items()))
+                )
         else:
             with clock.phase("place_s", "place_params", CAT_STAGE) as a:
                 if prep_key is None:
@@ -1900,36 +1372,13 @@ class DeviceBackend:
                     bytes_per_node = dict(prep.bytes_per_node)
                     a["put"] = names_put
                 a["bytes"] = sum(bytes_per_node.values())
-        if segments and segments_pre is None:
-            # plain segmented runs were rebuilding segments inside every
-            # timed rep (the same host-work-in-makespan bias the order
-            # hoist removes); the length-match guard in _run_segmented
-            # still handles drop-filter divergence
-            with clock.phase("plan_s", "segment_build", CAT_PLAN) as a:
-                segments_pre = self.build_segments(
-                    graph, schedule, order_once
-                )
-                a["segments"] = len(segments_pre)
 
         # planned fast path: precompute the immutable dispatch plan at
         # warmup time (resolved executables, prebuilt param bindings,
         # slot-indexed staging, donation patterns) so the timed loop does
         # no per-task bookkeeping at all
         plan = None
-        prog = None
-        if compiled:
-            from .compiled_schedule import CompiledSchedule
-
-            with clock.phase("plan_s", "program_build", CAT_PLAN) as a:
-                prog = CompiledSchedule.build(
-                    self, graph, schedule, params, graph_input,
-                    donate=donate, pre_analysis=self.pre_analysis,
-                    pre_report=pre_report,
-                )
-                a["phases"] = len(prog.ir.phases)
-                a["exchanges"] = prog.ir.n_exchanges
-            bytes_per_node = prog.param_bytes_per_node
-        elif planned:
+        if planned:
             from .dispatch_plan import DispatchPlan
 
             with clock.phase("plan_s", "plan_build", CAT_PLAN) as a:
@@ -1959,18 +1408,7 @@ class DeviceBackend:
             # artifacts, not steady-state behavior); one host span
             # covers the whole compile window
             with clock.phase("warmup_s", "warmup", CAT_PLAN) as a:
-                if prog is not None:
-                    # first run traces + XLA-compiles the whole-program
-                    # executable; same donation-warning note as the plan path
-                    t0 = time.perf_counter()
-                    with warnings.catch_warnings():
-                        warnings.filterwarnings(
-                            "ignore",
-                            message="Some donated buffers were not usable",
-                        )
-                        prog.run(graph_input, fence=True)
-                    compile_s = time.perf_counter() - t0
-                elif plan is not None:
+                if plan is not None:
                     # one full planned execution: jits every resolved
                     # executable (donating variants and coalesced groups
                     # included) and fills the static transfer-byte table.
@@ -1990,7 +1428,7 @@ class DeviceBackend:
                     # up, and the timed run's streamer starts cold (capacity
                     # misses are the thing being measured)
                     compile_s = self.warmup(
-                        graph, schedule, placed, graph_input, segments=segments,
+                        graph, schedule, placed, graph_input,
                         ext_outputs=ext_outputs,
                         streamer=(
                             self._ParamStreamer(
@@ -1999,8 +1437,6 @@ class DeviceBackend:
                             )
                             if stream_params else None
                         ),
-                        rebatch=rebatch,
-                        segments_pre=segments_pre,
                     )
                 a["compile_s"] = compile_s
 
@@ -2026,30 +1462,12 @@ class DeviceBackend:
         for r in range(reps):
             fence = r == reps - 1  # intermediate reps queue without fencing
             t_ph = time.perf_counter() if tracer is not None else 0.0
-            if prog is not None:
-                (
-                    output, timings, tedges, tbytes, n_fences, n_disp,
-                    touts, phases,
-                ) = prog.run(
-                    graph_input, fence=fence, tracer=tracer, metrics=mreg,
-                    mem=memprof,
-                )
-            elif plan is not None:
+            if plan is not None:
                 (
                     output, timings, tedges, tbytes, n_fences, n_disp,
                     touts, phases,
                 ) = plan.run(
                     graph_input, ext_outputs, fence=fence,
-                    tracer=tracer, metrics=mreg, mem=memprof,
-                )
-            elif segments:
-                (
-                    output, timings, tedges, tbytes, n_fences, n_disp,
-                    touts, phases,
-                ) = self._run_segmented(
-                    graph, schedule, placed, graph_input, ext_outputs,
-                    fence=fence, rebatch=rebatch, streamer=streamer,
-                    segments_pre=segments_pre, order=order_once,
                     tracer=tracer, metrics=mreg, mem=memprof,
                 )
             else:
@@ -2140,7 +1558,6 @@ class DeviceBackend:
                 dispatch_overhead_s=dispatch_overhead_s,
                 dispatch_phases=dispatch_phases,
                 planned=plan is not None,
-                compiled=prog is not None,
                 task_outputs=touts if keep_outputs else {},
                 streamed=streamer is not None,
                 param_loads=streamer.loads if streamer else 0,

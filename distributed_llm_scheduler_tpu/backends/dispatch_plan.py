@@ -59,7 +59,7 @@ all fixed before the first launch.  This module moves it to plan time:
 Fail-and-continue is preserved statically: tasks with failed (unplaced or
 transitively skipped) upstreams are dropped at plan build, mirroring the
 legacy loop's per-task check.  The end-of-run fence reads each device's
-last planned output, exactly like the legacy paths.
+last planned output, exactly like the legacy loop.
 """
 
 from __future__ import annotations
@@ -345,36 +345,6 @@ def _sds(x: Any):
     if not (hasattr(x, "shape") and hasattr(x, "dtype")):
         x = np.asarray(x)
     return jax.ShapeDtypeStruct(tuple(x.shape), x.dtype)
-
-
-def propagate_avals(
-    graph, order: Sequence[str], params: Dict[str, Any], graph_input: Any
-) -> Dict[str, Any]:
-    """Abstract output (pytree of ``ShapeDtypeStruct``) per task, by
-    ``jax.eval_shape`` propagation along a topological order.
-
-    The whole-program lowering (:mod:`.compiled_schedule`) needs every
-    task's output aval *before* tracing: non-owner ``switch`` branches
-    return ``zeros_like`` placeholders, and cross-device exchanges size
-    their transfers statically.  Shared here (next to the plan's static
-    transfer table) so plan-time and compile-time shape reasoning can't
-    diverge.  ``order`` must be dependency-closed: every ``arg_tasks``
-    reference resolves to an earlier entry or to the graph input.
-    """
-    param_avals = {
-        g: jax.tree_util.tree_map(_sds, params[g])
-        for g in graph.unique_params()
-        if g in params
-    }
-    in_aval = jax.tree_util.tree_map(_sds, graph_input)
-    avals: Dict[str, Any] = {}
-    for tid in order:
-        task = graph[tid]
-        pd = {loc: param_avals[g] for loc, g in task.param_items()}
-        aids = task.arg_tasks or task.dependencies
-        args = [avals[d] for d in aids] if aids else [in_aval]
-        avals[tid] = jax.eval_shape(task.fn, pd, *args)
-    return avals
 
 
 def _relinearize(graph, schedule, alive: List[str], done: set) -> List[str]:

@@ -61,12 +61,6 @@ def _make_fused_fn(member_fns: List[Callable[..., Any]],
             x = member_fns[i](sub, x)
         return x
 
-    # a chain of batch-axis-0-polymorphic ops is itself batch-axis-0
-    # polymorphic: the composite inherits rebatch eligibility
-    from .graph import is_batch0, mark_batch0
-
-    if all(is_batch0(f) for f in member_fns):
-        mark_batch0(fused)
     cache[key] = fused
     return fused
 

@@ -35,62 +35,6 @@ DEFAULT_PARAM_GB: float = 0.5
 GB: int = 1024**3
 
 
-def mark_batch0(fn):
-    """Declare ``fn`` batch-axis-0 polymorphic: for any split of its array
-    arguments along axis 0, ``fn(p, concat(xs, 0), ...) ==
-    concat([fn(p, x, ...) for x], 0)``.  True of per-token/per-row ops
-    (layer norms, matmuls on trailing dims, attention over independent
-    batch entries, residual adds) and false of axis-0 reductions or
-    axis-0 concats.  The segment re-batching pass
-    (:mod:`..backends.rebatch`) only folds sibling tasks whose fns carry
-    this marker — an unmarked fn is never batched, so correctness is
-    opt-in per op, not guessed."""
-    fn._dls_batch0 = True
-    return fn
-
-
-def is_batch0(fn) -> bool:
-    return bool(getattr(fn, "_dls_batch0", False))
-
-
-def mark_concat0(fn):
-    """Declare ``fn(p, x1, ..., xn) == concatenate(xs, axis=0)`` (ignoring
-    params).  The re-batching pass uses this to skip materializing a
-    concat whose inputs are exactly a batched class's members in order —
-    the batched value IS the concat, so the op becomes identity instead
-    of a slice-and-recopy round-trip of the full output."""
-    fn._dls_concat0 = True
-    return fn
-
-
-def is_concat0(fn) -> bool:
-    return bool(getattr(fn, "_dls_concat0", False))
-
-
-def mark_rootslice(fn, family, lo: int, hi: int, make):
-    """Declare a ROOT task fn (consumes the shared graph input, no task
-    args) to be the static batch-slice ``[lo, hi)`` instance of a slice
-    family: ``make(a, b)`` builds the family's fn for any range, and for
-    any split point ``a <= b <= c``::
-
-        make(a, c)(p, x) == concat([make(a, b)(p, x), make(b, c)(p, x)], 0)
-
-    True of per-row input transforms (embedding gathers over a batch
-    slice); the segment re-batching pass merges sibling roots whose
-    slices tile one contiguous range into a single ``make(lo0, hiN)``
-    call — the fused forward's full-batch gather, recovered whenever
-    placement co-locates the roots.  ``family`` must pin every closure
-    variable other than the slice (e.g. the vocab-shard bounds) so only
-    true siblings compare equal."""
-    fn._dls_rootslice = (family, int(lo), int(hi), make)
-    return fn
-
-
-def rootslice_of(fn):
-    """The ``(family, lo, hi, make)`` marker, or None."""
-    return getattr(fn, "_dls_rootslice", None)
-
-
 class TaskStatus(enum.Enum):
     PENDING = "pending"
     ASSIGNED = "assigned"

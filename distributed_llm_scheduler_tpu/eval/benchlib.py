@@ -287,17 +287,6 @@ class BenchResult:
     mfu_single_chip: Optional[float] = None
     dispatch_overhead: Optional[float] = None
     link_provenance: Optional[str] = None
-    # segment-fused single-chip execution (the production dispatch mode):
-    # measured makespan and its MFU
-    segmented_makespan_s: Optional[float] = None
-    mfu_segmented: Optional[float] = None
-    # whole-program compiled execution (backends/compiled_schedule.py):
-    # the entire run as ONE launch; its makespan, MFU, and per-rep host
-    # dispatch wall (the number the >=5x reduction gate compares against
-    # the planned path's dispatch_overhead_ms)
-    compiled_makespan_s: Optional[float] = None
-    mfu_compiled: Optional[float] = None
-    compiled_dispatch_overhead_ms: Optional[float] = None
     # the headline number is a cost-model REPLAY of the winning placement
     # (modeled=True, always — one chip cannot execute an 8-core
     # placement); fused_forward_s and the fence RTT ground the
@@ -376,22 +365,6 @@ class BenchResult:
             out["dispatch_overhead"] = round(self.dispatch_overhead, 4)
         if self.dispatch_overhead_ms is not None:
             out["dispatch_overhead_ms"] = round(self.dispatch_overhead_ms, 4)
-        if self.segmented_makespan_s is not None:
-            out["segmented_makespan_ms"] = round(
-                self.segmented_makespan_s * 1e3, 4
-            )
-        if self.mfu_segmented is not None:
-            out["mfu_segmented"] = round(self.mfu_segmented, 4)
-        if self.compiled_makespan_s is not None:
-            out["compiled_makespan_ms"] = round(
-                self.compiled_makespan_s * 1e3, 4
-            )
-        if self.mfu_compiled is not None:
-            out["mfu_compiled"] = round(self.mfu_compiled, 4)
-        if self.compiled_dispatch_overhead_ms is not None:
-            out["compiled_dispatch_overhead_ms"] = round(
-                self.compiled_dispatch_overhead_ms, 4
-            )
         out["modeled"] = self.modeled
         if self.fused_forward_s is not None:
             out["fused_forward_ms"] = round(self.fused_forward_s * 1e3, 4)

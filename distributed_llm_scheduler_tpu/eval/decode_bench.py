@@ -378,13 +378,11 @@ def measure_decode_dag(
     device — the task-graph inference path's perf number, next to the
     whole-program loop's.
 
-    Reports three numbers, honest about what each includes:
+    Reports two numbers, honest about what each includes:
 
     * ``step_ms_per_task`` — fence-amortized time of ONE decode-step DAG
-      under per-task dispatch (the placement-faithful mode; comparable to
+      through ``execute``'s default path (comparable to
       ``measure_decode``'s ``ms_per_token_step``);
-    * ``step_ms_segmented`` — same step with segment fusion (the
-      production single-node dispatch mode: one XLA launch per step);
     * ``tok_s_end_to_end`` — wall tok/s of a host-driven generation: the
       argmax runs on device and the host reads the batch token ids back
       (not the full logits) before it can fold the cache updates and
@@ -528,20 +526,6 @@ def measure_decode_dag(
     step_pt = best_of(2, lambda: backend.execute(
         ddag.graph, sched, params_c, step_in, warmup=False, reps=reps
     ).makespan_s)
-    try:
-        backend.execute(  # compile the segmented class once
-            ddag.graph, sched, params_c, step_in, segments=True
-        )
-        step_seg = best_of(2, lambda: backend.execute(
-            ddag.graph, sched, params_c, step_in, segments=True,
-            warmup=False, reps=reps,
-        ).makespan_s)
-    except Exception:
-        import traceback
-
-        print("decode_dag: WARNING segmented step failed:\n"
-              + traceback.format_exc(), file=sys.stderr)
-        step_seg = None
 
     # on-device K-step loop (backends/decode_loop.py): the scheduled step
     # DAG composed into one program, lax.scan over K tokens with donated
@@ -695,13 +679,6 @@ def measure_decode_dag(
         "token_agreement": round(token_agreement, 4),
         "step_ms_per_task": round(step_pt * 1e3, 4),
         "tok_s_per_task": round(batch / max(step_pt, 1e-12), 2),
-        "step_ms_segmented": (
-            round(step_seg * 1e3, 4) if step_seg is not None else None
-        ),
-        "tok_s_segmented": (
-            round(batch / max(step_seg, 1e-12), 2)
-            if step_seg is not None else None
-        ),
         "tok_s_end_to_end": (
             round(batch * n_timed / t_loop, 2) if t_loop > 0 else None
         ),
@@ -710,10 +687,10 @@ def measure_decode_dag(
         "looped": looped,
     }
     roof = decode_roofline(config, batch, max_len, dev)
-    if roof is not None and step_seg is not None:
+    if roof is not None:
         out["bound_tok_s"] = round(roof["bound_tok_s"], 2)
-        out["segmented_bound_utilization"] = round(
-            (batch / step_seg) / roof["bound_tok_s"], 4
+        out["step_bound_utilization"] = round(
+            (batch / step_pt) / roof["bound_tok_s"], 4
         )
     return out
 

@@ -29,9 +29,6 @@ from typing import Any, Dict, List, Optional, Sequence
 # appear in one of the maps)
 LOWER_BETTER = (
     "value",                  # headline makespan (ms)
-    "segmented_makespan_ms",
-    "compiled_makespan_ms",
-    "compiled_dispatch_overhead_ms",
     "fused_forward_ms",
     "fused_scalar_ms",
     "dispatch_overhead",
@@ -137,8 +134,6 @@ METRIC_DEFAULT_TOLERANCES = {
 HIGHER_BETTER = (
     "vs_baseline",
     "mfu_single_chip",
-    "mfu_segmented",
-    "mfu_compiled",
     "serve.goodput_tok_s",
     "serve.prefix.goodput_tok_s",
     "serve.prefix.goodput_gain",
@@ -172,14 +167,10 @@ BOOL_METRICS = (
 DEFAULT_METRICS = (
     "value",
     "vs_baseline",
-    "segmented_makespan_ms",
-    "compiled_makespan_ms",
     "dispatch_overhead",
     "peak_hbm_gb_modeled",
     "kv_pages_peak",
     "mfu_single_chip",
-    "mfu_segmented",
-    "mfu_compiled",
     "oracle_ok",
     "serve.goodput_tok_s",
     "serve.ttft_p99_ms",

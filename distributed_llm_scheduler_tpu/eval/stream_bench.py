@@ -8,8 +8,8 @@ node's parameter budget at a fraction of the model's
 total param bytes and execute with ``stream_params=True`` — prefetched
 batched loads with Belady (farthest-next-use) eviction keep residency
 under budget, so the model runs correctly even though its weights never
-co-reside.  Sibling legs measure the same budget with segment-fused
-dispatch and with int8 weights (half the streamed bytes).
+co-reside.  A sibling leg measures the same budget with int8 weights
+(half the streamed bytes).
 
 Run directly (on the TPU, or the CPU mesh for a functional check)::
 
@@ -176,28 +176,6 @@ def measure_streaming(
         + f", compute {rep_full.makespan_s*1e3:.1f} ms; "
         f"bound utilization {bound_utilization:.1%}")
 
-    # segment-granular streaming (r4): same budget, fused dispatch — the
-    # production answer when the model oversubscribes ONE device is a
-    # single fused program whose union streams as one batched load; with
-    # multi-segment placements the unit is the segment.  Reported
-    # alongside so the per-task and fused streaming modes stay comparable.
-    try:
-        rep_seg = backend.execute(
-            graph, sched, params, ids, stream_params=True, segments=True
-        )
-        seg_ok = oracle_close(fused, rep_seg.output, dtype_name)
-        seg_ms = rep_seg.makespan_s * 1e3
-        seg_peak_gb = max(rep_seg.peak_param_bytes.values()) / 1024**3
-        log(f"stream_bench: segmented capped makespan {seg_ms:.1f} ms "
-            f"({rep_seg.n_dispatches} launches, {rep_seg.param_load_calls} "
-            f"batched loads, peak {seg_peak_gb:.3f} GB); oracle: {seg_ok}")
-    except Exception:
-        import traceback
-
-        log("stream_bench: WARNING segmented streaming failed:\n"
-            + traceback.format_exc())
-        rep_seg, seg_ok, seg_ms, seg_peak_gb = None, None, None, None
-
     # int8-quantized streaming: same device budget, half the streamed
     # bytes — in the transfer-bound regime streaming lives in, cutting
     # bytes IS the optimization (the reference's founding constraint
@@ -274,20 +252,6 @@ def measure_streaming(
         "peak_resident_param_gb": round(peak_gb, 4),
         "budget_respected": bool(peak_gb <= budget_gb * 1.02 + 1e-6),
         "oracle_ok": bool(full_ok and cap_ok),
-        # segment-granular streaming leg (None when it failed)
-        "segmented_capped_makespan_ms": (
-            round(seg_ms, 3) if seg_ms is not None else None
-        ),
-        "segmented_oracle_ok": seg_ok,
-        "segmented_peak_resident_gb": (
-            round(seg_peak_gb, 4) if seg_peak_gb is not None else None
-        ),
-        "segmented_n_dispatches": (
-            rep_seg.n_dispatches if rep_seg is not None else None
-        ),
-        "segmented_load_calls": (
-            rep_seg.param_load_calls if rep_seg is not None else None
-        ),
         # int8 leg (None when it failed): same budget, ~half the bytes
         "quantized_capped_makespan_ms": (
             round(q_ms, 3) if q_ms is not None else None

@@ -21,13 +21,7 @@ from typing import Any, Callable, Dict, List
 import jax
 import jax.numpy as jnp
 
-from ..core.graph import (
-    Task,
-    TaskGraph,
-    mark_batch0,
-    mark_concat0,
-    mark_rootslice,
-)
+from ..core.graph import Task, TaskGraph
 from .gpt2_dag import ModelDAG, make_task_adder
 from .vocab_sharding import logit_concat_fn, make_embed_partial_fn, shard_bounds
 
@@ -99,41 +93,32 @@ def build_decoder_dag(
         def f_embedding(p, input_ids):
             return module.embedding(input_ids[lo:hi], p["tok_emb"])
 
-        return mark_rootslice(
-            f_embedding, "backbone_embedding", lo, hi, make_f_embedding
-        )
+        return f_embedding
 
-    @mark_concat0
     def f_concat(p, *chunks):
         return jnp.concatenate(chunks, axis=0)
 
-    @mark_batch0
     def f_norm(p, x):
         return module.rms_norm(x, p["g"], eps)
 
-    @mark_batch0
     def f_attn(p, x):
         return module.gqa_attention(
             x, p["wq"], p["wk"], p["wv"], p["wo"],
             config.n_heads, config.n_kv_heads, config.rope_theta,
         )
 
-    @mark_batch0
     def f_residual(p, a, b):
         return module.residual_add(a, b)
 
-    @mark_batch0
     def f_lm_head(p, x):
         return module.lm_head(x, p["w"])
 
-    @mark_batch0
     def f_embed_combine(p, *partials):
         out = partials[0]
         for part in partials[1:]:
             out = out + part
         return out
 
-    @mark_batch0
     def f_logit_shard(p, x):
         # lm_head is (D, V): column shards, unlike gpt2's tied row shards
         return x @ p["shard"]

@@ -28,13 +28,7 @@ from typing import Any, Callable, Dict, List, Optional
 import jax
 import jax.numpy as jnp
 
-from ..core.graph import (
-    Task,
-    TaskGraph,
-    mark_batch0,
-    mark_concat0,
-    mark_rootslice,
-)
+from ..core.graph import Task, TaskGraph
 from ..models import gpt2
 from ..models.gpt2 import GPT2Config
 from .vocab_sharding import logit_concat_fn, make_embed_partial_fn, shard_bounds
@@ -231,17 +225,8 @@ def build_gpt2_dag(
         def f_embedding(p, input_ids):
             return gpt2.embedding(input_ids[lo:hi], p["wte"], p["wpe"])
 
-        return mark_rootslice(
-            f_embedding, "gpt2_embedding", lo, hi, make_f_embedding
-        )
+        return f_embedding
 
-    # batch-axis-0-polymorphic ops are marked for the segment re-batching
-    # pass (backends/rebatch.py): per-token math, safe to run on sibling
-    # microbatches' concatenated inputs.  f_concat (axis-0 concat) is NOT
-    # batch0; the embedding roots carry slice-family markers
-    # (mark_rootslice) so co-located siblings merge into full-batch
-    # gathers instead.
-    @mark_batch0
     def f_embed_combine(p, *partials):
         T_ = partials[0].shape[-2]
         out = partials[0]
@@ -249,41 +234,32 @@ def build_gpt2_dag(
             out = out + part
         return out + p["wpe"][:T_]
 
-    @mark_concat0
     def f_concat(p, *chunks):
         return jnp.concatenate(chunks, axis=0)
 
-    @mark_batch0
     def f_ln(p, x):
         return gpt2.layer_norm(x, p["g"], p["b"], eps)
 
-    @mark_batch0
     def f_attn(p, x):
         return gpt2.causal_attention(
             x, p["qkv_w"], p["qkv_b"], p["proj_w"], p["proj_b"], config.n_head
         )
 
-    @mark_batch0
     def f_residual(p, a, b):
         return gpt2.residual_add(a, b)
 
-    @mark_batch0
     def f_ffn_expand(p, x):
         return gpt2.ffn_expand(x, p["fc_w"], p["fc_b"])
 
-    @mark_batch0
     def f_ffn_act(p, x):
         return gpt2.ffn_activation(x)
 
-    @mark_batch0
     def f_ffn_contract(p, x):
         return gpt2.ffn_contract(x, p["proj_w"], p["proj_b"])
 
-    @mark_batch0
     def f_output_projection(p, x):
         return gpt2.output_projection(x, p["wte"])
 
-    @mark_batch0
     def f_logit_shard(p, x):
         """Logit slice via the tied table's row shard: x @ shard.T — runs
         wherever the embedding parked that shard, so the tied table is

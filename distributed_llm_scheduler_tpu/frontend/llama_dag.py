@@ -24,7 +24,6 @@ from typing import Optional
 from ..models import llama
 from ..models.llama import LlamaConfig
 from .backbone import build_decoder_dag
-from ..core.graph import mark_batch0
 from .gpt2_dag import DEFAULT_EFFECTIVE_FLOPS, ModelDAG, graph_name_tags
 
 
@@ -44,19 +43,15 @@ def build_llama_dag(
     Bm = batch // microbatches
     T = seq_len
 
-    @mark_batch0
     def f_gate(p, x):
         return llama.ffn_gate(x, p["w"])
 
-    @mark_batch0
     def f_up(p, x):
         return llama.ffn_up(x, p["w"])
 
-    @mark_batch0
     def f_glu(p, g, u):
         return llama.ffn_glu(g, u)
 
-    @mark_batch0
     def f_down(p, x):
         return llama.ffn_down(x, p["w"])
 

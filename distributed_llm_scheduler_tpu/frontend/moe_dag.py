@@ -27,9 +27,7 @@ Two dispatch modes:
   runs SwiGLU on that, and the combine gathers outputs back by the
   metadata.  Measured calibration then times the top_k/E-scaled compute
   the FLOPs field claims — the disclosed E/k inflation is gone exactly
-  where expert placement matters.  Routed task fns are NOT batch-axis-0
-  polymorphic (capacity positions are global per microbatch), so they
-  are never re-batched across microbatch siblings.
+  where expert placement matters.
 """
 
 from __future__ import annotations
@@ -42,7 +40,6 @@ import jax.numpy as jnp
 from ..models import mixtral
 from ..models.mixtral import MixtralConfig
 from .backbone import build_decoder_dag
-from ..core.graph import mark_batch0
 from .gpt2_dag import DEFAULT_EFFECTIVE_FLOPS, ModelDAG, graph_name_tags
 
 
@@ -66,15 +63,12 @@ def build_moe_dag(
     Bm = batch // microbatches
     T = seq_len
 
-    @mark_batch0
     def f_router(p, x):
         return mixtral.router_weights(x, p["w"], config.top_k)
 
-    @mark_batch0
     def f_expert(p, x):
         return mixtral.expert_ffn(x, p["w_gate"], p["w_up"], p["w_down"])
 
-    @mark_batch0
     def f_combine(p, weights, *outs):
         return mixtral.moe_combine(weights, *outs)
 

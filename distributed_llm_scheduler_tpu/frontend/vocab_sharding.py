@@ -15,8 +15,6 @@ from typing import Callable, List
 
 import jax.numpy as jnp
 
-from ..core.graph import mark_batch0, mark_rootslice
-
 
 def shard_bounds(vocab_size: int, shards: int, align: int = 128) -> List[int]:
     """Near-balanced split boundaries: ``shards + 1`` cumulative offsets,
@@ -65,15 +63,9 @@ def make_embed_partial_fn(
         emb = p["shard"][jnp.clip(local, 0, rows - 1)]
         return emb * mask[..., None].astype(emb.dtype)
 
-    # slice family per vocab shard: sibling microbatch roots co-located in
-    # one segment merge into a single full-batch gather (rebatch pass)
-    return mark_rootslice(
-        f_embed_partial, ("embed_partial", lo_v, rows), lo_b, hi_b,
-        lambda a, b: make_embed_partial_fn(a, b, lo_v, rows),
-    )
+    return f_embed_partial
 
 
-@mark_batch0  # last-axis concat: batch-axis-0 polymorphic
 def logit_concat_fn(p, *slices):
     """Concatenate per-shard logit slices along the vocab axis."""
     return jnp.concatenate(slices, axis=-1)

@@ -1,7 +1,7 @@
 """Observability: span tracing, metrics, and Perfetto export.
 
-The cross-cutting layer ISSUE 4 adds over the three performance-critical
-subsystems (planned dispatch, segment fusion, paged decode):
+The cross-cutting layer ISSUE 4 adds over the two performance-critical
+subsystems (planned dispatch with its fused launches, paged decode):
 
 * :mod:`.trace` — structured span tracer (nested spans, categories,
   injectable clock);

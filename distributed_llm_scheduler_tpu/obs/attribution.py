@@ -53,15 +53,6 @@ _EPS = 1e-9
 # host-measured launch windows; decode-engine spans are excluded)
 _DEVICE_CATS = ("task", "launch")
 
-# the compiled execution path fuses each device's whole run into one
-# program: its device rows carry a single cat="program" span each, with
-# no per-task boundaries.  When a trace has NO per-task/launch device
-# spans, attribution degrades to PROGRAM-level granularity over those
-# spans — compute/dispatch/idle still tile the makespan exactly (the
-# cursor invariant is cat-agnostic) instead of returning an empty
-# critical path.
-_PROGRAM_CAT = "program"
-
 
 @dataclass
 class PathStep:
@@ -336,7 +327,6 @@ def attribute_run(
     span extent is used.
     """
     dev_spans: List[Dict[str, Any]] = []
-    program_spans: List[Dict[str, Any]] = []
     host_spans: List[Dict[str, Any]] = []
     flows: List[Dict[str, Any]] = []
     execute: Optional[Dict[str, Any]] = None
@@ -350,12 +340,8 @@ def attribute_run(
                     execute = ev  # events append at end(): last wins
             elif ev["cat"] in _DEVICE_CATS:
                 dev_spans.append(ev)
-            elif ev["cat"] == _PROGRAM_CAT:
-                program_spans.append(ev)
         elif ev["type"] == "flow":
             flows.append(ev)
-    if not dev_spans:
-        dev_spans = program_spans  # compiled run: program-level fallback
     if window is None and execute is not None:
         window = (execute["t0"], execute["t1"])
     return _attribute(
@@ -385,7 +371,6 @@ def attribute_trace(
         if ev.get("ph") == "M" and ev.get("name") == "thread_name":
             track_of[ev.get("tid")] = ev.get("args", {}).get("name", "")
     dev_spans: List[Dict[str, Any]] = []
-    program_spans: List[Dict[str, Any]] = []
     host_spans: List[Dict[str, Any]] = []
     starts: Dict[Any, Dict[str, Any]] = {}
     ends: Dict[Any, Dict[str, Any]] = {}
@@ -407,14 +392,10 @@ def attribute_trace(
                     execute = span
             elif span["cat"] in _DEVICE_CATS:
                 dev_spans.append(span)
-            elif span["cat"] == _PROGRAM_CAT:
-                program_spans.append(span)
         elif ph == "s":
             starts[ev.get("id")] = ev
         elif ph == "f":
             ends[ev.get("id")] = ev
-    if not dev_spans:
-        dev_spans = program_spans  # compiled run: program-level fallback
     flows: List[Dict[str, Any]] = []
     for fid, s in starts.items():
         e = ends.get(fid)

@@ -3,9 +3,8 @@
 PRs 4-5 built the measured *time* domain (span tracer, critical-path
 attribution, cost-model drift).  This module is the symmetric *memory*
 domain: a :class:`MemoryProfiler` receives allocation/free events from
-the instrumented backends — param staging and slab construction
-(``backends/device._array_bytes`` / ``compiled_schedule._leaf_bytes``
-sizes), task-output births, donation-driven frees (the same lifetimes
+the instrumented backends — param staging
+(``backends/device._array_bytes`` sizes), task-output births, donation-driven frees (the same lifetimes
 ``DispatchPlan.donation_table`` documents), cross-device transfer
 copies, and KV page-pool occupancy (``backends/decode_loop``) — and
 maintains one byte-exact timeline per device.

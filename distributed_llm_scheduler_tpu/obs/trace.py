@@ -63,7 +63,7 @@ CAT_CALL = "call"           # enclosing spans: ``execute`` and ``rep<r>``
 CAT_SCHEDULE = "schedule"   # dispatch-order linearization
 CAT_PLAN = "plan"           # plan build + warmup compilation
 CAT_STAGE = "stage"         # param placement + transfer staging
-CAT_LAUNCH = "launch"       # executable calls (tasks, groups, segments)
+CAT_LAUNCH = "launch"       # executable calls (tasks, fused groups)
 CAT_COLLECT = "collect"     # end-of-run fence + readbacks
 CAT_TASK = "task"           # per-task device spans (profile timings)
 CAT_TRANSFER = "transfer"   # cross-device flow edges

@@ -354,59 +354,19 @@ def quantize_dag(
     # keep sharing one jitted callable after the transform
     wrapped: Dict[Any, Callable[..., Any]] = {}
 
-    def dequant_wrap(fn, local_dtypes):
-        """The bare dequantizing shim around ``fn`` (no markers, no
-        memoization) — also the body the rootslice constructor uses for
-        merged-root calls, which are fresh per plan and must not grow the
-        ``wrapped`` cache with never-hit entries."""
-
-        def w(pd, *args, _fn=fn, _dt=dict(local_dtypes)):
-            deq = {
-                loc: dequantize(v, _dt.get(loc, jnp.float32))
-                for loc, v in pd.items()
-            }
-            return _fn(deq, *args)
-
-        return w
-
     def wrap(fn, local_dtypes):
         dt = tuple(sorted(local_dtypes.items()))
         key = (fn, dt)
         w = wrapped.get(key)
         if w is None:
-            w = dequant_wrap(fn, local_dtypes)
 
-            # dequant is per-param (broadcast under batching), so the
-            # wrapper preserves batch-axis-0 polymorphism / concat
-            # semantics — without this, quantized graphs lose segment
-            # re-batching (markers live on the fn object)
-            from ..core.graph import (
-                is_batch0,
-                is_concat0,
-                mark_batch0,
-                mark_concat0,
-                mark_rootslice,
-                rootslice_of,
-            )
+            def w(pd, *args, _fn=fn, _dt=dict(local_dtypes)):
+                deq = {
+                    loc: dequantize(v, _dt.get(loc, jnp.float32))
+                    for loc, v in pd.items()
+                }
+                return _fn(deq, *args)
 
-            if is_batch0(fn):
-                mark_batch0(w)
-            if is_concat0(fn):
-                mark_concat0(w)
-            rs = rootslice_of(fn)
-            if rs is not None:
-                # slice-family roots keep merging under quantization: the
-                # merged call must dequantize too, so the propagated
-                # family constructor wraps the original family's fn with
-                # the same local dtypes (and the dtypes join the family
-                # key — differently-quantized roots must not merge)
-                fam, lo, hi, make = rs
-                mark_rootslice(
-                    w, ("int8", fam, dt), lo, hi,
-                    lambda a, b, _m=make, _d=dict(local_dtypes): (
-                        dequant_wrap(_m(a, b), _d)
-                    ),
-                )
             wrapped[key] = w
         return w
 

@@ -875,16 +875,11 @@ def test_donation_last_consumer_is_clean():
     assert rep.ok, [d.render() for d in rep.diagnostics]
 
 
-def test_donation_compiled_summary():
-    clean = {
-        "path": "mesh", "param_argnums": (0,),
-        "input_argnums": (1, 2), "donated_argnums": (1, 2),
-    }
-    assert analyze_donation(clean).ok
-    rep = analyze_donation({**clean, "donated_argnums": (0, 1)})
-    assert rep.has("DON002")  # donating the aliased param slab
-    rep = analyze_donation({**clean, "donated_argnums": (1, 5)})
-    assert rep.has("DON003")  # argnum 5 is not a per-run input
+def test_donation_takes_a_plan_or_its_table_and_nothing_else():
+    assert analyze_donation(_table([])).ok
+    for not_a_plan in ({"donated_argnums": (1, 2)}, {}, None, [1]):
+        with pytest.raises(TypeError):
+            analyze_donation(not_a_plan)
 
 
 def test_donation_gate_wiring():
@@ -949,6 +944,40 @@ def test_collective_walk_sees_through_custom_derivatives():
     # malformed perm must not hide behind the custom-derivative call
     rep = check(rotate2)
     assert rep.has("COL004")
+
+
+def test_branch_divergence_col003():
+    """A cond whose branches issue different collective sequences is the
+    SPMD smuggling route for per-device divergence — the jaxpr walk
+    flags it."""
+    import jax
+
+    from distributed_llm_scheduler_tpu.analysis import (
+        analyze_collectives_jaxpr,
+    )
+
+    def good(x):
+        return jax.lax.cond(
+            x.sum() > 0,
+            lambda v: jax.lax.ppermute(v, "dev", [(0, 1)]),
+            lambda v: jax.lax.ppermute(v, "dev", [(0, 1)]),
+            x,
+        )
+
+    def bad(x):
+        return jax.lax.cond(
+            x.sum() > 0,
+            lambda v: jax.lax.ppermute(v, "dev", [(0, 1)]),
+            lambda v: v * 2.0,
+            x,
+        )
+
+    x = np.ones((4,), np.float32)
+    jaxpr_good = jax.make_jaxpr(good, axis_env=[("dev", 2)])(x)
+    jaxpr_bad = jax.make_jaxpr(bad, axis_env=[("dev", 2)])(x)
+    assert analyze_collectives_jaxpr(jaxpr_good).ok
+    rep = analyze_collectives_jaxpr(jaxpr_bad)
+    assert rep.has("COL003")
 
 
 def test_report_dedupe_counts_occurrences():

@@ -271,7 +271,6 @@ def test_measure_decode_dag_bench_leg():
     assert r["token_agreement"] == 1.0
     assert r["graph_classes_compiled"] == 2  # prefill + one decode class
     assert r["step_ms_per_task"] > 0
-    assert r["step_ms_segmented"] is not None and r["step_ms_segmented"] > 0
     assert r["tok_s_end_to_end"] is not None and r["n_timed_steps"] == 2
     # the K-step on-device loop leg: present, f32-exact vs whole-program
     assert r["looped"] is not None

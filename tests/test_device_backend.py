@@ -159,19 +159,19 @@ def test_reps_amortized_makespan(mesh_cluster, tiny_setup):
         )
 
 
-def test_reps_amortized_segmented(mesh_cluster, tiny_setup):
-    """Segment fusion with reps>1: same oracle, same segment count."""
+def test_reps_amortized_fused_launches(mesh_cluster, tiny_setup):
+    """Fused launches with reps>1: same oracle, same launch count."""
     dag, params, ids = tiny_setup
     schedule = get_scheduler("greedy").schedule(dag.graph, mesh_cluster)
     backend = DeviceBackend(mesh_cluster)
-    rep = backend.execute(
-        dag.graph, schedule, params, ids, segments=True, reps=3
-    )
+    once = backend.execute(dag.graph, schedule, params, ids)
+    rep = backend.execute(dag.graph, schedule, params, ids, reps=3)
     fused = dag.reference_forward(params, ids)
     np.testing.assert_allclose(
         np.asarray(fused), np.asarray(rep.output), rtol=2e-5, atol=2e-5
     )
     assert rep.makespan_s > 0
+    assert rep.n_dispatches == once.n_dispatches <= len(dag.graph)
 
 
 def _microbatch_pipeline():

@@ -785,13 +785,15 @@ def test_leaf_phases_tile_the_call_and_land_once_in_the_process_registry(
 
 
 @pytest.mark.parametrize("kw,split", [
-    ({"compiled": True}, True),
-    ({"segments": True}, False),
+    ({"coalesce": False}, True),
+    ({"planned": False}, False),
     ({"profile": True}, False),
-])
+    ({"stream_params": True}, False),
+], ids=lambda v: next(iter(v)) if isinstance(v, dict) else None)
 def test_every_execution_path_tiles_its_call(setup, kw, split):
-    """The paths that do not split their loop report ``loop_s`` as the
-    leaf; every path reports the fence wait and sums to the wall."""
+    """The per-task loop does not split its loop and reports ``loop_s``
+    as the leaf; the plan splits it into staging and launches; every
+    path reports the fence wait and sums to the wall."""
     dag, params, ids, backend, schedule = setup
     rep = backend.execute(dag.graph, schedule, params, ids, **kw)
     leaves = rep.leaf_phases()
@@ -1053,8 +1055,8 @@ def test_the_gate_runs_again_where_its_pass_was_not_its_own(small):
 
 
 @pytest.mark.parametrize("kw", [
-    {"memprof": True}, {"profile": True}, {"segments": True},
-    {"compiled": True}, {"stream_params": True}, {"planned": False},
+    {"memprof": True}, {"profile": True}, {"stream_params": True},
+    {"planned": False},
 ], ids=lambda kw: next(iter(kw)))
 def test_the_paths_that_bypass_the_prepared_call_leave_it_alone(small, kw):
     """``memprof`` (placement is its subject) and the paths that run no
@@ -1139,7 +1141,7 @@ def test_the_fence_round_trip_is_probed_once_a_backend(small, monkeypatch):
     monkeypatch.setattr(costmodel, "_fence_rtt", counted)
     reps = [
         backend.execute(dag.graph, schedule, params, ids, **kw)
-        for kw in ({}, {"warmup": False}, {"segments": True},
+        for kw in ({}, {"warmup": False}, {"planned": False},
                    {"fence_rtt": 0.25})
     ]
     assert len(calls) == 1

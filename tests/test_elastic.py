@@ -93,8 +93,7 @@ def test_no_failure_reschedules_only_incomplete(run_state):
     assert must_run == {t.task_id for t in graph.tasks()} - completed
 
 
-@pytest.mark.parametrize("segments", [False, True])
-def test_device_recovery_end_to_end(segments):
+def test_device_recovery_end_to_end():
     """The headline, via the PUBLIC flow: a first run retains outputs
     (keep_outputs=True), a node dies, reschedule() consumes the report's
     task_outputs, and re-execution with ext_outputs reproduces the fused
@@ -112,7 +111,7 @@ def test_device_recovery_end_to_end(segments):
     cluster = Cluster.from_jax_devices(jax.devices()[:4], hbm_cap_gb=8.0)
     schedule = get_scheduler("pack").schedule(graph, cluster)
     first = DeviceBackend(cluster).execute(
-        graph, schedule, params, ids, segments=segments, keep_outputs=True
+        graph, schedule, params, ids, keep_outputs=True
     )
     assert first.task_outputs  # retention is what makes recovery drivable
     # "mid-run" state: the first half of the assignment order finished
@@ -128,11 +127,10 @@ def test_device_recovery_end_to_end(segments):
     )
     assert not new_s.failed
     # available is exactly what we can feed: completed, on survivors, and
-    # actually retained (segment mode retains exports only)
+    # actually retained
     ext = {tid: first.task_outputs[tid] for tid in available}
     rep = DeviceBackend(survivors).execute(
-        remainder, new_s, params, ids,
-        ext_outputs=ext, segments=segments,
+        remainder, new_s, params, ids, ext_outputs=ext,
     )
     fused = dag.reference_forward(params, ids)
     np.testing.assert_allclose(

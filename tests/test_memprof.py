@@ -246,15 +246,16 @@ def exec_setup():
     return dag, params, ids, cluster, schedule
 
 
-@pytest.mark.parametrize("mode", ["interpreted", "planned", "compiled"])
+@pytest.mark.parametrize("mode", ["per_task_loop", "planned", "per_task_plan"])
 def test_memprof_run_bit_identical(exec_setup, mode):
-    """memprof instrumentation must not perturb results on any of the
-    three execution paths, and must record a verifiable timeline."""
+    """memprof instrumentation must not perturb results on either
+    execution path (nor on the plan's per-task parity reference), and
+    must record a verifiable timeline."""
     dag, params, ids, cluster, schedule = exec_setup
     kw = {
-        "interpreted": {"planned": False},
+        "per_task_loop": {"planned": False},
         "planned": {"planned": True},
-        "compiled": {"compiled": True},
+        "per_task_plan": {"coalesce": False},
     }[mode]
     backend = DeviceBackend(cluster)
     plain = backend.execute(dag.graph, schedule, params, ids, **kw)

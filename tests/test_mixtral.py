@@ -221,11 +221,6 @@ def test_routed_expert_flops_below_dense_inflation(tiny):
     # recorded useful flops; routed computes only the capacity buffer
     dense_true_flops = dense_task.flops * tiny.n_experts / tiny.top_k
     assert routed_task.flops < 0.7 * dense_true_flops
-    # routed fns are not batch0 (capacity is per-microbatch-global)
-    from distributed_llm_scheduler_tpu.core.graph import is_batch0
-
-    assert not is_batch0(routed_task.fn)
-    assert is_batch0(dense_task.fn)
 
 
 def test_routed_dag_microbatched_oracle_with_drops(tiny):

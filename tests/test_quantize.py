@@ -86,8 +86,8 @@ def test_quantized_close_to_full_precision(qsetup):
     assert rel < 0.05, rel
 
 
-def test_quantized_fused_graph_segments():
-    """Quantization composes with chain fusion and segment dispatch."""
+def test_quantized_fused_graph_fused_launches():
+    """Quantization composes with chain fusion and fused launches."""
     dag = build_gpt2_dag(GPT2Config.tiny(), batch=2, seq_len=16,
                          microbatches=2, vocab_shards=2)
     import dataclasses
@@ -97,13 +97,12 @@ def test_quantized_fused_graph_segments():
     params, ids = qdag.init_params(), qdag.make_inputs()
     cluster = Cluster.from_jax_devices(hbm_cap_gb=4.0)
     schedule = get_scheduler("pipeline").schedule(qdag.graph, cluster)
-    rep = DeviceBackend(cluster).execute(
-        qdag.graph, schedule, params, ids, segments=True
-    )
+    rep = DeviceBackend(cluster).execute(qdag.graph, schedule, params, ids)
     fused = qdag.reference_forward(params, ids)
     np.testing.assert_allclose(
         np.asarray(rep.output), np.asarray(fused), rtol=2e-4, atol=2e-4
     )
+    assert rep.planned and rep.n_dispatches < len(qdag.graph)
 
 
 def test_quantized_llama_family():

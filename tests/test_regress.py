@@ -21,14 +21,10 @@ BASE = {
     "metric": "makespan",
     "value": 100.0,
     "vs_baseline": 1.5,
-    "segmented_makespan_ms": 80.0,
-    "compiled_makespan_ms": 75.0,
     "dispatch_overhead": 0.2,
     "peak_hbm_gb_modeled": 4.0,
     "kv_pages_peak": 4,
     "mfu_single_chip": 0.30,
-    "mfu_segmented": 0.25,
-    "mfu_compiled": 0.28,
     "oracle_ok": True,
     "serve.goodput_tok_s": 200.0,
     "serve.ttft_p99_ms": 130.0,
@@ -123,11 +119,11 @@ def test_per_metric_tolerance_overrides_default():
 
 def test_missing_metric_is_a_failure_not_a_pass():
     fresh = dict(BASE)
-    del fresh["segmented_makespan_ms"]
+    del fresh["peak_hbm_gb_modeled"]
     v = compare_artifacts(fresh, BASE)
     assert not v.ok
     (bad,) = v.failures()
-    assert bad.metric == "segmented_makespan_ms" and bad.status == "missing"
+    assert bad.metric == "peak_hbm_gb_modeled" and bad.status == "missing"
     assert bad.fresh is None
     # ... and a None value counts as missing too
     v2 = compare_artifacts(_fresh(dispatch_overhead=None), BASE)
@@ -157,10 +153,10 @@ def test_metrics_narrows_the_comparison():
 
 
 def test_verdict_render_and_json():
-    v = compare_artifacts(_fresh(value=120.0, mfu_segmented=0.5), BASE)
+    v = compare_artifacts(_fresh(value=120.0, mfu_single_chip=0.5), BASE)
     text = v.render()
     assert "regress: FAIL" in text and "[!] value" in text
-    assert "[+] mfu_segmented" in text
+    assert "[+] mfu_single_chip" in text
     blob = json.loads(json.dumps(v.to_json()))
     assert blob["ok"] is False and blob["n_regressed"] == 1
     ok_text = compare_artifacts(BASE, BASE).render()

@@ -84,49 +84,6 @@ def test_streaming_multi_device(setup):
     )
 
 
-def test_segmented_streaming_single_device_exact(setup):
-    """Streaming composes with segment fusion: the oversubscribed
-    single-device run budget-splits into several fused programs, each
-    union loads as one batched call, residency respects the budget, and
-    the output stays exact."""
-    dag, params, ids = setup
-    cluster = _tight_cluster(dag, 1, 0.35)
-    schedule = get_scheduler("mru").schedule(dag.graph, cluster)
-    rep = DeviceBackend(cluster).execute(
-        dag.graph, schedule, params, ids, stream_params=True, segments=True
-    )
-    fused = dag.reference_forward(params, ids)
-    np.testing.assert_allclose(
-        np.asarray(fused), np.asarray(rep.output), rtol=2e-5, atol=2e-5
-    )
-    assert rep.streamed
-    # budget-aware segmentation: several fused programs, far fewer
-    # launches than tasks, one batched load per segment
-    assert 1 < rep.n_dispatches < len(dag.graph)
-    assert rep.param_load_calls <= rep.n_dispatches + 1
-    budget = int(cluster.devices[0].total_memory * 1024**3)
-    assert max(rep.peak_param_bytes.values()) <= budget * 1.02
-
-
-def test_segmented_streaming_multi_device_evicts(setup):
-    """Multi-segment placement under a tight budget: segment-granular
-    loads + evictions keep residency bounded, output exact."""
-    dag, params, ids = setup
-    cluster = _tight_cluster(dag, 4, 0.3)
-    schedule = get_scheduler("mru").schedule(dag.graph, cluster)
-    assert not schedule.failed
-    rep = DeviceBackend(cluster).execute(
-        dag.graph, schedule, params, ids, stream_params=True, segments=True
-    )
-    fused = dag.reference_forward(params, ids)
-    np.testing.assert_allclose(
-        np.asarray(fused), np.asarray(rep.output), rtol=2e-5, atol=2e-5
-    )
-    assert rep.n_dispatches > 1
-    assert rep.param_load_calls <= rep.n_dispatches
-    assert rep.param_loads >= len(dag.graph.unique_params())
-
-
 def test_streaming_stats_in_summary(setup):
     dag, params, ids = setup
     cluster = _tight_cluster(dag, 1, 0.35)
