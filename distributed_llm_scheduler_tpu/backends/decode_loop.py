@@ -195,23 +195,21 @@ def compose_paged_step_fn(
     mask: inactive slots (retired or not yet admitted) write the trash
     page, so one compiled step serves every admission/retirement state.
 
-    The cache is whatever :func:`...models.cache_spec`
-    says the family keeps, layer by layer (K and V pools, one latent
-    pool, or pools that differ between layers): layer ``i``'s task emits
-    ``{kind}_new`` for each of its pool kinds.  A ring layer's row goes
-    through the static ring table at ``lengths mod ring`` instead of the
-    page table (:class:`...models.kv_pages.CacheSpec`); a state layer's
-    ``{kind}_new`` IS its pool, the decoding slots' states already
-    updated in place by the task, and replaces it.  Layer tasks
-    that emit ``stats`` (an expert layer's routing counts) have them
-    stacked, layer-major — per name where a layer's ``stats`` is a dict
-    of named counts, over the layers that emit that name.
+    The cache is whatever :func:`...models.cache_spec` says the family
+    keeps, layer by layer (K and V pools, one latent pool, or pools that
+    differ between layers): layer ``i``'s task emits ``{kind}_new`` for
+    each of its pool kinds.  A ring layer's row goes through the static
+    ring table at ``lengths mod ring`` instead of the page table
+    (:class:`...models.kv_pages.CacheSpec`); a state layer's ``{kind}_new``
+    IS its pool, the decoding slots' states already updated in place by
+    the task.  Layer tasks that emit ``stats`` have them stacked,
+    layer-major — per name where ``stats`` is a dict of named counts.
 
     A family stepped with its draft module (``models.draft_rows`` > 1):
     ``ids`` is ``(S, R)``, every task's ``{kind}_new`` is ``(S, R, ...)``
-    and lands at ``lengths .. lengths + R - 1``, the spec's draft layers'
-    rows come from the ``draft`` task — the sink, whose whole output
-    dict (``logits``, ``draft_logits``) is returned as ``logits``.
+    and lands at ``lengths .. lengths + R - 1``, the draft layers' rows
+    come from the ``draft`` task, the sink: its output dict is ``logits``.
+    A graph that names passes: ``decode_passes.compose_looped_step_fn``.
 
     Returns ``step(weights, pools, page_table, ids, lengths, active)
     -> (logits, new_pools, stats or None)``.
@@ -221,6 +219,8 @@ def compose_paged_step_fn(
     order = _placed_order(graph, schedule)
     sink = [tid for tid in order if not graph.dependents(tid)][0]
     spec = cache_spec(config)
+    if spec.passes > 1 or getattr(graph, "pass_tasks", None):
+        return _looped(graph, order, spec)
     n_main = spec.n_layers - spec.draft_layers
     rows_per_step = draft_rows(config)
     if rows_per_step > 1 and spec.has_rings:
@@ -705,7 +705,6 @@ class PagedDecodeEngine:
         from ..models.kv_pages import TRASH_PAGE
 
         self._prefill_cache = {}
-
         np = self._np
         for s, pages in enumerate(self._slot_pages):
             if pages:
@@ -714,13 +713,14 @@ class PagedDecodeEngine:
                     self.memprof.free(
                         self._mem_node, f"kv:{self._slot_req[s]}"
                     )
-        # the KV arrays below are REBUILT, so retained prefix intern
-        # entries would point at zeroed pages — and a warm cache makes
-        # same-seed repeat runs diverge.  Fault-injector wrappers may
-        # not expose the method; pristine pools always do.
+        # the KV arrays below are REBUILT (the old ones go first: pools
+        # that fill the chip do not fit twice), so retained prefix intern
+        # entries would point at zeroed pages.  Fault-injector wrappers
+        # may not expose the method; pristine pools always do.
         drop = getattr(self.pool, "drop_cached", None)
         if drop is not None:
             drop()
+        self.pools = None
         self.pools = self.cache.init_pools(
             self.pool.n_pages, self.pool.page_size, self.config.dtype,
             slots=self.slots,
@@ -978,7 +978,7 @@ class PagedDecodeEngine:
                                    self.pool.refcount(dst)],
                     )
                 self.pools = self._cow_copy(
-                    self.pools, jnp.int32(src), jnp.int32(dst)
+                    self.pools, self._planes(src), self._planes(dst)
                 )
                 self.page_table[s, li] = dst
                 pages = self._slot_pages[s]
@@ -1568,8 +1568,8 @@ class PagedDecodeEngine:
             # a state layer's chunk begins from the state the slot holds
             # (``base > 0``) or from zero, and stops it at ``creal``
             carried = ({"state_carried": base > 0, "creal": C}
-                       if self.cache.has_state else {})
-            if carried:
+                       if self.cache.has_state else self._loop_span_args())
+            if self.cache.has_state:
                 self.metrics.counter(
                     "ssm.chunks_carried" if base else "ssm.first_chunks"
                 ).inc()
@@ -2124,6 +2124,12 @@ class PagedDecodeEngine:
                     reg.counter("decode.segments_behind_prefill").inc()
         self._seg_prev_t1 = t_sg1
         self._seg_span_args.update(args)
+        # pages in use of the pool's allocatable ones, once a dispatched
+        # segment (the gauge beside it keeps the last value only)
+        used = self.pool.used_pages / max(self.pool.n_pages - 1, 1)
+        for reg in (self.metrics, process_metrics()):
+            reg.histogram(
+                "decode.page_pool_used_share", unit="ratio").observe(used)
 
     def _emitted(self, toks, owed):
         """What a segment's readback gave each slot: ``(tokens of slot s
@@ -2200,6 +2206,8 @@ class PagedDecodeEngine:
                     reg.histogram(
                         "attn.full_row_share", unit="ratio"
                     ).observe(float(full / (full + ring)))
+        if "loop" in stats:
+            args.update(self._observe_loop(np.asarray(stats["loop"])))
         if "ssm" in stats:
             stepped = np.asarray(stats["ssm"])[:, 0]
             args["ssm_slots"] = float(stepped.sum())
@@ -2210,8 +2218,30 @@ class PagedDecodeEngine:
         if self.stats_probe is not None and (stats or probe):
             self.stats_probe(
                 {**{k: np.asarray(v) for k, v in stats.items()
-                    if k not in ("moe", "dsa", "attn", "ssm")}, **probe},
+                    if k not in ("moe", "dsa", "attn", "ssm", "loop")},
+                 **probe},
                 list(self._slot_req), self.lengths.copy(), owed)
+
+    def _observe_loop(self, loop) -> Dict[str, float]:
+        """A looped stack's counts of one segment, ``loop`` (steps,
+        passes, 1, 2) = (slots that ran the pass, their ``(u + 1) p_u``
+        of the exit gate summed), into the registries: the layer-stack
+        passes a decoded token cost, what the gate's distribution would
+        have cost it, the layer applications in all."""
+        passes_ran = float(loop[..., 0].sum())
+        slot_steps = float(loop[:, 0, ..., 0].sum())
+        args = {"passes": int(loop.shape[1]),
+                "rows_live": float((self.lengths * (self.remaining > 0)).sum())}
+        if slot_steps:
+            for reg in (self.metrics, process_metrics()):
+                reg.histogram("loop.passes_per_token", unit="passes").observe(
+                    passes_ran / slot_steps)
+                reg.histogram(
+                    "loop.exit_pass_expected", unit="passes").observe(
+                        float(loop[..., 1].sum()) / slot_steps)
+                reg.counter("loop.layer_passes").inc(
+                    int(round(passes_ran)) * self.n_layers)
+        return args
 
     def _observe_moe(self, stats, steps_ran: int) -> Dict[str, float]:
         """An expert family's routing counts of one segment, ``stats``
@@ -2397,3 +2427,19 @@ class PagedDecodeEngine:
             and gqa_paged_chunk_impl(
                 self.attention_impl, self.page_size, self.cache.head_dim,
                 self.config.dtype) != "xla")
+
+    def _planes(self, page: int):
+        """A page id as :attr:`_cow_copy` takes it: in every plane of a
+        cache whose layers run more than once, else the id itself."""
+        if self.cache.passes == 1:
+            return jnp.int32(page)
+        return jnp.asarray([page + u * self.pool.n_pages
+                            for u in range(self.cache.passes)], jnp.int32)
+
+    def _loop_span_args(self) -> Dict[str, Any]:
+        """What a ``prefill_chunk`` span says of a looped stack."""
+        return {"passes": self.cache.passes} if self.cache.passes > 1 else {}
+
+
+# below everything: no line above it moves (ROADMAP D16)
+from .decode_passes import compose_looped_step_fn as _looped  # noqa: E402

@@ -412,8 +412,16 @@ def build_paged_decode_dag(
     out_specs: Dict[str, Any] = {}
     add = make_task_adder(tasks, out_specs, specs, input_spec,
                           effective_flops)
-    _chain(fam, config, spec, add, fam.decode_flops(config, S, M), f_embed,
-           layer_fn, f_head, {"page_table": "page_table"})
+    from ..models import LOOP_FUNCTIONS
+
+    pass_tasks = None
+    if all(hasattr(fam, n) for n in LOOP_FUNCTIONS):
+        pass_tasks = _looped_chain(
+            fam, config, spec, add, tasks, out_specs,
+            fam.decode_flops(config, S, M), attention_impl)
+    else:
+        _chain(fam, config, spec, add, fam.decode_flops(config, S, M),
+               f_embed, layer_fn, f_head, {"page_table": "page_table"})
     if drafts:
         alias = dict(fam.draft_param_names(config))
         for i in range(spec.n_layers - spec.draft_layers, spec.n_layers):
@@ -475,6 +483,10 @@ def build_paged_decode_dag(
         + ("" if attention_impl is None else f"_att{attention_impl}")
     ).freeze()
     graph.attention_impl = attention_impl
+    if pass_tasks is not None:
+        # the task ids of each pass, in order: what the loop composer
+        # rolls into one traced pass (``backends/decode_passes.py``)
+        graph.pass_tasks = pass_tasks
     if spec.head_dim:
         # what splits a stored K/V row into heads: the DEC005 / DEC006
         # eligibility checks see the graph and its param specs only
@@ -527,3 +539,96 @@ def apply_cache_updates(
                 buf, new, (0, 0, pos, 0)
             )
     return out
+
+
+def _looped_chain(fam, config, spec, add, tasks, out_specs, flops,
+                  attention_impl):
+    """:func:`_chain` for a family whose layers run ``spec.passes`` times
+    a token (it offers ``models.LOOP_FUNCTIONS``; the seam decides, so a
+    config of ONE pass is built this way too and its pass closes like
+    any other): embed -> [one task a layer, the task that closes the
+    pass] x passes -> logits.
+
+    The tasks of layer ``l`` carry the SAME ``fn`` object, the same
+    weight aliases (a weight is named once in the graph and read by
+    ``passes`` tasks — placement, residency and the analysis passes
+    count its bytes once and its FLOPs every time) and the same pool
+    aliases: a layer's pool holds a plane a pass, and which plane a task
+    reads and writes is told by ``pass`` on its input edge (0 from the
+    embed task, one more from every ``p{u}_end``).  The edge also carries
+    the exit gate's running ``survive`` / ``expected``
+    (``fam.decode_pass_end``), so every pass's input has one structure
+    and the loop composer can carry it.  Pass 0's tasks are built through
+    ``add``; a later pass's are the same tasks under its own ids, each
+    behind the task before it.  Returns the task ids of each pass."""
+    import dataclasses
+
+    embed_flops, layer_flops, head_flops = flops
+    carried = ("lengths", "live", "pass", "survive", "expected")
+
+    def f_embed(p, inputs):
+        S = inputs["lengths"].shape[0]
+        return {"x": fam.decode_embed(
+            p, inputs["ids"], inputs["lengths"], config),
+            "lengths": inputs["lengths"], "live": inputs["active"],
+            "pass": jnp.zeros((), jnp.int32),
+            "survive": jnp.ones((S,), jnp.float32),
+            "expected": jnp.zeros((S,), jnp.float32)}
+
+    def layer_fn(i):
+        def f_layer(p, prev):
+            x, new, stats = fam.decode_layer(
+                p, prev["x"], prev["lengths"], prev["live"], config, i,
+                impl=attention_impl, u=prev["pass"])
+            out = {"x": x, **{k: prev[k] for k in carried},
+                   **{f"{k}_new": v for k, v in new.items()}}
+            if stats is not None:
+                out["stats"] = stats
+            return out
+        return f_layer
+
+    def f_end(p, prev):
+        x, survive, expected, stats = fam.decode_pass_end(
+            p, prev["x"], prev["live"], prev["pass"], prev["survive"],
+            prev["expected"], config)
+        return {"x": x, "lengths": prev["lengths"], "live": prev["live"],
+                "pass": prev["pass"] + 1, "survive": survive,
+                "expected": expected, "stats": stats}
+
+    def f_head(p, prev):
+        return fam.decode_head(p, prev["x"], config)
+
+    add("embed", f_embed, [], {k: k for k in fam.EMBED_PARAMS}, embed_flops,
+        "embed")
+    prev, fns, first = "embed", {}, []
+    for i in range(spec.n_layers):
+        alias = dict(fam.layer_param_names(config, i))
+        fn = fns.get(key := (*alias, *spec.layer_kinds(i)))
+        if fn is None:
+            fn = fns[key] = layer_fn(i)
+        alias.update(
+            {f"cache_{k}": f"cache_{k}_{i}" for k in spec.layer_kinds(i)})
+        alias["page_table"] = "page_table"
+        add(tid := f"p0_layer_{i}", fn, [prev], alias, layer_flops[i],
+            f"layer_{i}")
+        first.append(tid)
+        prev = tid
+    # the final norm and the gate: a few FLOPs a value of the residual
+    add("p0_end", f_end, [prev], {k: k for k in fam.PASS_END_PARAMS},
+        4.0 * out_specs["embed"]["x"].size, "pass_end")
+    first.append(prev := "p0_end")
+    by_id = {t.task_id: t for t in tasks}
+    passes = [tuple(first)]
+    for u in range(1, spec.passes):
+        mine = []
+        for tid in first:
+            new_id = f"p{u}_" + tid.split("_", 1)[1]
+            tasks.append(dataclasses.replace(
+                by_id[tid], task_id=new_id, dependencies=[prev],
+                arg_tasks=[prev]))
+            out_specs[new_id] = out_specs[tid]
+            mine.append(prev := new_id)
+        passes.append(tuple(mine))
+    add("logits", f_head, [prev], {k: k for k in fam.HEAD_PARAMS},
+        head_flops, "head")
+    return tuple(passes)

@@ -147,6 +147,13 @@ DRAFT_FUNCTIONS = (
     "DECODE_ROWS", "draft_param_names", "draft_decode", "draft_flops",
     "forward_cached_draft",
 )
+#: what a family whose layers run more than once a token (its
+#: ``cache_spec`` says ``passes`` > 1) offers BESIDE :data:`PAGED_FUNCTIONS`:
+#: the parameters and the function of the task that closes a pass, and a
+#: ``decode_layer`` that takes the pass ``u``.  ``build_paged_decode_dag``
+#: then emits the layers' tasks once a pass, all on the same weights, and
+#: ``compose_paged_step_fn`` rolls the passes into one traced loop
+LOOP_FUNCTIONS = ("PASS_END_PARAMS", "decode_pass_end")
 #: the functions the dense decode-step DAG calls (``build_decode_dag``)
 CACHED_FUNCTIONS = (
     "cache_spec", "layer_param_names", "cached_embed", "cached_layer",
@@ -221,6 +228,12 @@ for _f in (
     Family(
         "nemotron_h", f"{__name__}.nemotron_h", "NemotronHConfig",
         {"nemotron-h-tiny": "tiny"}, "n_layers", "max_positions",
+    ),
+    # a looped stack: the layers run several times a token on one set of
+    # weights, a K/V plane a pass (LOOP_FUNCTIONS)
+    Family(
+        "ouro", f"{__name__}.ouro", "OuroConfig", {"ouro-tiny": "tiny"},
+        "n_layers", "max_positions",
     ),
 ):
     register_family(_f)

@@ -591,6 +591,17 @@ class CacheSpec:
     own dtype where it is not the cache's (a float32 state beside bf16
     K/V).
 
+    **Passes.**  A stack whose ``layers`` run ``passes`` times a token on
+    one set of weights keeps a row a (pass, layer): a cache entry is no
+    longer a weight layer.  A layer's pool is still ONE array,
+    ``passes`` *planes* of ``n_pages`` pages each — pass ``u`` reads and
+    writes through ``page_table + u * n_pages`` (:meth:`plane`; page 0 of
+    a plane is that plane's trash page) — so one page id names a token's
+    rows in every pass of every layer exactly as it names them in every
+    layer, and the allocator, admission, preemption, retirement and the
+    kernels never hear of passes.  The dense cache holds, per kind,
+    entry ``u * layers + l`` for pass ``u`` of layer ``l``.
+
     **Draft layers.**  The last ``draft_layers`` entries are the layers
     of a draft module the family steps itself with (``models.
     DRAFT_FUNCTIONS``): pools like any other, over the same positions,
@@ -607,6 +618,19 @@ class CacheSpec:
     draft_layers: int = 0
     #: ``(pool kind, dtype)`` of the kinds not kept in the cache's dtype
     dtypes: Tuple[Tuple[str, Any], ...] = ()
+    #: times a token runs through ``layers`` (a looped stack): each pass
+    #: keeps rows of its own, in a plane of the layer's pool
+    passes: int = 1
+
+    def __post_init__(self) -> None:
+        if self.passes < 1:
+            raise ValueError(f"passes must be >= 1, got {self.passes}")
+        if self.passes > 1 and (self.has_rings or self.has_state
+                                or self.draft_layers):
+            raise ValueError(
+                "a cache whose layers run more than once is built for "
+                "paged layers only: a ring or a state is a slot's, one a "
+                "layer, and a draft module is stepped once")
 
     @classmethod
     def uniform(cls, kind: str, n_layers: int,
@@ -768,24 +792,36 @@ class CacheSpec:
 
     @property
     def paged_row_elems(self) -> int:
-        """Values one token occupies in the shared pages, all layers: a
-        ring layer holds none there."""
-        return sum(math.prod(row) for _, _, row, _, window in self._pools()
-                   if window is None)
+        """Values one token occupies in the shared pages, all layers and
+        all passes: a ring layer holds none there."""
+        return self.passes * sum(
+            math.prod(row) for _, _, row, _, window in self._pools()
+            if window is None)
+
+    def plane(self, pool: jax.Array, table: Any, u: Any) -> Any:
+        """``table`` (page ids, any shape) as pass ``u`` (static or
+        traced) of a paged layer reads and writes ``pool`` through it:
+        shifted into the pass's plane of ``pool.shape[0] // passes``
+        pages.  Pass 0's, and every pass of a one-pass cache, is
+        ``table`` itself."""
+        if self.passes == 1:
+            return table
+        return table + u * (pool.shape[0] // self.passes)
 
     def init_pools(self, n_pages: int, page_size: int, dtype: Any,
                    slots: Optional[int] = None) -> Dict[str, jax.Array]:
-        """Zeroed pools keyed ``cache_{kind}_{i}``, in the stored form;
-        a ring layer's holds ``slots`` rings and the trash page, a state
-        layer's ``slots`` states and the trash row."""
+        """Zeroed pools keyed ``cache_{kind}_{i}``, in the stored form
+        (a paged layer's ``passes`` planes of ``n_pages`` pages); a ring
+        layer's holds ``slots`` rings and the trash page, a state layer's
+        ``slots`` states and the trash row."""
         if (self.has_rings or self.has_state) and slots is None:
             raise ValueError("a spec with ring or state layers sizes their "
                              "pools by the engine's slots")
         ring = 1 + (slots or 0) * self.ring_pages(page_size)
         pools = {
             f"cache_{kind}_{i}": jnp.zeros(
-                (n_pages if window is None else ring, page_size,
-                 math.prod(row)), self.kind_dtype(kind, dtype))
+                (self.passes * n_pages if window is None else ring,
+                 page_size, math.prod(row)), self.kind_dtype(kind, dtype))
             for i, kind, row, _, window in self._pools()
         }
         pools.update({
@@ -810,15 +846,16 @@ class CacheSpec:
                    in_pages: bool = False) -> Dict[str, Any]:
         """The family's zeroed dense cache ``{kind: (L, b, ...)}``; per
         layer, ``L`` counts the layers that keep the kind and a ring
-        kind's ``cap`` is the ring (whole pages of ``page_size``).
-        ``in_pages``: the ring kinds alone — the paged layers stay in
-        their pages (:meth:`gather`).  A state kind: ``(L, b, *shape)``."""
+        kind's ``cap`` is the ring (whole pages of ``page_size``); with
+        ``passes`` ``L`` counts each of them once a pass.  ``in_pages``:
+        the ring kinds alone — the paged layers stay in their pages
+        (:meth:`gather`).  A state kind: ``(L, b, *shape)``."""
         ring = self.ring_pages(page_size or 1) * (page_size or 1)
         shapes: Dict[str, Any] = {}
         for _, kind, row, n, window in self._pools():
             if in_pages and window is None:
                 continue
-            shapes[kind] = (n + 1, *self._dense(
+            shapes[kind] = ((n + 1) * self.passes, *self._dense(
                 kind, (batch, cap if window is None else ring, *row)))
         for _, kind, shape, n in self._states():
             shapes[kind] = (n + 1, batch, *shape)
@@ -862,13 +899,24 @@ class CacheSpec:
             if window is not None:   # the ring whole: its dense rows
                 take = ring
                 rows_n = out[kind].shape[3 if self.kind == "kv" else 2]
-            rows = jnp.take(pools[f"cache_{kind}_{i}"], take, axis=0)
-            rows = self._from_rows(rows.reshape(batch, rows_n, *row))
-            at = ((n, slice(None), slice(None), slice(0, rows_n))
-                  if self.kind == "kv" else
-                  (n, slice(None), slice(0, rows_n)))
-            out[kind] = out[kind].at[at].set(rows.astype(out[kind].dtype))
+            pool = pools[f"cache_{kind}_{i}"]
+            for u, n_u in self._entries(kind, n):
+                rows = jnp.take(pool, self.plane(pool, take, u), axis=0)
+                rows = self._from_rows(rows.reshape(batch, rows_n, *row))
+                at = ((n_u, slice(None), slice(None), slice(0, rows_n))
+                      if self.kind == "kv" else
+                      (n_u, slice(None), slice(0, rows_n)))
+                out[kind] = out[kind].at[at].set(
+                    rows.astype(out[kind].dtype))
         return out
+
+    def _entries(self, kind: str, n: int):
+        """``(pass, dense entry)`` of the ``n``-th layer that keeps
+        ``kind``: entry ``u * layers with the kind + n`` for pass ``u``."""
+        if self.passes == 1:
+            return ((0, n),)
+        per_pass = sum(1 for _, k, *_ in self._pools() if k == kind)
+        return tuple((u, u * per_pass + n) for u in range(self.passes))
 
     def _from_rows(self, rows: jax.Array) -> jax.Array:
         return rows.transpose(0, 2, 1, 3) if self.kind == "kv" else rows
@@ -894,11 +942,13 @@ class CacheSpec:
                 new[f"cache_{kind}_{i}"] = cache[kind][n]
                 continue
             into = pages if window is None else ring
-            rows = self.to_rows(cache[kind][n])
-            paged = rows.reshape(into.shape[0], page_size, -1)
             pool = new[f"cache_{kind}_{i}"]
-            new[f"cache_{kind}_{i}"] = pool.at[into].set(
-                paged.astype(pool.dtype), mode="drop")
+            for u, n_u in self._entries(kind, n):
+                rows = self.to_rows(cache[kind][n_u])
+                paged = rows.reshape(into.shape[0], page_size, -1)
+                pool = pool.at[self.plane(pool, into, u)].set(
+                    paged.astype(pool.dtype), mode="drop")
+            new[f"cache_{kind}_{i}"] = pool
         return new
 
 

@@ -164,9 +164,9 @@ def _tiny(family: str):
 # -- the contract -----------------------------------------------------------------
 
 
-def test_the_toy_is_registered_beside_the_eight():
+def test_the_toy_is_registered_beside_the_nine():
     assert FAMILIES == ["dots3", "glm4_lite", "gpt2", "laguna", "llama",
-                        "mixtral", "nemotron_h", "toy", "xing4"]
+                        "mixtral", "nemotron_h", "ouro", "toy", "xing4"]
 
 
 @pytest.mark.parametrize("family", FAMILIES)
@@ -255,7 +255,7 @@ def test_family_is_decided_by_type_not_by_class_name():
     ("mixtral-8x7b", "mixtral"), ("mixtral-tiny", "mixtral"),
     ("xing4-tiny", "xing4"), ("dots3-tiny", "dots3"),
     ("glm4_lite-tiny", "glm4_lite"), ("laguna-tiny", "laguna"),
-    ("toy-tiny", "toy")])
+    ("ouro-tiny", "ouro"), ("toy-tiny", "toy")])
 def test_variant_names_make_their_familys_config(model, family):
     assert models.family_of_model(model).name == family
     assert models.family_of(models.model_config(model)) == family
@@ -290,10 +290,12 @@ def test_what_each_family_offers():
     dense = {f for f in rows
              if models.offers(rows[f], *models.CACHED_FUNCTIONS)}
     assert served == {"gpt2", "xing4", "dots3", "glm4_lite", "laguna",
-                      "nemotron_h", "toy"}
+                      "nemotron_h", "ouro", "toy"}
     assert dense == {"gpt2", "llama", "mixtral"}
     assert {f for f in rows if models.offers(
         rows[f], *models.DRAFT_FUNCTIONS)} == {"glm4_lite"}
+    assert {f for f in rows if models.offers(
+        rows[f], *models.LOOP_FUNCTIONS)} == {"ouro"}
 
 
 @pytest.mark.parametrize("family", ["gpt2", "llama", "xing4"])
