@@ -247,11 +247,16 @@ class DeviceBackend:
         # fn object -> jitted fn; survives across execute() calls so
         # benchmark reruns don't pay compilation again
         self._jit_cache: Dict[Any, Callable[..., Any]] = {}
-        # (fn object, donate_argnums) -> jitted donating variant; separate
-        # from _jit_cache so tasks sharing one fn but dying-buffer patterns
+        # (fn object, donate_argnums, native positions) -> jitted variant
+        # that donates and / or leaves its result's layout to the compiler;
+        # separate from _jit_cache (which a fused launch traces through)
+        # so tasks sharing one fn but dying-buffer patterns or readers
         # that differ never collide
-        self._donate_jit_cache: Dict[Tuple[Any, Tuple[int, ...]], Any] = {}
-        # (launch structure, donate_argnums) -> jitted fused launch
+        self._donate_jit_cache: Dict[
+            Tuple[Any, Tuple[int, ...], Tuple[int, ...]], Any
+        ] = {}
+        # (launch structure, donate_argnums, native positions) -> jitted
+        # fused launch
         # (dispatch_plan.launch_structure: member fn objects, in-run
         # wiring by position, exported positions).  No task id, graph or
         # parameter name is in the key or the value: layers, graphs and
@@ -654,28 +659,38 @@ class DeviceBackend:
 
     # -- compilation -------------------------------------------------------
     def _jitted(self, graph: TaskGraph, tid: str,
-                donate_argnums: Tuple[int, ...] = ()):
+                donate_argnums: Tuple[int, ...] = (),
+                native_pos: Tuple[int, ...] = ()):
         """One jitted callable per distinct fn *object*: tasks that share a
         fn (all layers' ln1 via param_alias) share the jit wrapper, so the
         per-layer compile multiplicity disappears.  XLA still compiles one
         executable per placement device (input sharding is part of the
         cache key) — that per-device cost is inherent.
 
-        ``donate_argnums`` (planned dispatch) selects a donating variant,
-        cached per (fn, pattern) so differing dying-buffer patterns never
-        collide; the empty pattern is the shared plain cache."""
+        ``donate_argnums`` (planned dispatch) selects a donating variant
+        and ``native_pos`` (``(0,)``: a task has one result) one whose
+        result keeps the layout the compiler writes it in
+        (:class:`.dispatch_plan.NativeLaunch`), as for a fused launch
+        (:meth:`_grouped_jitted`); cached per (fn, pattern, positions) so
+        differing launches never collide; the empty pattern is the shared
+        plain cache."""
         task = graph[tid]
         if task.fn is None:
             raise ValueError(
                 f"task {tid!r} has no fn; this graph is schedule-only "
                 "(synthetic DAGs execute on the simulated backend)"
             )
-        if donate_argnums:
-            key = (task.fn, donate_argnums)
+        if donate_argnums or native_pos:
+            key = (task.fn, donate_argnums, native_pos)
             fn = self._donate_jit_cache.get(key)
             if fn is None:
                 self.jit_cache_misses += 1
-                fn = jax.jit(task.fn, donate_argnums=donate_argnums)
+                if native_pos:
+                    from .dispatch_plan import NativeLaunch
+
+                    fn = NativeLaunch(task.fn, donate_argnums, None)
+                else:
+                    fn = jax.jit(task.fn, donate_argnums=donate_argnums)
                 self._donate_jit_cache[key] = fn
             else:
                 self.jit_cache_hits += 1
@@ -697,30 +712,36 @@ class DeviceBackend:
 
     def _grouped_jitted(
         self, key: Any, donate_argnums: Tuple[int, ...] = (),
+        native_pos: Tuple[int, ...] = (),
     ):
         """Jitted fused launch (dispatch_plan) for one launch *structure*
         ``key = (fns, binds, export_positions)``: the members run in order
         inside ONE executable, ``optimization_barrier`` between them
         keeping per-task numerics bit-identical to separate launches.
-        Cached per (structure, donate pattern), never per task ids: every
-        launch wired alike — the same span one layer down, the next
-        ``execute()``, another graph over the same fns — calls the same
-        jit object, and ``compile.group_structures`` in
+        The exports at ``native_pos`` keep the layout the compiler writes
+        them in (:class:`.dispatch_plan.NativeLaunch`); the others are
+        handed over in the runtime's default.
+        Cached per (structure, donate pattern, native positions), never
+        per task ids: every launch wired alike — the same span one layer
+        down, the next ``execute()``, another graph over the same fns —
+        calls the same jit object, and ``compile.group_structures`` in
         ``obs.process_metrics()`` counts how many this backend built."""
-        cache_key = (key, donate_argnums)
+        cache_key = (key, donate_argnums, native_pos)
         fn = self._group_cache.get(cache_key)
         if fn is None:
-            from .dispatch_plan import _build_group_fn
+            from .dispatch_plan import NativeLaunch, _build_group_fn
 
             fns, binds, export_pos = key
             self.jit_cache_misses += 1
-            fn = jax.jit(
-                _build_group_fn(
-                    tuple(self._jit_of(f) for f in fns),
-                    binds, export_pos,
-                ),
-                donate_argnums=donate_argnums or None,
+            fn = _build_group_fn(
+                tuple(self._jit_of(f) for f in fns), binds, export_pos,
             )
+            if native_pos:
+                fn = NativeLaunch(fn, donate_argnums, tuple(
+                    i in native_pos for i in range(len(export_pos))
+                ))
+            else:
+                fn = jax.jit(fn, donate_argnums=donate_argnums or None)
             self._group_cache[cache_key] = fn
             process_metrics().gauge("compile.group_structures").set(
                 len(self._group_cache)
@@ -1586,6 +1607,9 @@ class DeviceBackend:
         if plan is not None:
             pm.histogram("execute.tasks_per_launch").observe(
                 sum(len(st.tids) for st in plan.steps) / max(n_disp, 1)
+            )
+            pm.histogram("execute.native_layout_exports").observe(
+                plan.native_layout_exports
             )
         if prep is not None:
             pm.counter(f"execute.prepared.{outcome}").inc()

@@ -80,6 +80,10 @@ from jax.sharding import SingleDeviceSharding
 # API of the pinned jax (pyproject.toml): an upgrade that moves it fails
 # here, at import.
 from jax._src.lib import xla_client as _xc
+# what jit itself asks when it resolves a committed argument's layout
+# (``pjit._resolve_in_layouts``); private like the import above
+from jax._src.interpreters.pxla import is_default_layout as _is_default_layout
+from jax.experimental.layout import Format, Layout
 
 from ..obs.trace import CAT_LAUNCH, CAT_STAGE, annotate
 
@@ -105,6 +109,122 @@ def _tuple_getter(slots: Sequence[int]):
         s = slots[0]
         return lambda vals: (vals[s],)
     return itemgetter(*slots)
+
+
+def _compile_in_process(jitted: Any, *args: Any) -> Any:
+    """``jitted`` compiled for ``args`` here and now, neither read from nor
+    written to the persistent compilation cache: the executable.
+
+    An executable loaded from that cache hands out arrays that report the
+    runtime's default layout whatever layout it wrote them in (jaxlib
+    0.9.0: ``tests/test_dispatch_plan.py`` shows it on the CPU, where a
+    reader compiled against the reported layout reads wrong values; on
+    the v5e the runtime refuses the buffer), so a program that keeps
+    another layout has to be the process's own.  The cache is off for the
+    whole process while this compiles — a compile another thread issues
+    meanwhile is not cached either — and for nothing else: the executable
+    is called outside."""
+    from jax.experimental.compilation_cache import compilation_cache
+
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    try:
+        return jitted.lower(*args).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", was)
+        compilation_cache.reset_cache()
+
+
+class NativeLaunch:
+    """A launch's program whose results at ``asked`` keep the layout the
+    compiler writes them in.
+
+    ``auto`` leaves those results to the compiler (``Layout.AUTO``).  It
+    is compiled once per argument signature, ahead of its first call, and
+    asked what it picked (:meth:`picked`): where that is the runtime's
+    default for every result — every activation of the GPT-2 DAGs, and
+    everything on the CPU — ``auto`` is the launch's executable, the one
+    compile it costs today, served by the persistent cache like any
+    other.  Where a result comes back in another layout (the logits on
+    the v5e: 50,257 is no multiple of 128, so the default puts the batch
+    on the sublanes, an order the head's matmul never writes), the launch
+    runs a program of its own that names those layouts, compiled in this
+    process (:func:`_compile_in_process`); ``auto`` then only answered
+    the question, from the cache after the first run of a checkout.
+    """
+
+    __slots__ = ("fun", "donate_argnums", "asked", "auto", "_resolved")
+
+    def __init__(
+        self, fun: Any, donate_argnums: Tuple[int, ...],
+        asked: Optional[Tuple[bool, ...]],
+    ):
+        """``asked``: which results of the tuple ``fun`` returns are left
+        to the compiler; ``None`` where it returns one value (a single
+        task), which is."""
+        ask = Format(Layout.AUTO)
+        self.fun = fun
+        self.donate_argnums = donate_argnums or None
+        self.asked = asked
+        self.auto = jax.jit(
+            fun, donate_argnums=self.donate_argnums,
+            out_shardings=ask if asked is None else tuple(
+                ask if on else None for on in asked
+            ),
+        )
+        # argument signature -> (executable, results it keeps in another
+        # layout than the default)
+        self._resolved: Dict[Any, Tuple[Any, int]] = {}
+
+    def picked(self, pd: Any, args: Sequence[Any]) -> Tuple[Any, Any]:
+        """The compiler's answer for ``(pd, *args)``: the formats ``auto``
+        writes its results in, and their avals."""
+        compiled = self.auto.lower(pd, *args).compile()
+        return compiled.output_formats, compiled.out_info
+
+    def first_call(
+        self, pd: Any, args: Sequence[Any],
+    ) -> Tuple[Any, Any, int]:
+        """Run the launch on ``(pd, *args)`` and return ``(the executable
+        every later call with such arguments goes to, the results, how
+        many of them it keeps in another layout than the default)``."""
+        key = tuple(
+            (leaf.aval, leaf.format)
+            for leaf in jax.tree_util.tree_leaves((pd, args))
+        )
+        if key not in self._resolved:
+            self._resolved[key] = self.resolve(pd, args)
+        fn, n_kept = self._resolved[key]
+        return fn, fn(pd, *args), n_kept
+
+    def resolve(self, pd: Any, args: Sequence[Any]) -> Tuple[Any, int]:
+        """The executable for ``(pd, *args)`` — arrays, or their shapes
+        with the formats they arrive in — and how many of its results it
+        keeps in another layout than the default."""
+
+        def keep(picked: Any, avals: Any) -> Any:
+            return jax.tree_util.tree_map(
+                lambda fmt, aval: None if _is_default_layout(
+                    fmt.layout, fmt.sharding, aval) else fmt,
+                picked, avals,
+            )
+
+        picked, avals = self.picked(pd, args)
+        if self.asked is None:
+            kept = keep(picked, avals)
+        else:
+            kept = tuple(
+                keep(p, a) if on else None
+                for on, p, a in zip(self.asked, picked, avals)
+            )
+        n_kept = len(jax.tree_util.tree_leaves(kept))
+        if not n_kept:
+            return self.auto, 0
+        named = jax.jit(
+            self.fun, donate_argnums=self.donate_argnums, out_shardings=kept,
+        )
+        return _compile_in_process(named, pd, *args), n_kept
 
 
 _DONATION_OK: Optional[bool] = None
@@ -347,6 +467,17 @@ def _sds(x: Any):
     return jax.ShapeDtypeStruct(tuple(x.shape), x.dtype)
 
 
+def _leaf_avals(out_shape: Any) -> Optional[frozenset]:
+    """(shape, dtype) of every leaf of a task's ``out_shape``; None where
+    the graph carries none."""
+    if out_shape is None:
+        return None
+    return frozenset(
+        (tuple(leaf.shape), leaf.dtype)
+        for leaf in jax.tree_util.tree_leaves(out_shape)
+    )
+
+
 def _relinearize(graph, schedule, alive: List[str], done: set) -> List[str]:
     """Reorder ``alive`` to maximize consecutive same-device runs.
 
@@ -575,6 +706,10 @@ class PlanStep:
         "donate_argnums",  # jit donate positions (params dict is argument 0)
         "out_slots",     # value-table indices written (exports, in order)
         "out_tids",      # exported task id per out_slot (memprof births)
+        "native_slots",  # out_slots whose layout is left to the compiler
+        "program",       # where there are any, the NativeLaunch that ``fn``
+                         # is until the step's first call has resolved it
+                         # to an executable; else None
         "group",         # True => fn returns a tuple aligned with out_slots
     )
 
@@ -618,6 +753,10 @@ class DispatchPlan:
         # (param, node_id) -> every (step binding dict, local name) that
         # holds its placed array: what rebind() writes through
         self.param_binds = param_binds
+        # how many exported values the compiled launches left in another
+        # layout than the default (of those PlanStep.native_slots leaves to
+        # the compiler): known once the first run has resolved them
+        self.native_layout_exports: Optional[int] = None
 
     # -- construction ------------------------------------------------------
     @classmethod
@@ -741,6 +880,15 @@ class DispatchPlan:
             slot_of[t]: t for exports in exports_of for t in exports
         }
         protected = {final_slot} | {s for _, s in fence_slots}
+        # an exported value may stay in the layout its producer writes it
+        # in when every reader is a launch on its own chip, or nobody but
+        # the caller (a sink: the step's output); one that is put onto
+        # another chip, or kept for a later call, is handed over in the
+        # runtime's default layout
+        native_ok = frozenset() if keep_outputs else frozenset(
+            t for exports in exports_of for t in exports
+            if all(placement[c] == placement[t] for c in consumers[t])
+        )
 
         param_binds: Dict[
             Tuple[str, str], List[Tuple[Dict[str, Any], str]]
@@ -754,6 +902,10 @@ class DispatchPlan:
             return pd
 
         steps: List[PlanStep] = []
+        programs: List[Any] = []  # per step: what its executable is keyed by
+        # launches of one structure share one program, so a result is left
+        # to the compiler only where every launch that shares it may be
+        native_of: Dict[Any, Tuple[bool, ...]] = {}
         transfer_edges = 0
         for gi, g in enumerate(groups):
             node = placement[g[0]]
@@ -840,14 +992,46 @@ class DispatchPlan:
             if launch is not None:
                 step.out_slots = tuple(slot_of[t] for t in launch.exports)
                 step.out_tids = launch.exports
-                step.fn = backend._grouped_jitted(launch.key, donate_argnums)
                 step.pd = tuple(bind(t, node) for t in launch.members)
+                program = (launch.key, donate_argnums)
             else:
                 step.out_slots = (slot_of[g[0]],)
                 step.out_tids = (g[0],)
-                step.fn = backend._jitted(graph, g[0], donate_argnums)
                 step.pd = bind(g[0], node)
+                program = (graph[g[0]].fn, donate_argnums)
             steps.append(step)
+            # a result that can take a donated argument's buffer (jit pairs
+            # them by shape and dtype) is that buffer, layout and all: it
+            # is left to the compiler only where the graph's avals show
+            # that no dying argument of the launch fits it
+            dying = [_leaf_avals(graph[ext_list[p]].out_shape)
+                     for p in donate_pos]
+            made = {t: _leaf_avals(graph[t].out_shape) for t in step.out_tids}
+            ok = tuple(
+                t in native_ok and all(
+                    a is not None and made[t] is not None
+                    and a.isdisjoint(made[t])
+                    for a in dying
+                )
+                for t in step.out_tids
+            )
+            programs.append(program)
+            native_of[program] = tuple(
+                a and b for a, b in zip(native_of.get(program, ok), ok)
+            )
+
+        for step, program in zip(steps, programs):
+            native_pos = tuple(
+                i for i, ok in enumerate(native_of[program]) if ok
+            )
+            step.native_slots = tuple(step.out_slots[i] for i in native_pos)
+            if step.group:
+                step.fn = backend._grouped_jitted(*program, native_pos)
+            else:
+                step.fn = backend._jitted(
+                    graph, step.tids[0], program[1], native_pos
+                )
+            step.program = step.fn if native_pos else None
 
         keep_list = tuple(
             (t, slot_of[t]) for exports in exports_of for t in exports
@@ -918,7 +1102,7 @@ class DispatchPlan:
                 (
                     st.tids, st.node_id, st.arg_slots, st.xfer_slots,
                     st.xfer_map, st.donate_slots, st.donate_argnums,
-                    st.out_slots,
+                    st.out_slots, st.native_slots,
                 )
                 for st in self.steps
             ),
@@ -943,7 +1127,10 @@ class DispatchPlan:
             for pd, loc in self.param_binds.get(pair, ()):
                 pd[loc] = new
         if reshaped:
+            self.native_layout_exports = None
             for st in self.steps:
+                if st.program is not None:
+                    st.fn = st.program
                 if st.xfer_map:
                     st.xfer_avals = None
                     st.xfer_bytes = None
@@ -1012,6 +1199,10 @@ class DispatchPlan:
                 )
 
         tbytes = 0
+        # the first run resolves each launch that left a result's layout
+        # to its compiler (NativeLaunch.first_call)
+        first_run = self.native_layout_exports is None
+        n_native = 0
         t_d0 = time.perf_counter()
         with annotate("dispatch_loop"):
             for step in self.steps:
@@ -1070,12 +1261,18 @@ class DispatchPlan:
                 tbytes += step.xfer_bytes
                 if tracer is not None:
                     t_l0 = time.perf_counter()
-                if step.group:
+                if first_run and step.program is not None:
+                    step.fn, outs, kept = step.program.first_call(
+                        step.pd, args
+                    )
+                    n_native += kept
+                else:
                     outs = step.fn(step.pd, *args)
+                if step.group:
                     for s, o in zip(step.out_slots, outs):
                         vals[s] = o
                 else:
-                    vals[step.out_slots[0]] = step.fn(step.pd, *args)
+                    vals[step.out_slots[0]] = outs
                 if mem is not None:
                     # births, then the donation-consumed producers' deaths —
                     # the exact lifetimes donation_table() documents
@@ -1109,6 +1306,8 @@ class DispatchPlan:
                                 )
         t_d1 = time.perf_counter()
         loop_s = t_d1 - t_loop0
+        if first_run:
+            self.native_layout_exports = n_native
         if tracer is not None:
             # the host-track leaf over the per-launch spans of the device
             # tracks: with stage_input and fence it tiles the rep

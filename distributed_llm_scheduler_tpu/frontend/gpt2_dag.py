@@ -114,6 +114,7 @@ def make_task_adder(
     specs: Dict[str, Any],
     input_spec: Any,
     effective_flops: float,
+    traced: Optional[Dict[Any, Any]] = None,
 ) -> Callable[..., None]:
     """The one task-construction closure every frontend builder shares.
 
@@ -123,6 +124,13 @@ def make_task_adder(
     :class:`Task`.  ``alias`` maps fn-local param names -> global param
     names; structurally identical tasks (every layer's ln1, ...) share ONE
     fn object so jit compiles each op shape once, not once per layer.
+
+    ``traced``, a dict the builder hands over empty, remembers
+    (fn, argument tree and avals) -> output spec, so a shared ``fn`` is
+    traced once per argument shapes and not once per task: the
+    microbatched forward DAG has twins by the hundred (1,561 traces of 16
+    fns were 4 s of the placed-DAG cells' set-up).  Without it every task
+    is traced, as the decode, training and backbone builders have it.
     """
 
     def add(
@@ -135,7 +143,14 @@ def make_task_adder(
     ) -> None:
         dep_specs = [out_specs[d] for d in deps] if deps else [input_spec]
         pspec = {loc: specs[glob] for loc, glob in alias.items()}
-        out = jax.eval_shape(lambda pd, *a: fn(pd, *a), pspec, *dep_specs)
+        if traced is None:
+            out = jax.eval_shape(lambda pd, *a: fn(pd, *a), pspec, *dep_specs)
+        else:
+            leaves, tree = jax.tree_util.tree_flatten((pspec, dep_specs))
+            key = (fn, tree, tuple((tuple(x.shape), x.dtype) for x in leaves))
+            out = traced.get(key)
+            if out is None:
+                out = traced[key] = jax.eval_shape(fn, pspec, *dep_specs)
         out_specs[tid] = out
         globals_ = list(alias.values())
         tasks.append(
@@ -218,7 +233,8 @@ def build_gpt2_dag(
     tasks: List[Task] = []
     # running map of task_id -> output spec, for eval_shape chaining
     out_specs: Dict[str, Any] = {}
-    add = make_task_adder(tasks, out_specs, specs, input_spec, effective_flops)
+    add = make_task_adder(tasks, out_specs, specs, input_spec, effective_flops,
+                          traced={})
 
     # ---- task fns: fn(params_dict, *dep_outputs), local param names ------
     def make_f_embedding(lo, hi):

@@ -776,9 +776,11 @@ def test_leaf_phases_tile_the_call_and_land_once_in_the_process_registry(
     hists = process_metrics().snapshot()["histograms"]
     assert set(hists) == (
         {f"execute.phase.{k}" for k in LEAVES | {"loop_s"}}
-        | {"execute.wall_s", "execute.tasks_per_launch"}
+        | {"execute.wall_s", "execute.tasks_per_launch",
+           "execute.native_layout_exports"}
     )
     assert all(h["count"] == 5 for h in hists.values())
+    assert hists["execute.native_layout_exports"]["max"] == 0   # the CPU
     assert hists["execute.wall_s"]["max"] >= rep.wall_s
     reset_ambient()
     assert process_metrics().snapshot()["histograms"] == {}
@@ -1147,3 +1149,215 @@ def test_the_fence_round_trip_is_probed_once_a_backend(small, monkeypatch):
     assert len(calls) == 1
     assert all(r.leaf_phases()["rtt_s"] >= 0 for r in reps)
     assert reps[3].leaf_phases()["rtt_s"] == 0.0
+
+
+# -- the layout of what a launch exports -------------------------------------
+
+NATIVE_CASES = [(1, "heft"), (4, "pack"), (4, "roundrobin")]
+NATIVE_IDS = [f"{p}-{n}dev" for n, p in NATIVE_CASES]
+
+
+def _aval(graph, tid):
+    spec = graph[tid].out_shape
+    return tuple(spec.shape), spec.dtype
+
+
+@pytest.mark.parametrize("n_devices,policy", NATIVE_CASES, ids=NATIVE_IDS)
+def test_only_a_value_that_stays_on_its_chip_may_keep_its_layout(
+        n_devices, policy):
+    """The exports a plan leaves to the compiler (``native_slots``) are
+    those no launch on another chip reads, the step's output among them —
+    less what some launch of the same program may not leave open
+    (launches of one program ask alike) and a result that could take a
+    dying argument's buffer (it is that buffer); a value that is put, a
+    kept output and the graph input never are."""
+    dag, params, _ids, backend, schedule = _deep(
+        n_devices, policy, n_layer=2
+    )
+    graph, placement = dag.graph, schedule.placement
+    plan = _default_plan(dag, params, backend, schedule)
+    readers = {}
+    for t in graph.topo_order:
+        for d in _deps(graph, t):
+            readers.setdefault(d, []).append(t)
+    tid_of = {
+        s: t for st in plan.steps for t, s in zip(st.out_tids, st.out_slots)
+    }
+    stays = {
+        t for t in tid_of.values()
+        if all(placement[r] == placement[t] for r in readers.get(t, ()))
+    }
+    final = graph.topo_order[-1]
+    assert final in stays
+    put = {t for st in plan.steps for t in st.xfer_src_tids}
+    assert bool(put) is (n_devices > 1)
+    assert not put & stays
+    inputs = {s for _n, _d, s in plan.input_slots}
+    # per program (launches that share one executable): the positions its
+    # launches ask about, and those every one of them could
+    asked_of, could = {}, {}
+    for st in plan.steps:
+        assert set(st.native_slots) <= set(st.out_slots) - inputs
+        dying = {
+            _aval(graph, tid_of[st.arg_slots[a - 1]])
+            for a in st.donate_argnums
+        }
+        pos = {st.out_slots.index(s) for s in st.native_slots}
+        ok = {
+            i for i, s in enumerate(st.out_slots)
+            if tid_of[s] in stays and _aval(graph, tid_of[s]) not in dying
+        }
+        assert asked_of.setdefault(id(st.fn), pos) == pos
+        could[id(st.fn)] = could.get(id(st.fn), ok) & ok
+    assert asked_of == could
+    asked = {tid_of[s] for st in plan.steps for s in st.native_slots}
+    assert final in asked and asked <= stays
+    if n_devices == 1 and donation_supported():
+        assert asked < stays   # the residual stream is donated on
+    order = backend.dispatch_order(graph, schedule)
+    placed, _ = backend.place_params(graph, schedule, params)
+    kept = DispatchPlan.build(
+        backend, graph, schedule, order, placed, coalesce=True,
+        keep_outputs=True,
+    )
+    assert not any(st.native_slots for st in kept.steps)
+
+
+def _native_observed():
+    from distributed_llm_scheduler_tpu.obs import process_metrics
+
+    return process_metrics().snapshot()["histograms"].get(
+        "execute.native_layout_exports"
+    )
+
+
+@pytest.mark.parametrize("n_devices,policy", NATIVE_CASES[:2],
+                         ids=NATIVE_IDS[:2])
+def test_a_layout_the_compiler_picks_is_kept_and_its_readers_follow(
+        n_devices, policy, monkeypatch):
+    """With the real CPU compiler every export comes back in the default
+    layout: the histogram observes 0 and the logits are
+    ``execute_dag_locally``'s bit for bit.  With the compiler's answer
+    stubbed to another layout, each producer is built with it, the
+    readers compile against what they are handed — as many fused programs
+    as before — and the histogram observes how many exports kept it."""
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+
+    from distributed_llm_scheduler_tpu.backends import dispatch_plan
+    from distributed_llm_scheduler_tpu.frontend.gpt2_dag import (
+        execute_dag_locally,
+    )
+    from distributed_llm_scheduler_tpu.obs import process_metrics, reset_ambient
+
+    def structures():
+        return process_metrics().snapshot()["gauges"][
+            "compile.group_structures"]["value"]
+
+    reset_ambient()
+    dag, params, ids, backend, schedule = _deep(n_devices, policy, n_layer=2)
+    rep = backend.execute(dag.graph, schedule, params, ids)
+    want = np.asarray(execute_dag_locally(dag, params, ids))
+    assert np.array_equal(np.asarray(rep.output), want)
+    seen = _native_observed()
+    assert (seen["count"], seen["max"]) == (1, 0)
+    plan = backend._prepared[dag.graph].plan
+    assert plan.native_layout_exports == 0
+    asked = sum(len(st.native_slots) for st in plan.steps)
+    programs = len({id(st.fn) for st in plan.steps})
+    built, gauge = len(backend._group_cache), structures()
+
+    # the head's chip answers (0, 2, 1) for every value it is asked about
+    # (all of rank 3 in this graph); the others answer as they do
+    head = backend.cluster[schedule.placement["output_concat"]].jax_device
+    picked = Format(Layout((0, 2, 1)), SingleDeviceSharding(head))
+    real = dispatch_plan.NativeLaunch.picked
+
+    def answer(self, pd, args):
+        formats, avals = real(self, pd, args)
+        return jax.tree_util.tree_map(
+            lambda fmt: picked if fmt.sharding == picked.sharding else fmt,
+            formats,
+        ), avals
+
+    monkeypatch.setattr(dispatch_plan.NativeLaunch, "picked", answer)
+    reset_ambient()
+    other = DeviceBackend(backend.cluster)
+    stubbed = other.execute(dag.graph, schedule, params, ids)
+    again = other.execute(dag.graph, schedule, params, ids, warmup=False)
+    plan2 = other._prepared[dag.graph].plan
+    assert plan2.signature() == plan.signature()
+    on_head = sum(
+        len(st.native_slots) for st in plan2.steps if st.dev == head
+    )
+    assert 1 <= on_head <= asked
+    assert plan2.native_layout_exports == on_head
+    seen = _native_observed()
+    assert (seen["count"], seen["min"], seen["max"]) == (2, on_head, on_head)
+    assert stubbed.output.format.layout.major_to_minor == (0, 2, 1)
+    assert np.array_equal(np.asarray(stubbed.output), want)
+    assert np.array_equal(np.asarray(again.output), want)
+    assert len({id(st.fn) for st in plan2.steps}) == programs
+    assert (len(other._group_cache), structures()) == (built, gauge)
+    # the programs that keep a layout were compiled in this process, and
+    # the persistent cache is on again for everything after them
+    assert jax.config.jax_enable_compilation_cache
+    reset_ambient()
+
+
+def test_an_executable_from_the_persistent_cache_forgets_its_result_layout(
+        tmp_path):
+    """Why a launch that keeps a layout is compiled in this process
+    (``_compile_in_process``): with the pinned jaxlib an executable loaded
+    from the persistent compilation cache hands out arrays that report the
+    runtime's default layout whatever layout it wrote them in, and a
+    reader compiled against what they report reads other values.  The day
+    the first assertion after ``loaded`` fails the loader keeps layouts:
+    ``_compile_in_process`` and the second, named program of
+    ``NativeLaunch`` can go, and ``auto`` be the launch's executable."""
+    import jax.numpy as jnp
+    from jax.experimental.compilation_cache import compilation_cache
+    from jax.experimental.layout import Format, Layout
+    from jax.sharding import SingleDeviceSharding
+
+    from distributed_llm_scheduler_tpu.backends.dispatch_plan import (
+        _compile_in_process,
+    )
+
+    names = ("jax_compilation_cache_dir",
+             "jax_persistent_cache_min_compile_time_secs",
+             "jax_persistent_cache_min_entry_size_bytes")
+    was = {n: getattr(jax.config, n) for n in names}
+    dev = jax.devices()[0]
+    written = Format(Layout((0, 2, 1)), SingleDeviceSharding(dev))
+    x = jax.device_put(
+        jnp.arange(4 * 16 * 32, dtype=jnp.float32).reshape(4, 16, 32), dev
+    )
+
+    def producer():   # a new jit object each time: nothing kept in memory
+        return jax.jit(lambda a: a * 2 + 1, out_shardings=written)
+
+    reader = jax.jit(lambda a: a.sum(axis=1))
+    try:
+        jax.config.update(names[0], str(tmp_path))
+        jax.config.update(names[1], 0.0)
+        jax.config.update(names[2], 0)
+        compilation_cache.reset_cache()
+        fresh = producer()(x)
+        assert fresh.format.layout.major_to_minor == (0, 2, 1)
+        assert any("jit__lambda" in f.name for f in tmp_path.iterdir())
+        want = np.asarray(x).sum(axis=1) * 2 + 16
+        assert np.array_equal(np.asarray(reader(fresh)), want)
+        loaded = producer()(x)   # the same program, from the cache
+        assert loaded.format.layout.major_to_minor == (0, 1, 2)   # the fault
+        assert np.array_equal(np.asarray(loaded), np.asarray(fresh))
+        assert not np.array_equal(np.asarray(reader(loaded)), want)
+        # compiled in this process, with that entry in the cache, it holds
+        own = _compile_in_process(producer(), x)(x)
+        assert own.format.layout.major_to_minor == (0, 2, 1)
+        assert np.array_equal(np.asarray(reader(own)), want)
+        assert jax.config.jax_enable_compilation_cache
+    finally:
+        for n in names:
+            jax.config.update(n, was[n])
+        compilation_cache.reset_cache()

@@ -168,6 +168,25 @@ def test_microbatched_dag_matches_fused_forward():
     )
 
 
+def test_a_task_fn_is_traced_once_per_argument_shapes(monkeypatch):
+    """Building the DAG shape-evaluates a shared ``fn`` once per argument
+    shapes, not once per task: every layer's and microbatch's tasks read
+    the spec their first twin was traced to."""
+    calls = []
+    real = jax.eval_shape
+    monkeypatch.setattr(
+        jax, "eval_shape", lambda fn, *a, **k: calls.append(fn) or real(fn, *a, **k)
+    )
+    dag = build_gpt2_dag(GPT2Config.tiny(), batch=8, seq_len=16, microbatches=4)
+    fns = {dag.graph[t].fn for t in dag.graph.topo_order}
+    assert len(calls) < len(dag.graph) // 3
+    # the residual adds meet two shapes' worth of arguments at most
+    assert len(fns) <= len(calls) <= 2 * len(fns)
+    twins = [t for t in dag.graph.topo_order if t.endswith("layer_1_ln1")]
+    assert len(twins) == 4
+    assert len({id(dag.graph[t].out_shape) for t in twins}) == 1
+
+
 def test_microbatch_validation():
     with pytest.raises(ValueError, match="divisible"):
         build_gpt2_dag(GPT2Config.tiny(), batch=3, seq_len=16, microbatches=2)
