@@ -550,8 +550,8 @@ def _kernel_case(name: str, run_kernel, run_ref) -> Dict[str, Any]:
 def phase_kernels(meter: CompileMeter, interpret: bool = False,
                   n_head: int = 12, head_dim: int = 64,
                   flash_T: int = 512) -> Dict[str, Any]:
-    """``_flash_mha``, ``_paged_flash`` and ``_paged_flash_ragged``
-    compiled (``interpret`` is for CPU tests only) against their XLA
+    """``_flash_mha``, ``_flash_mha_rows``, ``_paged_flash`` and
+    ``_paged_flash_ragged`` compiled (``interpret`` is for CPU tests only) against their XLA
     references within ``KERNEL_ATOL``, at the serve geometry (page 8, 4
     slots, 4 pages per slot) and the execute phase's sequence length.
 
@@ -618,6 +618,25 @@ def phase_kernels(meter: CompileMeter, interpret: bool = False,
             lambda: A._flash_mha(q, k, v, causal=True, sm_scale=scale,
                                  block=A._pick_block(T), interpret=interpret),
             lambda: A.reference_mha(q, k, v, causal=True, sm_scale=scale),
+        )
+
+    def rows_case(name, T=512, H=16, hd=64):
+        """The row form at the medium-DAG attention task's shape: bf16 q,
+        k, v read as thirds of one ``(B, T, 3 * H * hd)`` projection
+        result, against the reference on the float32 head-split view."""
+        from distributed_llm_scheduler_tpu.ops import flash_rows as R
+
+        rng = np.random.RandomState(9)
+        qkv = jnp.asarray(rng.standard_normal((2, T, 3 * H * hd)),
+                          jnp.bfloat16)
+        return _kernel_case(
+            name,
+            lambda: R._flash_mha_rows(
+                qkv, qkv, qkv, n_head=H, packed=True, causal=True,
+                sm_scale=hd ** -0.5, interpret=interpret),
+            lambda: R._merge_heads(A.reference_mha(
+                *(t.astype(jnp.float32) for t in R._split_heads(qkv, H)),
+                causal=True, sm_scale=hd ** -0.5)),
         )
 
     def latent_cases():
@@ -693,6 +712,7 @@ def phase_kernels(meter: CompileMeter, interpret: bool = False,
     with timed(meter, ph):
         ph["cases"] = [
             flash_case(f"flash_mha_T{flash_T}", flash_T),
+            rows_case("flash_mha_rows_T512_h16"),
             paged_case(f"paged_flash_ps{ps}_f32"),
             ragged_case(f"paged_flash_ragged_ps{ps}_q8_f32"),
         ]

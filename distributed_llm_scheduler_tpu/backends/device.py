@@ -1611,6 +1611,9 @@ class DeviceBackend:
             pm.histogram("execute.native_layout_exports").observe(
                 plan.native_layout_exports
             )
+        row_form = getattr(graph, "attn_row_form_tasks", None)
+        if row_form is not None:
+            pm.histogram("execute.attn_row_form_tasks").observe(row_form)
         if prep is not None:
             pm.counter(f"execute.prepared.{outcome}").inc()
         return report

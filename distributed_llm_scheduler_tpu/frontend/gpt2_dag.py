@@ -31,6 +31,7 @@ import jax.numpy as jnp
 from ..core.graph import Task, TaskGraph
 from ..models import gpt2
 from ..models.gpt2 import GPT2Config
+from ..ops.flash_rows import rows_impl
 from .vocab_sharding import logit_concat_fn, make_embed_partial_fn, shard_bounds
 
 # Seed estimate for compute_time: effective sustained FLOP/s of one core on
@@ -286,6 +287,7 @@ def build_gpt2_dag(
     # reference test_gpt2.py:54-166; mb prefix only when pipelining) -------
     hd = D // H
     mb_outputs: List[str] = []
+    row_form_tasks = 0
     for m in range(microbatches):
         mb = f"mb{m}_" if microbatches > 1 else ""
         emb = f"{mb}embedding"
@@ -322,6 +324,12 @@ def build_gpt2_dag(
                 {"qkv_w": pre + "attn_qkv_w", "qkv_b": pre + "attn_qkv_b",
                  "proj_w": pre + "attn_proj_w", "proj_b": pre + "attn_proj_b"},
                 attn_flops, grp)
+            # does this task run the row-form kernel?  The predicate that
+            # dispatches inside ``gpt2.causal_attention``, on what the
+            # task is handed
+            x = out_specs[ln1]
+            row_form_tasks += rows_impl(
+                None, x.shape[1], H, hd, x.dtype) is not None
 
             attn_res = f"{mb}layer_{i}_attn_residual"
             add(attn_res, f_residual, [prev, attn], {}, 1.0 * Bm * T * D, grp)
@@ -382,6 +390,8 @@ def build_gpt2_dag(
         return params
 
     graph = TaskGraph(tasks, name=name).freeze()
+    # ``execute()`` reports it a call (``execute.attn_row_form_tasks``)
+    graph.attn_row_form_tasks = row_form_tasks
     return ModelDAG(
         graph=graph,
         config=config,

@@ -29,8 +29,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ..ops.attention import mha as _fused_mha
 from ..ops.attention import paged_decode_attention
+from ..ops.flash_rows import mha_rows as _mha_rows
 
 # Megatron split (parallel/sharding.py reads it): parameter-name pattern
 # -> PartitionSpec, checked in order
@@ -165,18 +165,18 @@ def causal_attention(
     """Multi-head causal self-attention incl. output projection — one task,
     matching the reference's per-layer "attention" granularity
     (reference test_gpt2.py:75-90: qkv + proj params on a single task)."""
-    B, T, D = x.shape
-    hd = D // n_head
+    # q, k, v stay where the projection wrote them: the row-form kernel
+    # reads the three thirds of ``qkv`` through its index maps and writes
+    # (B, T, D) for the output projection, when the shapes allow it
+    # (ops/flash_rows.rows_supported: whole 128-lane tiles of heads, T in
+    # whole blocks — GPT-2 small / medium / large); any other call (XL's
+    # 25 heads, the tiny configurations, XLA off the TPU) is split into
+    # (B, n_head, T, hd), run through ops/attention.mha and merged, as
+    # before.  The choice is the shapes' alone.  This function keeps its
+    # line count: the lines below it are in the serving kernels' trace
+    # stacks (ROADMAP D16).
     qkv = x @ qkv_w + qkv_b
-    q, k, v = jnp.split(qkv, 3, axis=-1)
-
-    def heads(t):  # (B, T, D) -> (B, n_head, T, hd)
-        return t.reshape(B, T, n_head, hd).transpose(0, 2, 1, 3)
-
-    q, k, v = heads(q), heads(k), heads(v)
-    # fused flash-attention kernel on TPU, plain-XLA path elsewhere (ops/)
-    out = _fused_mha(q, k, v, causal=True)
-    out = out.transpose(0, 2, 1, 3).reshape(B, T, D)
+    out = _mha_rows(qkv, n_head=n_head, causal=True)
     return out @ proj_w + proj_b
 
 

@@ -138,7 +138,8 @@ def test_smoke_kernels_phase_passes_interpreted(cs, meter):
                           flash_T=32)
     assert ph["ok"], ph
     assert [c["name"].split("_ps")[0].split("_T")[0] for c in ph["cases"]
-            ] == ["flash_mha", "paged_flash", "paged_flash_ragged"]
+            ] == ["flash_mha", "flash_mha_rows", "paged_flash",
+                  "paged_flash_ragged"]
     assert all("max_abs_diff" in c for c in ph["cases"] + ph["probe"])
 
 
@@ -780,3 +781,71 @@ def test_aot_laguna_chunk_program_leaves_its_pools_in_their_pages(
         assert not re.search(rf"copy-start\S*\(bf16\[{shape}\]", text), shape
     assert not re.search(rf"bf16\[(1,8,{cap},128|{ppseq},{ps},1024)\]", text)
     assert done.memory_analysis().temp_size_in_bytes < 0.2e9
+
+
+# every geometry class the row form's shape rule admits, at its largest
+@pytest.mark.parametrize("H,hd,T,dtype", [
+    (12, 64, 512, jnp.bfloat16),     # GPT-2 small: six tiles, three a step
+    (12, 64, 1024, jnp.bfloat16),
+    (20, 64, 1024, jnp.bfloat16),    # GPT-2 large at its full context
+    (20, 64, 1024, jnp.float32),
+    (8, 32, 512, jnp.bfloat16),      # four heads a tile, 1 MiB of scores
+    (4, 128, 1024, jnp.float32),     # a tile's blocks at the 4 MiB bound
+    (2, 256, 1024, jnp.bfloat16),    # a head of two tiles, at the bound
+], ids=lambda v: getattr(v, "__name__", str(v)))
+def test_aot_row_kernel_compiles_for_v5e_wherever_the_rule_admits(
+        v5e, H, hd, T, dtype):
+    """``auto`` on a TPU takes the row form on the rule's word alone, and
+    a Mosaic failure there is a hard error where the head-major path ran:
+    what ``rows_supported`` admits has to compile."""
+    from distributed_llm_scheduler_tpu.ops import flash_rows as R
+
+    assert R.rows_supported(T, H, hd, dtype)
+    jax.jit(lambda qkv: R._flash_mha_rows(
+        qkv, qkv, qkv, n_head=H, packed=True, causal=True,
+        sm_scale=hd ** -0.5, interpret=False,
+    )).lower(v5e((2, T, 3 * H * hd), dtype)).compile()
+
+
+def _split_mha_merge(x, qkv_w, qkv_b, proj_w, proj_b, n_head):
+    """The attention task as it stood before the row form (ISSUE 49):
+    q, k, v split into ``(B, H, T, hd)`` for the head-major kernel."""
+    from distributed_llm_scheduler_tpu.ops import attention as A
+    from distributed_llm_scheduler_tpu.ops import flash_rows as R
+
+    qkv = x @ qkv_w + qkv_b
+    out = R._merge_heads(A.mha(*R._split_heads(qkv, n_head), causal=True))
+    return out @ proj_w + proj_b
+
+
+@pytest.mark.parametrize("form,n_head,kernels,reorderings", [
+    ("rows", 16, ["_flash_mha_rows"], 0),
+    ("head-major", 16, ["_flash_mha"], 3),
+    ("rows", 25, ["_flash_mha"], 3),        # GPT-2 XL: the shapes refuse
+], ids=["medium-rows", "medium-head-major", "xl-falls-back"])
+def test_aot_attention_task_reads_its_projection_where_it_lies(
+        v5e, monkeypatch, form, n_head, kernels, reorderings):
+    """The ``causal_attention`` task at the medium-DAG shapes
+    (``bf16[4,512,1024]``, 16 heads) compiled for the v5e: ONE custom
+    call, named for the benchmark's ``^_flash_mha``, and no ``copy`` or
+    ``transpose`` of an activation — where the head-major form of the
+    same task, compiled the same way, re-orders q, k and v in HBM (and
+    GPT-2 XL's 25 heads still do: the shape rule refuses them)."""
+    from distributed_llm_scheduler_tpu.models import gpt2
+    from distributed_llm_scheduler_tpu.ops import attention as A
+
+    B, T, hd, dt = 4, 512, 64, jnp.bfloat16
+    D = n_head * hd
+    task = gpt2.causal_attention if form == "rows" else _split_mha_merge
+    monkeypatch.setattr(A, "_auto_impl", lambda: "pallas")
+    text = jax.jit(lambda *a: task(*a, n_head)).lower(
+        v5e((B, T, D), dt), v5e((D, 3 * D), dt), v5e((3 * D,), dt),
+        v5e((D, D), dt), v5e((D,), dt)).compile().as_text()
+    calls = re.findall(r"%(\S+?)(?:\.\d+)? = \S+ custom-call\([^\n]*"
+                       r"custom_call_target=\"tpu_custom_call\"", text)
+    assert calls == kernels
+    moved = re.findall(
+        rf"bf16\[(?:{B},{T},{D}|{B},{n_head},{T},{hd}|{B},{T},{n_head},{hd})\]\S* "
+        r"(?:copy|transpose)\(", text)
+    assert (len(moved) == 0) if not reorderings else (
+        len(moved) >= reorderings), moved

@@ -777,10 +777,11 @@ def test_leaf_phases_tile_the_call_and_land_once_in_the_process_registry(
     assert set(hists) == (
         {f"execute.phase.{k}" for k in LEAVES | {"loop_s"}}
         | {"execute.wall_s", "execute.tasks_per_launch",
-           "execute.native_layout_exports"}
+           "execute.native_layout_exports", "execute.attn_row_form_tasks"}
     )
     assert all(h["count"] == 5 for h in hists.values())
     assert hists["execute.native_layout_exports"]["max"] == 0   # the CPU
+    assert hists["execute.attn_row_form_tasks"]["max"] == 0     # XLA here
     assert hists["execute.wall_s"]["max"] >= rep.wall_s
     reset_ambient()
     assert process_metrics().snapshot()["histograms"] == {}
