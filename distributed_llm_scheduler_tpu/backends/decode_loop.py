@@ -2179,9 +2179,9 @@ class PagedDecodeEngine:
         rows those slots hold)) or a window-layer family's
         (``stats["attn"]``, (steps, layers, 2) = (rows the decoding
         slots' attention read in the full layers, in the window
-        layers)) or a state-layer family's (``stats["ssm"]``, (steps,
-        state layers) = the slots each layer stepped, every layer the
-        same).  Any other named array, and what :meth:`_emitted` read
+        layers)) or a state-layer family's (``stats["ssm"]`` / ``["conv"]``,
+        (steps, state layers) = the slots each layer stepped, every layer
+        the same).  Any other named array, and what :meth:`_emitted` read
         for it (``probe``), is the ``stats_probe``'s, if one is set."""
         np = self._np
         if not isinstance(stats, dict):
@@ -2208,19 +2208,31 @@ class PagedDecodeEngine:
                     ).observe(float(full / (full + ring)))
         if "loop" in stats:
             args.update(self._observe_loop(np.asarray(stats["loop"])))
-        if "ssm" in stats:
-            stepped = np.asarray(stats["ssm"])[:, 0]
-            args["ssm_slots"] = float(stepped.sum())
-            for reg in (self.metrics, process_metrics()):
-                reg.histogram("ssm.slots_stepped", unit="slots").observe(
-                    float(stepped[:max(steps_ran, 1)].mean()))
+        for kind in ("ssm", "conv"):
+            if kind in stats:
+                args[f"{kind}_slots"] = self._observe_slots_stepped(
+                    kind, np.asarray(stats[kind]), steps_ran)
         self._seg_span_args = args
         if self.stats_probe is not None and (stats or probe):
             self.stats_probe(
                 {**{k: np.asarray(v) for k, v in stats.items()
-                    if k not in ("moe", "dsa", "attn", "ssm", "loop")},
+                    if k not in ("moe", "dsa", "attn", "ssm", "conv", "loop")},
                  **probe},
                 list(self._slot_req), self.lengths.copy(), owed)
+
+    def _observe_slots_stepped(self, kind: str, stepped,
+                               steps_ran: int) -> float:
+        """A state-layer family's count of one segment, ``stepped``
+        (steps, state layers of ``kind``) = the slots whose state each
+        layer updated, every layer the same: histogram ``{kind}.
+        slots_stepped`` (mean over the steps in which a slot still
+        decoded) in the engine's registry and the process-wide one.
+        Returns the slot-steps of the whole segment, for its span."""
+        stepped = stepped[:, 0]
+        for reg in (self.metrics, process_metrics()):
+            reg.histogram(f"{kind}.slots_stepped", unit="slots").observe(
+                float(stepped[:max(steps_ran, 1)].mean()))
+        return float(stepped.sum())
 
     def _observe_loop(self, loop) -> Dict[str, float]:
         """A looped stack's counts of one segment, ``loop`` (steps,
@@ -2308,7 +2320,7 @@ class PagedDecodeEngine:
                 continue
             n = int(ran[s])
             if n:
-                self._tokens[rid].extend(int(t) for t in emitted[s])
+                self._tokens[rid].extend(emitted[s].tolist())
                 self.cur_tok[s, 0] = emitted[s][-1]
                 delivered += n
                 for rl in self._reqlogs:

@@ -235,6 +235,12 @@ for _f in (
         "ouro", f"{__name__}.ouro", "OuroConfig", {"ouro-tiny": "tiny"},
         "n_layers", "max_positions",
     ),
+    # every layer an operator AND a feed-forward: gated short convolutions
+    # (a state a slot) beside attention layers, all experts held
+    Family(
+        "lfm2", f"{__name__}.lfm2", "Lfm2Config", {"lfm2-tiny": "tiny"},
+        "n_layers", "max_positions",
+    ),
 ):
     register_family(_f)
 del _f

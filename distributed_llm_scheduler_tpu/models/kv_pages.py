@@ -516,7 +516,7 @@ class LayerCache:
     ``q_heads``: the query heads that read this layer's kv rows, where
     the layers differ in them (else :attr:`CacheSpec.q_heads`).
     ``state``: a **state layer** — each of ``rows`` is one fixed-size
-    array a SLOT (a recurrent state), not a row a token.  ``rows`` may be
+    array a SLOT (a state of any dtype), not a row a token.  ``rows`` may be
     empty: the layer caches nothing."""
 
     rows: Tuple[Tuple[str, Tuple[int, ...]], ...]
@@ -579,7 +579,7 @@ class CacheSpec:
 
     **State layers.**  A layer with ``state`` keeps, for each of its
     pool kinds, ONE array a slot whatever the context's length (a
-    recurrent mixer's state, its convolution's last inputs): pool ``(1 +
+    recurrent mixer's state, a convolution's last inputs): pool ``(1 +
     slots, *shape)``, row 0 the trash row, slot ``s`` row ``1 + s``
     (:meth:`state_rows`) — slot-owned like a ring, so the allocator,
     admission and ``pages_needed`` never hear of it.  It is not indexed
@@ -588,8 +588,8 @@ class CacheSpec:
     for the slots that decode).  The dense cache of a prefill program
     holds, per state kind, the slots' states stacked in layer order
     ``(layers with it, b, *shape)``.  ``dtypes`` gives a pool kind its
-    own dtype where it is not the cache's (a float32 state beside bf16
-    K/V).
+    own dtype where it is not the cache's (a float32 SSM state beside
+    bf16 K/V; a short convolution's carried rows keep the cache's).
 
     **Passes.**  A stack whose ``layers`` run ``passes`` times a token on
     one set of weights keeps a row a (pass, layer): a cache entry is no

@@ -508,11 +508,6 @@ class ServingFrontend:
         """Evict lower-tier in-flight victims until ``req`` fits;
         returns the new (free_slots, free_pages) or None when no victim
         set suffices (then nothing is evicted)."""
-        occ = self.engine.page_occupancy()
-        # under sharing, evicting a victim frees only its EXCLUSIVE
-        # pages (aliased prefix chunks stay resident for their other
-        # owners) — the conservative count keeps the estimate honest
-        per_req = occ.get("per_request_exclusive", occ["per_request"])
         prefilling = getattr(self.engine, "is_prefilling", None)
         victims = [
             v for v in self._inflight.values()
@@ -522,6 +517,16 @@ class ServingFrontend:
             and not (prefilling is not None
                      and prefilling(v.engine_rid()))
         ]
+        if not victims:
+            # one tier in flight: nobody to evict, and a full engine asks
+            # this of every waiting request every tick (the occupancy
+            # below walks every slot's pages: backlog x slots a tick)
+            return None
+        occ = self.engine.page_occupancy()
+        # under sharing, evicting a victim frees only its EXCLUSIVE
+        # pages (aliased prefix chunks stay resident for their other
+        # owners) — the conservative count keeps the estimate honest
+        per_req = occ.get("per_request_exclusive", occ["per_request"])
         # most recently arrived, lowest tier first: evict the work with
         # the least sunk queue-wait
         victims.sort(key=lambda v: (-v.a.priority, -v.a.t, v.a.rid))

@@ -164,9 +164,10 @@ def _tiny(family: str):
 # -- the contract -----------------------------------------------------------------
 
 
-def test_the_toy_is_registered_beside_the_nine():
-    assert FAMILIES == ["dots3", "glm4_lite", "gpt2", "laguna", "llama",
-                        "mixtral", "nemotron_h", "ouro", "toy", "xing4"]
+def test_the_toy_is_registered_beside_the_ten():
+    assert FAMILIES == ["dots3", "glm4_lite", "gpt2", "laguna", "lfm2",
+                        "llama", "mixtral", "nemotron_h", "ouro", "toy",
+                        "xing4"]
 
 
 @pytest.mark.parametrize("family", FAMILIES)
@@ -290,7 +291,7 @@ def test_what_each_family_offers():
     dense = {f for f in rows
              if models.offers(rows[f], *models.CACHED_FUNCTIONS)}
     assert served == {"gpt2", "xing4", "dots3", "glm4_lite", "laguna",
-                      "nemotron_h", "ouro", "toy"}
+                      "nemotron_h", "ouro", "lfm2", "toy"}
     assert dense == {"gpt2", "llama", "mixtral"}
     assert {f for f in rows if models.offers(
         rows[f], *models.DRAFT_FUNCTIONS)} == {"glm4_lite"}
